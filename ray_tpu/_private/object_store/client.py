@@ -45,11 +45,21 @@ def ensure_store_built() -> str:
     src = os.path.join(_repo_root(), "src", "object_store", "store.cc")
     if os.path.exists(path) and os.path.getmtime(path) >= os.path.getmtime(src):
         return path
-    subprocess.run(
-        ["make", "-C", os.path.join(_repo_root(), "src", "object_store")],
-        check=True,
-        capture_output=True,
-    )
+    try:
+        subprocess.run(
+            ["make", "-C", os.path.join(_repo_root(), "src", "object_store")],
+            check=True,
+            capture_output=True,
+            text=True,
+        )
+    except FileNotFoundError as e:
+        raise RuntimeError(
+            f"cannot build the object store daemon {path}: `make` is not "
+            f"installed") from e
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"cannot build the object store daemon {path} (is g++ "
+            f"installed?): make exited with {e.returncode}\n{e.stderr}") from e
     return path
 
 

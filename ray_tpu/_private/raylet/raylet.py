@@ -340,9 +340,10 @@ class Raylet:
         env["RAY_TPU_STORE_SOCKET"] = self.store_socket
         env["RAY_TPU_NODE_ID"] = self.node_id
         env["RAY_TPU_CONFIG_JSON"] = config.to_json()
-        # workers must not grab the TPU runtime at import; chips are
-        # assigned per-lease via TPU_VISIBLE_CHIPS
-        env.setdefault("JAX_PLATFORMS", "")
+        # JAX_PLATFORMS passes through untouched: the worker records it as
+        # the node's setting, pins itself to the CPU, and returns to the
+        # node's setting only under a lease that holds chips
+        # (default_worker.SetLeaseContext)
         return env
 
     def _get_zygote(self) -> Optional[Zygote]:

@@ -83,14 +83,13 @@ def _child(req: dict, protocol_fds) -> None:
 def main() -> None:
     # the heavy imports happen ONCE, before the serve loop; every spawn
     # is then a fork of this warmed image. jax is included (import only
-    # — no backend init, no threads): actor workers almost always need
-    # it, and one warmed copy is shared copy-on-write pool-wide.
-    import ray_tpu._private.workers.default_worker  # noqa: F401
+    # — it initialises no backend and starts no thread of its own; the
+    # OS threads present are numpy's BLAS pool, which survives a fork):
+    # actor workers almost always need it, and one warmed copy is shared
+    # copy-on-write pool-wide.
+    import jax  # noqa: F401
 
-    try:
-        import jax  # noqa: F401
-    except ImportError:
-        pass
+    import ray_tpu._private.workers.default_worker  # noqa: F401
 
     inp = sys.stdin.buffer
     out = sys.stdout.buffer

@@ -25,6 +25,10 @@ TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 TPU_NAME_ENV = "TPU_NAME"
 TPU_TOPOLOGY_ENV = "TPU_TOPOLOGY"  # e.g. "4x4"
 TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
+# per-process share of a host's chips (libtpu): chips -> x,y,z bounds
+TPU_CHIPS_PER_PROCESS_BOUNDS_ENV = "TPU_CHIPS_PER_PROCESS_BOUNDS"
+TPU_PROCESS_BOUNDS_ENV = "TPU_PROCESS_BOUNDS"
+_SUBSET_BOUNDS: Dict[int, str] = {1: "1,1,1", 2: "1,2,1"}
 # test/dev override
 FAKE_TPU_CHIPS_ENV = "RAY_TPU_FAKE_CHIPS"
 
@@ -60,25 +64,45 @@ def _detect_chips_from_devfs() -> int:
     return 0
 
 
+def jax_backend_is_up() -> bool:
+    """Whether this process has initialised a JAX backend — asked without
+    importing JAX or initialising one."""
+    import sys
+
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
 def _detect_chips_from_jax() -> int:
     """Last-resort detection via an initialized jax runtime — only if a
     backend ALREADY exists. jax.devices() on a cold runtime would
-    initialize the platform plugin here, inside resource detection: slow
-    at best, and a remote/tunneled TPU runtime that is down blocks
-    ray_tpu.init() indefinitely."""
-    import sys
+    initialize the TPU backend here, inside resource detection: slow at
+    best, and it would make the detecting process (driver or raylet) the
+    chip's owner, so no worker could have it."""
+    if not jax_backend_is_up():
+        return 0  # never trigger init here
+    import jax
 
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return 0
     try:
-        from jax._src import xla_bridge as _xb
-
-        if not getattr(_xb, "_backends", None):
-            return 0  # no backend initialized; never trigger init here
         return len([d for d in jax.devices() if "tpu" in d.platform.lower() or "TPU" in str(d)])
     except Exception:
         return 0
+
+
+def pin_jax_platforms(platforms: str) -> None:
+    """Hold this process's JAX to ``platforms`` (the JAX_PLATFORMS
+    syntax; "" = JAX's own choice). The environment covers a JAX not yet
+    imported, the config a JAX that is (the zygote imports it before the
+    fork). A backend that already exists is not undone by this."""
+    import sys
+
+    os.environ["JAX_PLATFORMS"] = platforms
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", platforms or None)
 
 
 def parse_pod_type(accelerator_type: str) -> Tuple[str, int]:
@@ -155,9 +179,18 @@ class TPUAcceleratorManager:
     @staticmethod
     def set_current_process_visible_accelerator_ids(ids: List[str]) -> None:
         os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
-        # jax reads TPU_VISIBLE_DEVICES / TPU_CHIPS_PER_PROCESS_BOUNDS for
-        # subsetting a host's chips; mirror for libtpu consumers.
         os.environ["TPU_VISIBLE_DEVICES"] = os.environ[TPU_VISIBLE_CHIPS_ENV]
+        # A process given SOME of its host's chips must also be told the
+        # shape of its share, or libtpu waits for the whole host's
+        # topology. A whole-host lease keeps the host's own description.
+        bounds = _SUBSET_BOUNDS.get(len(ids))
+        total = TPUAcceleratorManager.get_current_node_num_accelerators()
+        if bounds is not None and len(ids) < total:
+            os.environ[TPU_CHIPS_PER_PROCESS_BOUNDS_ENV] = bounds
+            os.environ[TPU_PROCESS_BOUNDS_ENV] = "1,1,1"
+        else:
+            os.environ.pop(TPU_CHIPS_PER_PROCESS_BOUNDS_ENV, None)
+            os.environ.pop(TPU_PROCESS_BOUNDS_ENV, None)
 
     @staticmethod
     def validate_resource_request_quantity(quantity: float) -> Tuple[bool, str]:

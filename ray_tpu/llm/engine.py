@@ -17,7 +17,9 @@ class LLMEngine:
         import jax
 
         from ray_tpu.models import transformer as T
+        from ray_tpu.parallel.bootstrap import configure_compilation_cache
 
+        configure_compilation_cache()
         self.config = config
         self.tokenizer = config.get_tokenizer()
         cfg = T.config(config.model)

@@ -34,6 +34,9 @@ def build_llm_deployment(config: LLMConfig):
     )
     class LLMServer:
         def __init__(self):
+            from ray_tpu.parallel.bootstrap import watch_compiles
+
+            self._compiles = watch_compiles()
             if config.continuous_batching:
                 from ray_tpu.llm.engine import ContinuousLLMEngine
 
@@ -62,9 +65,21 @@ def build_llm_deployment(config: LLMConfig):
             return self._generate_batch(prompt)
 
         def engine_stats(self) -> dict:
+            """The batcher's counters and the device this replica's
+            engine runs on, as JAX reports it in THIS process."""
+            import jax
+
             st = getattr(getattr(self.engine, "batcher", None), "stats",
                          None)
-            return dict(st) if st is not None else {}
+            out = dict(st) if st is not None else {}
+            devices = jax.devices()
+            mem = devices[0].memory_stats() or {}
+            out.update(self._compiles)
+            out.update(platform=devices[0].platform,
+                       device_kind=devices[0].device_kind,
+                       device_count=len(devices),
+                       peak_bytes_in_use=mem.get("peak_bytes_in_use"))
+            return out
 
         def generate_stream(self, prompt: str,
                             max_tokens: Optional[int] = None):

@@ -104,8 +104,8 @@ class ContinuousBatcher:
         self._last_tok = np.zeros(slots, np.int32)
         self._host_len = np.zeros(slots, np.int64)
         # stats (observable by tests/metrics)
-        self.stats = {"admitted": 0, "finished": 0, "steps": 0,
-                      "max_active": 0, "tokens_out": 0,
+        self.stats = {"admitted": 0, "finished": 0, "failed": 0,
+                      "steps": 0, "max_active": 0, "tokens_out": 0,
                       "last_admit_step": -1}
         self._prefill_jits: Dict[int, Any] = {}
         self._decode_jit = jax.jit(self._decode_impl)
@@ -144,6 +144,8 @@ class ContinuousBatcher:
             t = q.get()
             if t is None:
                 return
+            if isinstance(t, BaseException):
+                raise t  # the admit or the step failed: not a short answer
             yield t
 
     def shutdown(self) -> None:
@@ -232,10 +234,7 @@ class ContinuousBatcher:
                 # the slot goes back and THIS request fails; others and
                 # the pump survive
                 self._free.append(slot)
-                if req.future is not None and not req.future.done():
-                    req.future.set_exception(e)
-                if req.stream_q is not None:
-                    req.stream_q.put(None)
+                self._fail(req, e)
                 continue
             admitted = True
         self.stats["max_active"] = max(self.stats["max_active"],
@@ -323,15 +322,21 @@ class ContinuousBatcher:
                 self._step()
             except Exception as e:  # noqa: BLE001 — fail active requests
                 for req in list(self._active.values()):
-                    if req.future is not None and not req.future.done():
-                        req.future.set_exception(e)
-                    if req.stream_q is not None:
-                        req.stream_q.put(None)
+                    self._fail(req, e)
                     self._retire_silent(req)
                 import logging
 
                 logging.getLogger(__name__).exception(
                     "continuous-batching step failed")
+
+    def _fail(self, req: _Request, e: BaseException) -> None:
+        """Resolve a request with the device-side failure: its caller —
+        future or stream — sees the exception, and it is counted."""
+        self.stats["failed"] += 1
+        if req.future is not None and not req.future.done():
+            req.future.set_exception(e)
+        if req.stream_q is not None:
+            req.stream_q.put(e)
 
     def _retire_silent(self, req: _Request) -> None:
         if req.slot >= 0:

@@ -6,9 +6,11 @@ Three tiers, all the same math (softmax(QK^T * scale + mask) V):
 - `blockwise_attention` : online-softmax over KV chunks via `lax.scan` —
   O(S * block) memory, differentiable by autodiff, XLA-fusable. This is
   the building block ring attention rotates (ops/ring_attention.py).
-- `flash_attention` : pallas TPU kernel for the forward hot path
-  (inference / benchmark); falls back to blockwise off-TPU. Gradients
-  flow through a custom_vjp whose backward recomputes blockwise.
+- `flash_attention` : pallas TPU kernels for both passes
+  (FlashAttention-2 forward and backward under a custom_vjp); off-TPU
+  the same entry point runs blockwise. GSPMD cannot partition the
+  kernels: on a mesh they are called under shard_map
+  (train/step.py make_attn_fn).
 
 The reference framework has NO native attention (SURVEY.md §5
 "Long-context: absent in the reference" — it defers to vLLM/torch).
@@ -366,10 +368,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, block_q, block_k):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """Whether the default backend is a TPU. A failure to query devices
+    propagates: answering "no" would run a TPU job blockwise unnoticed."""
+    return jax.devices()[0].platform == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -18,12 +18,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import lax, shard_map as _shard_map
 
 
 def pipeline_spmd(body: Callable, x_mb: jax.Array, pos_mb: jax.Array,

@@ -23,10 +23,12 @@ from ray_tpu.parallel.sharding import (
 )
 from ray_tpu.parallel.bootstrap import (
     HostGroupSpec,
+    configure_compilation_cache,
     initialize_host,
     local_process_specs,
     megascale_env,
     shutdown_host,
+    watch_compiles,
 )
 
 __all__ = [
@@ -44,8 +46,10 @@ __all__ = [
     "constrain",
     "shard_batch",
     "HostGroupSpec",
+    "configure_compilation_cache",
     "initialize_host",
     "megascale_env",
     "shutdown_host",
     "local_process_specs",
+    "watch_compiles",
 ]

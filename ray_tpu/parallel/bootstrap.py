@@ -52,6 +52,54 @@ def megascale_env(spec: HostGroupSpec) -> Dict[str, str]:
     return env
 
 
+def configure_compilation_cache() -> str:
+    """Place JAX's persistent compilation cache for this process and
+    return the directory. Called by every process that compiles (train
+    worker, LLM replica).
+
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX's own handling of it
+    stands and nothing is set in code. Otherwise the cache goes to
+    ``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    the cache key — a directory from tempfile, a pid or the clock never
+    hits. Workers inherit the variable through the raylet's environment.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def watch_compiles() -> Dict[str, float]:
+    """Count this process's XLA compiles from JAX's own monitoring
+    events. Returns a dict that keeps updating: ``compile_s`` (seconds
+    spent obtaining executables, cache reads included) and the persistent
+    cache's ``cache_hits`` / ``cache_misses``. Costs nothing per step."""
+    import jax.monitoring
+
+    seen: Dict[str, float] = {"compile_s": 0.0, "cache_hits": 0,
+                              "cache_misses": 0}
+
+    def on_duration(event: str, duration: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen["compile_s"] += duration
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            seen["cache_hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            seen["cache_misses"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    return seen
+
+
 def initialize_host(spec: HostGroupSpec, platform: str = "tpu") -> None:
     """Set up this host process for multi-host SPMD.
 

@@ -102,10 +102,11 @@ def build_mesh(
 ) -> Mesh:
     """Build a `jax.sharding.Mesh` from a MeshSpec.
 
-    Uses `mesh_utils.create_device_mesh` when possible so the physical
-    ICI topology (2D/3D torus) lines up with the logical axes — the
-    difference between collectives at full ICI bandwidth and collectives
-    that hop. Falls back to a plain reshape for host/CPU device sets.
+    On TPU `mesh_utils.create_device_mesh` lines the physical ICI
+    topology (2D/3D torus) up with the logical axes — the difference
+    between collectives at full ICI bandwidth and collectives that hop —
+    and its failure is an error. Host/CPU device sets have no topology
+    and are reshaped in order.
     """
     devices = list(devices if devices is not None else jax.devices())
     sizes = spec.sizes()
@@ -115,14 +116,11 @@ def build_mesh(
             devices = devices[:need]
     spec = spec.resolve(len(devices))
     shape = tuple(spec.sizes()[a] for a in AXIS_ORDER)
-    try:
+    if len(devices) > 1 and devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
-        if len(devices) > 1 and devices[0].platform == "tpu":
-            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        else:
-            dev_array = np.asarray(devices).reshape(shape)
-    except Exception:
+        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 

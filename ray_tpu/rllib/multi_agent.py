@@ -106,9 +106,6 @@ class MultiAgentEnvRunner:
 
     def __init__(self, env_creator_bytes: bytes, mapping_bytes: bytes,
                  hidden, seed: int):
-        import os
-
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         from ray_tpu._private.serialization import loads_function
 
         self.env: MultiAgentEnv = loads_function(env_creator_bytes)()

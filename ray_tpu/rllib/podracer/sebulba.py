@@ -253,7 +253,6 @@ class _PodLearnerImpl:
     def __init__(self, cfg_dict: Dict[str, Any], obs_dim: int,
                  num_actions: int, rank: int = 0, world: int = 1,
                  group_name: str = "", checkpoint_dir: str = ""):
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         cfg_dict = dict(cfg_dict)
         cfg_dict["hidden"] = tuple(cfg_dict["hidden"])
         # every learner rank starts from the SAME cfg.seed params —

@@ -79,9 +79,6 @@ class PPOConfig(AlgorithmConfigBase):
 @ray_tpu.remote
 class EnvRunner:
     def __init__(self, env_spec, hidden, seed: int):
-        import os
-
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")  # rollouts stay on CPU
         self.env: Env = make_env(env_spec)
         self.hidden = hidden
         self.n_hidden = len(hidden)

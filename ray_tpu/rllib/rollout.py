@@ -73,9 +73,6 @@ class SampleRunner:
 
     def __init__(self, env_spec, hidden: Tuple[int, ...], seed: int,
                  mode: str = "categorical", net_key: str = "pi"):
-        import os
-
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         self.env: Env = make_env(env_spec)
         self.n_hidden = len(hidden)
         self.mode = mode

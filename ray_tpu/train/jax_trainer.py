@@ -24,7 +24,9 @@ import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
-from ray_tpu.parallel.bootstrap import HostGroupSpec, initialize_host
+from ray_tpu.parallel.bootstrap import (
+    HostGroupSpec, configure_compilation_cache, initialize_host,
+)
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import FailureConfig, Result, RunConfig, ScalingConfig
 from ray_tpu.train.session import TrainContext, _set_session
@@ -44,6 +46,7 @@ def _run_worker_loop(
     ordered report stream + error info."""
     if host_spec:
         initialize_host(HostGroupSpec(**host_spec))
+    configure_compilation_cache()
     ctx = TrainContext(
         world_rank=world_rank,
         world_size=world_size,

@@ -21,17 +21,13 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8 moved shard_map to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from ray_tpu.models.transformer import (
     TransformerConfig, forward, init_params, loss_fn, param_axes, trainable_mask,
 )
-from ray_tpu.ops.attention import gqa_expand
+from ray_tpu.ops.attention import flash_attention, gqa_expand
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.mesh import mesh_axis_size
 from ray_tpu.parallel.sharding import (
@@ -62,19 +58,58 @@ def default_optimizer(cfg: TransformerConfig, lr: float = 3e-4,
     return tx
 
 
+def _context_mesh(mesh: Mesh):
+    """The mesh a nested shard_map must be handed: inside another
+    (partial-manual) shard_map — e.g. the pipeline's "stage" region — it
+    is the context's abstract mesh, whose axis_types already mark the
+    outer manual axes."""
+    ctx_mesh = jax.sharding.get_abstract_mesh()
+    return mesh if ctx_mesh is None or ctx_mesh.empty else ctx_mesh
+
+
 def make_attn_fn(cfg: TransformerConfig, mesh: Mesh,
                  rules: Optional[Rules] = None) -> Optional[Callable]:
-    """Ring attention under shard_map when the sequence axis is sharded;
-    None (→ flash/blockwise under pure GSPMD) otherwise.
+    """Attention for a mesh: None on one device (→ the model's default,
+    flash_attention called directly); ring attention under shard_map when
+    the sequence axis is sharded; otherwise flash_attention under a
+    shard_map over the axes that shard batch and heads.
 
-    Partial-manual over ONLY the "sequence" axis: batch/head axes stay
-    GSPMD-automatic, which both keeps TP/DP partitioning on the einsums
-    around attention and lets this region nest inside the pipeline's
-    "stage"-manual shard_map (PP × SP composition — disjoint manual axis
-    sets nest cleanly)."""
+    The Pallas kernels cannot be partitioned by GSPMD (Mosaic refuses
+    anything but a fully manual region), so on a mesh of more than one
+    device the dense path makes every axis manual: batch and heads are
+    split by the rule table, the other axes see replicated operands.
+    Nested inside the pipeline's "stage"-manual region it takes the
+    remaining axes. That is right in numbers (naming "stage" again
+    corrupts the gradients) and runs blockwise off-TPU, but on a TPU
+    Mosaic still refuses it: JAX 0.9.0 checks the kernel against this
+    region's axes alone. Pipeline × kernels therefore fails with the
+    compiler's error on real chips; it is not made to pass by running
+    blockwise there.
+
+    The ring region is partial-manual over ONLY the "sequence" axis:
+    batch/head axes stay GSPMD-automatic, which both keeps TP/DP
+    partitioning on the einsums around attention and lets this region
+    nest inside the pipeline's shard_map (PP × SP composition — disjoint
+    manual axis sets nest cleanly). It does not use the kernels."""
     rules = rules or DEFAULT_RULES
-    if mesh_axis_size(mesh, "sequence") <= 1:
+    if mesh.size <= 1:
         return None
+    if mesh_axis_size(mesh, "sequence") <= 1:
+        qkv_spec = spec_for(("batch", None, "heads", None), rules, mesh)
+
+        def dense(q, k, v):
+            k, v = gqa_expand(k, v, q.shape[2])
+            use_mesh = _context_mesh(mesh)
+            free = set(use_mesh.axis_names) - set(use_mesh.manual_axes)
+            return _shard_map(
+                lambda q, k, v: flash_attention(q, k, v, causal=True),
+                mesh=use_mesh,
+                in_specs=(qkv_spec, qkv_spec, qkv_spec), out_specs=qkv_spec,
+                axis_names=free,
+                check_vma=False,
+            )(q, k, v)
+
+        return dense
     if mesh_axis_size(mesh, "stage") > 1:
         # PP×SP: the pipeline's shard_map is manual over {stage, sequence}
         # (ops/pipeline.py), so inside it "sequence" is already a bound
@@ -91,14 +126,8 @@ def make_attn_fn(cfg: TransformerConfig, mesh: Mesh,
             k, v = gqa_expand(k, v, q.shape[2])
             return ring_attention(q, k, v, axis_name="sequence", causal=True)
 
-        # When nested inside another (partial-manual) shard_map — e.g. the
-        # pipeline's "stage" region — the inner shard_map must be handed
-        # the context's abstract mesh, whose axis_types already mark the
-        # outer manual axes.
-        ctx_mesh = jax.sharding.get_abstract_mesh()
-        use_mesh = mesh if ctx_mesh is None or ctx_mesh.empty else ctx_mesh
         return _shard_map(
-            inner, mesh=use_mesh,
+            inner, mesh=_context_mesh(mesh),
             in_specs=(seq_spec, seq_spec, seq_spec), out_specs=seq_spec,
             axis_names={"sequence"},
             check_vma=False,
@@ -129,16 +158,17 @@ def state_shardings(cfg: TransformerConfig, optimizer: optax.GradientTransformat
 
     params_shape = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
     opt_shape = jax.eval_shape(optimizer.init, params_shape)
-    try:
-        opt_shard = optax.tree_map_params(
-            optimizer,
-            lambda _, s: s,
-            opt_shape,
-            p_shard,
-            transform_non_params=lambda _: repl,
-        )
-    except Exception:  # fallback: replicate optimizer state
-        opt_shard = jax.tree.map(lambda _: repl, opt_shape)
+    # multi_transform (LoRA) leaves an empty MaskedNode where a label's
+    # transform does not own the leaf: keep it, it holds no array
+    masked = lambda x: isinstance(x, optax.MaskedNode)
+    opt_shard = optax.tree_map_params(
+        optimizer,
+        lambda leaf, s: leaf if masked(leaf) else s,
+        opt_shape,
+        p_shard,
+        transform_non_params=lambda _: repl,
+        is_leaf=masked,
+    )
     return {"params": p_shard, "opt_state": opt_shard,
             "step": repl, "rng": repl}
 
@@ -148,24 +178,28 @@ def batch_sharding(mesh: Mesh, rules: Optional[Rules] = None) -> NamedSharding:
     return named_sharding(mesh, ("batch", "seq"), rules)
 
 
+def fresh_state(cfg: TransformerConfig, optimizer: optax.GradientTransformation,
+                key: jax.Array, seed: int = 0) -> TrainState:
+    """The train state at step 0 (``jax.eval_shape`` of this gives its
+    shapes without materializing anything)."""
+    params = init_params(cfg, key)
+    return {
+        "params": params,
+        "opt_state": optimizer.init(params),
+        "step": jnp.zeros((), jnp.int32),
+        "rng": jax.random.key_data(jax.random.key(seed)),
+    }
+
+
 def init_state(cfg: TransformerConfig, optimizer: optax.GradientTransformation,
                mesh: Mesh, rules: Optional[Rules] = None,
                seed: int = 0) -> TrainState:
     """Initialize the train state directly sharded (no host-side full
     materialization — params of a 7B model never exist unsharded)."""
     shardings = state_shardings(cfg, optimizer, mesh, rules)
-
-    def _init(key):
-        params = init_params(cfg, key)
-        return {
-            "params": params,
-            "opt_state": optimizer.init(params),
-            "step": jnp.zeros((), jnp.int32),
-            "rng": jax.random.key_data(jax.random.key(seed)),
-        }
-
+    init = functools.partial(fresh_state, cfg, optimizer, seed=seed)
     with jax.set_mesh(mesh):
-        return jax.jit(_init, out_shardings=shardings)(jax.random.key(seed))
+        return jax.jit(init, out_shardings=shardings)(jax.random.key(seed))
 
 
 def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformation,
@@ -210,12 +244,21 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
         jit_kwargs["donate_argnums"] = (0,)
     jitted = jax.jit(step, **jit_kwargs)
 
-    def run(state, batch):
-        batch = {k: jax.device_put(v, b_shard if v.ndim >= 2 else repl)
-                 for k, v in batch.items()}
-        with jax.set_mesh(mesh):
-            return jitted(state, batch)
+    def place(batch):
+        return {k: jax.device_put(v, b_shard if v.ndim >= 2 else repl)
+                for k, v in batch.items()}
 
+    def run(state, batch):
+        with jax.set_mesh(mesh):
+            return jitted(state, place(batch))
+
+    def lower(state, batch):
+        """The step lowered for these arguments: ``.as_text()`` shows
+        whether the kernels are in it, ``.compile()`` what it costs."""
+        with jax.set_mesh(mesh):
+            return jitted.lower(state, place(batch))
+
+    run.lower = lower
     run._jitted = jitted
     run._shardings = shardings
     run._batch_sharding = b_shard

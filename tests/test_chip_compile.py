@@ -1,0 +1,199 @@
+"""What only the chip's compiler can say, with no chip attached.
+
+The TPU compiler is installed here and compiles for a chip that is
+described (``v5e:2x2``) and not attached: it refuses what the chip would
+refuse — a kernel whose blocks do not fit the tiling or the fast memory, a
+program that does not fit HBM, a Mosaic kernel left to GSPMD to partition.
+Nothing runs, so these tests say nothing of results or time; the numeric
+test at the end runs the same kernels in interpret mode against
+``mha_reference``.
+
+``ops.attention._on_tpu`` asks JAX for its default backend and sees the CPU
+during such a compile, so the tests steer it here, not through an option of
+the program.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import transformer as T
+from ray_tpu.ops import attention as A
+from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+from ray_tpu.train import step as S
+
+# the flagship cell (chip_smoke.py WIDTHS); depth is cut, the layer stack
+# is one scanned body whatever its length
+WIDTHS = dict(hidden=2048, mlp_hidden=5632, layers=2, heads=16, kv_heads=16,
+              max_seq=2048, param_dtype=jnp.bfloat16)
+BATCH, SEQ, SLOTS = 8, 2048, 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _for_the_described_chip():
+    """Kernels on, persistent compilation cache off: an executable compiled
+    for a described chip is written to the cache but cannot be read back
+    without one, and the next compile would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(A, "_on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+def _on(sharding, tree):
+    """Shapes placed on described devices (nothing can be device_put there)."""
+    if not isinstance(sharding, (dict, list, tuple)):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+    return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sh), tree, sharding)
+
+
+def _qkv(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    return (jax.ShapeDtypeStruct((BATCH, SEQ, 16, 128), jnp.bfloat16,
+                                 sharding=one),) * 3
+
+
+def test_flash_forward_compiles(topo):
+    compiled = jax.jit(lambda q, k, v: A._flash_fwd_pallas(
+        q, k, v, True, None, 256, 512)).lower(*_qkv(topo)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_flash_backward_compiles(topo):
+    def loss(q, k, v):
+        return A.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(topo)).compile()
+    # forward (for its residuals), dQ pass, dK/dV pass
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def _compile_lora_step(mesh_spec, devices):
+    cfg = T.config("llama2_7b_lora", **WIDTHS)
+    mesh = build_mesh(mesh_spec, devices)
+    opt = S.default_optimizer(cfg)
+    step = S.make_train_step(cfg, opt, mesh)
+    state = _on(step._shardings, jax.eval_shape(
+        lambda: S.fresh_state(cfg, opt, jax.random.key(0))))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (BATCH, SEQ), jnp.int32, sharding=step._batch_sharding)}
+    with jax.set_mesh(mesh):
+        return step._jitted.lower(state, batch).compile(), state
+
+
+def _arg_bytes(state):
+    return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(state))
+
+
+def test_lora_step_compiles_for_one_chip(topo):
+    compiled, state = _compile_lora_step(MeshSpec(), topo.devices[:1])
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= _arg_bytes(state)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_lora_step_compiles_for_four_chips(topo):
+    """The README's headline — one GSPMD program, attention on the Pallas
+    kernels — on a real 2x2: refused before PR 21 ("Mosaic kernels cannot
+    be automatically partitioned"), because the dense path called the
+    kernel outside shard_map. Also the first time create_device_mesh meets
+    the 7-axis shape on a described 2x2."""
+    compiled, state = _compile_lora_step(MeshSpec(fsdp=2, tensor=2),
+                                         list(topo.devices))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text and "all-reduce" in text
+    # every large leaf is sharded over fsdp x tensor: a device holds about
+    # a quarter of the state (norms and the step counter are replicated)
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert 0.25 <= per_device / _arg_bytes(state) < 0.27
+
+
+def test_prefill_and_decode_compile(topo):
+    from ray_tpu.models.continuous_batching import ContinuousBatcher
+    from ray_tpu.models.decoding import init_cache
+
+    cfg = T.config("llama2_7b", **WIDTHS)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0))))
+    batcher = ContinuousBatcher.__new__(ContinuousBatcher)  # programs only
+    batcher.cfg, batcher.max_len, batcher.slots = cfg, SEQ, SLOTS
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    prefill = jax.jit(batcher._prefill_impl).lower(
+        params, arr((1, SEQ), jnp.int32), arr((1,), jnp.int32)).compile()
+    cache = _on(one, jax.eval_shape(lambda: init_cache(cfg, SLOTS, SEQ)))
+    decode = jax.jit(batcher._decode_impl).lower(
+        params, arr((SLOTS,), jnp.int32), cache,
+        _on(one, jax.eval_shape(lambda: jax.random.key(0))),
+        arr((SLOTS,), jnp.float32), arr((SLOTS,), jnp.int32),
+        arr((SLOTS,), jnp.bool_)).compile()
+    # _decode_jit does not donate the cache: it is in HBM twice
+    mem = decode.memory_analysis()
+    assert mem.output_size_in_bytes >= _arg_bytes(cache) - 64
+    for program in (prefill, decode):
+        m = program.memory_analysis()
+        assert (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes) < 16e9
+
+
+@pytest.mark.parametrize("causal,sq,sk", [
+    (True, 128, 128),    # block-aligned
+    (False, 128, 128),
+    (True, 100, 100),    # padded to the block
+    (False, 72, 136),    # cross-attention shape, both padded
+])
+def test_pallas_kernels_match_reference_in_interpret_mode(
+        monkeypatch, causal, sq, sk):
+    """Forward, dQ and dK/dV kernels against mha_reference and its
+    jax.grad, through the Pallas interpreter on the CPU."""
+    import jax.experimental.pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    ks = jax.random.split(jax.random.key(0), 4)
+    q = jax.random.normal(ks[0], (1, sq, 2, 32), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, 2, 32), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, 2, 32), jnp.float32)
+    g = jax.random.normal(ks[3], (1, sq, 2, 32), jnp.float32)
+
+    flash = functools.partial(A.flash_attention, causal=causal,
+                              block_q=64, block_k=64)
+    ref = functools.partial(A.mha_reference, causal=causal)
+    out, vjp = jax.vjp(flash, q, k, v)
+    want_out, want_vjp = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    for a, w in zip(vjp(g), want_vjp(g)):
+        np.testing.assert_allclose(a, w, atol=5e-5)

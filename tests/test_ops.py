@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ray_tpu.ops import blockwise_attention, flash_attention, gqa_expand, mha_reference
 from ray_tpu.ops.ring_attention import ring_attention
