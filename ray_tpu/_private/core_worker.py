@@ -22,6 +22,7 @@ from __future__ import annotations
 import asyncio
 import functools
 import logging
+import queue
 import threading
 import time
 import uuid
@@ -587,6 +588,54 @@ class _ActorStateHub:
                     ev.set()
 
 
+class _ReleaseWorker:
+    """One daemon thread that runs release work in the order it was handed
+    over. The hand-over is a ``SimpleQueue.put``, which is reentrant: the
+    callers are ``ObjectRef.__del__`` paths, and the collector may run a
+    ``__del__`` at any allocation, also inside
+    ``ThreadPoolExecutor.submit`` (asyncio's ``run_in_executor``, a gRPC
+    server), which holds the lock every pool of the process shares. A
+    pool's ``submit`` from that stack blocks the thread on itself, and
+    from then on every ``submit`` in the process (seen: an HTTP proxy's
+    loop thread; the test run never ended)."""
+
+    def __init__(self) -> None:
+        self._queue: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._stopped = False
+        self._thread = threading.Thread(
+            target=self._run, daemon=True, name="borrow-release")
+        self._thread.start()
+
+    def submit(self, fn, *args) -> None:
+        """After ``stop`` the work is dropped: the core worker is shut
+        down by then (server stopped, clients closing), a release RPC has
+        no one to speak for, and owners drop a dead borrower in their own
+        liveness sweep."""
+        if self._stopped:
+            logger.debug("release work after shutdown dropped: %r", fn)
+            return
+        self._queue.put((fn, args))
+
+    def _run(self) -> None:
+        while True:
+            item = self._queue.get()
+            if item is None:
+                return
+            fn, args = item
+            try:
+                fn(*args)
+            except Exception:  # noqa: BLE001 — release is best effort
+                logger.debug("release work failed", exc_info=True)
+
+    def stop(self, timeout: float = 1.0) -> None:
+        """The thread runs what was handed over before this, then ends;
+        waits for that at most ``timeout`` seconds (a release RPC to a
+        dead owner must not hold ``CoreWorker.shutdown``)."""
+        self._stopped = True
+        self._queue.put(None)
+        self._thread.join(timeout)
+
+
 class CoreWorker(CoreRuntime):
     def __init__(
         self,
@@ -719,9 +768,7 @@ class CoreWorker(CoreRuntime):
         self._handoff_borrows: Dict[ObjectID, List[Tuple[ObjectID, Tuple[str, int]]]] = {}
         self._borrow_lock = debug_locks.maybe_wrap(
             threading.Lock(), "core_worker.CoreWorker._borrow_lock")
-        from concurrent.futures import ThreadPoolExecutor as _TPE
-
-        self._borrow_release_pool = _TPE(max_workers=1, thread_name_prefix="borrow-release")
+        self._borrow_release_pool = _ReleaseWorker()
         w = worker_mod.global_worker
         if w is not None:
             w.reference_counter.set_borrow_release_callback(self._on_borrow_released)
@@ -3156,6 +3203,7 @@ class CoreWorker(CoreRuntime):
             for d in self._actor_dispatchers.values():
                 d.stop()
         self.server.stop()
+        self._borrow_release_pool.stop()
         try:
             self.plasma.close()
         except Exception:

@@ -8,7 +8,10 @@ equivalent wraps jax.profiler/xprof traces.
 
 from __future__ import annotations
 
+import glob
 import json
+import os
+import threading
 from typing import Any, Dict, List, Optional
 
 
@@ -62,26 +65,96 @@ def timeline(filename: Optional[str] = None) -> Optional[List[Dict[str, Any]]]:
 # ---------------------------------------------------------------------------
 # TPU device profiling (jax.profiler / xprof)
 # ---------------------------------------------------------------------------
-_trace_active = False
+class ProfileError(RuntimeError):
+    """A profile ended and wrote no trace."""
+
+
+class _Profile:
+    """The one profiler session a process may have open (JAX allows one).
+    Only the process that holds the chip can trace it: this is the hook it
+    exposes (``ray_tpu.tpu_profile`` in a train loop, ``profile_start`` /
+    ``profile_stop`` on a Serve replica)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._logdir: Optional[str] = None
+        self._before: set = set()
+
+    @staticmethod
+    def _xplanes(logdir: str) -> set:
+        return set(glob.glob(os.path.join(
+            logdir, "plugins", "profile", "*", "*.xplane.pb")))
+
+    def start(self, logdir: str) -> None:
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        # the Python tracer hooks every call of every thread: it slows the
+        # host loop a trace is taken to observe, and grows without bound
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2  # device_span()s and XLA's host events
+        options.raise_error_on_start_failure = True
+        with self._lock:
+            if self._logdir is not None:
+                raise RuntimeError(
+                    f"a profile into {self._logdir} is already running")
+            os.makedirs(logdir, exist_ok=True)
+            before = self._xplanes(logdir)
+            # a start that raises has set nothing here, and JAX keeps no
+            # session it failed to create: the next start finds none
+            jax.profiler.start_trace(logdir, profiler_options=options)
+            self._logdir, self._before = logdir, before
+
+    def stop(self) -> str:
+        """End the session and return the ``.xplane.pb`` it wrote. State is
+        cleared whatever happens: a stop that raised is not stopped again,
+        and the next start succeeds."""
+        import jax
+
+        with self._lock:
+            logdir, self._logdir = self._logdir, None
+            if logdir is None:
+                return ""
+            try:
+                jax.profiler.stop_trace()
+            except BaseException:
+                # JAX forgets its session only after a successful export
+                self._drop_jax_session()
+                raise
+            written = self._xplanes(logdir) - self._before
+        if not written:
+            # seen on the chip (PERF.md, PR 22): a session that wrote
+            # nothing and raised nothing. The window is the caller's to
+            # take again; a silent empty directory is not an answer.
+            raise ProfileError(f"the profile wrote no .xplane.pb under "
+                               f"{logdir}")
+        return max(written, key=os.path.getmtime)
+
+    @staticmethod
+    def _drop_jax_session() -> None:
+        from jax._src import profiler as jax_profiler
+
+        state = getattr(jax_profiler, "_profile_state", None)
+        if state is not None:
+            with state.lock:
+                state.reset()
+
+
+_profile = _Profile()
 
 
 def start_tpu_profile(logdir: str) -> None:
-    """Start a jax.profiler trace (view in XProf/TensorBoard). The TPU
-    analogue of the reference's GPU profiler runtime-env plugins."""
-    global _trace_active
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    _trace_active = True
+    """Start a jax.profiler trace of this process (view in XProf /
+    TensorBoard, or read with ``jax.profiler.ProfileData``): device
+    operations, XLA's host events and the program's ``device_span``s, no
+    Python tracer. Raises if the profiler cannot start."""
+    _profile.start(logdir)
 
 
-def stop_tpu_profile() -> None:
-    global _trace_active
-    import jax
-
-    if _trace_active:
-        jax.profiler.stop_trace()
-        _trace_active = False
+def stop_tpu_profile() -> str:
+    """Stop the trace; returns the path of the ``.xplane.pb`` written
+    ("" if none was running). Raises ProfileError if nothing was written."""
+    return _profile.stop()
 
 
 class tpu_profile:

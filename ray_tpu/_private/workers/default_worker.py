@@ -36,6 +36,7 @@ from ray_tpu._private.serialization import deserialize, loads_function, serializ
 from ray_tpu.exceptions import RayActorError, RayTaskError
 from ray_tpu.observability import dump as obs_dump
 from ray_tpu.observability import events as obs_events
+from ray_tpu.observability import schema as obs_schema
 from ray_tpu.observability import timeline as obs_timeline
 from ray_tpu.observability import tracing as obs_tracing
 
@@ -516,29 +517,31 @@ def _execute_streaming(
             from ray_tpu._private.serialization import serialize_prepare
 
             for value in fn(*args, **kwargs):
-                sv = serialize_prepare(value)
-                try:
-                    if sv.total <= config.object_store_inline_max_bytes:
-                        rep = client.call(
-                            "StreamingYield", task_id_bin=task_id.binary(),
-                            index=idx, kind="inline",
-                            data=sv.to_bytes(copy_path="inline"),
-                            timeout=60,
-                        )
-                    else:
-                        oid = ObjectID.from_index(task_id, idx + 1)
-                        w.core._plasma_put_segments(oid, sv)
-                        if obs_tracing.active():
-                            obs_events.record_event(
-                                "object_put", size=sv.total,
-                                job_id=w.core.job_id.hex(), inline=False)
-                        rep = client.call(
-                            "StreamingYield", task_id_bin=task_id.binary(),
-                            index=idx, kind="plasma", node_id=w.core.node_id,
-                            timeout=60,
-                        )
-                finally:
-                    sv.release()
+                # one span per streamed item (a Serve replica's every token)
+                with obs_tracing.device_span(obs_schema.WORKER_STREAM_YIELD):
+                    sv = serialize_prepare(value)
+                    try:
+                        if sv.total <= config.object_store_inline_max_bytes:
+                            rep = client.call(
+                                "StreamingYield", task_id_bin=task_id.binary(),
+                                index=idx, kind="inline",
+                                data=sv.to_bytes(copy_path="inline"),
+                                timeout=60,
+                            )
+                        else:
+                            oid = ObjectID.from_index(task_id, idx + 1)
+                            w.core._plasma_put_segments(oid, sv)
+                            if obs_tracing.active():
+                                obs_events.record_event(
+                                    "object_put", size=sv.total,
+                                    job_id=w.core.job_id.hex(), inline=False)
+                            rep = client.call(
+                                "StreamingYield", task_id_bin=task_id.binary(),
+                                index=idx, kind="plasma", node_id=w.core.node_id,
+                                timeout=60,
+                            )
+                    finally:
+                        sv.release()
                 if not (rep or {}).get("ok", True):
                     break  # consumer abandoned the stream — stop producing
                 idx += 1

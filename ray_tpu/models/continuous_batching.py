@@ -47,6 +47,8 @@ from ray_tpu.models.decoding import (
     init_cache,
 )
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.observability import schema as spans
+from ray_tpu.observability.tracing import device_span
 from ray_tpu.ops.attention import NEG_INF
 
 
@@ -77,7 +79,7 @@ class _Request:
     stream_q: Optional[queue.Queue]  # token stream, None-terminated
     out: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1
-    admitted_step: int = -1
+    t_submit: float = dataclasses.field(default_factory=time.monotonic)
 
 
 class ContinuousBatcher:
@@ -243,40 +245,50 @@ class ContinuousBatcher:
 
     def _admit_one(self, req: _Request, slot: int) -> None:
         bucket = min(self._bucket(len(req.tokens)), self.max_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, : len(req.tokens)] = req.tokens
-        pf = self._prefill_jits.get(bucket)
-        if pf is None:
-            pf = jax.jit(self._prefill_impl)
-            self._prefill_jits[bucket] = pf
-        last_logits, row_k, row_v = pf(
-            self.params, jnp.asarray(toks),
-            jnp.asarray([len(req.tokens)], np.int32))
-        # pad the row out to max_len before install
-        pad = self.max_len - row_k.shape[1]
-        if pad > 0:
-            zeros = jnp.zeros(
-                row_k.shape[:1] + (pad,) + row_k.shape[2:],
-                row_k.dtype)
-            row_k = jnp.concatenate([row_k, zeros], axis=1)
-            row_v = jnp.concatenate([row_v, zeros], axis=1)
-        self.cache = self._install_jit(
-            self.cache, row_k, row_v, slot, len(req.tokens))
-        self._rng, k = jax.random.split(self._rng)
-        first = _sample_per_slot(
-            last_logits[None], k,
-            jnp.asarray([req.sampling.temperature], np.float32),
-            jnp.asarray([req.sampling.top_k], np.int32))
+        with device_span(
+                spans.ENGINE_ADMIT, bucket=bucket,
+                prompt_len=len(req.tokens),
+                queued_ms=(time.monotonic() - req.t_submit) * 1e3):
+            self._prefill_into(req, slot, bucket)
+
+    def _prefill_into(self, req: _Request, slot: int, bucket: int) -> None:
+        with device_span(spans.ENGINE_PREFILL_DISPATCH):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, : len(req.tokens)] = req.tokens
+            pf = self._prefill_jits.get(bucket)
+            if pf is None:
+                pf = jax.jit(self._prefill_impl)
+                self._prefill_jits[bucket] = pf
+            last_logits, row_k, row_v = pf(
+                self.params, jnp.asarray(toks),
+                jnp.asarray([len(req.tokens)], np.int32))
+        with device_span(spans.ENGINE_INSTALL_DISPATCH):
+            # pad the row out to max_len before install
+            pad = self.max_len - row_k.shape[1]
+            if pad > 0:
+                zeros = jnp.zeros(
+                    row_k.shape[:1] + (pad,) + row_k.shape[2:],
+                    row_k.dtype)
+                row_k = jnp.concatenate([row_k, zeros], axis=1)
+                row_v = jnp.concatenate([row_v, zeros], axis=1)
+            self.cache = self._install_jit(
+                self.cache, row_k, row_v, slot, len(req.tokens))
+        with device_span(spans.ENGINE_FIRST_TOKEN_SYNC):
+            self._rng, k = jax.random.split(self._rng)
+            first = _sample_per_slot(
+                last_logits[None], k,
+                jnp.asarray([req.sampling.temperature], np.float32),
+                jnp.asarray([req.sampling.top_k], np.int32))
+            first_tok = int(np.asarray(first)[0])
         req.slot = slot
-        req.admitted_step = self.stats["steps"]
         self.stats["last_admit_step"] = self.stats["steps"]
         self._temps[slot] = req.sampling.temperature
         self._topks[slot] = req.sampling.top_k
         self._host_len[slot] = len(req.tokens)
-        self._last_tok[slot] = int(np.asarray(first)[0])
+        self._last_tok[slot] = first_tok
         self._active[slot] = req
         self.stats["admitted"] += 1
-        self._emit(req, self._last_tok[slot])
+        self._emit(req, first_tok)
 
     def _emit(self, req: _Request, tok: int) -> None:
         """Deliver one sampled token; free the slot when the request is
@@ -315,11 +327,15 @@ class ContinuousBatcher:
     def _pump(self) -> None:
         while not self._shutdown:
             if not self._active and self._waiting.empty():
-                self._wake.wait(timeout=0.1)
+                with device_span(spans.ENGINE_IDLE):
+                    self._wake.wait(timeout=0.1)
                 self._wake.clear()
                 continue
             try:
-                self._step()
+                # around the call, not inside it: the step's device arrays
+                # are released when its frame goes, and that is step time
+                with device_span(spans.ENGINE_STEP, step=self.stats["steps"]):
+                    self._step()
             except Exception as e:  # noqa: BLE001 — fail active requests
                 for req in list(self._active.values()):
                     self._fail(req, e)
@@ -348,17 +364,21 @@ class ContinuousBatcher:
         self._admit()
         if not self._active:
             return
-        active_mask = np.zeros(self.slots, bool)
-        for slot in self._active:
-            active_mask[slot] = True
-        self._rng, k = jax.random.split(self._rng)
-        toks, self.cache = self._decode_jit(
-            self.params, jnp.asarray(self._last_tok), self.cache, k,
-            jnp.asarray(self._temps), jnp.asarray(self._topks),
-            jnp.asarray(active_mask))
+        with device_span(spans.ENGINE_DECODE_DISPATCH,
+                         active=len(self._active)):
+            active_mask = np.zeros(self.slots, bool)
+            for slot in self._active:
+                active_mask[slot] = True
+            self._rng, k = jax.random.split(self._rng)
+            toks, self.cache = self._decode_jit(
+                self.params, jnp.asarray(self._last_tok), self.cache, k,
+                jnp.asarray(self._temps), jnp.asarray(self._topks),
+                jnp.asarray(active_mask))
         self.stats["steps"] += 1
-        toks_np = np.asarray(toks)
-        for slot, req in list(self._active.items()):
-            self._host_len[slot] += 1
-            self._last_tok[slot] = int(toks_np[slot])
-            self._emit(req, int(toks_np[slot]))
+        with device_span(spans.ENGINE_SAMPLE_SYNC):
+            toks_np = np.asarray(toks)
+        with device_span(spans.ENGINE_EMIT):
+            for slot, req in list(self._active.items()):
+                self._host_len[slot] += 1
+                self._last_tok[slot] = int(toks_np[slot])
+                self._emit(req, int(toks_np[slot]))

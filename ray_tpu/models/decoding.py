@@ -51,6 +51,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     )
 
 
+@jax.named_scope("attend_cached")
 def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
     """q [B,S,H,D] against the full cache [B,max_len,kvH,D].
 
@@ -110,12 +111,13 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
     x = x + attn
 
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
-    gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
-    up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
-    if lora is not None:
-        gate = gate + _lora_delta(y, lora["wi_a"], lora["wi_b"], scale)
-    act = jax.nn.silu(gate) * up
-    out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
+    with jax.named_scope("mlp"):
+        gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
+        up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
+        if lora is not None:
+            gate = gate + _lora_delta(y, lora["wi_a"], lora["wi_b"], scale)
+        act = jax.nn.silu(gate) * up
+        out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
     return x + out, k_cache, v_cache
 
 

@@ -247,6 +247,7 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
+@jax.named_scope("lora")
 def _lora_delta(x, a, b, scale):
     return jnp.einsum("bsh,hr->bsr", x, a.astype(x.dtype)) @ b.astype(x.dtype) * scale
 
@@ -321,13 +322,14 @@ def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
     if cfg.num_experts:
         out = _moe_mlp(cfg, y, p)
     else:
-        gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
-        up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
-        if lora_params is not None:
-            gate = gate + _lora_delta(y, lora_params["wi_a"], lora_params["wi_b"], scale)
-        act = jax.nn.silu(gate) * up
-        act = constrain(act, ("batch", "seq", "mlp"))
-        out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
+        with jax.named_scope("mlp"):
+            gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
+            up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
+            if lora_params is not None:
+                gate = gate + _lora_delta(y, lora_params["wi_a"], lora_params["wi_b"], scale)
+            act = jax.nn.silu(gate) * up
+            act = constrain(act, ("batch", "seq", "mlp"))
+            out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
     return x + constrain(out, ("batch", "seq", "embed"))
 
 

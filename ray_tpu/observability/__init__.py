@@ -56,7 +56,6 @@ from ray_tpu.observability.tracing import (
     configure,
     current_context,
     enabled,
-    seed_sampler,
     span,
 )
 
@@ -65,7 +64,6 @@ __all__ = [
     "configure",
     "current_context",
     "enabled",
-    "seed_sampler",
     "span",
     "record_event",
     "local_events",

@@ -42,3 +42,35 @@ EVENT_TYPES = {
     # podracer stage accounting (rllib/podracer/obs.py snapshots)
     "podracer_stage": "stages {name: {s, n}}, role",
 }
+
+# Device spans (observability/tracing.py ``device_span``): host spans on
+# the profiler's clock, ``ray_tpu.<layer>.<site>``. Written once here so
+# that sites and trace readers cannot drift. The value strings name the
+# stats an event carries (fixed when the span opens): only what a reader
+# or an operator's question needs, since a stat is built on every pass
+# whether or not a profile is running.
+ENGINE_IDLE = "ray_tpu.engine.idle"
+ENGINE_STEP = "ray_tpu.engine.step"
+ENGINE_ADMIT = "ray_tpu.engine.admit"
+ENGINE_PREFILL_DISPATCH = "ray_tpu.engine.prefill_dispatch"
+ENGINE_INSTALL_DISPATCH = "ray_tpu.engine.install_dispatch"
+ENGINE_FIRST_TOKEN_SYNC = "ray_tpu.engine.first_token_sync"
+ENGINE_DECODE_DISPATCH = "ray_tpu.engine.decode_dispatch"
+ENGINE_SAMPLE_SYNC = "ray_tpu.engine.sample_sync"
+ENGINE_EMIT = "ray_tpu.engine.emit"
+WORKER_STREAM_YIELD = "ray_tpu.worker.stream_yield"
+
+DEVICE_SPANS = {
+    # pump thread of models/continuous_batching.py
+    ENGINE_IDLE: "",
+    ENGINE_STEP: "step",
+    ENGINE_ADMIT: "bucket, prompt_len, queued_ms",
+    ENGINE_PREFILL_DISPATCH: "",
+    ENGINE_INSTALL_DISPATCH: "",
+    ENGINE_FIRST_TOKEN_SYNC: "",
+    ENGINE_DECODE_DISPATCH: "active",
+    ENGINE_SAMPLE_SYNC: "",
+    ENGINE_EMIT: "",
+    # handler threads of _private/workers/default_worker.py
+    WORKER_STREAM_YIELD: "",
+}

@@ -14,6 +14,14 @@ processes need no configuration — an inherited SAMPLED context forces
 span recording there, an unsampled/absent context costs one
 thread-local read. Finished spans are events on the bus
 (``events.py``) and flow to the GCS aggregator.
+
+Two sinks, one API. The bus records host lifecycles on ``time.time()``;
+the device trace (``jax.profiler``) has its own clock. ``device_span()``
+writes a span into the profiler's trace and nowhere else, for hot loops
+in the process that holds the chip; ``span()`` writes a bus event and,
+where JAX is already loaded, the same span into the profiler's trace.
+This is the only module that creates spans of either kind; the names of
+device spans are in ``schema.py``.
 """
 
 from __future__ import annotations
@@ -21,6 +29,7 @@ from __future__ import annotations
 import contextlib
 import os
 import random
+import sys
 import threading
 import time
 import uuid
@@ -38,16 +47,8 @@ _config = {
 
 # Root sampling uses a dedicated Random instance, NOT the process-global
 # random module: a seeded chaos run (PreemptionInjector) must not have
-# its injection schedule perturbed by trace sampling, and the sampling
-# itself becomes reproducible via seed_sampler()/RAY_TPU_TRACE_SEED.
-_sampler = random.Random(
-    int(os.environ["RAY_TPU_TRACE_SEED"])
-    if os.environ.get("RAY_TPU_TRACE_SEED", "").isdigit() else None)
-
-
-def seed_sampler(seed: int) -> None:
-    """Make root-span sampling decisions reproducible (chaos tests)."""
-    _sampler.seed(seed)
+# its injection schedule perturbed by trace sampling.
+_sampler = random.Random()
 
 
 # spans currently open (sampled only): span_id -> start record. Bounded
@@ -137,6 +138,39 @@ def for_outbound() -> Optional[Wire]:
     return None
 
 
+class _NoSpan:
+    """What ``device_span`` gives a process that has not loaded JAX."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+_annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def device_span(name: str, **stats):
+    """A span in the JAX profiler's trace, on the clock of the device's
+    own events: a ``jax.profiler.TraceAnnotation`` and nothing else. No
+    bus event, no switch: with no profiler session entering and leaving
+    one costs about half a microsecond, so hot loops keep theirs
+    unconditionally. ``stats`` come back as the event's stats. A process
+    that has not loaded JAX (the driver, the proxy, a worker without
+    chips) has no device trace to be in: there this is a shared no-op,
+    and JAX is never imported for it."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name, **stats)
+
+
 def _job_id_hex() -> str:
     from ray_tpu._private import worker as worker_mod
 
@@ -178,7 +212,11 @@ def span(name: str, kind: str = "span",
     Roots: created when tracing is enabled here and no span is active;
     subject to the sample rate. Children: inherit trace/job ids from
     the active span regardless of this process's own config (that's
-    what carries a trace across process boundaries)."""
+    what carries a trace across process boundaries).
+
+    A recorded span is also a ``device_span`` of the same name with its
+    ``trace_id`` and ``span_id`` as stats, in a process that has loaded
+    JAX: a replica's handler span then shows in its device trace."""
     parent = getattr(_state, "ctx", None)
     if parent is None:
         if not _config["enabled"]:
@@ -209,7 +247,8 @@ def span(name: str, kind: str = "span",
                                 "kind": kind, "ts": ts,
                                 "parent_span_id": parent_span_id}
     try:
-        yield ctx
+        with device_span(name, trace_id=trace_id, span_id=ctx.span_id):
+            yield ctx
     except BaseException:
         status = "error"
         raise

@@ -274,6 +274,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k):
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         out_shape=(
             jax.ShapeDtypeStruct(qf.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sqp), jnp.float32),
@@ -323,6 +324,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, block_q, block_k):
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_bwd_dq",
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         grid=(b * h, sqp // block_q),
         in_specs=[
@@ -342,6 +344,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, block_q, block_k):
     )
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_bwd_dkv",
         out_shape=(
             jax.ShapeDtypeStruct(kf.shape, k.dtype),
             jax.ShapeDtypeStruct(vf.shape, v.dtype),

@@ -16,6 +16,8 @@ from ray_tpu.serve.multiplex import get_multiplexed_model_id, multiplexed
 from ray_tpu.serve.controller import (
     delete,
     get_app_handle,
+    profile_start,
+    profile_stop,
     run,
     shutdown,
     status,
@@ -55,6 +57,8 @@ __all__ = [
     "grpc_proxy_stats",
     "http_proxy_stats",
     "multiplexed",
+    "profile_start",
+    "profile_stop",
     "request_deadline",
     "run",
     "shutdown",

@@ -14,6 +14,7 @@ here) is what keeps the MXU busy.
 from __future__ import annotations
 
 import math
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -243,6 +244,23 @@ class Replica:
             with self._ongoing_lock:
                 self._streams -= 1
                 self._ongoing -= 1
+
+    def profile_start(self, logdir: str) -> bool:
+        """Start a JAX profiler trace of this replica's process (only the
+        process that holds the chip can trace it). An actor method of the
+        replica, not a request: ``serve.profile_start`` calls it, and no
+        handle or proxy route reaches it."""
+        from ray_tpu._private import profiling
+
+        profiling.start_tpu_profile(logdir)
+        return True
+
+    def profile_stop(self) -> str:
+        """Stop the trace; returns the ``.xplane.pb`` written (a path on
+        the replica's node)."""
+        from ray_tpu._private import profiling
+
+        return profiling.stop_tpu_profile()
 
     def multiplexed_model_ids(self) -> list:
         from ray_tpu.serve.multiplex import replica_multiplexed_model_ids
@@ -652,12 +670,16 @@ def run(app: Application, *, name: Optional[str] = None,
     return DeploymentHandle(cfg.name, ctl, snapshot)
 
 
-def get_app_handle(name: str) -> DeploymentHandle:
-    ctl = _controller()
-    snapshot = ray_tpu.get(ctl.get_deployment.remote(name), timeout=60)
+def _snapshot_of(name: str) -> dict:
+    snapshot = ray_tpu.get(_controller().get_deployment.remote(name),
+                           timeout=60)
     if snapshot is None:
         raise ValueError(f"No deployment named {name!r}")
-    return DeploymentHandle(name, ctl, snapshot)
+    return snapshot
+
+
+def get_app_handle(name: str) -> DeploymentHandle:
+    return DeploymentHandle(name, _controller(), _snapshot_of(name))
 
 
 def delete(name: str) -> None:
@@ -679,6 +701,26 @@ def shutdown() -> None:
     except Exception:
         pass  # controller already dead/killed — shutdown is idempotent
     _state.controller = None
+
+
+def profile_start(name: str, logdir: str) -> int:
+    """Start a JAX profiler trace in every replica of deployment ``name``
+    (replica ``i`` writes under ``<logdir>/replica_<i>`` on its own node);
+    returns how many were started. The operator's way to trace a replica:
+    it calls the replica actors directly, off the request path."""
+    replicas = _snapshot_of(name)["replicas"]
+    ray_tpu.get([
+        r.profile_start.remote(os.path.join(logdir, f"replica_{i}"))
+        for i, r in enumerate(replicas)], timeout=120)
+    return len(replicas)
+
+
+def profile_stop(name: str) -> List[str]:
+    """Stop those traces; the ``.xplane.pb`` each replica wrote, in the
+    order of ``profile_start`` ("" for a replica that had none running)."""
+    return ray_tpu.get([r.profile_stop.remote()
+                        for r in _snapshot_of(name)["replicas"]],
+                       timeout=120)
 
 
 def status() -> Dict[str, Any]:

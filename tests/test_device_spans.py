@@ -1,0 +1,265 @@
+"""Device spans: the serve engine's host loop, the worker's stream loop and
+``observability.tracing.span`` on the clock of the JAX profiler's trace
+(``tracing.device_span``), and the program's profiler hook
+(``_private/profiling.py``) that takes such a trace.
+
+One toy ContinuousBatcher run under the hook is traced once for the module;
+the trace is read back with the benchmark's own reader
+(``benchmarks/program_spans.py``), so the names the program writes and the
+names the benchmark reads are held together here. CPU, no cluster.
+"""
+
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks import program_spans
+from ray_tpu._private import profiling
+from ray_tpu._private.ids import TaskID
+from ray_tpu._private.workers import default_worker
+from ray_tpu.models import transformer as T
+from ray_tpu.models.continuous_batching import ContinuousBatcher
+from ray_tpu.models.decoding import SamplingParams
+from ray_tpu.observability import schema, tracing
+
+SLOTS = 2
+ENGINE_CHILDREN_OF_STEP = (schema.ENGINE_DECODE_DISPATCH,
+                           schema.ENGINE_SAMPLE_SYNC, schema.ENGINE_EMIT)
+ENGINE_CHILDREN_OF_ADMIT = (schema.ENGINE_PREFILL_DISPATCH,
+                            schema.ENGINE_INSTALL_DISPATCH,
+                            schema.ENGINE_FIRST_TOKEN_SYNC)
+
+
+class _AckingClient:
+    """Stands for the caller's end of a stream: acknowledges every item."""
+
+    def __init__(self):
+        self.calls = []
+
+    def call(self, method, **kwargs):
+        self.calls.append((method, kwargs.get("index", kwargs.get("count"))))
+        return {"ok": True, "pending": 0}
+
+
+class _NoWorker:
+    def set_task_context(self, task_id, actor_id):
+        pass
+
+
+def _stream_two_items(client):
+    """The worker's streaming loop itself, with the caller's end faked."""
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(default_worker.worker_mod, "global_worker", _NoWorker())
+        m.setattr(default_worker, "get_client", lambda addr: client)
+        reply = default_worker._execute_streaming(
+            lambda: iter(["7 ", "8 "]), [], {}, TaskID.from_random(),
+            "stream", ("127.0.0.1", 1))
+    assert reply == {"returns": [], "streaming_done": 2}
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """{"parsed": the trace as program_spans reads it, "bridge": the context
+    of a recorded span(), "stream": the fake stream client}."""
+    cfg = T.config("debug", dtype=jnp.float32, param_dtype=jnp.float32)
+    params = T.init_params(cfg, jax.random.key(0))
+    cb = ContinuousBatcher(cfg, params, max_len=64, slots=SLOTS)
+    sp = SamplingParams(max_tokens=6)
+    logdir = str(tmp_path_factory.mktemp("xprof"))
+    client = _AckingClient()
+    try:
+        cb.submit([1, 2, 3], sp).result(timeout=120)  # compile outside
+        profiling.start_tpu_profile(logdir)
+        try:
+            time.sleep(0.25)  # the pump has nothing: engine.idle
+            # more requests than slots, at once: the later ones queue
+            futs = [cb.submit([4 + i, 5, 6], sp) for i in range(3 * SLOTS)]
+            for f in futs:
+                f.result(timeout=120)
+            _stream_two_items(client)
+            tracing.configure(enabled=True, sample_rate=1.0)
+            try:
+                with tracing.span("ray_tpu.test.bridged") as bridge:
+                    pass
+            finally:
+                tracing.configure(enabled=False)
+            with tracing.span("ray_tpu.test.untraced") as untraced:
+                pass
+            assert untraced is None
+        finally:
+            path = profiling.stop_tpu_profile()
+    finally:
+        cb.shutdown()
+    assert path.startswith(logdir) and path.endswith(".xplane.pb")
+    return {"parsed": program_spans.parse(path), "bridge": bridge,
+            "stream": client}
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and \
+        child[1] + child[2] <= parent[1] + parent[2] and child[3] == parent[3]
+
+
+@pytest.mark.parametrize("name", sorted(schema.DEVICE_SPANS))
+def test_every_site_is_in_the_trace_with_its_stats(traced, name):
+    found = program_spans.named(traced["parsed"], name)
+    assert found, f"no {name} in the trace"
+    want = {s.split(" ")[0] for s in schema.DEVICE_SPANS[name].split(", ") if s}
+    for span in found:
+        assert set(span[4]) == want and span[2] >= 0
+
+
+def test_names_are_the_ones_the_benchmark_reads():
+    ours = set(schema.DEVICE_SPANS)
+    read = {v for k, v in vars(program_spans).items()
+            if k.isupper() and isinstance(v, str)
+            and v.count(".") == 2 and not v.endswith(".")}
+    assert read <= ours and program_spans.STEP == schema.ENGINE_STEP
+    assert all(n.startswith(program_spans.PREFIX) for n in ours)
+    assert all(n.startswith(program_spans.ENGINE) for n in ours
+               if n != schema.WORKER_STREAM_YIELD)
+
+
+def test_a_step_holds_its_children_on_the_pump_thread(traced):
+    parsed = traced["parsed"]
+    line = program_spans.pump_line(parsed)
+    steps = program_spans.named(parsed, schema.ENGINE_STEP, line)
+    assert steps == program_spans.named(parsed, schema.ENGINE_STEP)
+    decoding = [s for s in steps if any(
+        _inside(c, s) for c in program_spans.named(
+            parsed, schema.ENGINE_DECODE_DISPATCH))]
+    assert len(decoding) >= 6  # max_tokens
+    for name in ENGINE_CHILDREN_OF_STEP:
+        children = program_spans.named(parsed, name)
+        assert all(any(_inside(c, s) for s in steps) for c in children), name
+        assert len(children) == len(decoding)
+    # numbered as the engine counts them, and every engine span is the pump's
+    numbers = [s[4]["step"] for s in steps]
+    assert numbers == sorted(numbers)
+    assert {s[3] for s in parsed["spans"]
+            if s[0].startswith(program_spans.ENGINE)} == {line}
+    assert program_spans.named(parsed, schema.ENGINE_IDLE, line)
+
+
+def test_an_admit_holds_its_three_children_inside_a_step(traced):
+    parsed = traced["parsed"]
+    admits = program_spans.named(parsed, schema.ENGINE_ADMIT)
+    steps = program_spans.named(parsed, schema.ENGINE_STEP)
+    assert len(admits) == 3 * SLOTS
+    for admit in admits:
+        assert any(_inside(admit, s) for s in steps)
+        for name in ENGINE_CHILDREN_OF_ADMIT:
+            inside = [c for c in program_spans.named(parsed, name)
+                      if _inside(c, admit)]
+            assert len(inside) == 1, name
+        assert admit[4]["bucket"] == 16 and admit[4]["prompt_len"] == 3
+
+
+def test_queued_ms_grows_when_the_slots_are_full(traced):
+    admits = program_spans.named(traced["parsed"], schema.ENGINE_ADMIT)
+    waits = [a[4]["queued_ms"] for a in admits]  # in order of admission
+    assert all(w >= 0 for w in waits)
+    # the first SLOTS found a free slot; the last waited for whole requests
+    assert min(waits[-SLOTS:]) > max(waits[:SLOTS])
+    assert program_spans.stat_median(
+        traced["parsed"], schema.ENGINE_ADMIT, "queued_ms") > 0
+
+
+def test_the_readers_numbers_on_this_trace(traced):
+    parsed = traced["parsed"]
+    assert program_spans.step_period_ms(parsed) > 0
+    host = program_spans.host_ms_per_step(parsed)
+    steps = program_spans.named(parsed, schema.ENGINE_STEP)
+    assert 0 < host <= max(s[2] for s in steps) / 1e6
+    assert program_spans.mean_ms(parsed, schema.ENGINE_ADMIT) > 0
+    idle = program_spans.idle_by_span(parsed)
+    assert idle and set(idle) <= {
+        n[len(program_spans.ENGINE):] for n in schema.DEVICE_SPANS} | {
+        "unattributed"}
+    assert 0 <= program_spans.idle_attributed_share(parsed) <= 100
+
+
+def test_one_stream_yield_span_for_each_item(traced):
+    spans = program_spans.named(traced["parsed"], schema.WORKER_STREAM_YIELD)
+    assert len(spans) == 2 and spans[0][1] + spans[0][2] <= spans[1][1]
+    assert traced["stream"].calls == [
+        ("StreamingYield", 0), ("StreamingYield", 1), ("StreamingDone", 2)]
+
+
+def test_span_joins_the_device_trace_only_when_it_records(traced):
+    parsed, ctx = traced["parsed"], traced["bridge"]
+    assert ctx is not None and ctx.sampled
+    bridged = program_spans.named(parsed, "ray_tpu.test.bridged")
+    assert [s[4] for s in bridged] == [
+        {"trace_id": ctx.trace_id, "span_id": ctx.span_id}]
+    # with tracing off span() returns before it opens anything
+    assert program_spans.named(parsed, "ray_tpu.test.untraced") == []
+
+
+def test_a_failed_start_leaves_no_session(tmp_path, monkeypatch):
+    def refuse(*args, **kwargs):
+        raise RuntimeError("the profiler refused to start")
+
+    with monkeypatch.context() as m:
+        m.setattr(jax.profiler, "start_trace", refuse)
+        with pytest.raises(RuntimeError, match="refused"):
+            profiling.start_tpu_profile(str(tmp_path / "a"))
+    assert profiling.stop_tpu_profile() == ""  # nothing to stop
+    with profiling.tpu_profile(str(tmp_path / "b")):
+        (jnp.ones((8, 8)) @ jnp.ones((8, 8))).block_until_ready()
+    assert profiling._Profile._xplanes(str(tmp_path / "b"))
+
+
+def test_a_failed_stop_is_not_left_running(tmp_path, monkeypatch):
+    real_stop = jax.profiler.stop_trace
+
+    def fail():
+        raise RuntimeError("export failed")
+
+    profiling.start_tpu_profile(str(tmp_path / "a"))
+    with pytest.raises(RuntimeError, match="already running"):
+        profiling.start_tpu_profile(str(tmp_path / "a2"))
+    with monkeypatch.context() as m:
+        m.setattr(jax.profiler, "stop_trace", fail)
+        with pytest.raises(RuntimeError, match="export failed"):
+            profiling.stop_tpu_profile()
+    with pytest.raises(RuntimeError, match="No profile started"):
+        real_stop()  # JAX holds no session either
+    profiling.start_tpu_profile(str(tmp_path / "b"))
+    assert profiling.stop_tpu_profile().startswith(str(tmp_path / "b"))
+
+
+def test_a_profile_that_writes_nothing_raises(tmp_path, monkeypatch):
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    profiling.start_tpu_profile(str(tmp_path))
+    with pytest.raises(profiling.ProfileError, match="no .xplane.pb"):
+        profiling.stop_tpu_profile()
+    assert profiling.stop_tpu_profile() == ""
+
+
+def test_the_driver_side_never_imports_jax():
+    """A process that has not loaded JAX (the driver, the proxy) opens spans
+    of both kinds without loading it."""
+    code = (
+        "import sys\n"
+        "import ray_tpu\n"
+        "from ray_tpu.observability import tracing\n"
+        "tracing.configure(enabled=True)\n"
+        "with tracing.span('driver.side') as ctx:\n"
+        "    assert ctx is not None\n"
+        "with tracing.device_span('ray_tpu.engine.step', step=1):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        p for p in (os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__))), os.environ.get("PYTHONPATH")) if p))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -122,16 +122,31 @@ def test_timeline_chrome_trace(cluster, tmp_path):
         assert json.load(f)
 
 
-def test_tpu_profile_context(cluster, tmp_path):
-    """tpu_profile wraps jax.profiler traces (CPU backend in CI)."""
+def test_tpu_profile_context(tmp_path):
+    """tpu_profile wraps jax.profiler traces (CPU backend in CI). In a
+    process of its own: a profiler session is process-wide state, and this
+    process is the driver of the module's cluster — a profile that fails
+    or hangs must fail this test alone (tests/test_device_spans.py holds
+    the hook's failure paths, in process)."""
     import glob
-
-    import jax.numpy as jnp
+    import os
+    import subprocess
+    import sys
 
     logdir = str(tmp_path / "xprof")
-    with ray_tpu.tpu_profile(logdir):
-        (jnp.ones((64, 64)) @ jnp.ones((64, 64))).block_until_ready()
-    assert glob.glob(logdir + "/**/*", recursive=True)
+    code = (
+        "import sys\n"
+        "import jax.numpy as jnp\n"
+        "import ray_tpu\n"
+        "with ray_tpu.tpu_profile(sys.argv[1]):\n"
+        "    (jnp.ones((64, 64)) @ jnp.ones((64, 64))).block_until_ready()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        p for p in (os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__))), os.environ.get("PYTHONPATH")) if p))
+    proc = subprocess.run([sys.executable, "-c", code, logdir], env=env,
+                          capture_output=True, text=True, timeout=180)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert glob.glob(logdir + "/plugins/profile/*/*.xplane.pb")
 
 
 def test_microbenchmark_suite_runs():

@@ -85,3 +85,50 @@ class TestBorrowedRefs:
             return x + 1
 
         assert ray_tpu.get(f.remote(41)) == 42
+
+
+def test_release_work_is_handed_over_without_the_pools_lock():
+    """An ObjectRef can die at any allocation, also inside
+    ``ThreadPoolExecutor.submit`` (``run_in_executor`` on a proxy's loop, a
+    gRPC server), which holds the lock every pool of the process shares. The
+    release path runs from that ``__del__``: handing its work over must not
+    need that lock, or the thread blocks on itself and every pool with it."""
+    import threading
+    from concurrent.futures import thread as pools
+
+    from ray_tpu._private.core_worker import _ReleaseWorker
+
+    worker, ran, done = _ReleaseWorker(), [], threading.Event()
+    try:
+        with pools._global_shutdown_lock:  # where submit builds its thread
+            for i in range(3):
+                worker.submit(ran.append, i)
+            worker.submit(done.set)
+            assert done.wait(5), "release work waited for the pools' lock"
+        assert ran == [0, 1, 2]  # in the order handed over
+        worker.submit(lambda: 1 / 0)  # a failing release ends nothing
+        worker.submit(ran.append, 3)
+    finally:
+        worker.stop(timeout=5)
+    assert ran == [0, 1, 2, 3] and not worker._thread.is_alive()
+
+
+def test_release_after_stop_is_dropped_and_stop_waits_a_bounded_time():
+    """``CoreWorker.shutdown`` stops the release thread and waits a bounded
+    time for a release in flight; an ``ObjectRef.__del__`` that comes later
+    finds the worker stopped: its release is dropped, not queued for ever."""
+    import threading
+    import time
+
+    from ray_tpu._private.core_worker import _ReleaseWorker
+
+    worker, ran, gate = _ReleaseWorker(), [], threading.Event()
+    worker.submit(gate.wait, 10)  # a release RPC that hangs
+    worker.submit(ran.append, "before")
+    t0 = time.monotonic()
+    worker.stop(timeout=0.2)
+    assert 0.15 < time.monotonic() - t0 < 2.0 and worker._thread.is_alive()
+    worker.submit(ran.append, "after")
+    gate.set()
+    worker._thread.join(5)
+    assert ran == ["before"] and not worker._thread.is_alive()

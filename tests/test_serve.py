@@ -2,8 +2,10 @@
 here: deploy/route/handle, replicas, batching, reconfigure, HTTP proxy)."""
 
 import json
+import os
 import threading
 import time
+import urllib.error
 import urllib.request
 
 import numpy as np
@@ -185,6 +187,66 @@ class TestModelServing:
         tokens = np.ones(16, dtype=np.int32)
         out = h.predict.remote(tokens).result(timeout=120)
         assert isinstance(out, int)
+
+
+    def test_replica_profile_hook(self, serve_cluster, tmp_path):
+        """``serve.profile_start`` / ``profile_stop`` trace every replica
+        of a deployment: the trace is of the replica's process, with the
+        handler span of a traced request in it. The hook is off the
+        request path: neither a handle nor the HTTP proxy reaches it."""
+        from ray_tpu import observability as obs
+
+        @serve.deployment(name="matmul", num_replicas=2)
+        class Matmul:
+            def __call__(self, n):
+                import jax.numpy as jnp
+
+                return float((jnp.ones((n, n)) @ jnp.ones((n, n))).sum())
+
+        h = serve.run(Matmul.bind())
+        for _ in range(4):  # loads JAX in both replicas
+            assert h.remote(8).result(timeout=120) == 512.0
+        assert serve.profile_stop("matmul") == ["", ""]
+
+        # the public doors: a request named like the hook starts nothing
+        with pytest.raises(Exception):
+            h.profile_start.remote(str(tmp_path / "door")).result(timeout=60)
+        port = serve.start_http_proxy(port=0)
+        try:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{port}/matmul/profile_start",
+                data=json.dumps(str(tmp_path / "door")).encode())
+            with pytest.raises(urllib.error.HTTPError) as err:
+                urllib.request.urlopen(req, timeout=60)
+            assert err.value.code >= 400
+        finally:
+            serve.stop_http_proxy()
+        assert not (tmp_path / "door").exists()
+        assert serve.profile_stop("matmul") == ["", ""]
+
+        assert serve.profile_start("matmul", str(tmp_path)) == 2
+        obs.configure(enabled=True, sample_rate=1.0)
+        try:
+            with obs.span("profiled") as root:
+                for _ in range(4):
+                    assert h.remote(16).result(timeout=120) == 4096.0
+        finally:
+            obs.configure(enabled=False)
+        paths = serve.profile_stop("matmul")
+        assert [os.path.relpath(p, tmp_path).split(os.sep)[0]
+                for p in paths] == ["replica_0", "replica_1"]
+        assert all(p.endswith(".xplane.pb") for p in paths)
+        assert serve.profile_stop("matmul") == ["", ""]
+
+        from jax.profiler import ProfileData
+
+        trace_ids = {
+            dict(e.stats)["trace_id"] for path in paths
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.endswith("handle_request_with_rejection")}
+        assert trace_ids == {root.trace_id}
 
 
 class TestReplicaSideRejection:
