@@ -57,23 +57,31 @@ def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
 
     kv_len_mask [B, max_len] marks valid cache slots; q_pos [B,S] are the
     global positions of the queries (causal: key position <= q position).
+
+    Attends by KV-head group on the cache's stored dtype and must never
+    repeat or upcast the cache: a decode step reads every cache row of
+    every layer, so any cache-sized temporary sets its time. With
+    `jnp.repeat(cache, rep, axis=2).astype(float32)` the compiler wrote and
+    re-read a float32 [16,2048,8,4,128] array (537 MB) for K and another
+    for V in every layer, 2.6 GB of HBM traffic where the bf16 cache layer
+    is 134 MB: 67% of a 72.5 ms decode step on the v5e (PERF.md, PR 24).
+    The products of two bf16 values are exact in float32, so float32
+    accumulation (`preferred_element_type`) gives the same logits; the
+    probabilities stay float32 and V is upcast inside the fusion.
     """
     b, s, h, d = q.shape
-    kvh = k_cache.shape[2]
-    rep = h // kvh
-    k = jnp.repeat(k_cache, rep, axis=2) if rep > 1 else k_cache
-    v = jnp.repeat(v_cache, rep, axis=2) if rep > 1 else v_cache
-    logits = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / (d ** 0.5)
-    t = k_cache.shape[1]
-    key_pos = jnp.arange(t)[None, :]  # [1, max_len]
-    causal = q_pos[:, None, :, None] >= key_pos[:, None, None, :] \
-        if q_pos.ndim == 2 else None
-    mask = kv_len_mask[:, None, None, :] & causal
+    t, kvh = k_cache.shape[1], k_cache.shape[2]
+    q5 = q.reshape(b, s, kvh, h // kvh, d)  # heads of one KV group adjoin
+    logits = jnp.einsum("bsgrd,btgd->bgrst", q5, k_cache,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+    key_pos = jnp.arange(t)
+    causal = q_pos[:, None, None, :, None] >= key_pos
+    mask = kv_len_mask[:, None, None, None, :] & causal
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhst,bthd->bshd", probs, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    out = jnp.einsum("bgrst,btgd->bsgrd", probs, v_cache,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s, h, d).astype(q.dtype)
 
 
 def _block_cached(cfg: TransformerConfig, x, p, lora, positions,

@@ -14,7 +14,9 @@ the program.
 """
 
 import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -167,6 +169,31 @@ def test_prefill_and_decode_compile(topo):
         m = program.memory_analysis()
         assert (m.argument_size_in_bytes + m.output_size_in_bytes
                 + m.temp_size_in_bytes) < 16e9
+
+
+def test_attend_cached_reads_the_cache_once(topo):
+    """The serve cells' decode shape (Mistral-7B widths: 32 heads over 8 KV
+    heads, 16 slots x 2048 positions), the function alone. Repeating the
+    cache per query head and upcasting it cost a float32 [16,2048,8,4,128]
+    temporary (537 MB) for K and one for V in every layer; the MHA widths
+    above never reach that."""
+    from ray_tpu.models.decoding import _attend_cached
+
+    slots, kv_heads, hd = 16, 8, 128
+    q, cache, q_pos, kv_len_mask = _on(SingleDeviceSharding(topo.devices[0]), (
+        jax.ShapeDtypeStruct((slots, 1, 32, hd), jnp.bfloat16),
+        jax.ShapeDtypeStruct((slots, SEQ, kv_heads, hd), jnp.bfloat16),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+        jax.ShapeDtypeStruct((slots, SEQ), jnp.bool_)))
+    cache_elems = math.prod(cache.shape)
+    compiled = jax.jit(_attend_cached).lower(
+        q, cache, cache, q_pos, kv_len_mask).compile()
+    # under one cache layer's bytes (67 MB)
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < cache_elems * cache.dtype.itemsize)
+    float32_shapes = set(re.findall(r"f32\[([\d,]+)\]", compiled.as_text()))
+    assert not [m for m in float32_shapes
+                if math.prod(map(int, m.split(","))) >= cache_elems]
 
 
 @pytest.mark.parametrize("causal,sq,sk", [

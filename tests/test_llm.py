@@ -11,12 +11,65 @@ import jax.numpy as jnp
 import ray_tpu
 
 from ray_tpu.models import transformer as T
-from ray_tpu.models.decoding import Generator, SamplingParams, init_cache
+from ray_tpu.models.decoding import (
+    Generator, SamplingParams, _attend_cached, init_cache,
+)
+from ray_tpu.ops.attention import NEG_INF
 
 
 def _tiny_cfg():
     # fp32 so the cached and uncached paths argmax identically
     return T.config("debug", dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+def _attend_reference(q, k_cache, v_cache, q_pos, kv_len_mask):
+    """Plain float32 attention over the cache: K/V repeated per query head
+    and upcast whole, which `_attend_cached` itself must never do."""
+    rep = q.shape[2] // k_cache.shape[2]
+    k = jnp.repeat(k_cache, rep, axis=2).astype(jnp.float32)
+    v = jnp.repeat(v_cache, rep, axis=2).astype(jnp.float32)
+    logits = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
+                        k) / q.shape[-1] ** 0.5
+    key_pos = jnp.arange(k.shape[1])
+    mask = (kv_len_mask[:, None, None, :]
+            & (q_pos[:, None, :, None] >= key_pos[None, None, None, :]))
+    probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF), axis=-1)
+    return jnp.einsum("bhst,bthd->bshd", probs, v).astype(q.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("s", [1, 7])
+@pytest.mark.parametrize("rep", [1, 4])
+def test_attend_cached_matches_plain_reference(rep, s, dtype):
+    """Grouped-query attention on the cache's own dtype against the plain
+    reference on the same values; rows 1 and 2 are ragged, row 2 holds a
+    single valid slot."""
+    b, t, kvh, d = 3, 24, 2, 16
+    ks = jax.random.split(jax.random.key(rep * 10 + s), 3)
+    q = jax.random.normal(ks[0], (b, s, kvh * rep, d), jnp.float32)
+    k_cache = jax.random.normal(ks[1], (b, t, kvh, d), jnp.float32)
+    v_cache = jax.random.normal(ks[2], (b, t, kvh, d), jnp.float32)
+    q, k_cache, v_cache = (a.astype(dtype) for a in (q, k_cache, v_cache))
+    lengths = jnp.asarray([t, 9, 1])
+    kv_len_mask = jnp.arange(t)[None, :] < lengths[:, None]
+    # the queries are the newest s positions (all of them at 0 in row 2)
+    q_pos = jnp.maximum(lengths[:, None] - s + jnp.arange(s)[None, :], 0)
+
+    got = jax.jit(_attend_cached)(q, k_cache, v_cache, q_pos, kv_len_mask)
+    want = _attend_reference(q, k_cache, v_cache, q_pos, kv_len_mask)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=1e-5)
+    else:
+        # both round their float32 result to bf16 once; a sum taken in
+        # another order may tip a value over to its neighbour (2**-8)
+        assert np.linalg.norm(got - want) <= 1e-3 * np.linalg.norm(want)
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -7)
+    # row 2 sees one key: every query returns that key's value, per group
+    only_value = np.repeat(np.asarray(v_cache[2, 0], np.float32), rep, axis=0)
+    np.testing.assert_allclose(
+        got[2], np.broadcast_to(only_value, got[2].shape), atol=1e-6)
 
 
 @pytest.fixture(scope="module")
