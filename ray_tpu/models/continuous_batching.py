@@ -109,6 +109,13 @@ class ContinuousBatcher:
         self.stats = {"admitted": 0, "finished": 0, "failed": 0,
                       "steps": 0, "max_active": 0, "tokens_out": 0,
                       "last_admit_step": -1}
+        if cfg.num_experts:
+            # what the experts received from real rows (prompt positions,
+            # active slots) and how many such rows there were, so that
+            # assignments / (rows x layers) reads experts_per_token exactly
+            # unless an assignment was dropped
+            self.stats.update(moe_expert_load=[0] * cfg.num_experts,
+                              moe_assignments=0, moe_rows=0)
         self._prefill_jits: Dict[int, Any] = {}
         self._decode_jit = jax.jit(self._decode_impl)
         self._install_jit = jax.jit(self._install_impl,
@@ -178,17 +185,20 @@ class ContinuousBatcher:
     # -- device programs ------------------------------------------------
     def _prefill_impl(self, params, tokens, length):
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
-        against a standalone single-row cache."""
+        against a standalone single-row cache; a sparse model's program
+        returns a fourth value, the experts' load [E] from the prompt's
+        real positions (a dense model's callers unpack three)."""
         s = tokens.shape[1]
         row_cache = init_cache(self.cfg, 1, s)
         positions = jnp.arange(s)[None, :]
         kv_mask = jnp.arange(s)[None, :] < length
-        logits, row_cache = forward_cached(
-            self.cfg, params, tokens, positions, row_cache, kv_mask)
+        logits, row_cache, aux = forward_cached(
+            self.cfg, params, tokens, positions, row_cache, kv_mask, kv_mask)
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
-        return last[0], row_cache.k[:, 0], row_cache.v[:, 0]
+        return (last[0], row_cache.k[:, 0], row_cache.v[:, 0],
+                *aux.values())
 
     def _install_impl(self, cache: KVCache, row_k, row_v, slot, length):
         """Scatter a prefilled row into its slot of the big cache (the
@@ -206,13 +216,14 @@ class ContinuousBatcher:
         positions = cache.lengths[:, None]
         kv_mask = jnp.arange(self.max_len)[None, :] <= \
             cache.lengths[:, None]
-        logits, cache = forward_cached(
-            self.cfg, params, toks[:, None], positions, cache, kv_mask)
+        logits, cache, aux = forward_cached(
+            self.cfg, params, toks[:, None], positions, cache, kv_mask,
+            active_mask[:, None])
         nxt = _sample_per_slot(logits[:, 0], rng, temps, topks)
         # only ACTIVE slots advance; free rows stay put so a later
         # install never races a drifting length past max_len
         new_len = jnp.where(active_mask, cache.lengths + 1, cache.lengths)
-        return nxt, KVCache(cache.k, cache.v, new_len)
+        return nxt, KVCache(cache.k, cache.v, new_len), *aux.values()
 
     # -- scheduler ------------------------------------------------------
     @staticmethod
@@ -259,9 +270,10 @@ class ContinuousBatcher:
             if pf is None:
                 pf = jax.jit(self._prefill_impl)
                 self._prefill_jits[bucket] = pf
-            last_logits, row_k, row_v = pf(
+            last_logits, row_k, row_v, *load = pf(
                 self.params, jnp.asarray(toks),
                 jnp.asarray([len(req.tokens)], np.int32))
+            self._fetch_ahead(load)
         with device_span(spans.ENGINE_INSTALL_DISPATCH):
             # pad the row out to max_len before install
             pad = self.max_len - row_k.shape[1]
@@ -280,6 +292,7 @@ class ContinuousBatcher:
                 jnp.asarray([req.sampling.temperature], np.float32),
                 jnp.asarray([req.sampling.top_k], np.int32))
             first_tok = int(np.asarray(first)[0])
+            self._count_experts(load, len(req.tokens))
         req.slot = slot
         self.stats["last_admit_step"] = self.stats["steps"]
         self._temps[slot] = req.sampling.temperature
@@ -289,6 +302,29 @@ class ContinuousBatcher:
         self._active[slot] = req
         self.stats["admitted"] += 1
         self._emit(req, first_tok)
+
+    @staticmethod
+    def _fetch_ahead(load: list) -> None:
+        """Start the load's copy to the host with the program's dispatch, so
+        that reading it after the tokens costs no second round trip."""
+        for array in load:
+            array.copy_to_host_async()
+
+    def _count_experts(self, load: list, rows: int) -> None:
+        """Add a program's expert load (`[load]`; `[]` from a dense model's
+        program) to the counters. Called where the program's tokens have
+        just been copied to the host, so the load is ready (`_fetch_ahead`)
+        and this is no sync point of its own."""
+        if not load:
+            return
+        load = np.asarray(load[0])
+        # a new list, not an update in place: `engine_stats` copies the
+        # dict from another thread and must see one state
+        self.stats["moe_expert_load"] = [
+            a + b for a, b in zip(self.stats["moe_expert_load"],
+                                  load.tolist())]
+        self.stats["moe_assignments"] += int(load.sum())
+        self.stats["moe_rows"] += rows
 
     def _emit(self, req: _Request, tok: int) -> None:
         """Deliver one sampled token; free the slot when the request is
@@ -370,13 +406,15 @@ class ContinuousBatcher:
             for slot in self._active:
                 active_mask[slot] = True
             self._rng, k = jax.random.split(self._rng)
-            toks, self.cache = self._decode_jit(
+            toks, self.cache, *load = self._decode_jit(
                 self.params, jnp.asarray(self._last_tok), self.cache, k,
                 jnp.asarray(self._temps), jnp.asarray(self._topks),
                 jnp.asarray(active_mask))
+            self._fetch_ahead(load)
         self.stats["steps"] += 1
         with device_span(spans.ENGINE_SAMPLE_SYNC):
             toks_np = np.asarray(toks)
+            self._count_experts(load, len(self._active))
         with device_span(spans.ENGINE_EMIT):
             for slot, req in list(self._active.items()):
                 self._host_len[slot] += 1

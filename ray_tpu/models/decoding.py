@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.transformer import (
-    TransformerConfig, _rms_norm, _rope,
+    TransformerConfig, _lora_delta, _qk_norm, _rms_norm, _rope, moe_dropless,
 )
 from ray_tpu.ops.attention import NEG_INF
 
@@ -84,10 +84,11 @@ def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
-def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
-                  k_cache, v_cache, kv_len_mask):
-    """One decoder block against cached K/V. Returns (x, new_k, new_v)
-    where new_k/new_v are this call's freshly computed K/V [B,S,kvH,D]."""
+def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
+                      k_cache, v_cache, kv_len_mask):
+    """The attention half of a decoder block against cached K/V. Returns
+    (x, k_cache, v_cache): the residual stream after attention and the
+    caches with this call's K/V written at `positions`."""
     scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
     b, s, _ = x.shape
     nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.hd
@@ -97,12 +98,12 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
     k = jnp.einsum("bsh,hnd->bsnd", y, p["wk"].astype(y.dtype))
     v = jnp.einsum("bsh,hnd->bsnd", y, p["wv"].astype(y.dtype))
     if lora is not None:
-        from ray_tpu.models.transformer import _lora_delta
-
         q = q + _lora_delta(y, lora["wq_a"], lora["wq_b"], scale).reshape(
             b, s, nh, hd)
         v = v + _lora_delta(y, lora["wv_a"], lora["wv_b"], scale).reshape(
             b, s, nkv, hd)
+    if cfg.qk_norm:
+        q, k = _qk_norm(cfg, q, k, p)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
 
@@ -116,9 +117,41 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
     v_cache = put(v_cache, v)
     attn = _attend_cached(q, k_cache, v_cache, positions, kv_len_mask)
     attn = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
-    x = x + attn
+    return x + attn, k_cache, v_cache
 
+
+def layers_to_scan(cfg: TransformerConfig, params):
+    """(tree, whole): the per-layer tree a layer scan runs over, and the
+    leaves that stay whole. A sparse model's expert weights are not
+    scanned: the body merges `whole` into its layer's parameters and hands
+    `_block_cached` the layer's index `tree["i"]`, so that the grouped
+    matmuls read the stack in place (`transformer._grouped_matmul`). A
+    dense model's tree is its stacked blocks (and adapters), as ever."""
+    blocks, whole = params["blocks"], {}
+    tree = {"p": blocks}
+    if cfg.num_experts:
+        whole = {n: blocks[n] for n in ("wi_gate", "wi_up", "wo_mlp")}
+        tree = {"p": {n: a for n, a in blocks.items() if n not in whole},
+                "i": jnp.arange(cfg.layers)}
+    if params.get("lora") is not None:
+        tree["l"] = params["lora"]
+    return tree, whole
+
+
+def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
+                  k_cache, v_cache, kv_len_mask, row_mask, layer=None):
+    """One decoder block against cached K/V. Returns (x, k_cache, v_cache,
+    load): the caches with this call's K/V written at `positions`, and the
+    assignments each expert received from the rows `row_mask` [B,S] marks
+    as real (None for a dense layer, which does not read the mask). With
+    `layer`, `p`'s expert weights are the whole stacks (`layers_to_scan`)."""
+    x, k_cache, v_cache = _attention_cached(
+        cfg, x, p, lora, positions, k_cache, v_cache, kv_len_mask)
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
+    if cfg.num_experts:
+        out, load = moe_dropless(cfg, y, p, row_mask, layer)
+        return x + out, k_cache, v_cache, load
+    scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
     with jax.named_scope("mlp"):
         gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
         up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
@@ -126,36 +159,39 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
             gate = gate + _lora_delta(y, lora["wi_a"], lora["wi_b"], scale)
         act = jax.nn.silu(gate) * up
         out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
-    return x + out, k_cache, v_cache
+    return x + out, k_cache, v_cache, None
 
 
 def forward_cached(cfg: TransformerConfig, params, tokens, positions,
-                   cache: KVCache, kv_len_mask):
+                   cache: KVCache, kv_len_mask, row_mask):
     """Forward [B,S] tokens through all layers, reading+writing the cache.
 
-    Returns (logits [B,S,V], new_cache). The layer stack is a lax.scan
-    over (stacked params, cache layers) — one compiled block body.
+    Returns (logits [B,S,V], new_cache, aux). The layer stack is a lax.scan
+    over (stacked params, cache layers) — one compiled block body. `aux` is
+    {} for a dense model; for a sparse one {"expert_load": int32 [E]}, the
+    assignments each expert received summed over the layers, from the rows
+    `row_mask` [B,S] marks as real (a prompt's positions below its length,
+    a decode step's active slots): pad rows and free slots are computed,
+    not counted.
     """
     x = params["embed"].astype(cfg.dtype)[tokens]
-    blocks, lora = params["blocks"], params.get("lora")
-    layer_tree = {"p": blocks}
-    if lora is not None:
-        layer_tree["l"] = lora
+    layer_tree, whole = layers_to_scan(cfg, params)
 
     def body(x, layer):
-        out, kc, vc = _block_cached(
-            cfg, x, layer["p"], layer.get("l"), positions,
-            layer["k"], layer["v"], kv_len_mask)
-        return out, (kc, vc)
+        out, kc, vc, load = _block_cached(
+            cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
+            layer["k"], layer["v"], kv_len_mask, row_mask, layer.get("i"))
+        return out, (kc, vc, load)
 
-    x, (new_k, new_v) = lax.scan(
+    x, (new_k, new_v, loads) = lax.scan(
         body, x, dict(layer_tree, k=cache.k, v=cache.v))
+    aux = {} if loads is None else {"expert_load": loads.sum(0)}
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     unembed = params.get("unembed")
     if unembed is None:
         unembed = params["embed"].T
     logits = jnp.einsum("bsh,hv->bsv", x, unembed.astype(x.dtype))
-    return logits, KVCache(new_k, new_v, cache.lengths)
+    return logits, KVCache(new_k, new_v, cache.lengths), aux
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,8 +234,9 @@ class Generator:
         b, s = tokens.shape
         positions = jnp.arange(s)[None, :].repeat(b, 0)
         kv_mask = jnp.arange(self.max_len)[None, :] < lengths[:, None]
-        logits, cache = forward_cached(
-            self.cfg, params, tokens, positions, cache, kv_mask)
+        logits, cache, _ = forward_cached(
+            self.cfg, params, tokens, positions, cache, kv_mask,
+            kv_mask[:, :s])
         # logits at each prompt's LAST real token
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None].repeat(
@@ -210,8 +247,9 @@ class Generator:
         b = tok.shape[0]
         positions = cache.lengths[:, None]  # next slot per sequence
         kv_mask = jnp.arange(self.max_len)[None, :] <= cache.lengths[:, None]
-        logits, cache = forward_cached(
-            self.cfg, params, tok[:, None], positions, cache, kv_mask)
+        logits, cache, _ = forward_cached(
+            self.cfg, params, tok[:, None], positions, cache, kv_mask,
+            jnp.ones((b, 1), bool))
         nxt = _sample(logits[:, 0], rng, temperature, top_k)
         return nxt, KVCache(cache.k, cache.v, cache.lengths + 1)
 

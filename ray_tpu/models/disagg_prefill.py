@@ -70,8 +70,8 @@ class PrefillReplica:
             row = init_cache(cfg, 1, s)
             positions = jnp.arange(s)[None, :]
             kv_mask = jnp.arange(s)[None, :] < length
-            logits, row = forward_cached(cfg, params, tokens, positions,
-                                         row, kv_mask)
+            logits, row, _ = forward_cached(cfg, params, tokens, positions,
+                                            row, kv_mask, kv_mask)
             last = jnp.take_along_axis(
                 logits, (length - 1)[:, None, None].repeat(
                     logits.shape[-1], -1), axis=1)[:, 0]

@@ -53,6 +53,7 @@ from ray_tpu.models.decoding import (
     _rms_norm,
     forward_cached,
     init_cache,
+    layers_to_scan,
 )
 from ray_tpu.models.transformer import TransformerConfig
 
@@ -287,8 +288,9 @@ class PagedBatcher:
         row = row._replace(k=k, v=v)
         positions = prefix_len + jnp.arange(s)[None, :]
         kv_mask = jnp.arange(self.max_len)[None, :] < (prefix_len + s)
-        logits, row = forward_cached(
-            self.cfg, params, tokens, positions, row, kv_mask)
+        logits, row, _ = forward_cached(
+            self.cfg, params, tokens, positions, row, kv_mask,
+            positions < length)
         last = jnp.take_along_axis(
             logits, (length - prefix_len - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
@@ -330,10 +332,7 @@ class PagedBatcher:
         cur_off = jnp.where(active_mask, lengths % ps, 0)
 
         x = params["embed"].astype(cfg.dtype)[toks[:, None]]
-        blocks, lora = params["blocks"], params.get("lora")
-        layer_tree = {"p": blocks}
-        if lora is not None:
-            layer_tree["l"] = lora
+        layer_tree, whole = layers_to_scan(cfg, params)
 
         def body(x, layer):
             # dense per-layer view of each slot's pages (transient —
@@ -342,9 +341,9 @@ class PagedBatcher:
                 b, t_total, cfg.kv_heads, cfg.hd)
             vd = layer["v"][page_table].reshape(
                 b, t_total, cfg.kv_heads, cfg.hd)
-            out, new_k_layer, new_v_layer = _block_cached(
-                cfg, x, layer["p"], layer.get("l"), positions,
-                kd, vd, kv_mask)
+            out, new_k_layer, new_v_layer, _ = _block_cached(
+                cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
+                kd, vd, kv_mask, active_mask[:, None], layer.get("i"))
             # fresh K/V of the current token sits at position `lengths`
             # of the dense view — pull it out and persist into the pool
             fresh_k = new_k_layer[jnp.arange(b), lengths]  # [B, kvH, D]

@@ -65,13 +65,21 @@ class TransformerConfig:
     num_experts: int = 0
     experts_per_token: int = 2
     capacity_factor: float = 1.25
+    # True: the k router weights are divided by their sum (Mixtral);
+    # False: used as the softmax gave them (OLMoE, norm_topk_prob false)
+    norm_topk_prob: bool = True
+    # learned RMSNorm over the whole q and k projections, before the split
+    # into heads and before RoPE (OLMoE's q_norm / k_norm)
+    qk_norm: bool = False
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden // self.heads
 
     def flops_per_token(self) -> float:
-        """Approx forward+backward FLOPs/token (6*N + attention), for MFU."""
+        """Approx forward+backward FLOPs/token (6*N + attention), for MFU.
+        For a sparse model N counts ALL experts, not the experts_per_token
+        a token runs: benchmarks/moe_cost.py is the yardstick there."""
         n_params = self.num_params()
         attn = 12 * self.layers * self.hidden * self.max_seq  # rough
         return 6 * n_params + attn
@@ -83,6 +91,8 @@ class TransformerConfig:
         if self.num_experts:
             mlp = self.num_experts * 3 * h * m + h * self.num_experts  # + router
         per_layer = h * (nh * hd) + 2 * h * (nkv * hd) + (nh * hd) * h + mlp + 2 * h
+        if self.qk_norm:
+            per_layer += nh * hd + nkv * hd
         emb = v * h * (1 if self.tie_embeddings else 2)
         return l * per_layer + emb + h
 
@@ -114,6 +124,20 @@ PRESETS: Dict[str, TransformerConfig] = {
         vocab_size=512, hidden=128, mlp_hidden=256, layers=2, heads=4,
         kv_heads=2, max_seq=128, remat=False, num_experts=4,
         experts_per_token=2,
+    ),
+    # allenai/OLMoE-1B-7B-0125-Instruct: MHA with QK-norm, 64 dropless
+    # SwiGLU experts of width 1024, top-8 without renormalisation
+    "olmoe_1b_7b": TransformerConfig(
+        vocab_size=50304, hidden=2048, mlp_hidden=1024, layers=16, heads=16,
+        kv_heads=16, max_seq=4096, rope_theta=1e4, norm_eps=1e-5,
+        num_experts=64, experts_per_token=8, norm_topk_prob=False,
+        qk_norm=True,
+    ),
+    "olmoe_debug": TransformerConfig(
+        vocab_size=512, hidden=128, mlp_hidden=64, layers=2, heads=4,
+        kv_heads=4, max_seq=128, remat=False, num_experts=16,
+        experts_per_token=4, norm_topk_prob=False, qk_norm=True,
+        dtype=jnp.float32,
     ),
 }
 
@@ -153,6 +177,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         "ln_attn": jnp.ones((l, h), pd),
         "ln_mlp": jnp.ones((l, h), pd),
     }
+    if cfg.qk_norm:
+        blocks["ln_q"] = jnp.ones((l, nh * hd), pd)
+        blocks["ln_k"] = jnp.ones((l, nkv * hd), pd)
     if cfg.num_experts:
         e = cfg.num_experts
         blocks["router"] = stack(keys[5], (h, e), h)
@@ -195,6 +222,9 @@ def param_axes(cfg: TransformerConfig) -> Params:
         "ln_attn": ("layers", "norm"),
         "ln_mlp": ("layers", "norm"),
     }
+    if cfg.qk_norm:
+        block_axes.update({"ln_q": ("layers", "heads"),
+                           "ln_k": ("layers", "kv_heads")})
     if cfg.num_experts:
         block_axes.update({
             "router": ("layers", "embed", None),  # router stays replicated
@@ -268,7 +298,9 @@ def _moe_mlp(cfg: TransformerConfig, y, p):
     logits = jnp.einsum("th,he->te", x, p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = lax.top_k(probs, k)  # [T,k]
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
 
     # capacity per expert; first-choice assignments get priority by
     # ordering the flattened (choice-major) token stream
@@ -296,6 +328,98 @@ def _moe_mlp(cfg: TransformerConfig, y, p):
     return out
 
 
+def _grouped_matmul(rows, weights, group_sizes, layer=None):
+    """rows [R,h] in E adjoining groups times weights [E,h,m] -> [R,m],
+    float32 accumulation and result.
+
+    With `layer` (a traced index) `weights` is the whole stack [L,E,h,m] and
+    is read in place: viewed as L*E groups of which only that layer's E hold
+    rows. A scanned layer loop must not slice its layer out first: the
+    grouped matmul is a custom call, so XLA cannot fuse the slice into it
+    and copies the layer's experts (805 MB a layer at OLMoE's widths: 3.6
+    against 1.2 ms a layer in a 16-row decode step, 7.9 against 5.5 ms in a
+    2048-token prefill on the v5e, PERF.md PR 25); empty groups cost
+    nothing measurable."""
+    if layer is not None:
+        n_layers, e = weights.shape[:2]
+        weights = weights.reshape(n_layers * e, *weights.shape[2:])
+        group_sizes = lax.dynamic_update_slice(
+            jnp.zeros((n_layers * e,), group_sizes.dtype), group_sizes,
+            (layer * e,))
+    return lax.ragged_dot(rows, weights, group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+@jax.named_scope("moe_router")
+def moe_router(cfg: TransformerConfig, x, p):
+    """x [T,h] -> (weights [T,k] float32, experts [T,k] int32): the top-k of
+    a float32 softmax over all experts, renormalised to sum to one only if
+    `cfg.norm_topk_prob`."""
+    logits = jnp.einsum("th,he->te", x, p["router"].astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                 cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    return weights, experts
+
+
+def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None):
+    """Dropless top-k token-choice experts: y [B,S,h] -> (out [B,S,h],
+    load [E] int32).
+
+    Every token runs its k experts whatever their load (OLMoE, Megablocks):
+    the T*k assignments are sorted by expert, their rows gathered, and the
+    gate, up and down projections are three grouped matmuls over the E
+    groups of that sorted order (`lax.ragged_dot`); then the rows go back
+    to token order and are summed with the router's weights. Shapes are
+    static: T*k rows always, only `group_sizes` is data. No [T*k, E, C]
+    dispatch tensor exists and nothing is dropped. Differentiable.
+
+    Routing is per token, so the rows of a padded prompt or of a free cache
+    slot are routed and computed like any other and cannot change a real
+    token's result; `row_mask` [B,S] (True = a real row) keeps them out of
+    `load`, the assignments each expert received from real rows.
+
+    `p` is one layer's parameters; with `layer` its three expert weights are
+    the whole stacks [L,E,...] and `layer` the index to use
+    (`_grouped_matmul` says why a layer scan wants that).
+    """
+    b, s, h = y.shape
+    e, k = cfg.num_experts, cfg.experts_per_token
+    t = b * s
+    x = y.reshape(t, h)
+    weights, experts = moe_router(cfg, x, p)
+    flat = experts.reshape(t * k)  # assignment a = token * k + choice
+    with jax.named_scope("moe_experts"):
+        order = jnp.argsort(flat, stable=True)  # sorted row -> assignment
+        group_sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+        rows = x[order // k]  # [T*k, h], one expert's rows adjoin
+        gate, up = (_grouped_matmul(rows, p[name].astype(x.dtype),
+                                    group_sizes, layer)
+                    for name in ("wi_gate", "wi_up"))
+        act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        down = _grouped_matmul(act, p["wo_mlp"].astype(x.dtype), group_sizes,
+                               layer)
+        # back to assignment order (a gather by the inverse permutation),
+        # then the weighted sum over each token's k experts, in float32
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+        out = (down[inverse].reshape(t, k, h) * weights[..., None]).sum(1)
+    load = group_sizes if row_mask is None else jnp.bincount(
+        flat, weights=jnp.repeat(row_mask.reshape(t).astype(jnp.int32), k),
+        length=e)
+    return out.astype(y.dtype).reshape(b, s, h), load
+
+
+def _qk_norm(cfg: TransformerConfig, q, k, p):
+    """OLMoE's q_norm / k_norm: RMSNorm over the whole projection (all
+    heads together), before RoPE."""
+    b, s = q.shape[:2]
+    q = _rms_norm(q.reshape(b, s, -1), p["ln_q"], cfg.norm_eps).reshape(q.shape)
+    k = _rms_norm(k.reshape(b, s, -1), p["ln_k"], cfg.norm_eps).reshape(k.shape)
+    return q, k
+
+
 def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
            attn_fn):
     """One decoder block. x [B,S,H_emb] in compute dtype."""
@@ -311,6 +435,8 @@ def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
     if lora_params is not None:
         q = q + _lora_delta(y, lora_params["wq_a"], lora_params["wq_b"], scale).reshape(b, s, nh, hd)
         v = v + _lora_delta(y, lora_params["wv_a"], lora_params["wv_b"], scale).reshape(b, s, nkv, hd)
+    if cfg.qk_norm:
+        q, k = _qk_norm(cfg, q, k, p)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     q = constrain(q, ("batch", "seq", "heads", None))

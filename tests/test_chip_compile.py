@@ -140,35 +140,73 @@ def test_lora_step_compiles_for_four_chips(topo):
     assert 0.25 <= per_device / _arg_bytes(state) < 0.27
 
 
-def test_prefill_and_decode_compile(topo):
+def _serve_programs(cfg, topo, slots):
+    """(prefill[SEQ], decode[slots x SEQ], cache shapes) of a
+    `ContinuousBatcher`, compiled for one described chip."""
     from ray_tpu.models.continuous_batching import ContinuousBatcher
     from ray_tpu.models.decoding import init_cache
 
-    cfg = T.config("llama2_7b", **WIDTHS)
     one = SingleDeviceSharding(topo.devices[0])
     params = _on(one, jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.key(0))))
     batcher = ContinuousBatcher.__new__(ContinuousBatcher)  # programs only
-    batcher.cfg, batcher.max_len, batcher.slots = cfg, SEQ, SLOTS
+    batcher.cfg, batcher.max_len, batcher.slots = cfg, SEQ, slots
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     prefill = jax.jit(batcher._prefill_impl).lower(
         params, arr((1, SEQ), jnp.int32), arr((1,), jnp.int32)).compile()
-    cache = _on(one, jax.eval_shape(lambda: init_cache(cfg, SLOTS, SEQ)))
+    cache = _on(one, jax.eval_shape(lambda: init_cache(cfg, slots, SEQ)))
     decode = jax.jit(batcher._decode_impl).lower(
-        params, arr((SLOTS,), jnp.int32), cache,
+        params, arr((slots,), jnp.int32), cache,
         _on(one, jax.eval_shape(lambda: jax.random.key(0))),
-        arr((SLOTS,), jnp.float32), arr((SLOTS,), jnp.int32),
-        arr((SLOTS,), jnp.bool_)).compile()
+        arr((slots,), jnp.float32), arr((slots,), jnp.int32),
+        arr((slots,), jnp.bool_)).compile()
+    return prefill, decode, cache
+
+
+def _total_bytes(program):
+    m = program.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+def test_prefill_and_decode_compile(topo):
+    prefill, decode, cache = _serve_programs(
+        T.config("llama2_7b", **WIDTHS), topo, SLOTS)
     # _decode_jit does not donate the cache: it is in HBM twice
     mem = decode.memory_analysis()
     assert mem.output_size_in_bytes >= _arg_bytes(cache) - 64
     for program in (prefill, decode):
+        assert _total_bytes(program) < 16e9
+
+
+def test_sparse_serve_programs_compile_and_fit(topo):
+    """The `serve-moe-doc-batch` deployment (OLMoE-1B-7B at its published
+    widths, 8 of 16 layers, 16 slots x 2048): the 2048-bucket prefill with
+    16,384 routed rows and the decode step with 128 compile for the chip as
+    grouped matmuls (no [rows, experts, capacity] dispatch tensor, no copy
+    of a layer's 805 MB of experts out of the stack) and fit its 16 GB
+    beside the undonated cache."""
+    cfg = T.config("olmoe_1b_7b", layers=8, param_dtype=jnp.bfloat16)
+    prefill, decode, _ = _serve_programs(cfg, topo, 16)
+    rows = cfg.experts_per_token * SEQ  # what `_moe_mlp` would dispatch:
+    dispatch_bytes = rows * cfg.num_experts * int(  # [rows, E, capacity] f32
+        cfg.capacity_factor * rows / cfg.num_experts) * 4
+    for name, program in (("prefill[2048]", prefill),
+                          ("decode[16x2048]", decode)):
         m = program.memory_analysis()
-        assert (m.argument_size_in_bytes + m.output_size_in_bytes
-                + m.temp_size_in_bytes) < 16e9
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        assert _total_bytes(program) < 16e9
+        # the load [E] leaves the program beside its tokens
+        assert "s32[64]" in program.as_text()
+        assert m.temp_size_in_bytes < dispatch_bytes / 2
+        assert not re.search(r"bf16\[64,(2048,1024|1024,2048)\]",
+                             program.as_text())
 
 
 def test_attend_cached_reads_the_cache_once(topo):
