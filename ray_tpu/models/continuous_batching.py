@@ -116,10 +116,7 @@ class ContinuousBatcher:
             # unless an assignment was dropped
             self.stats.update(moe_expert_load=[0] * cfg.num_experts,
                               moe_assignments=0, moe_rows=0)
-        self._prefill_jits: Dict[int, Any] = {}
-        self._decode_jit = jax.jit(self._decode_impl)
-        self._install_jit = jax.jit(self._install_impl,
-                                    donate_argnums=(0,))
+        self._jit_programs()
         self._thread = threading.Thread(
             target=self._pump, daemon=True, name="cb-pump")
         self._thread.start()
@@ -183,6 +180,16 @@ class ContinuousBatcher:
                 f"{self.max_len}")
 
     # -- device programs ------------------------------------------------
+    def _jit_programs(self) -> None:
+        """The programs as the pump runs them. Install and the decode step
+        are given the cache to keep (donated): each changes a few rows of
+        it in place, `self.cache` is replaced by what they return, and no
+        one may hold the cache that went in."""
+        self._prefill_jits: Dict[int, Any] = {}
+        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(2,))
+        self._install_jit = jax.jit(self._install_impl,
+                                    donate_argnums=(0,))
+
     def _prefill_impl(self, params, tokens, length):
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
         against a standalone single-row cache; a sparse model's program
@@ -376,6 +383,12 @@ class ContinuousBatcher:
                 for req in list(self._active.values()):
                     self._fail(req, e)
                     self._retire_silent(req)
+                # the step was given the cache to keep and may have
+                # consumed it before it raised. Every slot is free now, so
+                # an empty cache is the right state; the old one goes
+                # first, two do not fit beside the weights
+                self.cache = None
+                self.cache = init_cache(self.cfg, self.slots, self.max_len)
                 import logging
 
                 logging.getLogger(__name__).exception(

@@ -8,8 +8,12 @@ JAX-native over ray_tpu.models.transformer — the TPU-first shape:
   batch writing K/V for every layer into a preallocated cache
   [L, B, max_len, kvH, D] (static shapes — no per-token recompiles),
 - decode: ONE jitted single-token step per emitted token; the layer
-  stack is a `lax.scan` over (stacked params, cache layers) so the
-  compiled program is independent of depth,
+  stack is a `lax.scan` over the stacked params so the compiled program
+  is independent of depth. The cache rides in the scan's CARRY: a layer
+  writes this call's K/V rows into the stack in place and reads its
+  layer by index, so no layer is ever copied out of the stack and no
+  second stack is built. The jitted steps donate the cache: a caller
+  keeps the cache a step returns and never the one it passed in,
 - sampling (greedy / temperature / top-k) happens on-device; only the
   emitted token ids cross back to host.
 
@@ -68,6 +72,9 @@ def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
     The products of two bf16 values are exact in float32, so float32
     accumulation (`preferred_element_type`) gives the same logits; the
     probabilities stay float32 and V is upcast inside the fusion.
+
+    Its sibling rule is the caller's (`_write_stack`): never copy a layer
+    of the cache out of its stack to get here.
     """
     b, s, h, d = q.shape
     t, kvh = k_cache.shape[1], k_cache.shape[2]
@@ -84,11 +91,62 @@ def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
+def _write_layer(k_cache, v_cache, k, v, positions):
+    """`_attention_cached`'s plain cache access: `k_cache` / `v_cache` are ONE
+    layer [B, max_len, kvH, D]; fresh K/V [B, S, kvH, D] go to each
+    sequence's `positions` [B, S], and the layer is what attention reads."""
+    bidx = jnp.arange(k.shape[0])[:, None]
+    k_cache = k_cache.at[bidx, positions].set(k.astype(k_cache.dtype))
+    v_cache = v_cache.at[bidx, positions].set(v.astype(v_cache.dtype))
+    return k_cache, v_cache, k_cache, v_cache
+
+
+def _write_stack(layer):
+    """Cache access for a layer scan that CARRIES the whole stack
+    [L, B, max_len, kvH, D]: the rows are scattered into the stack at
+    [layer, sequence, position], in place, and the layer is read back by
+    index after the write (the new token attends to itself). Neither is a
+    copy of a layer: the read fuses into attention or is its one pass over
+    the cache, and with the stack donated the program holds no cache-sized
+    temporary (`tests/test_chip_compile.py`).
+
+    `positions` [B, S] are each sequence's S CONSECUTIVE positions, as
+    every engine writes a cache. The one thing read off the shapes rests
+    on that: S rows into a cache of S rows are the whole layer, written as
+    one slice (2,048 row scatters into the carried stack cost a 2,048-token
+    prefill 2 ms more than the parent's, PERF.md, PR 26)."""
+
+    def access(k_cache, v_cache, k, v, positions):
+        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        if k.shape[1] == k_cache.shape[2]:
+            # a batcher's prefill into a row cache of its bucket's length:
+            # the fresh K/V ARE the layer, no row is scattered or read back
+            return (lax.dynamic_update_index_in_dim(k_cache, k, layer, 0),
+                    lax.dynamic_update_index_in_dim(v_cache, v, layer, 0),
+                    k, v)
+        bidx = jnp.arange(k.shape[0])[:, None]
+        k_cache = k_cache.at[layer, bidx, positions].set(k)
+        v_cache = v_cache.at[layer, bidx, positions].set(v)
+        return (k_cache, v_cache,
+                lax.dynamic_index_in_dim(k_cache, layer, keepdims=False),
+                lax.dynamic_index_in_dim(v_cache, layer, keepdims=False))
+
+    return access
+
+
 def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
-                      k_cache, v_cache, kv_len_mask):
+                      k_cache, v_cache, kv_len_mask, access=_write_layer):
     """The attention half of a decoder block against cached K/V. Returns
     (x, k_cache, v_cache): the residual stream after attention and the
-    caches with this call's K/V written at `positions`."""
+    caches with this call's K/V written at `positions`.
+
+    The cache is touched in one place, by `access(k_cache, v_cache, k, v,
+    positions) -> (k_cache, v_cache, k_layer, v_layer)`: it writes the
+    fresh, rotated K/V wherever its cache keeps them and returns the
+    caches with the dense [B, max_len, kvH, D] view attention reads. One
+    layer's cache (the default), the carried stack (`_write_stack`) and
+    `PagedBatcher`'s page pool each bring their own; the block around it
+    has this one spelling."""
     scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
     b, s, _ = x.shape
     nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.hd
@@ -107,46 +165,43 @@ def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
 
-    # scatter fresh K/V into the cache at each sequence's positions, then
-    # attend against the whole (masked) cache
-    def put(cache, new):
-        bidx = jnp.arange(b)[:, None]
-        return cache.at[bidx, positions].set(new.astype(cache.dtype))
-
-    k_cache = put(k_cache, k)
-    v_cache = put(v_cache, v)
-    attn = _attend_cached(q, k_cache, v_cache, positions, kv_len_mask)
+    k_cache, v_cache, k_layer, v_layer = access(
+        k_cache, v_cache, k, v, positions)
+    attn = _attend_cached(q, k_layer, v_layer, positions, kv_len_mask)
     attn = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
     return x + attn, k_cache, v_cache
 
 
 def layers_to_scan(cfg: TransformerConfig, params):
     """(tree, whole): the per-layer tree a layer scan runs over, and the
-    leaves that stay whole. A sparse model's expert weights are not
-    scanned: the body merges `whole` into its layer's parameters and hands
-    `_block_cached` the layer's index `tree["i"]`, so that the grouped
-    matmuls read the stack in place (`transformer._grouped_matmul`). A
-    dense model's tree is its stacked blocks (and adapters), as ever."""
+    leaves that stay whole. The tree holds the stacked blocks (and
+    adapters) and each layer's index `tree["i"]`, by which the body
+    reaches what is not scanned: the KV cache it carries, and a sparse
+    model's expert weights. Those are not scanned: the body merges `whole`
+    into its layer's parameters and hands `_block_cached` the index, so
+    that the grouped matmuls read the stack in place
+    (`transformer._grouped_matmul`)."""
     blocks, whole = params["blocks"], {}
-    tree = {"p": blocks}
     if cfg.num_experts:
         whole = {n: blocks[n] for n in ("wi_gate", "wi_up", "wo_mlp")}
-        tree = {"p": {n: a for n, a in blocks.items() if n not in whole},
-                "i": jnp.arange(cfg.layers)}
+    tree = {"p": {n: a for n, a in blocks.items() if n not in whole},
+            "i": jnp.arange(cfg.layers)}
     if params.get("lora") is not None:
         tree["l"] = params["lora"]
     return tree, whole
 
 
 def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
-                  k_cache, v_cache, kv_len_mask, row_mask, layer=None):
+                  k_cache, v_cache, kv_len_mask, row_mask, layer=None,
+                  access=_write_layer):
     """One decoder block against cached K/V. Returns (x, k_cache, v_cache,
-    load): the caches with this call's K/V written at `positions`, and the
-    assignments each expert received from the rows `row_mask` [B,S] marks
-    as real (None for a dense layer, which does not read the mask). With
-    `layer`, `p`'s expert weights are the whole stacks (`layers_to_scan`)."""
+    load): the caches with this call's K/V written at `positions` by
+    `access` (`_attention_cached`), and the assignments each expert
+    received from the rows `row_mask` [B,S] marks as real (None for a dense
+    layer, which does not read the mask). With `layer`, `p`'s expert
+    weights are the whole stacks (`layers_to_scan`)."""
     x, k_cache, v_cache = _attention_cached(
-        cfg, x, p, lora, positions, k_cache, v_cache, kv_len_mask)
+        cfg, x, p, lora, positions, k_cache, v_cache, kv_len_mask, access)
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
     if cfg.num_experts:
         out, load = moe_dropless(cfg, y, p, row_mask, layer)
@@ -167,24 +222,35 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     """Forward [B,S] tokens through all layers, reading+writing the cache.
 
     Returns (logits [B,S,V], new_cache, aux). The layer stack is a lax.scan
-    over (stacked params, cache layers) — one compiled block body. `aux` is
-    {} for a dense model; for a sparse one {"expert_load": int32 [E]}, the
-    assignments each expert received summed over the layers, from the rows
-    `row_mask` [B,S] marks as real (a prompt's positions below its length,
-    a decode step's active slots): pad rows and free slots are computed,
-    not counted.
+    over the stacked params (one compiled block body) that CARRIES
+    `cache.k` / `cache.v` beside the residual stream: each layer writes
+    this call's rows into the stack in place and attends against its own
+    layer, read by index (`_write_stack`). A jitted caller that loops
+    donates `cache` and keeps `new_cache`: then the step changes B x S rows
+    of each layer and copies nothing, where a scan over the cache's layers
+    copied every layer out of the stack and back (42% of a decode step,
+    PERF.md, PR 26). Without donation the stack is copied once a call.
+    `positions` [B,S] are each sequence's S consecutive positions.
+
+    `aux` is {} for a dense model; for a sparse one {"expert_load": int32
+    [E]}, the assignments each expert received summed over the layers, from
+    the rows `row_mask` [B,S] marks as real (a prompt's positions below its
+    length, a decode step's active slots): pad rows and free slots are
+    computed, not counted.
     """
     x = params["embed"].astype(cfg.dtype)[tokens]
     layer_tree, whole = layers_to_scan(cfg, params)
 
-    def body(x, layer):
-        out, kc, vc, load = _block_cached(
+    def body(carry, layer):
+        x, k_cache, v_cache = carry
+        x, k_cache, v_cache, load = _block_cached(
             cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
-            layer["k"], layer["v"], kv_len_mask, row_mask, layer.get("i"))
-        return out, (kc, vc, load)
+            k_cache, v_cache, kv_len_mask, row_mask, layer["i"],
+            _write_stack(layer["i"]))
+        return (x, k_cache, v_cache), load
 
-    x, (new_k, new_v, loads) = lax.scan(
-        body, x, dict(layer_tree, k=cache.k, v=cache.v))
+    (x, new_k, new_v), loads = lax.scan(
+        body, (x, cache.k, cache.v), layer_tree)
     aux = {} if loads is None else {"expert_load": loads.sum(0)}
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     unembed = params.get("unembed")
@@ -226,9 +292,11 @@ class Generator:
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
-        self._prefill = jax.jit(self._prefill_impl)
+        # both take a cache to keep: the caller holds only what they return
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(3,))
         self._decode = jax.jit(
-            self._decode_impl, static_argnames=("temperature", "top_k"))
+            self._decode_impl, static_argnames=("temperature", "top_k"),
+            donate_argnums=(2,))
 
     def _prefill_impl(self, params, tokens, lengths, cache):
         b, s = tokens.shape

@@ -334,24 +334,23 @@ class PagedBatcher:
         x = params["embed"].astype(cfg.dtype)[toks[:, None]]
         layer_tree, whole = layers_to_scan(cfg, params)
 
+        def paged_access(pool_k, pool_v, k, v, positions):
+            # one layer of the pool: the current token's K/V go to their
+            # page, then attention reads a dense view of each slot's pages
+            # (transient, one layer only; the pool itself stays paged)
+            pool_k = pool_k.at[cur_page, cur_off].set(
+                k[:, 0].astype(pool_k.dtype))
+            pool_v = pool_v.at[cur_page, cur_off].set(
+                v[:, 0].astype(pool_v.dtype))
+            dense = (b, t_total, cfg.kv_heads, cfg.hd)
+            return (pool_k, pool_v, pool_k[page_table].reshape(dense),
+                    pool_v[page_table].reshape(dense))
+
         def body(x, layer):
-            # dense per-layer view of each slot's pages (transient —
-            # one layer only, the pool itself stays paged)
-            kd = layer["k"][page_table].reshape(
-                b, t_total, cfg.kv_heads, cfg.hd)
-            vd = layer["v"][page_table].reshape(
-                b, t_total, cfg.kv_heads, cfg.hd)
-            out, new_k_layer, new_v_layer, _ = _block_cached(
+            out, pk, pv, _ = _block_cached(
                 cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
-                kd, vd, kv_mask, active_mask[:, None], layer.get("i"))
-            # fresh K/V of the current token sits at position `lengths`
-            # of the dense view — pull it out and persist into the pool
-            fresh_k = new_k_layer[jnp.arange(b), lengths]  # [B, kvH, D]
-            fresh_v = new_v_layer[jnp.arange(b), lengths]
-            pk = layer["k"].at[cur_page, cur_off].set(
-                fresh_k.astype(layer["k"].dtype))
-            pv = layer["v"].at[cur_page, cur_off].set(
-                fresh_v.astype(layer["v"].dtype))
+                layer["k"], layer["v"], kv_mask, active_mask[:, None],
+                layer["i"], paged_access)
             return out, (pk, pv)
 
         x, (new_pool_k, new_pool_v) = lax.scan(

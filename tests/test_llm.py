@@ -12,7 +12,8 @@ import ray_tpu
 
 from ray_tpu.models import transformer as T
 from ray_tpu.models.decoding import (
-    Generator, SamplingParams, _attend_cached, init_cache,
+    Generator, KVCache, SamplingParams, _attend_cached, forward_cached,
+    init_cache,
 )
 from ray_tpu.ops.attention import NEG_INF
 
@@ -70,6 +71,97 @@ def test_attend_cached_matches_plain_reference(rep, s, dtype):
     only_value = np.repeat(np.asarray(v_cache[2, 0], np.float32), rep, axis=0)
     np.testing.assert_allclose(
         got[2], np.broadcast_to(only_value, got[2].shape), atol=1e-6)
+
+
+def _forward_cached_plainly(cfg, params, tokens, positions, cache,
+                            kv_len_mask):
+    """`forward_cached` of a dense model the plain way: a Python loop that
+    takes each layer's cache OUT of the stack, puts the fresh rows into
+    that copy, attends against it with the plain reference, and stacks the
+    copies again. What the layer scan must equal without ever doing it."""
+    bidx = jnp.arange(tokens.shape[0])[:, None]
+    x = params["embed"][tokens]
+    new_k, new_v = [], []
+    for i in range(cfg.layers):
+        p = jax.tree.map(lambda a: a[i], params["blocks"])
+        y = T._rms_norm(x, p["ln_attn"], cfg.norm_eps)
+        q, k, v = (jnp.einsum("bsh,hnd->bsnd", y, p[w])
+                   for w in ("wq", "wk", "wv"))
+        q = T._rope(q, positions, cfg.rope_theta)
+        k = T._rope(k, positions, cfg.rope_theta)
+        new_k.append(cache.k[i].at[bidx, positions].set(k))
+        new_v.append(cache.v[i].at[bidx, positions].set(v))
+        attn = _attend_reference(q, new_k[-1], new_v[-1], positions,
+                                 kv_len_mask)
+        x = x + jnp.einsum("bsnd,ndh->bsh", attn, p["wo"])
+        y = T._rms_norm(x, p["ln_mlp"], cfg.norm_eps)
+        x = x + (jax.nn.silu(y @ p["wi_gate"]) * (y @ p["wi_up"])) \
+            @ p["wo_mlp"]
+    x = T._rms_norm(x, params["ln_f"], cfg.norm_eps)
+    unembed = params.get("unembed")
+    if unembed is None:
+        unembed = params["embed"].T
+    return x @ unembed, KVCache(jnp.stack(new_k), jnp.stack(new_v),
+                                cache.lengths)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(8, 2), (4, 4)])
+def test_carried_cache_matches_a_plain_layer_loop(heads, kv_heads):
+    """Prefill and 8 decode steps through `forward_cached`, whose layer scan
+    carries the cache and writes rows into it in place (jitted with the
+    cache donated, as the engines run it), against the plain per-layer
+    loop: same logits, same cache contents, for a KV group of 4 and of 1.
+    Ragged prompts; slot 2 is a free slot of a continuous batch: empty, it
+    computes and writes at position 0 every step and never advances."""
+    cfg = T.config("debug", dtype=jnp.float32, param_dtype=jnp.float32,
+                   layers=3, heads=heads, kv_heads=kv_heads)
+    params = T.init_params(cfg, jax.random.key(1))
+    slots, max_len, s = 3, 32, 12
+    rng = np.random.default_rng(heads)
+    lengths = jnp.asarray([12, 5, 0], jnp.int32)
+    active = lengths > 0
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots, s)),
+                         jnp.int32)
+    step = jax.jit(
+        lambda tokens, positions, cache, kv_mask: forward_cached(
+            cfg, params, tokens, positions, cache, kv_mask,
+            jnp.ones(tokens.shape, bool))[:2],
+        donate_argnums=(2,))
+
+    def both(tokens, positions, kv_mask, got_cache, want_cache):
+        want, want_cache = _forward_cached_plainly(
+            cfg, params, tokens, positions, want_cache, kv_mask)
+        got, got_cache = step(tokens, positions, got_cache, kv_mask)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        np.testing.assert_allclose(got_cache.k, want_cache.k, atol=1e-5)
+        np.testing.assert_allclose(got_cache.v, want_cache.v, atol=1e-5)
+        return got, got_cache, want_cache
+
+    positions = jnp.arange(s)[None, :].repeat(slots, 0)
+    kv_mask = jnp.arange(max_len)[None, :] < lengths[:, None]
+    logits, got_cache, want_cache = both(
+        tokens, positions, kv_mask, init_cache(cfg, slots, max_len),
+        init_cache(cfg, slots, max_len))
+    assert float(jnp.abs(want_cache.k[:, 0, :12]).min()) > 0  # rows written
+    assert not want_cache.k[:, :, 12:].any()  # and no others
+    tok = jnp.argmax(logits[jnp.arange(slots), jnp.maximum(lengths - 1, 0)],
+                     axis=-1).astype(jnp.int32)
+    for _ in range(8):
+        kv_mask = jnp.arange(max_len)[None, :] <= lengths[:, None]
+        logits, got_cache, want_cache = both(
+            tok[:, None], lengths[:, None], kv_mask, got_cache, want_cache)
+        tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        lengths = jnp.where(active, lengths + 1, lengths)
+    assert lengths.tolist() == [20, 13, 0]
+    # each step wrote one row per slot and layer, the free slot's at 0
+    # every time (the prefill's 12 rows are all it holds)
+    assert bool(want_cache.k[:, 0, 19].any()) and \
+        not want_cache.k[:, 0, 20:].any()
+    assert not want_cache.k[:, 2, 12:].any()
+    # a batcher's prefill: the prompt's bucket into a row cache of the
+    # bucket's length, every row of which it writes (no scatter at all)
+    both(tokens[:1], positions[:1], jnp.arange(s)[None, :] < 9,
+         init_cache(cfg, 1, s), init_cache(cfg, 1, s))
 
 
 @pytest.fixture(scope="module")

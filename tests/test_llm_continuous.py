@@ -122,6 +122,67 @@ class TestContinuousBatcher:
         assert stream_toks == ref
 
 
+class TestDonatedCache:
+    """The decode step is given the cache to keep (`_jit_programs`)."""
+
+    def test_a_step_leaves_no_reference_to_the_cache_it_was_given(
+            self, tiny_model):
+        cfg, params = tiny_model
+        cb = ContinuousBatcher(cfg, params, max_len=64, slots=2)
+        seen = []
+        step = cb._decode_jit
+
+        def watched(params, toks, cache, *rest):
+            seen.append(cache)
+            return step(params, toks, cache, *rest)
+
+        cb._decode_jit = watched
+        try:
+            out = cb.submit([5, 17, 3], SamplingParams(max_tokens=4)
+                            ).result(timeout=120)
+        finally:
+            cb.shutdown()
+        assert len(out) == 4 and len(seen) == 3
+        if not seen[0].k.is_deleted():
+            pytest.skip(f"{jax.default_backend()} does not donate buffers")
+        # every cache a step took is gone: the engine holds the one the
+        # last step returned and nothing else
+        assert all(c.k.is_deleted() and c.v.is_deleted() for c in seen)
+        assert not cb.cache.k.is_deleted()
+
+    def test_a_failed_step_fails_its_requests_and_the_next_is_served(
+            self, tiny_model):
+        """A step that raises after it consumed the cache: the active
+        requests fail, the pump starts again from an empty cache (every
+        slot is free), and the next request gets the static Generator's
+        completion, not an error about a deleted array."""
+        cfg, params = tiny_model
+        sp = SamplingParams(max_tokens=6)
+        ref = Generator(cfg, params, max_len=64).generate([[7, 8, 9]], sp)
+        cb = ContinuousBatcher(cfg, params, max_len=64, slots=2)
+        step, raised = cb._decode_jit, []
+
+        def raises_once(*args):
+            out = step(*args)  # the cache in `args` is consumed
+            if not raised:
+                raised.append(True)
+                raise RuntimeError("the device fell over")
+            return out
+
+        cb._decode_jit = raises_once
+        try:
+            doomed = [cb.submit([5, 17, 3], sp), cb.submit([1, 2], sp)]
+            for fut in doomed:
+                with pytest.raises(RuntimeError, match="fell over"):
+                    fut.result(timeout=120)
+            assert cb.submit([7, 8, 9], sp).result(timeout=120) == ref[0]
+            stats = dict(cb.stats)
+        finally:
+            cb.shutdown()
+        assert stats["failed"] == 2 and stats["finished"] == 1
+        assert not cb.cache.k.is_deleted()
+
+
 class TestServeContinuous:
     def test_staggered_serving_traffic(self, ray_start_regular):
         """Serve replica under staggered mixed-length traffic: all
