@@ -25,6 +25,22 @@ This is the TPU-native version:
 pump thread runs steps while any request is active or waiting — the
 Serve replica's concurrent handlers all feed one device loop, keeping
 the MXU busy under mixed-length traffic.
+
+``ContinuousBatcher`` is the one scheduler: how a request's lifecycle is
+run (queue, admission, first token, step, emit, retire, failure, spans,
+counters) is here and nowhere else. WHERE K/V rows are kept is behind
+six methods it calls and never looks into (``_empty_cache``,
+``_prefill_into``, ``_decode``, ``_release``, ``_make_room``,
+``_refused_for_now``): this class answers them for fixed slots, and
+``models/paged_kv.PagedBatcher`` overrides them for refcounted pages with
+prefix reuse and preemption. A subclass, not a cache object held by the
+scheduler: the programs are methods that the benchmark and
+``tests/test_chip_compile.py`` lower from a bare instance, and both kinds
+of cache share ``_decode_impl`` through ``forward_cached``. What a cache
+holds for one request rides on the request as ``_Request.kv``, which the
+scheduler never reads. ``PrefillPrograms``, the scheduler's base, is the
+prompt program alone: what a prefill replica constructs
+(``models/disagg_prefill.py``).
 """
 
 from __future__ import annotations
@@ -43,6 +59,7 @@ import numpy as np
 from ray_tpu.models.decoding import (
     KVCache,
     SamplingParams,
+    _write_stack,
     forward_cached,
     init_cache,
 )
@@ -80,16 +97,73 @@ class _Request:
     out: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1
     t_submit: float = dataclasses.field(default_factory=time.monotonic)
+    # the cache's own record of what it holds for the request; the
+    # scheduler carries it and never reads it
+    kv: Any = None
 
 
-class ContinuousBatcher:
-    """Iteration-level scheduler over a fixed-slot KV cache."""
+class PrefillPrograms:
+    """A prompt through the model, one compiled program per length bucket:
+    all a prefill replica runs (`models/disagg_prefill.py`), and where the
+    scheduler below gets its prefill."""
 
-    def __init__(self, cfg: TransformerConfig, params, max_len: int = 512,
-                 slots: int = 8, seed: int = 0):
+    def __init__(self, cfg: TransformerConfig, params, max_len: int):
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
+        self._jit_programs()
+
+    def _jit_programs(self) -> None:
+        self._prefill_jits: Dict[int, Any] = {}
+
+    def _prefill_impl(self, params, tokens, length):
+        """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
+        against a standalone single-row cache; a sparse model's program
+        returns a fourth value, the experts' load [E] from the prompt's
+        real positions (a dense model's callers unpack three)."""
+        s = tokens.shape[1]
+        row_cache = init_cache(self.cfg, 1, s)
+        positions = jnp.arange(s)[None, :]
+        kv_mask = jnp.arange(s)[None, :] < length
+        logits, row_cache, aux = forward_cached(
+            self.cfg, params, tokens, positions, row_cache, kv_mask, kv_mask)
+        last = jnp.take_along_axis(
+            logits, (length - 1)[:, None, None].repeat(
+                logits.shape[-1], -1), axis=1)[:, 0]
+        return (last[0], row_cache.k[:, 0], row_cache.v[:, 0],
+                *aux.values())
+
+    @staticmethod
+    def _bucket(n: int) -> int:
+        b = 16
+        while b < n:
+            b *= 2
+        return b
+
+    def _prefill_program(self, bucket: int):
+        """The bucket's prefill program, compiled at its first prompt."""
+        pf = self._prefill_jits.get(bucket)
+        if pf is None:
+            pf = self._prefill_jits[bucket] = jax.jit(self._prefill_impl)
+        return pf
+
+    def _prefill(self, tokens: Sequence[int]):
+        """One prompt through its bucket's program: what `_prefill_impl`
+        returns, the rows of bucket length."""
+        bucket = min(self._bucket(len(tokens)), self.max_len)
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, : len(tokens)] = tokens
+        return self._prefill_program(bucket)(
+            self.params, jnp.asarray(toks),
+            jnp.asarray([len(tokens)], np.int32))
+
+
+class ContinuousBatcher(PrefillPrograms):
+    """Iteration-level scheduler, and the fixed-slot KV cache under it."""
+
+    def __init__(self, cfg: TransformerConfig, params, max_len: int = 512,
+                 slots: int = 8, seed: int = 0):
+        super().__init__(cfg, params, max_len)
         self.slots = slots
         self._waiting: "queue.Queue[_Request]" = queue.Queue()
         # scheduler state (_active/_free/_host_len/...) is confined to
@@ -99,7 +173,7 @@ class ContinuousBatcher:
         self._wake = threading.Event()
         self._shutdown = False
         self._rng = jax.random.key(seed)
-        self.cache = init_cache(cfg, slots, max_len)
+        self.cache = self._empty_cache()
         # per-slot host-side state (no device sync on the emit path)
         self._temps = np.zeros(slots, np.float32)
         self._topks = np.zeros(slots, np.int32)
@@ -116,7 +190,6 @@ class ContinuousBatcher:
             # unless an assignment was dropped
             self.stats.update(moe_expert_load=[0] * cfg.num_experts,
                               moe_assignments=0, moe_rows=0)
-        self._jit_programs()
         self._thread = threading.Thread(
             target=self._pump, daemon=True, name="cb-pump")
         self._thread.start()
@@ -125,27 +198,16 @@ class ContinuousBatcher:
     def submit(self, tokens: Sequence[int],
                sampling: Optional[SamplingParams] = None) -> Future:
         """Thread-safe: enqueue one request; resolves to List[int]."""
-        if self._shutdown:
-            raise RuntimeError("ContinuousBatcher was shut down")
-        fut: Future = Future()
-        req = _Request(list(tokens) or [0], sampling or SamplingParams(),
-                       fut, None)
-        self._check_len(req)
-        self._waiting.put(req)
-        self._wake.set()
-        return fut
+        return self._enqueue(_Request(
+            list(tokens) or [0], sampling or SamplingParams(), Future(),
+            None)).future
 
     def submit_stream(self, tokens: Sequence[int],
                       sampling: Optional[SamplingParams] = None):
         """Yields token ids as they are emitted."""
-        if self._shutdown:
-            raise RuntimeError("ContinuousBatcher was shut down")
-        q: queue.Queue = queue.Queue()
-        req = _Request(list(tokens) or [0], sampling or SamplingParams(),
-                       None, q)
-        self._check_len(req)
-        self._waiting.put(req)
-        self._wake.set()
+        q = self._enqueue(_Request(
+            list(tokens) or [0], sampling or SamplingParams(), None,
+            queue.Queue())).stream_q
         while True:
             t = q.get()
             if t is None:
@@ -154,13 +216,21 @@ class ContinuousBatcher:
                 raise t  # the admit or the step failed: not a short answer
             yield t
 
+    def _enqueue(self, req: _Request) -> _Request:
+        if self._shutdown:
+            raise RuntimeError(f"{type(self).__name__} was shut down")
+        self._check_len(req)
+        self._waiting.put(req)
+        self._wake.set()
+        return req
+
     def shutdown(self) -> None:
         self._shutdown = True
         self._wake.set()
         self._thread.join(timeout=10.0)
         # outstanding work can never run now: resolve it with an error
         # instead of hanging its callers
-        err = RuntimeError("ContinuousBatcher was shut down")
+        err = RuntimeError(f"{type(self).__name__} was shut down")
         leftovers = list(self._active.values())
         while not self._waiting.empty():
             try:
@@ -185,27 +255,10 @@ class ContinuousBatcher:
         are given the cache to keep (donated): each changes a few rows of
         it in place, `self.cache` is replaced by what they return, and no
         one may hold the cache that went in."""
-        self._prefill_jits: Dict[int, Any] = {}
+        super()._jit_programs()
         self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(2,))
         self._install_jit = jax.jit(self._install_impl,
                                     donate_argnums=(0,))
-
-    def _prefill_impl(self, params, tokens, length):
-        """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
-        against a standalone single-row cache; a sparse model's program
-        returns a fourth value, the experts' load [E] from the prompt's
-        real positions (a dense model's callers unpack three)."""
-        s = tokens.shape[1]
-        row_cache = init_cache(self.cfg, 1, s)
-        positions = jnp.arange(s)[None, :]
-        kv_mask = jnp.arange(s)[None, :] < length
-        logits, row_cache, aux = forward_cached(
-            self.cfg, params, tokens, positions, row_cache, kv_mask, kv_mask)
-        last = jnp.take_along_axis(
-            logits, (length - 1)[:, None, None].repeat(
-                logits.shape[-1], -1), axis=1)[:, 0]
-        return (last[0], row_cache.k[:, 0], row_cache.v[:, 0],
-                *aux.values())
 
     def _install_impl(self, cache: KVCache, row_k, row_v, slot, length):
         """Scatter a prefilled row into its slot of the big cache (the
@@ -219,27 +272,77 @@ class ContinuousBatcher:
         return KVCache(k, v, lengths)
 
     def _decode_impl(self, params, toks, cache, rng, temps, topks,
-                     active_mask):
+                     active_mask, *, access=_write_stack):
+        """One token for every slot. `access` is where the layers' K/V
+        rows live (`forward_cached`): the slots' stack here."""
         positions = cache.lengths[:, None]
         kv_mask = jnp.arange(self.max_len)[None, :] <= \
             cache.lengths[:, None]
         logits, cache, aux = forward_cached(
             self.cfg, params, toks[:, None], positions, cache, kv_mask,
-            active_mask[:, None])
+            active_mask[:, None], access)
         nxt = _sample_per_slot(logits[:, 0], rng, temps, topks)
         # only ACTIVE slots advance; free rows stay put so a later
         # install never races a drifting length past max_len
         new_len = jnp.where(active_mask, cache.lengths + 1, cache.lengths)
         return nxt, KVCache(cache.k, cache.v, new_len), *aux.values()
 
-    # -- scheduler ------------------------------------------------------
-    @staticmethod
-    def _bucket(n: int) -> int:
-        b = 16
-        while b < n:
-            b *= 2
-        return b
+    def _pad_row(self, row_k, row_v):
+        """A prefilled row [L, S, kvH, D] out to max_len, as install takes
+        it."""
+        pad = self.max_len - row_k.shape[1]
+        if pad <= 0:
+            return row_k, row_v
+        zeros = jnp.zeros(
+            row_k.shape[:1] + (pad,) + row_k.shape[2:], row_k.dtype)
+        return (jnp.concatenate([row_k, zeros], axis=1),
+                jnp.concatenate([row_v, zeros], axis=1))
 
+    # -- the cache: fixed slots ------------------------------------------
+    # The six methods the scheduler reaches K/V rows through. It calls them
+    # for every request and every step and never asks which cache answers.
+    def _empty_cache(self):
+        """The device cache with nothing in it: at construction, and again
+        after a step that raised."""
+        return init_cache(self.cfg, self.slots, self.max_len)
+
+    def _prefill_into(self, req: _Request, slot: int):
+        """Put the prompt's K/V into `slot`. Returns (logits at its last
+        position [V], the program's expert load, the rows that were
+        computed). What it takes for the request it takes before it can
+        fail or leaves with the request, for `_release` to give back."""
+        with device_span(spans.ENGINE_PREFILL_DISPATCH):
+            last_logits, row_k, row_v, *load = self._prefill(req.tokens)
+            self._fetch_ahead(load)
+        with device_span(spans.ENGINE_INSTALL_DISPATCH):
+            self.cache = self._install_jit(
+                self.cache, *self._pad_row(row_k, row_v), slot,
+                len(req.tokens))
+        return last_logits, load, len(req.tokens)
+
+    def _decode(self, toks, rng, temps, topks, active_mask):
+        """One decode step over the cache, which the step keeps. Returns
+        (sampled tokens [slots], the program's expert load)."""
+        toks, self.cache, *load = self._decode_jit(
+            self.params, toks, self.cache, rng, temps, topks, active_mask)
+        return toks, load
+
+    def _release(self, req: _Request) -> None:
+        """Give back what the cache holds for a request that leaves its
+        slot or never got one. A slot's rows are overwritten by the next
+        install: nothing."""
+
+    def _make_room(self) -> None:
+        """Before a step: every active slot can take one more row. A slot
+        is `max_len` rows long and `_emit` stops at the last: nothing."""
+
+    def _refused_for_now(self, req: _Request, e: Exception) -> bool:
+        """Whether a failed admit is the cache saying "not yet": the
+        request then keeps its place at the head of the queue. A free slot
+        is all a request needs here: never."""
+        return False
+
+    # -- scheduler ------------------------------------------------------
     def _admit(self) -> bool:
         admitted = False
         while self._free and not self._waiting.empty():
@@ -251,9 +354,20 @@ class ContinuousBatcher:
             try:
                 self._admit_one(req, slot)
             except Exception as e:  # noqa: BLE001 — e.g. compile OOM
-                # the slot goes back and THIS request fails; others and
-                # the pump survive
+                # the slot and what the cache took go back
                 self._free.append(slot)
+                self._release(req)
+                if self._refused_for_now(req, e):
+                    # to the FRONT (FIFO position kept — a tail requeue
+                    # would let every later small request leapfrog a big
+                    # one forever, its future never resolving), and no
+                    # more admits this step: retiring sequences make room
+                    # and the pump runs _admit every step
+                    with self._waiting.mutex:
+                        self._waiting.queue.appendleft(req)
+                        self._waiting.not_empty.notify()
+                    break
+                # THIS request fails; others and the pump survive
                 self._fail(req, e)
                 continue
             admitted = True
@@ -262,53 +376,32 @@ class ContinuousBatcher:
         return admitted
 
     def _admit_one(self, req: _Request, slot: int) -> None:
+        # the prompt's bucket; a cache that finds part of the prompt already
+        # there (`PagedBatcher`) runs the remainder's
         bucket = min(self._bucket(len(req.tokens)), self.max_len)
         with device_span(
                 spans.ENGINE_ADMIT, bucket=bucket,
                 prompt_len=len(req.tokens),
                 queued_ms=(time.monotonic() - req.t_submit) * 1e3):
-            self._prefill_into(req, slot, bucket)
-
-    def _prefill_into(self, req: _Request, slot: int, bucket: int) -> None:
-        with device_span(spans.ENGINE_PREFILL_DISPATCH):
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, : len(req.tokens)] = req.tokens
-            pf = self._prefill_jits.get(bucket)
-            if pf is None:
-                pf = jax.jit(self._prefill_impl)
-                self._prefill_jits[bucket] = pf
-            last_logits, row_k, row_v, *load = pf(
-                self.params, jnp.asarray(toks),
-                jnp.asarray([len(req.tokens)], np.int32))
-            self._fetch_ahead(load)
-        with device_span(spans.ENGINE_INSTALL_DISPATCH):
-            # pad the row out to max_len before install
-            pad = self.max_len - row_k.shape[1]
-            if pad > 0:
-                zeros = jnp.zeros(
-                    row_k.shape[:1] + (pad,) + row_k.shape[2:],
-                    row_k.dtype)
-                row_k = jnp.concatenate([row_k, zeros], axis=1)
-                row_v = jnp.concatenate([row_v, zeros], axis=1)
-            self.cache = self._install_jit(
-                self.cache, row_k, row_v, slot, len(req.tokens))
-        with device_span(spans.ENGINE_FIRST_TOKEN_SYNC):
-            self._rng, k = jax.random.split(self._rng)
-            first = _sample_per_slot(
-                last_logits[None], k,
-                jnp.asarray([req.sampling.temperature], np.float32),
-                jnp.asarray([req.sampling.top_k], np.int32))
-            first_tok = int(np.asarray(first)[0])
-            self._count_experts(load, len(req.tokens))
-        req.slot = slot
-        self.stats["last_admit_step"] = self.stats["steps"]
-        self._temps[slot] = req.sampling.temperature
-        self._topks[slot] = req.sampling.top_k
-        self._host_len[slot] = len(req.tokens)
-        self._last_tok[slot] = first_tok
-        self._active[slot] = req
-        self.stats["admitted"] += 1
-        self._emit(req, first_tok)
+            last_logits, load, rows = self._prefill_into(req, slot)
+            with device_span(spans.ENGINE_FIRST_TOKEN_SYNC):
+                self._rng, k = jax.random.split(self._rng)
+                first = _sample_per_slot(
+                    last_logits[None], k,
+                    jnp.asarray([req.sampling.temperature], np.float32),
+                    jnp.asarray([req.sampling.top_k], np.int32))
+                first_tok = int(np.asarray(first)[0])
+                self._count_experts(load, rows)
+            # inside the span: an admit is over when its first token is out
+            req.slot = slot
+            self.stats["last_admit_step"] = self.stats["steps"]
+            self._temps[slot] = req.sampling.temperature
+            self._topks[slot] = req.sampling.top_k
+            self._host_len[slot] = len(req.tokens)
+            self._last_tok[slot] = first_tok
+            self._active[slot] = req
+            self.stats["admitted"] += 1
+            self._emit(req, first_tok)
 
     @staticmethod
     def _fetch_ahead(load: list) -> None:
@@ -356,16 +449,30 @@ class ContinuousBatcher:
         if done:
             self._retire(req)
 
-    def _retire(self, req: _Request) -> None:
+    def _vacate(self, req: _Request) -> None:
+        """The request leaves its slot (done, failed or preempted)."""
         if req.slot >= 0:
+            self._release(req)
             self._active.pop(req.slot, None)
             self._free.append(req.slot)
             req.slot = -1
+
+    def _retire(self, req: _Request) -> None:
+        self._vacate(req)
         self.stats["finished"] += 1
         if req.future is not None and not req.future.done():
             req.future.set_result(list(req.out))
         if req.stream_q is not None:
             req.stream_q.put(None)
+
+    def _fail(self, req: _Request, e: BaseException) -> None:
+        """Resolve a request with the device-side failure: its caller —
+        future or stream — sees the exception, and it is counted."""
+        self.stats["failed"] += 1
+        if req.future is not None and not req.future.done():
+            req.future.set_exception(e)
+        if req.stream_q is not None:
+            req.stream_q.put(e)
 
     def _pump(self) -> None:
         while not self._shutdown:
@@ -382,47 +489,34 @@ class ContinuousBatcher:
             except Exception as e:  # noqa: BLE001 — fail active requests
                 for req in list(self._active.values()):
                     self._fail(req, e)
-                    self._retire_silent(req)
+                    self._vacate(req)
                 # the step was given the cache to keep and may have
                 # consumed it before it raised. Every slot is free now, so
                 # an empty cache is the right state; the old one goes
                 # first, two do not fit beside the weights
                 self.cache = None
-                self.cache = init_cache(self.cfg, self.slots, self.max_len)
+                self.cache = self._empty_cache()
                 import logging
 
                 logging.getLogger(__name__).exception(
                     "continuous-batching step failed")
 
-    def _fail(self, req: _Request, e: BaseException) -> None:
-        """Resolve a request with the device-side failure: its caller —
-        future or stream — sees the exception, and it is counted."""
-        self.stats["failed"] += 1
-        if req.future is not None and not req.future.done():
-            req.future.set_exception(e)
-        if req.stream_q is not None:
-            req.stream_q.put(e)
-
-    def _retire_silent(self, req: _Request) -> None:
-        if req.slot >= 0:
-            self._active.pop(req.slot, None)
-            self._free.append(req.slot)
-            req.slot = -1
-
     def _step(self) -> None:
         self._admit()
         if not self._active:
             return
+        self._make_room()
+        if not self._active:
+            return  # the cache took its last slot back
         with device_span(spans.ENGINE_DECODE_DISPATCH,
                          active=len(self._active)):
             active_mask = np.zeros(self.slots, bool)
             for slot in self._active:
                 active_mask[slot] = True
             self._rng, k = jax.random.split(self._rng)
-            toks, self.cache, *load = self._decode_jit(
-                self.params, jnp.asarray(self._last_tok), self.cache, k,
-                jnp.asarray(self._temps), jnp.asarray(self._topks),
-                jnp.asarray(active_mask))
+            toks, load = self._decode(
+                jnp.asarray(self._last_tok), k, jnp.asarray(self._temps),
+                jnp.asarray(self._topks), jnp.asarray(active_mask))
             self._fetch_ahead(load)
         self.stats["steps"] += 1
         with device_span(spans.ENGINE_SAMPLE_SYNC):

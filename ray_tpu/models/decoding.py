@@ -218,8 +218,11 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
 
 
 def forward_cached(cfg: TransformerConfig, params, tokens, positions,
-                   cache: KVCache, kv_len_mask, row_mask):
+                   cache: KVCache, kv_len_mask, row_mask,
+                   access=_write_stack):
     """Forward [B,S] tokens through all layers, reading+writing the cache.
+    The one layer loop over a cache: every engine's prefill and decode
+    program is this function under its own masks.
 
     Returns (logits [B,S,V], new_cache, aux). The layer stack is a lax.scan
     over the stacked params (one compiled block body) that CARRIES
@@ -231,6 +234,12 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     copied every layer out of the stack and back (42% of a decode step,
     PERF.md, PR 26). Without donation the stack is copied once a call.
     `positions` [B,S] are each sequence's S consecutive positions.
+
+    `access(layer)` is the layer's cache access (`_attention_cached`), and
+    `cache.k` / `cache.v` are whatever it indexes: the stack
+    [L, B, max_len, kvH, D] for the default, the page pools
+    [L, pages, page_size, kvH, D] for `PagedBatcher`'s. The loop only
+    carries them.
 
     `aux` is {} for a dense model; for a sparse one {"expert_load": int32
     [E]}, the assignments each expert received summed over the layers, from
@@ -246,7 +255,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
         x, k_cache, v_cache, load = _block_cached(
             cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
             k_cache, v_cache, kv_len_mask, row_mask, layer["i"],
-            _write_stack(layer["i"]))
+            access(layer["i"]))
         return (x, k_cache, v_cache), load
 
     (x, new_k, new_v), loads = lax.scan(
@@ -321,85 +330,69 @@ class Generator:
         nxt = _sample(logits[:, 0], rng, temperature, top_k)
         return nxt, KVCache(cache.k, cache.v, cache.lengths + 1)
 
-    def generate(self, prompts, sampling: Optional[SamplingParams] = None,
-                 seed: int = 0):
-        """prompts: list of int32 token-id lists → list of completions
-        (token-id lists, stop token excluded)."""
+    def _decode_loop(self, prompts, sampling: SamplingParams, seed: int):
+        """Prefill `prompts`, then yield (tokens [B], full [B]) for each of
+        at most `max_tokens` steps: the tokens sampled at this step and
+        which sequences have no cache row left for another. The consumer
+        decides who has stopped and closes the loop when all have."""
         import numpy as np
 
-        sampling = sampling or SamplingParams()
-        b = len(prompts)
         lens = np.array([len(p) for p in prompts], np.int32)
-        if int(lens.max()) >= self.max_len:
+        s = int(lens.max())
+        if s >= self.max_len:
             # JAX silently drops out-of-bounds cache scatters — without
             # this check an over-long prompt would "generate" garbage
             raise ValueError(
-                f"prompt length {int(lens.max())} >= max_len "
+                f"prompt length {s} >= max_len "
                 f"{self.max_len}; raise Generator(max_len=...)")
-        s = int(lens.max())
-        toks = np.zeros((b, s), np.int32)
+        toks = np.zeros((len(prompts), s), np.int32)
         for i, p in enumerate(prompts):
             toks[i, : len(p)] = p
-        cache = init_cache(self.cfg, b, self.max_len)
+        cache = init_cache(self.cfg, len(prompts), self.max_len)
         last_logits, cache = self._prefill(
             self.params, jnp.asarray(toks), jnp.asarray(lens), cache)
         rng = jax.random.key(seed)
         rng, k0 = jax.random.split(rng)
         tok = _sample(last_logits, k0, sampling.temperature, sampling.top_k)
-        outs = [[] for _ in range(b)]
-        done = np.zeros(b, bool)
         for _ in range(sampling.max_tokens):
-            tok_np = np.asarray(tok)
-            for i in range(b):
-                if not done[i]:
-                    if sampling.stop_token_id is not None and \
-                            int(tok_np[i]) == sampling.stop_token_id:
-                        done[i] = True
-                    else:
-                        outs[i].append(int(tok_np[i]))
-            # a sequence whose next KV slot is out of room stops alone —
-            # cache rows are per-sequence, so others keep decoding
-            lens_np = np.asarray(cache.lengths)
-            for i in range(b):
-                if not done[i] and lens_np[i] >= self.max_len:
-                    done[i] = True
-            if done.all():
-                break
+            yield np.asarray(tok), np.asarray(cache.lengths) >= self.max_len
             rng, k = jax.random.split(rng)
             tok, cache = self._decode(
                 self.params, tok, cache, k,
                 temperature=sampling.temperature, top_k=sampling.top_k)
+
+    def generate(self, prompts, sampling: Optional[SamplingParams] = None,
+                 seed: int = 0):
+        """prompts: list of int32 token-id lists → list of completions
+        (token-id lists, stop token excluded)."""
+        sampling = sampling or SamplingParams()
+        outs = [[] for _ in prompts]
+        done = [False] * len(prompts)
+        for tok, full in self._decode_loop(prompts, sampling, seed):
+            for i, t in enumerate(tok.tolist()):
+                if done[i]:
+                    continue
+                if t == sampling.stop_token_id:
+                    done[i] = True
+                    continue
+                outs[i].append(t)
+                # a sequence whose next KV slot is out of room stops alone —
+                # cache rows are per-sequence, so others keep decoding
+                done[i] = bool(full[i])
+            if all(done):
+                break
         return outs
 
     def generate_stream(self, prompt, sampling: Optional[SamplingParams] = None,
                         seed: int = 0):
         """Single-prompt streaming: yields one token id at a time (the
         Serve LLM deployment's token-stream path)."""
-        import numpy as np
-
         sampling = sampling or SamplingParams()
-        prompt = list(prompt) or [0]
-        if len(prompt) >= self.max_len:
-            raise ValueError(
-                f"prompt length {len(prompt)} >= max_len {self.max_len}; "
-                f"raise Generator(max_len=...)")
-        toks = np.asarray([prompt], np.int32)
-        lens = np.asarray([len(prompt)], np.int32)
-        cache = init_cache(self.cfg, 1, self.max_len)
-        last_logits, cache = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lens), cache)
-        rng = jax.random.key(seed)
-        rng, k0 = jax.random.split(rng)
-        tok = _sample(last_logits, k0, sampling.temperature, sampling.top_k)
-        for _ in range(sampling.max_tokens):
-            t = int(np.asarray(tok)[0])
-            if sampling.stop_token_id is not None and \
-                    t == sampling.stop_token_id:
+        for tok, full in self._decode_loop([list(prompt) or [0]], sampling,
+                                           seed):
+            t = int(tok[0])
+            if t == sampling.stop_token_id:
                 return
             yield t
-            if int(np.asarray(cache.lengths)[0]) >= self.max_len:
+            if full[0]:
                 return
-            rng, k = jax.random.split(rng)
-            tok, cache = self._decode(
-                self.params, tok, cache, k,
-                temperature=sampling.temperature, top_k=sampling.top_k)

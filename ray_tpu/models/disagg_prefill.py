@@ -26,7 +26,7 @@ the object-store path instead (``RowHandle`` falls back to plasma).
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -54,56 +54,24 @@ class PrefillReplica:
 
     def __init__(self, cfg: TransformerConfig, params, max_len: int,
                  channel: TensorChannel):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.models.decoding import forward_cached, init_cache
+        from ray_tpu.models.continuous_batching import PrefillPrograms
 
         self.cfg = cfg
-        self.params = params
         self.max_len = max_len
         self.channel = channel
-        self._jits: Dict[int, Any] = {}
-
-        def _prefill(params, tokens, length):
-            s = tokens.shape[1]
-            row = init_cache(cfg, 1, s)
-            positions = jnp.arange(s)[None, :]
-            kv_mask = jnp.arange(s)[None, :] < length
-            logits, row, _ = forward_cached(cfg, params, tokens, positions,
-                                            row, kv_mask, kv_mask)
-            last = jnp.take_along_axis(
-                logits, (length - 1)[:, None, None].repeat(
-                    logits.shape[-1], -1), axis=1)[:, 0]
-            return last[0], row.k[:, 0], row.v[:, 0]
-
-        self._impl = _prefill
-        self._jax = jax
+        # the scheduler's own prefill, one program per prompt-length bucket
+        self.programs = PrefillPrograms(cfg, params, max_len)
 
     def prefill(self, tokens: Sequence[int]):
         """Returns (n_tokens, last_logits) on the object path; the KV
         row goes out-of-band through the tensor channel."""
-        import jax
-
-        n = len(tokens)
-        bucket = 16
-        while bucket < n:
-            bucket *= 2
-        bucket = min(bucket, self.max_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = tokens
-        fn = self._jits.get(bucket)
-        if fn is None:
-            fn = jax.jit(self._impl)
-            self._jits[bucket] = fn
-        last, row_k, row_v = fn(self.params, toks,
-                                np.asarray([n], np.int32))
+        last, row_k, row_v, *_ = self.programs._prefill(tokens)
         row = np.zeros(_row_shape(self.cfg, self.max_len),
                        _TRANSPORT_DTYPE)
-        row[0, :, :bucket] = np.asarray(row_k, np.float32)
-        row[1, :, :bucket] = np.asarray(row_v, np.float32)
+        row[0, :, :row_k.shape[1]] = np.asarray(row_k, np.float32)
+        row[1, :, :row_v.shape[1]] = np.asarray(row_v, np.float32)
         self.channel.write(row, timeout=120.0)
-        return n, np.asarray(last, np.float32)
+        return len(tokens), np.asarray(last, np.float32)
 
 
 @ray_tpu.remote(max_concurrency=4)

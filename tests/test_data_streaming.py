@@ -109,16 +109,27 @@ def test_iter_jax_batches_from_pipeline(cluster):
 def test_streaming_stage_overlap(cluster, tmp_path):
     """VERDICT acceptance: stage 2 starts processing early blocks while
     stage 1 is still processing later blocks (no barrier between map
-    stages of a read -> map_batches -> ingest pipeline)."""
-    src = rdata.range(24 * 64, override_num_blocks=24).materialize()
+    stages of a read -> map_batches -> ingest pipeline).
+
+    Shown by an event, not by racing sleeps against the actor pool's
+    start-up: stage 1 does not finish its LAST block before stage 2 has
+    been handed its first. A streaming executor gets there however slow
+    the actors start; one with a barrier between the stages never does,
+    and the last block gives up at its timeout and says so."""
+    blocks, rows = 24, 64
+    src = rdata.range(blocks * rows, override_num_blocks=blocks).materialize()
     src.write_parquet(str(tmp_path / "pq"))
+    marker = str(tmp_path / "stage2_began")
 
     def stage1(b):
-        # long enough that stage 1 outlives stage 2's actor-pool spinup
-        # even on a fully loaded 1-CPU host (overlap must be observable,
-        # not racing actor creation)
-        time.sleep(0.75)
+        saw_stage2 = True
+        if b["id"].max() == blocks * rows - 1:  # the last block
+            deadline = time.time() + 90.0
+            while not os.path.exists(marker) and time.time() < deadline:
+                time.sleep(0.02)
+            saw_stage2 = os.path.exists(marker)
         out = dict(b)
+        out["saw_stage2"] = np.full(len(b["id"]), saw_stage2)
         out["t1_end"] = np.full(len(b["id"]), time.time())
         return out
 
@@ -129,20 +140,25 @@ def test_streaming_stage_overlap(cluster, tmp_path):
             self.blocks = 0
 
         def __call__(self, b):
+            started = time.time()
+            open(marker, "a").close()
             self.blocks += 1
-            time.sleep(0.1)
             out = dict(b)
-            out["t2_start"] = np.full(len(b["id"]), time.time())
+            out["t2_start"] = np.full(len(b["id"]), started)
             return out
 
     ds = (rdata.read_parquet(str(tmp_path / "pq"))
           .map_batches(stage1)
           .map_batches(Stage2, concurrency=2))
-    t1_end, t2_start = [], []
+    t1_end, t2_start, saw_stage2 = [], [], []
     for batch in ds.iter_batches(batch_size=None):
         t1_end.append(batch["t1_end"].max())
         t2_start.append(batch["t2_start"].min())
-    assert len(t1_end) == 24
+        saw_stage2.append(bool(batch["saw_stage2"].all()))
+    assert len(t1_end) == blocks
+    assert all(saw_stage2), (
+        "stages ran serially: stage 1's last block waited 90 s and stage 2 "
+        "had not been given a block")
     # overlap: some stage-2 work began BEFORE the last stage-1 block done
     assert min(t2_start) < max(t1_end), (
         f"stages ran serially: first t2 {min(t2_start):.3f} >= "

@@ -3,8 +3,9 @@
 (``tracing.device_span``), and the program's profiler hook
 (``_private/profiling.py``) that takes such a trace.
 
-One toy ContinuousBatcher run under the hook is traced once for the module;
-the trace is read back with the benchmark's own reader
+One toy run of each engine (the scheduler over its slots, and over
+`PagedBatcher`'s pages) under the hook is traced once for the module; the
+trace is read back with the benchmark's own reader
 (``benchmarks/program_spans.py``), so the names the program writes and the
 names the benchmark reads are held together here. CPU, no cluster.
 """
@@ -26,6 +27,7 @@ from ray_tpu._private.workers import default_worker
 from ray_tpu.models import transformer as T
 from ray_tpu.models.continuous_batching import ContinuousBatcher
 from ray_tpu.models.decoding import SamplingParams
+from ray_tpu.models.paged_kv import PagedBatcher
 from ray_tpu.observability import schema, tracing
 
 SLOTS = 2
@@ -63,13 +65,15 @@ def _stream_two_items(client):
     assert reply == {"returns": [], "streaming_done": 2}
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
+@pytest.fixture(scope="module", params=[ContinuousBatcher, PagedBatcher],
+                ids=["slots", "pages"])
+def traced(request, tmp_path_factory):
     """{"parsed": the trace as program_spans reads it, "bridge": the context
-    of a recorded span(), "stream": the fake stream client}."""
+    of a recorded span(), "stream": the fake stream client}. Once for each
+    kind of cache: the spans are the scheduler's, whatever keeps the rows."""
     cfg = T.config("debug", dtype=jnp.float32, param_dtype=jnp.float32)
     params = T.init_params(cfg, jax.random.key(0))
-    cb = ContinuousBatcher(cfg, params, max_len=64, slots=SLOTS)
+    cb = request.param(cfg, params, max_len=64, slots=SLOTS)
     sp = SamplingParams(max_tokens=6)
     logdir = str(tmp_path_factory.mktemp("xprof"))
     client = _AckingClient()
@@ -93,6 +97,10 @@ def traced(tmp_path_factory):
                 pass
             assert untraced is None
         finally:
+            # the pump has left its last step and closed its spans before
+            # the trace ends: a span still open at the stop is not recorded,
+            # and its children would stand alone
+            cb.shutdown()
             path = profiling.stop_tpu_profile()
     finally:
         cb.shutdown()

@@ -15,6 +15,7 @@ import ray_tpu
 from ray_tpu.models import transformer as T
 from ray_tpu.models.continuous_batching import ContinuousBatcher
 from ray_tpu.models.decoding import Generator, SamplingParams
+from ray_tpu.models.paged_kv import PagedBatcher
 
 
 def _tiny_cfg():
@@ -150,35 +151,55 @@ class TestDonatedCache:
         assert all(c.k.is_deleted() and c.v.is_deleted() for c in seen)
         assert not cb.cache.k.is_deleted()
 
+    @pytest.mark.parametrize("via", ["future", "stream"])
+    @pytest.mark.parametrize("engine", [ContinuousBatcher, PagedBatcher],
+                             ids=["slots", "pages"])
     def test_a_failed_step_fails_its_requests_and_the_next_is_served(
-            self, tiny_model):
+            self, tiny_model, engine, via):
         """A step that raises after it consumed the cache: the active
-        requests fail, the pump starts again from an empty cache (every
-        slot is free), and the next request gets the static Generator's
-        completion, not an error about a deleted array."""
+        requests fail, each with the exception (a stream too: not a short
+        answer), the pump starts again from an empty cache (every slot is
+        free), and the next request gets the static Generator's completion,
+        not an error about a deleted array. Whichever cache the scheduler
+        runs over."""
         cfg, params = tiny_model
         sp = SamplingParams(max_tokens=6)
         ref = Generator(cfg, params, max_len=64).generate([[7, 8, 9]], sp)
-        cb = ContinuousBatcher(cfg, params, max_len=64, slots=2)
+        cb = engine(cfg, params, max_len=64, slots=2)
         step, raised = cb._decode_jit, []
 
         def raises_once(*args):
             out = step(*args)  # the cache in `args` is consumed
-            if not raised:
+            if not raised and int(args[6].sum()) == 2:  # both are active
                 raised.append(True)
                 raise RuntimeError("the device fell over")
             return out
 
+        def doomed(prompt, errors):
+            sampling = SamplingParams(max_tokens=40)
+            try:
+                if via == "stream":
+                    list(cb.submit_stream(prompt, sampling))
+                else:
+                    cb.submit(prompt, sampling).result(timeout=120)
+            except RuntimeError as e:
+                errors.append(e)
+
         cb._decode_jit = raises_once
+        errors = []
         try:
-            doomed = [cb.submit([5, 17, 3], sp), cb.submit([1, 2], sp)]
-            for fut in doomed:
-                with pytest.raises(RuntimeError, match="fell over"):
-                    fut.result(timeout=120)
+            callers = [threading.Thread(target=doomed, args=(p, errors),
+                                        daemon=True)
+                       for p in ([5, 17, 3], [1, 2])]
+            for caller in callers:
+                caller.start()
+            for caller in callers:
+                caller.join(timeout=120)
             assert cb.submit([7, 8, 9], sp).result(timeout=120) == ref[0]
             stats = dict(cb.stats)
         finally:
             cb.shutdown()
+        assert [str(e) for e in errors] == ["the device fell over"] * 2
         assert stats["failed"] == 2 and stats["finished"] == 1
         assert not cb.cache.k.is_deleted()
 

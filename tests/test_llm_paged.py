@@ -4,6 +4,7 @@ kv_transfer, which the reference LLM library defers to —
 llm/_internal/serve/engines/vllm/)."""
 
 import time
+from concurrent.futures import Future
 
 import pytest
 
@@ -12,7 +13,7 @@ import jax.numpy as jnp
 
 import ray_tpu
 from ray_tpu.models import transformer as T
-from ray_tpu.models.continuous_batching import ContinuousBatcher
+from ray_tpu.models.continuous_batching import ContinuousBatcher, _Request
 from ray_tpu.models.decoding import SamplingParams
 from ray_tpu.models.paged_kv import PagedBatcher, PagedKV, prefix_keys
 
@@ -159,6 +160,39 @@ class TestPagedBatcher:
             assert pb.stats["preempted"] >= 1, pb.stats
         finally:
             pb.shutdown()
+
+
+    @pytest.mark.parametrize("max_len, max_tokens, preempt_at", [
+        (64, 12, (6, 11)),
+        # at the last row: it comes back with max_len tokens, which fill
+        # the row and need no page for a decode write
+        (32, 64, (31,)),
+    ])
+    def test_a_second_preemption_resumes_from_prompt_plus_output(
+            self, tiny_model, max_len, max_tokens, preempt_at):
+        """Preempted (at these lengths of its row), a request re-prefills
+        over its prompt and every token it has emitted, each once (the
+        second preemption used to append the whole output to tokens that
+        already held its start), and ends with the tokens of an undisturbed
+        run."""
+        cfg, params = tiny_model
+        prompt, sp = [5, 17, 3], SamplingParams(max_tokens=max_tokens)
+        pb = PagedBatcher(cfg, params, max_len=max_len, slots=2,
+                          page_size=16)
+        want = pb.submit(prompt, sp).result(timeout=120)
+        pb.shutdown()  # the pump is gone: the steps below are the test's
+        req = _Request(list(prompt), sp, Future(), None)
+        pb._waiting.put(req)
+        for at in preempt_at:
+            while req.slot < 0 or pb._host_len[req.slot] < at:
+                pb._step()
+            pb._preempt(req.slot)
+            assert req.tokens == prompt + req.out
+        while not req.future.done():
+            pb._step()
+        assert pb.stats["preempted"] == len(preempt_at)
+        assert pb.stats["failed"] == 0
+        assert req.future.result() == want
 
 
 class TestDisaggregatedPrefill:

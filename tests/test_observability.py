@@ -552,14 +552,14 @@ class TestPagedKvAdmitExhaustion:
         # RuntimeError instead of requeueing
         assert kv.rc[pA] == 0 and kv.rc[pB] == 0
         assert pA in kv.free and pB in kv.free
-        assert req.pages == []
+        assert req.kv.pages == []
         assert not req.future.done(), req.future.exception()
         assert pb._waiting.qsize() == 2
         # FIFO kept: the requeue goes to the FRONT — a tail requeue
         # would let every later small request leapfrog forever and the
         # big request's future would never resolve
         assert pb._waiting.queue[0] is req
-        assert len(pb._free_slots) == 2  # the slot went back too
+        assert len(pb._free) == 2  # the slot went back too
 
         # pool pressure relieved → the requeued request admits cleanly,
         # and the small one after it
@@ -567,7 +567,7 @@ class TestPagedKvAdmitExhaustion:
             kv.decref(p)
         pb._admit()
         assert pb._waiting.qsize() == 0
-        assert len(req.pages) == 4 and req.slot >= 0
+        assert len(req.kv.pages) == 4 and req.slot >= 0
         assert not req.future.done()
         assert small.slot >= 0 and not small.future.done()
 

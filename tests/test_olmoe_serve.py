@@ -291,7 +291,8 @@ def test_a_dense_model_pays_nothing():
 
 
 def test_paged_batcher_serves_the_sparse_model(model):
-    """Case 9a: `PagedBatcher` calls `_block_cached` itself."""
+    """Case 9a: the scheduler over `PagedBatcher`'s pages: `Generator`'s
+    tokens, and the counters of case 7 (the paged step's `aux` is counted)."""
     from ray_tpu.models.paged_kv import PagedBatcher
 
     cfg, params = model
@@ -303,9 +304,17 @@ def test_paged_batcher_serves_the_sparse_model(model):
     try:
         got = [f.result(timeout=120)
                for f in [paged.submit(p, sp) for p in prompts]]
+        stats = dict(paged.stats)
     finally:
         paged.shutdown()
     assert got == want
+    # no prompt shares a full page with another: each is prefilled whole
+    assert stats["prefill_tokens"] == sum(map(len, prompts))
+    rows = stats["prefill_tokens"] + len(prompts) * (sp.max_tokens - 1)
+    assert stats["moe_rows"] == rows
+    assert stats["moe_assignments"] == \
+        rows * cfg.experts_per_token * cfg.layers
+    assert sum(stats["moe_expert_load"]) == stats["moe_assignments"]
 
 
 def test_disaggregated_prefill_serves_the_sparse_model(ray_start_regular,
