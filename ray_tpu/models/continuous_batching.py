@@ -89,6 +89,15 @@ def _sample_per_slot(logits, rng, temps, topks):
 
 
 @dataclasses.dataclass
+class _Dispatched:
+    """A decode step that was dispatched and whose tokens the host has not
+    read: the device arrays, their copies to the host under way."""
+    toks: Any  # [slots] sampled tokens: the next step's input as it is
+    load: list  # the program's expert load ([] from a dense model)
+    reqs: Dict[int, "_Request"]  # slot -> the request the step advanced
+
+
+@dataclasses.dataclass
 class _Request:
     tokens: List[int]
     sampling: SamplingParams
@@ -170,6 +179,7 @@ class ContinuousBatcher(PrefillPrograms):
         # the pump thread; only _waiting and stats cross threads
         self._active: Dict[int, _Request] = {}
         self._free = list(range(slots))
+        self._inflight: Optional[_Dispatched] = None
         self._wake = threading.Event()
         self._shutdown = False
         self._rng = jax.random.key(seed)
@@ -178,11 +188,22 @@ class ContinuousBatcher(PrefillPrograms):
         self._temps = np.zeros(slots, np.float32)
         self._topks = np.zeros(slots, np.int32)
         self._last_tok = np.zeros(slots, np.int32)
+        # rows in the slot once every dispatched step has run: where the
+        # next step writes
         self._host_len = np.zeros(slots, np.int64)
-        # stats (observable by tests/metrics)
+        # tokens the slot's request may still be given here, by its
+        # `max_tokens` and by the slot's rows: the count that ends it
+        self._left = np.zeros(slots, np.int64)
+        # what a step takes that changes at an admit or a retire only, as
+        # it was last uploaded: name -> (host value, device array)
+        self._uploaded: Dict[str, tuple] = {}
+        # stats (observable by tests/metrics). `steps_ahead`: steps
+        # dispatched while the step before them was unread;
+        # `tokens_discarded`: slot-steps thrown away behind a stop token
         self.stats = {"admitted": 0, "finished": 0, "failed": 0,
                       "steps": 0, "max_active": 0, "tokens_out": 0,
-                      "last_admit_step": -1}
+                      "last_admit_step": -1, "steps_ahead": 0,
+                      "tokens_discarded": 0}
         if cfg.num_experts:
             # what the experts received from real rows (prompt positions,
             # active slots) and how many such rows there were, so that
@@ -333,8 +354,11 @@ class ContinuousBatcher(PrefillPrograms):
         install: nothing."""
 
     def _make_room(self) -> None:
-        """Before a step: every active slot can take one more row. A slot
-        is `max_len` rows long and `_emit` stops at the last: nothing."""
+        """Before a step: every slot it advances (`_next_slots`) can take
+        one more row. A cache that has to take a slot back for that reads
+        the step in flight first (`_drain`): the request it requeues must
+        hold every token computed for it. A slot is `max_len` rows long
+        and its request is counted out at the last: nothing."""
 
     def _refused_for_now(self, req: _Request, e: Exception) -> bool:
         """Whether a failed admit is the cache saying "not yet": the
@@ -398,16 +422,22 @@ class ContinuousBatcher(PrefillPrograms):
             self._temps[slot] = req.sampling.temperature
             self._topks[slot] = req.sampling.top_k
             self._host_len[slot] = len(req.tokens)
+            # the first token and one a step, a step a row: a prompt of
+            # `max_len` (a preempted request come back) gets the first alone
+            self._left[slot] = min(
+                req.sampling.max_tokens - len(req.out),
+                self.max_len + 1 - len(req.tokens))
             self._last_tok[slot] = first_tok
             self._active[slot] = req
             self.stats["admitted"] += 1
             self._emit(req, first_tok)
 
     @staticmethod
-    def _fetch_ahead(load: list) -> None:
-        """Start the load's copy to the host with the program's dispatch, so
-        that reading it after the tokens costs no second round trip."""
-        for array in load:
+    def _fetch_ahead(arrays: list) -> None:
+        """Start the arrays' copies to the host with the program's dispatch:
+        a step's tokens are then on their way while the next step runs, and
+        reading the expert load after them costs no second round trip."""
+        for array in arrays:
             array.copy_to_host_async()
 
     def _count_experts(self, load: list, rows: int) -> None:
@@ -428,9 +458,11 @@ class ContinuousBatcher(PrefillPrograms):
 
     def _emit(self, req: _Request, tok: int) -> None:
         """Deliver one sampled token; free the slot when the request is
-        done (stop token / max_tokens / out of cache room)."""
+        done: at a stop token, or counted out (`_left`: max_tokens, or the
+        slot's last row — the NEXT decode would write at position
+        `max_len`, matching Generator.generate's lengths >= max_len
+        stop)."""
         stop = req.sampling.stop_token_id
-        done = False
         if stop is not None and tok == stop:
             done = True
         else:
@@ -438,14 +470,8 @@ class ContinuousBatcher(PrefillPrograms):
             if req.stream_q is not None:
                 req.stream_q.put(int(tok))
             self.stats["tokens_out"] += 1
-            if len(req.out) >= req.sampling.max_tokens:
-                done = True
-        # prompt_len + emitted tokens occupy the row; the NEXT decode
-        # writes at position lengths[slot], which must stay < max_len —
-        # matching Generator.generate's lengths >= max_len stop
-        if not done and req.slot >= 0:
-            if self._host_len[req.slot] >= self.max_len:
-                done = True
+            self._left[req.slot] -= 1
+            done = self._left[req.slot] <= 0
         if done:
             self._retire(req)
 
@@ -476,7 +502,8 @@ class ContinuousBatcher(PrefillPrograms):
 
     def _pump(self) -> None:
         while not self._shutdown:
-            if not self._active and self._waiting.empty():
+            if not self._active and self._inflight is None \
+                    and self._waiting.empty():
                 with device_span(spans.ENGINE_IDLE):
                     self._wake.wait(timeout=0.1)
                 self._wake.clear()
@@ -487,6 +514,14 @@ class ContinuousBatcher(PrefillPrograms):
                 with device_span(spans.ENGINE_STEP, step=self.stats["steps"]):
                     self._step()
             except Exception as e:  # noqa: BLE001 — fail active requests
+                # a step in flight that is whole (`_step` leaves none that
+                # follows a failed read) was dispatched before the one that
+                # raised: its tokens go out first, so the requests fail
+                # holding what they would hold had no step run ahead
+                try:
+                    self._drain()
+                except Exception:  # noqa: BLE001 — the device is gone: `e`
+                    pass
                 for req in list(self._active.values()):
                     self._fail(req, e)
                     self._vacate(req)
@@ -501,29 +536,83 @@ class ContinuousBatcher(PrefillPrograms):
                 logging.getLogger(__name__).exception(
                     "continuous-batching step failed")
 
+    def _next_slots(self) -> List[int]:
+        """The slots the next step advances, in order of admission: the
+        active ones whose request may be given a token beyond the one in
+        flight for it. The others end, by count, when the step in flight
+        is read."""
+        pending = self._inflight.reqs if self._inflight else ()
+        return [s for s in self._active if self._left[s] > (s in pending)]
+
+    def _on_device(self, name: str, host: np.ndarray):
+        """`host` as a device array, uploaded when it differs from what
+        was uploaded under `name` last."""
+        last = self._uploaded.get(name)
+        if last is None or not np.array_equal(last[0], host):
+            kept = host.copy()  # `host` is written in place at an admit
+            last = self._uploaded[name] = (kept, jnp.asarray(kept))
+        return last[1]
+
     def _step(self) -> None:
-        self._admit()
-        if not self._active:
-            return
+        """One pass of the pump: dispatch the next decode step, THEN read
+        and emit the one before it. First, on a drained loop, whatever is
+        due that is not counting."""
+        ending = len(self._active) - len(self._next_slots())
+        if not self._waiting.empty() and (self._free or ending):
+            # an admit is due, now or once the step in flight is read and
+            # ends a slot: the request joins the step it joins with no
+            # step ahead
+            self._drain()
+            self._admit()
         self._make_room()
-        if not self._active:
-            return  # the cache took its last slot back
-        with device_span(spans.ENGINE_DECODE_DISPATCH,
-                         active=len(self._active)):
+        slots = self._next_slots()
+        if not slots:
+            # every active slot ends with the step in flight, or the cache
+            # took its last slot back
+            self._drain()
+            return
+        ahead = int(self._inflight is not None)
+        with device_span(spans.ENGINE_DECODE_DISPATCH, active=len(slots),
+                         ahead=ahead):
             active_mask = np.zeros(self.slots, bool)
-            for slot in self._active:
-                active_mask[slot] = True
+            active_mask[slots] = True
+            self._host_len[slots] += 1
             self._rng, k = jax.random.split(self._rng)
             toks, load = self._decode(
-                jnp.asarray(self._last_tok), k, jnp.asarray(self._temps),
-                jnp.asarray(self._topks), jnp.asarray(active_mask))
-            self._fetch_ahead(load)
+                # the step before's tokens where they are; after a drain
+                # the host has them all, an admit's first token among them
+                self._inflight.toks if ahead else jnp.array(self._last_tok),
+                k, self._on_device("temps", self._temps),
+                self._on_device("topks", self._topks),
+                self._on_device("active_mask", active_mask))
+            self._fetch_ahead([toks, *load])
+        newer = _Dispatched(toks, load, {s: self._active[s] for s in slots})
         self.stats["steps"] += 1
+        self.stats["steps_ahead"] += ahead
+        # nothing is in flight while the step before is read: if that
+        # fails, the failure path must not emit `newer` behind the hole
+        self._drain()
+        self._inflight = newer
+
+    def _drain(self) -> bool:
+        """Read and emit the step in flight, if there is one: after it the
+        scheduler's state holds every token computed, as if no step ran
+        ahead. Returns whether there was one."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._read(step)
+        return step is not None
+
+    def _read(self, step: _Dispatched) -> None:
         with device_span(spans.ENGINE_SAMPLE_SYNC):
-            toks_np = np.asarray(toks)
-            self._count_experts(load, len(self._active))
+            toks_np = np.asarray(step.toks)
+            self._count_experts(step.load, len(step.reqs))
         with device_span(spans.ENGINE_EMIT):
-            for slot, req in list(self._active.items()):
-                self._host_len[slot] += 1
-                self._last_tok[slot] = int(toks_np[slot])
-                self._emit(req, int(toks_np[slot]))
+            for slot, req in step.reqs.items():
+                if self._active.get(slot) is not req:
+                    # it ended at a stop token while this step was in
+                    # flight: retired as if the step had not run
+                    self.stats["tokens_discarded"] += 1
+                    continue
+                self._last_tok[slot] = tok = int(toks_np[slot])
+                self._emit(req, tok)

@@ -343,7 +343,8 @@ class PagedBatcher(ContinuousBatcher):
     def _decode(self, toks, rng, temps, topks, active_mask):
         toks, self.cache, *load = self._decode_jit(
             self.params, toks, self.cache, rng, temps, topks, active_mask,
-            jnp.asarray(self._page_table))
+            # a copy: growth writes the table while this step is in flight
+            self._on_device("page_table", self._page_table))
         return toks, load
 
     def _pages_to_admit(self, n: int) -> int:
@@ -378,18 +379,24 @@ class PagedBatcher(ContinuousBatcher):
         self._grow_pages()
 
     def _grow_pages(self) -> None:
-        """Per-step lazy growth: every active slot must own the page its
-        next decode write lands in. Pool exhausted → preempt the most
+        """Per-step lazy growth: every slot the step advances must own the
+        page its decode write lands in. Pool exhausted → preempt the most
         recently admitted slot (free its pages, requeue it — it
         re-prefills from prompt+generated when room returns), matching
-        vLLM's recompute-preemption policy."""
-        for slot in sorted(self._active):
+        vLLM's recompute-preemption policy. On a drained loop: the victim's
+        `out` then holds every token computed for it, and a request that
+        the step in flight ended has given its pages back."""
+        for slot in sorted(self._next_slots()):
+            if slot not in self._active:
+                continue  # taken back for an earlier slot's page
             pages = self._active[slot].kv.pages
             need = int(self._host_len[slot]) // self.page_size
             while need >= len(pages):
                 try:
                     page = self.kv.alloc()
                 except RuntimeError:
+                    if self._drain():
+                        return self._grow_pages()
                     # prefer preempting a DIFFERENT slot; if this is the
                     # only active one it preempts itself and returns
                     candidates = [s for s in self._active if s != slot]

@@ -68,7 +68,7 @@ DEVICE_SPANS = {
     ENGINE_PREFILL_DISPATCH: "",
     ENGINE_INSTALL_DISPATCH: "",
     ENGINE_FIRST_TOKEN_SYNC: "",
-    ENGINE_DECODE_DISPATCH: "active",
+    ENGINE_DECODE_DISPATCH: "active, ahead",
     ENGINE_SAMPLE_SYNC: "",
     ENGINE_EMIT: "",
     # handler threads of _private/workers/default_worker.py

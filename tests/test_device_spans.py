@@ -155,6 +155,30 @@ def test_a_step_holds_its_children_on_the_pump_thread(traced):
     assert program_spans.named(parsed, schema.ENGINE_IDLE, line)
 
 
+def test_a_dispatch_says_whether_it_went_ahead_of_the_read(traced):
+    """One decode step is kept in flight: a dispatch is `ahead` when the step
+    before it is still unread, and that step is then read in the same
+    `engine.step`, AFTER the dispatch. A dispatch is not ahead only behind
+    an admit (the loop is drained for one)."""
+    parsed = traced["parsed"]
+    steps = program_spans.named(parsed, schema.ENGINE_STEP)
+    admits = program_spans.named(parsed, schema.ENGINE_ADMIT)
+    syncs = program_spans.named(parsed, schema.ENGINE_SAMPLE_SYNC)
+    dispatches = program_spans.named(parsed, schema.ENGINE_DECODE_DISPATCH)
+    assert {d[4]["ahead"] for d in dispatches} == {0, 1}
+    for dispatch in dispatches:
+        step = next(s for s in steps if _inside(dispatch, s))
+        read = [c for c in syncs if _inside(c, step)]
+        if dispatch[4]["ahead"]:
+            assert len(read) == 1 and read[0][1] >= dispatch[1] + dispatch[2]
+        else:
+            assert any(_inside(a, step) for a in admits)
+            assert all(c[1] + c[2] <= dispatch[1] for c in read)
+    # (3 waves of SLOTS requests, 6 tokens each: 5 steps a wave, 4 ahead)
+    assert sum(d[4]["ahead"] for d in dispatches) >= \
+        len(dispatches) - len(admits) > 0
+
+
 def test_an_admit_holds_its_three_children_inside_a_step(traced):
     parsed = traced["parsed"]
     admits = program_spans.named(parsed, schema.ENGINE_ADMIT)
@@ -181,10 +205,11 @@ def test_queued_ms_grows_when_the_slots_are_full(traced):
 
 def test_the_readers_numbers_on_this_trace(traced):
     parsed = traced["parsed"]
-    assert program_spans.step_period_ms(parsed) > 0
     host = program_spans.host_ms_per_step(parsed)
     steps = program_spans.named(parsed, schema.ENGINE_STEP)
-    assert 0 < host <= max(s[2] for s in steps) / 1e6
+    longest = max(s[2] for s in steps) / 1e6
+    assert 0 < host <= longest
+    assert 0 < program_spans.step_period_ms(parsed) <= longest
     assert program_spans.mean_ms(parsed, schema.ENGINE_ADMIT) > 0
     idle = program_spans.idle_by_span(parsed)
     assert idle and set(idle) <= {

@@ -5,6 +5,7 @@ under staggered arrivals, and the Serve integration."""
 
 import threading
 import time
+from concurrent.futures import Future
 
 import pytest
 
@@ -13,7 +14,7 @@ import jax.numpy as jnp
 
 import ray_tpu
 from ray_tpu.models import transformer as T
-from ray_tpu.models.continuous_batching import ContinuousBatcher
+from ray_tpu.models.continuous_batching import ContinuousBatcher, _Request
 from ray_tpu.models.decoding import Generator, SamplingParams
 from ray_tpu.models.paged_kv import PagedBatcher
 
@@ -123,6 +124,12 @@ class TestContinuousBatcher:
         assert stream_toks == ref
 
 
+BOTH_CACHES = pytest.mark.parametrize(
+    "engine, more", [(ContinuousBatcher, {}),
+                     (PagedBatcher, {"page_size": 16})],
+    ids=["slots", "pages"])
+
+
 class TestDonatedCache:
     """The decode step is given the cache to keep (`_jit_programs`)."""
 
@@ -152,27 +159,30 @@ class TestDonatedCache:
         assert not cb.cache.k.is_deleted()
 
     @pytest.mark.parametrize("via", ["future", "stream"])
-    @pytest.mark.parametrize("engine", [ContinuousBatcher, PagedBatcher],
-                             ids=["slots", "pages"])
+    @BOTH_CACHES
     def test_a_failed_step_fails_its_requests_and_the_next_is_served(
-            self, tiny_model, engine, via):
-        """A step that raises after it consumed the cache: the active
-        requests fail, each with the exception (a stream too: not a short
-        answer), the pump starts again from an empty cache (every slot is
-        free), and the next request gets the static Generator's completion,
-        not an error about a deleted array. Whichever cache the scheduler
-        runs over."""
+            self, tiny_model, engine, more, via):
+        """A step that raises after it consumed the cache, with the step
+        before it still in flight: what that one computed goes out first,
+        then the active requests fail, each with the exception (a stream
+        too: not a short answer), the pump starts again from an empty cache
+        (every slot is free), and the next request gets the static
+        Generator's completion, not an error about a deleted array.
+        Whichever cache the scheduler runs over."""
         cfg, params = tiny_model
         sp = SamplingParams(max_tokens=6)
         ref = Generator(cfg, params, max_len=64).generate([[7, 8, 9]], sp)
-        cb = engine(cfg, params, max_len=64, slots=2)
-        step, raised = cb._decode_jit, []
+        cb = engine(cfg, params, max_len=64, slots=2, **more)
+        step, raised, rows = cb._decode_jit, [], []
 
         def raises_once(*args):
             out = step(*args)  # the cache in `args` is consumed
-            if not raised and int(args[6].sum()) == 2:  # both are active
+            both = int(args[6].sum()) == 2
+            if not raised and both and cb._inflight is not None:
                 raised.append(True)
                 raise RuntimeError("the device fell over")
+            if not raised:
+                rows.append(int(args[6].sum()))
             return out
 
         def doomed(prompt, errors):
@@ -195,13 +205,146 @@ class TestDonatedCache:
                 caller.start()
             for caller in callers:
                 caller.join(timeout=120)
+            failed_with = dict(cb.stats)
             assert cb.submit([7, 8, 9], sp).result(timeout=120) == ref[0]
             stats = dict(cb.stats)
         finally:
             cb.shutdown()
         assert [str(e) for e in errors] == ["the device fell over"] * 2
         assert stats["failed"] == 2 and stats["finished"] == 1
+        # a first token each and a token a row of every step that ran, the
+        # one in flight when the next raised among them
+        assert rows[-1] == 2 and failed_with["tokens_out"] == 2 + sum(rows)
         assert not cb.cache.k.is_deleted()
+
+
+def _steps_with_no_step_ahead(arrivals, slots):
+    """Decode steps of the plain loop (read every step before the next is
+    dispatched) over [(queued once this many steps have run, tokens the
+    request gets)]: a pass admits from the queue into the free slots, then
+    one step gives every active request a token; a request's first token
+    comes from its prefill."""
+    todo, waiting, active, steps = sorted(arrivals), [], [], 0
+    while todo or waiting or active:
+        while todo and todo[0][0] <= steps:
+            waiting.append(todo.pop(0)[1])
+        while waiting and len(active) < slots:
+            active.append(waiting.pop(0) - 1)
+            active = [n for n in active if n > 0]
+        assert active or not todo, "the schedule leaves the loop idle"
+        if active:
+            steps += 1
+            active = [n - 1 for n in active if n > 1]
+    return steps
+
+
+class TestOneStepAhead:
+    """The pump dispatches step n+1 before it reads step n: the same tokens
+    in the same steps, requests counted out with no step spent on them, and
+    only a stop token seen a step late."""
+
+    @BOTH_CACHES
+    def test_staggered_mixed_lengths_take_the_plain_loops_steps(
+            self, tiny_model, engine, more):
+        """Requests of mixed `max_tokens` (one counted out by the cache's
+        last row, one by its first token) queued at set step counts: each
+        gets the static Generator's tokens, in exactly the steps of the
+        loop that ran no step ahead, nearly all of them dispatched ahead,
+        none thrown away."""
+        cfg, params = tiny_model
+        slots, max_len = 2, 32
+        arrivals = [  # (queued once this many steps have run, prompt, max)
+            (0, [1, 2, 3], 20), (0, [4, 5], 5), (2, [7, 8, 9, 10], 5),
+            (6, list(range(30, 58)), 12), (8, [11], 1), (8, [12, 13], 9)]
+        gen = Generator(cfg, params, max_len=max_len)
+        want = [gen.generate([p], SamplingParams(max_tokens=m))[0]
+                for _, p, m in arrivals]
+        assert [len(w) for w in want] == [20, 5, 5, 5, 1, 9]  # 28 + 5 > 32
+
+        cb = engine(cfg, params, max_len=max_len, slots=slots, **more)
+        cb.shutdown()  # the pump is gone: the passes below are the test's
+        reqs = [_Request(list(p), SamplingParams(max_tokens=m), Future(), None)
+                for _, p, m in arrivals]
+        todo = sorted(zip([at for at, _, _ in arrivals], range(len(reqs))))
+        for _ in range(200):
+            while todo and todo[0][0] <= cb.stats["steps"]:
+                cb._waiting.put(reqs[todo.pop(0)[1]])
+            cb._step()
+            if not todo and all(r.future.done() for r in reqs):
+                break
+        assert [r.future.result(timeout=0) for r in reqs] == want
+        st = cb.stats
+        assert st["steps"] == _steps_with_no_step_ahead(
+            [(at, len(w)) for (at, _, _), w in zip(arrivals, want)], slots)
+        assert st["tokens_discarded"] == 0 and st["failed"] == 0
+        # a step has none before it only after an admit (4 passes admit)
+        assert st["steps"] == 20 and st["steps_ahead"] == 20 - 4
+        assert cb._inflight is None and not cb._active
+
+    @BOTH_CACHES
+    def test_a_stop_token_ends_the_request_and_its_step_in_flight_is_dropped(
+            self, tiny_model, engine, more):
+        """The stop token is read with the next step already in flight: the
+        stream ends before it, that step's token for the slot is counted
+        as discarded and never emitted, and the slot's next occupant (the
+        install overwrites the row the dropped step wrote) is right token
+        for token."""
+        cfg, params = tiny_model
+        gen = Generator(cfg, params, max_len=64)
+        free_run = gen.generate([[5, 17, 3]], SamplingParams(max_tokens=12))[0]
+        # a token a decode step samples, with more to come by count
+        at = next(i for i in range(2, 10) if free_run[i] not in free_run[:i])
+        stopped = SamplingParams(max_tokens=12, stop_token_id=free_run[at])
+        assert gen.generate([[5, 17, 3]], stopped)[0] == free_run[:at]
+        sp = SamplingParams(max_tokens=8)
+        want_next = gen.generate([[9, 4, 4, 1]], sp)[0]
+
+        cb = engine(cfg, params, max_len=64, slots=1, **more)
+        try:
+            first = cb.submit_stream([5, 17, 3], stopped)
+            head = next(first)  # queued at the generator's first pass
+            following = cb.submit([9, 4, 4, 1], sp)  # waits for the slot
+            assert [head, *first] == free_run[:at]
+            assert following.result(timeout=120) == want_next
+            stats = dict(cb.stats)
+        finally:
+            cb.shutdown()
+        assert stats["tokens_discarded"] == 1
+        assert stats["tokens_out"] == at + len(want_next)
+        # a step a token after each first one, the stop token's, and the
+        # one that was dropped
+        assert stats["steps"] == (at - 1) + 1 + 1 + (len(want_next) - 1)
+        assert stats["finished"] == 2 and stats["failed"] == 0
+
+    @BOTH_CACHES
+    def test_shutdown_with_a_step_in_flight_resolves_every_caller(
+            self, tiny_model, engine, more):
+        cfg, params = tiny_model
+        cb = engine(cfg, params, max_len=512, slots=2, **more)
+        long = SamplingParams(max_tokens=400)
+        streamed, errors = [], []
+
+        def stream():
+            try:
+                streamed.extend(cb.submit_stream([1, 2], long))
+            except RuntimeError as e:
+                errors.append(e)
+
+        try:
+            futs = [cb.submit([5, 17, 3], long), cb.submit([9], long)]
+            caller = threading.Thread(target=stream, daemon=True)
+            caller.start()
+            while cb.stats["steps_ahead"] < 3:
+                time.sleep(0.01)
+        finally:
+            cb.shutdown()
+        assert cb._inflight is not None and not cb._thread.is_alive()
+        for f in futs:
+            with pytest.raises(RuntimeError, match="was shut down"):
+                f.result(timeout=10)
+        caller.join(timeout=10)
+        assert not caller.is_alive() and not errors
+        assert cb.stats["failed"] == 0
 
 
 class TestServeContinuous:

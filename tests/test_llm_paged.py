@@ -162,6 +162,53 @@ class TestPagedBatcher:
             pb.shutdown()
 
 
+    def test_a_slot_is_taken_back_on_a_drained_loop(self, tiny_model):
+        """The pool runs out with a decode step in flight (the pump keeps
+        one): the step is read and emitted before the victim is requeued,
+        so it comes back over its prompt plus every token computed for it,
+        and all three end with the tokens of a pool that never ran out."""
+        cfg, params = tiny_model
+        sp = SamplingParams(max_tokens=40)
+        prompts = [[i, i + 1, i + 2] for i in range(3)]
+        roomy = PagedBatcher(cfg, params, max_len=64, slots=2, page_size=16)
+        try:
+            want = [f.result(timeout=300)
+                    for f in [roomy.submit(p, sp) for p in prompts]]
+            assert roomy.stats["preempted"] == 0
+        finally:
+            roomy.shutdown()
+        pb = PagedBatcher(cfg, params, max_len=64, slots=2, page_size=16,
+                          num_pages=6)
+        preempt, drain, seen, for_room, prompt_of = \
+            pb._preempt, pb._drain, [], [], {}
+
+        def watched_preempt(slot):
+            req = pb._active[slot]
+            prompt = prompt_of.setdefault(id(req), list(req.tokens))
+            preempt(slot)
+            seen.append((pb._inflight is None,
+                         req.tokens == prompt + req.out))
+
+        def watched_drain():
+            starved = not pb.kv.free
+            drained = drain()
+            for_room.append(drained and starved)
+            return drained
+
+        pb._preempt, pb._drain = watched_preempt, watched_drain
+        try:
+            outs = [f.result(timeout=300)
+                    for f in [pb.submit(p, sp) for p in prompts]]
+            stats = dict(pb.stats)
+        finally:
+            pb.shutdown()
+        assert outs == want
+        assert stats["preempted"] == len(seen) >= 1 and stats["failed"] == 0
+        # a step was in flight when the pool ran out, none at the preemption
+        assert any(for_room)
+        assert all(drained and whole for drained, whole in seen), seen
+        assert stats["tokens_discarded"] == 0
+
     @pytest.mark.parametrize("max_len, max_tokens, preempt_at", [
         (64, 12, (6, 11)),
         # at the last row: it comes back with max_len tokens, which fill
@@ -186,6 +233,9 @@ class TestPagedBatcher:
         for at in preempt_at:
             while req.slot < 0 or pb._host_len[req.slot] < at:
                 pb._step()
+            # the step that wrote row `at - 1` is in flight: a slot is taken
+            # back on a drained loop, as `_grow_pages` does it
+            assert pb._drain() and pb._host_len[req.slot] == at
             pb._preempt(req.slot)
             assert req.tokens == prompt + req.out
         while not req.future.done():
