@@ -127,9 +127,12 @@ class PrefillPrograms:
 
     def _prefill_impl(self, params, tokens, length):
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
-        against a standalone single-row cache; a sparse model's program
-        returns a fourth value, the experts' load [E] from the prompt's
-        real positions (a dense model's callers unpack three)."""
+        against a standalone single-row cache. A stateful model's program
+        (`cfg.stateful`) returns the row's state [L, ...] next, as the
+        prompt's TRUE last position left it; a sparse model's then what its
+        expert layers counted (`forward_cached`'s `aux`), the experts' load
+        [E] from the prompt's real positions first (a dense model's callers
+        unpack three)."""
         s = tokens.shape[1]
         row_cache = init_cache(self.cfg, 1, s)
         positions = jnp.arange(s)[None, :]
@@ -139,8 +142,14 @@ class PrefillPrograms:
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
-        return (last[0], row_cache.k[:, 0], row_cache.v[:, 0],
-                *aux.values())
+        return last[0], *self._row_of(row_cache), *aux.values()
+
+    @staticmethod
+    def _row_of(row_cache: KVCache) -> tuple:
+        """A one-sequence cache as a prefill program returns it: (row_k,
+        row_v) and, from a stateful model, the row's state."""
+        state = () if row_cache.state is None else (row_cache.state[:, 0],)
+        return row_cache.k[:, 0], row_cache.v[:, 0], *state
 
     @staticmethod
     def _bucket(n: int) -> int:
@@ -204,6 +213,16 @@ class ContinuousBatcher(PrefillPrograms):
                       "steps": 0, "max_active": 0, "tokens_out": 0,
                       "last_admit_step": -1, "steps_ahead": 0,
                       "tokens_discarded": 0}
+        if cfg.stateful:
+            # prefills whose state went into a slot with their rows, and
+            # slots whose state was cleared when their request left
+            self.stats.update(state_installs=0, state_resets=0)
+        # A list while someone wants to know which expert each row was given
+        # (a program's `expert_choice`, from a router that gives one): every
+        # admit and every step read appends ({slot: request}, choices
+        # [layers, rows]); rows are a prefill's positions or a step's slots.
+        # None: nothing is kept.
+        self.route_log: Optional[list] = None
         if cfg.num_experts:
             # what the experts received from real rows (prompt positions,
             # active slots) and how many such rows there were, so that
@@ -280,17 +299,34 @@ class ContinuousBatcher(PrefillPrograms):
         self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(2,))
         self._install_jit = jax.jit(self._install_impl,
                                     donate_argnums=(0,))
+        self._reset_state_jit = jax.jit(self._reset_state_impl,
+                                        donate_argnums=(0,))
 
-    def _install_impl(self, cache: KVCache, row_k, row_v, slot, length):
+    def _install_impl(self, cache: KVCache, row_k, row_v, slot, length,
+                      row_state=None):
         """Scatter a prefilled row into its slot of the big cache (the
         row is padded to max_len, so the whole slot — including stale
-        data from its previous occupant — is overwritten)."""
+        data from its previous occupant — is overwritten), and a stateful
+        model's `row_state` [L, ...] with it."""
         k = jax.lax.dynamic_update_slice(
             cache.k, row_k[:, None], (0, slot, 0, 0, 0))
         v = jax.lax.dynamic_update_slice(
             cache.v, row_v[:, None], (0, slot, 0, 0, 0))
         lengths = cache.lengths.at[slot].set(length)
-        return KVCache(k, v, lengths)
+        return KVCache(k, v, lengths,
+                       self._slot_state(cache.state, slot, row_state))
+
+    @staticmethod
+    def _slot_state(state, slot, row_state):
+        """The state stack [L, slots, ...] with `slot`'s replaced by a
+        prefill's `row_state` [L, ...]; a model without state has neither."""
+        if row_state is None:
+            return state
+        return state.at[:, slot].set(row_state.astype(state.dtype))
+
+    def _reset_state_impl(self, cache: KVCache, slot):
+        """`slot`'s state back to a new sequence's (zeros)."""
+        return cache._replace(state=cache.state.at[:, slot].set(0))
 
     def _decode_impl(self, params, toks, cache, rng, temps, topks,
                      active_mask, *, access=_write_stack):
@@ -302,11 +338,13 @@ class ContinuousBatcher(PrefillPrograms):
         logits, cache, aux = forward_cached(
             self.cfg, params, toks[:, None], positions, cache, kv_mask,
             active_mask[:, None], access)
-        nxt = _sample_per_slot(logits[:, 0], rng, temps, topks)
-        # only ACTIVE slots advance; free rows stay put so a later
-        # install never races a drifting length past max_len
+        with jax.named_scope("sample"):
+            nxt = _sample_per_slot(logits[:, 0], rng, temps, topks)
+        # only ACTIVE slots advance (their state too: `forward_cached` was
+        # given the mask); free rows stay put so a later install never
+        # races a drifting length past max_len
         new_len = jnp.where(active_mask, cache.lengths + 1, cache.lengths)
-        return nxt, KVCache(cache.k, cache.v, new_len), *aux.values()
+        return nxt, cache._replace(lengths=new_len), *aux.values()
 
     def _pad_row(self, row_k, row_v):
         """A prefilled row [L, S, kvH, D] out to max_len, as install takes
@@ -328,18 +366,29 @@ class ContinuousBatcher(PrefillPrograms):
         return init_cache(self.cfg, self.slots, self.max_len)
 
     def _prefill_into(self, req: _Request, slot: int):
-        """Put the prompt's K/V into `slot`. Returns (logits at its last
-        position [V], the program's expert load, the rows that were
-        computed). What it takes for the request it takes before it can
-        fail or leaves with the request, for `_release` to give back."""
+        """Put the prompt's K/V (and state) into `slot`. Returns (logits at
+        its last position [V], what the program's expert layers counted,
+        the rows that were computed). What it takes for the request it
+        takes before it can fail or leaves with the request, for `_release`
+        to give back."""
         with device_span(spans.ENGINE_PREFILL_DISPATCH):
-            last_logits, row_k, row_v, *load = self._prefill(req.tokens)
+            last_logits, row_k, row_v, *rest = self._prefill(req.tokens)
+            state, load = self._row_state(rest)
             self._fetch_ahead(load)
         with device_span(spans.ENGINE_INSTALL_DISPATCH):
             self.cache = self._install_jit(
                 self.cache, *self._pad_row(row_k, row_v), slot,
-                len(req.tokens))
+                len(req.tokens), *state)
         return last_logits, load, len(req.tokens)
+
+    def _row_state(self, rest: list):
+        """What a prefill program returned after its rows, split into the
+        row's state (`[state]`; `[]` from a model without) and what its
+        expert layers counted; the state is counted as installed."""
+        n = int(self.cfg.stateful)
+        if n:
+            self.stats["state_installs"] += 1
+        return rest[:n], rest[n:]
 
     def _decode(self, toks, rng, temps, topks, active_mask):
         """One decode step over the cache, which the step keeps. Returns
@@ -351,7 +400,19 @@ class ContinuousBatcher(PrefillPrograms):
     def _release(self, req: _Request) -> None:
         """Give back what the cache holds for a request that leaves its
         slot or never got one. A slot's rows are overwritten by the next
-        install: nothing."""
+        install and masked until then: nothing. A stateful model's slot goes
+        back to a new sequence's zeros at once (behind whatever step is in
+        flight: the device runs them in order). No result depends on it: a
+        step keeps a free slot's state as it is and the next install writes
+        over it; a slot nobody holds then holds nobody's data, and
+        `state_resets` beside `state_installs` shows every slot that was
+        taken was given back."""
+        self._reset_state(req.slot)
+
+    def _reset_state(self, slot: int) -> None:
+        if self.cfg.stateful and slot >= 0:
+            self.cache = self._reset_state_jit(self.cache, slot)
+            self.stats["state_resets"] += 1
 
     def _make_room(self) -> None:
         """Before a step: every slot it advances (`_next_slots`) can take
@@ -416,6 +477,7 @@ class ContinuousBatcher(PrefillPrograms):
                     jnp.asarray([req.sampling.top_k], np.int32))
                 first_tok = int(np.asarray(first)[0])
                 self._count_experts(load, rows)
+                self._log_routes(load, {slot: req})
             # inside the span: an admit is over when its first token is out
             req.slot = slot
             self.stats["last_admit_step"] = self.stats["steps"]
@@ -455,6 +517,12 @@ class ContinuousBatcher(PrefillPrograms):
                                   load.tolist())]
         self.stats["moe_assignments"] += int(load.sum())
         self.stats["moe_rows"] += rows
+
+    def _log_routes(self, counted: list, reqs: Dict[int, _Request]) -> None:
+        """Keep a program's `expert_choice` (`counted[1]`, behind the load)
+        for whoever set `route_log`; called where `_count_experts` is."""
+        if self.route_log is not None and len(counted) > 1:
+            self.route_log.append((dict(reqs), np.asarray(counted[1])))
 
     def _emit(self, req: _Request, tok: int) -> None:
         """Deliver one sampled token; free the slot when the request is
@@ -572,11 +640,13 @@ class ContinuousBatcher(PrefillPrograms):
             self._drain()
             return
         ahead = int(self._inflight is not None)
+        self._host_len[slots] += 1
+        # `rows`: the positions the step's sequences hold, its own among
+        # them: what its attention has to read
         with device_span(spans.ENGINE_DECODE_DISPATCH, active=len(slots),
-                         ahead=ahead):
+                         ahead=ahead, rows=int(self._host_len[slots].sum())):
             active_mask = np.zeros(self.slots, bool)
             active_mask[slots] = True
-            self._host_len[slots] += 1
             self._rng, k = jax.random.split(self._rng)
             toks, load = self._decode(
                 # the step before's tokens where they are; after a drain
@@ -607,6 +677,7 @@ class ContinuousBatcher(PrefillPrograms):
         with device_span(spans.ENGINE_SAMPLE_SYNC):
             toks_np = np.asarray(step.toks)
             self._count_experts(step.load, len(step.reqs))
+            self._log_routes(step.load, step.reqs)
         with device_span(spans.ENGINE_EMIT):
             for slot, req in step.reqs.items():
                 if self._active.get(slot) is not req:

@@ -42,6 +42,22 @@ class KVCache(NamedTuple):
     k: jax.Array  # [L, B, max_len, kvH, D]
     v: jax.Array  # [L, B, max_len, kvH, D]
     lengths: jax.Array  # [B] — tokens currently in cache per sequence
+    # What a sequence keeps between steps besides its K/V rows, [L, B, ...]:
+    # one position of an attention that looks one token back
+    # (`cfg.stateful`, models/zaya.py). Read and rewritten every step, not
+    # appended to; None for a model whose sequences are their rows, and then
+    # no program has an operand for it.
+    state: Optional[jax.Array] = None
+
+
+def init_state(cfg: TransformerConfig, batch: int, dtype=None):
+    """`KVCache.state` of `batch` new sequences: zeros, or None for a model
+    whose sequences keep nothing but their rows."""
+    if not cfg.stateful:
+        return None
+    from ray_tpu.models import zaya
+
+    return zaya.init_state(cfg, batch, dtype or cfg.dtype)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -52,6 +68,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
+        state=init_state(cfg, batch, dtype),
     )
 
 
@@ -193,19 +210,40 @@ def layers_to_scan(cfg: TransformerConfig, params):
 
 def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
                   k_cache, v_cache, kv_len_mask, row_mask, layer=None,
-                  access=_write_layer):
-    """One decoder block against cached K/V. Returns (x, k_cache, v_cache,
-    load): the caches with this call's K/V written at `positions` by
-    `access` (`_attention_cached`), and the assignments each expert
-    received from the rows `row_mask` [B,S] marks as real (None for a dense
-    layer, which does not read the mask). With `layer`, `p`'s expert
-    weights are the whole stacks (`layers_to_scan`)."""
-    x, k_cache, v_cache = _attention_cached(
-        cfg, x, p, lora, positions, k_cache, v_cache, kv_len_mask, access)
+                  access=_write_layer, state=None, route=None):
+    """One decoder block against cached K/V. Returns ((x, k_cache, v_cache,
+    state, route), counted): the caches with this call's K/V written at
+    `positions` by `access` (`_attention_cached`), and what the expert layer
+    counted, a tuple: () from a dense layer (which does not read
+    `row_mask`); the assignments each expert received from the rows
+    `row_mask` [B,S] marks as real; and behind them, from a router that
+    chooses one expert a token, every row's choice. With `layer`, `p`'s
+    expert weights are the whole stacks (`layers_to_scan`).
+
+    `state` and `route` are None but for the sublayers that carry them
+    (models/zaya.py): the stateful attention's state stack, which it reads
+    and rewrites at `layer` as `row_mask` says, and the router's
+    representation, which goes from this layer to the next."""
+    if cfg.attention == "cca":
+        from ray_tpu.models import zaya
+
+        x, k_cache, v_cache, state = zaya.attention_cached(
+            cfg, x, p, positions, k_cache, v_cache, state, kv_len_mask,
+            row_mask, layer, access)
+    else:
+        x, k_cache, v_cache = _attention_cached(
+            cfg, x, p, lora, positions, k_cache, v_cache, kv_len_mask, access)
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
     if cfg.num_experts:
-        out, load = moe_dropless(cfg, y, p, row_mask, layer)
-        return x + out, k_cache, v_cache, load
+        routing, chosen = None, ()
+        if cfg.router == "zaya_mlp":
+            from ray_tpu.models import zaya
+
+            routing, route = zaya.router(cfg, y.reshape(-1, y.shape[-1]), p,
+                                         route)
+            chosen = (routing[1][:, 0],)
+        out, load = moe_dropless(cfg, y, p, row_mask, layer, routing)
+        return (x + out, k_cache, v_cache, state, route), (load, *chosen)
     scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
     with jax.named_scope("mlp"):
         gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
@@ -214,7 +252,7 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
             gate = gate + _lora_delta(y, lora["wi_a"], lora["wi_b"], scale)
         act = jax.nn.silu(gate) * up
         out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
-    return x + out, k_cache, v_cache, None
+    return (x + out, k_cache, v_cache, state, route), ()
 
 
 def forward_cached(cfg: TransformerConfig, params, tokens, positions,
@@ -245,28 +283,41 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     [E]}, the assignments each expert received summed over the layers, from
     the rows `row_mask` [B,S] marks as real (a prompt's positions below its
     length, a decode step's active slots): pad rows and free slots are
-    computed, not counted.
+    computed, not counted. A router that gives a token ONE expert adds
+    {"expert_choice": int32 [L, B*S]}, every row's expert in every layer: a
+    near-tie that rounding flips there is a whole other expert, so a
+    comparison with a reference has to know the route that was taken.
+
+    `row_mask` also tells a stateful attention (`cache.state`, carried and
+    written in place beside `cache.k` / `cache.v`) which of this call's
+    positions is each sequence's last: the state it leaves is that
+    position's, and a sequence with no real row keeps the state it had.
     """
     x = params["embed"].astype(cfg.dtype)[tokens]
     layer_tree, whole = layers_to_scan(cfg, params)
+    route = None
+    if cfg.router == "zaya_mlp":  # the first layer's router adds nothing
+        route = jnp.zeros((tokens.size, cfg.router_hidden), jnp.float32)
 
     def body(carry, layer):
-        x, k_cache, v_cache = carry
-        x, k_cache, v_cache, load = _block_cached(
+        x, k_cache, v_cache, state, route = carry
+        return _block_cached(
             cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
             k_cache, v_cache, kv_len_mask, row_mask, layer["i"],
-            access(layer["i"]))
-        return (x, k_cache, v_cache), load
+            access(layer["i"]), state, route)
 
-    (x, new_k, new_v), loads = lax.scan(
-        body, (x, cache.k, cache.v), layer_tree)
-    aux = {} if loads is None else {"expert_load": loads.sum(0)}
+    (x, new_k, new_v, new_state, _), counted = lax.scan(
+        body, (x, cache.k, cache.v, cache.state, route), layer_tree)
+    aux = dict(zip(("expert_load", "expert_choice"), counted))
+    if aux:
+        aux["expert_load"] = aux["expert_load"].sum(0)
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-    unembed = params.get("unembed")
-    if unembed is None:
-        unembed = params["embed"].T
-    logits = jnp.einsum("bsh,hv->bsv", x, unembed.astype(x.dtype))
-    return logits, KVCache(new_k, new_v, cache.lengths), aux
+    with jax.named_scope("lm_head"):
+        unembed = params.get("unembed")
+        if unembed is None:
+            unembed = params["embed"].T
+        logits = jnp.einsum("bsh,hv->bsv", x, unembed.astype(x.dtype))
+    return logits, KVCache(new_k, new_v, cache.lengths, new_state), aux
 
 
 @dataclasses.dataclass(frozen=True)
@@ -318,7 +369,7 @@ class Generator:
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
-        return last, KVCache(cache.k, cache.v, lengths)
+        return last, cache._replace(lengths=lengths)
 
     def _decode_impl(self, params, tok, cache, rng, *, temperature, top_k):
         b = tok.shape[0]
@@ -328,7 +379,7 @@ class Generator:
             self.cfg, params, tok[:, None], positions, cache, kv_mask,
             jnp.ones((b, 1), bool))
         nxt = _sample(logits[:, 0], rng, temperature, top_k)
-        return nxt, KVCache(cache.k, cache.v, cache.lengths + 1)
+        return nxt, cache._replace(lengths=cache.lengths + 1)
 
     def _decode_loop(self, prompts, sampling: SamplingParams, seed: int):
         """Prefill `prompts`, then yield (tokens [B], full [B]) for each of

@@ -131,6 +131,13 @@ class DisaggPrefillEngine:
     def __init__(self, cfg: TransformerConfig, params, max_len: int = 256,
                  slots: int = 4, page_size: int = 32,
                  num_cpus: float = 0.5):
+        if cfg.stateful:
+            # the channel's row is K and V alone; decoding from it would
+            # start every sequence from a new sequence's state
+            raise ValueError(
+                f"attention {cfg.attention!r} keeps a state beside its K/V "
+                "rows that the KV channel does not carry: serve it from one "
+                "replica (ContinuousBatcher / PagedBatcher)")
         self.channel = TensorChannel(_row_shape(cfg, max_len),
                                      _TRANSPORT_DTYPE)
         self.prefiller = PrefillReplica.options(num_cpus=num_cpus).remote(

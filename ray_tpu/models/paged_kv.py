@@ -58,6 +58,7 @@ from ray_tpu.models.decoding import (
     SamplingParams,
     forward_cached,
     init_cache,
+    init_state,
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import schema as spans
@@ -190,6 +191,10 @@ class PagedBatcher(ContinuousBatcher):
         replica (disaggregated prefill — reference:
         llm/_internal/serve/engines/vllm/kv_transfer/). ``row_k/row_v``
         are [L, S, kvH, D] with S >= len(tokens)."""
+        if self.cfg.stateful:
+            raise ValueError(
+                f"attention {self.cfg.attention!r} keeps a state beside its "
+                "K/V rows and a premade row brings none: submit the prompt")
         return self._enqueue(_Request(
             list(tokens) or [0], sampling or SamplingParams(), Future(),
             None, kv=_Held(premade_row=(
@@ -205,8 +210,11 @@ class PagedBatcher(ContinuousBatcher):
         """Continuation prefill: [1, S] remainder tokens at positions
         prefix_len.., attending over the reused prefix (gathered into
         the row) plus themselves. Returns (last_logits [V], row_k,
-        row_v [L, max_len, kvH, D]) and, from a sparse model, the experts'
-        load from the remainder's real positions."""
+        row_v [L, max_len, kvH, D]), then a stateful model's row state and
+        what a sparse model's expert layers counted over the remainder's
+        real positions, as the scheduler's own prefill does. A stateful
+        model's prompts are prefilled whole (`_prefill_into`), so the state
+        a remainder starts from is a new sequence's."""
         s = tokens.shape[1]
         row = init_cache(self.cfg, 1, self.max_len)
         k = lax.dynamic_update_slice(
@@ -222,12 +230,14 @@ class PagedBatcher(ContinuousBatcher):
         last = jnp.take_along_axis(
             logits, (length - prefix_len - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
-        return last[0], row.k[:, 0], row.v[:, 0], *aux.values()
+        return last[0], *self._row_of(row), *aux.values()
 
     def _install_impl(self, cache: KVCache, row_k, row_v, page_ids, slot,
-                      length):
+                      length, row_state=None):
         """Scatter a [L, max_len] row into the pool at page_ids
-        [pages_per_seq] (trash page 0 for pages not to keep)."""
+        [pages_per_seq] (trash page 0 for pages not to keep). A stateful
+        model's `row_state` goes to the slot, not to a page: the state is
+        kept per sequence, [L, slots, ...], outside the pool."""
         paged = (row_k.shape[0], self.pages_per_seq, self.page_size,
                  *row_k.shape[2:])
         return KVCache(
@@ -235,7 +245,8 @@ class PagedBatcher(ContinuousBatcher):
                 row_k.reshape(paged).astype(cache.k.dtype)),
             cache.v.at[:, page_ids].set(
                 row_v.reshape(paged).astype(cache.v.dtype)),
-            cache.lengths.at[slot].set(length))
+            cache.lengths.at[slot].set(length),
+            self._slot_state(cache.state, slot, row_state))
 
     def _gather_row(self, page_ids):
         """[pages_per_seq] page ids -> dense [L, max_len] row (for
@@ -283,13 +294,16 @@ class PagedBatcher(ContinuousBatcher):
                  self.cfg.kv_heads, self.cfg.hd)
         return KVCache(jnp.zeros(shape, self.cfg.dtype),
                        jnp.zeros(shape, self.cfg.dtype),
-                       jnp.zeros((self.slots,), jnp.int32))
+                       jnp.zeros((self.slots,), jnp.int32),
+                       init_state(self.cfg, self.slots))
 
     def _prefill_into(self, req: _Request, slot: int):
         n = len(req.tokens)
         held = req.kv = req.kv or _Held()
         reused: List[int] = []  # a premade row's KV arrived whole
-        if held.premade_row is None:
+        # a stateful attention reads position t-1, and no page keeps the
+        # state at its boundary: such a prompt is prefilled whole
+        if held.premade_row is None and not self.cfg.stateful:
             reused = self.kv.lookup_prefix(
                 prefix_keys(req.tokens, self.page_size))
             # reuse must leave at least one token to prefill (the last
@@ -315,7 +329,7 @@ class PagedBatcher(ContinuousBatcher):
         page_ids = np.zeros(self.pages_per_seq, np.int32)
         page_ids[:len(held.pages)] = held.pages
 
-        load, rows = [], 0  # a premade row was computed elsewhere
+        state, load, rows = [], [], 0  # a premade row was computed elsewhere
         with device_span(spans.ENGINE_PREFILL_DISPATCH):
             if held.premade_row is not None:
                 row_k, row_v, last_logits = held.premade_row
@@ -326,17 +340,19 @@ class PagedBatcher(ContinuousBatcher):
                 bucket = min(self._bucket(rows), self.max_len - prefix_len)
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :rows] = remainder
-                last_logits, row_k, row_v, *load = \
+                last_logits, row_k, row_v, *rest = \
                     self._prefill_program(bucket)(
                         self.params, jnp.asarray(toks),
                         jnp.asarray([n], np.int32),
                         *self._gather_row(jnp.asarray(page_ids)),
                         jnp.asarray(prefix_len, np.int32))
+                state, load = self._row_state(rest)
                 self._fetch_ahead(load)
                 self.stats["prefill_tokens"] += rows
         with device_span(spans.ENGINE_INSTALL_DISPATCH):
             self.cache = self._install_jit(
-                self.cache, row_k, row_v, jnp.asarray(page_ids), slot, n)
+                self.cache, row_k, row_v, jnp.asarray(page_ids), slot, n,
+                *state)
         self._page_table[slot] = page_ids
         return last_logits, load, rows
 
@@ -358,6 +374,7 @@ class PagedBatcher(ContinuousBatcher):
         for page in req.kv.pages:
             self.kv.decref(page)
         req.kv.pages = []
+        self._reset_state(req.slot)
 
     def _retire(self, req: _Request) -> None:
         if req.slot >= 0:

@@ -71,10 +71,42 @@ class TransformerConfig:
     # learned RMSNorm over the whole q and k projections, before the split
     # into heads and before RoPE (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
+    # Which attention sublayer: "gqa" (grouped-query attention over K/V rows,
+    # the block below) or "cca" (ZAYA1's compressed convolutional attention,
+    # models/zaya.py: attention inside the latent heads x head_dim with two
+    # causal convolutions and a value shift, which keep one position of
+    # state beside the K/V rows). The cached forward alone runs "cca".
+    attention: str = "gqa"
+    # Which router a sparse layer has: "linear" (one matrix, `moe_router`)
+    # or "zaya_mlp" (models/zaya.py: a small MLP on a `router_hidden`-wide
+    # projection that also adds the layer before's projection)
+    router: str = "linear"
+    router_hidden: int = 0
+    # share of each head's dimensions that RoPE rotates ("cca" only)
+    partial_rotary: float = 1.0
+
+    def __post_init__(self):
+        if self.attention not in ("gqa", "cca"):
+            raise ValueError(f"unknown attention {self.attention!r}")
+        if self.router not in ("linear", "zaya_mlp"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if self.router == "zaya_mlp" and not (self.num_experts
+                                              and self.router_hidden):
+            raise ValueError("router 'zaya_mlp' needs num_experts and "
+                             "router_hidden")
+        if self.attention == "gqa" and self.partial_rotary != 1.0:
+            raise ValueError("partial_rotary is read by attention 'cca' "
+                             "alone")
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden // self.heads
+
+    @property
+    def stateful(self) -> bool:
+        """Whether a sequence keeps more than K/V rows between steps
+        (`KVCache.state`)."""
+        return self.attention == "cca"
 
     def flops_per_token(self) -> float:
         """Approx forward+backward FLOPs/token (6*N + attention), for MFU.
@@ -93,6 +125,10 @@ class TransformerConfig:
         per_layer = h * (nh * hd) + 2 * h * (nkv * hd) + (nh * hd) * h + mlp + 2 * h
         if self.qk_norm:
             per_layer += nh * hd + nkv * hd
+        if self.attention == "cca" or self.router == "zaya_mlp":
+            from ray_tpu.models import zaya
+
+            per_layer += zaya.extra_params(self)
         emb = v * h * (1 if self.tie_embeddings else 2)
         return l * per_layer + emb + h
 
@@ -139,6 +175,23 @@ PRESETS: Dict[str, TransformerConfig] = {
         experts_per_token=4, norm_topk_prob=False, qk_norm=True,
         dtype=jnp.float32,
     ),
+    # Zyphra/ZAYA1-8B (models/zaya.py): attention in a compressed latent
+    # with a convolution state, a router that carries its representation
+    # from layer to layer, top-1 of 16 wide experts, a tied 262k vocabulary
+    "zaya1_8b": TransformerConfig(
+        vocab_size=262272, hidden=2048, mlp_hidden=2048, layers=40, heads=8,
+        kv_heads=2, head_dim=128, max_seq=131072, rope_theta=5e6,
+        norm_eps=1e-5, tie_embeddings=True, num_experts=16,
+        experts_per_token=1, norm_topk_prob=False, attention="cca",
+        router="zaya_mlp", router_hidden=256, partial_rotary=0.5,
+    ),
+    "zaya_debug": TransformerConfig(
+        vocab_size=512, hidden=128, mlp_hidden=64, layers=3, heads=4,
+        kv_heads=2, head_dim=16, max_seq=128, remat=False,
+        tie_embeddings=True, num_experts=8, experts_per_token=1,
+        norm_topk_prob=False, attention="cca", router="zaya_mlp",
+        router_hidden=32, partial_rotary=0.5, dtype=jnp.float32,
+    ),
 }
 
 
@@ -180,9 +233,14 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     if cfg.qk_norm:
         blocks["ln_q"] = jnp.ones((l, nh * hd), pd)
         blocks["ln_k"] = jnp.ones((l, nkv * hd), pd)
+    if cfg.attention == "cca" or cfg.router == "zaya_mlp":
+        from ray_tpu.models import zaya
+
+        zaya.init_block_params(cfg, blocks, stack, jax.random.fold_in(key, 13))
     if cfg.num_experts:
         e = cfg.num_experts
-        blocks["router"] = stack(keys[5], (h, e), h)
+        if cfg.router == "linear":
+            blocks["router"] = stack(keys[5], (h, e), h)
         blocks["wi_gate"] = stack(keys[6], (e, h, m), h)
         blocks["wi_up"] = stack(keys[7], (e, h, m), h)
         blocks["wo_mlp"] = stack(keys[8], (e, m, h), m)
@@ -225,9 +283,14 @@ def param_axes(cfg: TransformerConfig) -> Params:
     if cfg.qk_norm:
         block_axes.update({"ln_q": ("layers", "heads"),
                            "ln_k": ("layers", "kv_heads")})
+    if cfg.attention == "cca" or cfg.router == "zaya_mlp":
+        from ray_tpu.models import zaya
+
+        zaya.update_block_axes(cfg, block_axes)
     if cfg.num_experts:
+        if cfg.router == "linear":  # the router stays replicated
+            block_axes["router"] = ("layers", "embed", None)
         block_axes.update({
-            "router": ("layers", "embed", None),  # router stays replicated
             "wi_gate": ("layers", "expert", "embed", "mlp"),
             "wi_up": ("layers", "expert", "embed", "mlp"),
             "wo_mlp": ("layers", "expert", "mlp", "embed"),
@@ -364,7 +427,8 @@ def moe_router(cfg: TransformerConfig, x, p):
     return weights, experts
 
 
-def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None):
+def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
+                 routing=None):
     """Dropless top-k token-choice experts: y [B,S,h] -> (out [B,S,h],
     load [E] int32).
 
@@ -383,13 +447,14 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None):
 
     `p` is one layer's parameters; with `layer` its three expert weights are
     the whole stacks [L,E,...] and `layer` the index to use
-    (`_grouped_matmul` says why a layer scan wants that).
+    (`_grouped_matmul` says why a layer scan wants that). `routing` is
+    `moe_router`'s pair from a router of another kind (`zaya.router`).
     """
     b, s, h = y.shape
     e, k = cfg.num_experts, cfg.experts_per_token
     t = b * s
     x = y.reshape(t, h)
-    weights, experts = moe_router(cfg, x, p)
+    weights, experts = routing or moe_router(cfg, x, p)
     flat = experts.reshape(t * k)  # assignment a = token * k + choice
     with jax.named_scope("moe_experts"):
         order = jnp.argsort(flat, stable=True)  # sorted row -> assignment
@@ -477,6 +542,11 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
     ``mesh`` with a "stage" axis > 1 switches the layer stack to
     pipeline parallelism (ops/pipeline.py) with ``num_microbatches``.
     """
+    if cfg.stateful or cfg.router != "linear":
+        raise ValueError(
+            f"attention {cfg.attention!r} / router {cfg.router!r} run in the "
+            "cached forward alone (decoding.forward_cached): the training "
+            "forward has no such sublayer")
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
     attn_fn = attn_fn or _default_attn(cfg)

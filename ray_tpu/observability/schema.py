@@ -68,9 +68,31 @@ DEVICE_SPANS = {
     ENGINE_PREFILL_DISPATCH: "",
     ENGINE_INSTALL_DISPATCH: "",
     ENGINE_FIRST_TOKEN_SYNC: "",
-    ENGINE_DECODE_DISPATCH: "active, ahead",
+    ENGINE_DECODE_DISPATCH: "active, ahead, rows",
     ENGINE_SAMPLE_SYNC: "",
     ENGINE_EMIT: "",
     # handler threads of _private/workers/default_worker.py
     WORKER_STREAM_YIELD: "",
+}
+
+# Scopes INSIDE the compiled programs (`jax.named_scope` at the sites in
+# models/): an operation's scope is in the compiled program's text
+# (`metadata={op_name=".../cca.attend/..."}`), not in the device trace, which
+# names an operation by its HLO line alone; `benchmarks/scope_ops.py` goes
+# from one to the other. Value: what runs under the scope.
+PROGRAM_SCOPES = {
+    "cca.project": "models/zaya.py: the latent q, k and v projections",
+    "cca.conv": "models/zaya.py: the two convolutions, the q-k mean, the "
+                "value shift, the state's read and write",
+    "cca.attend": "models/zaya.py: L2 norm, RoPE, the row write, attention "
+                  "over the cache (attend_cached inside it), wo",
+    "zaya.router": "models/zaya.py: projection, carried sum, MLP, choice",
+    "moe_router": "models/transformer.py: the linear router and its top-k",
+    "moe_experts": "models/transformer.py: sort, grouped matmuls, unsort",
+    "attend_cached": "models/decoding.py: attention over the cached rows",
+    "mlp": "the dense SwiGLU MLP",
+    "lora": "models/transformer.py: an adapter's two matmuls",
+    "lm_head": "models/decoding.py: the logits' matmul over the vocabulary",
+    "sample": "models/continuous_batching.py: the decode step's sampling "
+              "(the full-vocabulary sort, the draw)",
 }

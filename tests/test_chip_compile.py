@@ -150,15 +150,19 @@ SERVE_CONFIGS = {
         rope_theta=1e6, tie_embeddings=False, param_dtype=jnp.bfloat16),
     "olmoe-1b-7b-serve-d8": dict(
         name="olmoe_1b_7b", layers=8, param_dtype=jnp.bfloat16),
+    # 32 slots, and its traffic's prefill bucket is 1024
+    "zaya1-8b-serve-d16": dict(
+        name="zaya1_8b", layers=16, param_dtype=jnp.bfloat16, slots=32,
+        bucket=1024),
 }
 
 
 @pytest.fixture(scope="module")
 def serve_programs(topo):
-    """configuration name -> (cfg, prefill[SEQ], decode[16 x SEQ], cache
-    shapes) of a `ContinuousBatcher`, compiled once for one described chip:
-    the programs as the engine jits them, the decode step's donation of its
-    cache included."""
+    """configuration name -> (cfg, prefill[bucket], decode[slots x SEQ],
+    cache shapes) of a `ContinuousBatcher`, compiled once for one described
+    chip: the programs as the engine jits them, the decode step's donation
+    of its cache included."""
     from ray_tpu.models.continuous_batching import ContinuousBatcher
     from ray_tpu.models.decoding import init_cache
 
@@ -170,21 +174,23 @@ def serve_programs(topo):
     @functools.cache
     def compiled(name):
         widths = dict(SERVE_CONFIGS[name])
+        slots = widths.pop("slots", SERVE_SLOTS)
+        bucket = widths.pop("bucket", SEQ)
         cfg = T.config(widths.pop("name"), **widths)
         params = _on(one, jax.eval_shape(
             lambda: T.init_params(cfg, jax.random.key(0))))
         batcher = ContinuousBatcher.__new__(ContinuousBatcher)  # programs only
-        batcher.cfg, batcher.max_len, batcher.slots = cfg, SEQ, SERVE_SLOTS
+        batcher.cfg, batcher.max_len, batcher.slots = cfg, SEQ, slots
         batcher._jit_programs()
         prefill = jax.jit(batcher._prefill_impl).lower(  # as `_prefill_into`
-            params, arr((1, SEQ), jnp.int32), arr((1,), jnp.int32)).compile()
-        cache = _on(one, jax.eval_shape(
-            lambda: init_cache(cfg, SERVE_SLOTS, SEQ)))
+            params, arr((1, bucket), jnp.int32), arr((1,), jnp.int32)
+        ).compile()
+        cache = _on(one, jax.eval_shape(lambda: init_cache(cfg, slots, SEQ)))
         decode = batcher._decode_jit.lower(
-            params, arr((SERVE_SLOTS,), jnp.int32), cache,
+            params, arr((slots,), jnp.int32), cache,
             _on(one, jax.eval_shape(lambda: jax.random.key(0))),
-            arr((SERVE_SLOTS,), jnp.float32), arr((SERVE_SLOTS,), jnp.int32),
-            arr((SERVE_SLOTS,), jnp.bool_)).compile()
+            arr((slots,), jnp.float32), arr((slots,), jnp.int32),
+            arr((slots,), jnp.bool_)).compile()
         return cfg, prefill, decode, cache
 
     return compiled
@@ -198,7 +204,14 @@ def _total_bytes(program):
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("name", sorted(SERVE_CONFIGS))
+def _entry_parameters(program) -> int:
+    """Operands of a compiled program: `parameter(i)` lines of its ENTRY."""
+    entry = program.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    return len(re.findall(r" parameter\(\d+\)", entry))
+
+
+@pytest.mark.parametrize("name", ["mistral7b-v03-serve-d16",
+                                  "olmoe-1b-7b-serve-d8"])
 def test_prefill_and_decode_compile(serve_programs, name):
     """The decode step of both serve configurations is given its cache to
     keep: the cache comes out in the buffers it went in by (2.15 GB
@@ -227,6 +240,63 @@ def test_prefill_and_decode_compile(serve_programs, name):
     assert not [n for n, shape, op in written if shape.startswith(stack)
                 and "dynamic-update-slice" in n + op]
     assert _total_bytes(prefill) < 16e9
+    # PR 30's optional state and router carry are None here: the programs
+    # take the parameters, the three cache arrays and what a step is given
+    # (tokens, key, temperatures, top-ks, mask; tokens and length), no more
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    assert cache.state is None
+    assert _entry_parameters(decode) == leaves + 3 + 5
+    assert _entry_parameters(prefill) == leaves + 2
+
+
+def test_stateful_serve_programs_compile_and_fit(serve_programs):
+    """The `serve-cca-reason-long-out` deployment (ZAYA1-8B at its published
+    widths, 16 of 40 layers, 32 slots x 2048): the decode step is given its
+    latent cache AND its convolution state to keep (both aliased in to out,
+    neither a temporary: what is left is the 262,272-column logits and their
+    sort), the prefill of the 1024 bucket returns the state beside its rows,
+    both fit the chip, and the named scopes reach the compiled program's
+    text, which is where `benchmarks/scope_ops.py` reads them."""
+    from benchmarks import scope_ops
+
+    cfg, prefill, decode, cache = serve_programs("zaya1-8b-serve-d16")
+    slots = cache.lengths.shape[0]
+    kept = _arg_bytes((cache.k, cache.v, cache.state))
+    assert cache.state.shape == (16, slots, 21, 128)  # 2,688 values
+    layer = _arg_bytes((cache.k, cache.v)) / cfg.layers
+    for name, program in (("prefill[1024]", prefill),
+                          (f"decode[{slots}x2048]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        assert _total_bytes(program) < 16e9
+        # the load [E] and the choices leave the program beside its tokens
+        assert "s32[16]" in program.as_text()
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    logits = slots * cfg.vocab_size * 4
+    assert m.temp_size_in_bytes < layer + 4 * logits
+    assert _total_bytes(decode) < 10e9
+    text = decode.as_text()
+    written = re.findall(r"%(\S+) = (\S+) ([\w-]+)\(", text)
+    stack = "bf16[" + ",".join(map(str, cache.k.shape)) + "]"
+    assert not [n for n, shape, op in written if shape.startswith(stack)
+                and "dynamic-update-slice" in n + op]
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    assert _entry_parameters(decode) == leaves + 4 + 5  # the state is one
+    scopes = scope_ops.op_scopes(text)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) >= {"cca.project", "cca.conv", "cca.attend",
+                           "zaya.router", "moe_experts", "lm_head", "sample"}
+    # the vocabulary of scopes is written once, where the spans' is
+    from ray_tpu.observability import schema
+
+    assert set(scope_ops.SCOPES) <= set(schema.PROGRAM_SCOPES)
 
 
 def test_sparse_serve_programs_compile_and_fit(serve_programs):
