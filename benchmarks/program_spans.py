@@ -11,9 +11,10 @@ operations, on the same clock. ``trace_reduce`` reads only the benchmark's
 own ``bench.`` spans; this file reads the program's, with their stats and
 the thread (line of the host plane) they were opened on.
 
-Two stages, like ``trace_reduce``: ``load`` parses a file into a plain
-structure (once per process), and everything else is arithmetic on that
-structure, checked on a recorded sample under ``tests/data/``. Every number
+Two stages, like ``trace_reduce``, whose ``load_xplane`` reads the file once
+for both: ``from_trace`` takes the spans and the device's busy intervals out
+of what it loaded, and everything else is arithmetic on that structure,
+checked on a recorded sample under ``tests/data/``. Every number
 is None where the trace has no such span (the parent of the PR that added
 the spans, a cell without an engine), so the harness leaves the metric out.
 """
@@ -23,15 +24,13 @@ from __future__ import annotations
 import bisect
 import gzip
 import json
-import os
 import statistics
 import sys
-import time
 from collections import defaultdict
 
-from benchmarks import harness, trace_reduce
+from benchmarks import trace_reduce
 
-PREFIX = "ray_tpu."
+PREFIX = trace_reduce.PROGRAM_SPAN_PREFIX
 ENGINE = "ray_tpu.engine."
 IDLE = ENGINE + "idle"
 STEP = ENGINE + "step"
@@ -41,29 +40,18 @@ DECODE_DISPATCH = ENGINE + "decode_dispatch"
 SAMPLE_SYNC = ENGINE + "sample_sync"
 STREAM_YIELD = "ray_tpu.worker.stream_yield"
 
-_parsed = {}  # path of an .xplane.pb -> what parse made of it
-
 
 def parse(path: str) -> dict:
-    """{"spans": [[name, start_ns, dur_ns, line, {stat: value}]],
-    "busy": {device: [[start, end]]}, "window": {device: [start, end]}}.
-    ``line`` numbers the host plane's lines: one per thread, and the only
-    identity a thread has in the trace (every Python thread's line is named
-    ``python``)."""
-    from jax.profiler import ProfileData
+    """``from_trace`` of an ``.xplane.pb``."""
+    return from_trace(trace_reduce.load_xplane(path))
 
-    spans, line_no = [], 0
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            line_no += 1
-            for e in line.events:
-                if e.name.startswith(PREFIX):
-                    spans.append([e.name, e.start_ns, e.duration_ns, line_no,
-                                  {k: v for k, v in e.stats}])
-    spans.sort(key=lambda s: (s[1], -s[2]))
-    return dict(device_intervals(trace_reduce.load_xplane(path)), spans=spans)
+
+def from_trace(trace: dict) -> dict:
+    """{"spans": [[name, start_ns, dur_ns, line, {stat: value}]],
+    "busy": {device: [[start, end]]}, "window": {device: [start, end]}} of
+    a trace as ``trace_reduce.load_xplane`` loaded it."""
+    spans = sorted(trace["program_spans"], key=lambda s: (s[1], -s[2]))
+    return dict(device_intervals(trace), spans=spans)
 
 
 def device_intervals(trace: dict) -> dict:
@@ -102,30 +90,23 @@ def load_sample(path: str) -> dict:
 
 
 def load(ctx) -> dict | None:
-    """The parsed trace of this run, or None where it wrote none. Both
-    runners trace into ``.bench_out/<cell>/trace``, and the file is still
-    there when ``run.py`` assembles the line."""
-    if not ctx.get("trace"):
-        return None
-    try:
-        path = trace_reduce.find_xplane(os.path.join(
-            harness.ROOT, ".bench_out", ctx["cell"]["name"], "trace"))
-    except FileNotFoundError:
-        return None
-    if path not in _parsed:
-        t0 = time.perf_counter()
-        _parsed[path] = parsed = parse(path)
-        idle, periods = idle_by_span(parsed), step_periods_ms(parsed)
-        harness.say(
-            "program_spans", parse_s=round(time.perf_counter() - t0, 2),
-            n_spans=len(parsed["spans"]),
-            idle_s_by_span={k: round(v, 4) for k, v in sorted(
-                idle.items(), key=lambda kv: -kv[1])} if idle else None,
-            step_period_ms={"n": len(periods),
-                            "median": round(statistics.median(periods), 3),
-                            "mean": round(statistics.fmean(periods), 3)}
-            if periods else None)
-    return _parsed[path]
+    """The parsed trace of this run, which its runner left in the reduced
+    trace under ``program_spans``, or None where the run has none."""
+    return (ctx.get("trace") or {}).get("program_spans")
+
+
+def describe(parsed: dict, idle: dict | None) -> dict:
+    """What a traced run says of its spans on stderr: how many, the idle
+    seconds by span (``idle_by_span``'s), and the step period."""
+    periods = step_periods_ms(parsed)
+    return dict(
+        n_spans=len(parsed["spans"]),
+        idle_s_by_span={k: round(v, 4) for k, v in sorted(
+            idle.items(), key=lambda kv: -kv[1])} if idle else None,
+        step_period_ms={"n": len(periods),
+                        "median": round(statistics.median(periods), 3),
+                        "mean": round(statistics.fmean(periods), 3)}
+        if periods else None)
 
 
 def named(parsed: dict, name: str, line=None) -> list:

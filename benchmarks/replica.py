@@ -7,7 +7,11 @@ is the replica, which has no hook of its own (PERF.md lists one for the
 ``build_llm_deployment`` returns:
 
 - ``bench_profile_start`` / ``bench_profile_stop``: the JAX profiler around a
-  steady window, reduced to numbers inside the replica;
+  steady window. The replica starts and stops it and does nothing else with
+  it: the stop alone takes 10-100 s here (the profiler's own collection of
+  the device's events), and the runner reduces the file in its own process
+  once the replica is gone (a reduction here would add seconds of Python to
+  the GIL that the engine's pump and the stream threads share);
 - ``bench_reference_check``: a seeded prompt through the batcher's own
   prefill and batched decode programs, beside other busy slots, against
   ``reference.py``'s full forward, at the published widths, on the chip;
@@ -20,6 +24,7 @@ top level.
 from __future__ import annotations
 
 import os
+import time
 
 
 class IdTokenizer:
@@ -48,7 +53,6 @@ def build_application(llm_config, config: dict):
 
             harness.setup_compile_cache()
             super().__init__()
-            self._bench_trace_dir = None
 
         def bench_device(self) -> dict:
             import jax
@@ -63,25 +67,11 @@ def build_application(llm_config, config: dict):
                         peak_bytes_reserved=stats.get("peak_bytes_reserved"))
 
         def bench_profile_start(self, trace_dir: str) -> bool:
-            import jax
-
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.host_tracer_level = 2
-            options.raise_error_on_start_failure = True
-            os.makedirs(trace_dir, exist_ok=True)
-            jax.profiler.start_trace(trace_dir, profiler_options=options)
-            self._bench_trace_dir = trace_dir
+            profile_start(trace_dir)
             return True
 
-        def bench_profile_stop(self, sample_to: str = "") -> dict:
-            import jax
-
-            from benchmarks import trace_reduce
-
-            jax.profiler.stop_trace()
-            return trace_reduce.reduce_dir(self._bench_trace_dir,
-                                           sample_to=sample_to)
+        def bench_profile_stop(self, trace_dir: str) -> dict:
+            return profile_stop(trace_dir)
 
         def bench_reference_check(self, seed: int, prompt_len: int,
                                   new_tokens: int) -> dict:
@@ -89,6 +79,29 @@ def build_application(llm_config, config: dict):
                                    new_tokens)
 
     return Deployment(BenchLLMServer, app.deployment._config).bind()
+
+
+def profile_start(trace_dir: str) -> None:
+    """The profiler without the Python tracer (it slows the host loop it is
+    meant to observe); a refused start raises."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    options.raise_error_on_start_failure = True
+    os.makedirs(trace_dir, exist_ok=True)
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+
+
+def profile_stop(trace_dir: str) -> dict:
+    """Stop the profiler, which writes its file, and say where and how long
+    that took. Reading the file is the runner's, after this process."""
+    import jax
+
+    t0 = time.perf_counter()
+    jax.profiler.stop_trace()
+    return {"trace_dir": trace_dir, "stop_s": time.perf_counter() - t0}
 
 
 def reference_check(engine, config: dict, seed: int, prompt_len: int,

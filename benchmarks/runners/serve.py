@@ -3,8 +3,10 @@
 This process is the Serve driver: it starts the cluster, deploys one replica
 (which takes the chip), starts the HTTP proxy and plays the cell's traffic
 against it from one event loop. It never initialises a JAX backend; the
-device, its memory, the trace and the reference check all come from the
-replica through ``replica.py``'s added methods.
+device, its memory, the profiler and the reference check are the replica's,
+through ``replica.py``'s added methods. The profiler's file is read here,
+once the cluster is down: nothing then shares the interpreter with the
+reduction, and the reduction takes nothing from the window it describes.
 
 The deployment and the proxy run with the limits the program ships (60 s
 deadline, 256 in flight, queue depth 128). The HTTP front door takes one
@@ -21,7 +23,7 @@ import time
 
 import numpy as np
 
-from benchmarks import harness, loadgen, replica
+from benchmarks import harness, loadgen, program_spans, replica, trace_reduce
 
 WARMUP_TIMEOUT_S = 540  # the first request of a bucket compiles
 # an end-to-end metric so named is that percentile of the first-token times
@@ -133,19 +135,25 @@ class Deployed:
         handle, args = self.handle, self.args
         schedule = loadgen.make_schedule(traffic, seed, seconds,
                                          self.cfg.vocab_size)
-        marks = {}
+        marks, failed = {}, []
+        opened = threading.Event()
         trace_dir = os.path.join(args["out_dir"], "trace")
 
         def on_open():
-            marks["open_wall"] = time.time()
-            marks["engine_open"] = handle.engine_stats.remote().result()
-            marks["proxy_open"] = serve.http_proxy_stats()
-            if trace:
-                time.sleep(float(traffic.get("trace_after_s", 5.0)))
-                handle.bench_profile_start.remote(trace_dir).result()
-                time.sleep(float(traffic.get("trace_s", 5.0)))
-                marks["trace"] = handle.bench_profile_stop.remote(
-                    args.get("sample_to", "")).result()
+            try:
+                marks["open_wall"] = time.time()
+                marks["engine_open"] = handle.engine_stats.remote().result()
+                marks["proxy_open"] = serve.http_proxy_stats()
+                if trace:
+                    time.sleep(float(traffic.get("trace_after_s", 5.0)))
+                    handle.bench_profile_start.remote(trace_dir).result()
+                    time.sleep(float(traffic.get("trace_s", 5.0)))
+                    marks["trace"] = handle.bench_profile_stop.remote(
+                        trace_dir).result()
+            except Exception as e:  # noqa: BLE001 — raised below, by measure
+                failed.append(e)
+            finally:
+                opened.set()
 
         def on_close():
             marks["engine_close"] = handle.engine_stats.remote().result()
@@ -157,11 +165,16 @@ class Deployed:
         closer.start()
         played = loadgen.play(self.port, traffic, schedule, seconds,
                               on_open=on_open)
-        closer.join(timeout=30)
-        for _ in range(600):  # the trace reduction may still be running
-            if "engine_open" in marks and (not trace or "trace" in marks):
-                break
-            time.sleep(0.1)
+        # no timed wait: a stop that returns late is late, and a trace
+        # dropped for it made a line without busy_s (PR 31). The child's own
+        # limit in run.py bounds both
+        closer.join()
+        opened.wait()
+        if failed:
+            raise RuntimeError(
+                f"the window's opening failed (counters"
+                f"{', the profiler' if trace else ''}): {failed[0]!r}"
+            ) from failed[0]
         return account(self, traffic, schedule, played, marks)
 
 
@@ -232,6 +245,34 @@ def account(dep: Deployed, traffic: dict, schedule: dict, played: dict,
     }
 
 
+def read_trace(stopped: dict, sample_to: str = "") -> dict:
+    """From the file the replica's profiler wrote to the numbers of the
+    traced window and the program's spans, loaded once for both. A file
+    without a device operation is a failed run, not a line without
+    ``busy_s``."""
+    t0 = time.perf_counter()
+    loaded = trace_reduce.load_xplane(
+        trace_reduce.find_xplane(stopped["trace_dir"]))
+    summary = trace_reduce.reduce_trace(loaded, sample_to)
+    if not summary:
+        raise RuntimeError(
+            f"the trace under {stopped['trace_dir']} holds no device "
+            f"operation: planes {sorted(loaded['devices'])}, "
+            f"{len(loaded['program_spans'])} program spans")
+    summary["program_spans"] = parsed = program_spans.from_trace(loaded)
+    idle = program_spans.idle_by_span(parsed)
+    if idle:
+        # by the engine span the pump was in; trace_reduce's own gaps know
+        # the benchmark's spans only, and a serve cell opens none
+        summary["idle_gaps"] = sorted(
+            ([n, s] for n, s in idle.items() if s > 0), key=lambda g: -g[1])
+    harness.say("trace", stop_s=round(stopped["stop_s"], 2),
+                reduce_s=round(time.perf_counter() - t0, 2),
+                busy_s=summary["busy_s"], window_s=summary["window_s"],
+                **program_spans.describe(parsed, idle))
+    return summary
+
+
 def run(cell: dict, args: dict) -> dict:
     from ray_tpu.accelerators.tpu import jax_backend_is_up
 
@@ -242,6 +283,9 @@ def run(cell: dict, args: dict) -> dict:
         stats_end = handle.engine_stats.remote().result()
         device = handle.bench_device.remote().result()
         backend_up = jax_backend_is_up()
+    stopped = win.pop("trace")
+    trace = read_trace(stopped, args.get("sample_to", "")) \
+        if args["trace"] else {}
     problems = dep.problems + win.pop("problems")
     if backend_up:
         problems.append("the driver (and its proxy) initialised a JAX backend")
@@ -252,7 +296,6 @@ def run(cell: dict, args: dict) -> dict:
                           - stats_warm["cache_hits"] - stats_warm["cache_misses"])
     if compiled_in_window:
         problems.append(f"{compiled_in_window} compilation(s) after warm-up")
-    trace = win.pop("trace")
     # the cell's percentile metrics, by name: ttft_p80_ms, itl_p99_ms, ...
     for m in cell["end_to_end"]:
         named = PERCENTILE_METRIC.match(m["name"])

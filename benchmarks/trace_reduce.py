@@ -5,9 +5,10 @@ Two stages, so that the arithmetic can be checked on a small recorded trace
 
 1. ``load_xplane`` reads the file with ``jax.profiler.ProfileData`` into a
    plain structure: per device the operations of the ``XLA Ops`` line and the
-   programs of the ``XLA Modules`` line, and the benchmark's own host spans
-   (``jax.profiler.TraceAnnotation`` names that start with ``bench.``).
-   Times are nanoseconds on the trace's clock.
+   programs of the ``XLA Modules`` line, the benchmark's own host spans
+   (``jax.profiler.TraceAnnotation`` names that start with ``bench.``) and
+   the program's (``ray_tpu.``, for ``program_spans.py``: one pass over the
+   file serves both). Times are nanoseconds on the trace's clock.
 2. ``summarize`` reduces that structure: busy time as the union of the
    intervals in which an operation ran, self time per operation (a ``while``
    does not count its body twice), idle gaps named by the host span that
@@ -29,6 +30,7 @@ import re
 from collections import defaultdict
 
 SPAN_PREFIX = "bench."
+PROGRAM_SPAN_PREFIX = "ray_tpu."
 COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
                        "all-to-all", "collective-permute",
                        "collective-broadcast")
@@ -81,11 +83,15 @@ def op_tag(text: str) -> str:
 def load_xplane(path: str) -> dict:
     """{"devices": {name: {"ops": [[name, start, dur, tag]],
     "programs": [[name, start, dur]], "async": [[name, start, dur]],
-    "other_lines": {line: n_events}}}, "spans": [[name, start, dur]]}"""
+    "other_lines": {line: n_events}}}, "spans": [[name, start, dur]],
+    "program_spans": [[name, start, dur, line, {stat: value}]]}. ``line``
+    numbers the host planes' lines: one per thread, and the only identity a
+    thread has in the trace (every Python thread's line is named
+    ``python``)."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    devices, spans, cpu_ops = {}, [], []
+    devices, spans, program_spans, cpu_ops, line_no = {}, [], [], [], 0
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             dev = {"ops": [], "programs": [], "async": [], "other_lines": {}}
@@ -107,9 +113,14 @@ def load_xplane(path: str) -> dict:
             devices[plane.name] = dev
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
+                line_no += 1
                 for e in line.events:
                     if e.name.startswith(SPAN_PREFIX):
                         spans.append([e.name, e.start_ns, e.duration_ns])
+                    elif e.name.startswith(PROGRAM_SPAN_PREFIX):
+                        program_spans.append(
+                            [e.name, e.start_ns, e.duration_ns, line_no,
+                             {k: v for k, v in e.stats}])
                     elif line.name.startswith("tf_XLA") and e.duration_ns > 0 \
                             and any(k == "hlo_op" for k, _ in e.stats):
                         cpu_ops.append([e.name, e.start_ns, e.duration_ns, ""])
@@ -117,7 +128,8 @@ def load_xplane(path: str) -> dict:
         devices["cpu"] = {"ops": sorted(cpu_ops, key=lambda o: o[1]),
                           "programs": [], "async": [], "other_lines": {}}
     spans.sort(key=lambda s: s[1])
-    return {"devices": devices, "spans": spans}
+    return {"devices": devices, "spans": spans,
+            "program_spans": program_spans}
 
 
 def save_sample(trace: dict, path: str, max_ops: int = 4000) -> None:
@@ -318,7 +330,12 @@ def kernel_self_s(summary: dict, tag_prefixes) -> float:
 
 
 def reduce_dir(trace_dir: str, sample_to: str = "") -> dict:
-    trace = load_xplane(find_xplane(trace_dir))
+    return reduce_trace(load_xplane(find_xplane(trace_dir)), sample_to)
+
+
+def reduce_trace(trace: dict, sample_to: str = "") -> dict:
+    """The summary of a loaded trace, empty where no operation ran on a
+    device; a cut of the trace is kept at ``sample_to`` if that is given."""
     if sample_to:
         os.makedirs(os.path.dirname(sample_to), exist_ok=True)
         save_sample(trace, sample_to)
