@@ -69,23 +69,46 @@ from ray_tpu.observability.tracing import device_span
 from ray_tpu.ops.attention import NEG_INF
 
 
-def _sample_per_slot(logits, rng, temps, topks):
+def _sample_per_slot(logits, rng, temps, topks, active):
     """Vectorized sampling: per-row temperature (0 = greedy) and top-k
-    (0 = unfiltered). logits [B, V] -> ids [B]."""
+    (0 = unfiltered). logits [B, V] -> ids [B]. The work follows what the
+    `active` rows ask for, decided inside the program: every row's argmax
+    always; the scaling and the draw over [B, V] only in a step where some
+    active row has a temperature; the full-vocabulary sort, the k-th
+    threshold and the filter only where such a row also has a top-k. A row's
+    id is the same in every branch it can come out of (a greedy row's is the
+    argmax, an unfiltered row's `filtered` is `scaled`). Inactive rows do not
+    count: a slot keeps its last request's temperature and top-k until the
+    next admit, and nobody reads its id."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    f32 = logits.astype(jnp.float32)
-    scaled = f32 / jnp.maximum(temps, 1e-6)[:, None]
-    # per-row kth threshold: value at rank (top_k - 1) descending;
-    # top_k == 0 disables the filter for that row
-    v = logits.shape[-1]
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    idx = jnp.clip(topks - 1, 0, v - 1)[:, None]
-    kth = jnp.take_along_axis(sorted_desc, idx, axis=1)
-    filtered = jnp.where(
-        (topks[:, None] > 0) & (scaled < kth), NEG_INF, scaled)
-    sampled = jax.random.categorical(rng, filtered, axis=-1).astype(
-        jnp.int32)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    draws = active & (temps > 0.0)
+
+    def draw():
+        f32 = logits.astype(jnp.float32)
+        scaled = f32 / jnp.maximum(temps, 1e-6)[:, None]
+
+        def top_k_filtered():
+            # per-row kth threshold: value at rank (top_k - 1) descending;
+            # top_k == 0 disables the filter for that row
+            v = logits.shape[-1]
+            sorted_desc = -jnp.sort(-scaled, axis=-1)
+            idx = jnp.clip(topks - 1, 0, v - 1)[:, None]
+            kth = jnp.take_along_axis(sorted_desc, idx, axis=1)
+            return jnp.where(
+                (topks[:, None] > 0) & (scaled < kth), NEG_INF, scaled)
+
+        filtered = jax.lax.cond(
+            jnp.any(draws & (topks > 0)), top_k_filtered, lambda: scaled)
+        sampled = jax.random.categorical(rng, filtered, axis=-1).astype(
+            jnp.int32)
+        return jnp.where(temps > 0.0, sampled, greedy)
+
+    return jax.lax.cond(jnp.any(draws), draw, lambda: greedy)
+
+
+# an admit's first token: the same function as a program of its own (called
+# eagerly, a `lax.cond` is traced and compiled again at every call)
+_sample_first = jax.jit(_sample_per_slot)
 
 
 @dataclasses.dataclass
@@ -208,10 +231,14 @@ class ContinuousBatcher(PrefillPrograms):
         self._uploaded: Dict[str, tuple] = {}
         # stats (observable by tests/metrics). `steps_ahead`: steps
         # dispatched while the step before them was unread;
-        # `tokens_discarded`: slot-steps thrown away behind a stop token
+        # `steps_sampled` / `steps_sorted`: steps in which an active row had
+        # a temperature / a temperature and a top-k, so that sampling drew /
+        # sorted the vocabulary; `tokens_discarded`: slot-steps thrown away
+        # behind a stop token
         self.stats = {"admitted": 0, "finished": 0, "failed": 0,
                       "steps": 0, "max_active": 0, "tokens_out": 0,
                       "last_admit_step": -1, "steps_ahead": 0,
+                      "steps_sampled": 0, "steps_sorted": 0,
                       "tokens_discarded": 0}
         if cfg.stateful:
             # prefills whose state went into a slot with their rows, and
@@ -339,7 +366,8 @@ class ContinuousBatcher(PrefillPrograms):
             self.cfg, params, toks[:, None], positions, cache, kv_mask,
             active_mask[:, None], access)
         with jax.named_scope("sample"):
-            nxt = _sample_per_slot(logits[:, 0], rng, temps, topks)
+            nxt = _sample_per_slot(
+                logits[:, 0], rng, temps, topks, active_mask)
         # only ACTIVE slots advance (their state too: `forward_cached` was
         # given the mask); free rows stay put so a later install never
         # races a drifting length past max_len
@@ -471,10 +499,11 @@ class ContinuousBatcher(PrefillPrograms):
             last_logits, load, rows = self._prefill_into(req, slot)
             with device_span(spans.ENGINE_FIRST_TOKEN_SYNC):
                 self._rng, k = jax.random.split(self._rng)
-                first = _sample_per_slot(
+                first = _sample_first(
                     last_logits[None], k,
                     jnp.asarray([req.sampling.temperature], np.float32),
-                    jnp.asarray([req.sampling.top_k], np.int32))
+                    jnp.asarray([req.sampling.top_k], np.int32),
+                    np.ones(1, bool))
                 first_tok = int(np.asarray(first)[0])
                 self._count_experts(load, rows)
                 self._log_routes(load, {slot: req})
@@ -641,10 +670,16 @@ class ContinuousBatcher(PrefillPrograms):
             return
         ahead = int(self._inflight is not None)
         self._host_len[slots] += 1
+        # what the step's sampling does beyond the argmax, by the same rows
+        # the program looks at (`_sample_per_slot`): the draw, and the sort
+        draws = self._temps[slots] > 0
+        sampled = int(draws.any())
+        sorts = int((draws & (self._topks[slots] > 0)).any())
         # `rows`: the positions the step's sequences hold, its own among
         # them: what its attention has to read
         with device_span(spans.ENGINE_DECODE_DISPATCH, active=len(slots),
-                         ahead=ahead, rows=int(self._host_len[slots].sum())):
+                         ahead=ahead, rows=int(self._host_len[slots].sum()),
+                         sampled=sampled, sorted=sorts):
             active_mask = np.zeros(self.slots, bool)
             active_mask[slots] = True
             self._rng, k = jax.random.split(self._rng)
@@ -659,6 +694,8 @@ class ContinuousBatcher(PrefillPrograms):
         newer = _Dispatched(toks, load, {s: self._active[s] for s in slots})
         self.stats["steps"] += 1
         self.stats["steps_ahead"] += ahead
+        self.stats["steps_sampled"] += sampled
+        self.stats["steps_sorted"] += sorts
         # nothing is in flight while the step before is read: if that
         # fails, the failure path must not emit `newer` behind the hole
         self._drain()

@@ -68,7 +68,7 @@ DEVICE_SPANS = {
     ENGINE_PREFILL_DISPATCH: "",
     ENGINE_INSTALL_DISPATCH: "",
     ENGINE_FIRST_TOKEN_SYNC: "",
-    ENGINE_DECODE_DISPATCH: "active, ahead, rows",
+    ENGINE_DECODE_DISPATCH: "active, ahead, rows, sampled, sorted",
     ENGINE_SAMPLE_SYNC: "",
     ENGINE_EMIT: "",
     # handler threads of _private/workers/default_worker.py
@@ -94,5 +94,6 @@ PROGRAM_SCOPES = {
     "lora": "models/transformer.py: an adapter's two matmuls",
     "lm_head": "models/decoding.py: the logits' matmul over the vocabulary",
     "sample": "models/continuous_batching.py: the decode step's sampling "
-              "(the full-vocabulary sort, the draw)",
+              "(the argmax; in a conditional's branches the draw and the "
+              "full-vocabulary sort)",
 }

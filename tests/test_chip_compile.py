@@ -219,7 +219,10 @@ def test_prefill_and_decode_compile(serve_programs, name):
     sparse: before PR 26 the layer scan sliced each layer into a private
     copy and wrote the whole layer back into a second stack, 42-45% of the
     step on the chip), and nothing updates a slice of the stack but the
-    scatter of one row per slot."""
+    scatter of one row per slot. Sampling's conditionals stand between the
+    logits and the tokens and cost none of this: outside their branches are
+    the [16, vocabulary] logits and the argmax; the scaled copy, the draw
+    and the sort are inside, where a greedy step does not go."""
     cfg, prefill, decode, cache = serve_programs(name)
     cache_bytes = _arg_bytes((cache.k, cache.v))
     m = decode.memory_analysis()
@@ -254,8 +257,10 @@ def test_stateful_serve_programs_compile_and_fit(serve_programs):
     """The `serve-cca-reason-long-out` deployment (ZAYA1-8B at its published
     widths, 16 of 40 layers, 32 slots x 2048): the decode step is given its
     latent cache AND its convolution state to keep (both aliased in to out,
-    neither a temporary: what is left is the 262,272-column logits and their
-    sort), the prefill of the 1024 bucket returns the state beside its rows,
+    neither a temporary: what is left is the 262,272-column logits and, in
+    the branches of sampling's conditionals, which a greedy step does not
+    take, their scaled copy, the draw and the sort: the test below), the
+    prefill of the 1024 bucket returns the state beside its rows,
     both fit the chip, and the named scopes reach the compiled program's
     text, which is where `benchmarks/scope_ops.py` reads them."""
     from benchmarks import scope_ops
@@ -297,6 +302,49 @@ def test_stateful_serve_programs_compile_and_fit(serve_programs):
     from ray_tpu.observability import schema
 
     assert set(scope_ops.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+@pytest.mark.parametrize("name", sorted(SERVE_CONFIGS))
+def test_decode_sorts_the_vocabulary_only_in_a_conditionals_branch(
+        serve_programs, name):
+    """The chip's compiler keeps sampling's conditionals (it could have
+    flattened them into selects, and every greedy step would sort again:
+    16.6 of the ZAYA1 step's 30.3 ms at PR 30): the entry computation holds
+    the argmax and ONE conditional under the `sample` scope, every sort over
+    [slots, vocabulary] sits in a computation that is some conditional's
+    branch, two conditionals deep, and the entry's own operations under
+    `sample` still reach `scope_ops.op_scopes`."""
+    from benchmarks import scope_ops
+
+    cfg, _, decode, cache = serve_programs(name)
+    text = decode.as_text()
+    logits = f"[{cache.lengths.shape[0]},{cfg.vocab_size}]"
+    holder, sorts = {}, []  # computation of every instruction; the sorts
+    inside = None
+    for line in text.splitlines():
+        head = scope_ops._COMPUTATION.match(line)
+        if head:
+            inside = head[1]
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) [\w\-]+\(", line)
+        if m and inside:
+            holder[m[1]] = inside
+            if re.search(r"[)}] sort\(", line) and logits in m[2]:
+                sorts.append(m[1])
+    assert len(sorts) == 1  # the top-k threshold's, nothing else's
+    branch_of = {}  # branch computation -> the conditional that takes it
+    for cond, branches in re.findall(
+            r"%?([\w.\-]+) = .* conditional\(.*branch_computations=\{([^}]*)\}",
+            text):
+        for b in branches.split(","):
+            branch_of[b.strip().lstrip("%")] = cond
+    entry = text.split("\nENTRY ", 1)[1].split(" ", 1)[0].lstrip("%")
+    inner = branch_of[holder[sorts[0]]]  # KeyError: the sort is in no branch
+    outer = branch_of[holder[inner]]
+    assert holder[outer] == entry
+    sampled = scope_ops.op_scopes(text, ("sample",))["sample"]
+    assert {inner, outer, sorts[0]} <= set(sampled)
+    in_entry = [op for op in sampled if holder.get(op) == entry]
+    assert outer in in_entry and len(in_entry) > 1  # the argmax beside it
 
 
 def test_sparse_serve_programs_compile_and_fit(serve_programs):

@@ -11,12 +11,18 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 import ray_tpu
 from ray_tpu.models import transformer as T
-from ray_tpu.models.continuous_batching import ContinuousBatcher, _Request
+from ray_tpu.models.continuous_batching import (
+    ContinuousBatcher,
+    _Request,
+    _sample_per_slot,
+)
 from ray_tpu.models.decoding import Generator, SamplingParams
 from ray_tpu.models.paged_kv import PagedBatcher
+from ray_tpu.ops.attention import NEG_INF
 
 
 def _tiny_cfg():
@@ -128,6 +134,168 @@ BOTH_CACHES = pytest.mark.parametrize(
     "engine, more", [(ContinuousBatcher, {}),
                      (PagedBatcher, {"page_size": 16})],
     ids=["slots", "pages"])
+
+
+def _sample_every_row_sorted(logits, rng, temps, topks):
+    """`_sample_per_slot` as it was before sampling followed its rows (PR
+    30's, to the letter): the argmax, the full sort and the draw for every
+    row, whatever was asked. Frozen here as the oracle."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    f32 = logits.astype(jnp.float32)
+    scaled = f32 / jnp.maximum(temps, 1e-6)[:, None]
+    v = logits.shape[-1]
+    sorted_desc = -jnp.sort(-scaled, axis=-1)
+    idx = jnp.clip(topks - 1, 0, v - 1)[:, None]
+    kth = jnp.take_along_axis(sorted_desc, idx, axis=1)
+    filtered = jnp.where(
+        (topks[:, None] > 0) & (scaled < kth), NEG_INF, scaled)
+    sampled = jax.random.categorical(rng, filtered, axis=-1).astype(
+        jnp.int32)
+    return jnp.where(temps > 0.0, sampled, greedy)
+
+
+ROWS, VOCAB = 6, 97
+EVERY_ROW = [True] * ROWS
+# name -> (temperatures, top-ks, active rows); an inactive row's values are
+# what its last request left in the slot
+SAMPLING_MIXES = {
+    "all_greedy": ([0.0] * ROWS, [0] * ROWS, EVERY_ROW),
+    "greedy_rows_that_name_a_top_k": (
+        [0.0] * ROWS, [0, 5, 0, 1, 0, VOCAB], EVERY_ROW),
+    "all_sampled_unfiltered": (
+        [1.5, 0.7, 1.0, 2.0, 0.3, 1.1], [0] * ROWS, EVERY_ROW),
+    "mixed_temperatures": (
+        [0.0, 0.9, 0.0, 1.3, 0.0, 0.0], [0] * ROWS, EVERY_ROW),
+    "top_k_1": ([0.0, 0.9, 1.4, 0.0, 1.0, 0.8], [0, 1, 0, 0, 1, 0],
+                EVERY_ROW),
+    "top_k_5": ([1.2, 0.9, 0.0, 0.0, 1.0, 0.8], [5, 0, 5, 0, 5, 0],
+                EVERY_ROW),
+    "top_k_whole_vocabulary": (
+        [1.2, 0.9, 0.0, 1.7, 1.0, 0.8], [VOCAB, 0, 0, VOCAB, 3, 0],
+        EVERY_ROW),
+    "stale_row_beside_greedy_rows": (
+        [0.0, 0.9, 0.0, 0.0, 1.3, 0.0], [0, 20, 0, 0, 0, 0],
+        [True, False, True, True, False, False]),
+    "stale_top_k_beside_an_unfiltered_draw": (
+        [0.0, 0.9, 1.1, 0.0, 1.3, 0.0], [0, 20, 0, 0, 4, 7],
+        [True, False, True, True, False, True]),
+    "stale_row_beside_a_top_k_draw": (
+        [0.8, 0.9, 0.0, 0.0, 1.3, 0.0], [3, 20, 0, 0, 0, 0],
+        [True, False, True, False, False, True]),
+}
+
+
+def _primitives(jaxpr, inside_cond=False):
+    """(primitive name, whether a `cond`'s branch holds it) of every
+    equation of `jaxpr`, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, inside_cond
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(
+                sub, inside_cond or eqn.primitive.name == "cond")
+
+
+class TestSamplingFollowsItsRows:
+    """Sampling does what its ACTIVE rows ask for: the argmax alone for
+    greedy rows, the draw only where a row has a temperature, the sort only
+    where such a row has a top-k; no active row's token differs from the
+    function that did all of it for every row."""
+
+    @pytest.mark.parametrize("mix", sorted(SAMPLING_MIXES))
+    def test_active_rows_get_the_tokens_of_the_full_sort(self, mix):
+        temps, topks, active = (np.asarray(a, t) for a, t in zip(
+            SAMPLING_MIXES[mix], (np.float32, np.int32, bool)))
+        oracle, sampler = jax.jit(_sample_every_row_sorted), \
+            jax.jit(_sample_per_slot)
+        for seed in range(3):
+            logits = 3.0 * jax.random.normal(
+                jax.random.key(100 + seed), (ROWS, VOCAB), jnp.float32)
+            key = jax.random.key(seed)
+            want = np.asarray(oracle(logits, key, temps, topks))
+            got = np.asarray(sampler(logits, key, temps, topks, active))
+            assert got.dtype == np.int32 and got.shape == (ROWS,)
+            assert (got[active] == want[active]).all(), (seed, got, want)
+            greedy = np.asarray(jnp.argmax(logits, axis=-1))
+            assert (got[active & (temps == 0)]
+                    == greedy[active & (temps == 0)]).all()
+            if mix == "all_sampled_unfiltered":  # the draw is a draw
+                assert (got != greedy).any()
+            if mix == "top_k_1":  # and the filter a filter
+                assert (got[topks == 1] == greedy[topks == 1]).all()
+
+    def test_the_sort_and_the_draw_are_inside_conditionals(self):
+        """The sampler's jaxpr: the argmax is unconditional; the random bits
+        and the sort are in a `cond`'s branches, the sort one `cond` deeper
+        than the draw, and no sort is outside every branch."""
+        jaxpr = jax.make_jaxpr(_sample_per_slot)(
+            jnp.zeros((ROWS, VOCAB)), jax.random.key(0), jnp.zeros(ROWS),
+            jnp.zeros(ROWS, jnp.int32), jnp.ones(ROWS, bool)).jaxpr
+        seen = set(_primitives(jaxpr))
+        assert ("argmax", False) in seen and ("cond", False) in seen
+        assert ("sort", True) in seen and ("sort", False) not in seen
+        assert ("random_bits", True) in seen
+        assert ("random_bits", False) not in seen
+        outer = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+        assert len(outer) == 1
+        # branch 0 is the predicate's False: the argmax as it is
+        greedy_branch, draw_branch = outer[0].params["branches"]
+        assert not list(_primitives(greedy_branch.jaxpr))
+        drawn = dict(_primitives(draw_branch.jaxpr))
+        assert drawn["random_bits"] is False and drawn["sort"] is True
+
+    @BOTH_CACHES
+    def test_steps_are_counted_by_what_their_active_rows_ask_for(
+            self, tiny_model, engine, more):
+        """A greedy run counts no sampled and no sorted step. Beside a
+        request that draws from its top 5 and one that draws unfiltered,
+        the greedy requests get the greedy run's tokens; `steps_sorted`
+        rises while the first is active and `steps_sampled` while either
+        is, and both stop when they retire though their slots keep the
+        stale temperature and top-k."""
+        cfg, params = tiny_model
+        greedy_prompts = [[5, 6, 7], [9, 10]]
+
+        def run(extra):
+            cb = engine(cfg, params, max_len=64, slots=4, **more)
+            cb.shutdown()  # the pump is gone: the passes below are the test's
+            reqs = [_Request(list(p), sp, Future(), None)
+                    for p, sp in [(p, SamplingParams(max_tokens=14))
+                                  for p in greedy_prompts] + extra]
+            for r in reqs:
+                cb._waiting.put(r)
+            seen = []  # after every pass: (steps, sampled, sorted)
+            for _ in range(100):
+                cb._step()
+                seen.append(tuple(cb.stats[k] for k in (
+                    "steps", "steps_sampled", "steps_sorted")))
+                if all(r.future.done() for r in reqs):
+                    break
+            return cb, [r.future.result(timeout=0) for r in reqs], seen
+
+        cb, plain, seen = run([])
+        assert cb.stats["steps"] == 13
+        assert cb.stats["steps_sampled"] == cb.stats["steps_sorted"] == 0
+        gen = Generator(cfg, params, max_len=64)
+        assert plain == gen.generate(greedy_prompts,
+                                     SamplingParams(max_tokens=14))
+
+        cb, mixed, seen = run([
+            ([5, 6, 7], SamplingParams(max_tokens=4, temperature=0.9,
+                                       top_k=5)),
+            ([3, 4], SamplingParams(max_tokens=7, temperature=1.2))])
+        assert mixed[:2] == plain and len(mixed[2]) == 4 \
+            and len(mixed[3]) == 7
+        # a first token comes from the admit: 3 and 6 decode steps
+        assert cb.stats["steps"] == 13
+        assert cb.stats["steps_sorted"] == 3
+        assert cb.stats["steps_sampled"] == 6
+        assert seen[:7] == [(1, 1, 1), (2, 2, 2), (3, 3, 3), (4, 4, 3),
+                            (5, 5, 3), (6, 6, 3), (7, 6, 3)]
+        # the slots they left keep what they asked for: nobody reads it
+        assert sorted(cb._temps[cb._temps > 0].tolist()) == \
+            pytest.approx([0.9, 1.2])
+        assert (cb._topks > 0).sum() == 1
+        assert seen[-1] == (13, 6, 3)
 
 
 class TestDonatedCache:
