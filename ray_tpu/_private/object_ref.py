@@ -9,9 +9,48 @@ task argument registers a borrow with the owner.
 
 from __future__ import annotations
 
+import collections
+import gc
+import threading
 from typing import Any, Optional, Tuple
 
 from ray_tpu._private.ids import ObjectID
+
+# A ref that dies in a reference cycle is finalised by the cyclic collector,
+# which runs wherever an allocation tipped it: inside the release path's own
+# critical sections too (``ObjectID.__hash__`` under the memory store's
+# lock, the borrow and lineage tables, the store client), whose plain locks
+# the very same thread then holds. Releasing inline there waits on itself
+# for ever, and every ``get`` of the process behind it (PR 34: a serve
+# driver that never returned; ROADMAP Design `undelivered-result-hang`). So a
+# ref the collector finalises is only queued, and released where the program
+# next lets a ref go (`__del__` outside a collection) or asks for an object.
+_collector = threading.local()  # .running: this thread is inside a collection
+_orphans: collections.deque = collections.deque()  # append / popleft: atomic
+
+
+def _collector_phase(phase: str, info: dict) -> None:
+    _collector.running = phase == "start"
+
+
+gc.callbacks.append(_collector_phase)
+
+
+def release_orphans() -> None:
+    """Let go of the refs the cyclic collector finalised; a no-op inside a
+    collection, which may be anywhere."""
+    if not _orphans or getattr(_collector, "running", False):
+        return
+    from ray_tpu._private import worker as _worker_mod
+
+    w = _worker_mod.global_worker
+    while _orphans:
+        try:
+            oid = _orphans.popleft()
+        except IndexError:
+            return
+        if w is not None and w.connected:
+            w.reference_counter.remove_local_reference(oid)
 
 
 class ObjectRef:
@@ -60,8 +99,12 @@ class ObjectRef:
     # -- lifecycle --------------------------------------------------------
     def __del__(self) -> None:
         try:
+            if getattr(_collector, "running", False):
+                _orphans.append(self._id)
+                return
             from ray_tpu._private import worker as _worker_mod
 
+            release_orphans()
             w = _worker_mod.global_worker
             if w is not None and w.connected:
                 w.reference_counter.remove_local_reference(self._id)

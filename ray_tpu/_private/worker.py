@@ -15,7 +15,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 
 from ray_tpu._private.config import config
 from ray_tpu._private.ids import ActorID, JobID, TaskID, WorkerID
-from ray_tpu._private.object_ref import ObjectRef
+from ray_tpu._private.object_ref import ObjectRef, release_orphans
 from ray_tpu._private.reference_counter import ReferenceCounter
 
 logger = logging.getLogger(__name__)
@@ -230,6 +230,7 @@ def get(
     for r in ref_list:
         if not isinstance(r, ObjectRef):
             raise TypeError(f"ray_tpu.get() expects ObjectRef(s), got {type(r)}")
+    release_orphans()  # refs the cyclic collector could only queue
     values = w.core.get(ref_list, timeout=timeout)
     return values[0] if single else values
 

@@ -41,6 +41,14 @@ holds for one request rides on the request as ``_Request.kv``, which the
 scheduler never reads. ``PrefillPrograms``, the scheduler's base, is the
 prompt program alone: what a prefill replica constructs
 (``models/disagg_prefill.py``).
+
+A model may keep two kinds of rows (a layer pattern, ``models/laguna.py``):
+its full layers' in the slots, its window layers' in a ring of ``window``
+rows a slot beside them (``KVCache.ring_k``). A prefill then returns both,
+the slots' rows of bucket length and the ring as the prompt's TRUE length
+leaves it, and install writes the whole of both into the slot: nothing of
+the slot's last occupant stays visible. The decode step keeps both (donated
+together). Pages hold no ring: ``PagedBatcher`` refuses such a model.
 """
 
 from __future__ import annotations
@@ -152,10 +160,12 @@ class PrefillPrograms:
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
         against a standalone single-row cache. A stateful model's program
         (`cfg.stateful`) returns the row's state [L, ...] next, as the
-        prompt's TRUE last position left it; a sparse model's then what its
-        expert layers counted (`forward_cached`'s `aux`), the experts' load
-        [E] from the prompt's real positions first (a dense model's callers
-        unpack three)."""
+        prompt's TRUE last position left it; a layer pattern's the window
+        layers' ring rows (ring_k, ring_v [window layers, window, kvH, D]: the
+        last `window` positions of the prompt's TRUE length); a sparse
+        model's then what its expert layers counted (`forward_cached`'s
+        `aux`), the experts' load [E] from the prompt's real positions first
+        (a dense model's callers unpack three)."""
         s = tokens.shape[1]
         row_cache = init_cache(self.cfg, 1, s)
         positions = jnp.arange(s)[None, :]
@@ -170,9 +180,12 @@ class PrefillPrograms:
     @staticmethod
     def _row_of(row_cache: KVCache) -> tuple:
         """A one-sequence cache as a prefill program returns it: (row_k,
-        row_v) and, from a stateful model, the row's state."""
+        row_v) and, from a stateful model, the row's state; from a layer
+        pattern, its window layers' ring."""
         state = () if row_cache.state is None else (row_cache.state[:, 0],)
-        return row_cache.k[:, 0], row_cache.v[:, 0], *state
+        ring = () if row_cache.ring_k is None else (
+            row_cache.ring_k[:, 0], row_cache.ring_v[:, 0])
+        return row_cache.k[:, 0], row_cache.v[:, 0], *state, *ring
 
     @staticmethod
     def _bucket(n: int) -> int:
@@ -245,10 +258,11 @@ class ContinuousBatcher(PrefillPrograms):
             # slots whose state was cleared when their request left
             self.stats.update(state_installs=0, state_resets=0)
         # A list while someone wants to know which expert each row was given
-        # (a program's `expert_choice`, from a router that gives one): every
-        # admit and every step read appends ({slot: request}, choices
-        # [layers, rows]); rows are a prefill's positions or a step's slots.
-        # None: nothing is kept.
+        # (a program's `expert_choice`, from a router that gives one, or the
+        # k of a layer pattern's): every admit and every step read appends
+        # ({slot: request}, choices [layers, rows] or [layers, rows, k]);
+        # rows are a prefill's positions or a step's slots. None: nothing
+        # is kept.
         self.route_log: Optional[list] = None
         if cfg.num_experts:
             # what the experts received from real rows (prompt positions,
@@ -257,6 +271,12 @@ class ContinuousBatcher(PrefillPrograms):
             # unless an assignment was dropped
             self.stats.update(moe_expert_load=[0] * cfg.num_experts,
                               moe_assignments=0, moe_rows=0)
+        if cfg.experts_held:
+            # of those assignments, the ones to experts held here (the rest
+            # are the other chips' of the layer), and the held experts that
+            # had at least one real row, summed over layers and decode steps:
+            # what the steps' grouped matmuls had to read
+            self.stats.update(moe_assignments_held=0, moe_experts_reached=0)
         self._thread = threading.Thread(
             target=self._pump, daemon=True, name="cb-pump")
         self._thread.start()
@@ -330,23 +350,29 @@ class ContinuousBatcher(PrefillPrograms):
                                         donate_argnums=(0,))
 
     def _install_impl(self, cache: KVCache, row_k, row_v, slot, length,
-                      row_state=None):
+                      row_state=None, ring_k=None, ring_v=None):
         """Scatter a prefilled row into its slot of the big cache (the
         row is padded to max_len, so the whole slot — including stale
         data from its previous occupant — is overwritten), and a stateful
-        model's `row_state` [L, ...] with it."""
+        model's `row_state` [L, ...] with it; a layer pattern's `ring_k` /
+        `ring_v` [window layers, window, kvH, D] replace the slot's whole
+        ring likewise."""
         k = jax.lax.dynamic_update_slice(
             cache.k, row_k[:, None], (0, slot, 0, 0, 0))
         v = jax.lax.dynamic_update_slice(
             cache.v, row_v[:, None], (0, slot, 0, 0, 0))
         lengths = cache.lengths.at[slot].set(length)
         return KVCache(k, v, lengths,
-                       self._slot_state(cache.state, slot, row_state))
+                       self._slot_state(cache.state, slot, row_state),
+                       self._slot_state(cache.ring_k, slot, ring_k),
+                       self._slot_state(cache.ring_v, slot, ring_v))
 
     @staticmethod
     def _slot_state(state, slot, row_state):
         """The state stack [L, slots, ...] with `slot`'s replaced by a
-        prefill's `row_state` [L, ...]; a model without state has neither."""
+        prefill's `row_state` [L, ...]; a model without state has neither.
+        A ring stack [window layers, slots, window, ...] is kept the same
+        way."""
         if row_state is None:
             return state
         return state.at[:, slot].set(row_state.astype(state.dtype))
@@ -410,9 +436,12 @@ class ContinuousBatcher(PrefillPrograms):
         return last_logits, load, len(req.tokens)
 
     def _row_state(self, rest: list):
-        """What a prefill program returned after its rows, split into the
-        row's state (`[state]`; `[]` from a model without) and what its
-        expert layers counted; the state is counted as installed."""
+        """What a prefill program returned after its rows, split into what
+        install takes behind them (`[state]` from a stateful model, `[None,
+        ring_k, ring_v]` from a layer pattern, `[]` from any other) and what
+        the expert layers counted; the state is counted as installed."""
+        if self.cfg.layer_kinds:
+            return [None, *rest[:2]], rest[2:]
         n = int(self.cfg.stateful)
         if n:
             self.stats["state_installs"] += 1
@@ -531,14 +560,17 @@ class ContinuousBatcher(PrefillPrograms):
         for array in arrays:
             array.copy_to_host_async()
 
-    def _count_experts(self, load: list, rows: int) -> None:
+    def _count_experts(self, load: list, rows: int,
+                       step: bool = False) -> None:
         """Add a program's expert load (`[load]`; `[]` from a dense model's
         program) to the counters. Called where the program's tokens have
         just been copied to the host, so the load is ready (`_fetch_ahead`)
-        and this is no sync point of its own."""
+        and this is no sync point of its own. `step`: a decode step's, whose
+        reached experts (`[load, choices, reached]` from a held share) are
+        what its grouped matmuls read; a prefill reaches them all."""
         if not load:
             return
-        load = np.asarray(load[0])
+        loads, load = load, np.asarray(load[0])
         # a new list, not an update in place: `engine_stats` copies the
         # dict from another thread and must see one state
         self.stats["moe_expert_load"] = [
@@ -546,6 +578,12 @@ class ContinuousBatcher(PrefillPrograms):
                                   load.tolist())]
         self.stats["moe_assignments"] += int(load.sum())
         self.stats["moe_rows"] += rows
+        if self.cfg.experts_held:
+            first, count = self.cfg.experts_held
+            self.stats["moe_assignments_held"] += int(
+                load[first:first + count].sum())
+            if step:
+                self.stats["moe_experts_reached"] += int(np.asarray(loads[2]))
 
     def _log_routes(self, counted: list, reqs: Dict[int, _Request]) -> None:
         """Keep a program's `expert_choice` (`counted[1]`, behind the load)
@@ -676,10 +714,14 @@ class ContinuousBatcher(PrefillPrograms):
         sampled = int(draws.any())
         sorts = int((draws & (self._topks[slots] > 0)).any())
         # `rows`: the positions the step's sequences hold, its own among
-        # them: what its attention has to read
+        # them: what its attention has to read; `window_rows`: those of them
+        # a window layer's ring holds (a layer pattern alone)
+        ring = {"window_rows": int(np.minimum(
+            self._host_len[slots], self.cfg.window).sum())} \
+            if self.cfg.window else {}
         with device_span(spans.ENGINE_DECODE_DISPATCH, active=len(slots),
                          ahead=ahead, rows=int(self._host_len[slots].sum()),
-                         sampled=sampled, sorted=sorts):
+                         sampled=sampled, sorted=sorts, **ring):
             active_mask = np.zeros(self.slots, bool)
             active_mask[slots] = True
             self._rng, k = jax.random.split(self._rng)
@@ -713,7 +755,7 @@ class ContinuousBatcher(PrefillPrograms):
     def _read(self, step: _Dispatched) -> None:
         with device_span(spans.ENGINE_SAMPLE_SYNC):
             toks_np = np.asarray(step.toks)
-            self._count_experts(step.load, len(step.reqs))
+            self._count_experts(step.load, len(step.reqs), step=True)
             self._log_routes(step.load, step.reqs)
         with device_span(spans.ENGINE_EMIT):
             for slot, req in step.reqs.items():

@@ -20,6 +20,17 @@ JAX-native over ray_tpu.models.transformer — the TPU-first shape:
 Left-padding-free: prompts are right-padded, per-sequence lengths track
 the true positions, and attention masks cache slots >= the sequence's
 current length.
+
+A model may keep two kinds of rows. With a layer pattern
+(`TransformerConfig.layer_kinds`, models/laguna.py) the full layers' rows
+are the slots above, [full layers, B, max_len, kvH, D], and the window
+layers' are a RING beside them, `KVCache.ring_k` / `ring_v` [window
+layers, B, window, kvH, D]: position p lives at row p mod window, a decode
+step overwrites the row of the position that has just left the window,
+and a prefill leaves the last `window` positions of the prompt's TRUE
+length. Both ride in the same carry, are written in place and are donated
+together; for every other model the ring is None and no program has an
+operand for it (as `KVCache.state`).
 """
 
 from __future__ import annotations
@@ -48,6 +59,11 @@ class KVCache(NamedTuple):
     # appended to; None for a model whose sequences are their rows, and then
     # no program has an operand for it.
     state: Optional[jax.Array] = None
+    # The window layers' rows of a layer pattern, [window layers, B, window,
+    # kvH, D]: position p at row p mod window (models/laguna.py). `k` / `v`
+    # are then the full layers' alone. None for every other model.
+    ring_k: Optional[jax.Array] = None
+    ring_v: Optional[jax.Array] = None
 
 
 def init_state(cfg: TransformerConfig, batch: int, dtype=None):
@@ -63,12 +79,15 @@ def init_state(cfg: TransformerConfig, batch: int, dtype=None):
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None) -> KVCache:
     dtype = dtype or cfg.dtype
-    shape = (cfg.layers, batch, max_len, cfg.kv_heads, cfg.hd)
+    shape = (cfg.full_layers, batch, max_len, cfg.kv_heads, cfg.hd)
+    ring = (cfg.window_layers, batch, cfg.window, cfg.kv_heads, cfg.hd)
     return KVCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
         state=init_state(cfg, batch, dtype),
+        ring_k=jnp.zeros(ring, dtype) if cfg.layer_kinds else None,
+        ring_v=jnp.zeros(ring, dtype) if cfg.layer_kinds else None,
     )
 
 
@@ -292,7 +311,15 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     written in place beside `cache.k` / `cache.v`) which of this call's
     positions is each sequence's last: the state it leaves is that
     position's, and a sequence with no real row keeps the state it had.
+
+    A layer pattern (`cfg.layer_kinds`) has a loop of its own over the same
+    carry plus the ring (`laguna.forward_cached`): a scan over periods.
     """
+    if cfg.layer_kinds:
+        from ray_tpu.models import laguna
+
+        return laguna.forward_cached(cfg, params, tokens, positions, cache,
+                                     kv_len_mask, row_mask, access)
     x = params["embed"].astype(cfg.dtype)[tokens]
     layer_tree, whole = layers_to_scan(cfg, params)
     route = None

@@ -131,6 +131,12 @@ class DisaggPrefillEngine:
     def __init__(self, cfg: TransformerConfig, params, max_len: int = 256,
                  slots: int = 4, page_size: int = 32,
                  num_cpus: float = 0.5):
+        if cfg.layer_kinds:
+            raise ValueError(
+                f"a layer pattern {cfg.layer_kinds!r} keeps its window "
+                "layers' rows in a ring that the KV channel does not carry "
+                "and the decode replica's pages do not hold: serve it from "
+                "one replica (ContinuousBatcher)")
         if cfg.stateful:
             # the channel's row is K and V alone; decoding from it would
             # start every sequence from a new sequence's state

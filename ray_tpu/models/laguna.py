@@ -1,0 +1,418 @@
+"""A layer pattern: window layers beside full ones (poolside/Laguna-S-2.1,
+`model_type` laguna). Imported only where a configuration has one
+(`TransformerConfig.layer_kinds`); the cache's slots, the grouped attention,
+the expert matmuls, sampling and the scheduler are the other models'
+(`decoding._attend_cached` / `_write_stack`, `transformer.moe_dropless`).
+
+**Layers.** One leading layer of full attention with a dense SwiGLU MLP,
+then periods of `layer_kinds` (window, window, window, full), every one with
+sparse experts. The two kinds differ in their number of query heads
+(`heads` full, `window_heads` window; the same `kv_heads`), so `wq` / `wo`
+cannot share a stack: parameters are stacked BY KIND, `blocks["full"]`
+[full layers, ...], `blocks["window"]` [window layers, ...], `blocks["sparse"]`
+[sparse layers, ...] (its expert stacks hold the `experts_held` alone and are
+read in place by `_grouped_matmul(layer=...)`), `blocks["dense"]` the leading
+MLP alone. `forward_cached` runs the leading layer, then ONE `lax.scan` over
+periods whose body unrolls a period's layers.
+
+**Attention of kind K** on the normed stream y: `q = y Wq_K` [n_K, D], `k`,
+`v` [kv_heads, D]; RoPE by kind (full: `rope_theta` over the first
+`partial_rotary` of a head, YaRN's frequencies, cos and sin times its
+attention factor; window: `window_rope_theta`, plain, the whole head);
+causal softmax attention, a window layer over the last `window` positions
+(`i - j < window`: the token itself counts); `head_gate`: `o_r <- sigmoid(y
+Wg_K)_r o_r` before `Wo_K`.
+
+**Two kinds of rows in one carry.** A full layer's rows are slots of
+`max_len` (`KVCache.k` / `.v`, written by `_write_stack`). A window layer
+keeps `window` rows a sequence in a RING (`KVCache.ring_k` / `.ring_v`):
+position p at row p mod window. A decode step (S = 1) writes row `len mod
+window`, which held the position that has just left the window, and attends
+over the rows that hold a position (`p - ((p - r) mod window) >= 0`); every
+key is already rotated by its own position, so the ring's order does not
+matter. A call with S > 1 is a prefill FROM POSITION 0 (every engine's):
+attention runs over the fresh rows in a BAND (query blocks of `window`
+against their own block and the one before: S x 2 window logits a head, not
+S x S), and the ring it leaves is the last `window` positions of each
+sequence's TRUE length (`row_mask`), zero where there is none, the whole
+ring overwritten.
+
+**Experts.** `moe_router` over all `num_experts` (renormalised top-k times
+`routed_scale`), `moe_dropless` with `experts_held` for the experts whose
+weights are here, and the shared expert (`moe.shared`), a dense SwiGLU that
+every token runs, added ungated. The chips that share a layer each hold a
+share of its experts; what the absent ones would add is left out here, and
+nothing stands in for the other chip or for the exchange.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from ray_tpu.models.decoding import KVCache, _attend_cached, _write_stack
+from ray_tpu.models.transformer import (
+    TransformerConfig, _rms_norm, _rope, moe_dropless, moe_router,
+)
+from ray_tpu.ops.attention import NEG_INF
+
+EXPERT_LEAVES = ("wi_gate", "wi_up", "wo_mlp")
+# A leaf larger than this many elements is drawn a piece at a time
+# (`_draw`): its float32 draw would not fit beside the leaves before it.
+WHOLE_DRAW_MAX = 1 << 28
+
+
+# -- parameters --------------------------------------------------------------
+
+def leaves(cfg: TransformerConfig) -> dict:
+    """{(group, ..., name): (shape, fan_in, logical axes)} of every
+    parameter leaf; `fan_in` None is a norm's weight (ones)."""
+    h, d, nkv = cfg.hidden, cfg.hd, cfg.kv_heads
+    out = {("embed",): ((cfg.vocab_size, h), h, ("vocab", "embed")),
+           ("unembed",): ((h, cfg.vocab_size), h, ("embed", "vocab")),
+           ("ln_f",): ((h,), None, ("norm",))}
+    for kind, n, nh in (("full", cfg.full_layers, cfg.heads),
+                        ("window", cfg.window_layers, cfg.window_heads)):
+        at = ("blocks", kind)
+        out[at + ("wq",)] = ((n, h, nh, d), h,
+                             ("layers", "embed", "heads", "head_dim"))
+        for name in ("wk", "wv"):
+            out[at + (name,)] = ((n, h, nkv, d), h,
+                                 ("layers", "embed", "kv_heads", "head_dim"))
+        out[at + ("wo",)] = ((n, nh, d, h), nh * d,
+                             ("layers", "heads", "head_dim", "embed"))
+        if cfg.head_gate:
+            out[at + ("wg",)] = ((n, h, nh), h, ("layers", "embed", "heads"))
+        out[at + ("ln_attn",)] = ((n, h), None, ("layers", "norm"))
+    m = cfg.dense_mlp_hidden
+    out[("blocks", "dense", "ln_mlp")] = ((h,), None, ("norm",))
+    out[("blocks", "dense", "wi_gate")] = ((h, m), h, ("embed", "mlp"))
+    out[("blocks", "dense", "wi_up")] = ((h, m), h, ("embed", "mlp"))
+    out[("blocks", "dense", "wo_mlp")] = ((m, h), m, ("mlp", "embed"))
+    n, m, at = cfg.sparse_layers, cfg.mlp_hidden, ("blocks", "sparse")
+    held = cfg.experts_held[1] if cfg.experts_held else cfg.num_experts
+    out[at + ("ln_mlp",)] = ((n, h), None, ("layers", "norm"))
+    out[at + ("router",)] = ((n, h, cfg.num_experts), h,
+                             ("layers", "embed", None))
+    out[at + ("wi_gate",)] = ((n, held, h, m), h,
+                              ("layers", "expert", "embed", "mlp"))
+    out[at + ("wi_up",)] = ((n, held, h, m), h,
+                            ("layers", "expert", "embed", "mlp"))
+    out[at + ("wo_mlp",)] = ((n, held, m, h), m,
+                             ("layers", "expert", "mlp", "embed"))
+    if cfg.shared_expert_hidden:
+        ms = cfg.shared_expert_hidden
+        out[at + ("shared_gate",)] = ((n, h, ms), h,
+                                      ("layers", "embed", "mlp"))
+        out[at + ("shared_up",)] = ((n, h, ms), h, ("layers", "embed", "mlp"))
+        out[at + ("shared_down",)] = ((n, ms, h), ms,
+                                      ("layers", "mlp", "embed"))
+    return out
+
+
+def _tree(flat: dict) -> dict:
+    out: dict = {}
+    for path, value in flat.items():
+        node = out
+        for name in path[:-1]:
+            node = node.setdefault(name, {})
+        node[path[-1]] = value
+    return out
+
+
+def num_params(cfg: TransformerConfig) -> int:
+    """What is HELD here: `experts_held` experts a layer, not `num_experts`."""
+    return sum(math.prod(shape) for shape, _, _ in leaves(cfg).values())
+
+
+def param_axes(cfg: TransformerConfig) -> dict:
+    return _tree({path: axes for path, (_, _, axes) in leaves(cfg).items()})
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "fan_in", "dtype"))
+def _draw(key, shape, fan_in, dtype):
+    """A leaf at its stacked shape, never held twice or whole in float32: a
+    large one is drawn over its leading axes a piece at a time and cast
+    inside (the one block's `stack()` holds a Python list of layers AND
+    their `jnp.stack`, each drawn in float32: 14.2 GB for OLMoE's 7.1)."""
+    lead = 0
+    while math.prod(shape[lead:]) > WHOLE_DRAW_MAX and lead < len(shape) - 1:
+        lead += 1
+
+    def piece(k):
+        return (jax.random.normal(k, shape[lead:], jnp.float32)
+                / math.sqrt(fan_in)).astype(dtype)
+
+    if not lead:
+        return piece(key)
+    keys = jax.random.split(key, math.prod(shape[:lead]))
+    return lax.map(piece, keys).reshape(shape)
+
+
+def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
+    flat = leaves(cfg)
+    out = {}
+    for i, (path, (shape, fan_in, _)) in enumerate(flat.items()):
+        if fan_in is None:
+            out[path] = jnp.ones(shape, cfg.param_dtype)
+        else:
+            out[path] = _draw(jax.random.fold_in(key, i), shape, fan_in,
+                              cfg.param_dtype)
+    return _tree(out)
+
+
+# -- rotary embeddings by kind -------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def full_rope_table(cfg: TransformerConfig):
+    """(rotated dimensions, their rot/2 inverse frequencies, the factor on
+    cos and sin) of a full layer: `rope_theta` over `partial_rotary` of the
+    head; with `rope_yarn` the frequencies are YaRN's (a dimension that turns
+    more than `beta_fast` times within the original positions keeps its
+    frequency, one that turns less than `beta_slow` times has it divided by
+    `factor`, a linear ramp between) and cos and sin carry its attention
+    factor."""
+    rot = int(cfg.hd * cfg.partial_rotary)
+    inv = cfg.rope_theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+    if cfg.rope_yarn is None:
+        return rot, inv.astype(np.float32), 1.0
+    factor, original, beta_fast, beta_slow, attention_factor = cfg.rope_yarn
+
+    def dimension_of(turns):
+        return rot * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(dimension_of(beta_fast)), 0)
+    high = min(math.ceil(dimension_of(beta_slow)), rot - 1)
+    if low == high:
+        high += 0.001
+    keep = 1.0 - np.clip((np.arange(rot // 2) - low) / (high - low), 0, 1)
+    inv = inv / factor * (1 - keep) + inv * keep
+    return rot, inv.astype(np.float32), float(attention_factor)
+
+
+def rope(cfg: TransformerConfig, kind: str, x, positions):
+    """x [B, S, heads, D] rotated by `kind`'s rule (rotate-half layout)."""
+    if kind == "window":
+        return _rope(x, positions, cfg.window_rope_theta)
+    rot, inv, scale = full_rope_table(cfg)
+    ang = positions[..., None].astype(jnp.float32) * inv  # [B, S, rot/2]
+    cos = (jnp.cos(ang) * scale)[:, :, None, :]
+    sin = (jnp.sin(ang) * scale)[:, :, None, :]
+    x1, x2 = jnp.split(x[..., :rot].astype(jnp.float32), 2, axis=-1)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([turned.astype(x.dtype), x[..., rot:]], axis=-1)
+
+
+# -- attention -----------------------------------------------------------------
+
+def _attend_band(q, k, v, window: int):
+    """A prefill's window attention over its own fresh rows: q [B, S, H, D],
+    k / v [B, S, kvH, D] at positions 0..S-1, query i against keys j with
+    `0 <= i - j < window`. In blocks of `window` queries against their own
+    block of keys and the one before: [H, S, 2 window] float32 logits, where
+    the full mask would take [H, S, S]."""
+    b, s, h, d = q.shape
+    kvh = k.shape[2]
+    if s <= window:  # every earlier position is inside the window
+        pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+        return _attend_cached(q, k, v, pos, jnp.ones((b, s), bool))
+    pad = -s % window
+    if pad:  # pad keys lie behind every real query
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+    nb = (s + pad) // window
+    q6 = q.reshape(b, nb, window, kvh, h // kvh, d)
+
+    def with_before(rows):  # [B, nb, 2 window, kvH, D]
+        blocks = rows.reshape(b, nb, window, kvh, d)
+        before = jnp.concatenate(
+            [jnp.zeros_like(blocks[:, :1]), blocks[:, :-1]], axis=1)
+        return jnp.concatenate([before, blocks], axis=2)
+
+    k2, v2 = with_before(k), with_before(v)
+    logits = jnp.einsum("bnqgrd,bntgd->bngrqt", q6, k2,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+    # query a of a block is key a + window of its 2 window keys
+    a, t = jnp.arange(window)[:, None], jnp.arange(2 * window)[None, :]
+    mask = (t > a) & (t <= a + window)
+    first = (jnp.arange(nb) == 0)[:, None, None]  # no block before block 0
+    mask = jnp.where(first, mask & (t >= window), mask)  # [nb, w, 2w]
+    logits = jnp.where(mask[None, :, None, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bngrqt,bntgd->bnqgrd", probs, v2,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, nb * window, h, d)[:, :s].astype(q.dtype)
+
+
+def _ring_positions(last, window: int):
+    """[B, window]: the position each ring row holds when a sequence's newest
+    position is `last` [B]; negative where the row holds none yet."""
+    last = last[:, None]
+    return last - (last - jnp.arange(window)) % window
+
+
+def _ring_attention(cfg: TransformerConfig, q, k, v, positions, row_mask,
+                    ring_k, ring_v, layer):
+    """A window layer's cache access and attention. Returns (ring_k, ring_v,
+    attention [B, S, H, D]); the rings are the stacks [window layers, B,
+    window, kvH, D], written at `layer` in place."""
+    w = cfg.window
+    b, s = q.shape[:2]
+    k, v = k.astype(ring_k.dtype), v.astype(ring_v.dtype)
+    if s == 1:  # a decode step: one row in, the ring read once
+        pos = positions[:, 0]
+        bidx = jnp.arange(b)
+        ring_k = ring_k.at[layer, bidx, pos % w].set(k[:, 0])
+        ring_v = ring_v.at[layer, bidx, pos % w].set(v[:, 0])
+        holds = _ring_positions(pos, w) >= 0
+        attn = _attend_cached(
+            q, lax.dynamic_index_in_dim(ring_k, layer, keepdims=False),
+            lax.dynamic_index_in_dim(ring_v, layer, keepdims=False),
+            jnp.full((b, 1), w), holds)  # every row it holds is in the past
+        return ring_k, ring_v, attn
+    # a prefill from position 0: attention over the fresh rows, and the ring
+    # as the sequence's TRUE last position leaves it
+    attn = _attend_band(q, k, v, w)
+    held = _ring_positions(row_mask.sum(1).astype(jnp.int32) - 1, w)
+    at = jnp.clip(held, 0, s - 1)[:, :, None, None]
+
+    def rows(fresh):
+        return jnp.where((held >= 0)[:, :, None, None],
+                         jnp.take_along_axis(fresh, at, axis=1), 0)
+
+    return (lax.dynamic_update_index_in_dim(ring_k, rows(k), layer, 0),
+            lax.dynamic_update_index_in_dim(ring_v, rows(v), layer, 0), attn)
+
+
+def attention(cfg: TransformerConfig, kind: str, x, p, positions, k_cache,
+              v_cache, kv_len_mask, row_mask, layer):
+    """The attention half of a layer of `kind` ("full": `k_cache` / `v_cache`
+    are the slots' stacks, written by `_write_stack`; "window": the ring
+    stacks), `layer` its index within its kind. Returns (x, k_cache,
+    v_cache)."""
+    with jax.named_scope(f"attn.{kind}"):
+        y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
+        q = jnp.einsum("bsh,hnd->bsnd", y, p["wq"].astype(y.dtype))
+        k = jnp.einsum("bsh,hnd->bsnd", y, p["wk"].astype(y.dtype))
+        v = jnp.einsum("bsh,hnd->bsnd", y, p["wv"].astype(y.dtype))
+        q, k = rope(cfg, kind, q, positions), rope(cfg, kind, k, positions)
+        if kind == "full":
+            k_cache, v_cache, k_layer, v_layer = _write_stack(layer)(
+                k_cache, v_cache, k, v, positions)
+            attn = _attend_cached(q, k_layer, v_layer, positions, kv_len_mask)
+        else:
+            k_cache, v_cache, attn = _ring_attention(
+                cfg, q, k, v, positions, row_mask, k_cache, v_cache, layer)
+        if cfg.head_gate:
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsh,hn->bsn", y, p["wg"].astype(y.dtype),
+                preferred_element_type=jnp.float32))
+            attn = (attn * gate[..., None]).astype(attn.dtype)
+        out = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
+    return x + out, k_cache, v_cache
+
+
+# -- the MLP halves ---------------------------------------------------------------
+
+def _swiglu(y, gate, up, down):
+    act = jax.nn.silu(jnp.einsum("bsh,hm->bsm", y, gate.astype(y.dtype))) \
+        * jnp.einsum("bsh,hm->bsm", y, up.astype(y.dtype))
+    return jnp.einsum("bsm,mh->bsh", act, down.astype(act.dtype))
+
+
+def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer):
+    """The expert half of sparse layer `layer`: `p` is that layer's small
+    parameters and the WHOLE expert stacks (`_grouped_matmul` reads its
+    layer in place). Returns (x, load [num_experts] from the real rows, the
+    experts every row chose [B*S, k], how many of the experts held here the
+    real rows reached)."""
+    y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
+    routing = moe_router(cfg, y.reshape(-1, y.shape[-1]), p)
+    routed, load = moe_dropless(cfg, y, p, row_mask, layer, routing)
+    x = x + routed
+    if cfg.shared_expert_hidden:
+        with jax.named_scope("moe.shared"):
+            x = x + _swiglu(y, p["shared_gate"], p["shared_up"],
+                            p["shared_down"])
+    first, count = cfg.experts_held or (0, cfg.num_experts)
+    reached = (load[first:first + count] > 0).sum().astype(jnp.int32)
+    return x, load.astype(jnp.int32), routing[1], reached
+
+
+def _take(tree, i):
+    """Layer `i` of a kind's stacked parameters."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+def forward_cached(cfg: TransformerConfig, params, tokens, positions,
+                   cache: KVCache, kv_len_mask, row_mask, access=_write_stack):
+    """`decoding.forward_cached` for a layer pattern: the same arguments and
+    results, the carry being the residual stream, the full layers' stacks
+    and the window layers' rings. `aux` is {"expert_load": int32
+    [num_experts], every routed assignment of the real rows summed over the
+    sparse layers; "expert_choice": int32 [sparse layers, B*S, k], every
+    row's experts in every layer (the k-th and (k+1)-th probability of 256
+    lie close, rounding flips them, and a flipped expert moves a logit by a
+    third of the logits' spread: a comparison with a reference has to know
+    the sets that were taken, as with ZAYA1's one expert);
+    "experts_reached": how many experts held here the real rows reached,
+    summed over the layers: what a step's grouped matmuls read}."""
+    if access is not _write_stack:
+        raise ValueError(
+            f"a layer pattern {cfg.layer_kinds!r} keeps its full layers' rows "
+            "in slots and its window layers' in a ring: no other cache "
+            "access (pages) holds a ring")
+    blocks, kinds = params["blocks"], cfg.layer_kinds
+    sparse = {n: a for n, a in blocks["sparse"].items()
+              if n not in EXPERT_LEAVES}
+    experts = {n: blocks["sparse"][n] for n in EXPERT_LEAVES}
+    x = params["embed"].astype(cfg.dtype)[tokens]
+
+    x, k, v = attention(cfg, "full", x, _take(blocks["full"], 0), positions,
+                        cache.k, cache.v, kv_len_mask, row_mask, 0)
+    dense = blocks["dense"]
+    with jax.named_scope("mlp"):
+        x = x + _swiglu(_rms_norm(x, dense["ln_mlp"], cfg.norm_eps),
+                        dense["wi_gate"], dense["wi_up"], dense["wo_mlp"])
+
+    def period(carry, i):
+        x, k, v, ring_k, ring_v = carry
+        full = 1 + i * kinds.count("full")
+        window = i * kinds.count("window")
+        load, reached, choices = 0, 0, []
+        for j, kind in enumerate(kinds):
+            if kind == "full":
+                x, k, v = attention(
+                    cfg, kind, x, _take(blocks["full"], full), positions, k, v,
+                    kv_len_mask, row_mask, full)
+                full += 1
+            else:
+                x, ring_k, ring_v = attention(
+                    cfg, kind, x, _take(blocks["window"], window), positions,
+                    ring_k, ring_v, kv_len_mask, row_mask, window)
+                window += 1
+            layer = i * len(kinds) + j
+            x, l, chosen, r = sparse_mlp(
+                cfg, x, dict(_take(sparse, layer), **experts), row_mask, layer)
+            load, reached = load + l, reached + r
+            choices.append(chosen)
+        return (x, k, v, ring_k, ring_v), (load, jnp.stack(choices), reached)
+
+    (x, k, v, ring_k, ring_v), (load, choice, reached) = lax.scan(
+        period, (x, k, v, cache.ring_k, cache.ring_v),
+        jnp.arange(cfg.periods))
+    aux = {"expert_load": load.sum(0),
+           "expert_choice": choice.reshape(-1, *choice.shape[2:]),
+           "experts_reached": reached.sum()}
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsh,hv->bsv", x,
+                            params["unembed"].astype(x.dtype))
+    return logits, KVCache(k, v, cache.lengths, None, ring_k, ring_v), aux

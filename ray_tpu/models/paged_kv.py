@@ -172,6 +172,13 @@ class PagedBatcher(ContinuousBatcher):
         recompute-preemption absorb the shortfall — vLLM's model);
         ``extra_pages`` adds headroom so freed prefix pages survive
         longer in the cache."""
+        if cfg.layer_kinds:
+            # and with it `submit_prefilled`: a premade row brings no ring
+            raise ValueError(
+                f"a layer pattern {cfg.layer_kinds!r} keeps its window "
+                "layers' rows in a ring beside the slots; pages hold no ring "
+                "(prefix reuse and preemption would have to rebuild it): "
+                "serve it from ContinuousBatcher")
         if max_len % page_size != 0:
             raise ValueError("max_len must be a multiple of page_size")
         self.page_size = page_size

@@ -82,8 +82,38 @@ class TransformerConfig:
     # projection that also adds the layer before's projection)
     router: str = "linear"
     router_hidden: int = 0
-    # share of each head's dimensions that RoPE rotates ("cca" only)
+    # share of each head's dimensions that RoPE rotates ("cca", and the
+    # full layers of a layer pattern)
     partial_rotary: float = 1.0
+    # A layer pattern (models/laguna.py; the cached forward alone runs it):
+    # one leading layer of full attention with a dense MLP `dense_mlp_hidden`
+    # wide, then (layers - 1) / len(layer_kinds) periods of these kinds,
+    # "window" or "full", every one with sparse experts. () = one kind of
+    # layer, the block below. A window layer has `window_heads` query heads
+    # (`heads` are a full layer's), attends to the last `window` positions,
+    # the token's own among them, and keeps that many K/V rows a sequence in
+    # a ring (`KVCache.ring_k`); its RoPE is plain at `window_rope_theta`
+    # over the whole head, a full layer's is `rope_theta` over
+    # `partial_rotary` of the head, scaled by `rope_yarn` = (factor,
+    # original positions, beta_fast, beta_slow, attention_factor).
+    layer_kinds: Tuple[str, ...] = ()
+    window: int = 0
+    window_heads: int = 0
+    window_rope_theta: float = 10000.0
+    rope_yarn: Optional[Tuple[float, ...]] = None
+    dense_mlp_hidden: int = 0
+    # one sigmoid gate a query head, from the layer's normed input, on the
+    # head's output before `wo` (a layer pattern's attention)
+    head_gate: bool = False
+    # Expert layers of `moe_dropless` (the cached forward). The k router
+    # weights times `routed_scale`; a dense SwiGLU of `shared_expert_hidden`
+    # that every token runs beside its k experts; and `experts_held` =
+    # (first, count): the experts whose weights are HERE, where two or more
+    # chips share a layer. The router still scores all `num_experts`; what
+    # the absent ones would add is left out.
+    routed_scale: float = 1.0
+    shared_expert_hidden: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if self.attention not in ("gqa", "cca"):
@@ -94,9 +124,48 @@ class TransformerConfig:
                                               and self.router_hidden):
             raise ValueError("router 'zaya_mlp' needs num_experts and "
                              "router_hidden")
-        if self.attention == "gqa" and self.partial_rotary != 1.0:
-            raise ValueError("partial_rotary is read by attention 'cca' "
-                             "alone")
+        if self.attention == "gqa" and self.partial_rotary != 1.0 \
+                and not self.layer_kinds:
+            raise ValueError("partial_rotary is read by attention 'cca' and "
+                             "by a layer pattern's full layers alone")
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not (self.num_experts and 0 <= first and count >= 1
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no share of "
+                    f"num_experts {self.num_experts}")
+        pattern = (self.window, self.window_heads, self.dense_mlp_hidden,
+                   self.head_gate, self.rope_yarn, self.shared_expert_hidden,
+                   self.experts_held)
+        if not self.layer_kinds:
+            if any(pattern):
+                raise ValueError(
+                    "window, window_heads, dense_mlp_hidden, head_gate, "
+                    "rope_yarn, shared_expert_hidden and experts_held belong "
+                    "to a layer pattern (layer_kinds): the one block has none")
+            return
+        if set(self.layer_kinds) - {"window", "full"}:
+            raise ValueError(f"unknown layer kinds {self.layer_kinds!r}")
+        if (self.layers - 1) % len(self.layer_kinds) or self.layers < 2:
+            raise ValueError(
+                f"layers {self.layers} is not one leading layer and whole "
+                f"periods of {self.layer_kinds!r}")
+        if not (self.window > 0 and self.window_heads and self.num_experts
+                and self.dense_mlp_hidden):
+            raise ValueError("a layer pattern needs window, window_heads, "
+                             "num_experts and dense_mlp_hidden")
+        if self.window_heads % self.kv_heads or self.heads % self.kv_heads:
+            raise ValueError("both kinds' query heads are whole groups of "
+                             "kv_heads")
+        if self.attention != "gqa" or self.router != "linear" \
+                or self.qk_norm or self.lora_rank or self.tie_embeddings:
+            raise ValueError("a layer pattern's layers are grouped-query "
+                             "attention and a linear router, without QK-norm, "
+                             "adapters or a tied head")
+        if self.rope_yarn is not None and len(self.rope_yarn) != 5:
+            raise ValueError("rope_yarn is (factor, original positions, "
+                             "beta_fast, beta_slow, attention_factor)")
 
     @property
     def hd(self) -> int:
@@ -107,6 +176,34 @@ class TransformerConfig:
         """Whether a sequence keeps more than K/V rows between steps
         (`KVCache.state`)."""
         return self.attention == "cca"
+
+    @property
+    def periods(self) -> int:
+        """Periods of `layer_kinds` behind the leading layer (0: no pattern)."""
+        return (self.layers - 1) // len(self.layer_kinds) \
+            if self.layer_kinds else 0
+
+    @property
+    def full_layers(self) -> int:
+        """Layers whose K/V rows are slots of `max_len` (`KVCache.k`): all
+        of them without a pattern; with one, the leading layer and each
+        period's "full" ones."""
+        if not self.layer_kinds:
+            return self.layers
+        return 1 + self.periods * self.layer_kinds.count("full")
+
+    @property
+    def window_layers(self) -> int:
+        """Layers whose K/V rows are a ring of `window` (`KVCache.ring_k`)."""
+        return self.periods * self.layer_kinds.count("window")
+
+    @property
+    def sparse_layers(self) -> int:
+        """Layers that route: all of a sparse model's, or all but a
+        pattern's leading dense one."""
+        if not self.num_experts:
+            return 0
+        return self.layers - 1 if self.layer_kinds else self.layers
 
     def flops_per_token(self) -> float:
         """Approx forward+backward FLOPs/token (6*N + attention), for MFU.
@@ -119,6 +216,10 @@ class TransformerConfig:
     def num_params(self) -> int:
         h, m, l, v = self.hidden, self.mlp_hidden, self.layers, self.vocab_size
         hd, nh, nkv = self.hd, self.heads, self.kv_heads
+        if self.layer_kinds:
+            from ray_tpu.models import laguna
+
+            return laguna.num_params(self)
         mlp = 3 * h * m
         if self.num_experts:
             mlp = self.num_experts * 3 * h * m + h * self.num_experts  # + router
@@ -192,6 +293,23 @@ PRESETS: Dict[str, TransformerConfig] = {
         norm_topk_prob=False, attention="cca", router="zaya_mlp",
         router_hidden=32, partial_rotary=0.5, dtype=jnp.float32,
     ),
+    # poolside/Laguna-S-2.1's block at debug widths (models/laguna.py): one
+    # full layer with a dense MLP, then two periods of three window layers
+    # (6 heads, a ring of 8) and one full layer (4 heads), a gate a head,
+    # top-4 of 16 experts times 2.5 of which 8 are held, a shared expert.
+    # The published widths are the benchmark's to build from `config.json`
+    # (benchmarks/runners/serve_laguna.py)
+    "laguna_debug": TransformerConfig(
+        vocab_size=512, hidden=128, mlp_hidden=64, layers=9, heads=4,
+        kv_heads=2, head_dim=16, max_seq=128, remat=False, rope_theta=5e5,
+        norm_eps=1e-6, num_experts=16, experts_per_token=4,
+        norm_topk_prob=True, partial_rotary=0.5,
+        layer_kinds=("window", "window", "window", "full"), window=8,
+        window_heads=6, window_rope_theta=1e4,
+        rope_yarn=(4.0, 16.0, 32.0, 1.0, 1.1386294361119891),
+        dense_mlp_hidden=192, head_gate=True, routed_scale=2.5,
+        shared_expert_hidden=64, experts_held=(0, 8), dtype=jnp.float32,
+    ),
 }
 
 
@@ -213,6 +331,10 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     leading ``layers`` dim so the forward is one ``lax.scan`` — one XLA
     while-loop body compiled once, not ``layers`` inlined copies (compile
     time and HBM win on TPU)."""
+    if cfg.layer_kinds:  # stacked by kind, never held twice
+        from ray_tpu.models import laguna
+
+        return laguna.init_params(cfg, key)
     h, m, v, l = cfg.hidden, cfg.mlp_hidden, cfg.vocab_size, cfg.layers
     hd, nh, nkv = cfg.hd, cfg.heads, cfg.kv_heads
     pd = cfg.param_dtype
@@ -272,6 +394,10 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
 def param_axes(cfg: TransformerConfig) -> Params:
     """Pytree of logical-axis tuples mirroring init_params output.
     Feed to parallel.sharding.tree_shardings(mesh, ...) for NamedShardings."""
+    if cfg.layer_kinds:
+        from ray_tpu.models import laguna
+
+        return laguna.param_axes(cfg)
     block_axes: Params = {
         "wq": ("layers", "embed", "heads", "head_dim"),
         "wk": ("layers", "embed", "kv_heads", "head_dim"),
@@ -417,13 +543,15 @@ def _grouped_matmul(rows, weights, group_sizes, layer=None):
 def moe_router(cfg: TransformerConfig, x, p):
     """x [T,h] -> (weights [T,k] float32, experts [T,k] int32): the top-k of
     a float32 softmax over all experts, renormalised to sum to one only if
-    `cfg.norm_topk_prob`."""
+    `cfg.norm_topk_prob`, then times `cfg.routed_scale`."""
     logits = jnp.einsum("th,he->te", x, p["router"].astype(x.dtype),
                         preferred_element_type=jnp.float32)
     weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1),
                                  cfg.experts_per_token)
     if cfg.norm_topk_prob:
         weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scale != 1.0:
+        weights = weights * cfg.routed_scale
     return weights, experts
 
 
@@ -449,6 +577,15 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
     the whole stacks [L,E,...] and `layer` the index to use
     (`_grouped_matmul` says why a layer scan wants that). `routing` is
     `moe_router`'s pair from a router of another kind (`zaya.router`).
+
+    `cfg.experts_held` = (first, count) says which of the `num_experts` have
+    their weights here (`p`'s expert stacks are [count, ...]): a layer that two or
+    more chips share, each holding its experts. The router scores and
+    chooses over all of them; the assignments to absent experts sort behind
+    the held groups, are in no group, and their rows are set to zero before
+    the weighted sum, so what they add is exactly nothing. T*k rows stay
+    static. `load` still counts every expert: held / all is the share of
+    the routed work that is done here.
     """
     b, s, h = y.shape
     e, k = cfg.num_experts, cfg.experts_per_token
@@ -456,9 +593,15 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
     x = y.reshape(t, h)
     weights, experts = routing or moe_router(cfg, x, p)
     flat = experts.reshape(t * k)  # assignment a = token * k + choice
+    held = cfg.experts_held
+    group, groups = flat, e  # the group of weights an assignment multiplies
+    if held is not None:
+        first, groups = held
+        here = (flat >= first) & (flat < first + groups)
+        group = jnp.where(here, flat - first, groups)  # absent: behind all
     with jax.named_scope("moe_experts"):
-        order = jnp.argsort(flat, stable=True)  # sorted row -> assignment
-        group_sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+        order = jnp.argsort(group, stable=True)  # sorted row -> assignment
+        group_sizes = jnp.bincount(group, length=groups).astype(jnp.int32)
         rows = x[order // k]  # [T*k, h], one expert's rows adjoin
         gate, up = (_grouped_matmul(rows, p[name].astype(x.dtype),
                                     group_sizes, layer)
@@ -466,13 +609,19 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
         act = (jax.nn.silu(gate) * up).astype(x.dtype)
         down = _grouped_matmul(act, p["wo_mlp"].astype(x.dtype), group_sizes,
                                layer)
+        if held is not None:  # rows of no group: whatever the kernel left
+            down = jnp.where(here[order][:, None], down, 0.0)
         # back to assignment order (a gather by the inverse permutation),
         # then the weighted sum over each token's k experts, in float32
         inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
         out = (down[inverse].reshape(t, k, h) * weights[..., None]).sum(1)
-    load = group_sizes if row_mask is None else jnp.bincount(
-        flat, weights=jnp.repeat(row_mask.reshape(t).astype(jnp.int32), k),
-        length=e)
+    if row_mask is None:
+        load = group_sizes if held is None else jnp.bincount(
+            flat, length=e).astype(jnp.int32)
+    else:
+        load = jnp.bincount(
+            flat, weights=jnp.repeat(row_mask.reshape(t).astype(jnp.int32), k),
+            length=e)
     return out.astype(y.dtype).reshape(b, s, h), load
 
 
@@ -542,6 +691,12 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
     ``mesh`` with a "stage" axis > 1 switches the layer stack to
     pipeline parallelism (ops/pipeline.py) with ``num_microbatches``.
     """
+    if cfg.layer_kinds:
+        raise ValueError(
+            f"a layer pattern {cfg.layer_kinds!r} (window layers beside full "
+            "ones, parameters stacked by kind) runs in the cached forward "
+            "alone (decoding.forward_cached): the training forward scans one "
+            "kind of block")
     if cfg.stateful or cfg.router != "linear":
         raise ValueError(
             f"attention {cfg.attention!r} / router {cfg.router!r} run in the "

@@ -68,6 +68,8 @@ DEVICE_SPANS = {
     ENGINE_PREFILL_DISPATCH: "",
     ENGINE_INSTALL_DISPATCH: "",
     ENGINE_FIRST_TOKEN_SYNC: "",
+    # a layer pattern's engine (models/laguna.py) adds `window_rows`: of
+    # `rows`, those a window layer's ring holds (min(rows, window) a slot)
     ENGINE_DECODE_DISPATCH: "active, ahead, rows, sampled, sorted",
     ENGINE_SAMPLE_SYNC: "",
     ENGINE_EMIT: "",
@@ -87,6 +89,12 @@ PROGRAM_SCOPES = {
     "cca.attend": "models/zaya.py: L2 norm, RoPE, the row write, attention "
                   "over the cache (attend_cached inside it), wo",
     "zaya.router": "models/zaya.py: projection, carried sum, MLP, choice",
+    "attn.full": "models/laguna.py: a full layer's projections, RoPE, row "
+                 "write, attention over its slots (attend_cached inside "
+                 "it), the gate, wo",
+    "attn.window": "models/laguna.py: the same of a window layer, over its "
+                   "ring",
+    "moe.shared": "models/laguna.py: the shared expert's SwiGLU",
     "moe_router": "models/transformer.py: the linear router and its top-k",
     "moe_experts": "models/transformer.py: sort, grouped matmuls, unsort",
     "attend_cached": "models/decoding.py: attention over the cached rows",

@@ -154,6 +154,17 @@ SERVE_CONFIGS = {
     "zaya1-8b-serve-d16": dict(
         name="zaya1_8b", layers=16, param_dtype=jnp.bfloat16, slots=32,
         bucket=1024),
+    # Laguna-S-2.1's published widths, one chip's share of a layer that two
+    # hold: 5 of 48 layers, 128 of 256 experts, half the vocabulary; 32
+    # slots of 4096 positions, the 2048 bucket
+    "laguna-s-2.1-serve-ep2-d5": dict(
+        name="laguna_debug", vocab_size=50176, hidden=3072, mlp_hidden=1024,
+        layers=5, heads=48, kv_heads=8, head_dim=128, max_seq=1048576,
+        num_experts=256, experts_per_token=10, experts_held=(0, 128),
+        window=512, window_heads=72, dense_mlp_hidden=12288,
+        shared_expert_hidden=1024,
+        rope_yarn=(128.0, 8192.0, 32.0, 1.0, 1.4852030263919618),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, slots=32, max_len=4096),
 }
 
 
@@ -176,16 +187,18 @@ def serve_programs(topo):
         widths = dict(SERVE_CONFIGS[name])
         slots = widths.pop("slots", SERVE_SLOTS)
         bucket = widths.pop("bucket", SEQ)
+        max_len = widths.pop("max_len", SEQ)
         cfg = T.config(widths.pop("name"), **widths)
         params = _on(one, jax.eval_shape(
             lambda: T.init_params(cfg, jax.random.key(0))))
         batcher = ContinuousBatcher.__new__(ContinuousBatcher)  # programs only
-        batcher.cfg, batcher.max_len, batcher.slots = cfg, SEQ, slots
+        batcher.cfg, batcher.max_len, batcher.slots = cfg, max_len, slots
         batcher._jit_programs()
         prefill = jax.jit(batcher._prefill_impl).lower(  # as `_prefill_into`
             params, arr((1, bucket), jnp.int32), arr((1,), jnp.int32)
         ).compile()
-        cache = _on(one, jax.eval_shape(lambda: init_cache(cfg, slots, SEQ)))
+        cache = _on(one, jax.eval_shape(
+            lambda: init_cache(cfg, slots, max_len)))
         decode = batcher._decode_jit.lower(
             params, arr((slots,), jnp.int32), cache,
             _on(one, jax.eval_shape(lambda: jax.random.key(0))),
@@ -302,6 +315,72 @@ def test_stateful_serve_programs_compile_and_fit(serve_programs):
     from ray_tpu.observability import schema
 
     assert set(scope_ops.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+def test_window_serve_programs_compile_and_fit(serve_programs):
+    """The `serve-window-moe-code-long-out` deployment (Laguna-S-2.1 at its
+    published widths, 5 of 48 layers, 128 of 256 experts, 32 slots x 4096):
+    the decode step is given BOTH kinds of rows to keep, the full layers'
+    slots and the window layers' rings (both aliased in to out, neither a
+    temporary, each written a row at a time), the prefill of the 2048 bucket
+    returns the ring beside its rows and computes a window layer's logits in
+    a band ([72, S, 2 x 512], never [72, S, S]), the held experts are read
+    in place, both programs fit the chip beside each other's arguments, and
+    the scopes reach the compiled text."""
+    from benchmarks import harness, scope_ops
+
+    cfg, prefill, decode, cache = serve_programs("laguna-s-2.1-serve-ep2-d5")
+    slots = cache.lengths.shape[0]
+    assert cache.k.shape == (2, slots, 4096, 8, 128)
+    assert cache.ring_k.shape == (3, slots, 512, 8, 128)
+    assert cache.state is None
+    kept = _arg_bytes((cache.k, cache.v, cache.ring_k, cache.ring_v))
+    assert round(kept / 1e9, 3) == 1.275  # 1.074 of slots, 0.201 of rings
+    for name, program in (("prefill[2048]", prefill),
+                          (f"decode[{slots}x4096]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        # the load over all 256 experts leaves the program beside its tokens
+        assert "s32[256]" in program.as_text()
+        # no layer's held experts (2.4 GB) are copied out of their stack
+        assert not re.search(r"bf16\[128,(3072,1024|1024,3072)\]",
+                             program.as_text())
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    assert m.temp_size_in_bytes < _arg_bytes((cache.k, cache.v)) / 2
+    assert _total_bytes(decode) < 13.5e9
+    # a prefill runs beside the engine's cache
+    assert _total_bytes(prefill) + kept < 15.5e9
+    text = decode.as_text()
+    written = re.findall(r"%(\S+) = (\S+) ([\w-]+)\(", text)
+    for stack in (cache.k, cache.ring_k):
+        shape = "bf16[" + ",".join(map(str, stack.shape)) + "]"
+        assert [n for n, sh, op in written if sh.startswith(shape)
+                and "scatter" in n + op], shape
+        assert not [n for n, sh, op in written if sh.startswith(shape)
+                    and "dynamic-update-slice" in n + op], shape
+    # a window layer's prefill costs S x 2 window: no [*, 2048, 2048] logits
+    # of 72 heads, the band's [.., 512, 1024] instead
+    ptext = prefill.as_text()
+    assert re.search(r"f32\[1,4,8,9,512,1024\]", ptext)
+    assert not re.search(r"f32\[1,8,9,2048,2048\]", ptext)
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    assert _entry_parameters(decode) == leaves + 5 + 5  # k, v, lengths, rings
+    assert _entry_parameters(prefill) == leaves + 2
+    runner = harness.load_module("runners", "serve_laguna")
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) >= {"attn.window", "attn.full", "moe.shared",
+                           "moe_router", "moe_experts", "mlp", "lm_head",
+                           "sample"}
+    from ray_tpu.observability import schema
+
+    assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
 
 
 @pytest.mark.parametrize("name", sorted(SERVE_CONFIGS))

@@ -132,3 +132,47 @@ def test_release_after_stop_is_dropped_and_stop_waits_a_bounded_time():
     gate.set()
     worker._thread.join(5)
     assert ran == ["before"] and not worker._thread.is_alive()
+
+
+def test_a_ref_the_collector_finalises_under_the_memory_stores_lock_is_queued(
+        ray_start_regular):
+    """The cyclic collector runs wherever an allocation tips it: under the
+    memory store's lock too (``ObjectID.__hash__`` is Python). A ref in a
+    cycle that it finalises there must not be released inline (the release
+    path takes that very lock: the thread waited on itself for ever, and
+    every ``get`` of the process behind it): it is queued, and let go of
+    where the program next lets a ref go or asks for an object."""
+    import gc
+    import threading
+
+    import ray_tpu
+    from ray_tpu._private import object_ref
+    from ray_tpu._private import worker as worker_mod
+
+    w = worker_mod.global_worker
+    store, counter = w.core.memory_store, w.reference_counter
+
+    class CollectsWhenHashed(type(ray_tpu.put(0)._id)):
+        def __hash__(self):  # the collector, tipped under the store's lock
+            gc.collect()
+            return super().__hash__()
+
+    gc.disable()
+    try:
+        ref = ray_tpu.put(b"held by a cycle alone")
+        oid = ref._id
+        cycle = [ref]
+        cycle.append(cycle)
+        del ref, cycle
+        found = []
+        looking = threading.Thread(target=lambda: found.append(
+            store.contains(CollectsWhenHashed(oid.binary()))), daemon=True)
+        looking.start()
+        looking.join(10)
+    finally:
+        gc.enable()
+    assert found == [False], "the lookup waited on the lock it holds"
+    assert oid in object_ref._orphans and counter.has_reference(oid)
+    assert ray_tpu.get(ray_tpu.put(1)) == 1  # asking for an object lets go
+    assert oid not in object_ref._orphans and not counter.has_reference(oid)
+    assert not store.contains(oid)
