@@ -74,7 +74,7 @@ from ray_tpu.models.decoding import (
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import schema as spans
 from ray_tpu.observability.tracing import device_span
-from ray_tpu.ops.attention import NEG_INF
+from ray_tpu.ops.attention import NEG_INF, decode_block
 
 
 def _sample_per_slot(logits, rng, temps, topks, active):
@@ -247,12 +247,15 @@ class ContinuousBatcher(PrefillPrograms):
         # `steps_sampled` / `steps_sorted`: steps in which an active row had
         # a temperature / a temperature and a top-k, so that sampling drew /
         # sorted the vocabulary; `tokens_discarded`: slot-steps thrown away
-        # behind a stop token
+        # behind a stop token; `kv_rows_held` / `kv_rows_read`: the cache
+        # rows the steps' sequences held, over all layers, and the rows the
+        # steps' attention read for them (`_kv_rows`)
         self.stats = {"admitted": 0, "finished": 0, "failed": 0,
                       "steps": 0, "max_active": 0, "tokens_out": 0,
                       "last_admit_step": -1, "steps_ahead": 0,
                       "steps_sampled": 0, "steps_sorted": 0,
-                      "tokens_discarded": 0}
+                      "tokens_discarded": 0, "kv_rows_held": 0,
+                      "kv_rows_read": 0}
         if cfg.stateful:
             # prefills whose state went into a slot with their rows, and
             # slots whose state was cleared when their request left
@@ -388,9 +391,12 @@ class ContinuousBatcher(PrefillPrograms):
         positions = cache.lengths[:, None]
         kv_mask = jnp.arange(self.max_len)[None, :] <= \
             cache.lengths[:, None]
+        # what each slot holds once its token is written: the same prefix as
+        # `kv_mask`, stated as a count; a slot that takes no part holds none
+        rows = jnp.where(active_mask, cache.lengths + 1, 0)
         logits, cache, aux = forward_cached(
             self.cfg, params, toks[:, None], positions, cache, kv_mask,
-            active_mask[:, None], access)
+            active_mask[:, None], access, rows)
         with jax.named_scope("sample"):
             nxt = _sample_per_slot(
                 logits[:, 0], rng, temps, topks, active_mask)
@@ -453,6 +459,29 @@ class ContinuousBatcher(PrefillPrograms):
         toks, self.cache, *load = self._decode_jit(
             self.params, toks, self.cache, rng, temps, topks, active_mask)
         return toks, load
+
+    def _kv_rows(self, lens: np.ndarray):
+        """(held, read, read in one full layer): the cache rows a decode step
+        must read for sequences of `lens` rows (the step's own among them),
+        summed over the layers, a window layer's ring holding `window` at
+        most; and what the step's attention reads for them: each slot's rows
+        in whole blocks (`ops.attention.decode_attention`; a free slot
+        nothing). Before PR 35 a step read `slots x max_len` a full layer
+        and `slots x window` a window layer whatever was held."""
+        cfg = self.cfg
+        row_bytes = cfg.kv_heads * cfg.hd * jnp.dtype(cfg.dtype).itemsize
+
+        def in_blocks(rows, t):
+            block = decode_block(t, row_bytes)
+            return int((-(-rows // block) * block).sum())
+
+        full = in_blocks(lens, self.max_len)
+        held, read = cfg.full_layers * int(lens.sum()), cfg.full_layers * full
+        if cfg.window_layers:
+            ring = np.minimum(lens, cfg.window)
+            held += cfg.window_layers * int(ring.sum())
+            read += cfg.window_layers * in_blocks(ring, cfg.window)
+        return held, read, full
 
     def _release(self, req: _Request) -> None:
         """Give back what the cache holds for a request that leaves its
@@ -714,14 +743,17 @@ class ContinuousBatcher(PrefillPrograms):
         sampled = int(draws.any())
         sorts = int((draws & (self._topks[slots] > 0)).any())
         # `rows`: the positions the step's sequences hold, its own among
-        # them: what its attention has to read; `window_rows`: those of them
-        # a window layer's ring holds (a layer pattern alone)
-        ring = {"window_rows": int(np.minimum(
-            self._host_len[slots], self.cfg.window).sum())} \
+        # them: what its attention has to read in a full layer; `rows_read`:
+        # what it reads there, whole blocks; `window_rows`: the rows a window
+        # layer's ring holds of them (a layer pattern alone)
+        lens = self._host_len[slots]
+        held, read, rows_read = self._kv_rows(lens)
+        ring = {"window_rows": int(np.minimum(lens, self.cfg.window).sum())} \
             if self.cfg.window else {}
         with device_span(spans.ENGINE_DECODE_DISPATCH, active=len(slots),
-                         ahead=ahead, rows=int(self._host_len[slots].sum()),
-                         sampled=sampled, sorted=sorts, **ring):
+                         ahead=ahead, rows=int(lens.sum()),
+                         rows_read=rows_read, sampled=sampled, sorted=sorts,
+                         **ring):
             active_mask = np.zeros(self.slots, bool)
             active_mask[slots] = True
             self._rng, k = jax.random.split(self._rng)
@@ -738,6 +770,8 @@ class ContinuousBatcher(PrefillPrograms):
         self.stats["steps_ahead"] += ahead
         self.stats["steps_sampled"] += sampled
         self.stats["steps_sorted"] += sorts
+        self.stats["kv_rows_held"] += held
+        self.stats["kv_rows_read"] += read
         # nothing is in flight while the step before is read: if that
         # fails, the failure path must not emit `newer` behind the hole
         self._drain()

@@ -10,10 +10,13 @@ JAX-native over ray_tpu.models.transformer — the TPU-first shape:
 - decode: ONE jitted single-token step per emitted token; the layer
   stack is a `lax.scan` over the stacked params so the compiled program
   is independent of depth. The cache rides in the scan's CARRY: a layer
-  writes this call's K/V rows into the stack in place and reads its
-  layer by index, so no layer is ever copied out of the stack and no
-  second stack is built. The jitted steps donate the cache: a caller
-  keeps the cache a step returns and never the one it passed in,
+  writes this call's K/V rows into the stack in place, and on the chip
+  attends with a kernel that is given the stack, the layer and how many
+  rows each slot holds (`ops.attention.decode_attention`): it reads
+  those rows where they lie, so no layer is ever cut or copied out of
+  the stack and a row no sequence holds is never read. The jitted steps
+  donate the cache: a caller keeps the cache a step returns and never
+  the one it passed in,
 - sampling (greedy / temperature / top-k) happens on-device; only the
   emitted token ids cross back to host.
 
@@ -46,6 +49,7 @@ from jax import lax
 from ray_tpu.models.transformer import (
     TransformerConfig, _lora_delta, _qk_norm, _rms_norm, _rope, moe_dropless,
 )
+from ray_tpu.ops import attention as attention_ops
 from ray_tpu.ops.attention import NEG_INF
 
 
@@ -110,7 +114,10 @@ def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
     probabilities stay float32 and V is upcast inside the fusion.
 
     Its sibling rule is the caller's (`_write_stack`): never copy a layer
-    of the cache out of its stack to get here.
+    of the cache out of its stack to get here. Who gets here: a prefill
+    (S > 1, over its fresh rows or the indexed layer), `PagedBatcher`'s
+    gathered view, and a decode step off the chip; on the chip a decode
+    step over a stack reads only the rows held (`attend_held`).
     """
     b, s, h, d = q.shape
     t, kvh = k_cache.shape[1], k_cache.shape[2]
@@ -134,17 +141,53 @@ def _write_layer(k_cache, v_cache, k, v, positions):
     bidx = jnp.arange(k.shape[0])[:, None]
     k_cache = k_cache.at[bidx, positions].set(k.astype(k_cache.dtype))
     v_cache = v_cache.at[bidx, positions].set(v.astype(v_cache.dtype))
-    return k_cache, v_cache, k_cache, v_cache
+    return k_cache, v_cache, (k_cache, v_cache)
+
+
+class StackLayer(NamedTuple):
+    """Where a layer's rows lie in a cache that is a stack: the stacks
+    [N, B, T, kvH, D] and the layer's index, NOT a view cut out of them."""
+    k: jax.Array
+    v: jax.Array
+    layer: Any  # int32 scalar
+
+    def view(self):
+        """The layer as dense [B, T, kvH, D] arrays, read by index: what a
+        prefill into a longer cache and a step off the chip attend over."""
+        return (lax.dynamic_index_in_dim(self.k, self.layer, keepdims=False),
+                lax.dynamic_index_in_dim(self.v, self.layer, keepdims=False))
+
+
+def attend_held(q, held, q_pos, kv_len_mask, rows=None):
+    """q [B, S, H, D] against what a cache access returned as `held`: a
+    dense (k, v) [B, T, kvH, D] pair, or a `StackLayer`. One token a
+    sequence (S == 1) over a stack whose caller states `rows` [B], how many
+    rows each slot holds (a prefix; 0: the slot takes no part), goes to the
+    kernel that reads those rows in the stack and nothing else
+    (`ops.attention.decode_attention`; on a TPU, as `flash_attention`).
+    Everything else is `_attend_cached` over the dense rows under
+    `kv_len_mask` and the causal rule. What decides is in the arguments:
+    no option, no model's name."""
+    if isinstance(held, StackLayer):
+        if (rows is not None and q.shape[1] == 1
+                and attention_ops.decode_attention_takes(held.k)):
+            with jax.named_scope("attend_cached"):
+                return attention_ops.decode_attention(
+                    q[:, 0], held.k, held.v, held.layer, rows)[:, None]
+        held = held.view()
+    return _attend_cached(q, *held, q_pos, kv_len_mask)
 
 
 def _write_stack(layer):
     """Cache access for a layer scan that CARRIES the whole stack
     [L, B, max_len, kvH, D]: the rows are scattered into the stack at
-    [layer, sequence, position], in place, and the layer is read back by
-    index after the write (the new token attends to itself). Neither is a
-    copy of a layer: the read fuses into attention or is its one pass over
-    the cache, and with the stack donated the program holds no cache-sized
-    temporary (`tests/test_chip_compile.py`).
+    [layer, sequence, position], in place, and what attention gets is the
+    written stack and the layer (`StackLayer`), not a view: `attend_held`
+    reads the held rows where they lie (the new token's own among them),
+    and with the stack donated the program holds no temporary of a layer's
+    size (`tests/test_chip_compile.py`). XLA left to index the layer fused
+    the index into one full-length pass in three configurations and copied
+    the layer out in the fourth (PERF.md, PR 35).
 
     `positions` [B, S] are each sequence's S CONSECUTIVE positions, as
     every engine writes a cache. The one thing read off the shapes rests
@@ -159,30 +202,30 @@ def _write_stack(layer):
             # the fresh K/V ARE the layer, no row is scattered or read back
             return (lax.dynamic_update_index_in_dim(k_cache, k, layer, 0),
                     lax.dynamic_update_index_in_dim(v_cache, v, layer, 0),
-                    k, v)
+                    (k, v))
         bidx = jnp.arange(k.shape[0])[:, None]
         k_cache = k_cache.at[layer, bidx, positions].set(k)
         v_cache = v_cache.at[layer, bidx, positions].set(v)
-        return (k_cache, v_cache,
-                lax.dynamic_index_in_dim(k_cache, layer, keepdims=False),
-                lax.dynamic_index_in_dim(v_cache, layer, keepdims=False))
+        return k_cache, v_cache, StackLayer(k_cache, v_cache, layer)
 
     return access
 
 
 def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
-                      k_cache, v_cache, kv_len_mask, access=_write_layer):
+                      k_cache, v_cache, kv_len_mask, access=_write_layer,
+                      rows=None):
     """The attention half of a decoder block against cached K/V. Returns
     (x, k_cache, v_cache): the residual stream after attention and the
     caches with this call's K/V written at `positions`.
 
     The cache is touched in one place, by `access(k_cache, v_cache, k, v,
-    positions) -> (k_cache, v_cache, k_layer, v_layer)`: it writes the
-    fresh, rotated K/V wherever its cache keeps them and returns the
-    caches with the dense [B, max_len, kvH, D] view attention reads. One
-    layer's cache (the default), the carried stack (`_write_stack`) and
-    `PagedBatcher`'s page pool each bring their own; the block around it
-    has this one spelling."""
+    positions) -> (k_cache, v_cache, held)`: it writes the fresh, rotated
+    K/V wherever its cache keeps them and returns the caches with what
+    attention reads (`attend_held`): a dense (k, v) [B, max_len, kvH, D]
+    pair, or the stack and the layer. One layer's cache (the default), the
+    carried stack (`_write_stack`) and `PagedBatcher`'s page pool each
+    bring their own; the block around it has this one spelling. `rows` [B]
+    is a decode step's statement of the rows each slot holds."""
     scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
     b, s, _ = x.shape
     nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.hd
@@ -201,9 +244,8 @@ def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
 
-    k_cache, v_cache, k_layer, v_layer = access(
-        k_cache, v_cache, k, v, positions)
-    attn = _attend_cached(q, k_layer, v_layer, positions, kv_len_mask)
+    k_cache, v_cache, held = access(k_cache, v_cache, k, v, positions)
+    attn = attend_held(q, held, positions, kv_len_mask, rows)
     attn = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
     return x + attn, k_cache, v_cache
 
@@ -229,7 +271,7 @@ def layers_to_scan(cfg: TransformerConfig, params):
 
 def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
                   k_cache, v_cache, kv_len_mask, row_mask, layer=None,
-                  access=_write_layer, state=None, route=None):
+                  access=_write_layer, state=None, route=None, rows=None):
     """One decoder block against cached K/V. Returns ((x, k_cache, v_cache,
     state, route), counted): the caches with this call's K/V written at
     `positions` by `access` (`_attention_cached`), and what the expert layer
@@ -248,10 +290,11 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
 
         x, k_cache, v_cache, state = zaya.attention_cached(
             cfg, x, p, positions, k_cache, v_cache, state, kv_len_mask,
-            row_mask, layer, access)
+            row_mask, layer, access, rows)
     else:
         x, k_cache, v_cache = _attention_cached(
-            cfg, x, p, lora, positions, k_cache, v_cache, kv_len_mask, access)
+            cfg, x, p, lora, positions, k_cache, v_cache, kv_len_mask, access,
+            rows)
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
     if cfg.num_experts:
         routing, chosen = None, ()
@@ -276,7 +319,7 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
 
 def forward_cached(cfg: TransformerConfig, params, tokens, positions,
                    cache: KVCache, kv_len_mask, row_mask,
-                   access=_write_stack):
+                   access=_write_stack, rows=None):
     """Forward [B,S] tokens through all layers, reading+writing the cache.
     The one layer loop over a cache: every engine's prefill and decode
     program is this function under its own masks.
@@ -285,7 +328,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     over the stacked params (one compiled block body) that CARRIES
     `cache.k` / `cache.v` beside the residual stream: each layer writes
     this call's rows into the stack in place and attends against its own
-    layer, read by index (`_write_stack`). A jitted caller that loops
+    layer of it (`_write_stack`, `attend_held`). A jitted caller that loops
     donates `cache` and keeps `new_cache`: then the step changes B x S rows
     of each layer and copies nothing, where a scan over the cache's layers
     copied every layer out of the stack and back (42% of a decode step,
@@ -307,6 +350,12 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     near-tie that rounding flips there is a whole other expert, so a
     comparison with a reference has to know the route that was taken.
 
+    `rows` [B] is a decode step's (S == 1) own statement of how many rows
+    each sequence holds once its token is written, 0 for a slot that takes
+    no part: with it the step's attention reads those rows and no others
+    (`attend_held`). The values are the device's (`cache.lengths`), so one
+    program serves every mix of lengths. A prefill leaves it None.
+
     `row_mask` also tells a stateful attention (`cache.state`, carried and
     written in place beside `cache.k` / `cache.v`) which of this call's
     positions is each sequence's last: the state it leaves is that
@@ -319,7 +368,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
         from ray_tpu.models import laguna
 
         return laguna.forward_cached(cfg, params, tokens, positions, cache,
-                                     kv_len_mask, row_mask, access)
+                                     kv_len_mask, row_mask, access, rows)
     x = params["embed"].astype(cfg.dtype)[tokens]
     layer_tree, whole = layers_to_scan(cfg, params)
     route = None
@@ -331,7 +380,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
         return _block_cached(
             cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
             k_cache, v_cache, kv_len_mask, row_mask, layer["i"],
-            access(layer["i"]), state, route)
+            access(layer["i"]), state, route, rows)
 
     (x, new_k, new_v, new_state, _), counted = lax.scan(
         body, (x, cache.k, cache.v, cache.state, route), layer_tree)
@@ -404,7 +453,7 @@ class Generator:
         kv_mask = jnp.arange(self.max_len)[None, :] <= cache.lengths[:, None]
         logits, cache, _ = forward_cached(
             self.cfg, params, tok[:, None], positions, cache, kv_mask,
-            jnp.ones((b, 1), bool))
+            jnp.ones((b, 1), bool), rows=cache.lengths + 1)
         nxt = _sample(logits[:, 0], rng, temperature, top_k)
         return nxt, cache._replace(lengths=cache.lengths + 1)
 

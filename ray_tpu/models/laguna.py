@@ -55,7 +55,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ray_tpu.models.decoding import KVCache, _attend_cached, _write_stack
+from ray_tpu.models.decoding import (
+    KVCache, StackLayer, _attend_cached, _write_stack, attend_held,
+)
 from ray_tpu.models.transformer import (
     TransformerConfig, _rms_norm, _rope, moe_dropless, moe_router,
 )
@@ -258,23 +260,27 @@ def _ring_positions(last, window: int):
 
 
 def _ring_attention(cfg: TransformerConfig, q, k, v, positions, row_mask,
-                    ring_k, ring_v, layer):
+                    ring_k, ring_v, layer, rows=None):
     """A window layer's cache access and attention. Returns (ring_k, ring_v,
     attention [B, S, H, D]); the rings are the stacks [window layers, B,
-    window, kvH, D], written at `layer` in place."""
+    window, kvH, D], written at `layer` in place. `rows` [B]: the positions
+    a decode step's sequences hold (`forward_cached`); a ring holds the
+    last `window` of them."""
     w = cfg.window
     b, s = q.shape[:2]
     k, v = k.astype(ring_k.dtype), v.astype(ring_v.dtype)
-    if s == 1:  # a decode step: one row in, the ring read once
+    if s == 1:  # a decode step: one row in, the rows the ring holds read once
         pos = positions[:, 0]
         bidx = jnp.arange(b)
         ring_k = ring_k.at[layer, bidx, pos % w].set(k[:, 0])
         ring_v = ring_v.at[layer, bidx, pos % w].set(v[:, 0])
-        holds = _ring_positions(pos, w) >= 0
-        attn = _attend_cached(
-            q, lax.dynamic_index_in_dim(ring_k, layer, keepdims=False),
-            lax.dynamic_index_in_dim(ring_v, layer, keepdims=False),
-            jnp.full((b, 1), w), holds)  # every row it holds is in the past
+        # while a sequence is shorter than the window its rows are the prefix
+        # 0..pos, after that the whole ring; every row held is in the past,
+        # so the causal rule has nothing to do
+        attn = attend_held(
+            q, StackLayer(ring_k, ring_v, layer), jnp.full((b, 1), w),
+            _ring_positions(pos, w) >= 0,
+            None if rows is None else jnp.minimum(rows, w))
         return ring_k, ring_v, attn
     # a prefill from position 0: attention over the fresh rows, and the ring
     # as the sequence's TRUE last position leaves it
@@ -282,20 +288,20 @@ def _ring_attention(cfg: TransformerConfig, q, k, v, positions, row_mask,
     held = _ring_positions(row_mask.sum(1).astype(jnp.int32) - 1, w)
     at = jnp.clip(held, 0, s - 1)[:, :, None, None]
 
-    def rows(fresh):
+    def kept(fresh):
         return jnp.where((held >= 0)[:, :, None, None],
                          jnp.take_along_axis(fresh, at, axis=1), 0)
 
-    return (lax.dynamic_update_index_in_dim(ring_k, rows(k), layer, 0),
-            lax.dynamic_update_index_in_dim(ring_v, rows(v), layer, 0), attn)
+    return (lax.dynamic_update_index_in_dim(ring_k, kept(k), layer, 0),
+            lax.dynamic_update_index_in_dim(ring_v, kept(v), layer, 0), attn)
 
 
 def attention(cfg: TransformerConfig, kind: str, x, p, positions, k_cache,
-              v_cache, kv_len_mask, row_mask, layer):
+              v_cache, kv_len_mask, row_mask, layer, rows=None):
     """The attention half of a layer of `kind` ("full": `k_cache` / `v_cache`
     are the slots' stacks, written by `_write_stack`; "window": the ring
     stacks), `layer` its index within its kind. Returns (x, k_cache,
-    v_cache)."""
+    v_cache). `rows`: `forward_cached`'s."""
     with jax.named_scope(f"attn.{kind}"):
         y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
         q = jnp.einsum("bsh,hnd->bsnd", y, p["wq"].astype(y.dtype))
@@ -303,12 +309,13 @@ def attention(cfg: TransformerConfig, kind: str, x, p, positions, k_cache,
         v = jnp.einsum("bsh,hnd->bsnd", y, p["wv"].astype(y.dtype))
         q, k = rope(cfg, kind, q, positions), rope(cfg, kind, k, positions)
         if kind == "full":
-            k_cache, v_cache, k_layer, v_layer = _write_stack(layer)(
+            k_cache, v_cache, held = _write_stack(layer)(
                 k_cache, v_cache, k, v, positions)
-            attn = _attend_cached(q, k_layer, v_layer, positions, kv_len_mask)
+            attn = attend_held(q, held, positions, kv_len_mask, rows)
         else:
             k_cache, v_cache, attn = _ring_attention(
-                cfg, q, k, v, positions, row_mask, k_cache, v_cache, layer)
+                cfg, q, k, v, positions, row_mask, k_cache, v_cache, layer,
+                rows)
         if cfg.head_gate:
             gate = jax.nn.sigmoid(jnp.einsum(
                 "bsh,hn->bsn", y, p["wg"].astype(y.dtype),
@@ -352,7 +359,8 @@ def _take(tree, i):
 
 
 def forward_cached(cfg: TransformerConfig, params, tokens, positions,
-                   cache: KVCache, kv_len_mask, row_mask, access=_write_stack):
+                   cache: KVCache, kv_len_mask, row_mask, access=_write_stack,
+                   rows=None):
     """`decoding.forward_cached` for a layer pattern: the same arguments and
     results, the carry being the residual stream, the full layers' stacks
     and the window layers' rings. `aux` is {"expert_load": int32
@@ -376,7 +384,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     x = params["embed"].astype(cfg.dtype)[tokens]
 
     x, k, v = attention(cfg, "full", x, _take(blocks["full"], 0), positions,
-                        cache.k, cache.v, kv_len_mask, row_mask, 0)
+                        cache.k, cache.v, kv_len_mask, row_mask, 0, rows)
     dense = blocks["dense"]
     with jax.named_scope("mlp"):
         x = x + _swiglu(_rms_norm(x, dense["ln_mlp"], cfg.norm_eps),
@@ -391,12 +399,12 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
             if kind == "full":
                 x, k, v = attention(
                     cfg, kind, x, _take(blocks["full"], full), positions, k, v,
-                    kv_len_mask, row_mask, full)
+                    kv_len_mask, row_mask, full, rows)
                 full += 1
             else:
                 x, ring_k, ring_v = attention(
                     cfg, kind, x, _take(blocks["window"], window), positions,
-                    ring_k, ring_v, kv_len_mask, row_mask, window)
+                    ring_k, ring_v, kv_len_mask, row_mask, window, rows)
                 window += 1
             layer = i * len(kinds) + j
             x, l, chosen, r = sparse_mlp(

@@ -283,8 +283,8 @@ class PagedBatcher(ContinuousBatcher):
                 pool_v = pool_v.at[layer, cur_page, cur_off].set(
                     v[:, 0].astype(pool_v.dtype))
                 return (pool_k, pool_v,
-                        pool_k[layer, page_table].reshape(dense),
-                        pool_v[layer, page_table].reshape(dense))
+                        (pool_k[layer, page_table].reshape(dense),
+                         pool_v[layer, page_table].reshape(dense)))
 
             return access
 
@@ -369,6 +369,13 @@ class PagedBatcher(ContinuousBatcher):
             # a copy: growth writes the table while this step is in flight
             self._on_device("page_table", self._page_table))
         return toks, load
+
+    def _kv_rows(self, lens):
+        """Pages are gathered into a dense view of every slot before a
+        step's attention reads them: all of it is read, whatever is held."""
+        held, _, _ = super()._kv_rows(lens)
+        whole = self.slots * self.max_len
+        return held, self.cfg.layers * whole, whole
 
     def _pages_to_admit(self, n: int) -> int:
         """Pages an n-token prompt takes at admission: its own and the one

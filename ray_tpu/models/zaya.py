@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.decoding import _attend_cached
+from ray_tpu.models.decoding import attend_held
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm, _rope
 
 
@@ -154,11 +154,13 @@ def _partial_rope(cfg: TransformerConfig, x, positions):
 
 
 def attention_cached(cfg: TransformerConfig, x, p, positions, k_cache,
-                     v_cache, state, kv_len_mask, row_mask, layer, access):
+                     v_cache, state, kv_len_mask, row_mask, layer, access,
+                     rows=None):
     """The attention half of a decoder block for "cca", against cached k/v
     and the carried state stack [L, B, heads, D]. Returns (x, k_cache,
-    v_cache, state). `access` writes and reads the K/V rows as for any
-    attention (`decoding._attention_cached`); the state is this function's."""
+    v_cache, state). `access` writes the K/V rows and `rows` states what a
+    decode step's slots hold, as for any attention
+    (`decoding._attention_cached`); the state is this function's."""
     b, s, _ = x.shape
     nh, nkv, d = cfg.heads, cfg.kv_heads, cfg.hd
     g, half, rep = nh + nkv, nkv // 2, nh // nkv
@@ -201,9 +203,8 @@ def attention_cached(cfg: TransformerConfig, x, p, positions, k_cache,
         k = _unit(k, math.sqrt(d)) * p["tau"].astype(f32)[:, None]
         q = _partial_rope(cfg, q, positions).astype(x.dtype)
         k = _partial_rope(cfg, k, positions).astype(x.dtype)
-        k_cache, v_cache, k_layer, v_layer = access(
-            k_cache, v_cache, k, v, positions)
-        attn = _attend_cached(q, k_layer, v_layer, positions, kv_len_mask)
+        k_cache, v_cache, held = access(k_cache, v_cache, k, v, positions)
+        attn = attend_held(q, held, positions, kv_len_mask, rows)
         attn = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
     return x + attn, k_cache, v_cache, state
 

@@ -68,9 +68,11 @@ DEVICE_SPANS = {
     ENGINE_PREFILL_DISPATCH: "",
     ENGINE_INSTALL_DISPATCH: "",
     ENGINE_FIRST_TOKEN_SYNC: "",
-    # a layer pattern's engine (models/laguna.py) adds `window_rows`: of
-    # `rows`, those a window layer's ring holds (min(rows, window) a slot)
-    ENGINE_DECODE_DISPATCH: "active, ahead, rows, sampled, sorted",
+    # `rows`: the positions the step's sequences hold; `rows_read`: what its
+    # attention reads of a full layer for them (whole blocks). A layer
+    # pattern's engine (models/laguna.py) adds `window_rows`: of `rows`,
+    # those a window layer's ring holds (min(rows, window) a slot)
+    ENGINE_DECODE_DISPATCH: "active, ahead, rows, rows_read, sampled, sorted",
     ENGINE_SAMPLE_SYNC: "",
     ENGINE_EMIT: "",
     # handler threads of _private/workers/default_worker.py

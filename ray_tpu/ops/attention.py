@@ -411,6 +411,214 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+# ---------------------------------------------------------------------------
+# Decode attention over a cache STACK (TPU): one new token a sequence against
+# the rows the sequence holds, read where they lie.
+# ---------------------------------------------------------------------------
+
+DECODE_BLOCK_ROWS = 512  # rows a block at most
+DECODE_BLOCK_BYTES = 2 << 20  # and bytes of K (and of V) a block at most
+
+
+def decode_block(t: int, row_bytes: int) -> int:
+    """Rows of one block of a slot of `t` rows of `row_bytes` (all KV heads):
+    at most 512 rows and 2 MiB (two buffers each of K and V: 8 MiB of VMEM),
+    a divisor of `t`. A slot reads whole blocks, so a smaller block wastes
+    fewer rows past a sequence's end and a larger one has fewer copies to
+    start and to wait for. On the v5e (my chip runs, PR 35: all layers of a
+    step, slots full / half full): 8 KV heads 725 / 550 GB/s of held rows
+    at 512 rows against 563 / 488 at 256; 16 KV heads 732 / 557 against 606 /
+    528; 2 KV heads 596 / 451 at 512, 707 / 439 at 1,024, 392 / 337 at 256."""
+    block = min(t, DECODE_BLOCK_ROWS, max(8, DECODE_BLOCK_BYTES // row_bytes))
+    while t % block:
+        block //= 2
+    return block
+
+
+def decode_attention_takes(stack) -> bool:
+    """Whether `decode_attention` runs on a cache stack [N, B, T, kvH, D] of
+    this shape and dtype, here: on a TPU (as `flash_attention`), rows of
+    whole lanes, slots of whole sublanes, and 2- or 4-byte values whose KV
+    heads fill whole 32-bit words and whole tiles of 1, 2, 4 or 8 words
+    (XLA then keeps a position's heads unpadded, and so does the kernel)."""
+    _, _, t, kvh, d = stack.shape
+    itemsize = stack.dtype.itemsize
+    if itemsize not in (2, 4) or kvh % (4 // itemsize):
+        return False
+    words = kvh // (4 // itemsize)
+    return (_on_tpu() and (words in (1, 2, 4) or words % 8 == 0)
+            and t % 8 == 0 and d % 128 == 0)
+
+
+def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
+                             kbuf, vbuf, sem, *, block):
+    """q_ref / o_ref [B, kvH, R, D] in VMEM (a KV head's `rep` query heads
+    padded to R rows); k_hbm / v_hbm the stacks [N, B, T, kvH, D] where XLA
+    keeps them; kbuf / vbuf [2, block, kvH, D]. ONE invocation walks the
+    slots in order and each slot's `ceil(rows / block)` blocks, the next
+    block's copy (the same slot's, or block 0 of the next slot that holds a
+    row) in flight while this one is attended to: a slot without rows starts
+    no copy at all."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_slots, kvh, r, d = q_ref.shape
+    layer = layer_ref[0]
+    # 2-byte rows lie in pairs of KV heads, one 32-bit word a pair and lane
+    packing = 4 // kbuf.dtype.itemsize
+
+    def copies(b, j, buf):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        return (pltpu.make_async_copy(k_hbm.at[layer, b, at], kbuf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[layer, b, at], vbuf.at[buf],
+                                      sem.at[1, buf]))
+
+    def start(b, j, buf):
+        for copy in copies(b, j, buf):
+            copy.start()
+
+    def holds_rows_from(b):
+        """The first slot >= b that holds a row; `n_slots` if none does."""
+        return lax.while_loop(
+            lambda i: (i < n_slots)
+            & (rows_ref[jnp.minimum(i, n_slots - 1)] == 0),
+            lambda i: i + 1, b)
+
+    def heads_of(ref, g0, keep=None):
+        """The rows [block, D] of KV heads g0 .. g0 + packing - 1 of one
+        buffer [block, kvH, D]: a strided read of the heads' sublanes, and
+        for 2-byte rows the two halves of each word. Rows outside `keep`
+        [block, D] come out as zeros whatever the buffer holds there."""
+        flat = ref.reshape(block * kvh, d)
+        if packing == 1:
+            rows = flat[pl.ds(g0, block, stride=kvh), :]
+            return [rows if keep is None else jnp.where(keep, rows, 0)]
+        words = flat.bitcast(jnp.uint32)[
+            pl.ds(g0 // 2, block, stride=kvh // 2), :]
+        if keep is not None:
+            words = jnp.where(keep, words, jnp.uint32(0))
+        return [pltpu.bitcast(half, jnp.float32).astype(ref.dtype)
+                for half in (words << 16, words & jnp.uint32(0xFFFF0000))]
+
+    first = holds_rows_from(0)
+
+    @pl.when(first < n_slots)
+    def _():
+        start(first, 0, 0)
+
+    def slot(b, buf):
+        rows = rows_ref[b]
+        n_blocks = pl.cdiv(rows, block)
+        after = holds_rows_from(b + 1)
+
+        def attend(j, carry):
+            buf, state = carry
+            more = j + 1 < n_blocks
+
+            @pl.when(more)
+            def _():
+                start(b, j + 1, 1 - buf)
+
+            @pl.when(jnp.logical_not(more) & (after < n_slots))
+            def _():
+                start(after, 0, 1 - buf)
+
+            for copy in copies(b, j, buf):
+                copy.wait()
+            # what a block holds past the slot's rows is someone else's or
+            # stale: its logits are masked, and its V rows are zeros (a
+            # probability of zero does not clear a NaN)
+            held = (j * block + lax.broadcasted_iota(
+                jnp.int32, (r, block), 1)) < rows
+            v_held = (j * block + lax.broadcasted_iota(
+                jnp.int32, (block, d), 0)) < rows
+            new_state = []
+            for g0 in range(0, kvh, packing):
+                ks = heads_of(kbuf.at[buf], g0)
+                vs = heads_of(vbuf.at[buf], g0, v_held)
+                for g, k, v in zip(range(g0, g0 + packing), ks, vs):
+                    m, l, acc = state[g]
+                    s = lax.dot_general(
+                        q_ref[b, g], k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * (d ** -0.5)
+                    s = jnp.where(held, s, NEG_INF)
+                    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    alpha = jnp.exp(m - m_new)
+                    l = l * alpha + p.sum(axis=-1, keepdims=True)
+                    acc = acc * alpha + jnp.dot(
+                        p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32)
+                    new_state.append((m_new, l, acc))
+            return 1 - buf, tuple(new_state)
+
+        empty = (jnp.full((r, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((r, 1), jnp.float32),
+                 jnp.zeros((r, d), jnp.float32))
+        buf, state = lax.fori_loop(0, n_blocks, attend, (buf, (empty,) * kvh))
+        for g, (_, l, acc) in enumerate(state):  # no row held: zeros
+            o_ref[b, g] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return buf
+
+    lax.fori_loop(0, n_slots, slot, 0)
+
+
+def decode_attention(q, k_stack, v_stack, layer, rows,
+                     block: Optional[int] = None):
+    """One new token a sequence against its cached rows: q [B, H, D], the
+    cache STACKS k_stack / v_stack [N, B, T, kvH, D], `layer` (int32 scalar)
+    the layer to read and `rows` (int32 [B]) how many of its T rows each
+    slot holds, a prefix. Returns [B, H, D] in q's dtype: softmax(q k^T /
+    sqrt(D)) v over rows 0 .. rows[b] - 1 per KV-head group (the heads of
+    one group adjoin), zeros where rows[b] == 0.
+
+    A Pallas kernel. `layer` and `rows` are scalar-prefetch operands and
+    the stacks stay in HBM: slot b's `ceil(rows[b] / block)` blocks of K and
+    of V are copied from [layer, b, block] and nothing else is read, no
+    layer is cut out of its stack and no [B, T] logits exist. Operands in
+    the cache's dtype, products summed in float32, the running maximum, sum
+    and accumulator float32 across blocks; the probabilities enter the
+    product with V in the cache's dtype, as the MXU takes them from
+    `_attend_cached`'s float32 at default precision."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    n, _, t, kvh, _ = k_stack.shape
+    rep = h // kvh
+    itemsize = k_stack.dtype.itemsize
+    block = block or decode_block(t, kvh * d * itemsize)
+    tile = 8 * (4 // itemsize)  # rows of a tile of q's dtype
+    r = -(-rep // tile) * tile
+    q4 = q.astype(k_stack.dtype).reshape(b, kvh, rep, d)
+    if r != rep:
+        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r - rep), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_decode_attention_kernel, block=block),
+        name="decode_attention",
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, block, kvh, d), k_stack.dtype),
+                pltpu.VMEM((2, block, kvh, d), v_stack.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      # a copy is started for every block counted here and waited for
+      jnp.clip(rows.astype(jnp.int32), 0, t), q4, k_stack, v_stack)
+    return out[:, :, :rep].reshape(b, h, d)
+
+
 def gqa_expand(k, v, num_q_heads: int):
     """Expand grouped KV heads to match q heads (GQA → MHA view).
 

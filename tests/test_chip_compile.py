@@ -453,6 +453,96 @@ def test_sparse_serve_programs_compile_and_fit(serve_programs):
                              program.as_text())
 
 
+# the five decode shapes of the serve configurations: stack layers, slots,
+# rows a slot, KV heads, query heads (Mistral, OLMoE, ZAYA1, Laguna's full
+# layers and its ring); head_dim 128 everywhere
+DECODE_SHAPES = {
+    "16x2048x8-group-of-4": (16, 16, 2048, 8, 32),
+    "16x2048x16-group-of-1": (8, 16, 2048, 16, 16),
+    "32x2048x2-group-of-4": (16, 32, 2048, 2, 8),
+    "32x4096x8-group-of-6": (2, 32, 4096, 8, 48),
+    "ring-32x512x8-group-of-9": (3, 32, 512, 8, 72),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(DECODE_SHAPES))
+def test_decode_attention_compiles(topo, shape):
+    """The decode kernel at the serve cells' shapes: the stacks go in as XLA
+    keeps them (tiled over KV heads x head_dim: a `[N, B, T, kvH * D]` view
+    would be a copy of the whole stack), nothing of a layer's size comes
+    out, and the program around the one Mosaic call holds no temporary."""
+    n, b, t, kvh, h = DECODE_SHAPES[shape]
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    stack = arr((n, b, t, kvh, 128))
+    compiled = jax.jit(A.decode_attention).lower(
+        arr((b, h, 128)), stack, stack, arr((), jnp.int32),
+        arr((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * b * h * 128 * 4
+    assert f"[{n},{b},{t},{kvh * 128}]" not in text
+
+
+def _results(text):
+    """(name, dtype, dims, op) of every instruction of a compiled program,
+    those inside fused computations among them."""
+    for name, dtype, dims, op in re.findall(
+            r"%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", text):
+        yield name, dtype, [int(d) for d in dims.split(",") if d], op
+
+
+@pytest.mark.parametrize("name", sorted(SERVE_CONFIGS))
+def test_decode_reads_the_cache_in_its_stack_with_the_kernel(
+        serve_programs, name):
+    """Every decode program the engine jits (dense, sparse, stateful, layer
+    pattern): attention is the Mosaic call that is given the stack, under
+    the scope the benchmark's readers select by (`attend_cached`,
+    `cca.attend`, `attn.full`, `attn.window`); NO instruction of the program,
+    fused or not, has a result of a cache layer's size (67 / 134 / 33.5 / 268
+    / 34 MB: before PR 35 a `dynamic-slice` fusion read the whole layer, or a
+    `slice` copied it out), and none holds float32 logits over `max_len`
+    (or the window) key positions; the cache stays aliased in to out."""
+    from benchmarks import harness, scope_ops
+
+    cfg, _, decode, cache = serve_programs(name)
+    text = decode.as_text()
+    slots = cache.lengths.shape[0]
+    kinds = {"slots": (cache.k, cfg.heads)}
+    if cache.ring_k is not None:
+        kinds["ring"] = (cache.ring_k, cfg.window_heads)
+    calls = [line for line in text.splitlines()  # not the `ragged-dot`s'
+             if "tpu_custom_call" in line and "%decode_attention" in line]
+    per_body = 1 if not cfg.layer_kinds else 1 + len(cfg.layer_kinds)
+    assert len(calls) == per_body  # the leading layer's, and a period's four
+    for kind, (stack, heads) in kinds.items():
+        layer = math.prod(stack.shape[1:])
+        t = stack.shape[2]
+        for op_name, dtype, dims, op in _results(text):
+            assert math.prod(dims) != layer, (kind, op_name, dims, op)
+            assert not (dtype == "f32" and t in dims
+                        and math.prod(dims) >= slots * heads * t), (
+                kind, op_name, dims, op)
+    kept = [a for a in (cache.k, cache.v, cache.state, cache.ring_k,
+                        cache.ring_v) if a is not None]
+    assert decode.memory_analysis().alias_size_in_bytes >= _arg_bytes(kept)
+    # the scopes, as `serve_zaya.decode_op_scopes` / `serve_laguna`'s read them
+    wanted, scopes = ("attend_cached",), scope_ops.SCOPES + ("attend_cached",)
+    if cfg.attention == "cca":
+        wanted = ("cca.attend",)
+    if cfg.layer_kinds:
+        wanted = ("attn.full", "attn.window")
+        scopes = harness.load_module("runners", "serve_laguna").SCOPES
+    by_scope = scope_ops.op_scopes(text, scopes)
+    called = {scope_ops._INSTRUCTION.match(line)[1] for line in calls}
+    assert called <= {op for s in wanted for op in by_scope[s]}
+    for scope in wanted:
+        assert called & set(by_scope[scope]), scope
+
+
 def test_attend_cached_reads_the_cache_once(topo):
     """The serve cells' decode shape (Mistral-7B widths: 32 heads over 8 KV
     heads, 16 slots x 2048 positions), the function alone. Repeating the

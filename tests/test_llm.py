@@ -73,6 +73,155 @@ def test_attend_cached_matches_plain_reference(rep, s, dtype):
         got[2], np.broadcast_to(only_value, got[2].shape), atol=1e-6)
 
 
+@pytest.fixture
+def kernels_through_the_interpreter(monkeypatch):
+    """The chip's path on the CPU: `_on_tpu` says yes (steered here, not by
+    an option of the program) and every Pallas call runs interpreted. Blocks
+    of 16 rows, so that a toy slot has several."""
+    import functools
+
+    import jax.experimental.pallas as pl
+
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(A, "DECODE_BLOCK_ROWS", 16)
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    return A
+
+
+def _close(got, want, dtype):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=1e-5)
+    else:  # as for `_attend_cached`: one rounding to bf16, another order
+        assert np.linalg.norm(got - want) <= 4e-3 * np.linalg.norm(want)
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -6, atol=2.0 ** -8)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("heads,kv_heads", [
+    (32, 8), (16, 16), (8, 2), (48, 8), (72, 8)])
+def test_decode_attention_reads_the_rows_held_and_no_others(
+        kernels_through_the_interpreter, heads, kv_heads, dtype):
+    """The kernel against `_attend_cached` on the same stack, for the five
+    head layouts of the serve configurations (groups of 4, 1, 4, 6 and 9),
+    a layer that is not the first, and slots that hold no row, one, a
+    block, a block and one, every row. Whatever lies beyond a slot's rows
+    (stale, or another's) is NaN here and must not reach the output; a slot
+    without rows gets zeros."""
+    A = kernels_through_the_interpreter
+    n, t, d, block, layer = 3, 64, 128, 16, 2
+    rows = jnp.asarray([0, 1, block, block + 1, t, 0, 37], jnp.int32)
+    b = rows.shape[0]
+    ks = jax.random.split(jax.random.key(heads), 3)
+    q = jax.random.normal(ks[0], (b, heads, d), jnp.float32).astype(dtype)
+    k, v = (jax.random.normal(key, (n, b, t, kv_heads, d), jnp.float32
+                              ).astype(dtype) for key in ks[1:])
+    held = jnp.arange(t)[None, :] < rows[:, None]
+    want = _attend_cached(q[:, None], k[layer], v[layer],
+                          jnp.full((b, 1), t), held)[:, 0]
+    stale = ~held[None, :, :, None, None]
+    got = jax.jit(A.decode_attention)(
+        q, jnp.where(stale, jnp.nan, k), jnp.where(stale, jnp.nan, v),
+        jnp.int32(layer), rows)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    live = np.asarray(rows) > 0
+    _close(got[live], want[live], dtype)
+    assert not np.asarray(got[~live], np.float32).any()
+    assert A.decode_block(t, kv_heads * d * q.dtype.itemsize) == block
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_over_a_ring(kernels_through_the_interpreter, dtype):
+    """A window layer's ring: position p at row p mod window. The rows held
+    are stated as `min(pos + 1, window)`, a prefix, for sequences below, at
+    and past the window; against the mask of the rows that hold a position
+    (`laguna._ring_positions`), with NaN in the rows that hold none."""
+    from ray_tpu.models.laguna import _ring_positions
+
+    A = kernels_through_the_interpreter
+    n, w, kvh, h, d, layer = 2, 32, 2, 18, 128, 1
+    pos = jnp.asarray([0, 5, w - 2, w - 1, w, 3 * w + 7], jnp.int32)
+    b = pos.shape[0]
+    ks = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.float32).astype(dtype)
+    k, v = (jax.random.normal(key, (n, b, w, kvh, d), jnp.float32
+                              ).astype(dtype) for key in ks[1:])
+    holds = _ring_positions(pos, w) >= 0
+    want = _attend_cached(q[:, None], k[layer], v[layer],
+                          jnp.full((b, 1), w), holds)[:, 0]
+    empty = ~holds[None, :, :, None, None]
+    got = jax.jit(A.decode_attention)(
+        q, jnp.where(empty, jnp.nan, k), jnp.where(empty, jnp.nan, v),
+        jnp.int32(layer), jnp.minimum(pos + 1, w))
+    _close(got, want, dtype)
+
+
+@pytest.mark.parametrize("name", ["debug", "zaya_debug", "laguna_debug"])
+def test_the_decode_step_with_the_kernel_gives_the_xla_steps_tokens(
+        kernels_through_the_interpreter, monkeypatch, name):
+    """One `ContinuousBatcher` a cache kind (plain slots, slots and a state,
+    slots and a ring): requests join and leave over a dozen steps, the pump's
+    passes made by hand. With the kernel in the step (S == 1 over a stack
+    decides, nothing else) the tokens are the XLA step's, and the counters
+    say exactly what the steps held and read: a request of n prompt tokens
+    and m answers runs m - 1 steps at n + 1 .. n + m - 1 rows."""
+    from concurrent.futures import Future
+
+    from ray_tpu.models.continuous_batching import (
+        ContinuousBatcher, _Request)
+
+    A = kernels_through_the_interpreter
+    cfg = T.config(name, dtype=jnp.float32, param_dtype=jnp.float32,
+                   head_dim=128)  # whole lanes: `decode_attention_takes`
+    params = T.init_params(cfg, jax.random.key(2))
+    slots, max_len = 3, 64
+    rng = np.random.default_rng(7)
+    arrivals = [(0, 5, 13), (0, 17, 4), (2, 30, 7), (5, 3, 6), (8, 11, 5)]
+    prompts = [rng.integers(1, cfg.vocab_size, n).tolist()
+               for _, n, _ in arrivals]
+
+    def serve():
+        cb = ContinuousBatcher(cfg, params, max_len=max_len, slots=slots)
+        cb.shutdown()  # the pump is gone: the passes below are the test's
+        reqs = [_Request(list(p), SamplingParams(max_tokens=m), Future(), None)
+                for p, (_, _, m) in zip(prompts, arrivals)]
+        todo = sorted((at, i) for i, (at, _, _) in enumerate(arrivals))
+        for _ in range(100):
+            while todo and todo[0][0] <= cb.stats["steps"]:
+                cb._waiting.put(reqs[todo.pop(0)[1]])
+            cb._step()
+            if not todo and all(r.future.done() for r in reqs):
+                break
+        return [r.future.result(timeout=0) for r in reqs], cb.stats
+
+    traced, real = [], A.decode_attention  # one call a layer (kind) traced
+    monkeypatch.setattr(A, "decode_attention", lambda *a, **kw: (
+        traced.append(a[1].shape[2]), real(*a, **kw))[1])
+    got, stats = serve()
+    assert set(traced) == {max_len, cfg.window} - {0}
+    monkeypatch.setattr(A, "_on_tpu", lambda: False)
+    del traced[:]
+    want, _ = serve()
+    assert traced == [] and got == want
+    assert [len(g) for g in got] == [m for _, _, m in arrivals]
+    assert stats["steps"] >= 12 and stats["max_active"] == slots
+
+    def rows_of(t, block):  # (held, read) over every step of every request
+        lens = np.concatenate([np.minimum(np.arange(n + 1, n + m), t)
+                               for _, n, m in arrivals])
+        return np.array([lens.sum(), (-(-lens // block) * block).sum()])
+
+    total = cfg.full_layers * rows_of(max_len, 16)
+    if cfg.window_layers:
+        total += cfg.window_layers * rows_of(cfg.window, cfg.window)
+    assert (stats["kv_rows_held"], stats["kv_rows_read"]) == tuple(total)
+    assert stats["kv_rows_read"] < stats["steps"] * slots * (
+        cfg.full_layers * max_len + cfg.window_layers * cfg.window)
+
+
 def _forward_cached_plainly(cfg, params, tokens, positions, cache,
                             kv_len_mask):
     """`forward_cached` of a dense model the plain way: a Python loop that
