@@ -496,7 +496,12 @@ def _execute_streaming(
     """Run a generator task, pushing one StreamingYield per value to the
     caller as it is produced (reference: task_manager.cc:778 generator
     item returns). The per-yield ack is the backpressure: the generator
-    does not advance until the caller has registered the previous item."""
+    does not advance until the caller has registered the previous item.
+
+    Each item is a `ray_tpu.worker.stream_yield` device span. This thread
+    holds the GIL while it serialises the item and releases it; it holds
+    none in the blocking call itself, the span's `worker.stream_rpc` child
+    (the wire, the caller's StreamingYield handler, the ack back)."""
     w = worker_mod.global_worker
     w.set_task_context(task_id, actor_id)
     if submit_ts:
@@ -522,12 +527,8 @@ def _execute_streaming(
                     sv = serialize_prepare(value)
                     try:
                         if sv.total <= config.object_store_inline_max_bytes:
-                            rep = client.call(
-                                "StreamingYield", task_id_bin=task_id.binary(),
-                                index=idx, kind="inline",
-                                data=sv.to_bytes(copy_path="inline"),
-                                timeout=60,
-                            )
+                            item = {"kind": "inline",
+                                    "data": sv.to_bytes(copy_path="inline")}
                         else:
                             oid = ObjectID.from_index(task_id, idx + 1)
                             w.core._plasma_put_segments(oid, sv)
@@ -535,11 +536,13 @@ def _execute_streaming(
                                 obs_events.record_event(
                                     "object_put", size=sv.total,
                                     job_id=w.core.job_id.hex(), inline=False)
+                            item = {"kind": "plasma",
+                                    "node_id": w.core.node_id}
+                        with obs_tracing.device_span(
+                                obs_schema.WORKER_STREAM_RPC, bytes=sv.total):
                             rep = client.call(
                                 "StreamingYield", task_id_bin=task_id.binary(),
-                                index=idx, kind="plasma", node_id=w.core.node_id,
-                                timeout=60,
-                            )
+                                index=idx, timeout=60, **item)
                     finally:
                         sv.release()
                 if not (rep or {}).get("ok", True):

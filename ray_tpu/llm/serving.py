@@ -13,11 +13,14 @@ replica holds one compiled engine:
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional
 
 from ray_tpu import serve
 from ray_tpu.llm.config import LLMConfig
 from ray_tpu.models.decoding import SamplingParams
+from ray_tpu.observability import schema
+from ray_tpu.observability.tracing import device_span
 
 
 def build_llm_deployment(config: LLMConfig):
@@ -65,8 +68,9 @@ def build_llm_deployment(config: LLMConfig):
             return self._generate_batch(prompt)
 
         def engine_stats(self) -> dict:
-            """The batcher's counters and the device this replica's
-            engine runs on, as JAX reports it in THIS process."""
+            """The batcher's counters, this process's CPU seconds and the
+            device this replica's engine runs on, as JAX reports it in
+            THIS process."""
             import jax
 
             st = getattr(getattr(self.engine, "batcher", None), "stats",
@@ -75,6 +79,9 @@ def build_llm_deployment(config: LLMConfig):
             devices = jax.devices()
             mem = devices[0].memory_stats() or {}
             out.update(self._compiles)
+            # CPU seconds of this process, all threads: over an interval, a
+            # whole core of a Python process is a saturated GIL
+            out["process_cpu_s"] = time.process_time()
             out.update(platform=devices[0].platform,
                        device_kind=devices[0].device_kind,
                        device_count=len(devices),
@@ -97,16 +104,28 @@ def build_llm_deployment(config: LLMConfig):
             else:
                 stream = self.engine.generator.generate_stream(
                     ids, sampling, seed=self.engine.next_seed())
-            out_ids = []
-            prev_text = ""
-            for t in stream:
-                out_ids.append(t)
-                text = self.tokenizer.decode(out_ids)
-                delta, prev_text = text[len(prev_text):], text
-                if delta:
-                    yield delta
+            yield from text_deltas(self.tokenizer, stream)
 
     return LLMServer.bind()
+
+
+def text_deltas(tokenizer, stream):
+    """Text deltas of a stream of token ids: every id decodes the WHOLE
+    answer so far again and yields what is new of it. Each decode is a
+    `ray_tpu.replica.detokenize` span [ids: the ids decoded; backlog: the ids
+    the engine has emitted for this stream and this thread has not taken
+    yet, 0 while the handler keeps up with the pump]."""
+    backlog = getattr(stream, "backlog", int)  # a stream without a queue: 0
+    out_ids = []
+    prev_text = ""
+    for t in stream:
+        out_ids.append(t)
+        with device_span(schema.REPLICA_DETOKENIZE, ids=len(out_ids),
+                         backlog=backlog()):
+            text = tokenizer.decode(out_ids)
+            delta, prev_text = text[len(prev_text):], text
+        if delta:
+            yield delta
 
 
 def serve_llm(config: LLMConfig):

@@ -77,6 +77,10 @@ from ray_tpu.observability.tracing import device_span
 from ray_tpu.ops.attention import NEG_INF, decode_block
 
 
+# passes of the pump between two bookings of its clocks (`_book`)
+BOOK_EVERY = 32
+
+
 def _sample_per_slot(logits, rng, temps, topks, active):
     """Vectorized sampling: per-row temperature (0 = greedy) and top-k
     (0 = unfiltered). logits [B, V] -> ids [B]. The work follows what the
@@ -140,6 +144,35 @@ class _Request:
     # the cache's own record of what it holds for the request; the
     # scheduler carries it and never reads it
     kv: Any = None
+
+
+class _TokenStream:
+    """A streamed request's token ids as the pump emits them, and how many
+    of them nobody has taken yet. The request is enqueued when the first id
+    is asked for: iteration is a generator's."""
+
+    def __init__(self, batcher, req: _Request):
+        self._req = req
+        self._ids = self._emitted(batcher)
+
+    def _emitted(self, batcher):
+        q = batcher._enqueue(self._req).stream_q
+        while True:
+            t = q.get()
+            if t is None:
+                return
+            if isinstance(t, BaseException):
+                raise t  # the admit or the step failed: not a short answer
+            yield t
+
+    def __iter__(self):
+        return self._ids
+
+    def __next__(self) -> int:
+        return next(self._ids)
+
+    def backlog(self) -> int:
+        return len(self._req.stream_q.queue)  # no lock: a reading, a token
 
 
 class PrefillPrograms:
@@ -249,13 +282,26 @@ class ContinuousBatcher(PrefillPrograms):
         # sorted the vocabulary; `tokens_discarded`: slot-steps thrown away
         # behind a stop token; `kv_rows_held` / `kv_rows_read`: the cache
         # rows the steps' sequences held, over all layers, and the rows the
-        # steps' attention read for them (`_kv_rows`)
+        # steps' attention read for them (`_kv_rows`); `pump_step_s`: wall
+        # seconds of the pump's passes (`engine.step`), `pump_sync_s`: of
+        # them, the two waits for the device (`sample_sync`,
+        # `first_token_sync`), `pump_cpu_s`: the pump thread's own CPU
+        # seconds in them, all three booked together (`_book`). step - sync
+        # - cpu is the time the pump wanted to run and did not: the GIL, or
+        # a core it did not get
         self.stats = {"admitted": 0, "finished": 0, "failed": 0,
                       "steps": 0, "max_active": 0, "tokens_out": 0,
                       "last_admit_step": -1, "steps_ahead": 0,
                       "steps_sampled": 0, "steps_sorted": 0,
                       "tokens_discarded": 0, "kv_rows_held": 0,
-                      "kv_rows_read": 0}
+                      "kv_rows_read": 0, "pump_step_s": 0.0,
+                      "pump_sync_s": 0.0, "pump_cpu_s": 0.0}
+        # of the passes since the last booking (`_book`): their wall time
+        # and their waits for the device, the thread's CPU clock when the
+        # first of them began, and the passes ever made
+        self._step_s = self._sync_s = 0.0
+        self._cpu_at: Optional[float] = None
+        self._passes = 0
         if cfg.stateful:
             # prefills whose state went into a slot with their rows, and
             # slots whose state was cleared when their request left
@@ -294,17 +340,11 @@ class ContinuousBatcher(PrefillPrograms):
 
     def submit_stream(self, tokens: Sequence[int],
                       sampling: Optional[SamplingParams] = None):
-        """Yields token ids as they are emitted."""
-        q = self._enqueue(_Request(
+        """Yields token ids as they are emitted; `.backlog()` of what is
+        returned: the ids the pump has emitted and nobody has taken yet."""
+        return _TokenStream(self, _Request(
             list(tokens) or [0], sampling or SamplingParams(), None,
-            queue.Queue())).stream_q
-        while True:
-            t = q.get()
-            if t is None:
-                return
-            if isinstance(t, BaseException):
-                raise t  # the admit or the step failed: not a short answer
-            yield t
+            queue.Queue()))
 
     def _enqueue(self, req: _Request) -> _Request:
         if self._shutdown:
@@ -461,7 +501,7 @@ class ContinuousBatcher(PrefillPrograms):
         return toks, load
 
     def _kv_rows(self, lens: np.ndarray):
-        """(held, read, read in one full layer): the cache rows a decode step
+        """(held, read): the cache rows a decode step
         must read for sequences of `lens` rows (the step's own among them),
         summed over the layers, a window layer's ring holding `window` at
         most; and what the step's attention reads for them: each slot's rows
@@ -475,13 +515,13 @@ class ContinuousBatcher(PrefillPrograms):
             block = decode_block(t, row_bytes)
             return int((-(-rows // block) * block).sum())
 
-        full = in_blocks(lens, self.max_len)
-        held, read = cfg.full_layers * int(lens.sum()), cfg.full_layers * full
+        held = cfg.full_layers * int(lens.sum())
+        read = cfg.full_layers * in_blocks(lens, self.max_len)
         if cfg.window_layers:
             ring = np.minimum(lens, cfg.window)
             held += cfg.window_layers * int(ring.sum())
             read += cfg.window_layers * in_blocks(ring, cfg.window)
-        return held, read, full
+        return held, read
 
     def _release(self, req: _Request) -> None:
         """Give back what the cache holds for a request that leaves its
@@ -555,6 +595,7 @@ class ContinuousBatcher(PrefillPrograms):
                 prompt_len=len(req.tokens),
                 queued_ms=(time.monotonic() - req.t_submit) * 1e3):
             last_logits, load, rows = self._prefill_into(req, slot)
+            t_sync = time.perf_counter()
             with device_span(spans.ENGINE_FIRST_TOKEN_SYNC):
                 self._rng, k = jax.random.split(self._rng)
                 first = _sample_first(
@@ -565,6 +606,7 @@ class ContinuousBatcher(PrefillPrograms):
                 first_tok = int(np.asarray(first)[0])
                 self._count_experts(load, rows)
                 self._log_routes(load, {slot: req})
+            self._sync_s += time.perf_counter() - t_sync
             # inside the span: an admit is over when its first token is out
             req.slot = slot
             self.stats["last_admit_step"] = self.stats["steps"]
@@ -668,15 +710,13 @@ class ContinuousBatcher(PrefillPrograms):
         while not self._shutdown:
             if not self._active and self._inflight is None \
                     and self._waiting.empty():
+                self._book()
                 with device_span(spans.ENGINE_IDLE):
                     self._wake.wait(timeout=0.1)
                 self._wake.clear()
                 continue
             try:
-                # around the call, not inside it: the step's device arrays
-                # are released when its frame goes, and that is step time
-                with device_span(spans.ENGINE_STEP, step=self.stats["steps"]):
-                    self._step()
+                self._timed_step()
             except Exception as e:  # noqa: BLE001 — fail active requests
                 # a step in flight that is whole (`_step` leaves none that
                 # follows a failed read) was dispatched before the one that
@@ -699,6 +739,44 @@ class ContinuousBatcher(PrefillPrograms):
 
                 logging.getLogger(__name__).exception(
                     "continuous-batching step failed")
+
+    def _timed_step(self) -> None:
+        """One `_step` under its span, with the pass's wall time and its
+        waits for the device added to what `_book` will book. The span
+        carries the pump's three clocks as last booked, so that a trace
+        holds the counters of its own window."""
+        st = self.stats
+        if self._cpu_at is None:
+            self._cpu_at = time.thread_time()
+        wall = time.perf_counter()
+        # around the call, not inside it: the step's device arrays are
+        # released when its frame goes, and that is step time
+        with device_span(spans.ENGINE_STEP, step=st["steps"],
+                         pump_step_s=st["pump_step_s"],
+                         pump_sync_s=st["pump_sync_s"],
+                         pump_cpu_s=st["pump_cpu_s"]):
+            self._step()
+        self._step_s += time.perf_counter() - wall
+        self._passes += 1
+        if self._passes % BOOK_EVERY == 0:
+            self._book()
+
+    def _book(self) -> None:
+        """Book the passes since the last booking: their wall time, their
+        waits for the device and the thread's CPU time over them, all three
+        at once (new floats, not updates in place: `engine_stats` copies
+        the dict from another thread), so that they cover the same passes
+        whenever they are read. Every `BOOK_EVERY` passes and when the pump
+        goes idle, not every pass: reading the thread's CPU clock is a system
+        call (0.3 us on a plain kernel, 5.6 us on the chip machine's)."""
+        if self._cpu_at is None:
+            return
+        st = self.stats
+        st["pump_cpu_s"] += time.thread_time() - self._cpu_at
+        st["pump_step_s"] += self._step_s
+        st["pump_sync_s"] += self._sync_s
+        self._step_s = self._sync_s = 0.0
+        self._cpu_at = None  # read again when the next pass begins
 
     def _next_slots(self) -> List[int]:
         """The slots the next step advances, in order of admission: the
@@ -743,17 +821,15 @@ class ContinuousBatcher(PrefillPrograms):
         sampled = int(draws.any())
         sorts = int((draws & (self._topks[slots] > 0)).any())
         # `rows`: the positions the step's sequences hold, its own among
-        # them: what its attention has to read in a full layer; `rows_read`:
-        # what it reads there, whole blocks; `window_rows`: the rows a window
-        # layer's ring holds of them (a layer pattern alone)
+        # them: what its attention has to read in a full layer;
+        # `window_rows`: the rows a window layer's ring holds of them (a
+        # layer pattern alone)
         lens = self._host_len[slots]
-        held, read, rows_read = self._kv_rows(lens)
+        held, read = self._kv_rows(lens)
         ring = {"window_rows": int(np.minimum(lens, self.cfg.window).sum())} \
             if self.cfg.window else {}
         with device_span(spans.ENGINE_DECODE_DISPATCH, active=len(slots),
-                         ahead=ahead, rows=int(lens.sum()),
-                         rows_read=rows_read, sampled=sampled, sorted=sorts,
-                         **ring):
+                         ahead=ahead, rows=int(lens.sum()), **ring):
             active_mask = np.zeros(self.slots, bool)
             active_mask[slots] = True
             self._rng, k = jax.random.split(self._rng)
@@ -787,10 +863,12 @@ class ContinuousBatcher(PrefillPrograms):
         return step is not None
 
     def _read(self, step: _Dispatched) -> None:
+        t_sync = time.perf_counter()
         with device_span(spans.ENGINE_SAMPLE_SYNC):
             toks_np = np.asarray(step.toks)
             self._count_experts(step.load, len(step.reqs), step=True)
             self._log_routes(step.load, step.reqs)
+        self._sync_s += time.perf_counter() - t_sync
         with device_span(spans.ENGINE_EMIT):
             for slot, req in step.reqs.items():
                 if self._active.get(slot) is not req:

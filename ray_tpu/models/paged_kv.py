@@ -373,9 +373,8 @@ class PagedBatcher(ContinuousBatcher):
     def _kv_rows(self, lens):
         """Pages are gathered into a dense view of every slot before a
         step's attention reads them: all of it is read, whatever is held."""
-        held, _, _ = super()._kv_rows(lens)
-        whole = self.slots * self.max_len
-        return held, self.cfg.layers * whole, whole
+        held, _ = super()._kv_rows(lens)
+        return held, self.cfg.layers * self.slots * self.max_len
 
     def _pages_to_admit(self, n: int) -> int:
         """Pages an n-token prompt takes at admission: its own and the one

@@ -58,25 +58,41 @@ ENGINE_FIRST_TOKEN_SYNC = "ray_tpu.engine.first_token_sync"
 ENGINE_DECODE_DISPATCH = "ray_tpu.engine.decode_dispatch"
 ENGINE_SAMPLE_SYNC = "ray_tpu.engine.sample_sync"
 ENGINE_EMIT = "ray_tpu.engine.emit"
+REPLICA_DETOKENIZE = "ray_tpu.replica.detokenize"
 WORKER_STREAM_YIELD = "ray_tpu.worker.stream_yield"
+WORKER_STREAM_RPC = "ray_tpu.worker.stream_rpc"
 
 DEVICE_SPANS = {
     # pump thread of models/continuous_batching.py
     ENGINE_IDLE: "",
-    ENGINE_STEP: "step",
+    # the pump's three clocks (`ContinuousBatcher.stats`) as last booked
+    # (every few passes: `_book`): two bookings in a trace give the
+    # counters of the time between them
+    ENGINE_STEP: "step, pump_step_s, pump_sync_s, pump_cpu_s",
     ENGINE_ADMIT: "bucket, prompt_len, queued_ms",
     ENGINE_PREFILL_DISPATCH: "",
     ENGINE_INSTALL_DISPATCH: "",
     ENGINE_FIRST_TOKEN_SYNC: "",
-    # `rows`: the positions the step's sequences hold; `rows_read`: what its
-    # attention reads of a full layer for them (whole blocks). A layer
-    # pattern's engine (models/laguna.py) adds `window_rows`: of `rows`,
-    # those a window layer's ring holds (min(rows, window) a slot)
-    ENGINE_DECODE_DISPATCH: "active, ahead, rows, rows_read, sampled, sorted",
+    # `rows`: the positions the step's sequences hold. A layer pattern's
+    # engine (models/laguna.py) adds `window_rows`: of `rows`, those a
+    # window layer's ring holds (min(rows, window) a slot). What a step's
+    # attention reads for them and whether its sampling drew or sorted are
+    # counters (`kv_rows_read`, `steps_sampled`, `steps_sorted`)
+    ENGINE_DECODE_DISPATCH: "active, ahead, rows",
     ENGINE_SAMPLE_SYNC: "",
     ENGINE_EMIT: "",
-    # handler threads of _private/workers/default_worker.py
+    # handler threads of a replica, one a stream. A streamed token goes
+    # pump `engine.emit` -> the stream's queue -> llm/serving.py
+    # `text_deltas` (`replica.detokenize`: the WHOLE answer so far decoded
+    # again; `ids` = how many, `backlog` = ids emitted and not yet taken, 0
+    # while the handler keeps up) -> _private/workers/default_worker.py
+    # `_execute_streaming` (`worker.stream_yield`: one generator item).
+    # A yield holds the GIL while it serialises the item and releases it
+    # and NOT in its `worker.stream_rpc` child, the blocking StreamingYield
+    # call (`bytes` sent): the wire, the caller's handler, the ack back
+    REPLICA_DETOKENIZE: "ids, backlog",
     WORKER_STREAM_YIELD: "",
+    WORKER_STREAM_RPC: "bytes",
 }
 
 # Scopes INSIDE the compiled programs (`jax.named_scope` at the sites in

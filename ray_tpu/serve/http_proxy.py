@@ -35,6 +35,7 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Dict, Optional
 
 from ray_tpu._private.streaming import ObjectRefGenerator, StreamEnd
@@ -79,7 +80,8 @@ class _ProxyStats:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._c = {f: 0 for f in self.FIELDS}
+        self._c = dict({f: 0 for f in self.FIELDS},
+                       stream_items=0, stream_forward_s=0.0)
 
     def inc(self, field: str, n: int = 1) -> None:
         with self._lock:
@@ -99,7 +101,15 @@ class _ProxyStats:
                     kwargs={"deadline_exceeded_total": total},
                     daemon=True, name="obs-504-dump").start()
 
-    def snapshot(self) -> Dict[str, int]:
+    def forwarded(self, seconds: float) -> None:
+        """One item of a streamed answer went out: `stream_items`, and in
+        `stream_forward_s` the front door's own work on it, from the
+        resolved value to the return of the write (not the wait for it)."""
+        with self._lock:
+            self._c["stream_items"] += 1
+            self._c["stream_forward_s"] += seconds
+
+    def snapshot(self) -> Dict[str, float]:
         with self._lock:
             return dict(self._c)
 
@@ -423,9 +433,12 @@ class _AsyncProxy:
                 b"Content-Type: application/json\r\n"
                 b"Transfer-Encoding: chunked\r\n\r\n")
             if not ended_early:
-                await self._send(writer,
-                                 _chunk(_json_bytes(first) + b"\n"))
+                value = first
                 while True:
+                    t0 = time.perf_counter()
+                    await self._send(writer,
+                                     _chunk(_json_bytes(value) + b"\n"))
+                    self.stats.forwarded(time.perf_counter() - t0)
                     try:
                         ref = await gen.anext_ref(
                             timeout=deadline.remaining_or_raise())
@@ -433,8 +446,6 @@ class _AsyncProxy:
                             ref, deadline.remaining_or_raise())
                     except StreamEnd:
                         break
-                    await self._send(writer,
-                                     _chunk(_json_bytes(value) + b"\n"))
             self.stats.inc("ok")
         except _ClientGone:
             # the consumer hung up: nothing to write, nobody to blame —
@@ -513,12 +524,16 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
     return _proxy.port
 
 
-def http_proxy_stats() -> Dict[str, int]:
-    """Front-door counters + admission stats of the running proxy
-    (empty when no proxy is up) — the soak harness's scrape point."""
+def http_proxy_stats() -> Dict[str, float]:
+    """Front-door counters + admission stats of the running proxy and its
+    process's CPU seconds (empty when no proxy is up) — the soak harness's
+    scrape point."""
     if _proxy is None:
         return {}
     out = _proxy.stats.snapshot()
+    # CPU seconds of the process that holds the proxy, all threads: over an
+    # interval, a whole core of a Python process is a saturated GIL
+    out["process_cpu_s"] = time.process_time()
     out.update({f"admission_{k}": v
                 for k, v in _proxy.admission.stats().items()})
     return out

@@ -4,7 +4,8 @@
 (``_private/profiling.py``) that takes such a trace.
 
 One toy run of each engine (the scheduler over its slots, and over
-`PagedBatcher`'s pages) under the hook is traced once for the module; the
+`PagedBatcher`'s pages) under the hook is traced once for the module, with
+one answer streamed through the replica's `text_deltas`; the
 trace is read back with the benchmark's own reader
 (``benchmarks/program_spans.py``), so the names the program writes and the
 names the benchmark reads are held together here. CPU, no cluster.
@@ -20,17 +21,19 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmarks import program_spans
+from benchmarks import program_spans, stream_spans
 from ray_tpu._private import profiling
 from ray_tpu._private.ids import TaskID
 from ray_tpu._private.workers import default_worker
-from ray_tpu.models import transformer as T
+from ray_tpu.llm import serving
+from ray_tpu.models import continuous_batching, transformer as T
 from ray_tpu.models.continuous_batching import ContinuousBatcher
 from ray_tpu.models.decoding import SamplingParams
 from ray_tpu.models.paged_kv import PagedBatcher
 from ray_tpu.observability import schema, tracing
 
 SLOTS = 2
+STREAMED_PROMPT = [7, 8, 9, 10]  # by its length its admit is told apart
 ENGINE_CHILDREN_OF_STEP = (schema.ENGINE_DECODE_DISPATCH,
                            schema.ENGINE_SAMPLE_SYNC, schema.ENGINE_EMIT)
 ENGINE_CHILDREN_OF_ADMIT = (schema.ENGINE_PREFILL_DISPATCH,
@@ -47,6 +50,11 @@ class _AckingClient:
     def call(self, method, **kwargs):
         self.calls.append((method, kwargs.get("index", kwargs.get("count"))))
         return {"ok": True, "pending": 0}
+
+
+class _IdTokenizer:
+    def decode(self, ids) -> str:
+        return " ".join(map(str, ids)) + " "
 
 
 class _NoWorker:
@@ -69,7 +77,8 @@ def _stream_two_items(client):
                 ids=["slots", "pages"])
 def traced(request, tmp_path_factory):
     """{"parsed": the trace as program_spans reads it, "bridge": the context
-    of a recorded span(), "stream": the fake stream client}. Once for each
+    of a recorded span(), "stream": the fake stream client, "deltas": the
+    text of the streamed answer, piece by piece}. Once for each
     kind of cache: the spans are the scheduler's, whatever keeps the rows."""
     cfg = T.config("debug", dtype=jnp.float32, param_dtype=jnp.float32)
     params = T.init_params(cfg, jax.random.key(0))
@@ -77,6 +86,10 @@ def traced(request, tmp_path_factory):
     sp = SamplingParams(max_tokens=6)
     logdir = str(tmp_path_factory.mktemp("xprof"))
     client = _AckingClient()
+    patch = pytest.MonkeyPatch()
+    # the pump books its clocks every few passes: a short trace of few
+    # passes has to hold some bookings
+    patch.setattr(continuous_batching, "BOOK_EVERY", 4)
     try:
         cb.submit([1, 2, 3], sp).result(timeout=120)  # compile outside
         profiling.start_tpu_profile(logdir)
@@ -87,6 +100,8 @@ def traced(request, tmp_path_factory):
             for f in futs:
                 f.result(timeout=120)
             _stream_two_items(client)
+            deltas = list(serving.text_deltas(
+                _IdTokenizer(), cb.submit_stream(STREAMED_PROMPT, sp)))
             tracing.configure(enabled=True, sample_rate=1.0)
             try:
                 with tracing.span("ray_tpu.test.bridged") as bridge:
@@ -104,9 +119,16 @@ def traced(request, tmp_path_factory):
             path = profiling.stop_tpu_profile()
     finally:
         cb.shutdown()
+        patch.undo()
     assert path.startswith(logdir) and path.endswith(".xplane.pb")
     return {"parsed": program_spans.parse(path), "bridge": bridge,
-            "stream": client}
+            "stream": client, "deltas": deltas}
+
+
+def _queued_admits(parsed):
+    """The admits of the requests that were submitted at once."""
+    return [a for a in program_spans.named(parsed, schema.ENGINE_ADMIT)
+            if a[4]["prompt_len"] != len(STREAMED_PROMPT)]
 
 
 def _inside(child, parent):
@@ -125,13 +147,18 @@ def test_every_site_is_in_the_trace_with_its_stats(traced, name):
 
 def test_names_are_the_ones_the_benchmark_reads():
     ours = set(schema.DEVICE_SPANS)
-    read = {v for k, v in vars(program_spans).items()
+    read = {v for module in (program_spans, stream_spans)
+            for k, v in vars(module).items()
             if k.isupper() and isinstance(v, str)
             and v.count(".") == 2 and not v.endswith(".")}
     assert read <= ours and program_spans.STEP == schema.ENGINE_STEP
     assert all(n.startswith(program_spans.PREFIX) for n in ours)
-    assert all(n.startswith(program_spans.ENGINE) for n in ours
-               if n != schema.WORKER_STREAM_YIELD)
+    assert {n for n in ours if not n.startswith(program_spans.ENGINE)} == {
+        schema.REPLICA_DETOKENIZE, schema.WORKER_STREAM_YIELD,
+        schema.WORKER_STREAM_RPC}
+    assert stream_spans.DETOKENIZE == schema.REPLICA_DETOKENIZE
+    assert stream_spans.STREAM_RPC == schema.WORKER_STREAM_RPC
+    assert stream_spans.EMIT == schema.ENGINE_EMIT
 
 
 def test_a_step_holds_its_children_on_the_pump_thread(traced):
@@ -181,7 +208,7 @@ def test_a_dispatch_says_whether_it_went_ahead_of_the_read(traced):
 
 def test_an_admit_holds_its_three_children_inside_a_step(traced):
     parsed = traced["parsed"]
-    admits = program_spans.named(parsed, schema.ENGINE_ADMIT)
+    admits = _queued_admits(parsed)
     steps = program_spans.named(parsed, schema.ENGINE_STEP)
     assert len(admits) == 3 * SLOTS
     for admit in admits:
@@ -194,7 +221,7 @@ def test_an_admit_holds_its_three_children_inside_a_step(traced):
 
 
 def test_queued_ms_grows_when_the_slots_are_full(traced):
-    admits = program_spans.named(traced["parsed"], schema.ENGINE_ADMIT)
+    admits = _queued_admits(traced["parsed"])
     waits = [a[4]["queued_ms"] for a in admits]  # in order of admission
     assert all(w >= 0 for w in waits)
     # the first SLOTS found a free slot; the last waited for whole requests
@@ -223,6 +250,55 @@ def test_one_stream_yield_span_for_each_item(traced):
     assert len(spans) == 2 and spans[0][1] + spans[0][2] <= spans[1][1]
     assert traced["stream"].calls == [
         ("StreamingYield", 0), ("StreamingYield", 1), ("StreamingDone", 2)]
+
+
+def test_every_stream_rpc_is_inside_a_yield_on_its_thread(traced):
+    parsed = traced["parsed"]
+    yields = program_spans.named(parsed, schema.WORKER_STREAM_YIELD)
+    rpcs = program_spans.named(parsed, schema.WORKER_STREAM_RPC)
+    assert len(rpcs) == len(yields) == 2
+    for rpc, parent in zip(rpcs, yields):
+        assert _inside(rpc, parent)
+        assert rpc[4]["bytes"] > 0
+    # the yield less its call: serialising the item and releasing it
+    assert program_spans.mean_ms(parsed, schema.WORKER_STREAM_RPC) <= \
+        program_spans.mean_ms(parsed, schema.WORKER_STREAM_YIELD)
+
+
+def test_one_detokenize_span_a_token_with_ids_growing_by_one(traced):
+    parsed = traced["parsed"]
+    spans = program_spans.named(parsed, schema.REPLICA_DETOKENIZE)
+    assert traced["deltas"] and len(spans) == len(traced["deltas"]) == 6
+    assert [s[4]["ids"] for s in spans] == [1, 2, 3, 4, 5, 6]
+    assert all(s[4]["backlog"] >= 0 for s in spans)
+    # on the thread that asked, not on the pump's
+    assert {s[3] for s in spans}.isdisjoint({program_spans.pump_line(parsed)})
+    # each id was emitted by the pump before its handler decoded it
+    handoffs = stream_spans.handoffs_ms(parsed)
+    assert handoffs and all(h >= 0 for h in handoffs)
+    assert len(handoffs) == sum(s[4]["backlog"] == 0 for s in spans[1:])
+    assert stream_spans.handoff_ms_p50(parsed) >= 0
+
+
+def test_a_trace_holds_the_pumps_counters_of_its_own_window(traced):
+    """`engine.step` carries the pump's clocks as last booked: what two
+    bookings in a trace say between them is what `ContinuousBatcher.stats`
+    says over the same passes."""
+    parsed = traced["parsed"]
+    steps = program_spans.named(parsed, schema.ENGINE_STEP)
+    for key in ("pump_step_s", "pump_sync_s", "pump_cpu_s"):
+        values = [s[4][key] for s in steps]
+        assert values == sorted(values) and values[-1] > values[0] >= 0, key
+    c = stream_spans.traced_counters(parsed)
+    assert 0 < c["steps"] <= steps[-1][4]["step"] - steps[0][4]["step"]
+    assert 0 <= c["pump_sync_s"] <= c["pump_step_s"] <= c["window_s"]
+    assert 0 <= c["pump_cpu_s"] <= c["pump_step_s"]
+    assert stream_spans.pump_cpu_ms_per_step(c) > 0
+    wait = stream_spans.pump_wait_ms_per_step(c)
+    assert wait + stream_spans.pump_cpu_ms_per_step(c) == pytest.approx(
+        (c["pump_step_s"] - c["pump_sync_s"]) / c["steps"] * 1e3)
+    share = stream_spans.idle_stream_work_share(parsed)
+    assert share is None or 0 <= share["idle"] <= 100
 
 
 def test_span_joins_the_device_trace_only_when_it_records(traced):

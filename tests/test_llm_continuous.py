@@ -85,6 +85,46 @@ class TestContinuousBatcher:
         print(f"continuous batching: {st['tokens_out']} tokens in "
               f"{dt:.2f}s = {tps:,.0f} tok/s (slots=2, requests=4)")
 
+    def test_the_pumps_three_clocks(self, tiny_model):
+        """`pump_step_s` (wall), `pump_sync_s` (of it, the waits for the
+        device) and `pump_cpu_s` (the thread's own CPU) grow with the steps,
+        the two parts never beyond the whole, and stand still while the pump
+        idles; a stream says how many ids it holds that nobody took."""
+        cfg, params = tiny_model
+        cb = ContinuousBatcher(cfg, params, max_len=64, slots=2)
+        clocks = ("pump_step_s", "pump_sync_s", "pump_cpu_s")
+        try:
+            assert [cb.stats[k] for k in clocks] == [0.0, 0.0, 0.0]
+            seen = [dict(cb.stats)]
+            for _ in range(2):
+                cb.submit([5, 17, 3], SamplingParams(max_tokens=6)
+                          ).result(timeout=120)
+                while cb._inflight is not None or cb._active:
+                    time.sleep(0.01)  # the pass that retired it is booked
+                time.sleep(0.05)
+                seen.append(dict(cb.stats))
+            time.sleep(0.3)  # engine.idle: nothing moves
+            idle = dict(cb.stats)
+            stream = cb.submit_stream([5, 17, 3], SamplingParams(max_tokens=6))
+            first = next(stream)
+            while cb.stats["finished"] < 3:
+                time.sleep(0.01)
+            assert stream.backlog() == 6  # five ids and the end's marker
+            assert [first] + list(stream) == cb.submit(
+                [5, 17, 3], SamplingParams(max_tokens=6)).result(timeout=120)
+            assert stream.backlog() == 0 and list(stream) == []
+        finally:
+            cb.shutdown()
+        for before, after in zip(seen, seen[1:]):
+            assert after["steps"] > before["steps"]
+            assert all(after[k] > before[k] for k in clocks)
+        for st in seen[1:]:
+            assert 0 < st["pump_sync_s"] <= st["pump_step_s"]
+            assert 0 < st["pump_cpu_s"] <= st["pump_step_s"]
+        assert {k: idle[k] for k in clocks + ("steps",)} == \
+            {k: seen[-1][k] for k in clocks + ("steps",)}
+        assert all(isinstance(cb.stats[k], float) for k in clocks)
+
     def test_late_request_joins_mid_decode(self, tiny_model):
         """A request submitted while others are decoding is admitted at
         a step > 0 — iteration-level scheduling, not batch-drain."""
@@ -549,6 +589,11 @@ class TestServeContinuous:
             assert not errors, errors
             assert len(results) == 5
             stats = handle.engine_stats.remote().result()
+            # the pump's clocks and the process's CPU seconds: what says
+            # whether this replica is CPU-bound
+            assert 0 < stats["pump_cpu_s"] <= stats["pump_step_s"]
+            assert 0 < stats["process_cpu_s"] < \
+                handle.engine_stats.remote().result()["process_cpu_s"]
             assert stats["admitted"] == 5
             assert stats["max_active"] <= 2  # bounded by cache_slots
             assert stats["finished"] == 5

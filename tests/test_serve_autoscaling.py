@@ -128,6 +128,20 @@ def test_http_proxy_basic_and_streaming(serve_cluster):
     assert lines == [{"i": i} for i in range(4)]
     conn.close()
 
+    # the front door counts what it forwards: the chunks of a streamed
+    # answer, the seconds of its own work on them, its process's CPU seconds
+    before = serve.http_proxy_stats()
+    assert before["stream_items"] == 4 and before["stream_forward_s"] > 0
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
+    conn.request("POST", "/stream", body=json.dumps(3))
+    assert len(conn.getresponse().read().decode().strip().splitlines()) == 3
+    conn.close()
+    after = serve.http_proxy_stats()
+    assert after["stream_items"] == 7
+    assert after["stream_forward_s"] > before["stream_forward_s"]
+    assert after["process_cpu_s"] > before["process_cpu_s"] > 0
+    assert after["ok"] == before["ok"] + 1
+
     # unknown deployment -> 404
     conn = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
     conn.request("POST", "/nope", body=json.dumps(1))
