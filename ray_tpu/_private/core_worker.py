@@ -3001,27 +3001,39 @@ class CoreWorker(CoreRuntime):
         self._streams[task_id] = st
         return ObjectRefGenerator(self, task_id, st)
 
-    def _handle_streaming_yield(
-        self, task_id_bin: bytes, index: int, kind: str,
-        data: Optional[bytes] = None, node_id: Optional[str] = None,
-    ) -> dict:
-        tid = TaskID(task_id_bin)
-        st = self._streams.get(tid)
-        if st is None:
-            return {"ok": False}  # stream abandoned — drop
-        oid = ObjectID.from_index(tid, index + 1)
+    def _handle_streaming_yield(self, items: List[tuple]) -> dict:
+        """One call of a producer's ``StreamSender``: every item its
+        streams had ready for this caller, ``(task_id_bin, index, kind,
+        data | node_id)`` in hand-over order. Each item is registered as
+        its own object under its own reference; each stream is woken once
+        a call and answered ``{ok, pending}`` (``ok`` False: abandoned;
+        ``pending``: its unconsumed buffer, the producer's backpressure)."""
         rc = self._ref_counter()
-        if not rc.has_reference(oid):
-            rc.add_owned_object(oid)
-        if kind == "inline":
-            self.memory_store.put(oid, ("inline", data))
-        else:
-            self.memory_store.put(oid, ("plasma", node_id))
-        with st.cv:
-            st.arrived[index] = oid
-            st.notify_locked()
-            pending = len(st.arrived)
-        return {"ok": True, "pending": pending}
+        # task_id_bin -> (its TaskID, its stream or None, {index: oid})
+        touched: Dict[bytes, tuple] = {}
+        for task_id_bin, index, kind, payload in items:
+            if task_id_bin not in touched:
+                tid = TaskID(task_id_bin)
+                touched[task_id_bin] = (tid, self._streams.get(tid), {})
+            tid, st, arrived = touched[task_id_bin]
+            if st is None:
+                continue  # stream abandoned — drop
+            oid = ObjectID.from_index(tid, index + 1)
+            if not rc.has_reference(oid):
+                rc.add_owned_object(oid)
+            self.memory_store.put(oid, (kind, payload))
+            arrived[index] = oid
+        replies = {}
+        for task_id_bin, (_, st, arrived) in touched.items():
+            if st is None:
+                replies[task_id_bin] = {"ok": False}
+                continue
+            with st.cv:
+                st.arrived.update(arrived)
+                st.notify_locked()
+                replies[task_id_bin] = {"ok": True,
+                                        "pending": len(st.arrived)}
+        return replies
 
     def _handle_streaming_credit(self, task_id_bin: bytes) -> dict:
         """Producer-side backpressure poll: how many yields sit undelivered

@@ -26,6 +26,7 @@ from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu._private import streaming
 from ray_tpu._private import worker as worker_mod
 from ray_tpu._private.config import config
 from ray_tpu._private.core_worker import CoreWorker
@@ -493,15 +494,17 @@ def _execute_streaming(
     trace_ctx=None,
     submit_ts: float = 0.0,
 ) -> dict:
-    """Run a generator task, pushing one StreamingYield per value to the
-    caller as it is produced (reference: task_manager.cc:778 generator
-    item returns). The per-yield ack is the backpressure: the generator
-    does not advance until the caller has registered the previous item.
+    """Run a generator task, handing each value it yields to the caller's
+    ``StreamSender`` as it is produced (reference: task_manager.cc:778
+    generator item returns, reported without waiting for each). A yield
+    does not wait for its item's ack: the sender's thread carries what has
+    gathered for the caller in one StreamingYield call. The generator is
+    held only while it is ``streaming_generator_buffer_size`` items ahead
+    of its consumer.
 
-    Each item is a `ray_tpu.worker.stream_yield` device span. This thread
-    holds the GIL while it serialises the item and releases it; it holds
-    none in the blocking call itself, the span's `worker.stream_rpc` child
-    (the wire, the caller's StreamingYield handler, the ack back)."""
+    Each item is a `ray_tpu.worker.stream_yield` device span: serialising
+    it, handing it over and, rarely, that wait. The blocking call is the
+    sender thread's `worker.stream_rpc` span, one a CALL."""
     w = worker_mod.global_worker
     w.set_task_context(task_id, actor_id)
     if submit_ts:
@@ -511,7 +514,8 @@ def _execute_streaming(
                 tags={"kind": "actor_task" if actor_id else "task"})
         except Exception:  # noqa: BLE001
             pass
-    client = get_client(tuple(caller_addr))
+    sender = streaming.sender_for(caller_addr)
+    out = streaming.OutStream(task_id.binary())
     idx = 0
     try:
         args, kwargs = _resolve_args(packed_args, packed_kwargs)
@@ -527,45 +531,23 @@ def _execute_streaming(
                     sv = serialize_prepare(value)
                     try:
                         if sv.total <= config.object_store_inline_max_bytes:
-                            item = {"kind": "inline",
-                                    "data": sv.to_bytes(copy_path="inline")}
+                            kind = "inline"
+                            payload = sv.to_bytes(copy_path="inline")
                         else:
+                            # in the store before the caller hears of it
                             oid = ObjectID.from_index(task_id, idx + 1)
                             w.core._plasma_put_segments(oid, sv)
                             if obs_tracing.active():
                                 obs_events.record_event(
                                     "object_put", size=sv.total,
                                     job_id=w.core.job_id.hex(), inline=False)
-                            item = {"kind": "plasma",
-                                    "node_id": w.core.node_id}
-                        with obs_tracing.device_span(
-                                obs_schema.WORKER_STREAM_RPC, bytes=sv.total):
-                            rep = client.call(
-                                "StreamingYield", task_id_bin=task_id.binary(),
-                                index=idx, timeout=60, **item)
+                            kind, payload = "plasma", w.core.node_id
+                        size = sv.total
                     finally:
                         sv.release()
-                if not (rep or {}).get("ok", True):
-                    break  # consumer abandoned the stream — stop producing
+                    if not sender.put(out, idx, kind, payload, size):
+                        break  # consumer abandoned the stream — stop producing
                 idx += 1
-                # consumer backpressure: pause while the un-consumed buffer
-                # on the caller is deep (reference: generator_backpressure_
-                # num_objects); the registration ack alone doesn't bound it
-                limit = config.streaming_generator_buffer_size
-                while (rep or {}).get("pending", 0) >= limit:
-                    time.sleep(0.02)
-                    try:
-                        rep = client.call(
-                            "StreamingCredit", task_id_bin=task_id.binary(),
-                            timeout=30,
-                        )
-                    except Exception:  # noqa: BLE001
-                        break
-                    if not rep.get("ok", True):
-                        rep = {"ok": False}
-                        break
-                if not (rep or {}).get("ok", True):
-                    break
         done = {"count": idx, "error": None}
     except BaseException as e:  # noqa: BLE001
         tb = traceback.format_exc()
@@ -573,13 +555,8 @@ def _execute_streaming(
         done = {"count": idx, "error": serialize(err)}
     finally:
         w.set_task_context(None, None)
-    try:
-        client.call(
-            "StreamingDone", task_id_bin=task_id.binary(),
-            count=done["count"], error=done["error"], timeout=60,
-        )
-    except Exception:  # noqa: BLE001 — the reply carries the same info
-        pass
+    # behind the stream's last item, and out before the task's reply
+    sender.finish(out, done["count"], done["error"])
     reply = {"returns": [], "streaming_done": done["count"]}
     if done["error"] is not None:
         reply["stream_error"] = done["error"]

@@ -17,6 +17,7 @@ import time
 from typing import Optional
 
 from ray_tpu import serve
+from ray_tpu._private import streaming
 from ray_tpu.llm.config import LLMConfig
 from ray_tpu.models.decoding import SamplingParams
 from ray_tpu.observability import schema
@@ -68,9 +69,9 @@ def build_llm_deployment(config: LLMConfig):
             return self._generate_batch(prompt)
 
         def engine_stats(self) -> dict:
-            """The batcher's counters, this process's CPU seconds and the
-            device this replica's engine runs on, as JAX reports it in
-            THIS process."""
+            """The batcher's counters, this process's CPU seconds, what it
+            has sent as a producer of streams, and the device this
+            replica's engine runs on, as JAX reports it in THIS process."""
             import jax
 
             st = getattr(getattr(self.engine, "batcher", None), "stats",
@@ -82,6 +83,8 @@ def build_llm_deployment(config: LLMConfig):
             # CPU seconds of this process, all threads: over an interval, a
             # whole core of a Python process is a saturated GIL
             out["process_cpu_s"] = time.process_time()
+            # `stream_calls`, `stream_items_sent`
+            out.update(streaming.send_stats())
             out.update(platform=devices[0].platform,
                        device_kind=devices[0].device_kind,
                        device_count=len(devices),

@@ -86,13 +86,16 @@ DEVICE_SPANS = {
     # `text_deltas` (`replica.detokenize`: the WHOLE answer so far decoded
     # again; `ids` = how many, `backlog` = ids emitted and not yet taken, 0
     # while the handler keeps up) -> _private/workers/default_worker.py
-    # `_execute_streaming` (`worker.stream_yield`: one generator item).
-    # A yield holds the GIL while it serialises the item and releases it
-    # and NOT in its `worker.stream_rpc` child, the blocking StreamingYield
-    # call (`bytes` sent): the wire, the caller's handler, the ack back
+    # `_execute_streaming` (`worker.stream_yield`: one generator item
+    # serialised and handed to the caller's `streaming.StreamSender`; the
+    # yield waits only when its stream is a whole buffer ahead of its
+    # consumer). `worker.stream_rpc` is on the SENDER's thread, one span a
+    # StreamingYield CALL: the wire, the caller's handler, the ack back,
+    # for the `items` (`bytes` in all) that every stream of this process
+    # had handed over for that caller since the call before
     REPLICA_DETOKENIZE: "ids, backlog",
     WORKER_STREAM_YIELD: "",
-    WORKER_STREAM_RPC: "bytes",
+    WORKER_STREAM_RPC: "items, bytes",
 }
 
 # Scopes INSIDE the compiled programs (`jax.named_scope` at the sites in

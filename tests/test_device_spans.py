@@ -21,8 +21,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmarks import program_spans, stream_spans
-from ray_tpu._private import profiling
+from benchmarks import harness, program_spans, stream_spans
+from ray_tpu._private import profiling, streaming
 from ray_tpu._private.ids import TaskID
 from ray_tpu._private.workers import default_worker
 from ray_tpu.llm import serving
@@ -42,14 +42,20 @@ ENGINE_CHILDREN_OF_ADMIT = (schema.ENGINE_PREFILL_DISPATCH,
 
 
 class _AckingClient:
-    """Stands for the caller's end of a stream: acknowledges every item."""
+    """Stands for the caller's end of a stream: acknowledges every item of
+    every call (`calls`: StreamingYield with its items' indices,
+    StreamingDone with its count)."""
 
     def __init__(self):
         self.calls = []
 
     def call(self, method, **kwargs):
-        self.calls.append((method, kwargs.get("index", kwargs.get("count"))))
-        return {"ok": True, "pending": 0}
+        if method == "StreamingYield":
+            self.calls.append((method, [i[1] for i in kwargs["items"]]))
+            return {i[0]: {"ok": True, "pending": 0}
+                    for i in kwargs["items"]}
+        self.calls.append((method, kwargs["count"]))
+        return {"ok": True}
 
 
 class _IdTokenizer:
@@ -66,7 +72,8 @@ def _stream_two_items(client):
     """The worker's streaming loop itself, with the caller's end faked."""
     with pytest.MonkeyPatch.context() as m:
         m.setattr(default_worker.worker_mod, "global_worker", _NoWorker())
-        m.setattr(default_worker, "get_client", lambda addr: client)
+        m.setattr(streaming, "get_client", lambda addr: client)
+        m.setattr(streaming, "_senders", {})
         reply = default_worker._execute_streaming(
             lambda: iter(["7 ", "8 "]), [], {}, TaskID.from_random(),
             "stream", ("127.0.0.1", 1))
@@ -248,21 +255,40 @@ def test_the_readers_numbers_on_this_trace(traced):
 def test_one_stream_yield_span_for_each_item(traced):
     spans = program_spans.named(traced["parsed"], schema.WORKER_STREAM_YIELD)
     assert len(spans) == 2 and spans[0][1] + spans[0][2] <= spans[1][1]
-    assert traced["stream"].calls == [
-        ("StreamingYield", 0), ("StreamingYield", 1), ("StreamingDone", 2)]
+    # every item went once, in order, and the end behind the last of them
+    calls = traced["stream"].calls
+    assert calls[-1] == ("StreamingDone", 2)
+    assert all(method == "StreamingYield" for method, _ in calls[:-1])
+    assert [i for _, indices in calls[:-1] for i in indices] == [0, 1]
 
 
-def test_every_stream_rpc_is_inside_a_yield_on_its_thread(traced):
+def test_a_stream_rpc_is_one_call_of_the_senders_thread(traced):
     parsed = traced["parsed"]
+    assert schema.DEVICE_SPANS[schema.WORKER_STREAM_RPC] == "items, bytes"
     yields = program_spans.named(parsed, schema.WORKER_STREAM_YIELD)
     rpcs = program_spans.named(parsed, schema.WORKER_STREAM_RPC)
-    assert len(rpcs) == len(yields) == 2
-    for rpc, parent in zip(rpcs, yields):
-        assert _inside(rpc, parent)
-        assert rpc[4]["bytes"] > 0
-    # the yield less its call: serialising the item and releasing it
-    assert program_spans.mean_ms(parsed, schema.WORKER_STREAM_RPC) <= \
-        program_spans.mean_ms(parsed, schema.WORKER_STREAM_YIELD)
+    sent = [indices for method, indices in traced["stream"].calls
+            if method == "StreamingYield"]
+    # one span a CALL with the items it carried, not on a yielding thread
+    assert [r[4]["items"] for r in rpcs] == [len(i) for i in sent]
+    assert all(r[4]["bytes"] > 0 for r in rpcs)
+    assert {r[3] for r in rpcs}.isdisjoint({y[3] for y in yields})
+    # a yield serialises and hands over; no call starts before its first item
+    assert rpcs[0][1] >= yields[0][1]
+    ctx = {"cell": {}, "counters": {}, "device": {},
+           "trace": {"program_spans": parsed}}
+    read = harness.load_reader("reason_stream_items_per_call").read
+    assert read(ctx) == pytest.approx(2 / len(sent))
+    # a trace from before the calls carried a count: nothing to read
+    before = dict(parsed, spans=[
+        s[:4] + [{"bytes": s[4]["bytes"]}]
+        if s[0] == schema.WORKER_STREAM_RPC else s for s in parsed["spans"]])
+    assert read(dict(ctx, trace={"program_spans": before})) is None
+    assert read(dict(ctx, trace={})) is None
+    sample = program_spans.load_sample(os.path.join(
+        harness.HERE, "tests", "data", "serve_chat_spans_sample.json.gz"))
+    assert program_spans.named(sample, schema.WORKER_STREAM_YIELD)
+    assert read(dict(ctx, trace={"program_spans": sample})) is None
 
 
 def test_one_detokenize_span_a_token_with_ids_growing_by_one(traced):

@@ -594,6 +594,8 @@ class TestServeContinuous:
             assert 0 < stats["pump_cpu_s"] <= stats["pump_step_s"]
             assert 0 < stats["process_cpu_s"] < \
                 handle.engine_stats.remote().result()["process_cpu_s"]
+            # the process as a producer of streams (nothing streamed here)
+            assert stats["stream_items_sent"] >= stats["stream_calls"] >= 0
             assert stats["admitted"] == 5
             assert stats["max_active"] <= 2  # bounded by cache_slots
             assert stats["finished"] == 5

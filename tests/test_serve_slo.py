@@ -297,6 +297,78 @@ class TestLoadShedding:
 
 
 # =====================================================================
+# A streamed answer over HTTP: values that left the replica in one call
+# still come one JSON line a value, in order; errors keep their frames
+# =====================================================================
+class TestStreamedAnswer:
+    @staticmethod
+    def _stream(port, path, payload, timeout_s=30):
+        """(status, the answer's JSON lines in order)."""
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
+        try:
+            conn.request("POST", path, body=json.dumps(payload),
+                         headers={"Content-Type": "application/json",
+                                  slo.TIMEOUT_HEADER: str(timeout_s)})
+            resp = conn.getresponse()
+            return resp.status, [json.loads(line) for line in
+                                 resp.read().decode().splitlines()
+                                 if line.strip()]
+        finally:
+            conn.close()
+
+    def test_bursts_come_one_line_a_value(self, serve_cluster):
+        @serve.deployment(name="bursts", num_replicas=1)
+        class Bursts:
+            def gen(self, req):
+                for burst in range(req["bursts"]):
+                    for i in range(10):  # ten values without a pause
+                        yield {"i": 10 * burst + i}
+                    if req.get("fail_after") == burst:
+                        raise ValueError("broke between bursts")
+                    time.sleep(req["pause"])
+
+        serve.run(Bursts.bind(), name="bursts")
+        port = serve.start_http_proxy(port=0)
+        try:
+            before = serve.http_proxy_stats()
+            status, lines = self._stream(
+                port, "/bursts/gen", {"bursts": 2, "pause": 0.2})
+            assert status == 200
+            assert lines == [{"i": i} for i in range(20)]
+            after = serve.http_proxy_stats()
+            assert after["stream_items"] - before["stream_items"] == 20
+            assert after["stream_forward_s"] > before["stream_forward_s"]
+            assert after["ok"] == before["ok"] + 1
+
+            # the generator's error: every value before it, then ONE
+            # terminal frame, then a clean end
+            status, lines = self._stream(
+                port, "/bursts/gen",
+                {"bursts": 2, "pause": 0.2, "fail_after": 1})
+            assert status == 200
+            assert lines[:-1] == [{"i": i} for i in range(20)]
+            assert lines[-1]["terminal"] is True
+            assert lines[-1]["error"]["code"] == "internal"
+            assert "broke between bursts" in lines[-1]["error"]["message"]
+
+            # the deadline passes mid-stream, between two bursts: what
+            # was yielded in time, then the 504's terminal frame
+            errors = serve.http_proxy_stats()["stream_terminal_errors"]
+            status, lines = self._stream(
+                port, "/bursts/gen", {"bursts": 3, "pause": 2.0},
+                timeout_s=1.0)
+            assert status == 200
+            assert lines[:-1] == [{"i": i} for i in range(10)]
+            assert lines[-1]["terminal"] is True
+            assert lines[-1]["error"]["code"] == "deadline_exceeded"
+            stats = serve.http_proxy_stats()
+            assert stats["stream_terminal_errors"] == errors + 1
+            assert stats["stream_items"] - after["stream_items"] == 30
+        finally:
+            serve.stop_http_proxy()
+
+
+# =====================================================================
 # Replica death: mid-stream terminal frame, unary transparent retry
 # =====================================================================
 class TestReplicaDeath:
