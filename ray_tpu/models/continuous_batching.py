@@ -42,13 +42,18 @@ scheduler never reads. ``PrefillPrograms``, the scheduler's base, is the
 prompt program alone: what a prefill replica constructs
 (``models/disagg_prefill.py``).
 
-A model may keep two kinds of rows (a layer pattern, ``models/laguna.py``):
-its full layers' in the slots, its window layers' in a ring of ``window``
-rows a slot beside them (``KVCache.ring_k``). A prefill then returns both,
-the slots' rows of bucket length and the ring as the prompt's TRUE length
-leaves it, and install writes the whole of both into the slot: nothing of
-the slot's last occupant stays visible. The decode step keeps both (donated
-together). Pages hold no ring: ``PagedBatcher`` refuses such a model.
+A model may keep more than one kind of memory a sequence (a layer pattern):
+``models/laguna.py`` its full layers' rows in the slots and its window
+layers' in a ring of ``window`` rows a slot beside them (``KVCache.ring_k``);
+``models/kimi_linear.py`` a float32 matrix state a head with a convolution
+window (read and rewritten every step) beside one latent row a position.
+A prefill returns all of it (``_row_of``), rows of bucket length and the rest
+as the prompt's TRUE length leaves it, and install writes the whole of each
+into the slot (``_slot_fields`` walks what the cache object holds, whatever
+it holds): nothing of the slot's last occupant stays visible. The decode
+step keeps all of it (donated together), advances active slots alone, and a
+slot given back has its states cleared. Pages hold none of these:
+``PagedBatcher`` refuses such a model.
 """
 
 from __future__ import annotations
@@ -65,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.decoding import (
+    STATES,
     KVCache,
     SamplingParams,
     _write_stack,
@@ -79,6 +85,9 @@ from ray_tpu.ops.attention import NEG_INF, decode_block
 
 # passes of the pump between two bookings of its clocks (`_book`)
 BOOK_EVERY = 32
+# what a cache may keep a slot behind `k`, `v` and `lengths`, in the order a
+# prefill program returns it and install takes it
+KEPT = KVCache._fields[3:]
 
 
 def _sample_per_slot(logits, rng, temps, topks, active):
@@ -191,14 +200,16 @@ class PrefillPrograms:
 
     def _prefill_impl(self, params, tokens, length):
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
-        against a standalone single-row cache. A stateful model's program
-        (`cfg.stateful`) returns the row's state [L, ...] next, as the
-        prompt's TRUE last position left it; a layer pattern's the window
-        layers' ring rows (ring_k, ring_v [window layers, window, kvH, D]: the
-        last `window` positions of the prompt's TRUE length); a sparse
-        model's then what its expert layers counted (`forward_cached`'s
-        `aux`), the experts' load [E] from the prompt's real positions first
-        (a dense model's callers unpack three)."""
+        against a standalone single-row cache. Next comes what else the
+        model keeps a sequence (`_row_of`): a stateful attention's state
+        [L, ...] as the prompt's TRUE last position left it; a layer
+        pattern's window layers' ring rows (ring_k, ring_v [window layers,
+        window, kvH, D]: the last `window` positions of the prompt's TRUE
+        length), or its matrix states, convolution windows (at the TRUE
+        last position) and latent rows; a sparse model's then what its
+        expert layers counted (`forward_cached`'s `aux`), the experts' load
+        [E] from the prompt's real positions first (a dense model's callers
+        unpack three)."""
         s = tokens.shape[1]
         row_cache = init_cache(self.cfg, 1, s)
         positions = jnp.arange(s)[None, :]
@@ -213,12 +224,13 @@ class PrefillPrograms:
     @staticmethod
     def _row_of(row_cache: KVCache) -> tuple:
         """A one-sequence cache as a prefill program returns it: (row_k,
-        row_v) and, from a stateful model, the row's state; from a layer
-        pattern, its window layers' ring."""
-        state = () if row_cache.state is None else (row_cache.state[:, 0],)
-        ring = () if row_cache.ring_k is None else (
-            row_cache.ring_k[:, 0], row_cache.ring_v[:, 0])
-        return row_cache.k[:, 0], row_cache.v[:, 0], *state, *ring
+        row_v), then whatever else the model keeps a sequence, in the
+        cache's own order (`KEPT`): a stateful attention's state; a layer
+        pattern's ring, or its matrix states, convolution windows and latent
+        rows."""
+        return row_cache.k[:, 0], row_cache.v[:, 0], *(
+            getattr(row_cache, name)[:, 0] for name in KEPT
+            if getattr(row_cache, name) is not None)
 
     @staticmethod
     def _bucket(n: int) -> int:
@@ -393,36 +405,52 @@ class ContinuousBatcher(PrefillPrograms):
                                         donate_argnums=(0,))
 
     def _install_impl(self, cache: KVCache, row_k, row_v, slot, length,
-                      row_state=None, ring_k=None, ring_v=None):
+                      *kept):
         """Scatter a prefilled row into its slot of the big cache (the
         row is padded to max_len, so the whole slot — including stale
-        data from its previous occupant — is overwritten), and a stateful
-        model's `row_state` [L, ...] with it; a layer pattern's `ring_k` /
-        `ring_v` [window layers, window, kvH, D] replace the slot's whole
-        ring likewise."""
+        data from its previous occupant — is overwritten), and with it
+        whatever else the prefill returned for the sequence, `kept` in the
+        cache's own order (`KEPT`: a state [L, ...], a ring pair [window
+        layers, window, kvH, D], matrix states, convolution windows, latent
+        rows; None where the model keeps none): each replaces the slot's
+        whole part of its stack."""
         k = jax.lax.dynamic_update_slice(
             cache.k, row_k[:, None], (0, slot, 0, 0, 0))
         v = jax.lax.dynamic_update_slice(
             cache.v, row_v[:, None], (0, slot, 0, 0, 0))
         lengths = cache.lengths.at[slot].set(length)
-        return KVCache(k, v, lengths,
-                       self._slot_state(cache.state, slot, row_state),
-                       self._slot_state(cache.ring_k, slot, ring_k),
-                       self._slot_state(cache.ring_v, slot, ring_v))
+        return KVCache(k, v, lengths, *self._slot_fields(cache, slot, kept))
 
     @staticmethod
-    def _slot_state(state, slot, row_state):
-        """The state stack [L, slots, ...] with `slot`'s replaced by a
-        prefill's `row_state` [L, ...]; a model without state has neither.
-        A ring stack [window layers, slots, window, ...] is kept the same
-        way."""
-        if row_state is None:
-            return state
-        return state.at[:, slot].set(row_state.astype(state.dtype))
+    def _slot_fields(cache: KVCache, slot, rows=None) -> list:
+        """The one walk over what a cache keeps a slot behind `k` / `v`: its
+        stacks in `KEPT`'s order ([layers, slots, ...] each, None where the
+        model keeps none) with `slot`'s part replaced. By a prefill's `rows`
+        ([layers, ...] each, in the same order, None or missing: left as it
+        is): the whole part, rows of a bucket shorter than the slot followed
+        by zeros, so that nothing of the last occupant is left. Without
+        `rows`, a slot given back: what every step reads and rewrites
+        (`decoding.STATES`) returns to a new sequence's zeros; rows stay,
+        masked by the slot's length until the next install overwrites them."""
+        out = []
+        for i, name in enumerate(KEPT):
+            stack = getattr(cache, name)
+            row = rows[i] if rows is not None and i < len(rows) else None
+            if stack is not None and row is not None:
+                if name not in STATES and row.shape[1] < stack.shape[2]:
+                    row = jnp.pad(row, ((0, 0), (0, stack.shape[2]
+                                                 - row.shape[1]))
+                                  + ((0, 0),) * (row.ndim - 2))
+                stack = stack.at[:, slot].set(row.astype(stack.dtype))
+            elif stack is not None and rows is None and name in STATES:
+                stack = stack.at[:, slot].set(0)
+            out.append(stack)
+        return out
 
     def _reset_state_impl(self, cache: KVCache, slot):
-        """`slot`'s state back to a new sequence's (zeros)."""
-        return cache._replace(state=cache.state.at[:, slot].set(0))
+        """`slot`'s states back to a new sequence's (zeros)."""
+        return cache._replace(
+            **dict(zip(KEPT, self._slot_fields(cache, slot))))
 
     def _decode_impl(self, params, toks, cache, rng, temps, topks,
                      active_mask, *, access=_write_stack):
@@ -483,15 +511,17 @@ class ContinuousBatcher(PrefillPrograms):
 
     def _row_state(self, rest: list):
         """What a prefill program returned after its rows, split into what
-        install takes behind them (`[state]` from a stateful model, `[None,
-        ring_k, ring_v]` from a layer pattern, `[]` from any other) and what
-        the expert layers counted; the state is counted as installed."""
-        if self.cfg.layer_kinds:
-            return [None, *rest[:2]], rest[2:]
-        n = int(self.cfg.stateful)
-        if n:
+        install takes behind them (one entry a field of `KEPT` up to the
+        last the model keeps, None where it keeps none: `cfg.keeps` says
+        which the program returned; nothing for a model that keeps rows
+        alone) and what the expert layers counted; a state is counted as
+        installed."""
+        names = [name for name in KEPT if name in self.cfg.keeps]
+        given = dict(zip(names, rest))
+        if self.cfg.stateful:
             self.stats["state_installs"] += 1
-        return rest[:n], rest[n:]
+        last = max((KEPT.index(name) + 1 for name in names), default=0)
+        return [given.get(name) for name in KEPT[:last]], rest[len(names):]
 
     def _decode(self, toks, rng, temps, topks, active_mask):
         """One decode step over the cache, which the step keeps. Returns
@@ -503,30 +533,39 @@ class ContinuousBatcher(PrefillPrograms):
     def _kv_rows(self, lens: np.ndarray):
         """(held, read): the cache rows a decode step
         must read for sequences of `lens` rows (the step's own among them),
-        summed over the layers, a window layer's ring holding `window` at
-        most; and what the step's attention reads for them: each slot's rows
-        in whole blocks (`ops.attention.decode_attention`; a free slot
-        nothing). Before PR 35 a step read `slots x max_len` a full layer
-        and `slots x window` a window layer whatever was held."""
+        summed over the layers that keep rows (a window layer's ring holding
+        `window` at most, a latent layer one row a position, a linear-
+        attention layer none); and what the step's attention reads for
+        them: each slot's rows in whole blocks
+        (`ops.attention.decode_attention`; a free slot nothing). Before PR
+        35 a step read `slots x max_len` a full layer and `slots x window`
+        a window layer whatever was held."""
         cfg = self.cfg
-        row_bytes = cfg.kv_heads * cfg.hd * jnp.dtype(cfg.dtype).itemsize
+        itemsize = jnp.dtype(cfg.dtype).itemsize
 
-        def in_blocks(rows, t):
+        def in_blocks(rows, t, row_bytes):
             block = decode_block(t, row_bytes)
             return int((-(-rows // block) * block).sum())
 
+        kv_bytes = cfg.kv_heads * cfg.hd * itemsize
         held = cfg.full_layers * int(lens.sum())
-        read = cfg.full_layers * in_blocks(lens, self.max_len)
+        read = cfg.full_layers * in_blocks(lens, self.max_len, kv_bytes)
         if cfg.window_layers:
             ring = np.minimum(lens, cfg.window)
             held += cfg.window_layers * int(ring.sum())
-            read += cfg.window_layers * in_blocks(ring, cfg.window)
+            read += cfg.window_layers * in_blocks(ring, cfg.window, kv_bytes)
+        if "latent" in cfg.keeps:
+            latent = cfg.layers_of("mla")
+            held += latent * int(lens.sum())
+            read += latent * in_blocks(lens, self.max_len,
+                                       cfg.latent_row * itemsize)
         return held, read
 
     def _release(self, req: _Request) -> None:
         """Give back what the cache holds for a request that leaves its
         slot or never got one. A slot's rows are overwritten by the next
-        install and masked until then: nothing. A stateful model's slot goes
+        install and masked until then: nothing. A stateful model's slot
+        (`_slot_fields`: whatever its layers read and rewrite every step) goes
         back to a new sequence's zeros at once (behind whatever step is in
         flight: the device runs them in order). No result depends on it: a
         step keeps a free slot's state as it is and the next install writes

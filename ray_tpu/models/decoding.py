@@ -68,31 +68,54 @@ class KVCache(NamedTuple):
     # are then the full layers' alone. None for every other model.
     ring_k: Optional[jax.Array] = None
     ring_v: Optional[jax.Array] = None
+    # A pattern of "kda" and "mla" layers (models/kimi_linear.py). `mat`
+    # [kda layers, B, heads, D, D] float32: a linear-attention head's matrix
+    # state, keys x values; `conv` [kda layers, B, (taps - 1) * 3 * heads * D]:
+    # the last inputs of its three convolutions; both read and rewritten
+    # every step, as `state`. `latent` [mla layers, B, max_len, latent_row]:
+    # a latent-attention layer's one row a position (latent + rope values,
+    # zeros up to whole lanes: `cfg.latent_row`), appended as `k` / `v` are
+    # but with no heads. `k` / `v` then have no layer at all.
+    mat: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
+    latent: Optional[jax.Array] = None
 
 
-def init_state(cfg: TransformerConfig, batch: int, dtype=None):
-    """`KVCache.state` of `batch` new sequences: zeros, or None for a model
-    whose sequences keep nothing but their rows."""
+# A cache's per-slot fields, [layers, B, ...] each (`TransformerConfig.keeps`
+# says which a configuration has). ROWS are appended a position at a time and
+# masked by `lengths`: a slot's next occupant overwrites them whole. STATES
+# are read and rewritten by every step: they start from zeros.
+ROWS = ("k", "v", "ring_k", "ring_v", "latent")
+STATES = ("state", "mat", "conv")
+
+
+def init_state(cfg: TransformerConfig, batch: int, dtype=None) -> dict:
+    """{field: zeros} of `KVCache`'s STATES for `batch` new sequences, by
+    what the configuration's layers keep (`cfg.keeps`); {} for a model whose
+    sequences keep nothing but their rows. The shapes are the mixer's own."""
     if not cfg.stateful:
-        return None
-    from ray_tpu.models import zaya
-
-    return zaya.init_state(cfg, batch, dtype or cfg.dtype)
+        return {}
+    if cfg.layer_kinds:
+        mixer = cfg.pattern_module()
+    else:
+        from ray_tpu.models import zaya as mixer
+    return mixer.init_state(cfg, batch, dtype or cfg.dtype)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None) -> KVCache:
     dtype = dtype or cfg.dtype
-    shape = (cfg.full_layers, batch, max_len, cfg.kv_heads, cfg.hd)
-    ring = (cfg.window_layers, batch, cfg.window, cfg.kv_heads, cfg.hd)
-    return KVCache(
-        k=jnp.zeros(shape, dtype),
-        v=jnp.zeros(shape, dtype),
-        lengths=jnp.zeros((batch,), jnp.int32),
-        state=init_state(cfg, batch, dtype),
-        ring_k=jnp.zeros(ring, dtype) if cfg.layer_kinds else None,
-        ring_v=jnp.zeros(ring, dtype) if cfg.layer_kinds else None,
-    )
+    shapes = {
+        "k": (cfg.full_layers, batch, max_len, cfg.kv_heads, cfg.hd),
+        "ring_k": (cfg.window_layers, batch, cfg.window, cfg.kv_heads,
+                   cfg.hd),
+        "latent": (cfg.layers_of("mla"), batch, max_len, cfg.latent_row)}
+    shapes.update(v=shapes["k"], ring_v=shapes["ring_k"])
+    rows = {n: jnp.zeros(shapes[n], dtype) for n in cfg.keeps if n in ROWS}
+    for name in ("k", "v"):  # a pattern without full layers: no layer of rows
+        rows.setdefault(name, jnp.zeros((0, *shapes[name][1:]), dtype))
+    return KVCache(lengths=jnp.zeros((batch,), jnp.int32), **rows,
+                   **init_state(cfg, batch, dtype))
 
 
 @jax.named_scope("attend_cached")
@@ -361,14 +384,15 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     positions is each sequence's last: the state it leaves is that
     position's, and a sequence with no real row keeps the state it had.
 
-    A layer pattern (`cfg.layer_kinds`) has a loop of its own over the same
-    carry plus the ring (`laguna.forward_cached`): a scan over periods.
+    A layer pattern (`cfg.layer_kinds`) has a loop of its own over what its
+    kinds of layers keep (`laguna.forward_cached`: this carry plus the ring;
+    `kimi_linear.forward_cached`: matrix states, convolution windows and
+    latent rows): a scan over periods.
     """
     if cfg.layer_kinds:
-        from ray_tpu.models import laguna
-
-        return laguna.forward_cached(cfg, params, tokens, positions, cache,
-                                     kv_len_mask, row_mask, access, rows)
+        return cfg.pattern_module().forward_cached(
+            cfg, params, tokens, positions, cache, kv_len_mask, row_mask,
+            access, rows)
     x = params["embed"].astype(cfg.dtype)[tokens]
     layer_tree, whole = layers_to_scan(cfg, params)
     route = None

@@ -133,10 +133,11 @@ class DisaggPrefillEngine:
                  num_cpus: float = 0.5):
         if cfg.layer_kinds:
             raise ValueError(
-                f"a layer pattern {cfg.layer_kinds!r} keeps its window "
-                "layers' rows in a ring that the KV channel does not carry "
-                "and the decode replica's pages do not hold: serve it from "
-                "one replica (ContinuousBatcher)")
+                f"a layer pattern {cfg.layer_kinds!r} keeps "
+                f"{', '.join(n for n in cfg.keeps if n not in ("k", "v"))} a "
+                "sequence, which the KV channel does not carry and the "
+                "decode replica's pages do not hold: serve it from one "
+                "replica (ContinuousBatcher)")
         if cfg.stateful:
             # the channel's row is K and V alone; decoding from it would
             # start every sequence from a new sequence's state

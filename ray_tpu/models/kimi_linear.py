@@ -1,0 +1,559 @@
+"""A pattern of delta-rule linear-attention layers beside latent-attention
+layers (moonshotai/Kimi-Linear-48B-A3B-Instruct, `model_type` kimi_linear;
+the recurrence is arXiv:2510.26692). Imported only where a configuration has
+one (`TransformerConfig.pattern_module`); the expert matmuls, sampling, the
+scheduler and the drawing of weights are the other models'
+(`transformer.moe_dropless`, `laguna._draw`).
+
+**Layers.** `cfg.kinds`: a leading "kda" layer with a dense SwiGLU MLP, whole
+periods of `layer_kinds` (kda, kda, mla, kda) and the trailing layers
+`tail_kinds` (kda, mla), every layer behind the first with sparse experts.
+Parameters are stacked BY KIND (`blocks["kda" | "mla" | "sparse"]`,
+`blocks["dense"]` the leading MLP alone); `forward_cached` runs the leading
+layer, ONE `lax.scan` over the periods, then the trailing layers. With y the
+RMS-normed stream:
+
+**A "kda" layer** (`heads` heads of `hd`, keys and values alike): `q~, k~,
+v~ = y Wq, y Wk, y Wv`; a causal depthwise convolution of `kda_conv` taps and
+SiLU on each; `q = l2norm(c_q) / sqrt(hd)`, `k = l2norm(c_k)`, `v = c_v`; a
+decay by CHANNEL `a = exp(-exp(A_log) softplus((y Wfa) Wfb + dt_bias))`, a
+step `beta = sigmoid(y Wb)` a head; the state S [keys, values] of a head,
+float32, zero at a sequence's start:
+`S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T`,
+`o_t = S_t^T q_t`; `o <- RMSNorm_head(o) * sigmoid((y Wga) Wgb)`, then `Wo`.
+A sequence keeps S (`KVCache.mat`) and the last `kda_conv - 1` inputs of the
+three convolutions (`KVCache.conv`). A decode step (S == 1) is one update of
+S, every product into it exact in float32 (on a TPU `ops.delta_rule.
+state_update`: the stack read once and written once, in place). A call with S > 1 is a prefill
+FROM POSITION 0 (every engine's) and runs the recurrence a CHUNK at a time
+(`kda_chunks`); the state and the window it leaves are those at each
+sequence's TRUE last position (`row_mask`): a pad position has beta 0 and
+decay 1.
+
+**An "mla" layer** (`heads` heads; no rotation is applied: the `mla_rope_dim`
+"rope" dimensions are one key part shared by all heads): `[q_n ; q_r]_i = y
+Wq_i`; `[c~ ; k_r] = y Wkva`, `c = RMSNorm(c~)`; a sequence keeps `[c ; k_r]`
+a position (`KVCache.latent`, in whole lanes: `cfg.latent_row`). Two programs for one mathematics: a prefill
+EXPANDS `[k_n ; v]_i = c Wkvb_i` and attends over heads of `hd +
+mla_rope_dim`; a decode step ABSORBS, `q'_i = Wkvb_i[:, :hd] q_n_i`, scores
+`(q'_i . c_j + q_r_i . k_r_j) / sqrt(hd + mla_rope_dim)` against the held
+rows, `u_i = sum_j p_j c_j`, `o_i = u_i Wkvb_i[:, hd:]`: each held row read
+once, where it lies (`ops.attention.latent_decode_attention` on a TPU).
+
+**Experts.** `router`: sigmoid scores over all `num_experts` in float32, the
+top k of score + a stored bias, the weights the scores alone, renormalised,
+times `routed_scale`; then `laguna.sparse_mlp`: `moe_dropless` with
+`experts_held`, and the shared expert. What the absent experts would add is left out here, and nothing
+stands in for the other chips or for their exchange.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models.decoding import KVCache, _write_stack
+from ray_tpu.models.laguna import (
+    EXPERT_LEAVES, _draw, _swiglu, _take, _tree, sparse_mlp,
+)
+from ray_tpu.models.transformer import TransformerConfig, _rms_norm
+from ray_tpu.ops import attention as attention_ops
+from ray_tpu.ops import delta_rule
+from ray_tpu.ops.attention import NEG_INF
+
+KDA_CHUNK = 32  # positions a chunk of the prefill's scan (`kda_chunks`)
+F32 = jnp.float32
+HI = lax.Precision.HIGHEST
+
+
+# -- parameters --------------------------------------------------------------
+
+def leaves(cfg: TransformerConfig) -> dict:
+    """{(group, ..., name): (shape, init, logical axes)} of every parameter
+    leaf. `init` is a fan-in (normal over its root), None (a norm's weight:
+    ones), or the name of one of the family's initialisers (`_special`)."""
+    h, d, nh = cfg.hidden, cfg.hd, cfg.heads
+    lat, rope, taps = cfg.mla_latent, cfg.mla_rope_dim, cfg.kda_conv
+    out = {("embed",): ((cfg.vocab_size, h), h, ("vocab", "embed")),
+           ("unembed",): ((h, cfg.vocab_size), h, ("embed", "vocab")),
+           ("ln_f",): ((h,), None, ("norm",))}
+    n, at = cfg.layers_of("kda"), ("blocks", "kda")
+    heads = ("layers", "embed", "heads")  # [in, heads * D]: `_to_heads`
+    out[at + ("ln_attn",)] = ((n, h), None, ("layers", "norm"))
+    for name in ("wq", "wk", "wv"):
+        out[at + (name,)] = ((n, h, nh * d), h, heads)
+        out[at + ("conv_" + name[1],)] = (
+            (n, taps, nh, d), "taps", ("layers", None, "heads", "head_dim"))
+    for name in ("w_fa", "w_ga"):  # the low-rank decay and output gates
+        out[at + (name,)] = ((n, h, d), h, ("layers", "embed", None))
+    for name in ("w_fb", "w_gb"):
+        out[at + (name,)] = ((n, d, nh * d), d, ("layers", None, "heads"))
+    out[at + ("a_log",)] = ((n, nh), "a_log", ("layers", "heads"))
+    out[at + ("dt_bias",)] = ((n, nh, d), "dt_bias",
+                              ("layers", "heads", "head_dim"))
+    out[at + ("w_b",)] = ((n, h, nh), h, ("layers", "embed", "heads"))
+    out[at + ("o_norm",)] = ((n, d), None, ("layers", "norm"))
+    out[at + ("wo",)] = ((n, nh, d, h), nh * d,
+                         ("layers", "heads", "head_dim", "embed"))
+    n, at = cfg.layers_of("mla"), ("blocks", "mla")
+    out[at + ("ln_attn",)] = ((n, h), None, ("layers", "norm"))
+    out[at + ("wq",)] = ((n, h, nh * (d + rope)), h, heads)
+    out[at + ("wkv_a",)] = ((n, h, lat + rope), h, ("layers", "embed", None))
+    out[at + ("kv_norm",)] = ((n, lat), None, ("layers", "norm"))
+    out[at + ("wkv_b",)] = ((n, lat, nh, 2 * d), lat,
+                            ("layers", None, "heads", "head_dim"))
+    out[at + ("wo",)] = ((n, nh, d, h), nh * d,
+                         ("layers", "heads", "head_dim", "embed"))
+    m = cfg.dense_mlp_hidden
+    out[("blocks", "dense", "ln_mlp")] = ((h,), None, ("norm",))
+    out[("blocks", "dense", "wi_gate")] = ((h, m), h, ("embed", "mlp"))
+    out[("blocks", "dense", "wi_up")] = ((h, m), h, ("embed", "mlp"))
+    out[("blocks", "dense", "wo_mlp")] = ((m, h), m, ("mlp", "embed"))
+    n, m, at = cfg.sparse_layers, cfg.mlp_hidden, ("blocks", "sparse")
+    held = cfg.experts_held[1] if cfg.experts_held else cfg.num_experts
+    out[at + ("ln_mlp",)] = ((n, h), None, ("layers", "norm"))
+    out[at + ("router",)] = ((n, h, cfg.num_experts), h,
+                             ("layers", "embed", None))
+    if cfg.router_score == "sigmoid":
+        out[at + ("router_bias",)] = ((n, cfg.num_experts), "router_bias",
+                                      ("layers", None))
+    out[at + ("wi_gate",)] = ((n, held, h, m), h,
+                              ("layers", "expert", "embed", "mlp"))
+    out[at + ("wi_up",)] = ((n, held, h, m), h,
+                            ("layers", "expert", "embed", "mlp"))
+    out[at + ("wo_mlp",)] = ((n, held, m, h), m,
+                             ("layers", "expert", "mlp", "embed"))
+    if cfg.shared_expert_hidden:
+        ms = cfg.shared_expert_hidden
+        out[at + ("shared_gate",)] = ((n, h, ms), h,
+                                      ("layers", "embed", "mlp"))
+        out[at + ("shared_up",)] = ((n, h, ms), h, ("layers", "embed", "mlp"))
+        out[at + ("shared_down",)] = ((n, ms, h), ms,
+                                      ("layers", "mlp", "embed"))
+    return out
+
+
+def num_params(cfg: TransformerConfig) -> int:
+    """What is HELD here: `experts_held` experts a layer, not `num_experts`."""
+    return sum(math.prod(shape) for shape, _, _ in leaves(cfg).values())
+
+
+def param_axes(cfg: TransformerConfig) -> dict:
+    return _tree({path: axes for path, (_, _, axes) in leaves(cfg).items()})
+
+
+def _special(key, shape, init: str, dtype):
+    """The leaves a normal draw would leave degenerate, by the family's
+    initialisers (the linear-attention library's, as remembered): the taps
+    uniform in +-1/sqrt(taps); `a_log` the log of a rate uniform in [1, 16);
+    `dt_bias` the inverse softplus of a step log-uniform in [0.001, 0.1);
+    the selection bias normal of 0.01 about zero (a trained one is stored;
+    zeros would leave it unexercised). Decays then lie strictly between 0
+    and 1."""
+    if init == "taps":
+        bound = 1 / math.sqrt(shape[1])
+        out = jax.random.uniform(key, shape, F32, -bound, bound)
+    elif init == "a_log":
+        out = jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
+    elif init == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(key, shape, F32, math.log(1e-3),
+                                        math.log(1e-1)))
+        out = dt + jnp.log(-jnp.expm1(-dt))
+    elif init == "router_bias":
+        out = 0.01 * jax.random.normal(key, shape, F32)
+    else:
+        raise ValueError(f"unknown initialiser {init!r}")
+    return out.astype(dtype)
+
+
+def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
+    out = {}
+    for i, (path, (shape, init, _)) in enumerate(leaves(cfg).items()):
+        k = jax.random.fold_in(key, i)
+        if init is None:
+            out[path] = jnp.ones(shape, cfg.param_dtype)
+        elif isinstance(init, str):
+            out[path] = _special(k, shape, init, cfg.param_dtype)
+        else:
+            out[path] = _draw(k, shape, init, cfg.param_dtype)
+    return _tree(out)
+
+
+def init_state(cfg: TransformerConfig, batch: int, dtype) -> dict:
+    """What `batch` new sequences keep in the "kda" layers: the matrix
+    states, float32 whatever the stream's dtype, and the convolutions'
+    windows, the `kda_conv - 1` last inputs of q, k and v flat in one row a
+    sequence (positions, then q | k | v, heads, head_dim): a slot is one row
+    of whole lanes, where [taps - 1, 3, heads, D] a slot made the chip's
+    compiler transpose the stack in and out of every step."""
+    n, nh, d = cfg.layers_of("kda"), cfg.heads, cfg.hd
+    return {"mat": jnp.zeros((n, batch, nh, d, d), F32),
+            "conv": jnp.zeros((n, batch, (cfg.kda_conv - 1) * 3 * nh * d),
+                              dtype)}
+
+
+# -- the linear-attention layer -------------------------------------------------
+
+def _to_heads(y, w, heads: int, out=None):
+    """y [B, S, in] times w [in, heads * D] -> [B, S, heads, D]. The
+    projections into heads are kept as plain matrices [in, heads * D]: as
+    [in, heads, D] the chip tiles them over (heads, D), another order than a
+    product over `in` reads, and its compiler copied them every decode step,
+    whole stacks hoisted out of the layer loop or a layer's matrix before its
+    product (1.1 GB a step; `benchmarks/rehearse_kimi_linear.py --text`, PR
+    38)."""
+    flat = jnp.einsum("bsi,im->bsm", y, w.astype(y.dtype),
+                      preferred_element_type=out)
+    return flat.reshape(*y.shape[:2], heads, -1)
+
+
+def _from_heads(o, w):
+    """o [B, S, heads, D] times w [heads, D, out] -> [B, S, out]."""
+    return jnp.einsum("bsnd,ndh->bsh", o, w.astype(o.dtype))
+
+
+def _l2norm(x):
+    return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def kda_step(state, q, k, v, log_a, beta):
+    """One position of the recurrence for every sequence and head: state
+    [B, H, K, V] float32; q, k, v, log_a [B, H, D] float32; beta [B, H].
+    Returns (state, o [B, H, V]). Elementwise products and sums, so that
+    every product into the state is exact in float32 (a float32 matmul at
+    the TPU's default precision rounds its operands to bfloat16)."""
+    state = state * jnp.exp(log_a)[..., None]
+    u = beta[..., None] * (v - (k[..., None] * state).sum(-2))
+    state = state + k[..., None] * u[..., None, :]
+    return state, (q[..., None] * state).sum(-2)
+
+
+def kda_chunks(state, q, k, v, log_a, beta, chunk: int = KDA_CHUNK):
+    """The recurrence over S positions a chunk at a time: state [B, H, K, V]
+    float32 entering; q, k, v, log_a [B, S, H, D] float32; beta [B, S, H].
+    Returns (state after position S - 1, o [B, S, H, V]).
+
+    Exact, derived from the recurrence. With g_t the running sum of log_a
+    inside a chunk (G_t = exp(g_t)) and S_0 the state entering it, `S_t =
+    Diag(a_t) S_{t-1} + k_t u_t^T` with `u_t = beta_t (v_t - S_0^T (G_t *
+    k_t) - sum_{s<t} u_s A_kk[t, s])`, `A_kk[t, s] = sum_d k_t[d] k_s[d] G_t[d]
+    / G_s[d]`: a unit lower-triangular system of the chunk's size for U; then
+    `o_t = S_0^T (G_t * q_t) + sum_{s<=t} u_s A_qk[t, s]` and `S_C = Diag(G_C)
+    S_0 + sum_s (G_C / G_s * k_s) u_s^T`. Every ratio G_t / G_s is formed as
+    exp(g_t - g_s) with s <= t, at most 1: `1 / G_s` alone overflows where a
+    channel decays fast (the seeded rates reach e^-13 a position). The
+    products with the state run at the highest precision.
+
+    Chunks of 32: on the v5e a layer's 2,048 positions take 5.0 ms at 32,
+    7.6 at 64 and 12.3 at 128 (the [C, C, D] ratios are elementwise work,
+    the scan's steps about 40 us each). Tried and slower or no faster (my
+    chip runs, PR 38): the ratios in sub-chunks of 16 with matmuls between
+    them (5.0 at 32), the system inverted by halves in place of the
+    triangular solve (5.9), and everything that does not depend on the state
+    computed for all chunks at once ahead of a scan of four matmuls (7.1)."""
+    b, s, h, d = q.shape
+    chunk = min(chunk, s)
+    pad = -s % chunk
+    if pad:  # beta 0 and decay 1: the state passes a pad position unchanged
+        q, k, v, log_a = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                          for a in (q, k, v, log_a))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n = (s + pad) // chunk
+
+    def by_chunk(a):  # [B, S, H, ...] -> [n, B, H, C, ...]
+        a = a.reshape(b, n, chunk, *a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 1, 0), 2, 3)
+
+    t = jnp.arange(chunk)
+    below, upto = t[:, None] > t[None, :], t[:, None] >= t[None, :]
+    eye = jnp.eye(chunk, dtype=F32)
+
+    def one(state, xs):
+        q, k, v, log_a, beta = xs  # [B, H, C, D], beta [B, H, C]
+        g = jnp.cumsum(log_a, axis=2)
+        # exp(g_t - g_s) for s <= t, 0 above the diagonal: [B, H, C, C, D]
+        ratio = jnp.exp(jnp.where(
+            upto[..., None], g[:, :, :, None] - g[:, :, None, :], -jnp.inf))
+        a_kk = (k[:, :, :, None] * k[:, :, None, :] * ratio).sum(-1)
+        a_qk = (q[:, :, :, None] * k[:, :, None, :] * ratio).sum(-1)
+        decayed = jnp.exp(g)
+        rhs = beta[..., None] * (v - jnp.einsum(
+            "bhck,bhkv->bhcv", decayed * k, state, precision=HI))
+        system = eye + beta[..., None] * jnp.where(below, a_kk, 0.0)
+        u = jax.scipy.linalg.solve_triangular(
+            system, rhs, lower=True, unit_diagonal=True)
+        o = jnp.einsum("bhck,bhkv->bhcv", decayed * q, state, precision=HI) \
+            + jnp.einsum("bhcs,bhsv->bhcv", a_qk, u, precision=HI)
+        to_end = jnp.exp(g[:, :, -1:] - g)  # G_C / G_s
+        state = decayed[:, :, -1, :, None] * state + jnp.einsum(
+            "bhsk,bhsv->bhkv", to_end * k, u, precision=HI)
+        return state, o
+
+    state, o = lax.scan(one, state, tuple(
+        by_chunk(a) for a in (q, k, v, log_a, beta)))
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)  # [B, n, C, H, V]
+    return state, o.reshape(b, n * chunk, h, d)[:, :s]
+
+
+def kda_attention(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
+    """The attention half of "kda" layer `layer` (its index within its
+    kind): `mat` / `conv` are the stacks, read and rewritten at `layer` in
+    place. Returns (x, mat, conv)."""
+    b, s, _ = x.shape
+    nh, d, taps = cfg.heads, cfg.hd, cfg.kda_conv
+    y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
+    real = row_mask.astype(F32)  # [B, S]
+    n_real = row_mask.sum(1).astype(jnp.int32)
+    with jax.named_scope("kda.project"):
+        # the three projections' channels side by side, flat: [B, S, 3 H D]
+        z = jnp.concatenate([jnp.einsum(
+            "bsh,hm->bsm", y, p[w].astype(y.dtype))
+            for w in ("wq", "wk", "wv")], axis=-1)
+    with jax.named_scope("kda.conv"):
+        before = lax.dynamic_index_in_dim(conv, layer, keepdims=False)
+        seen = jnp.concatenate(
+            [before.reshape(b, taps - 1, -1).astype(z.dtype), z], axis=1)
+        w = jnp.concatenate([p[c].reshape(taps, -1) for c in (
+            "conv_q", "conv_k", "conv_v")], axis=-1).astype(F32)
+        c = jax.nn.silu(sum(w[j] * seen[:, j:j + s].astype(F32)
+                            for j in range(taps)))
+        # the window after this call: the inputs of each sequence's last
+        # `taps - 1` real positions; what it was for a row that has none
+        at = n_real[:, None] + jnp.arange(taps - 1)  # into `seen`
+        window = jnp.take_along_axis(seen, at[:, :, None], axis=1)
+        conv = lax.dynamic_update_index_in_dim(
+            conv, window.reshape(b, -1).astype(conv.dtype), layer, 0)
+        c = c.reshape(b, s, 3, nh, d)
+        q = _l2norm(c[:, :, 0]) * d ** -0.5
+        k, v = _l2norm(c[:, :, 1]), c[:, :, 2]
+    with jax.named_scope("kda.gate"):
+        f = jnp.einsum("bsh,hr->bsr", y, p["w_fa"].astype(y.dtype))
+        f = _to_heads(f, p["w_fb"], nh, F32)
+        log_a = -jnp.exp(p["a_log"].astype(F32))[:, None] * jax.nn.softplus(
+            f + p["dt_bias"].astype(F32))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsh,hn->bsn", y, p["w_b"].astype(y.dtype),
+            preferred_element_type=F32))
+        # a position that is no sequence's leaves the state as it is
+        log_a = log_a * real[:, :, None, None]
+        beta = beta * real[:, :, None]
+        gate = jnp.einsum("bsh,hr->bsr", y, p["w_ga"].astype(y.dtype))
+        gate = jax.nn.sigmoid(_to_heads(gate, p["w_gb"], nh, F32))
+    # the state's read and write are the sublayer's, under its scope
+    with jax.named_scope("kda.state" if s == 1 else "kda.prefill_scan"):
+        if s == 1 and delta_rule.state_update_takes(mat):
+            mat, o = delta_rule.state_update(  # read once, written once
+                mat, layer, q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
+                beta[:, 0])
+            o = o[:, None]
+        else:
+            before = lax.dynamic_index_in_dim(mat, layer, keepdims=False)
+            if s == 1:
+                after, o = kda_step(before, q[:, 0], k[:, 0], v[:, 0],
+                                    log_a[:, 0], beta[:, 0])
+                o = o[:, None]
+            else:
+                after, o = kda_chunks(before, q, k, v, log_a, beta)
+            mat = lax.dynamic_update_index_in_dim(mat, after, layer, 0)
+    with jax.named_scope("kda.out"):
+        o = _rms_norm(o, p["o_norm"].astype(F32), cfg.norm_eps) * gate
+        out = _from_heads(o.astype(x.dtype), p["wo"])
+    return x + out, mat, conv
+
+
+# -- the latent-attention layer ----------------------------------------------------
+
+def latent_attend(q, latent, layer, rows, kv_len_mask, value_dim: int,
+                  sm_scale: float):
+    """A decode step's absorbed attention: q [B, H, latent_row] against the
+    rows of layer `layer` of the stack `latent` [N, B, T, latent_row]; the
+    first `value_dim` of a row are its value too. `rows` [B]: how
+    many each slot holds (0: it takes no part). On a TPU the kernel that
+    reads those rows where they lie, once; elsewhere the same mathematics
+    over the layer under `kv_len_mask` [B, T]. Returns [B, H, value_dim]
+    float32."""
+    if rows is not None and attention_ops.latent_decode_attention_takes(
+            latent, value_dim):
+        return attention_ops.latent_decode_attention(
+            q, latent, layer, rows, value_dim, sm_scale)
+    held = lax.dynamic_index_in_dim(latent, layer, keepdims=False)
+    logits = jnp.einsum("bhc,btc->bht", q.astype(held.dtype), held,
+                        preferred_element_type=F32) * sm_scale
+    mask = kv_len_mask if rows is None else (
+        jnp.arange(held.shape[1])[None] < rows[:, None])
+    probs = jax.nn.softmax(jnp.where(mask[:, None], logits, NEG_INF), -1)
+    probs = jnp.where(mask.any(-1)[:, None, None], probs, 0.0)
+    return jnp.einsum("bht,btc->bhc", probs.astype(held.dtype),
+                      held[..., :value_dim], preferred_element_type=F32)
+
+
+def mla_attention(cfg: TransformerConfig, x, p, positions, latent,
+                  kv_len_mask, row_mask, layer, rows=None):
+    """The attention half of "mla" layer `layer` (its index within its
+    kind): `latent` is the stack, this call's rows written at [layer,
+    sequence, position] in place. Returns (x, latent)."""
+    b, s, _ = x.shape
+    nh, d, lat, rope = cfg.heads, cfg.hd, cfg.mla_latent, cfg.mla_rope_dim
+    sm_scale = (d + rope) ** -0.5
+    y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
+    wkv_b = p["wkv_b"].astype(y.dtype)  # [latent, H, 2 D]
+    with jax.named_scope("mla.project"):
+        # wq's columns: every head's `d` unrotated dimensions, then every
+        # head's `rope` shared-key dimensions, so that each part is a plain
+        # split of the product (heads of d + rope side by side made the
+        # chip's compiler copy the matrix every step)
+        q = jnp.einsum("bsh,hm->bsm", y, p["wq"].astype(y.dtype))
+        q_n = q[..., :nh * d].reshape(b, s, nh, d)
+        q_r = q[..., nh * d:].reshape(b, s, nh, rope)
+        kv = jnp.einsum("bsh,hc->bsc", y, p["wkv_a"].astype(y.dtype))
+        row = jnp.concatenate(
+            [_rms_norm(kv[..., :lat], p["kv_norm"], cfg.norm_eps),
+             kv[..., lat:],
+             jnp.zeros((b, s, cfg.latent_row - lat - rope), kv.dtype)],
+            axis=-1).astype(latent.dtype)
+        if s == latent.shape[2]:  # a prefill into a row cache of its bucket
+            latent = lax.dynamic_update_index_in_dim(latent, row, layer, 0)
+        else:
+            latent = latent.at[layer, jnp.arange(b)[:, None], positions].set(
+                row)
+    if s == 1:  # a decode step: absorbed, over the rows held
+        with jax.named_scope("mla.project"):
+            absorbed = jnp.einsum("bnd,cnd->bnc", q_n[:, 0],
+                                  wkv_b[..., :d])
+            q_row = jnp.concatenate(
+                [absorbed, q_r[:, 0],
+                 jnp.zeros((b, nh, cfg.latent_row - lat - rope), q.dtype)],
+                axis=-1)
+        with jax.named_scope("mla.attend"):
+            u = latent_attend(q_row, latent, layer, rows, kv_len_mask, lat,
+                              sm_scale)
+        with jax.named_scope("mla.out"):
+            o = jnp.einsum("bnc,cnd->bnd", u.astype(y.dtype),
+                           wkv_b[..., d:])[:, None]
+    else:  # a prefill from position 0: expanded, over its own fresh rows
+        with jax.named_scope("mla.attend"):
+            expanded = jnp.einsum("bsc,cnd->bsnd", row[..., :lat].astype(
+                y.dtype), wkv_b)
+            logits = jnp.einsum("bsnd,btnd->bnst", q_n,
+                                expanded[..., :d],
+                                preferred_element_type=F32)
+            logits = (logits + jnp.einsum(
+                "bsnr,btr->bnst", q_r,
+                row[..., lat:lat + rope].astype(y.dtype),
+                preferred_element_type=F32)) * sm_scale
+            seen = (positions[:, :, None] >= positions[:, None, :]) \
+                & row_mask[:, None, :]
+            probs = jax.nn.softmax(
+                jnp.where(seen[:, None], logits, NEG_INF), axis=-1)
+            o = jnp.einsum("bnst,btnd->bsnd", probs.astype(y.dtype),
+                           expanded[..., d:])
+    with jax.named_scope("mla.out"):
+        out = _from_heads(o.astype(x.dtype), p["wo"])
+    return x + out, latent
+
+
+# -- the MLP halves ---------------------------------------------------------------
+
+@jax.named_scope("moe_router")
+def router(cfg: TransformerConfig, x, p):
+    """x [T, h] -> (weights [T, k] float32, experts [T, k] int32),
+    `transformer.moe_router`'s pair: sigmoid scores over all experts, the k
+    chosen by score + the stored bias, the weights the scores WITHOUT it,
+    divided by their sum if `norm_topk_prob`, times `routed_scale`. One
+    group of experts: no grouping."""
+    logits = jnp.einsum("th,he->te", x, p["router"].astype(x.dtype),
+                        preferred_element_type=F32)
+    scores = jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    choose = scores
+    if "router_bias" in p:
+        choose = scores + p["router_bias"].astype(F32)
+    _, experts = lax.top_k(choose, cfg.experts_per_token)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-20)
+    return weights * cfg.routed_scale, experts
+
+
+def forward_cached(cfg: TransformerConfig, params, tokens, positions,
+                   cache: KVCache, kv_len_mask, row_mask, access=_write_stack,
+                   rows=None):
+    """`decoding.forward_cached` for this pattern: the same arguments and
+    results, the carry being the residual stream, the "kda" layers' matrix
+    states and convolution windows and the "mla" layers' latent rows, all
+    written in place at [layer of its kind]. `aux` as `laguna.
+    forward_cached`'s: "expert_load", "expert_choice" [sparse layers, B*S,
+    k], "experts_reached"."""
+    if access is not _write_stack:
+        raise ValueError(
+            f"a layer pattern {cfg.layer_kinds!r} keeps a matrix state a head "
+            "and one latent row a position: no other cache access (pages) "
+            "holds either")
+    blocks = params["blocks"]
+    sparse = {n: a for n, a in blocks["sparse"].items()
+              if n not in EXPERT_LEAVES}
+    experts = {n: blocks["sparse"][n] for n in EXPERT_LEAVES}
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    period = len(cfg.layer_kinds)
+
+    def attend(kind, x, held, at):
+        """One layer of `kind`, the `at[kind]`-th of it; counts it."""
+        mat, conv, latent = held
+        p = _take(blocks[kind], at[kind])
+        if kind == "kda":
+            x, mat, conv = kda_attention(cfg, x, p, mat, conv, row_mask,
+                                         at[kind])
+        else:
+            x, latent = mla_attention(cfg, x, p, positions, latent,
+                                      kv_len_mask, row_mask, at[kind], rows)
+        return x, (mat, conv, latent), dict(at, **{kind: at[kind] + 1})
+
+    def layers(x, held, at, kinds, first_sparse):
+        """`kinds` layers in a row, each with its sparse MLP."""
+        load, reached, choices = 0, 0, []
+        for j, kind in enumerate(kinds):
+            x, held, at = attend(kind, x, held, at)
+            layer = first_sparse + j
+            x, l, chosen, r = sparse_mlp(
+                cfg, x, dict(_take(sparse, layer), **experts), row_mask, layer,
+                router)
+            load, reached = load + l, reached + r
+            choices.append(chosen)
+        return x, held, (load, jnp.stack(choices), reached)
+
+    held = (cache.mat, cache.conv, cache.latent)
+    x, held, _ = attend(cfg.lead_kind, x, held, {"kda": 0, "mla": 0})
+    dense = blocks["dense"]
+    with jax.named_scope("mlp"):
+        x = x + _swiglu(_rms_norm(x, dense["ln_mlp"], cfg.norm_eps),
+                        dense["wi_gate"], dense["wi_up"], dense["wo_mlp"])
+    lead = {k: int(cfg.lead_kind == k) for k in ("kda", "mla")}
+
+    def one_period(carry, i):
+        x, held = carry
+        at = {k: lead[k] + i * cfg.layer_kinds.count(k) for k in lead}
+        x, held, counted = layers(x, held, at, cfg.layer_kinds, i * period)
+        return (x, held), counted
+
+    (x, held), (load, choice, reached) = lax.scan(
+        one_period, (x, held), jnp.arange(cfg.periods))
+    load, reached = load.sum(0), reached.sum()
+    choice = choice.reshape(-1, *choice.shape[2:])
+    if cfg.tail_kinds:
+        at = {k: lead[k] + cfg.periods * cfg.layer_kinds.count(k)
+              for k in lead}
+        x, held, (l, c, r) = layers(x, held, at, cfg.tail_kinds,
+                                    cfg.periods * period)
+        load, reached = load + l, reached + r
+        choice = jnp.concatenate([choice, c])
+    aux = {"expert_load": load, "expert_choice": choice,
+           "experts_reached": reached}
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsh,hv->bsv", x,
+                            params["unembed"].astype(x.dtype))
+    mat, conv, latent = held
+    return logits, cache._replace(mat=mat, conv=conv, latent=latent), aux

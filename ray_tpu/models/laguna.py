@@ -333,14 +333,16 @@ def _swiglu(y, gate, up, down):
     return jnp.einsum("bsm,mh->bsh", act, down.astype(act.dtype))
 
 
-def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer):
+def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer,
+               router=moe_router):
     """The expert half of sparse layer `layer`: `p` is that layer's small
     parameters and the WHOLE expert stacks (`_grouped_matmul` reads its
-    layer in place). Returns (x, load [num_experts] from the real rows, the
-    experts every row chose [B*S, k], how many of the experts held here the
-    real rows reached)."""
+    layer in place); `router(cfg, rows, p)` gives `moe_router`'s pair.
+    Returns (x, load [num_experts] from the real rows, the experts every row
+    chose [B*S, k], how many of the experts held here the real rows
+    reached)."""
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
-    routing = moe_router(cfg, y.reshape(-1, y.shape[-1]), p)
+    routing = router(cfg, y.reshape(-1, y.shape[-1]), p)
     routed, load = moe_dropless(cfg, y, p, row_mask, layer, routing)
     x = x + routed
     if cfg.shared_expert_hidden:

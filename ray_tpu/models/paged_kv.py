@@ -175,9 +175,11 @@ class PagedBatcher(ContinuousBatcher):
         if cfg.layer_kinds:
             # and with it `submit_prefilled`: a premade row brings no ring
             raise ValueError(
-                f"a layer pattern {cfg.layer_kinds!r} keeps its window "
-                "layers' rows in a ring beside the slots; pages hold no ring "
-                "(prefix reuse and preemption would have to rebuild it): "
+                f"a layer pattern {cfg.layer_kinds!r} keeps "
+                f"{', '.join(n for n in cfg.keeps if n not in ("k", "v"))} a "
+                "sequence beside or in place of the slots' rows; pages hold "
+                "no ring, matrix state, convolution window or latent row "
+                "(prefix reuse and preemption would have to rebuild them): "
                 "serve it from ContinuousBatcher")
         if max_len % page_size != 0:
             raise ValueError("max_len must be a multiple of page_size")
@@ -240,11 +242,12 @@ class PagedBatcher(ContinuousBatcher):
         return last[0], *self._row_of(row), *aux.values()
 
     def _install_impl(self, cache: KVCache, row_k, row_v, page_ids, slot,
-                      length, row_state=None):
+                      length, *kept):
         """Scatter a [L, max_len] row into the pool at page_ids
         [pages_per_seq] (trash page 0 for pages not to keep). A stateful
-        model's `row_state` goes to the slot, not to a page: the state is
-        kept per sequence, [L, slots, ...], outside the pool."""
+        model's row state (`kept`, as `ContinuousBatcher._install_impl`'s)
+        goes to the slot, not to a page: the state is kept per sequence,
+        [L, slots, ...], outside the pool."""
         paged = (row_k.shape[0], self.pages_per_seq, self.page_size,
                  *row_k.shape[2:])
         return KVCache(
@@ -253,7 +256,7 @@ class PagedBatcher(ContinuousBatcher):
             cache.v.at[:, page_ids].set(
                 row_v.reshape(paged).astype(cache.v.dtype)),
             cache.lengths.at[slot].set(length),
-            self._slot_state(cache.state, slot, row_state))
+            *self._slot_fields(cache, slot, kept))
 
     def _gather_row(self, page_ids):
         """[pages_per_seq] page ids -> dense [L, max_len] row (for
@@ -302,7 +305,7 @@ class PagedBatcher(ContinuousBatcher):
         return KVCache(jnp.zeros(shape, self.cfg.dtype),
                        jnp.zeros(shape, self.cfg.dtype),
                        jnp.zeros((self.slots,), jnp.int32),
-                       init_state(self.cfg, self.slots))
+                       **init_state(self.cfg, self.slots))
 
     def _prefill_into(self, req: _Request, slot: int):
         n = len(req.tokens)

@@ -114,6 +114,25 @@ class TransformerConfig:
     routed_scale: float = 1.0
     shared_expert_hidden: int = 0
     experts_held: Optional[Tuple[int, int]] = None
+    # A pattern of the kinds "kda" and "mla" (models/kimi_linear.py; the
+    # cached forward alone runs it): a leading layer of `lead_kind` with the
+    # dense MLP, whole periods of `layer_kinds`, then the layers `tail_kinds`,
+    # every layer behind the first with sparse experts.
+    # "kda": delta-rule linear attention of `heads` heads of `hd` that keeps
+    # a float32 [hd, hd] matrix a head and the last `kda_conv - 1` inputs of
+    # three depthwise convolutions (`KVCache.mat` / `.conv`); "mla": latent
+    # attention of `heads` heads that keeps `mla_latent + mla_rope_dim`
+    # values a position (`KVCache.latent`), no rotation applied. A pattern of
+    # "window" and "full" leads with "full" and has no tail.
+    lead_kind: str = "full"
+    tail_kinds: Tuple[str, ...] = ()
+    kda_conv: int = 0
+    mla_latent: int = 0
+    mla_rope_dim: int = 0
+    # How a linear router scores: "softmax" (`moe_router`), or "sigmoid" with
+    # a stored selection bias that chooses the k experts and stays out of
+    # their weights (`kimi_linear.router`)
+    router_score: str = "softmax"
 
     def __post_init__(self):
         if self.attention not in ("gqa", "cca"):
@@ -137,16 +156,34 @@ class TransformerConfig:
                     f"num_experts {self.num_experts}")
         pattern = (self.window, self.window_heads, self.dense_mlp_hidden,
                    self.head_gate, self.rope_yarn, self.shared_expert_hidden,
-                   self.experts_held)
+                   self.experts_held, self.tail_kinds, self.kda_conv,
+                   self.mla_latent, self.mla_rope_dim,
+                   self.lead_kind != "full", self.router_score != "softmax")
         if not self.layer_kinds:
             if any(pattern):
                 raise ValueError(
                     "window, window_heads, dense_mlp_hidden, head_gate, "
-                    "rope_yarn, shared_expert_hidden and experts_held belong "
-                    "to a layer pattern (layer_kinds): the one block has none")
+                    "rope_yarn, shared_expert_hidden, experts_held, "
+                    "lead_kind, tail_kinds, kda_conv, mla_latent, "
+                    "mla_rope_dim and router_score belong to a layer pattern "
+                    "(layer_kinds): the one block has none")
             return
-        if set(self.layer_kinds) - {"window", "full"}:
-            raise ValueError(f"unknown layer kinds {self.layer_kinds!r}")
+        kinds = {self.lead_kind, *self.layer_kinds, *self.tail_kinds}
+        if kinds <= {"kda", "mla"}:
+            self._check_linear_pattern()
+            return
+        if kinds - {"window", "full"}:
+            raise ValueError(
+                f"unknown layer kinds {sorted(kinds)!r}: a pattern is of "
+                "'window' and 'full' layers (models/laguna.py) or of 'kda' "
+                "and 'mla' layers (models/kimi_linear.py), not of both")
+        if self.lead_kind != "full" or self.tail_kinds or self.kda_conv \
+                or self.mla_latent or self.mla_rope_dim \
+                or self.router_score != "softmax":
+            raise ValueError(
+                "a pattern of window and full layers leads with a full "
+                "layer, ends on a whole period, and has no kda_conv, "
+                "mla_latent, mla_rope_dim or sigmoid router")
         if (self.layers - 1) % len(self.layer_kinds) or self.layers < 2:
             raise ValueError(
                 f"layers {self.layers} is not one leading layer and whole "
@@ -167,35 +204,110 @@ class TransformerConfig:
             raise ValueError("rope_yarn is (factor, original positions, "
                              "beta_fast, beta_slow, attention_factor)")
 
+    def _check_linear_pattern(self):
+        """A pattern of "kda" and "mla" layers: what it needs, and what of
+        the other blocks' options it refuses, each by name."""
+        body = self.layers - 1 - len(self.tail_kinds)
+        if body < 0 or body % len(self.layer_kinds):
+            raise ValueError(
+                f"layers {self.layers} is not one leading {self.lead_kind!r} "
+                f"layer, whole periods of {self.layer_kinds!r} and the "
+                f"trailing layers {self.tail_kinds!r}")
+        if not (self.kda_conv >= 2 and self.mla_latent and self.mla_rope_dim
+                and self.num_experts and self.dense_mlp_hidden):
+            raise ValueError("a pattern of kda and mla layers needs kda_conv "
+                             "(taps, >= 2), mla_latent, mla_rope_dim, "
+                             "num_experts and dense_mlp_hidden")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score {self.router_score!r}")
+        refused = dict(
+            window=self.window, window_heads=self.window_heads,
+            head_gate=self.head_gate, rope_yarn=self.rope_yarn,
+            qk_norm=self.qk_norm, lora_rank=self.lora_rank,
+            tie_embeddings=self.tie_embeddings,
+            attention=self.attention != "gqa", router=self.router != "linear",
+            partial_rotary=self.partial_rotary != 1.0,
+            kv_heads=self.kv_heads != self.heads)
+        if any(refused.values()):
+            raise ValueError(
+                "a pattern of kda and mla layers has no "
+                f"{', '.join(n for n, v in refused.items() if v)}: its heads "
+                "are all alike, nothing rotates, and its router is linear")
+
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden // self.heads
 
+    def pattern_module(self):
+        """The module that runs this configuration's layer pattern
+        (parameters stacked by kind, `forward_cached`), imported only where
+        a configuration has one."""
+        if set(self.kinds) & {"kda", "mla"}:
+            from ray_tpu.models import kimi_linear
+
+            return kimi_linear
+        from ray_tpu.models import laguna
+
+        return laguna
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """Every layer's kind, in order; () without a pattern."""
+        if not self.layer_kinds:
+            return ()
+        return (self.lead_kind, *self.layer_kinds * self.periods,
+                *self.tail_kinds)
+
+    def layers_of(self, kind: str) -> int:
+        """How many of a pattern's layers are of `kind`."""
+        return self.kinds.count(kind)
+
+    @property
+    def latent_row(self) -> int:
+        """Width of a cached latent row (`KVCache.latent`): its `mla_latent
+        + mla_rope_dim` values in whole 128-lane words, the rest zeros. The
+        chip's tiling keeps a 576-value bfloat16 row in 640 lanes whatever
+        its logical width; stated so, a copy takes whole rows."""
+        return -(-(self.mla_latent + self.mla_rope_dim) // 128) * 128
+
+    @property
+    def keeps(self) -> Tuple[str, ...]:
+        """The per-slot fields of `decoding.KVCache` that this
+        configuration's layers keep for a sequence: rows appended a position
+        at a time ("k", "v", "ring_k", "ring_v", "latent") and states read
+        and rewritten every step ("state", "mat", "conv")."""
+        kinds = set(self.kinds)
+        if kinds & {"kda", "mla"}:
+            return (("mat", "conv") if "kda" in kinds else ()) + (
+                ("latent",) if "mla" in kinds else ())
+        return ("k", "v") + (("state",) if self.attention == "cca" else ()) \
+            + (("ring_k", "ring_v") if "window" in kinds else ())
+
     @property
     def stateful(self) -> bool:
-        """Whether a sequence keeps more than K/V rows between steps
-        (`KVCache.state`)."""
-        return self.attention == "cca"
+        """Whether a sequence keeps more than rows between steps: something
+        its layers read and rewrite every step (`KVCache.STATES`)."""
+        return bool(set(self.keeps) & {"state", "mat", "conv"})
 
     @property
     def periods(self) -> int:
-        """Periods of `layer_kinds` behind the leading layer (0: no pattern)."""
-        return (self.layers - 1) // len(self.layer_kinds) \
-            if self.layer_kinds else 0
+        """Whole periods of `layer_kinds` behind the leading layer (0: no
+        pattern)."""
+        return (self.layers - 1 - len(self.tail_kinds)) \
+            // len(self.layer_kinds) if self.layer_kinds else 0
 
     @property
     def full_layers(self) -> int:
         """Layers whose K/V rows are slots of `max_len` (`KVCache.k`): all
-        of them without a pattern; with one, the leading layer and each
-        period's "full" ones."""
+        of them without a pattern; with one, its "full" ones."""
         if not self.layer_kinds:
             return self.layers
-        return 1 + self.periods * self.layer_kinds.count("full")
+        return self.layers_of("full")
 
     @property
     def window_layers(self) -> int:
         """Layers whose K/V rows are a ring of `window` (`KVCache.ring_k`)."""
-        return self.periods * self.layer_kinds.count("window")
+        return self.layers_of("window")
 
     @property
     def sparse_layers(self) -> int:
@@ -217,9 +329,7 @@ class TransformerConfig:
         h, m, l, v = self.hidden, self.mlp_hidden, self.layers, self.vocab_size
         hd, nh, nkv = self.hd, self.heads, self.kv_heads
         if self.layer_kinds:
-            from ray_tpu.models import laguna
-
-            return laguna.num_params(self)
+            return self.pattern_module().num_params(self)
         mlp = 3 * h * m
         if self.num_experts:
             mlp = self.num_experts * 3 * h * m + h * self.num_experts  # + router
@@ -310,6 +420,21 @@ PRESETS: Dict[str, TransformerConfig] = {
         dense_mlp_hidden=192, head_gate=True, routed_scale=2.5,
         shared_expert_hidden=64, experts_held=(0, 8), dtype=jnp.float32,
     ),
+    # moonshotai/Kimi-Linear-48B-A3B-Instruct's block at debug widths
+    # (models/kimi_linear.py): a leading kda layer with a dense MLP, two
+    # periods of (kda, kda, mla, kda) and the trailing (kda, mla), 4 heads of
+    # 16, a latent of 32 + 8, sigmoid top-4 of 16 experts times 2.446 of
+    # which 8 are held, a shared expert. The published widths are the
+    # benchmark's to build (benchmarks/runners/serve_kimi_linear.py)
+    "kimi_linear_debug": TransformerConfig(
+        vocab_size=512, hidden=128, mlp_hidden=64, layers=11, heads=4,
+        kv_heads=4, head_dim=16, max_seq=128, remat=False, norm_eps=1e-5,
+        num_experts=16, experts_per_token=4, norm_topk_prob=True,
+        layer_kinds=("kda", "kda", "mla", "kda"), lead_kind="kda",
+        tail_kinds=("kda", "mla"), kda_conv=4, mla_latent=32, mla_rope_dim=8,
+        router_score="sigmoid", dense_mlp_hidden=192, routed_scale=2.446,
+        shared_expert_hidden=64, experts_held=(0, 8), dtype=jnp.float32,
+    ),
 }
 
 
@@ -332,9 +457,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     while-loop body compiled once, not ``layers`` inlined copies (compile
     time and HBM win on TPU)."""
     if cfg.layer_kinds:  # stacked by kind, never held twice
-        from ray_tpu.models import laguna
-
-        return laguna.init_params(cfg, key)
+        return cfg.pattern_module().init_params(cfg, key)
     h, m, v, l = cfg.hidden, cfg.mlp_hidden, cfg.vocab_size, cfg.layers
     hd, nh, nkv = cfg.hd, cfg.heads, cfg.kv_heads
     pd = cfg.param_dtype
@@ -395,9 +518,7 @@ def param_axes(cfg: TransformerConfig) -> Params:
     """Pytree of logical-axis tuples mirroring init_params output.
     Feed to parallel.sharding.tree_shardings(mesh, ...) for NamedShardings."""
     if cfg.layer_kinds:
-        from ray_tpu.models import laguna
-
-        return laguna.param_axes(cfg)
+        return cfg.pattern_module().param_axes(cfg)
     block_axes: Params = {
         "wq": ("layers", "embed", "heads", "head_dim"),
         "wk": ("layers", "embed", "kv_heads", "head_dim"),
@@ -693,8 +814,8 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
     """
     if cfg.layer_kinds:
         raise ValueError(
-            f"a layer pattern {cfg.layer_kinds!r} (window layers beside full "
-            "ones, parameters stacked by kind) runs in the cached forward "
+            f"a layer pattern {cfg.layer_kinds!r} (layers of several kinds, "
+            "parameters stacked by kind) runs in the cached forward "
             "alone (decoding.forward_cached): the training forward scans one "
             "kind of block")
     if cfg.stateful or cfg.router != "linear":

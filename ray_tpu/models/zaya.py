@@ -64,7 +64,8 @@ def init_state(cfg: TransformerConfig, batch: int, dtype):
     if cfg.kv_heads % 2:
         raise ValueError("attention 'cca' shifts half of the KV heads: "
                          f"kv_heads {cfg.kv_heads} is odd")
-    return jnp.zeros((cfg.layers, batch, state_heads(cfg), cfg.hd), dtype)
+    return {"state": jnp.zeros(
+        (cfg.layers, batch, state_heads(cfg), cfg.hd), dtype)}
 
 
 def extra_params(cfg: TransformerConfig) -> int:

@@ -115,8 +115,31 @@ PROGRAM_SCOPES = {
                  "it), the gate, wo",
     "attn.window": "models/laguna.py: the same of a window layer, over its "
                    "ring",
-    "moe.shared": "models/laguna.py: the shared expert's SwiGLU",
-    "moe_router": "models/transformer.py: the linear router and its top-k",
+    "kda.project": "models/kimi_linear.py: a linear-attention layer's q, k "
+                   "and v projections",
+    "kda.conv": "models/kimi_linear.py: the three depthwise convolutions, "
+                "their windows' read and write, SiLU, the L2 norms",
+    "kda.gate": "models/kimi_linear.py: the low-rank decay gate, the step "
+                "beta, the low-rank output gate",
+    "kda.state": "models/kimi_linear.py: a decode step's state update: "
+                 "every sequence's matrix state decayed, corrected by one "
+                 "rank-one term and read out (read once, written once)",
+    "kda.prefill_scan": "models/kimi_linear.py: a prefill's recurrence, a "
+                        "chunk of positions at a time",
+    "kda.out": "models/kimi_linear.py: the head norm, the output gate, wo",
+    "mla.project": "models/kimi_linear.py: a latent-attention layer's query "
+                   "and latent projections, the latent's norm, the row "
+                   "write; in a decode step the key expansion absorbed into "
+                   "the query",
+    "mla.attend": "models/kimi_linear.py: attention: a decode step's over "
+                  "the held latent rows (absorbed), a prefill's over keys "
+                  "and values expanded from its own fresh rows",
+    "mla.out": "models/kimi_linear.py: a decode step's value expansion; wo",
+    "moe.shared": "models/laguna.py, models/kimi_linear.py: the shared "
+                  "expert's SwiGLU",
+    "moe_router": "models/transformer.py: the linear router and its top-k "
+                  "(softmax); models/kimi_linear.py: the sigmoid router with "
+                  "its selection bias",
     "moe_experts": "models/transformer.py: sort, grouped matmuls, unsort",
     "attend_cached": "models/decoding.py: attention over the cached rows",
     "mlp": "the dense SwiGLU MLP",

@@ -619,6 +619,144 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
     return out[:, :, :rep].reshape(b, h, d)
 
 
+def latent_decode_attention_takes(stack, value_dim: int) -> bool:
+    """Whether `latent_decode_attention` runs on a stack [N, B, T, width] of
+    this shape and dtype, here: on a TPU, 2-byte rows whose value part is
+    whole lanes, slots of whole tiles of rows."""
+    _, _, t, width = stack.shape
+    return (_on_tpu() and stack.dtype.itemsize == 2 and t % 16 == 0
+            and value_dim % 128 == 0 and value_dim <= width)
+
+
+def _latent_decode_kernel(layer_ref, rows_ref, q_ref, lat_hbm, o_ref, buf,
+                          sem, *, block, value_dim, sm_scale):
+    """q_ref [B, R, width] / o_ref [B, R, value_dim] in VMEM; lat_hbm the
+    stack [N, B, T, width] where XLA keeps it; buf [2, block, width]. ONE
+    invocation walks the slots and each slot's `ceil(rows / block)` blocks,
+    as `_decode_attention_kernel` does, the next block's copy in flight
+    while this one is attended to; a block is read ONCE and serves the
+    logits (all `width` of a row) and the values (its first `value_dim`)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_slots, r, _ = q_ref.shape
+    layer = layer_ref[0]
+
+    def copy(b, j, at_buf):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        return pltpu.make_async_copy(lat_hbm.at[layer, b, at],
+                                     buf.at[at_buf], sem.at[at_buf])
+
+    def holds_rows_from(b):
+        return lax.while_loop(
+            lambda i: (i < n_slots)
+            & (rows_ref[jnp.minimum(i, n_slots - 1)] == 0),
+            lambda i: i + 1, b)
+
+    first = holds_rows_from(0)
+
+    @pl.when(first < n_slots)
+    def _():
+        copy(first, 0, 0).start()
+
+    def slot(b, at_buf):
+        rows = rows_ref[b]
+        n_blocks = pl.cdiv(rows, block)
+        after = holds_rows_from(b + 1)
+
+        def attend(j, carry):
+            at_buf, (m, l, acc) = carry
+            more = j + 1 < n_blocks
+
+            @pl.when(more)
+            def _():
+                copy(b, j + 1, 1 - at_buf).start()
+
+            @pl.when(jnp.logical_not(more) & (after < n_slots))
+            def _():
+                copy(after, 0, 1 - at_buf).start()
+
+            copy(b, j, at_buf).wait()
+            # past the slot's rows a block holds someone else's or stale
+            # rows: their logits are masked and their values zeros
+            held = (j * block + lax.broadcasted_iota(
+                jnp.int32, (r, block), 1)) < rows
+            v_held = (j * block + lax.broadcasted_iota(
+                jnp.int32, (block, value_dim), 0)) < rows
+            k = buf[at_buf]
+            s = lax.dot_general(
+                q_ref[b], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(held, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + p.sum(axis=-1, keepdims=True)
+            v = jnp.where(v_held, k[:, :value_dim], 0)
+            acc = acc * alpha + jnp.dot(p.astype(v.dtype), v,
+                                        preferred_element_type=jnp.float32)
+            return 1 - at_buf, (m_new, l, acc)
+
+        empty = (jnp.full((r, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((r, 1), jnp.float32),
+                 jnp.zeros((r, value_dim), jnp.float32))
+        at_buf, (_, l, acc) = lax.fori_loop(0, n_blocks, attend,
+                                            (at_buf, empty))
+        o_ref[b] = acc / jnp.maximum(l, 1e-30)  # no row held: zeros
+        return at_buf
+
+    lax.fori_loop(0, n_slots, slot, 0)
+
+
+def latent_decode_attention(q, stack, layer, rows, value_dim: int,
+                            sm_scale: float, block: Optional[int] = None):
+    """One new token a sequence against its cached LATENT rows (a decode
+    step of latent attention with the key and value expansions absorbed
+    into q and the output): q [B, H, width], `stack` [N, B, T, width], a
+    row's first `value_dim` its value as well, `layer` (int32 scalar) the
+    layer to read, `rows` (int32 [B]) how many of its T rows each slot
+    holds, a prefix. Returns float32 [B, H, value_dim]: softmax(q row^T
+    sm_scale) row[:value_dim] over rows 0 .. rows[b] - 1, every head over
+    the same rows; zeros where rows[b] == 0.
+
+    `decode_attention`'s sibling: the stack stays in HBM, slot b's
+    `ceil(rows[b] / block)` blocks are copied from [layer, b, block], each
+    ONCE, and nothing else is read; operands in the cache's dtype, sums and
+    the running maximum, sum and accumulator float32."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, width = q.shape
+    t = stack.shape[2]
+    block = block or decode_block(t, width * stack.dtype.itemsize)
+    tile = 8 * (4 // stack.dtype.itemsize)
+    r = -(-h // tile) * tile
+    q3 = q.astype(stack.dtype)
+    if r != h:
+        q3 = jnp.pad(q3, ((0, 0), (0, r - h), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, block=block,
+                          value_dim=value_dim, sm_scale=sm_scale),
+        name="latent_decode_attention",
+        out_shape=jax.ShapeDtypeStruct((b, r, value_dim), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, block, width), stack.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.clip(rows.astype(jnp.int32), 0, t), q3, stack)
+    return out[:, :h]
+
+
 def gqa_expand(k, v, num_q_heads: int):
     """Expand grouped KV heads to match q heads (GQA → MHA view).
 

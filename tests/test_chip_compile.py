@@ -168,6 +168,22 @@ SERVE_CONFIGS = {
 }
 
 
+# Kimi-Linear-48B-A3B-Instruct's published widths AND depth (27 layers), one
+# chip's share of a layer that sixteen hold: 16 of 256 experts, an eighth of
+# the vocabulary; 32 slots of 4096 positions, the 2048 bucket. Not among
+# SERVE_CONFIGS: its cache has no K/V rows for the tests that walk those
+KIMI_LINEAR = "kimi-linear-48b-a3b-serve-ep16"
+PATTERN_CONFIGS = {
+    KIMI_LINEAR: dict(
+        name="kimi_linear_debug", vocab_size=20480, hidden=2304,
+        mlp_hidden=1024, layers=27, heads=32, kv_heads=32, head_dim=128,
+        max_seq=1048576, num_experts=256, experts_per_token=8,
+        experts_held=(0, 16), mla_latent=512, mla_rope_dim=64,
+        dense_mlp_hidden=9216, shared_expert_hidden=1024,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, slots=32, max_len=4096),
+}
+
+
 @pytest.fixture(scope="module")
 def serve_programs(topo):
     """configuration name -> (cfg, prefill[bucket], decode[slots x SEQ],
@@ -184,7 +200,7 @@ def serve_programs(topo):
 
     @functools.cache
     def compiled(name):
-        widths = dict(SERVE_CONFIGS[name])
+        widths = dict({**SERVE_CONFIGS, **PATTERN_CONFIGS}[name])
         slots = widths.pop("slots", SERVE_SLOTS)
         bucket = widths.pop("bucket", SEQ)
         max_len = widths.pop("max_len", SEQ)
@@ -493,6 +509,76 @@ def _results(text):
     for name, dtype, dims, op in re.findall(
             r"%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", text):
         yield name, dtype, [int(d) for d in dims.split(",") if d], op
+
+
+def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
+    """The `serve-kda-mla-rollout-long-out` deployment (Kimi-Linear at its
+    published widths and depth, 16 of 256 experts, 32 slots x 4096): the
+    decode step is given the matrix states, the convolution windows and the
+    latent rows to keep (all aliased in to out), holds no temporary of the
+    state stack's or of a latent layer's size, reads the latent rows with
+    the Mosaic call that is given the stack, under `mla.attend`; the prefill
+    of the 2048 bucket runs the recurrence in chunks; both fit the chip
+    beside each other's arguments, and the scopes reach the compiled text."""
+    from benchmarks import harness, scope_ops
+
+    cfg, prefill, decode, cache = serve_programs(KIMI_LINEAR)
+    slots = cache.lengths.shape[0]
+    assert cfg.kinds.count("kda") == 20 and cfg.kinds.count("mla") == 7
+    assert cfg.kinds[0] == "kda" and cfg.kinds[-2:] == ("kda", "mla")
+    assert cache.k.shape[0] == 0 and cache.state is None
+    assert cache.mat.shape == (20, slots, 32, 128, 128)
+    assert cache.mat.dtype == jnp.float32
+    assert cache.conv.shape == (20, slots, 3 * 3 * 32 * 128)
+    assert cache.latent.shape == (7, slots, 4096, 640)
+    kept = _arg_bytes((cache.mat, cache.conv, cache.latent))
+    assert round(_arg_bytes(cache.mat) / 1e9, 2) == 1.34
+    assert round(_arg_bytes(cache.latent) / 1e9, 2) == 1.17  # 1.06 of values
+    for name, program in (("prefill[2048]", prefill),
+                          (f"decode[{slots}x4096]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        assert "s32[256]" in program.as_text()  # the load over all experts
+        # no layer's held experts are copied out of their stack
+        assert not re.search(r"bf16\[16,(2304,1024|1024,2304)\]",
+                             program.as_text())
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    # no copy of the state stack (1.34 GB), of a layer of it for every slot
+    # twice over, or of a latent layer (168 MB)
+    assert m.temp_size_in_bytes < _arg_bytes(cache.latent) / 7
+    assert _total_bytes(decode) < 12.5e9
+    assert _total_bytes(prefill) + kept < 15.5e9  # beside the engine's cache
+    text = decode.as_text()
+    latent_layer = math.prod(cache.latent.shape[1:])
+    for op_name, dtype, dims, op in _results(text):
+        assert math.prod(dims) != latent_layer, (op_name, dims, op)
+        assert not (dtype == "f32" and 4096 in dims
+                    and math.prod(dims) >= slots * 32 * 4096), (op_name, dims)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line
+             and "%latent_decode_attention" in line]
+    assert len(calls) == 2  # a period's one latent layer, and the last layer
+    runner = harness.load_module("runners", "serve_kimi_linear")
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) >= set(runner.SCOPES) - {"kda.prefill_scan"}
+    called = {scope_ops._INSTRUCTION.match(line)[1] for line in calls}
+    assert called <= set(scopes["mla.attend"])
+    pscopes = scope_ops.op_scopes(prefill.as_text(), runner.SCOPES)
+    assert "kda.prefill_scan" in pscopes and "kda.state" not in pscopes
+    # a chunk's [heads, 32, 32] system inside the scan over 64 chunks
+    assert re.search(r"f32\[1,32,32,32\]", prefill.as_text())
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    assert _entry_parameters(decode) == leaves + 5 + 6  # k, v, lengths + 3
+    from ray_tpu.observability import schema
+
+    assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
 
 
 @pytest.mark.parametrize("name", sorted(SERVE_CONFIGS))
