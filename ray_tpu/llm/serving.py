@@ -74,9 +74,13 @@ def build_llm_deployment(config: LLMConfig):
             replica's engine runs on, as JAX reports it in THIS process."""
             import jax
 
-            st = getattr(getattr(self.engine, "batcher", None), "stats",
-                         None)
+            batcher = getattr(self.engine, "batcher", None)
+            st = getattr(batcher, "stats", None)
             out = dict(st) if st is not None else {}
+            if getattr(batcher, "moe_grouped_path", None):
+                # per jitted program, what a sparse model's grouped expert
+                # matmuls were traced with: "kernel" or "ragged_dot"
+                out["moe_grouped_path"] = batcher.moe_grouped_path
             devices = jax.devices()
             mem = devices[0].memory_stats() or {}
             out.update(self._compiles)

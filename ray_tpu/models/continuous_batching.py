@@ -80,6 +80,7 @@ from ray_tpu.models.decoding import (
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import schema as spans
 from ray_tpu.observability.tracing import device_span
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.attention import NEG_INF, decode_block
 
 
@@ -197,6 +198,19 @@ class PrefillPrograms:
 
     def _jit_programs(self) -> None:
         self._prefill_jits: Dict[int, Any] = {}
+        # jitted program -> what its grouped expert matmuls were traced
+        # with, "kernel" or "ragged_dot" (`_traced_with`); a dense model's
+        # programs have none and book nothing. `engine_stats()` carries it
+        self.moe_grouped_path: Dict[str, str] = {}
+
+    def _traced_with(self, program: str, paths: set) -> None:
+        """Book, while `program` is being traced, the implementation(s)
+        `ops.grouped_matmul` chose for its grouped matmuls: a program that
+        fell back to `lax.ragged_dot` says so in one look. A new dict, not
+        an update in place: a reader may be copying the old one."""
+        if paths:
+            self.moe_grouped_path = {**self.moe_grouped_path,
+                                     program: "+".join(sorted(paths))}
 
     def _prefill_impl(self, params, tokens, length):
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
@@ -214,8 +228,11 @@ class PrefillPrograms:
         row_cache = init_cache(self.cfg, 1, s)
         positions = jnp.arange(s)[None, :]
         kv_mask = jnp.arange(s)[None, :] < length
-        logits, row_cache, aux = forward_cached(
-            self.cfg, params, tokens, positions, row_cache, kv_mask, kv_mask)
+        with grouped_matmul.paths_traced() as paths:
+            logits, row_cache, aux = forward_cached(
+                self.cfg, params, tokens, positions, row_cache, kv_mask,
+                kv_mask)
+        self._traced_with(f"prefill_{s}", paths)
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
@@ -462,9 +479,11 @@ class ContinuousBatcher(PrefillPrograms):
         # what each slot holds once its token is written: the same prefix as
         # `kv_mask`, stated as a count; a slot that takes no part holds none
         rows = jnp.where(active_mask, cache.lengths + 1, 0)
-        logits, cache, aux = forward_cached(
-            self.cfg, params, toks[:, None], positions, cache, kv_mask,
-            active_mask[:, None], access, rows)
+        with grouped_matmul.paths_traced() as paths:
+            logits, cache, aux = forward_cached(
+                self.cfg, params, toks[:, None], positions, cache, kv_mask,
+                active_mask[:, None], access, rows)
+        self._traced_with("decode", paths)
         with jax.named_scope("sample"):
             nxt = _sample_per_slot(
                 logits[:, 0], rng, temps, topks, active_mask)

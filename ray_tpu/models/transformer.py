@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import flash_attention, gqa_expand
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -640,24 +641,28 @@ def _moe_mlp(cfg: TransformerConfig, y, p):
 
 def _grouped_matmul(rows, weights, group_sizes, layer=None):
     """rows [R,h] in E adjoining groups times weights [E,h,m] -> [R,m],
-    float32 accumulation and result.
+    float32 accumulation and result; `weights` a pair (gate, up) -> the gated
+    unit silu(rows x gate) * (rows x up) in the rows' dtype, from ONE call.
 
     With `layer` (a traced index) `weights` is the whole stack [L,E,h,m] and
-    is read in place: viewed as L*E groups of which only that layer's E hold
-    rows. A scanned layer loop must not slice its layer out first: the
-    grouped matmul is a custom call, so XLA cannot fuse the slice into it
-    and copies the layer's experts (805 MB a layer at OLMoE's widths: 3.6
-    against 1.2 ms a layer in a 16-row decode step, 7.9 against 5.5 ms in a
-    2048-token prefill on the v5e, PERF.md PR 25); empty groups cost
-    nothing measurable."""
-    if layer is not None:
-        n_layers, e = weights.shape[:2]
-        weights = weights.reshape(n_layers * e, *weights.shape[2:])
-        group_sizes = lax.dynamic_update_slice(
-            jnp.zeros((n_layers * e,), group_sizes.dtype), group_sizes,
-            (layer * e,))
-    return lax.ragged_dot(rows, weights, group_sizes,
-                          preferred_element_type=jnp.float32)
+    is read in place, INDEXED by `layer`: a scanned layer loop must not slice
+    its layer out first, because the grouped matmul is a custom call either
+    way, so XLA cannot fuse the slice into it and copies the layer's experts
+    (226-805 MB a layer: 102-2,457 us a call beside the call's own 106-887
+    at the four serve cells' decode shapes on the v5e, PERF.md PR 41).
+
+    What runs where (`ops/grouped_matmul.py`, which decides by the shapes it
+    is given): on a TPU a call of at most one tile of rows a group (a decode
+    step's 32-320 rows, a short prompt's) is a Pallas kernel that keeps the
+    rows in fast memory and streams each reached expert's matrix from
+    `weights[layer, expert]` in contiguous blocks of about 1 MB; `layer`
+    reaches it as a prefetched scalar, so its metadata is a layer's E groups.
+    Everywhere else, and for a long prompt's thousands of rows a group, it is
+    `lax.ragged_dot` over the stack VIEWED as L*E groups of which only that
+    layer's hold rows: the compiler's kernel spends 18-22 us a call on
+    metadata over 416-1,024 groups where 16-128 cost 1-6, which a prefill's
+    5 ms layer does not feel and a decode step's 0.1-0.9 ms call does."""
+    return grouped_matmul(rows, weights, group_sizes, layer)
 
 
 @jax.named_scope("moe_router")
@@ -683,9 +688,11 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
 
     Every token runs its k experts whatever their load (OLMoE, Megablocks):
     the T*k assignments are sorted by expert, their rows gathered, and the
-    gate, up and down projections are three grouped matmuls over the E
-    groups of that sorted order (`lax.ragged_dot`); then the rows go back
-    to token order and are summed with the router's weights. Shapes are
+    gate, up and down projections are grouped matmuls over the E groups of
+    that sorted order, gate and up in one call and down in a second
+    (`_grouped_matmul`: a kernel cut to a decode step's few rows a group on a
+    TPU, `lax.ragged_dot` for a long prompt and off a TPU); then the rows go
+    back to token order and are summed with the router's weights. Shapes are
     static: T*k rows always, only `group_sizes` is data. No [T*k, E, C]
     dispatch tensor exists and nothing is dropped. Differentiable.
 
@@ -724,10 +731,9 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
         order = jnp.argsort(group, stable=True)  # sorted row -> assignment
         group_sizes = jnp.bincount(group, length=groups).astype(jnp.int32)
         rows = x[order // k]  # [T*k, h], one expert's rows adjoin
-        gate, up = (_grouped_matmul(rows, p[name].astype(x.dtype),
-                                    group_sizes, layer)
-                    for name in ("wi_gate", "wi_up"))
-        act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        act = _grouped_matmul(
+            rows, (p["wi_gate"].astype(x.dtype), p["wi_up"].astype(x.dtype)),
+            group_sizes, layer)
         down = _grouped_matmul(act, p["wo_mlp"].astype(x.dtype), group_sizes,
                                layer)
         if held is not None:  # rows of no group: whatever the kernel left

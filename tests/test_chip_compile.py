@@ -220,8 +220,11 @@ def serve_programs(topo):
             _on(one, jax.eval_shape(lambda: jax.random.key(0))),
             arr((slots,), jnp.float32), arr((slots,), jnp.int32),
             arr((slots,), jnp.bool_)).compile()
+        # what the engine's `moe_grouped_path` would say of the two programs
+        grouped_paths[name] = batcher.moe_grouped_path
         return cfg, prefill, decode, cache
 
+    compiled.grouped_paths = grouped_paths = {}
     return compiled
 
 
@@ -627,6 +630,59 @@ def test_decode_reads_the_cache_in_its_stack_with_the_kernel(
     assert called <= {op for s in wanted for op in by_scope[s]}
     for scope in wanted:
         assert called & set(by_scope[scope]), scope
+
+
+# the four sparse serve programs: what one layer's expert stack holds (the
+# held experts x hidden x expert width) and the totals their own tests hold
+SPARSE_PROGRAMS = {
+    "olmoe-1b-7b-serve-d8": (64 * 2048 * 1024, 10e9),
+    "zaya1-8b-serve-d16": (16 * 2048 * 2048, 10e9),
+    "laguna-s-2.1-serve-ep2-d5": (128 * 3072 * 1024, 13.5e9),
+    KIMI_LINEAR: (16 * 2304 * 1024, 12.5e9),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SPARSE_PROGRAMS))
+def test_decode_multiplies_the_experts_with_the_kernel(serve_programs, name):
+    """Every sparse decode program the engine jits: the grouped matmuls are
+    the Mosaic calls of `ops/grouped_matmul.py`, two a sparse layer body
+    (gate and up in one, down in the other), under `moe_experts`, each under
+    a name the benchmark's readers select the expert operations by; NO
+    instruction has a result of one layer's expert stack's size (the stack
+    is indexed where it lies: 134-805 MB a layer copied otherwise); no
+    `lax.ragged_dot` is left in the step; the cache stays aliased and the
+    total under what the configuration's own test holds it to (the kernel's
+    buffers are fast memory, not the program's). The 2,048-token prefill's
+    thousands of rows a group keep `lax.ragged_dot`, and the engine's
+    counter says both."""
+    from benchmarks import moe_cost, scope_ops
+
+    cfg, prefill, decode, cache = serve_programs(name)
+    layer_stack, limit = SPARSE_PROGRAMS[name]
+    text = decode.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "%ragged_dot" in line]
+    # one scanned body, or a pattern's period and trailing layers unrolled
+    bodies = len(cfg.layer_kinds) + len(cfg.tail_kinds) or 1
+    assert len(calls) == 2 * bodies
+    called = {scope_ops._INSTRUCTION.match(line)[1] for line in calls}
+    assert all(moe_cost.EXPERT_OP.search(op) for op in called)
+    assert {op.split(".")[0] for op in called} == {"ragged_dot_gated",
+                                                   "ragged_dot_rows"}
+    by_scope = scope_ops.op_scopes(text, ("moe_experts",))
+    assert called <= set(by_scope["moe_experts"])
+    assert "ragged-dot" not in text
+    for op_name, dtype, dims, op in _results(text):
+        assert math.prod(dims) != layer_stack, (op_name, dims, op)
+    kept = [getattr(cache, f) for f in cache._fields
+            if f != "lengths" and getattr(cache, f) is not None]
+    assert decode.memory_analysis().alias_size_in_bytes >= _arg_bytes(kept)
+    assert _total_bytes(decode) < limit
+    assert "ragged-dot" in prefill.as_text()
+    assert "%ragged_dot" not in prefill.as_text()
+    bucket = 1024 if name.startswith("zaya") else SEQ
+    assert serve_programs.grouped_paths[name] == {
+        "decode": "kernel", f"prefill_{bucket}": "ragged_dot"}
 
 
 def test_attend_cached_reads_the_cache_once(topo):
