@@ -68,11 +68,20 @@ def load_module(directory: str, name: str):
 
 
 def load_reader(metric: str):
-    """The reader of a per-layer metric: ``layer_metrics/<metric>.py``. A
-    name appears once in BENCHMARK.json, with one ``moves``, so what two
-    cells share is entered twice, the second time under a prefix
-    (``doc_slot_occupancy``); a name with no file of its own is read by the
-    file of the name without its first words (``slot_occupancy.py``)."""
+    """The reader of a per-layer metric: ``layer_metrics/<metric>.py``; a
+    name with no file of its own is read by the file of the name without its
+    first words (``tput_slot_occupancy`` by ``slot_occupancy.py``).
+
+    BENCHMARK.json holds ONE entry for each pair (reader file, ``moves``),
+    and the entry's ``workloads`` lists every cell the reader is read in
+    (PR 42; ``tests/test_contract.py`` here refuses a second). A new cell
+    JOINS the lists of the readers it shares, by appending its name, and adds
+    entries only for readers of its own. A prefix is for the one case in
+    which two entries must share a file: a reader that moves another
+    end-to-end metric in other cells. The entry whose cells report the
+    chat or train metric keeps the reader's name (``slot_occupancy`` moves
+    ``itl_p95_ms``), the one that moves ``out_tokens_per_s`` is
+    ``tput_<reader>``. No reader sees its metric's name."""
     words = metric.split("_")
     for i in range(len(words)):
         name = "_".join(words[i:])

@@ -2,7 +2,7 @@
 layers beside latent-attention layers, and a held share of its experts,
 require of the chip in one decode step. The yardstick of
 ``kda_state_roofline`` and ``mla_attention_roofline`` (and, through
-``laguna_cost``, of ``rollout_held_experts_roofline``); a decode step is
+``laguna_cost``, of ``held_experts_roofline`` in this cell); a decode step is
 memory bound at these shapes.
 
 Required work counts the published mathematics only, and only bytes that are

@@ -23,7 +23,8 @@ import time
 
 import numpy as np
 
-from benchmarks import harness, loadgen, program_spans, replica, trace_reduce
+from benchmarks import (harness, loadgen, program_spans, replica,
+                        stream_spans, trace_reduce)
 
 WARMUP_TIMEOUT_S = 540  # the first request of a bucket compiles
 # an end-to-end metric so named is that percentile of the first-token times
@@ -241,6 +242,11 @@ def account(dep: Deployed, traffic: dict, schedule: dict, played: dict,
         "engine": _engine_delta(marks["engine_close"], marks["engine_open"]),
         "proxy": {k: marks["proxy_close"].get(k, 0) - marks["proxy_open"].get(k, 0)
                   for k in ("requests", "ok", "shed", "deadline_exceeded")},
+        # the pump's clocks, both processes' CPU seconds and the front
+        # door's own work over the window; no end-to-end metric reads it
+        "stream_path": stream_spans.window_counters(
+            marks["engine_open"], marks["engine_close"],
+            marks["proxy_open"], marks["proxy_close"], window_s),
         "window_s": window_s, "n_requests": len(records),
     }
 

@@ -8,13 +8,16 @@ replica's process and the process that holds the front door are.
         --seed 11 [--seconds 51]
 
 The cell's own runner deploys, warms and plays the cell's traffic as
-``run.py`` would (``Deployed.measure``); the window's two snapshots of
-``engine_stats`` and ``http_proxy_stats``, which the runner keeps five and
-four keys of, are kept whole here and reduced by ``stream_spans.py``. A traced
+``run.py`` would (``Deployed.measure``); the runner's ``account`` reduces the
+window's two snapshots of ``engine_stats`` and ``http_proxy_stats`` through
+``stream_spans.window_counters`` (``counters["stream_path"]``, PR 42). A traced
 run reads the pump's two from its ``engine.step`` spans instead
-(``layer_metrics/pump_*_ms_per_step.py``), under the profiler; the two
-processes' CPU shares and the front door's own work are read here alone,
-until a runner carries them (PERF.md, section 7). NOTE what the front door's process is in
+(``layer_metrics/pump_*_ms_per_step.py``) and the front door's own work an
+item from the same key (``layer_metrics/proxy_forward_ms_per_item.py``). The
+two processes' CPU shares are read HERE ALONE and have no entry: a traced
+window holds the profiler's start, collection and stop in the replica's
+process between the two snapshots, and read 227.5 % of a core where this tool
+read 127.1 (PERF.md, section 6, PR 42). NOTE what the front door's process is in
 this benchmark: the load generator's clients run in it too. Like run.py's
 Serve driver this process never initialises a JAX backend; one JSON line
 goes to standard output.
@@ -49,18 +52,8 @@ def main() -> int:
     cell = harness.load_cell(args.workload, toy=args.toy)
     runner = harness.load_module("runners", cell["config"]["runner"])
     # the copy of runners/serve.py that this cell's runner runs (its own,
-    # rebound, or serve.py itself), with the window's snapshots kept whole
+    # rebound, or serve.py itself)
     serve = getattr(runner, "serve", runner)
-    account = serve.account
-
-    def account_with_counters(dep, traffic, schedule, played, marks):
-        win = account(dep, traffic, schedule, played, marks)
-        win["stream_path"] = stream_spans.window_counters(
-            marks["engine_open"], marks["engine_close"],
-            marks["proxy_open"], marks["proxy_close"], win["window_s"])
-        return win
-
-    serve.account = account_with_counters
     run_args = {"seed": args.seed, "seconds": args.seconds, "trace": False,
                 "t0_wall": time.time(),
                 "out_dir": os.path.join(ROOT, ".bench_out", "stream_counters")}

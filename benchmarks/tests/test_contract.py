@@ -46,6 +46,13 @@ def test_benchmark_json_meets_the_contract():
     assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.1
     names = [m["name"] for m in b["end_to_end"] + b["per_layer"]]
     assert len(set(names)) == len(names)
+    assert 1 <= len(b["per_layer"]) <= 128
+    # one entry a reader and a moved metric: what cells share is one entry
+    # with the cells in its list, not an entry a cell under a prefix (PR 42)
+    read_by = [(harness.load_reader(m["name"]).__file__, m["moves"])
+               for m in b["per_layer"]]
+    assert len(set(read_by)) == len(read_by), sorted(
+        pair for pair in set(read_by) if read_by.count(pair) > 1)
     for m in b["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
         assert 0.01 <= m["bound"] <= 0.1
@@ -72,7 +79,7 @@ def test_benchmark_json_meets_the_contract():
 
 
 def test_a_twin_metric_is_read_by_the_file_of_its_name_without_the_prefix():
-    assert harness.load_reader("doc_slot_occupancy").__file__.endswith(
+    assert harness.load_reader("tput_slot_occupancy").__file__.endswith(
         os.path.join("layer_metrics", "slot_occupancy.py"))
     assert harness.load_reader("slot_occupancy").__file__.endswith(
         os.path.join("layer_metrics", "slot_occupancy.py"))

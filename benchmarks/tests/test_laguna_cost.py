@@ -135,11 +135,11 @@ def _ctx(toy=False, spans=True, moe=True, scopes=SCOPES):
     ("full_attention_ms_per_decode_step", 2.0),
     ("shared_expert_ms_per_decode_step", 0.2),
     ("moe_router_ms_per_decode_step", 0.1),
-    ("code_moe_expert_ms_per_decode_step", 10.0),
-    ("code_head_sample_ms_per_decode_step", 0.45),
-    ("code_moe_assignments_per_token", 10.0),
+    ("moe_expert_ms_per_decode_step", 10.0),
+    ("head_sample_ms_per_decode_step", 0.45),
+    ("moe_assignments_per_token", 10.0),
     ("moe_held_share", 64500 / 128000),
-    ("code_decode_step_device_ms", 15.0),
+    ("tput_decode_step_device_ms", 15.0),
 ])
 def test_each_reader_on_a_recorded_run(metric, want):
     read = harness.load_reader(metric).read

@@ -1,0 +1,110 @@
+"""The per-layer list since PR 42: one entry for each pair (reader file,
+moved metric), with the cells it is read in. Every entry that took the place
+of several is held to the names it replaced: the same reader file. (No
+reader sees its metric's name, so one file is one value: comparing the two
+names' values could not fail. The span readers that find something in the
+recorded chat trace are held to the values recorded from it instead.) The
+table is also the way back from a new name to the names the ledger's lines
+before PR 42 carry."""
+
+import os
+
+import pytest
+
+from benchmarks import harness
+from benchmarks import program_spans as P
+
+# entry -> the prefixes under which its reader was entered before PR 42
+# ("-" is the reader's own name): `doc_slot_occupancy`, ... -> `tput_slot_occupancy`
+MERGED = {
+    "compile_s": "- moe",
+    "replica_start_s": "- moe",
+    "tput_proxy_refused_share": "doc moe",
+    "tput_decode_steps_per_s": "doc moe reason code",
+    "tput_slot_occupancy": "doc moe reason code rollout",
+    "tput_prefill_device_ms_per_req": "doc moe",
+    "doc_prefill_device_share": "- moe",
+    "tput_device_idle_share": "doc moe reason code rollout",
+    "tput_ttft_p50_ms": "doc moe",
+    "tput_itl_p99_ms": "doc moe",
+    "tput_engine_host_ms_per_step": "doc moe reason code",
+    "tput_engine_admit_ms_per_req": "doc moe",
+    "tput_idle_attributed_share": "doc moe",
+    "tput_stream_yield_ms_per_token": "doc reason code",
+    "tput_decode_step_device_ms": "moe reason code rollout",
+    "moe_expert_ms_per_decode_step": "- reason code rollout",
+    "moe_assignments_per_token": "- reason code rollout",
+    "moe_expert_load_max_over_mean": "- reason",
+    "tput_engine_step_period_ms": "reason code rollout",
+    "head_sample_ms_per_decode_step": "- code",
+    "held_experts_roofline": "- rollout",
+    "tput_pump_cpu_ms_per_step": "reason code",
+    "tput_pump_wait_ms_per_step": "reason code",
+    "tput_stream_detokenize_ms_per_token": "reason code",
+    "tput_stream_rpc_ms_per_token": "reason code",
+    "tput_idle_stream_work_share": "reason code",
+    "tput_stream_items_per_call": "reason code",
+}
+# what the merged span readers read on SAMPLE (the chat cell's recorded chip
+# trace, reduced on the CPU: arithmetic over its spans, no new reading); the
+# other merged span readers find no span of theirs in it and give None
+RECORDED = {
+    "tput_engine_host_ms_per_step": 6.2952595,
+    "tput_idle_attributed_share": 98.03556279819415,
+    "tput_stream_yield_ms_per_token": 4.929532090909091,
+    "tput_engine_step_period_ms": 79.606649,
+}
+SAMPLE = os.path.join(os.path.dirname(__file__), "data",
+                      "serve_chat_spans_sample.json.gz")
+
+
+def replaced(entry: str) -> list:
+    """The names ``entry`` took the place of."""
+    reader = os.path.basename(harness.load_reader(entry).__file__)[:-3]
+    return [reader if p == "-" else f"{p}_{reader}"
+            for p in MERGED[entry].split()]
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """A traced chat run as its runner leaves it to the readers, spans only."""
+    return {"cell": {}, "counters": {}, "device": {},
+            "trace": {"program_spans": P.load_sample(SAMPLE)}}
+
+
+@pytest.mark.parametrize("entry", MERGED)
+def test_a_merged_entry_is_read_by_the_file_of_every_name_it_replaced(entry):
+    per_layer = {m["name"]: m for m in harness.load_benchmark()["per_layer"]}
+    olds = replaced(entry)
+    assert len(olds) >= 2 and len(set(olds)) == len(olds)
+    assert all(old == entry or old not in per_layer for old in olds)
+    reader = harness.load_reader(entry)
+    for old in olds:
+        assert harness.load_reader(old).__file__ == reader.__file__
+    # as many cells as names: the rollout cell joined some lists besides
+    assert len(per_layer[entry]["workloads"]) >= len(olds)
+
+
+@pytest.mark.parametrize("entry", [
+    m["name"] for m in harness.load_benchmark()["per_layer"]
+    if m["name"] in MERGED and m["source"] == "program_span"])
+def test_a_merged_span_reader_reads_the_recorded_value(entry, recorded):
+    value = harness.load_reader(entry).read(recorded)
+    if entry in RECORDED:
+        assert value == pytest.approx(RECORDED[entry], rel=1e-9)
+    else:
+        assert value is None
+
+
+def test_the_table_is_every_entry_that_several_cells_share():
+    shared = {m["name"] for m in harness.load_benchmark()["per_layer"]
+              if m["name"].startswith("tput_")}
+    # new in PR 42 and nothing replaced; the two CPU shares that
+    # `counters["stream_path"]` also holds have no entry: read in a traced
+    # window, they hold the profiler's CPU (PERF.md, section 6, PR 42)
+    assert shared - set(MERGED) == {"tput_proxy_forward_ms_per_item"}
+    assert shared and all(m["moves"] == "out_tokens_per_s"
+                          for m in harness.load_benchmark()["per_layer"]
+                          if m["name"] in shared)
+    with pytest.raises(FileNotFoundError):  # retired in PR 42, reader and all
+        harness.load_reader("stream_hop_gap_ms")

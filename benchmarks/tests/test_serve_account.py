@@ -11,7 +11,8 @@ from benchmarks.runners import serve
 N_NEW = 3
 
 
-def _window(answers, repeats, repeat_every=2):
+def _window(answers, repeats, repeat_every=2, stats_close=None,
+            proxy_close=None):
     """A played open-loop window of len(answers) requests, each answered
     with the given token ids (None: a 503)."""
     dep = types.SimpleNamespace(tok=replica.IdTokenizer(), n_new=N_NEW,
@@ -29,8 +30,12 @@ def _window(answers, repeats, repeat_every=2):
               "late_s": [0.0]}
     stats = {k: 0 for k in ("admitted", "finished", "failed", "steps",
                             "tokens_out")}
-    marks = {"open_wall": 0.0, "engine_open": stats, "engine_close": stats,
-             "proxy_open": {}, "proxy_close": {}}
+    marks = {"open_wall": 0.0, "engine_open": stats,
+             "engine_close": dict(stats, **(stats_close or {})),
+             "proxy_open": {}, "proxy_close": proxy_close or {}}
+    if stats_close:  # a program that keeps the stream path's counters
+        marks["engine_open"] = dict(stats, **dict.fromkeys(stats_close, 0.0))
+        marks["proxy_open"] = dict.fromkeys(proxy_close, 0)
     traffic = {"loop": "open", "repeat_every": repeat_every}
     schedule = {"n_ramp": 0, "repeats": repeats, "prompts": answers}
     return serve.account(dep, traffic, schedule, played, marks)
@@ -58,6 +63,22 @@ def test_a_clean_window():
 def test_what_makes_a_window_incorrect(answers, repeats, problem):
     win = _window(answers, repeats)
     assert any(problem in p for p in win["problems"]), win["problems"]
+
+
+def test_the_window_carries_the_stream_paths_counters_where_the_program_keeps_them():
+    answers = [[1, 2, 3], [4, 5, 6], [1, 2, 3]]
+    assert _window(answers, [0, 2])["stream_path"] is None  # an older program
+    win = _window(answers, [0, 2], stats_close={
+        "steps": 40, "pump_step_s": 18.0, "pump_sync_s": 9.0,
+        "pump_cpu_s": 3.0, "process_cpu_s": 24.0}, proxy_close={
+        "process_cpu_s": 26.0, "stream_items": 9, "stream_forward_s": 0.0018})
+    c = win["stream_path"]
+    assert c["window_s"] == win["window_s"] == 20.0 and c["steps"] == 40
+    assert (c["replica_cpu_s"], c["frontdoor_cpu_s"], c["stream_items"]) == (
+        24.0, 26.0, 9)
+    # nothing that decides an end-to-end metric reads the key
+    assert win["out_tokens_per_s"] == pytest.approx(9 / 20.0)
+    assert win["problems"] == [] and win["failed"] == 0
 
 
 def test_a_mix_without_repeats_is_not_asked_for_them():
