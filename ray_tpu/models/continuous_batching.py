@@ -347,7 +347,7 @@ class ContinuousBatcher(PrefillPrograms):
             # active slots) and how many such rows there were, so that
             # assignments / (rows x layers) reads experts_per_token exactly
             # unless an assignment was dropped
-            self.stats.update(moe_expert_load=[0] * cfg.num_experts,
+            self.stats.update(moe_expert_load=[0] * cfg.router_outputs,
                               moe_assignments=0, moe_rows=0)
         if cfg.experts_held:
             # of those assignments, the ones to experts held here (the rest
@@ -355,6 +355,18 @@ class ContinuousBatcher(PrefillPrograms):
             # had at least one real row, summed over layers and decode steps:
             # what the steps' grouped matmuls had to read
             self.stats.update(moe_assignments_held=0, moe_experts_reached=0)
+        if cfg.zero_experts:
+            # of the real rows' choices, those on zero-compute outputs (their
+            # weight times the input, no matmul) and on routed experts that
+            # are not held here; the rows the programs GATHERED for their
+            # grouped matmuls (`transformer.rows_gathered`: a static number
+            # a call, pad rows' and free slots' choices among them), against
+            # which the held assignments are the rows that met a weight;
+            # and, summed over
+            # the programs, the most routed experts one real row chose in
+            # one layer (`aux["routed_most"]`)
+            self.stats.update(moe_assignments_zero=0, moe_assignments_absent=0,
+                              moe_rows_gathered=0, moe_routed_most=0)
         self._thread = threading.Thread(
             target=self._pump, daemon=True, name="cb-pump")
         self._thread.start()
@@ -574,7 +586,7 @@ class ContinuousBatcher(PrefillPrograms):
             held += cfg.window_layers * int(ring.sum())
             read += cfg.window_layers * in_blocks(ring, cfg.window, kv_bytes)
         if "latent" in cfg.keeps:
-            latent = cfg.layers_of("mla")
+            latent = cfg.latent_layers  # by sublayer: two a double layer
             held += latent * int(lens.sum())
             read += latent * in_blocks(lens, self.max_len,
                                        cfg.latent_row * itemsize)
@@ -713,6 +725,14 @@ class ContinuousBatcher(PrefillPrograms):
                 load[first:first + count].sum())
             if step:
                 self.stats["moe_experts_reached"] += int(np.asarray(loads[2]))
+        if self.cfg.zero_experts:
+            first, count = self.cfg.experts_held or (0, self.cfg.num_experts)
+            on_zero = int(load[self.cfg.num_experts:].sum())
+            self.stats["moe_assignments_zero"] += on_zero
+            self.stats["moe_assignments_absent"] += int(
+                load.sum() - on_zero - load[first:first + count].sum())
+            self.stats["moe_routed_most"] += int(np.asarray(loads[3]))
+            self.stats["moe_rows_gathered"] += int(np.asarray(loads[4]))
 
     def _log_routes(self, counted: list, reqs: Dict[int, _Request]) -> None:
         """Keep a program's `expert_choice` (`counted[1]`, behind the load)

@@ -72,10 +72,13 @@ class KVCache(NamedTuple):
     # [kda layers, B, heads, D, D] float32: a linear-attention head's matrix
     # state, keys x values; `conv` [kda layers, B, (taps - 1) * 3 * heads * D]:
     # the last inputs of its three convolutions; both read and rewritten
-    # every step, as `state`. `latent` [mla layers, B, max_len, latent_row]:
-    # a latent-attention layer's one row a position (latent + rope values,
-    # zeros up to whole lanes: `cfg.latent_row`), appended as `k` / `v` are
-    # but with no heads. `k` / `v` then have no layer at all.
+    # every step, as `state`. `latent` [latent layers, B, max_len,
+    # latent_row]: a latent-attention SUBLAYER's one row a position (the
+    # normed, scaled latent, then the shared key part, rotated where the
+    # configuration rotates it; zeros up to whole lanes: `cfg.latent_row`),
+    # appended as `k` / `v` are but with no heads. One layer an "mla" layer,
+    # two a "scmoe" double layer (models/longcat.py), in the layers' order:
+    # `cfg.latent_layers`. `k` / `v` then have no layer at all.
     mat: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None
@@ -109,7 +112,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         "k": (cfg.full_layers, batch, max_len, cfg.kv_heads, cfg.hd),
         "ring_k": (cfg.window_layers, batch, cfg.window, cfg.kv_heads,
                    cfg.hd),
-        "latent": (cfg.layers_of("mla"), batch, max_len, cfg.latent_row)}
+        "latent": (cfg.latent_layers, batch, max_len, cfg.latent_row)}
     shapes.update(v=shapes["k"], ring_v=shapes["ring_k"])
     rows = {n: jnp.zeros(shapes[n], dtype) for n in cfg.keeps if n in ROWS}
     for name in ("k", "v"):  # a pattern without full layers: no layer of rows

@@ -30,15 +30,22 @@ FROM POSITION 0 (every engine's) and runs the recurrence a CHUNK at a time
 sequence's TRUE last position (`row_mask`): a pad position has beta 0 and
 decay 1.
 
-**An "mla" layer** (`heads` heads; no rotation is applied: the `mla_rope_dim`
-"rope" dimensions are one key part shared by all heads): `[q_n ; q_r]_i = y
-Wq_i`; `[c~ ; k_r] = y Wkva`, `c = RMSNorm(c~)`; a sequence keeps `[c ; k_r]`
-a position (`KVCache.latent`, in whole lanes: `cfg.latent_row`). Two programs for one mathematics: a prefill
-EXPANDS `[k_n ; v]_i = c Wkvb_i` and attends over heads of `hd +
-mla_rope_dim`; a decode step ABSORBS, `q'_i = Wkvb_i[:, :hd] q_n_i`, scores
-`(q'_i . c_j + q_r_i . k_r_j) / sqrt(hd + mla_rope_dim)` against the held
-rows, `u_i = sum_j p_j c_j`, `o_i = u_i Wkvb_i[:, hd:]`: each held row read
-once, where it lies (`ops.attention.latent_decode_attention` on a TPU).
+**An "mla" layer** (`heads` heads; here no rotation is applied: the
+`mla_rope_dim` "rope" dimensions are one key part shared by all heads): `[q_n
+; q_r]_i = y Wq_i`; `[c~ ; k_r] = y Wkva`, `c = RMSNorm(c~)`; a sequence keeps
+`[c ; k_r]` a position (`KVCache.latent`, in whole lanes: `cfg.latent_row`).
+Two programs for one mathematics: a prefill EXPANDS `[k_n ; v]_i = c Wkvb_i`
+and attends over heads of `hd + mla_rope_dim`; a decode step ABSORBS, `q'_i =
+Wkvb_i[:, :hd] q_n_i`, scores `(q'_i . c_j + q_r_i . k_r_j) / sqrt(hd +
+mla_rope_dim)` against the held rows, `u_i = sum_j p_j c_j`, `o_i = u_i
+Wkvb_i[:, hd:]`: each held row read once, where it lies
+(`ops.attention.latent_decode_attention` on a TPU). `mla_attention` also runs
+the sublayer as DeepSeek-V3's family publishes it, by three fields that are
+off in this model (`models/longcat.py` sets them): a low-rank query
+(`mla_q_rank`), `q_r` and the shared `k_r` rotated by position
+(`mla_rotate`), two factors (`mla_scales`); and a prefill whose float32
+logits [heads, S, S] would pass `PREFILL_LOGITS_MAX` attends a block of
+queries at a time.
 
 **Experts.** `router`: sigmoid scores over all `num_experts` in float32, the
 top k of score + a stored bias, the weights the scores alone, renormalised,
@@ -65,6 +72,11 @@ from ray_tpu.ops import delta_rule
 from ray_tpu.ops.attention import NEG_INF
 
 KDA_CHUNK = 32  # positions a chunk of the prefill's scan (`kda_chunks`)
+# A latent prefill's float32 logits [heads, S, S] up to this many bytes are
+# one array; past it the queries go `PREFILL_QUERY_BLOCK` at a time
+# (`_attend_expanded`). 32 heads x 2,048^2 are 0.5 GiB, 64 x 4,096^2 are 4.
+PREFILL_LOGITS_MAX = 1 << 30
+PREFILL_QUERY_BLOCK = 512
 F32 = jnp.float32
 HI = lax.Precision.HIGHEST
 
@@ -75,8 +87,7 @@ def leaves(cfg: TransformerConfig) -> dict:
     """{(group, ..., name): (shape, init, logical axes)} of every parameter
     leaf. `init` is a fan-in (normal over its root), None (a norm's weight:
     ones), or the name of one of the family's initialisers (`_special`)."""
-    h, d, nh = cfg.hidden, cfg.hd, cfg.heads
-    lat, rope, taps = cfg.mla_latent, cfg.mla_rope_dim, cfg.kda_conv
+    h, d, nh, taps = cfg.hidden, cfg.hd, cfg.heads, cfg.kda_conv
     out = {("embed",): ((cfg.vocab_size, h), h, ("vocab", "embed")),
            ("unembed",): ((h, cfg.vocab_size), h, ("embed", "vocab")),
            ("ln_f",): ((h,), None, ("norm",))}
@@ -98,15 +109,7 @@ def leaves(cfg: TransformerConfig) -> dict:
     out[at + ("o_norm",)] = ((n, d), None, ("layers", "norm"))
     out[at + ("wo",)] = ((n, nh, d, h), nh * d,
                          ("layers", "heads", "head_dim", "embed"))
-    n, at = cfg.layers_of("mla"), ("blocks", "mla")
-    out[at + ("ln_attn",)] = ((n, h), None, ("layers", "norm"))
-    out[at + ("wq",)] = ((n, h, nh * (d + rope)), h, heads)
-    out[at + ("wkv_a",)] = ((n, h, lat + rope), h, ("layers", "embed", None))
-    out[at + ("kv_norm",)] = ((n, lat), None, ("layers", "norm"))
-    out[at + ("wkv_b",)] = ((n, lat, nh, 2 * d), lat,
-                            ("layers", None, "heads", "head_dim"))
-    out[at + ("wo",)] = ((n, nh, d, h), nh * d,
-                         ("layers", "heads", "head_dim", "embed"))
+    out.update(mla_leaves(cfg, (cfg.layers_of("mla"),), ("layers",)))
     m = cfg.dense_mlp_hidden
     out[("blocks", "dense", "ln_mlp")] = ((h,), None, ("norm",))
     out[("blocks", "dense", "wi_gate")] = ((h, m), h, ("embed", "mlp"))
@@ -133,6 +136,40 @@ def leaves(cfg: TransformerConfig) -> dict:
         out[at + ("shared_up",)] = ((n, h, ms), h, ("layers", "embed", "mlp"))
         out[at + ("shared_down",)] = ((n, ms, h), ms,
                                       ("layers", "mlp", "embed"))
+    return out
+
+
+def mla_leaves(cfg: TransformerConfig, lead: tuple, axes: tuple) -> dict:
+    """`leaves`' entries of the latent-attention sublayers `blocks["mla"]`,
+    stacked over the leading axes `lead` (named `axes`): `wq` whole, or with
+    `mla_q_rank` its two factors and the norm between them. The two matrices
+    whose INPUT `mla_scales` multiplies (`wq` behind the query's norm,
+    `wkv_b`) are drawn over the root of their fan-in times that factor: the
+    published factors are sqrt(hidden / rank), what gives a query, a key and
+    a value the variance of a full-rank projection when the matrix behind
+    the low rank is drawn over the root of `hidden`. Drawn over the root of
+    the rank alone the factors make attention logits of standard deviation
+    6, a softmax that is one key on seeded weights, and one bfloat16
+    rounding of a near-tie then moves the logits by half their spread (0.53
+    - 0.62 of it at the published widths on the chip, PERF.md PR 44)."""
+    h, d, nh = cfg.hidden, cfg.hd, cfg.heads
+    lat, rope, rank = cfg.mla_latent, cfg.mla_rope_dim, cfg.mla_q_rank
+    scale_q, scale_kv = cfg.mla_scales
+    at = ("blocks", "mla")
+    out = {at + ("ln_attn",): ((*lead, h), None, (*axes, "norm"))}
+    if rank:
+        out[at + ("wq_a",)] = ((*lead, h, rank), h, (*axes, "embed", None))
+        out[at + ("q_norm",)] = ((*lead, rank), None, (*axes, "norm"))
+    out[at + ("wq",)] = ((*lead, rank or h, nh * (d + rope)),
+                         round((rank or h) * scale_q ** 2),
+                         (*axes, None if rank else "embed", "heads"))
+    out[at + ("wkv_a",)] = ((*lead, h, lat + rope), h, (*axes, "embed", None))
+    out[at + ("kv_norm",)] = ((*lead, lat), None, (*axes, "norm"))
+    out[at + ("wkv_b",)] = ((*lead, lat, nh, 2 * d),
+                            round(lat * scale_kv ** 2),
+                            (*axes, None, "heads", "head_dim"))
+    out[at + ("wo",)] = ((*lead, nh, d, h), nh * d,
+                         (*axes, "heads", "head_dim", "embed"))
     return out
 
 
@@ -390,14 +427,73 @@ def latent_attend(q, latent, layer, rows, kv_len_mask, value_dim: int,
                       held[..., :value_dim], preferred_element_type=F32)
 
 
+def rotate_interleaved(x, positions, theta: float):
+    """x [B, S, ..., R] with its last axis rotated by position, the pairs
+    INTERLEAVED (dimensions 2i and 2i + 1 turn together by `positions *
+    theta ** (-2i / R)`: DeepSeek-V3's layout, where `transformer._rope`
+    pairs i with i + R / 2). Float32 inside, x's dtype out."""
+    r = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, r, 2, dtype=F32) / r)
+    ang = positions.astype(F32)[..., None] * inv  # [B, S, R / 2]
+    ang = ang.reshape(*ang.shape[:2], *(1,) * (x.ndim - 3), r // 2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pairs = x.astype(F32).reshape(*x.shape[:-1], r // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos], -1).reshape(
+        x.shape).astype(x.dtype)
+
+
+def _attend_expanded(q_n, q_r, k_n, k_r, v, positions, row_mask, sm_scale,
+                     block=None):
+    """A prefill's causal attention over its own fresh rows, expanded: q_n
+    [B, S, H, D] and q_r [B, S, H, R] against k_n [B, S, H, D] and the one
+    shared k_r [B, S, R], values v [B, S, H, Dv]; float32 logits and softmax.
+    `block`: queries go that many at a time, each block against the keys up
+    to its own end (a prefill starts at position 0 and its positions ascend,
+    so later keys are masked for every query of the block): the largest
+    logits array is [H, block, S], not [H, S, S], and what lies wholly above
+    the diagonal is never computed. A row's result is the unblocked one's."""
+    s = q_n.shape[1]
+
+    def attend(lo, hi):
+        logits = jnp.einsum("bsnd,btnd->bnst", q_n[:, lo:hi], k_n[:, :hi],
+                            preferred_element_type=F32)
+        logits = (logits + jnp.einsum(
+            "bsnr,btr->bnst", q_r[:, lo:hi], k_r[:, :hi],
+            preferred_element_type=F32)) * sm_scale
+        seen = (positions[:, lo:hi, None] >= positions[:, None, :hi]) \
+            & row_mask[:, None, :hi]
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, None], logits, NEG_INF), axis=-1)
+        return jnp.einsum("bnst,btnd->bsnd", probs.astype(v.dtype),
+                          v[:, :hi])
+
+    if not block or block >= s:
+        return attend(0, s)
+    return jnp.concatenate([attend(lo, min(lo + block, s))
+                            for lo in range(0, s, block)], axis=1)
+
+
 def mla_attention(cfg: TransformerConfig, x, p, positions, latent,
                   kv_len_mask, row_mask, layer, rows=None):
-    """The attention half of "mla" layer `layer` (its index within its
-    kind): `latent` is the stack, this call's rows written at [layer,
-    sequence, position] in place. Returns (x, latent)."""
+    """The attention half of latent-attention sublayer `layer` (its index
+    in `KVCache.latent`): `latent` is the stack, this call's rows written at
+    [layer, sequence, position] in place. Returns (x, latent).
+
+    By the configuration's fields: `mla_q_rank` (the query through `wq_a`,
+    an RMSNorm and `wq`, else through `wq` alone); `mla_rotate` (`q_r` and
+    the one shared `k_r` rotated by position under `mla.rotate`, the key
+    BEFORE it is cached, so a cached row is `[c ; rot(k_r)]` and a decode
+    step rotates its own query alone); `mla_scales` = (on the query: folded
+    into the softmax's scale, which both of its parts share; on the normed
+    latent: applied in float32 before the row is rounded, so on the keys'
+    unrotated part and on the values, not on `k_r`). A prefill whose float32
+    logits [heads, S, S] would pass `PREFILL_LOGITS_MAX` attends
+    `PREFILL_QUERY_BLOCK` queries at a time (`_attend_expanded`)."""
     b, s, _ = x.shape
     nh, d, lat, rope = cfg.heads, cfg.hd, cfg.mla_latent, cfg.mla_rope_dim
-    sm_scale = (d + rope) ** -0.5
+    scale_q, scale_kv = cfg.mla_scales
+    sm_scale = scale_q * (d + rope) ** -0.5
     y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
     wkv_b = p["wkv_b"].astype(y.dtype)  # [latent, H, 2 D]
     with jax.named_scope("mla.project"):
@@ -405,15 +501,31 @@ def mla_attention(cfg: TransformerConfig, x, p, positions, latent,
         # head's `rope` shared-key dimensions, so that each part is a plain
         # split of the product (heads of d + rope side by side made the
         # chip's compiler copy the matrix every step)
-        q = jnp.einsum("bsh,hm->bsm", y, p["wq"].astype(y.dtype))
+        q_in = y
+        if cfg.mla_q_rank:
+            q_in = _rms_norm(jnp.einsum("bsh,hr->bsr", y,
+                                        p["wq_a"].astype(y.dtype)),
+                             p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsh,hm->bsm", q_in, p["wq"].astype(y.dtype))
         q_n = q[..., :nh * d].reshape(b, s, nh, d)
         q_r = q[..., nh * d:].reshape(b, s, nh, rope)
         kv = jnp.einsum("bsh,hc->bsc", y, p["wkv_a"].astype(y.dtype))
+        c, k_r = kv[..., :lat], kv[..., lat:]
+        if scale_kv == 1.0:
+            c = _rms_norm(c, p["kv_norm"], cfg.norm_eps)
+        else:  # the factor in float32, one rounding into the row
+            c32 = c.astype(F32)
+            c = (c32 * lax.rsqrt(jnp.mean(c32 * c32, -1, keepdims=True)
+                                 + cfg.norm_eps)
+                 * (p["kv_norm"].astype(F32) * scale_kv)).astype(c.dtype)
+    if cfg.mla_rotate:
+        with jax.named_scope("mla.rotate"):
+            q_r = rotate_interleaved(q_r, positions, cfg.rope_theta)
+            k_r = rotate_interleaved(k_r, positions, cfg.rope_theta)
+    with jax.named_scope("mla.project"):
         row = jnp.concatenate(
-            [_rms_norm(kv[..., :lat], p["kv_norm"], cfg.norm_eps),
-             kv[..., lat:],
-             jnp.zeros((b, s, cfg.latent_row - lat - rope), kv.dtype)],
-            axis=-1).astype(latent.dtype)
+            [c, k_r, jnp.zeros((b, s, cfg.latent_row - lat - rope),
+                               kv.dtype)], axis=-1).astype(latent.dtype)
         if s == latent.shape[2]:  # a prefill into a row cache of its bucket
             latent = lax.dynamic_update_index_in_dim(latent, row, layer, 0)
         else:
@@ -437,19 +549,12 @@ def mla_attention(cfg: TransformerConfig, x, p, positions, latent,
         with jax.named_scope("mla.attend"):
             expanded = jnp.einsum("bsc,cnd->bsnd", row[..., :lat].astype(
                 y.dtype), wkv_b)
-            logits = jnp.einsum("bsnd,btnd->bnst", q_n,
-                                expanded[..., :d],
-                                preferred_element_type=F32)
-            logits = (logits + jnp.einsum(
-                "bsnr,btr->bnst", q_r,
-                row[..., lat:lat + rope].astype(y.dtype),
-                preferred_element_type=F32)) * sm_scale
-            seen = (positions[:, :, None] >= positions[:, None, :]) \
-                & row_mask[:, None, :]
-            probs = jax.nn.softmax(
-                jnp.where(seen[:, None], logits, NEG_INF), axis=-1)
-            o = jnp.einsum("bnst,btnd->bsnd", probs.astype(y.dtype),
-                           expanded[..., d:])
+            o = _attend_expanded(
+                q_n, q_r, expanded[..., :d],
+                row[..., lat:lat + rope].astype(y.dtype), expanded[..., d:],
+                positions, row_mask, sm_scale,
+                PREFILL_QUERY_BLOCK if nh * s * s * 4 > PREFILL_LOGITS_MAX
+                else None)
     with jax.named_scope("mla.out"):
         out = _from_heads(o.astype(x.dtype), p["wo"])
     return x + out, latent

@@ -36,6 +36,7 @@ from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
+HELD_SHARE_CAPPED = 32  # `held_rows_cap`: shares of the outputs under 1 / this
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,6 +135,26 @@ class TransformerConfig:
     # a stored selection bias that chooses the k experts and stays out of
     # their weights (`kimi_linear.router`)
     router_score: str = "softmax"
+    # Latent attention as DeepSeek-V3's family publishes it (an "mla" layer's
+    # and a "scmoe" double layer's; all off for Kimi-Linear). `mla_q_rank`: a
+    # low-rank query, hidden -> `mla_q_rank`, RMSNorm, -> heads x (hd +
+    # mla_rope_dim); `mla_rotate`: the query's `mla_rope_dim` part and the ONE
+    # shared key part are rotated by position (`rope_theta`, pairs
+    # INTERLEAVED), the key before it is cached; `mla_scales` = (a factor on
+    # the query behind its low-rank norm, a factor on the normed latent, so on
+    # the keys' unrotated part and on the values).
+    mla_q_rank: int = 0
+    mla_rotate: bool = False
+    mla_scales: Tuple[float, float] = (1.0, 1.0)
+    # A pattern of the one kind "scmoe" (models/longcat.py; the cached forward
+    # alone runs it): `layers` DOUBLE layers and nothing before or behind them
+    # (`lead_kind` ""), each two latent-attention sublayers (two cache layers:
+    # `latent_layers`), two dense SwiGLU MLPs of `dense_mlp_hidden` and one
+    # expert layer on a shortcut beside the second attention and MLP.
+    # `zero_experts`: router outputs behind the `num_experts` routed ones that
+    # multiply nothing: a choice among them adds its weight times the expert
+    # layer's input (`moe_dropless`), here, whatever `experts_held` says.
+    zero_experts: int = 0
 
     def __post_init__(self):
         if self.attention not in ("gqa", "cca"):
@@ -160,24 +181,37 @@ class TransformerConfig:
                    self.experts_held, self.tail_kinds, self.kda_conv,
                    self.mla_latent, self.mla_rope_dim,
                    self.lead_kind != "full", self.router_score != "softmax")
+        latent = (self.mla_q_rank, self.mla_rotate,
+                  tuple(self.mla_scales) != (1.0, 1.0), self.zero_experts)
         if not self.layer_kinds:
-            if any(pattern):
+            if any(pattern) or any(latent):
                 raise ValueError(
                     "window, window_heads, dense_mlp_hidden, head_gate, "
                     "rope_yarn, shared_expert_hidden, experts_held, "
                     "lead_kind, tail_kinds, kda_conv, mla_latent, "
-                    "mla_rope_dim and router_score belong to a layer pattern "
+                    "mla_rope_dim, router_score, mla_q_rank, mla_rotate, "
+                    "mla_scales and zero_experts belong to a layer pattern "
                     "(layer_kinds): the one block has none")
             return
-        kinds = {self.lead_kind, *self.layer_kinds, *self.tail_kinds}
-        if kinds <= {"kda", "mla"}:
-            self._check_linear_pattern()
+        kinds = {self.lead_kind, *self.layer_kinds, *self.tail_kinds} - {""}
+        if kinds == {"scmoe"}:
+            self._check_double_pattern()
             return
-        if kinds - {"window", "full"}:
+        if not (kinds <= {"kda", "mla"} or kinds <= {"window", "full"}):
             raise ValueError(
                 f"unknown layer kinds {sorted(kinds)!r}: a pattern is of "
                 "'window' and 'full' layers (models/laguna.py) or of 'kda' "
-                "and 'mla' layers (models/kimi_linear.py), not of both")
+                "and 'mla' layers (models/kimi_linear.py), not of both, or "
+                "of 'scmoe' double layers alone (models/longcat.py)")
+        if self.zero_experts:
+            raise ValueError("zero_experts belong to a pattern of 'scmoe' "
+                             "double layers")
+        if kinds <= {"kda", "mla"}:
+            self._check_linear_pattern()
+            return
+        if any(latent):
+            raise ValueError("mla_q_rank, mla_rotate and mla_scales are a "
+                             "latent-attention layer's ('mla', 'scmoe')")
         if self.lead_kind != "full" or self.tail_kinds or self.kda_conv \
                 or self.mla_latent or self.mla_rope_dim \
                 or self.router_score != "softmax":
@@ -209,7 +243,7 @@ class TransformerConfig:
         """A pattern of "kda" and "mla" layers: what it needs, and what of
         the other blocks' options it refuses, each by name."""
         body = self.layers - 1 - len(self.tail_kinds)
-        if body < 0 or body % len(self.layer_kinds):
+        if not self.lead_kind or body < 0 or body % len(self.layer_kinds):
             raise ValueError(
                 f"layers {self.layers} is not one leading {self.lead_kind!r} "
                 f"layer, whole periods of {self.layer_kinds!r} and the "
@@ -235,6 +269,36 @@ class TransformerConfig:
                 f"{', '.join(n for n, v in refused.items() if v)}: its heads "
                 "are all alike, nothing rotates, and its router is linear")
 
+    def _check_double_pattern(self):
+        """A pattern of "scmoe" double layers: what it needs, and what of
+        the other blocks' options it refuses, each by name."""
+        if self.layer_kinds != ("scmoe",) or self.lead_kind \
+                or self.tail_kinds or self.layers < 1:
+            raise ValueError(
+                "a pattern of double layers is layer_kinds ('scmoe',) with "
+                "lead_kind '' and no tail_kinds: `layers` of them and "
+                "nothing else")
+        if not (self.mla_latent and self.mla_rope_dim and self.num_experts
+                and self.dense_mlp_hidden) or self.mla_rope_dim % 2:
+            raise ValueError("a double layer needs mla_latent, an even "
+                             "mla_rope_dim, num_experts and dense_mlp_hidden")
+        if self.router_score != "softmax" or self.zero_experts < 0:
+            raise ValueError("a double layer's router is a softmax over "
+                             "num_experts + zero_experts outputs")
+        refused = dict(
+            window=self.window, window_heads=self.window_heads,
+            head_gate=self.head_gate, rope_yarn=self.rope_yarn,
+            qk_norm=self.qk_norm, lora_rank=self.lora_rank,
+            tie_embeddings=self.tie_embeddings, kda_conv=self.kda_conv,
+            shared_expert_hidden=self.shared_expert_hidden,
+            attention=self.attention != "gqa", router=self.router != "linear",
+            partial_rotary=self.partial_rotary != 1.0,
+            kv_heads=self.kv_heads != self.heads)
+        if any(refused.values()):
+            raise ValueError(
+                "a pattern of double layers has no "
+                f"{', '.join(n for n, v in refused.items() if v)}")
+
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden // self.heads
@@ -243,6 +307,10 @@ class TransformerConfig:
         """The module that runs this configuration's layer pattern
         (parameters stacked by kind, `forward_cached`), imported only where
         a configuration has one."""
+        if "scmoe" in self.kinds:
+            from ray_tpu.models import longcat
+
+            return longcat
         if set(self.kinds) & {"kda", "mla"}:
             from ray_tpu.models import kimi_linear
 
@@ -256,12 +324,24 @@ class TransformerConfig:
         """Every layer's kind, in order; () without a pattern."""
         if not self.layer_kinds:
             return ()
-        return (self.lead_kind, *self.layer_kinds * self.periods,
-                *self.tail_kinds)
+        lead = (self.lead_kind,) if self.lead_kind else ()
+        return (*lead, *self.layer_kinds * self.periods, *self.tail_kinds)
 
     def layers_of(self, kind: str) -> int:
         """How many of a pattern's layers are of `kind`."""
         return self.kinds.count(kind)
+
+    @property
+    def latent_layers(self) -> int:
+        """Layers of `KVCache.latent`: one an "mla" layer, two a "scmoe"
+        double layer (its two attention sublayers), in the layers' order."""
+        return self.layers_of("mla") + 2 * self.layers_of("scmoe")
+
+    @property
+    def router_outputs(self) -> int:
+        """What a sparse layer's router scores: the routed experts, then
+        the zero-compute ones."""
+        return self.num_experts + self.zero_experts
 
     @property
     def latent_row(self) -> int:
@@ -278,9 +358,9 @@ class TransformerConfig:
         at a time ("k", "v", "ring_k", "ring_v", "latent") and states read
         and rewritten every step ("state", "mat", "conv")."""
         kinds = set(self.kinds)
-        if kinds & {"kda", "mla"}:
+        if kinds & {"kda", "mla", "scmoe"}:
             return (("mat", "conv") if "kda" in kinds else ()) + (
-                ("latent",) if "mla" in kinds else ())
+                ("latent",) if kinds & {"mla", "scmoe"} else ())
         return ("k", "v") + (("state",) if self.attention == "cca" else ()) \
             + (("ring_k", "ring_v") if "window" in kinds else ())
 
@@ -292,9 +372,9 @@ class TransformerConfig:
 
     @property
     def periods(self) -> int:
-        """Whole periods of `layer_kinds` behind the leading layer (0: no
-        pattern)."""
-        return (self.layers - 1 - len(self.tail_kinds)) \
+        """Whole periods of `layer_kinds` behind the leading layer, if the
+        pattern has one (0: no pattern)."""
+        return (self.layers - bool(self.lead_kind) - len(self.tail_kinds)) \
             // len(self.layer_kinds) if self.layer_kinds else 0
 
     @property
@@ -313,10 +393,10 @@ class TransformerConfig:
     @property
     def sparse_layers(self) -> int:
         """Layers that route: all of a sparse model's, or all but a
-        pattern's leading dense one."""
+        pattern's leading dense one (a double layer routes once)."""
         if not self.num_experts:
             return 0
-        return self.layers - 1 if self.layer_kinds else self.layers
+        return self.layers - bool(self.lead_kind and self.layer_kinds)
 
     def flops_per_token(self) -> float:
         """Approx forward+backward FLOPs/token (6*N + attention), for MFU.
@@ -435,6 +515,23 @@ PRESETS: Dict[str, TransformerConfig] = {
         tail_kinds=("kda", "mla"), kda_conv=4, mla_latent=32, mla_rope_dim=8,
         router_score="sigmoid", dense_mlp_hidden=192, routed_scale=2.446,
         shared_expert_hidden=64, experts_held=(0, 8), dtype=jnp.float32,
+    ),
+    # meituan-longcat/LongCat-Flash-Chat's double layer at debug widths
+    # (models/longcat.py): 3 double layers of two latent attentions (4 heads
+    # of 16 + 8 rotated, a latent of 32, a query through 24), two dense MLPs
+    # and one expert layer on the shortcut: softmax top-4 of 16 routed (4
+    # held) + 8 zero-compute outputs, not renormalised, times 6. The
+    # published widths are the benchmark's to build
+    # (benchmarks/runners/serve_longcat.py)
+    "longcat_debug": TransformerConfig(
+        vocab_size=512, hidden=128, mlp_hidden=64, layers=3, heads=4,
+        kv_heads=4, head_dim=16, max_seq=128, remat=False, rope_theta=1e7,
+        norm_eps=1e-5, num_experts=16, experts_per_token=4,
+        norm_topk_prob=False, layer_kinds=("scmoe",), lead_kind="",
+        mla_latent=32, mla_rope_dim=8, mla_q_rank=24, mla_rotate=True,
+        mla_scales=((128 / 24) ** 0.5, 2.0), zero_experts=8,
+        dense_mlp_hidden=192, routed_scale=6.0, experts_held=(0, 4),
+        dtype=jnp.float32,
     ),
 }
 
@@ -665,6 +762,44 @@ def _grouped_matmul(rows, weights, group_sizes, layer=None):
     return grouped_matmul(rows, weights, group_sizes, layer)
 
 
+def held_rows_cap(cfg: TransformerConfig, assignments: int):
+    """How many of a call's `assignments` (T x k, static) `moe_dropless`
+    gathers rows for where only a thin share of the router's outputs is held
+    here, or None: all of them. With 16 of 768 outputs held (zero-compute
+    ones among the rest) one assignment in 48 meets a weight, and the static
+    T x k layout gathered, multiplied as no group's and unsorted 48 rows for
+    it: 73 ms of a 4,096-token prefill's 402 and a decode step's 384 rows, too
+    many for the grouped kernel's one tile a group (my chip runs, PR 44). The
+    sorted order has the held groups' rows FIRST, so the first `cap` rows
+    hold them all unless more than `cap` assignments are held: four times
+    the even share, 64 at least, in whole tiles; a call that holds more
+    (`lax.cond` on the count) takes the whole layout, so nothing is ever
+    dropped. Only under a share of 1 / `HELD_SHARE_CAPPED`, and where it
+    halves the rows at least: the thicker shares' programs are what they
+    were."""
+    if cfg.experts_held is None:
+        return None
+    count, outputs = cfg.experts_held[1], cfg.num_experts + cfg.zero_experts
+    if count * HELD_SHARE_CAPPED > outputs:
+        return None
+    cap = max(64, 4 * -(-assignments * count // outputs))
+    cap = -(-cap // 16) * 16
+    return cap if 2 * cap <= assignments else None
+
+
+def rows_gathered(cfg: TransformerConfig, experts):
+    """How many rows `moe_dropless` gathers for a call whose rows chose
+    `experts` [T, k] (int32 scalar): `held_rows_cap`'s, or all T x k where it
+    has none or more assignments than that are held."""
+    n = experts.size
+    cap = held_rows_cap(cfg, n)
+    if cap is None:
+        return jnp.int32(n)
+    first, count = cfg.experts_held
+    held = ((experts >= first) & (experts < first + count)).sum()
+    return jnp.where(held <= cap, cap, n).astype(jnp.int32)
+
+
 @jax.named_scope("moe_router")
 def moe_router(cfg: TransformerConfig, x, p):
     """x [T,h] -> (weights [T,k] float32, experts [T,k] int32): the top-k of
@@ -714,41 +849,84 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
     the weighted sum, so what they add is exactly nothing. T*k rows stay
     static. `load` still counts every expert: held / all is the share of
     the routed work that is done here.
+
+    `cfg.zero_experts` router outputs behind the `num_experts` routed ones
+    are a THIRD class, zero-compute experts that return their input: a choice
+    among them (an index >= `num_experts`) multiplies nothing. Its assignment
+    sorts behind the held groups with the absent ones' and is in no group;
+    what the class adds is `(the sum of a token's weights on it) x the
+    token's input`, one multiply a token under the scope `moe.zero`, done
+    HERE whatever `experts_held` says (a token's home chip does it; nothing
+    is exchanged for it). A token so does the work of 0 to k real experts.
+    `load` is then [num_experts + zero_experts], the zero-compute outputs
+    counted apart behind the routed ones.
+
+    Where the held share is thin (`held_rows_cap`) the rows of the first
+    `cap` sorted assignments alone are gathered and multiplied, and added to
+    their tokens; a call with more held assignments than that takes the
+    whole T*k layout: the result is the same either way.
     """
     b, s, h = y.shape
-    e, k = cfg.num_experts, cfg.experts_per_token
+    e, k, zero = cfg.num_experts, cfg.experts_per_token, cfg.zero_experts
     t = b * s
     x = y.reshape(t, h)
     weights, experts = routing or moe_router(cfg, x, p)
     flat = experts.reshape(t * k)  # assignment a = token * k + choice
     held = cfg.experts_held
     group, groups = flat, e  # the group of weights an assignment multiplies
-    if held is not None:
-        first, groups = held
+    if held is not None or zero:
+        first, groups = held or (0, e)
         here = (flat >= first) & (flat < first + groups)
-        group = jnp.where(here, flat - first, groups)  # absent: behind all
+        group = jnp.where(here, flat - first, groups)  # the rest: behind all
     with jax.named_scope("moe_experts"):
         order = jnp.argsort(group, stable=True)  # sorted row -> assignment
         group_sizes = jnp.bincount(group, length=groups).astype(jnp.int32)
-        rows = x[order // k]  # [T*k, h], one expert's rows adjoin
-        act = _grouped_matmul(
-            rows, (p["wi_gate"].astype(x.dtype), p["wi_up"].astype(x.dtype)),
-            group_sizes, layer)
-        down = _grouped_matmul(act, p["wo_mlp"].astype(x.dtype), group_sizes,
-                               layer)
-        if held is not None:  # rows of no group: whatever the kernel left
-            down = jnp.where(here[order][:, None], down, 0.0)
-        # back to assignment order (a gather by the inverse permutation),
-        # then the weighted sum over each token's k experts, in float32
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
-        out = (down[inverse].reshape(t, k, h) * weights[..., None]).sum(1)
+
+        def through_experts(order):
+            """The rows of the sorted assignments `order` through their
+            groups' gate, up and down matrices: [len(order), h] float32,
+            zeros where an assignment is in no group."""
+            rows = x[order // k]  # one expert's rows adjoin
+            act = _grouped_matmul(
+                rows, (p["wi_gate"].astype(x.dtype),
+                       p["wi_up"].astype(x.dtype)), group_sizes, layer)
+            down = _grouped_matmul(act, p["wo_mlp"].astype(x.dtype),
+                                   group_sizes, layer)
+            if held is not None or zero:  # no group: whatever was left
+                down = jnp.where(here[order][:, None], down, 0.0)
+            return down
+
+        def all_rows():
+            # back to assignment order (a gather by the inverse
+            # permutation), then the weighted sum over each token's k
+            # experts, in float32
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+            return (through_experts(order)[inverse].reshape(t, k, h)
+                    * weights[..., None]).sum(1)
+
+        def first_rows(cap):
+            # the groups' rows lie first in the sorted order: those alone,
+            # each added to its token with its weight
+            first = order[:cap]
+            return jnp.zeros((t, h), jnp.float32).at[first // k].add(
+                through_experts(first)
+                * weights.reshape(t * k)[first][:, None])
+
+        cap = held_rows_cap(cfg, t * k)
+        out = all_rows() if cap is None else lax.cond(
+            group_sizes.sum() <= cap, functools.partial(first_rows, cap),
+            all_rows)
+    if zero:
+        with jax.named_scope("moe.zero"):
+            on_zero = jnp.where(experts >= e, weights, 0.0).sum(-1)
+            out = out + on_zero[:, None] * x.astype(jnp.float32)
     if row_mask is None:
-        load = group_sizes if held is None else jnp.bincount(
-            flat, length=e).astype(jnp.int32)
+        load = group_sizes if held is None and not zero else jnp.bincount(
+            flat, length=e + zero).astype(jnp.int32)
     else:
         load = jnp.bincount(
             flat, weights=jnp.repeat(row_mask.reshape(t).astype(jnp.int32), k),
-            length=e)
+            length=e + zero)
     return out.astype(y.dtype).reshape(b, s, h), load
 
 

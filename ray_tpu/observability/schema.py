@@ -127,10 +127,15 @@ PROGRAM_SCOPES = {
     "kda.prefill_scan": "models/kimi_linear.py: a prefill's recurrence, a "
                         "chunk of positions at a time",
     "kda.out": "models/kimi_linear.py: the head norm, the output gate, wo",
-    "mla.project": "models/kimi_linear.py: a latent-attention layer's query "
-                   "and latent projections, the latent's norm, the row "
-                   "write; in a decode step the key expansion absorbed into "
-                   "the query",
+    "mla.project": "models/kimi_linear.py: a latent-attention sublayer's "
+                   "query (through its low-rank factors and their norm, "
+                   "where it has them) and latent projections, the latent's "
+                   "norm and factor, the row write; in a decode step the key "
+                   "expansion absorbed into the query",
+    "mla.rotate": "models/kimi_linear.py: the rotation by position of the "
+                  "query's rope part and of the one shared key part "
+                  "(`mla_rotate`), pairs interleaved, before the key is "
+                  "cached",
     "mla.attend": "models/kimi_linear.py: attention: a decode step's over "
                   "the held latent rows (absorbed), a prefill's over keys "
                   "and values expanded from its own fresh rows",
@@ -141,6 +146,14 @@ PROGRAM_SCOPES = {
                   "(softmax); models/kimi_linear.py: the sigmoid router with "
                   "its selection bias",
     "moe_experts": "models/transformer.py: sort, grouped matmuls, unsort",
+    "moe.zero": "models/transformer.py: the zero-compute outputs' part of an "
+                "expert layer: the sum of a token's weights on them times "
+                "its input. What they were chosen how often is a counter "
+                "(`moe_assignments_zero`, beside `moe_assignments_held` / "
+                "`_absent`; `moe_rows_gathered`: the rows the static layout "
+                "gathered; `moe_routed_most`: the most routed experts a row)",
+    "scmoe.dense": "models/longcat.py: a double layer's two dense SwiGLU "
+                   "MLPs",
     "attend_cached": "models/decoding.py: attention over the cached rows",
     "mlp": "the dense SwiGLU MLP",
     "lora": "models/transformer.py: an adapter's two matmuls",

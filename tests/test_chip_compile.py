@@ -173,6 +173,7 @@ SERVE_CONFIGS = {
 # the vocabulary; 32 slots of 4096 positions, the 2048 bucket. Not among
 # SERVE_CONFIGS: its cache has no K/V rows for the tests that walk those
 KIMI_LINEAR = "kimi-linear-48b-a3b-serve-ep16"
+LONGCAT = "longcat-flash-serve-ep32-d4"
 PATTERN_CONFIGS = {
     KIMI_LINEAR: dict(
         name="kimi_linear_debug", vocab_size=20480, hidden=2304,
@@ -181,6 +182,18 @@ PATTERN_CONFIGS = {
         experts_held=(0, 16), mla_latent=512, mla_rope_dim=64,
         dense_mlp_hidden=9216, shared_expert_hidden=1024,
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, slots=32, max_len=4096),
+    # LongCat-Flash-Chat's published widths, 4 of 28 double layers, one
+    # chip's share of a layer that 32 hold: 16 of 512 routed experts beside
+    # the 256 zero-compute outputs, an eighth of the vocabulary; 32 slots of
+    # 5120 positions, the 4096 bucket
+    LONGCAT: dict(
+        name="longcat_debug", vocab_size=16384, hidden=6144, mlp_hidden=2048,
+        layers=4, heads=64, kv_heads=64, head_dim=128, max_seq=131072,
+        num_experts=512, zero_experts=256, experts_per_token=12,
+        experts_held=(0, 16), mla_latent=512, mla_rope_dim=64,
+        mla_q_rank=1536, mla_scales=(2.0, 12 ** 0.5), dense_mlp_hidden=12288,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, slots=32, max_len=5120,
+        bucket=4096),
 }
 
 
@@ -579,6 +592,77 @@ def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     leaves = len(jax.tree.leaves(jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.key(0)))))
     assert _entry_parameters(decode) == leaves + 5 + 6  # k, v, lengths + 3
+    from ray_tpu.observability import schema
+
+    assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+def test_longcat_serve_programs_compile(serve_programs):
+    """LongCat-Flash's programs at the published widths, 4 double layers: the
+    decode step is given the latent rows of all 8 attention SUBLAYERS to keep
+    (aliased in to out), holds no temporary of a sublayer's size and no
+    float32 logits over `max_len`, reads the rows with the Mosaic call that
+    is given the stack, twice a scanned double layer, under `mla.attend`;
+    the prefill of the 4096 bucket holds no [64, 4096, 4096] logits (its
+    largest block is [64, 512, 4096]) and no [4096 x 12, 6144] expert rows
+    (1024 rows a call); both fit the chip, the prefill beside the engine's
+    cache, and the scopes reach the compiled text."""
+    from benchmarks import harness, scope_ops
+
+    cfg, prefill, decode, cache = serve_programs(LONGCAT)
+    slots = cache.lengths.shape[0]
+    assert cfg.kinds == ("scmoe",) * 4 and cfg.latent_layers == 8
+    assert cache.k.shape[0] == 0 and cache.state is None and cache.mat is None
+    assert cache.latent.shape == (8, slots, 5120, 640)
+    kept = _arg_bytes(cache.latent)
+    assert round(kept / 1e9, 2) == 1.68  # 1.51 of values
+    for name, program in (("prefill[4096]", prefill),
+                          (f"decode[{slots}x5120]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        assert "s32[768]" in program.as_text()  # the load over all outputs
+        # no layer's held experts are copied out of their stack
+        assert not re.search(r"bf16\[16,(6144,2048|2048,6144)\]",
+                             program.as_text())
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    # under one sublayer (0.14 GB, my compile, PR 44; a sublayer is 0.21): no
+    # copy of the stack, and none of a double layer's part of a parameter
+    # stack (0.30 GB a dense matrix's [2, ...] when the sublayer was sliced
+    # out in two steps: half the step on the chip)
+    assert m.temp_size_in_bytes < kept / 8
+    assert _total_bytes(decode) < 14e9
+    assert _total_bytes(prefill) + kept < 15.5e9  # beside the engine's cache
+    text, ptext = decode.as_text(), prefill.as_text()
+    sublayer = math.prod(cache.latent.shape[1:])
+    for op_name, dtype, dims, op in _results(text):
+        assert math.prod(dims) != sublayer, (op_name, dims, op)
+        assert not (dtype == "f32" and 5120 in dims
+                    and math.prod(dims) >= slots * 64 * 5120), (op_name, dims)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line
+             and "%latent_decode_attention" in line]
+    assert len(calls) == 2  # a double layer's two sublayers, scanned
+    runner = harness.load_module("runners", "serve_longcat")
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) == set(runner.SCOPES)
+    called = {scope_ops._INSTRUCTION.match(line)[1] for line in calls}
+    assert called <= set(scopes["mla.attend"])
+    pscopes = scope_ops.op_scopes(ptext, runner.SCOPES)
+    assert set(pscopes) >= set(runner.SCOPES) - {"sample"}
+    # a block of 512 queries against the keys up to its end, never S x S
+    assert re.search(r"f32\[64,512,4096\]", ptext)
+    assert not re.search(r"f32\[(1,)?64,4096,4096\]", ptext)
+    assert re.search(r"\[12288,6144\]", ptext)  # 1024 rows x 12 a call
+    assert not re.search(r"\[49152,6144\]", ptext)
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    assert _entry_parameters(decode) == leaves + 5 + 4  # k, v, lengths, latent
     from ray_tpu.observability import schema
 
     assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)

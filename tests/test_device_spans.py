@@ -277,7 +277,7 @@ def test_a_stream_rpc_is_one_call_of_the_senders_thread(traced):
     assert rpcs[0][1] >= yields[0][1]
     ctx = {"cell": {}, "counters": {}, "device": {},
            "trace": {"program_spans": parsed}}
-    read = harness.load_reader("reason_stream_items_per_call").read
+    read = harness.load_reader("tput_stream_items_per_call").read
     assert read(ctx) == pytest.approx(2 / len(sent))
     # a trace from before the calls carried a count: nothing to read
     before = dict(parsed, spans=[
