@@ -6,7 +6,13 @@ NamedSharding choices over ONE jitted program (SURVEY.md §2.3):
 
 - params/optimizer state sharded by logical-axis rules (fsdp/tensor),
 - batch sharded over (replica, data, fsdp) × sequence,
-- gradients all-reduced implicitly by GSPMD over the data axes,
+- only the trainable leaves are differentiated (``trainable_mask``: every
+  leaf of a dense config, the adapters alone of a LoRA one): no gradient
+  of a frozen weight is computed, summed across chips or kept, and the
+  frozen leaves leave the step in the buffers they came in by; the
+  ``grad_norm`` metric is the norm of the gradients that exist, the
+  trainable leaves',
+- those gradients all-reduced implicitly by GSPMD over the data axes,
 - sequence axis > 1 switches attention to ring_attention under
   shard_map (exact, comms overlap compute on ICI).
 
@@ -41,14 +47,18 @@ def default_optimizer(cfg: TransformerConfig, lr: float = 3e-4,
                       weight_decay: float = 0.1,
                       params_template: Optional[Any] = None) -> optax.GradientTransformation:
     """AdamW + global-norm clip; LoRA configs train only adapter leaves
-    via optax.masked (reference target: Llama LoRA fine-tune, BASELINE.md)."""
+    (reference target: Llama LoRA fine-tune, BASELINE.md)."""
     tx = optax.chain(
         optax.clip_by_global_norm(1.0),
         optax.adamw(lr, b1=0.9, b2=0.95, weight_decay=weight_decay),
     )
     if cfg.lora_rank:
         # multi_transform (not optax.masked — masked passes frozen-leaf
-        # gradients through unchanged) so frozen params get zero updates.
+        # gradients through unchanged) so frozen params get zero updates
+        # and the clip sees the adapters' norm alone: the norm the step
+        # reports as ``grad_norm``. The step never computes a frozen
+        # leaf's gradient (make_train_step): the zeros it hands over in
+        # their place are masked out here, unread.
         labels = lambda params: jax.tree.map(
             lambda t: "train" if t else "freeze", trainable_mask(cfg, params)
         )
@@ -206,7 +216,14 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
                     mesh: Mesh, rules: Optional[Rules] = None,
                     donate: bool = True,
                     num_microbatches: Optional[int] = None) -> Callable[[TrainState, Dict], Tuple[TrainState, Dict]]:
-    """Build the jitted sharded train step: (state, batch) → (state, metrics)."""
+    """Build the jitted sharded train step: (state, batch) → (state, metrics).
+
+    The step differentiates the leaves ``trainable_mask`` marks and no
+    other: a LoRA config's frozen base has no gradient in the program, and
+    leaves it as it came in. ``metrics["grad_norm"]`` is the norm of the
+    gradients that exist, the trainable leaves': the norm
+    ``default_optimizer`` clips by. ``run.differentiated`` counts them
+    (``leaves`` of ``of_leaves``, ``params`` of ``of_params``)."""
     rules = _effective_rules(mesh, rules)
     attn = make_attn_fn(cfg, mesh, rules)
     n_stage = mesh_axis_size(mesh, "stage")
@@ -214,18 +231,36 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     shardings = state_shardings(cfg, optimizer, mesh, rules)
     b_shard = batch_sharding(mesh, rules)
     repl = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    mask = trainable_mask(cfg, shapes)  # Python bools: static in the trace
+    sizes = [(m, s.size) for m, s in zip(jax.tree.leaves(mask),
+                                         jax.tree.leaves(shapes))]
+    differentiated = {
+        "leaves": sum(m for m, _ in sizes), "of_leaves": len(sizes),
+        "params": sum(n for m, n in sizes if m),
+        "of_params": sum(n for _, n in sizes),
+    }
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
         params = state["params"]
+        trainable = jax.tree.map(lambda m, p: p if m else None, mask, params)
 
-        def lf(p):
-            return loss_fn(cfg, p, batch, attn_fn=attn, mesh=pp_mesh,
+        def lf(trainable):
+            merged = jax.tree.map(lambda m, t, frozen: t if m else frozen,
+                                  mask, trainable, params)
+            return loss_fn(cfg, merged, batch, attn_fn=attn, mesh=pp_mesh,
                            num_microbatches=num_microbatches)
 
-        (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params)
-        updates, new_opt = optimizer.update(grads, state["opt_state"], params)
-        new_params = optax.apply_updates(params, updates)
+        (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(trainable)
         gnorm = optax.global_norm(grads)
+        # the optimizer keeps the whole tree (its state's, the checkpoint's);
+        # a frozen leaf's place holds a constant that nothing reads
+        grads = jax.tree.map(lambda m, g, p: g if m else jnp.zeros_like(p),
+                             mask, grads, params)
+        updates, new_opt = optimizer.update(grads, state["opt_state"], params)
+        new_params = jax.tree.map(
+            lambda m, p, u: optax.apply_updates(p, u) if m else p,
+            mask, params, updates)
         metrics = dict(metrics, grad_norm=gnorm)
         new_state = {
             "params": new_params,
@@ -262,6 +297,7 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     run._jitted = jitted
     run._shardings = shardings
     run._batch_sharding = b_shard
+    run.differentiated = differentiated
     return run
 
 

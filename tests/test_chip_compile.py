@@ -14,6 +14,7 @@ the program.
 """
 
 import functools
+import itertools
 import math
 import os
 import re
@@ -98,39 +99,62 @@ def test_flash_backward_compiles(topo):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
-def _compile_lora_step(mesh_spec, devices):
-    cfg = T.config("llama2_7b_lora", **WIDTHS)
-    mesh = build_mesh(mesh_spec, devices)
-    opt = S.default_optimizer(cfg)
-    step = S.make_train_step(cfg, opt, mesh)
-    state = _on(step._shardings, jax.eval_shape(
-        lambda: S.fresh_state(cfg, opt, jax.random.key(0))))
-    batch = {"tokens": jax.ShapeDtypeStruct(
-        (BATCH, SEQ), jnp.int32, sharding=step._batch_sharding)}
-    with jax.set_mesh(mesh):
-        return step._jitted.lower(state, batch).compile(), state
+# the train cells' widths (`benchmarks/configs/mistral7b-v03-lora-*.json`:
+# Mistral-7B-v0.3, rank 16, a step of 4 x 2048) at the depth each cell runs
+BENCH_WIDTHS = dict(vocab_size=32768, hidden=4096, mlp_hidden=14336, heads=32,
+                    kv_heads=8, head_dim=128, max_seq=2048, rope_theta=1e6,
+                    tie_embeddings=False, param_dtype=jnp.bfloat16)
+MESHES = {"one": (MeshSpec(), 1), "four": (MeshSpec(fsdp=2, tensor=2), 4)}
+# temporaries a device of the step that differentiated every leaf (commit
+# 5bf1d99, `benchmarks/rehearse.py`): the frozen stacks' gradients were in it
+PARENT_TEMP_BYTES = {"one": 8.274e9, "four": 10.938e9}
+
+
+@pytest.fixture(scope="module")
+def train_steps(topo):
+    """(chips, widths, lora) -> (compiled step, state shapes, cfg), each
+    compiled once: "flagship" is `WIDTHS` with a step of 8 x 2048, "bench"
+    the train cells' own; `lora=False` is the same widths trained dense."""
+    @functools.cache
+    def compiled(chips, widths="flagship", lora=True):
+        if widths == "flagship":
+            kw, batch = dict(WIDTHS), BATCH
+        else:
+            kw, batch = dict(BENCH_WIDTHS, layers={"one": 8, "four": 32}[chips]), 4
+        cfg = T.config("llama2_7b_lora", **kw, lora_rank=16 if lora else 0)
+        spec, count = MESHES[chips]
+        mesh = build_mesh(spec, list(topo.devices)[:count])
+        opt = S.default_optimizer(cfg)
+        step = S.make_train_step(cfg, opt, mesh)
+        state = _on(step._shardings, jax.eval_shape(
+            lambda: S.fresh_state(cfg, opt, jax.random.key(0))))
+        tokens = {"tokens": jax.ShapeDtypeStruct(
+            (batch, SEQ), jnp.int32, sharding=step._batch_sharding)}
+        with jax.set_mesh(mesh):
+            return step._jitted.lower(state, tokens).compile(), state, cfg
+
+    return compiled
 
 
 def _arg_bytes(state):
     return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(state))
 
 
-def test_lora_step_compiles_for_one_chip(topo):
-    compiled, state = _compile_lora_step(MeshSpec(), topo.devices[:1])
+def test_lora_step_compiles_for_one_chip(train_steps):
+    compiled, state, _ = train_steps("one")
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= _arg_bytes(state)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
-def test_lora_step_compiles_for_four_chips(topo):
+def test_lora_step_compiles_for_four_chips(train_steps):
     """The README's headline — one GSPMD program, attention on the Pallas
     kernels — on a real 2x2: refused before PR 21 ("Mosaic kernels cannot
     be automatically partitioned"), because the dense path called the
     kernel outside shard_map. Also the first time create_device_mesh meets
     the 7-axis shape on a described 2x2."""
-    compiled, state = _compile_lora_step(MeshSpec(fsdp=2, tensor=2),
-                                         list(topo.devices))
+    compiled, state, _ = train_steps("four")
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text and "all-reduce" in text
@@ -138,6 +162,77 @@ def test_lora_step_compiles_for_four_chips(topo):
     # a quarter of the state (norms and the step counter are replicated)
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert 0.25 <= per_device / _arg_bytes(state) < 0.27
+
+
+@pytest.mark.parametrize("chips", ["one", "four"])
+def test_lora_step_executes_no_frozen_gradient(train_steps, chips):
+    """Forward 2N, recomputed forward 2N, the activations' gradient 2N: 6N.
+    The step that also differentiated the frozen base executed 8N, as the
+    dense step of the same widths has to. (The compiler counts a loop's
+    body once: the share is a layer's and the head's, 0.70 here.)"""
+    lora, _, _ = train_steps(chips)
+    dense, _, _ = train_steps(chips, lora=False)
+    share = lora.cost_analysis()["flops"] / dense.cost_analysis()["flops"]
+    print(f"{chips}: LoRA step / dense step = {share:.3f} of the operations")
+    assert 0.6 < share <= 0.8
+
+
+def _summed(text):
+    """(name, element type and dimensions) of every array an `all-reduce` or
+    a `reduce-scatter` of a compiled program returns, alone or in a tuple."""
+    lines = re.findall(
+        r"%(\S+) = (.*?) (?:all-reduce|reduce-scatter)(?:-start)?\(", text)
+    return [(name, shape) for name, shapes in lines
+            for shape in re.findall(r"\w+\[[\d,]*\]", shapes)]
+
+
+def _frozen_shapes(state, cfg):
+    """Result types a frozen leaf's gradient could have on a device: the
+    leaf whole or one layer of its stack, each dimension whole or as the
+    leaf's sharding cuts it (a gradient is summed before it is scattered)."""
+    shapes = set()
+    mask = T.trainable_mask(cfg, state["params"])
+    for leaf, trains in zip(jax.tree.leaves(state["params"]),
+                            jax.tree.leaves(mask)):
+        if trains or leaf.ndim < 2:
+            continue  # a norm's [hidden] is also a row of activations
+        cut = leaf.sharding.shard_shape(leaf.shape)
+        for dims in itertools.product(*zip(leaf.shape, cut)):
+            for lead in {dims[0], 1} if leaf.ndim > 2 else {dims[0]}:
+                shapes.add("bf16[" + ",".join(map(str, (lead,) + dims[1:])) + "]")
+    return shapes
+
+
+@pytest.mark.parametrize("chips", ["one", "four"])
+def test_lora_step_keeps_no_frozen_gradient(train_steps, chips):
+    """The train cells' own step (PR 45): the stacked gradients of the
+    frozen base are not among its temporaries (2.8 GB for the MLP's on a
+    device of either cell, 5.3 and 8.2 GB for all of them), nothing with a
+    frozen stack's shape is computed or written (the stacks are parameters,
+    loop state, and the relayout and the gather the forward asks for), and
+    no collective sums anything of a frozen leaf's shape."""
+    compiled, state, cfg = train_steps(chips, "bench")
+    mem = compiled.memory_analysis()
+    mlp = sum(2 * math.prod(w.sharding.shard_shape(w.shape))
+              for w in (state["params"]["blocks"][k]
+                        for k in ("wi_gate", "wi_up", "wo_mlp")))
+    print(f"{chips}: temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB "
+          f"(parent {PARENT_TEMP_BYTES[chips] / 1e9:.3f}), a device's MLP "
+          f"stacks {mlp / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < PARENT_TEMP_BYTES[chips] - mlp
+    # the donated state comes out in the buffers it went in by
+    assert mem.alias_size_in_bytes >= 0.999 * mem.argument_size_in_bytes
+    frozen = _frozen_shapes(state, cfg)
+    text = compiled.as_text()
+    moved_only = {"parameter", "get-tuple-element", "bitcast", "copy",
+                  "copy-start", "copy-done", "all-gather", "all-gather-start",
+                  "all-gather-done", "custom-call"}
+    made = [(name, dtype, dims, op) for name, dtype, dims, op in _results(text)
+            if op not in moved_only and dims[:1] != [1]
+            and f"{dtype}[{','.join(map(str, dims))}]" in frozen]
+    assert not made, made
+    summed = [(name, shape) for name, shape in _summed(text) if shape in frozen]
+    assert not summed, summed
 
 
 # the serve cells' configurations (`benchmarks/configs/*-serve-*.json`):

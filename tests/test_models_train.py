@@ -11,6 +11,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from ray_tpu.models import transformer as T
@@ -122,6 +123,95 @@ class TestTrainStep:
         after = state["params"]
         np.testing.assert_array_equal(before["blocks"]["wq"], np.asarray(after["blocks"]["wq"]))
         assert not np.array_equal(before["lora"]["wq_b"], np.asarray(after["lora"]["wq_b"]))
+
+
+def _plain_step(cfg, opt, mesh, num_microbatches=None):
+    """The step as it was before it split its parameters: every leaf
+    differentiated, the whole tree handed to the optimizer and applied.
+    Its ``grad_norm`` is ``optax.global_norm`` of the adapters' gradients
+    (of every leaf's for a dense config)."""
+    attn = S.make_attn_fn(cfg, mesh)
+    pp_mesh = mesh if mesh.shape.get("stage", 1) > 1 else None
+    shardings = S.state_shardings(cfg, opt, mesh)
+
+    def step(state, batch):
+        params = state["params"]
+
+        def lf(p):
+            return T.loss_fn(cfg, p, batch, attn_fn=attn, mesh=pp_mesh,
+                             num_microbatches=num_microbatches)
+
+        (_, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params)
+        updates, new_opt = opt.update(grads, state["opt_state"], params)
+        trained = grads["lora"] if cfg.lora_rank else grads
+        return (dict(state, params=optax.apply_updates(params, updates),
+                     opt_state=new_opt, step=state["step"] + 1),
+                dict(metrics, grad_norm=optax.global_norm(trained)))
+
+    return jax.jit(step, in_shardings=(shardings, None),
+                   out_shardings=(shardings, None))
+
+
+class TestStepDifferentiatesTrainableLeavesOnly:
+    @pytest.mark.parametrize(
+        "spec,step_kw",
+        [(None, {}), (MeshSpec(fsdp=4, tensor=2), {}),
+         (MeshSpec(data=2, stage=2, tensor=2), {"num_microbatches": 4})],
+        ids=["one_device", "fsdp4xtp2", "dp2xpp2xtp2"],
+    )
+    def test_lora_step_is_the_plain_step_on_its_adapters(self, spec, step_kw):
+        """Two steps (the second with B off zero, so A's gradient too):
+        adapters, optimizer state and loss as the step that differentiates
+        every leaf gives them; the frozen base bit for bit as it came in."""
+        cfg = T.config("debug", lora_rank=4)
+        mesh = (build_mesh(spec) if spec is not None
+                else build_mesh(MeshSpec(), [jax.devices()[0]]))
+        opt = S.default_optimizer(cfg, lr=1e-2)
+        state = S.init_state(cfg, opt, mesh)
+        ts = S.make_train_step(cfg, opt, mesh, donate=False, **step_kw)
+        plain = _plain_step(cfg, opt, mesh, **step_kw)
+        adapters = sum(x.size for x in jax.tree.leaves(state["params"]["lora"]))
+        assert ts.differentiated == {
+            "leaves": len(state["params"]["lora"]),
+            "of_leaves": len(jax.tree.leaves(state["params"])),
+            "params": adapters, "of_params": cfg.num_params() + adapters}
+        before = jax.device_get(state["params"])
+        want = state
+        for i in range(2):
+            b = _batch(cfg, seed=i)
+            state, m = ts(state, b)
+            with jax.set_mesh(mesh):
+                want, wm = plain(want, b)
+            np.testing.assert_allclose(m["loss"], wm["loss"], rtol=1e-6)
+            np.testing.assert_allclose(m["grad_norm"], wm["grad_norm"],
+                                       rtol=1e-5)
+        assert int(state["step"]) == 2
+        same = lambda a, b: np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=1e-4, atol=1e-7)
+        jax.tree.map(same, state["params"]["lora"], want["params"]["lora"])
+        jax.tree.map(same, state["opt_state"], want["opt_state"])
+        after = jax.device_get(state["params"])
+        assert not np.array_equal(before["lora"]["wq_a"], after["lora"]["wq_a"])
+        for name in set(before) - {"lora"}:
+            jax.tree.map(np.testing.assert_array_equal,
+                         before[name], after[name])
+
+    def test_dense_step_differentiates_every_leaf_at_the_same_cost(self):
+        cfg = T.config("debug")
+        mesh = build_mesh(MeshSpec(), [jax.devices()[0]])
+        opt = S.default_optimizer(cfg)
+        ts = S.make_train_step(cfg, opt, mesh, donate=False)
+        d = ts.differentiated
+        assert d["params"] == d["of_params"] == cfg.num_params()
+        assert d["leaves"] == d["of_leaves"]
+        state, b = S.init_state(cfg, opt, mesh), _batch(cfg)
+        with jax.set_mesh(mesh):
+            want = _plain_step(cfg, opt, mesh).lower(state, b).compile()
+        got = ts.lower(state, b).compile()
+        assert got.cost_analysis()["flops"] == want.cost_analysis()["flops"]
+        assert (got.memory_analysis().temp_size_in_bytes
+                == want.memory_analysis().temp_size_in_bytes)
 
 
 class TestCheckpoint:
