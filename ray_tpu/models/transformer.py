@@ -15,7 +15,11 @@ Design notes (TPU):
   params kept fp32 by default (master weights), cast per-step.
 - attention = ops.flash_attention (pallas on TPU) or ops.ring_attention
   when the sequence axis is sharded.
-- ``jax.checkpoint`` per block to trade FLOPs for HBM (long context).
+- ``jax.checkpoint`` per block with a policy: beside the block's input the
+  backward keeps the named results of the block's matmuls and of the flash
+  forward kernel (``REMAT_LADDER``) and recomputes only elementwise work
+  (norms, the rotation, the SiLU product); the step builder steps down the
+  ladder, to the bare checkpoint at last, where the device's memory is short.
 - rotary embeddings computed on the fly (no cached tables → no host
   transfers, fuses into the kernel).
 """
@@ -30,12 +34,28 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import flash_attention, gqa_expand
+from ray_tpu.ops.attention import FLASH_KEPT, flash_attention, gqa_expand
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
+# What a block's checkpoint keeps for its backward, by `checkpoint_name`,
+# richest first; `train/step.py::make_train_step` takes the first rung whose
+# compiled step fits the device. q, k and v as `attn_fn` gets them (k and v
+# before `gqa_expand`: the KV heads, not the query heads) and the kernel's
+# `out` and `lse` are what the flash backward reads; `gate` and `up` are what
+# the SiLU product's reads; the residual after attention saves `wo`'s second
+# run, and an adapter's `x @ a` (rank values a token, read by b's gradient)
+# its own. With all of them no matmul and no flash forward kernel runs twice.
+# The sparse MLP names nothing: its experts are recomputed on every rung.
+REMAT_ATTENTION = ("attn_q", "attn_k", "attn_v") + FLASH_KEPT
+REMAT_LADDER = (
+    REMAT_ATTENTION + ("mlp_gate", "mlp_up", "resid_attn", "lora_xa"),
+    REMAT_ATTENTION,
+    (),  # the bare checkpoint: the block's input alone
+)
 HELD_SHARE_CAPPED = 32  # `held_rows_cap`: shares of the outputs under 1 / this
 
 
@@ -687,7 +707,8 @@ def _rope(x, positions, theta):
 
 @jax.named_scope("lora")
 def _lora_delta(x, a, b, scale):
-    return jnp.einsum("bsh,hr->bsr", x, a.astype(x.dtype)) @ b.astype(x.dtype) * scale
+    xa = checkpoint_name(jnp.einsum("bsh,hr->bsr", x, a.astype(x.dtype)), "lora_xa")
+    return xa @ b.astype(x.dtype) * scale
 
 
 def _moe_mlp(cfg: TransformerConfig, y, p):
@@ -959,9 +980,12 @@ def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     q = constrain(q, ("batch", "seq", "heads", None))
+    q, k, v = (checkpoint_name(t, name)
+               for t, name in zip((q, k, v), REMAT_ATTENTION))
     attn = attn_fn(q, k, v)
     attn = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
-    x = x + constrain(attn, ("batch", "seq", "embed"))
+    x = checkpoint_name(x + constrain(attn, ("batch", "seq", "embed")),
+                        "resid_attn")
 
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
     if cfg.num_experts:
@@ -972,6 +996,8 @@ def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
             up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
             if lora_params is not None:
                 gate = gate + _lora_delta(y, lora_params["wi_a"], lora_params["wi_b"], scale)
+            gate = checkpoint_name(gate, "mlp_gate")
+            up = checkpoint_name(up, "mlp_up")
             act = jax.nn.silu(gate) * up
             act = constrain(act, ("batch", "seq", "mlp"))
             out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
@@ -988,13 +1014,16 @@ def _default_attn(cfg: TransformerConfig):
 def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
             positions: Optional[jax.Array] = None,
             attn_fn=None, mesh=None,
-            num_microbatches: Optional[int] = None) -> jax.Array:
+            num_microbatches: Optional[int] = None,
+            remat_kept: Tuple[str, ...] = REMAT_LADDER[0]) -> jax.Array:
     """tokens [B,S] int32 → logits [B,S,V] (compute dtype).
 
     ``attn_fn(q,k,v)->o`` overrides attention — ring_attention for
     sequence parallelism is passed in by the train-step builder.
     ``mesh`` with a "stage" axis > 1 switches the layer stack to
     pipeline parallelism (ops/pipeline.py) with ``num_microbatches``.
+    ``remat_kept``: with ``cfg.remat``, the names each block's checkpoint
+    keeps for the backward, a rung of ``REMAT_LADDER``.
     """
     if cfg.layer_kinds:
         raise ValueError(
@@ -1029,11 +1058,15 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
     if lora is not None:
         layer_tree["l"] = lora
     def _remat(fn):
-        # Full per-block remat: the backward recomputes each block from its
-        # input. Selective policies (saving attention outputs) don't help
-        # here — flash_attention's custom_vjp needs its lse residual, which
-        # only the re-run forward kernel produces.
-        return jax.checkpoint(fn) if cfg.remat else fn
+        # A checkpoint a block: the backward has the block's input and the
+        # results named in `remat_kept`, and recomputes the rest from them.
+        # The flash kernel's `out` and `lse` are named inside its custom_vjp
+        # (`ops/attention.py::_flash_fwd`), so the policy keeps the residuals
+        # themselves and the forward kernel runs once.
+        if not cfg.remat:
+            return fn
+        return jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.save_only_these_names(*remat_kept))
 
     n_stage = mesh.shape.get("stage", 1) if mesh is not None else 1
     if n_stage > 1:
@@ -1064,14 +1097,16 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
 
 def loss_fn(cfg: TransformerConfig, params: Params, batch: Dict[str, jax.Array],
             attn_fn=None, mesh=None,
-            num_microbatches: Optional[int] = None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+            num_microbatches: Optional[int] = None,
+            remat_kept: Tuple[str, ...] = REMAT_LADDER[0]) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Next-token cross-entropy. batch: tokens [B,S], optional loss_mask [B,S].
     Returns (loss, metrics)."""
     tokens = batch["tokens"]
     # Forward over the FULL sequence (sequence-parallel shards must keep
     # S divisible by the mesh axis); shift at the logits instead.
     logits = forward(cfg, params, tokens, attn_fn=attn_fn, mesh=mesh,
-                     num_microbatches=num_microbatches)[:, :-1]
+                     num_microbatches=num_microbatches,
+                     remat_kept=remat_kept)[:, :-1]
     targets = tokens[:, 1:]
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)

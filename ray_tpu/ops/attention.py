@@ -26,6 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
 
@@ -387,11 +388,22 @@ def flash_attention(q, k, v, causal: bool = True,
     return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)[0]
 
 
+# What a `jax.checkpoint` around the caller has to keep for the backward not
+# to run the forward kernel again (`save_only_these_names`). The names are
+# given HERE, to the very values `_flash_bwd` reads: named outside
+# `flash_attention`, the result is another variable than the residual, and
+# the kernel runs a second time for its `lse`.
+FLASH_KEPT = ("flash_out", "flash_lse")
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     if _on_tpu():
         out, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k)
+        out = checkpoint_name(out, FLASH_KEPT[0])
+        lse = checkpoint_name(lse, FLASH_KEPT[1])
         return out, (q, k, v, out, lse)
-    out = blockwise_attention(q, k, v, causal, sm_scale, block_k)
+    out = checkpoint_name(
+        blockwise_attention(q, k, v, causal, sm_scale, block_k), FLASH_KEPT[0])
     return out, (q, k, v, None, None)
 
 

@@ -13,6 +13,9 @@ NamedSharding choices over ONE jitted program (SURVEY.md §2.3):
   ``grad_norm`` metric is the norm of the gradients that exist, the
   trainable leaves',
 - those gradients all-reduced implicitly by GSPMD over the data axes,
+- each block's checkpoint keeps what its backward reads (the named results
+  of its matmuls and of the flash forward kernel), as far down
+  ``REMAT_LADDER`` as the device's memory asks (``run.remat_kept``),
 - sequence axis > 1 switches attention to ring_attention under
   shard_map (exact, comms overlap compute on ICI).
 
@@ -31,7 +34,8 @@ from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.transformer import (
-    TransformerConfig, forward, init_params, loss_fn, param_axes, trainable_mask,
+    REMAT_LADDER, TransformerConfig, forward, init_params, loss_fn, param_axes,
+    trainable_mask,
 )
 from ray_tpu.ops.attention import flash_attention, gqa_expand
 from ray_tpu.ops.ring_attention import ring_attention
@@ -41,6 +45,11 @@ from ray_tpu.parallel.sharding import (
 )
 
 TrainState = Dict[str, Any]
+# Share of the device's `bytes_limit` a compiled step leaves free to be
+# taken: the compiler's count leaves out what the allocator loses between
+# buffers and what else the process holds on the device (a prefetched batch,
+# an evaluation's program).
+REMAT_HEADROOM = 0.05
 
 
 def default_optimizer(cfg: TransformerConfig, lr: float = 3e-4,
@@ -212,6 +221,21 @@ def init_state(cfg: TransformerConfig, optimizer: optax.GradientTransformation,
         return jax.jit(init, out_shardings=shardings)(jax.random.key(seed))
 
 
+def _bytes_limit(mesh: Mesh) -> Optional[int]:
+    """What one of this process's devices of the mesh can hold, or None
+    where the backend does not say (the CPU)."""
+    return (mesh.local_devices[0].memory_stats() or {}).get("bytes_limit")
+
+
+def _step_bytes(compiled) -> int:
+    """A device's bytes while the compiled step runs: arguments, results
+    that are not donated arguments, temporaries, the program."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes)
+
+
 def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformation,
                     mesh: Mesh, rules: Optional[Rules] = None,
                     donate: bool = True,
@@ -223,7 +247,18 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     leaves it as it came in. ``metrics["grad_norm"]`` is the norm of the
     gradients that exist, the trainable leaves': the norm
     ``default_optimizer`` clips by. ``run.differentiated`` counts them
-    (``leaves`` of ``of_leaves``, ``params`` of ``of_params``)."""
+    (``leaves`` of ``of_leaves``, ``params`` of ``of_params``).
+
+    With ``cfg.remat`` each block's checkpoint keeps the names of one rung
+    of ``REMAT_LADDER``, and memory decides which: at the first call,
+    where the device says what it holds, the step is compiled rung by
+    rung, richest first, until one's count (``_step_bytes``) stays under
+    ``bytes_limit`` less ``REMAT_HEADROOM``;
+    the last rung, the bare checkpoint, is taken as it is. The rung that
+    fits is compiled once (the call reuses the executable); a step that
+    falls back pays one more compile a rung. Where no limit can be read the
+    first rung stands. ``run.remat_kept`` is the names taken: ``()`` for
+    the bare checkpoint, None without ``cfg.remat``."""
     rules = _effective_rules(mesh, rules)
     attn = make_attn_fn(cfg, mesh, rules)
     n_stage = mesh_axis_size(mesh, "stage")
@@ -241,7 +276,7 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
         "of_params": sum(n for _, n in sizes),
     }
 
-    def step(state: TrainState, batch: Dict[str, jax.Array]):
+    def step(kept, state: TrainState, batch: Dict[str, jax.Array]):
         params = state["params"]
         trainable = jax.tree.map(lambda m, p: p if m else None, mask, params)
 
@@ -249,7 +284,8 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
             merged = jax.tree.map(lambda m, t, frozen: t if m else frozen,
                                   mask, trainable, params)
             return loss_fn(cfg, merged, batch, attn_fn=attn, mesh=pp_mesh,
-                           num_microbatches=num_microbatches)
+                           num_microbatches=num_microbatches,
+                           remat_kept=kept or ())
 
         (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(trainable)
         gnorm = optax.global_norm(grads)
@@ -277,7 +313,33 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     )
     if donate:
         jit_kwargs["donate_argnums"] = (0,)
-    jitted = jax.jit(step, **jit_kwargs)
+    ladder = REMAT_LADDER if cfg.remat else (None,)
+    settled = len(ladder) == 1
+
+    def rung(kept):
+        return jax.jit(functools.partial(step, kept), **jit_kwargs)
+
+    def fits(jitted, state, batch, limit):
+        try:
+            need = _step_bytes(jitted.lower(state, batch).compile())
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return False  # the compiler itself refused it
+        return need <= (1 - REMAT_HEADROOM) * limit
+
+    def settle(state, batch):
+        """Down the ladder to the first rung that fits the device."""
+        nonlocal settled
+        settled = True
+        limit = _bytes_limit(mesh)
+        if limit is None:
+            return
+        for kept in ladder:
+            if kept != run.remat_kept:
+                run.remat_kept, run._jitted = kept, rung(kept)
+            if kept == ladder[-1] or fits(run._jitted, state, batch, limit):
+                return
 
     def place(batch):
         return {k: jax.device_put(v, b_shard if v.ndim >= 2 else repl)
@@ -285,16 +347,21 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
 
     def run(state, batch):
         with jax.set_mesh(mesh):
-            return jitted(state, place(batch))
+            batch = place(batch)
+            if not settled:
+                settle(state, batch)
+            return run._jitted(state, batch)
 
     def lower(state, batch):
-        """The step lowered for these arguments: ``.as_text()`` shows
+        """The step lowered for these arguments, on the rung it stands on
+        (the first until a call has settled it): ``.as_text()`` shows
         whether the kernels are in it, ``.compile()`` what it costs."""
         with jax.set_mesh(mesh):
-            return jitted.lower(state, place(batch))
+            return run._jitted.lower(state, place(batch))
 
     run.lower = lower
-    run._jitted = jitted
+    run.remat_kept = ladder[0]
+    run._jitted = rung(ladder[0])
     run._shardings = shardings
     run._batch_sharding = b_shard
     run.differentiated = differentiated

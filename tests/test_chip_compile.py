@@ -108,12 +108,36 @@ MESHES = {"one": (MeshSpec(), 1), "four": (MeshSpec(fsdp=2, tensor=2), 4)}
 # temporaries a device of the step that differentiated every leaf (commit
 # 5bf1d99, `benchmarks/rehearse.py`): the frozen stacks' gradients were in it
 PARENT_TEMP_BYTES = {"one": 8.274e9, "four": 10.938e9}
+# and of the step that keeps the block's input alone (the bare checkpoint,
+# `T.REMAT_LADDER[-1]`: commit 52fc031, and this tree's last rung)
+BARE_TEMP_BYTES = {"one": 2.959e9, "four": 2.71e9}
+# What the compiler's final count adds to a step that keeps names, beyond
+# their bytes: its buffer assignment (`--xla_dump_to`) charges the names
+# exactly their bytes (5.652 GB on one chip), the count it reports 1.27 GB
+# more than that on one chip and less than that on four. No array of the
+# program is behind it (PERF.md section 6, PR 46).
+FINAL_COUNT_SLACK_BYTES = 1.35e9
+# how (batch, heads and MLP width) are cut on a device of each mesh
+CUTS = {"one": (1, 1), "four": (2, 2)}
+
+
+def _kept_bytes(cfg, chips, batch):
+    """A device's bytes of what `T.REMAT_LADDER[0]` keeps beside the block's
+    input, all layers, from the shapes: q, k, v, the kernel's out and lse,
+    gate, up, the residual after attention, the adapters' `x @ a`."""
+    by_batch, by_heads = CUTS[chips]
+    rows = batch // by_batch * SEQ
+    heads = 2 * cfg.heads * cfg.hd + 2 * cfg.kv_heads * cfg.hd  # q, out, k, v
+    a_layer = (2 * rows * (heads + 2 * cfg.mlp_hidden) // by_heads
+               + 4 * rows * cfg.heads // by_heads  # lse, float32
+               + 2 * rows * cfg.hidden + 3 * 2 * rows * cfg.lora_rank)
+    return cfg.layers * a_layer
 
 
 @pytest.fixture(scope="module")
 def train_steps(topo):
-    """(chips, widths, lora) -> (compiled step, state shapes, cfg), each
-    compiled once: "flagship" is `WIDTHS` with a step of 8 x 2048, "bench"
+    """(chips, widths, lora) -> (compiled step, state shapes, cfg, the step
+    `make_train_step` returned), each compiled once: "flagship" is `WIDTHS` with a step of 8 x 2048, "bench"
     the train cells' own; `lora=False` is the same widths trained dense."""
     @functools.cache
     def compiled(chips, widths="flagship", lora=True):
@@ -131,7 +155,7 @@ def train_steps(topo):
         tokens = {"tokens": jax.ShapeDtypeStruct(
             (batch, SEQ), jnp.int32, sharding=step._batch_sharding)}
         with jax.set_mesh(mesh):
-            return step._jitted.lower(state, tokens).compile(), state, cfg
+            return step._jitted.lower(state, tokens).compile(), state, cfg, step
 
     return compiled
 
@@ -141,7 +165,7 @@ def _arg_bytes(state):
 
 
 def test_lora_step_compiles_for_one_chip(train_steps):
-    compiled, state, _ = train_steps("one")
+    compiled, state, _, _ = train_steps("one")
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= _arg_bytes(state)
@@ -154,7 +178,7 @@ def test_lora_step_compiles_for_four_chips(train_steps):
     be automatically partitioned"), because the dense path called the
     kernel outside shard_map. Also the first time create_device_mesh meets
     the 7-axis shape on a described 2x2."""
-    compiled, state, _ = train_steps("four")
+    compiled, state, _, _ = train_steps("four")
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text and "all-reduce" in text
@@ -166,15 +190,47 @@ def test_lora_step_compiles_for_four_chips(train_steps):
 
 @pytest.mark.parametrize("chips", ["one", "four"])
 def test_lora_step_executes_no_frozen_gradient(train_steps, chips):
-    """Forward 2N, recomputed forward 2N, the activations' gradient 2N: 6N.
-    The step that also differentiated the frozen base executed 8N, as the
-    dense step of the same widths has to. (The compiler counts a loop's
-    body once: the share is a layer's and the head's, 0.70 here.)"""
-    lora, _, _ = train_steps(chips)
-    dense, _, _ = train_steps(chips, lora=False)
+    """Forward 2N, the activations' gradient 2N: 4N, and no recomputed
+    forward since the checkpoint keeps what the backward reads (PR 46; 6N
+    with the bare checkpoint). The dense step of the same widths executes
+    6N: those and every weight's gradient (8N with the bare checkpoint).
+    The share reads the compiler's operation counts of the two compiled
+    flagship steps (a loop's body counted once: a layer's and the head's):
+    4N / 6N, 0.669 here; 0.70 with the bare checkpoint, 6N / 8N where the
+    head, which no checkpoint wraps, is 4N / 6N."""
+    lora, _, _, _ = train_steps(chips)
+    dense, _, _, _ = train_steps(chips, lora=False)
     share = lora.cost_analysis()["flops"] / dense.cost_analysis()["flops"]
     print(f"{chips}: LoRA step / dense step = {share:.3f} of the operations")
     assert 0.6 < share <= 0.8
+
+
+KEPT_LIMITS = {  # operations (today 1.421e13 / 3.556e12), bytes a device
+    "one": (1.20e13, 14.5e9), "four": (3.0e12, 12.6e9)}
+
+
+@pytest.mark.parametrize("chips", ["one", "four"])
+def test_lora_step_runs_no_matmul_and_no_flash_forward_twice(train_steps, chips):
+    """The train cells' own step (PR 46): every block's checkpoint keeps the
+    richest rung of `T.REMAT_LADDER`, so the compiled step holds ONE flash
+    forward call (two with the bare checkpoint: the backward ran it again
+    for `lse`) beside the two backward kernels, executes a layer's forward
+    once (1.159e13 operations a layer and the head on one chip for
+    1.421e13, 2.900e12 for 3.556e12 on four), and still fits the chip."""
+    compiled, _, _, step = train_steps(chips, "bench")
+    assert step.remat_kept == T.REMAT_LADDER[0]
+    assert set(A.FLASH_KEPT) < set(step.remat_kept)
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_attention_fwd[.\d]* = ", text)) == 1
+    assert text.count("tpu_custom_call") == 3
+    flops, fits = KEPT_LIMITS[chips]
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"{chips}: {compiled.cost_analysis()['flops']:.4e} operations, "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} + "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} = {total / 1e9:.3f} GB")
+    assert compiled.cost_analysis()["flops"] < flops
+    assert total < fits
 
 
 def _summed(text):
@@ -211,15 +267,22 @@ def test_lora_step_keeps_no_frozen_gradient(train_steps, chips):
     frozen stack's shape is computed or written (the stacks are parameters,
     loop state, and the relayout and the gather the forward asks for), and
     no collective sums anything of a frozen leaf's shape."""
-    compiled, state, cfg = train_steps(chips, "bench")
+    compiled, state, cfg, _ = train_steps(chips, "bench")
     mem = compiled.memory_analysis()
     mlp = sum(2 * math.prod(w.sharding.shard_shape(w.shape))
               for w in (state["params"]["blocks"][k]
                         for k in ("wi_gate", "wi_up", "wo_mlp")))
+    kept = _kept_bytes(cfg, chips, batch=4)
     print(f"{chips}: temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB "
-          f"(parent {PARENT_TEMP_BYTES[chips] / 1e9:.3f}), a device's MLP "
-          f"stacks {mlp / 1e9:.3f} GB")
-    assert mem.temp_size_in_bytes < PARENT_TEMP_BYTES[chips] - mlp
+          f"(the bare checkpoint's {BARE_TEMP_BYTES[chips] / 1e9:.3f} + "
+          f"{kept / 1e9:.3f} of kept names), a device's MLP stacks "
+          f"{mlp / 1e9:.3f} GB")
+    # the temporaries are the bare checkpoint's (which held no frozen
+    # gradient: under the older step's by the MLP stacks and more) and the
+    # names the checkpoint keeps since PR 46, counted from their shapes
+    assert BARE_TEMP_BYTES[chips] < PARENT_TEMP_BYTES[chips] - mlp
+    assert mem.temp_size_in_bytes < (BARE_TEMP_BYTES[chips] + kept
+                                     + FINAL_COUNT_SLACK_BYTES)
     # the donated state comes out in the buffers it went in by
     assert mem.alias_size_in_bytes >= 0.999 * mem.argument_size_in_bytes
     frozen = _frozen_shapes(state, cfg)

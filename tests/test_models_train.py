@@ -214,6 +214,165 @@ class TestStepDifferentiatesTrainableLeavesOnly:
                 == want.memory_analysis().temp_size_in_bytes)
 
 
+def _count(jaxpr, primitive="dot_general"):
+    """Equations of one primitive in a jaxpr and every jaxpr inside it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == primitive
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    n += _count(inner, primitive)
+    return n
+
+
+REMAT_CONFIGS = {
+    "lora": dict(lora_rank=4),
+    "dense": dict(),
+    "sparse": dict(num_experts=4, experts_per_token=2),
+}
+
+
+class TestRematKeepsWhatTheBackwardReads:
+    """``remat=True`` checkpoints every block with a policy that keeps the
+    named results of its matmuls and of the flash forward
+    (``T.REMAT_LADDER``): the same numbers as no checkpoint, no matmul run
+    twice, and a fall down the ladder where the device's memory is short."""
+
+    @staticmethod
+    def _one_device():
+        return build_mesh(MeshSpec(), [jax.devices()[0]])
+
+    @pytest.mark.parametrize("kind", sorted(REMAT_CONFIGS))
+    def test_remat_step_is_the_step_without_a_checkpoint(self, kind):
+        """Two steps in float32: loss, gradient norm, the updated leaves and
+        the optimizer's state under the policy as without a checkpoint."""
+        mesh, out = self._one_device(), {}
+        for remat in (True, False):
+            cfg = T.config("debug", remat=remat, dtype=jnp.float32,
+                           **REMAT_CONFIGS[kind])
+            opt = S.default_optimizer(cfg, lr=1e-2)
+            state = S.init_state(cfg, opt, mesh)
+            ts = S.make_train_step(cfg, opt, mesh, donate=False)
+            metrics = []
+            for i in range(2):
+                state, m = ts(state, _batch(cfg, seed=i))
+                metrics.append((float(m["loss"]), float(m["grad_norm"])))
+            assert ts.remat_kept == (T.REMAT_LADDER[0] if remat else None)
+            out[remat] = (metrics, state)
+        (got_m, got), (want_m, want) = out[True], out[False]
+        np.testing.assert_allclose(got_m, want_m, rtol=1e-6)
+        same = lambda a, b: np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=1e-5, atol=1e-7)
+        jax.tree.map(same, got["params"], want["params"])
+        jax.tree.map(same, got["opt_state"], want["opt_state"])
+        moved = got["params"]["lora" if kind == "lora" else "blocks"]
+        fresh = S.init_state(cfg, opt, mesh)["params"]
+        name = "wq_a" if kind == "lora" else "wq"
+        assert not np.array_equal(
+            moved[name], fresh["lora" if kind == "lora" else "blocks"][name])
+
+    @pytest.mark.parametrize(
+        "spec,step_kw",
+        [(MeshSpec(fsdp=4, tensor=2), {}),
+         (MeshSpec(data=2, sequence=2, tensor=2), {}),
+         (MeshSpec(data=2, stage=2, tensor=2), {"num_microbatches": 4})],
+        ids=["fsdp4xtp2", "dp2xsp2xtp2", "dp2xpp2xtp2"],
+    )
+    def test_remat_step_on_a_mesh(self, spec, step_kw):
+        """The policy under the other callers of the checkpoint: flash
+        attention inside `shard_map`, ring attention (q, k, v kept, attention
+        recomputed) and the pipeline's `apply_stage`: a LoRA step's loss and
+        gradient norm as without a checkpoint."""
+        out = {}
+        for remat in (True, False):
+            cfg = T.config("debug", lora_rank=4, remat=remat,
+                           dtype=jnp.float32)
+            mesh = build_mesh(spec)
+            opt = S.default_optimizer(cfg, lr=1e-2)
+            state = S.init_state(cfg, opt, mesh)
+            ts = S.make_train_step(cfg, opt, mesh, donate=False, **step_kw)
+            for i in range(2):
+                state, m = ts(state, _batch(cfg, seed=i))
+            out[remat] = (float(m["loss"]), float(m["grad_norm"]))
+        np.testing.assert_allclose(out[True], out[False], rtol=1e-5)
+
+    @pytest.mark.parametrize("kind", sorted(REMAT_CONFIGS))
+    def test_no_matmul_runs_twice(self, kind):
+        """The gradient's jaxpr: each rung down the ladder holds more
+        ``dot_general`` (the MLP's and `wo`'s, then the projections'); with
+        every name kept a dense-MLP block holds no more than without a
+        checkpoint. `_moe_mlp` names nothing: a sparse block's experts are
+        recomputed on every rung."""
+        b = _batch(T.config("debug"))
+
+        def dots(remat, kept=T.REMAT_LADDER[0]):
+            cfg = T.config("debug", remat=remat, **REMAT_CONFIGS[kind])
+            params = T.init_params(cfg, jax.random.key(0))
+            grad = jax.grad(lambda p: T.loss_fn(cfg, p, b, remat_kept=kept)[0])
+            return _count(jax.make_jaxpr(grad)(params).jaxpr)
+
+        rungs = [dots(True, kept) for kept in T.REMAT_LADDER]
+        print(f"{kind}: dot_general by rung {rungs}, no checkpoint {dots(False)}")
+        assert rungs[0] < rungs[1] < rungs[2]
+        if kind == "sparse":
+            assert rungs[0] > dots(False)
+        else:
+            assert rungs[0] <= dots(False)
+
+    def test_ladder_falls_to_the_rung_that_fits(self, monkeypatch):
+        """A stubbed limit, no chip: the builder compiles rung by rung at
+        its first call and takes the first whose bytes stay under the limit
+        less ``REMAT_HEADROOM``; ``run.remat_kept`` says which; the limit is
+        read once."""
+        cfg = T.config("debug", lora_rank=4, remat=True, layers=4,
+                       dtype=jnp.float32)
+        mesh = self._one_device()
+        opt = S.default_optimizer(cfg)
+        state, b = S.init_state(cfg, opt, mesh), _batch(cfg, s=128)
+        reads = []
+
+        def built(limit):
+            monkeypatch.setattr(
+                S, "_bytes_limit", lambda mesh: reads.append(limit) or limit)
+            ts = S.make_train_step(cfg, opt, mesh, donate=False)
+            assert ts.remat_kept == T.REMAT_LADDER[0]  # until it is called
+            _, m = ts(state, b)
+            ts(state, b)
+            need = S._step_bytes(ts._jitted.lower(state, b).compile())
+            return ts.remat_kept, need, float(m["loss"])
+
+        n0 = len(reads)
+        kept, need0, loss0 = built(None)  # nothing to read: the first rung
+        assert kept == T.REMAT_LADDER[0] and len(reads) == n0 + 1
+        kept, need, loss = built(1 << 60)
+        assert (kept, need, loss) == (T.REMAT_LADDER[0], need0, loss0)
+        # the first rung misses the limit by a byte; the second is smaller
+        kept, need1, loss = built(int((need0 - 1) / (1 - S.REMAT_HEADROOM)))
+        assert kept == T.REMAT_LADDER[1] and need1 < need0
+        np.testing.assert_allclose(loss, loss0, rtol=1e-6)
+        reads_before = len(reads)
+        kept, need2, loss = built(1)  # nothing fits: the bare checkpoint
+        assert kept == () and need2 < need1
+        assert len(reads) == reads_before + 1
+        np.testing.assert_allclose(loss, loss0, rtol=1e-6)
+
+    def test_lower_shows_the_rung_the_step_stands_on(self, monkeypatch):
+        cfg = T.config("debug", lora_rank=4, remat=True, dtype=jnp.float32)
+        mesh = self._one_device()
+        opt = S.default_optimizer(cfg)
+        state, b = S.init_state(cfg, opt, mesh), _batch(cfg)
+        monkeypatch.setattr(S, "_bytes_limit", lambda mesh: 1)
+        ts = S.make_train_step(cfg, opt, mesh, donate=False)
+        dots = lambda: ts.lower(state, b).as_text().count("dot_general")
+        first = dots()  # no call yet: the first rung
+        ts(state, b)
+        assert ts.remat_kept == ()
+        assert dots() > first
+
+
 class TestCheckpoint:
     def test_save_restore_roundtrip(self, tmp_path):
         from ray_tpu.train import restore_state, save_state
