@@ -2,7 +2,9 @@
 
 The reference has no native models (SURVEY.md §2.4 — Train/Serve wrap
 torch/vLLM); here models are in-framework so Train/Serve/bench drive one
-code path.
+code path. One record configures every model (`TransformerConfig`) and one
+table says which module runs it (`families.py`), each family's imported
+where a configuration first asks for it.
 """
 
 from ray_tpu.models.transformer import (

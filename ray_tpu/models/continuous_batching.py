@@ -42,23 +42,25 @@ scheduler never reads. ``PrefillPrograms``, the scheduler's base, is the
 prompt program alone: what a prefill replica constructs
 (``models/disagg_prefill.py``).
 
-A model may keep more than one kind of memory a sequence (a layer pattern):
-``models/laguna.py`` its full layers' rows in the slots and its window
-layers' in a ring of ``window`` rows a slot beside them (``KVCache.ring_k``);
-``models/kimi_linear.py`` a float32 matrix state a head with a convolution
-window (read and rewritten every step) beside one latent row a position.
-A prefill returns all of it (``_row_of``), rows of bucket length and the rest
-as the prompt's TRUE length leaves it, and install writes the whole of each
-into the slot (``_slot_fields`` walks what the cache object holds, whatever
-it holds): nothing of the slot's last occupant stays visible. The decode
-step keeps all of it (donated together), advances active slots alone, and a
-slot given back has its states cleared. Pages hold none of these:
-``PagedBatcher`` refuses such a model.
+A family may keep more than K/V rows a sequence and says what in one
+statement (``TransformerConfig.kept``): rows of ``max_len`` a slot (``k`` /
+``v``, or one ``latent`` row a position), rows in a RING of fewer (``ring_k``
+/ ``ring_v``) and STATES that every step reads and rewrites (``state``: the
+position before; ``mat`` / ``conv``: a float32 matrix a head, a convolution's
+window). The engine walks that statement and names no family: the cache is
+built from it, a prefill returns all of it (``_row_of``), rows of bucket
+length and the rest as the prompt's TRUE length leaves it, install writes the
+whole of each into the slot (``_slot_fields``: nothing of the slot's last
+occupant stays visible) and ``_kv_rows`` sums over its rows. The decode step
+keeps all of it (donated together), advances active slots alone, and a slot
+given back has its states cleared. Pages hold K/V rows and one ``state`` a
+slot: ``PagedBatcher`` refuses a configuration that keeps anything else.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 import queue
 import threading
 import time
@@ -564,32 +566,26 @@ class ContinuousBatcher(PrefillPrograms):
     def _kv_rows(self, lens: np.ndarray):
         """(held, read): the cache rows a decode step
         must read for sequences of `lens` rows (the step's own among them),
-        summed over the layers that keep rows (a window layer's ring holding
-        `window` at most, a latent layer one row a position, a linear-
-        attention layer none); and what the step's attention reads for
+        summed over the layers that keep rows (`cfg.kept`: K and V one row;
+        a ring holding its window at most, a latent layer one row a
+        position, a state none); and what the step's attention reads for
         them: each slot's rows in whole blocks
         (`ops.attention.decode_attention`; a free slot nothing). Before PR
         35 a step read `slots x max_len` a full layer and `slots x window`
         a window layer whatever was held."""
-        cfg = self.cfg
-        itemsize = jnp.dtype(cfg.dtype).itemsize
-
         def in_blocks(rows, t, row_bytes):
             block = decode_block(t, row_bytes)
             return int((-(-rows // block) * block).sum())
 
-        kv_bytes = cfg.kv_heads * cfg.hd * itemsize
-        held = cfg.full_layers * int(lens.sum())
-        read = cfg.full_layers * in_blocks(lens, self.max_len, kv_bytes)
-        if cfg.window_layers:
-            ring = np.minimum(lens, cfg.window)
-            held += cfg.window_layers * int(ring.sum())
-            read += cfg.window_layers * in_blocks(ring, cfg.window, kv_bytes)
-        if "latent" in cfg.keeps:
-            latent = cfg.latent_layers  # by sublayer: two a double layer
-            held += latent * int(lens.sum())
-            read += latent * in_blocks(lens, self.max_len,
-                                       cfg.latent_row * itemsize)
+        itemsize = jnp.dtype(self.cfg.dtype).itemsize
+        held = read = 0
+        for kept in self.cfg.kept(self.max_len):
+            if kept.rows is None:  # a state: no rows
+                continue
+            rows = np.minimum(lens, kept.rows)  # a ring holds its window
+            held += kept.layers * int(rows.sum())
+            read += kept.layers * in_blocks(
+                rows, kept.rows, math.prod(kept.shape) * itemsize)
         return held, read
 
     def _release(self, req: _Request) -> None:

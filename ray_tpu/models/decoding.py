@@ -24,16 +24,14 @@ Left-padding-free: prompts are right-padded, per-sequence lengths track
 the true positions, and attention masks cache slots >= the sequence's
 current length.
 
-A model may keep two kinds of rows. With a layer pattern
-(`TransformerConfig.layer_kinds`, models/laguna.py) the full layers' rows
-are the slots above, [full layers, B, max_len, kvH, D], and the window
-layers' are a RING beside them, `KVCache.ring_k` / `ring_v` [window
-layers, B, window, kvH, D]: position p lives at row p mod window, a decode
-step overwrites the row of the position that has just left the window,
-and a prefill leaves the last `window` positions of the prompt's TRUE
-length. Both ride in the same carry, are written in place and are donated
-together; for every other model the ring is None and no program has an
-operand for it (as `KVCache.state`).
+A family may keep more than these rows, and states what (`TransformerConfig.
+kept`): e.g. window layers keep a RING beside the slots, `KVCache.ring_k` /
+`ring_v` [window layers, B, window, kvH, D]: position p lives at row p mod
+window, a decode step overwrites the row of the position that has just left
+the window, and a prefill leaves the last `window` positions of the prompt's
+TRUE length. All of it rides in the same carry, is written in place and is
+donated together; a field a family does not keep is None and no program has
+an operand for it.
 """
 
 from __future__ import annotations
@@ -46,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import families
 from ray_tpu.models.transformer import (
     TransformerConfig, _lora_delta, _qk_norm, _rms_norm, _rope, moe_dropless,
 )
@@ -84,39 +83,35 @@ class KVCache(NamedTuple):
     latent: Optional[jax.Array] = None
 
 
-# A cache's per-slot fields, [layers, B, ...] each (`TransformerConfig.keeps`
-# says which a configuration has). ROWS are appended a position at a time and
-# masked by `lengths`: a slot's next occupant overwrites them whole. STATES
-# are read and rewritten by every step: they start from zeros.
+# A cache's per-slot fields, [layers, B, ...] each (`TransformerConfig.kept`,
+# its family's statement, says which a configuration has, and their shapes).
+# ROWS are appended a position at a time and masked by `lengths`: a slot's
+# next occupant overwrites them whole. STATES (`Kept.rows` None) are read and
+# rewritten by every step: they start from zeros.
 ROWS = ("k", "v", "ring_k", "ring_v", "latent")
 STATES = ("state", "mat", "conv")
 
 
 def init_state(cfg: TransformerConfig, batch: int, dtype=None) -> dict:
     """{field: zeros} of `KVCache`'s STATES for `batch` new sequences, by
-    what the configuration's layers keep (`cfg.keeps`); {} for a model whose
-    sequences keep nothing but their rows. The shapes are the mixer's own."""
-    if not cfg.stateful:
-        return {}
-    if cfg.layer_kinds:
-        mixer = cfg.pattern_module()
-    else:
-        from ray_tpu.models import zaya as mixer
-    return mixer.init_state(cfg, batch, dtype or cfg.dtype)
+    what the configuration's layers keep (`cfg.kept`); {} for a model whose
+    sequences keep nothing but their rows."""
+    return {name: jnp.zeros((kept.layers, batch, *kept.shape),
+                            kept.dtype or dtype or cfg.dtype)
+            for kept in cfg.kept() if kept.rows is None
+            for name in kept.fields}
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None) -> KVCache:
     dtype = dtype or cfg.dtype
-    shapes = {
-        "k": (cfg.full_layers, batch, max_len, cfg.kv_heads, cfg.hd),
-        "ring_k": (cfg.window_layers, batch, cfg.window, cfg.kv_heads,
-                   cfg.hd),
-        "latent": (cfg.latent_layers, batch, max_len, cfg.latent_row)}
-    shapes.update(v=shapes["k"], ring_v=shapes["ring_k"])
-    rows = {n: jnp.zeros(shapes[n], dtype) for n in cfg.keeps if n in ROWS}
-    for name in ("k", "v"):  # a pattern without full layers: no layer of rows
-        rows.setdefault(name, jnp.zeros((0, *shapes[name][1:]), dtype))
+    rows = {name: jnp.zeros((kept.layers, batch, kept.rows, *kept.shape),
+                            kept.dtype or dtype)
+            for kept in cfg.kept(max_len) if kept.rows is not None
+            for name in kept.fields}
+    for name in ("k", "v"):  # a family without K/V rows: no layer of them
+        rows.setdefault(name, jnp.zeros(
+            (0, batch, max_len, cfg.kv_heads, cfg.hd), dtype))
     return KVCache(lengths=jnp.zeros((batch,), jnp.int32), **rows,
                    **init_state(cfg, batch, dtype))
 
@@ -308,13 +303,11 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
     expert weights are the whole stacks (`layers_to_scan`).
 
     `state` and `route` are None but for the sublayers that carry them
-    (models/zaya.py): the stateful attention's state stack, which it reads
-    and rewrites at `layer` as `row_mask` says, and the router's
-    representation, which goes from this layer to the next."""
+    (the family's, `families.SUBLAYERS`): the stateful attention's state
+    stack, which it reads and rewrites at `layer` as `row_mask` says, and the
+    router's representation, which goes from this layer to the next."""
     if cfg.attention == "cca":
-        from ray_tpu.models import zaya
-
-        x, k_cache, v_cache, state = zaya.attention_cached(
+        x, k_cache, v_cache, state = families.of(cfg).attention_cached(
             cfg, x, p, positions, k_cache, v_cache, state, kv_len_mask,
             row_mask, layer, access, rows)
     else:
@@ -325,10 +318,8 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
     if cfg.num_experts:
         routing, chosen = None, ()
         if cfg.router == "zaya_mlp":
-            from ray_tpu.models import zaya
-
-            routing, route = zaya.router(cfg, y.reshape(-1, y.shape[-1]), p,
-                                         route)
+            routing, route = families.of(cfg).router(
+                cfg, y.reshape(-1, y.shape[-1]), p, route)
             chosen = (routing[1][:, 0],)
         out, load = moe_dropless(cfg, y, p, row_mask, layer, routing)
         return (x + out, k_cache, v_cache, state, route), (load, *chosen)
@@ -388,12 +379,12 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     position's, and a sequence with no real row keeps the state it had.
 
     A layer pattern (`cfg.layer_kinds`) has a loop of its own over what its
-    kinds of layers keep (`laguna.forward_cached`: this carry plus the ring;
-    `kimi_linear.forward_cached`: matrix states, convolution windows and
-    latent rows): a scan over periods.
+    kinds of layers keep (its family's `forward_cached`, `families.PATTERNS`:
+    this carry plus a ring; matrix states, convolution windows and latent
+    rows): a scan over periods.
     """
     if cfg.layer_kinds:
-        return cfg.pattern_module().forward_cached(
+        return families.of(cfg).forward_cached(
             cfg, params, tokens, positions, cache, kv_len_mask, row_mask,
             access, rows)
     x = params["embed"].astype(cfg.dtype)[tokens]
@@ -414,13 +405,20 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     aux = dict(zip(("expert_load", "expert_choice"), counted))
     if aux:
         aux["expert_load"] = aux["expert_load"].sum(0)
+    return (lm_head(cfg, params, x),
+            KVCache(new_k, new_v, cache.lengths, new_state), aux)
+
+
+def lm_head(cfg: TransformerConfig, params, x):
+    """Every cached forward's end: the stream x [B, S, h] through the final
+    norm and the output matrix (the embedding's transpose where they are
+    tied) -> logits [B, S, V]."""
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     with jax.named_scope("lm_head"):
         unembed = params.get("unembed")
         if unembed is None:
             unembed = params["embed"].T
-        logits = jnp.einsum("bsh,hv->bsv", x, unembed.astype(x.dtype))
-    return logits, KVCache(new_k, new_v, cache.lengths, new_state), aux
+        return jnp.einsum("bsh,hv->bsv", x, unembed.astype(x.dtype))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -33,6 +33,7 @@ import numpy as np
 import ray_tpu
 from ray_tpu.experimental.channel import TensorChannel
 from ray_tpu.models.decoding import SamplingParams
+from ray_tpu.models.families import only_kv_rows
 from ray_tpu.models.transformer import TransformerConfig
 
 _TRANSPORT_DTYPE = "float32"  # numpy has no bfloat16; rows are cast
@@ -131,20 +132,10 @@ class DisaggPrefillEngine:
     def __init__(self, cfg: TransformerConfig, params, max_len: int = 256,
                  slots: int = 4, page_size: int = 32,
                  num_cpus: float = 0.5):
-        if cfg.layer_kinds:
-            raise ValueError(
-                f"a layer pattern {cfg.layer_kinds!r} keeps "
-                f"{', '.join(n for n in cfg.keeps if n not in ("k", "v"))} a "
-                "sequence, which the KV channel does not carry and the "
-                "decode replica's pages do not hold: serve it from one "
-                "replica (ContinuousBatcher)")
-        if cfg.stateful:
-            # the channel's row is K and V alone; decoding from it would
-            # start every sequence from a new sequence's state
-            raise ValueError(
-                f"attention {cfg.attention!r} keeps a state beside its K/V "
-                "rows that the KV channel does not carry: serve it from one "
-                "replica (ContinuousBatcher / PagedBatcher)")
+        # the channel's row is K and V alone; decoding from it would start
+        # every sequence from a new sequence's state, ring or rows
+        only_kv_rows(cfg, "the KV channel does not carry them: serve it from "
+                     "one replica (ContinuousBatcher)")
         self.channel = TensorChannel(_row_shape(cfg, max_len),
                                      _TRANSPORT_DTYPE)
         self.prefiller = PrefillReplica.options(num_cpus=num_cpus).remote(

@@ -1,9 +1,9 @@
 """A pattern of delta-rule linear-attention layers beside latent-attention
 layers (moonshotai/Kimi-Linear-48B-A3B-Instruct, `model_type` kimi_linear;
 the recurrence is arXiv:2510.26692). Imported only where a configuration has
-one (`TransformerConfig.pattern_module`); the expert matmuls, sampling, the
-scheduler and the drawing of weights are the other models'
-(`transformer.moe_dropless`, `laguna._draw`).
+one (`families.PATTERNS`); the expert matmuls, sampling, the scheduler and
+the drawing of weights are the other models' (`transformer.moe_dropless`,
+`pattern._draw`).
 
 **Layers.** `cfg.kinds`: a leading "kda" layer with a dense SwiGLU MLP, whole
 periods of `layer_kinds` (kda, kda, mla, kda) and the trailing layers
@@ -49,7 +49,7 @@ queries at a time.
 
 **Experts.** `router`: sigmoid scores over all `num_experts` in float32, the
 top k of score + a stored bias, the weights the scores alone, renormalised,
-times `routed_scale`; then `laguna.sparse_mlp`: `moe_dropless` with
+times `routed_scale`; then `pattern.sparse_mlp`: `moe_dropless` with
 `experts_held`, and the shared expert. What the absent experts would add is left out here, and nothing
 stands in for the other chips or for their exchange.
 """
@@ -62,9 +62,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.decoding import KVCache, _write_stack
-from ray_tpu.models.laguna import (
-    EXPERT_LEAVES, _draw, _swiglu, _take, _tree, sparse_mlp,
+from ray_tpu.models.decoding import KVCache, _write_stack, lm_head
+from ray_tpu.models.families import Kept
+from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
+    EXPERT_LEAVES, _swiglu, _take, init_params, mlp_leaves, num_params,
+    only_the_stack, param_axes, sparse_mlp,
 )
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm
 from ray_tpu.ops import attention as attention_ops
@@ -80,13 +82,51 @@ PREFILL_QUERY_BLOCK = 512
 F32 = jnp.float32
 HI = lax.Precision.HIGHEST
 
+# -- the family (`families.py`) ---------------------------------------------------
+FIELDS = frozenset({
+    "layer_kinds", "lead_kind", "tail_kinds", "kda_conv", "mla_latent",
+    "mla_rope_dim", "mla_q_rank", "mla_rotate", "mla_scales", "router_score",
+    "dense_mlp_hidden", "shared_expert_hidden", "experts_held"})
+
+
+def check(cfg: TransformerConfig) -> None:
+    body = cfg.layers - 1 - len(cfg.tail_kinds)
+    if not cfg.lead_kind or body < 0 or body % len(cfg.layer_kinds):
+        raise ValueError(
+            f"layers {cfg.layers} is not one leading {cfg.lead_kind!r} "
+            f"layer, whole periods of {cfg.layer_kinds!r} and the "
+            f"trailing layers {cfg.tail_kinds!r}")
+    if not (cfg.kda_conv >= 2 and cfg.mla_latent and cfg.mla_rope_dim
+            and cfg.num_experts and cfg.dense_mlp_hidden):
+        raise ValueError("a pattern of kda and mla layers needs kda_conv "
+                         "(taps, >= 2), mla_latent, mla_rope_dim, "
+                         "num_experts and dense_mlp_hidden")
+    if cfg.kv_heads != cfg.heads:
+        raise ValueError("a pattern of kda and mla layers has heads that "
+                         f"are all alike: kv_heads {cfg.kv_heads} is not "
+                         f"heads {cfg.heads}")
+
+
+def kept(cfg: TransformerConfig, max_len: int):
+    """A "kda" layer's matrix states, float32 whatever the stream's dtype,
+    and its convolutions' windows, the `kda_conv - 1` last inputs of q, k
+    and v flat in one row a sequence (positions, then q | k | v, heads,
+    head_dim): a slot is one row of whole lanes, where [taps - 1, 3, heads,
+    D] a slot made the chip's compiler transpose the stack in and out of
+    every step. An "mla" layer's one latent row a position."""
+    kda, nh, d = cfg.layers_of("kda"), cfg.heads, cfg.hd
+    return (Kept(("mat",), kda, None, (nh, d, d), F32),
+            Kept(("conv",), kda, None, ((cfg.kda_conv - 1) * 3 * nh * d,)),
+            Kept(("latent",), cfg.layers_of("mla"), max_len,
+                 (cfg.latent_row,)))
+
 
 # -- parameters --------------------------------------------------------------
 
 def leaves(cfg: TransformerConfig) -> dict:
     """{(group, ..., name): (shape, init, logical axes)} of every parameter
     leaf. `init` is a fan-in (normal over its root), None (a norm's weight:
-    ones), or the name of one of the family's initialisers (`_special`)."""
+    ones), or the name of one of the family's initialisers (`special`)."""
     h, d, nh, taps = cfg.hidden, cfg.hd, cfg.heads, cfg.kda_conv
     out = {("embed",): ((cfg.vocab_size, h), h, ("vocab", "embed")),
            ("unembed",): ((h, cfg.vocab_size), h, ("embed", "vocab")),
@@ -110,32 +150,7 @@ def leaves(cfg: TransformerConfig) -> dict:
     out[at + ("wo",)] = ((n, nh, d, h), nh * d,
                          ("layers", "heads", "head_dim", "embed"))
     out.update(mla_leaves(cfg, (cfg.layers_of("mla"),), ("layers",)))
-    m = cfg.dense_mlp_hidden
-    out[("blocks", "dense", "ln_mlp")] = ((h,), None, ("norm",))
-    out[("blocks", "dense", "wi_gate")] = ((h, m), h, ("embed", "mlp"))
-    out[("blocks", "dense", "wi_up")] = ((h, m), h, ("embed", "mlp"))
-    out[("blocks", "dense", "wo_mlp")] = ((m, h), m, ("mlp", "embed"))
-    n, m, at = cfg.sparse_layers, cfg.mlp_hidden, ("blocks", "sparse")
-    held = cfg.experts_held[1] if cfg.experts_held else cfg.num_experts
-    out[at + ("ln_mlp",)] = ((n, h), None, ("layers", "norm"))
-    out[at + ("router",)] = ((n, h, cfg.num_experts), h,
-                             ("layers", "embed", None))
-    if cfg.router_score == "sigmoid":
-        out[at + ("router_bias",)] = ((n, cfg.num_experts), "router_bias",
-                                      ("layers", None))
-    out[at + ("wi_gate",)] = ((n, held, h, m), h,
-                              ("layers", "expert", "embed", "mlp"))
-    out[at + ("wi_up",)] = ((n, held, h, m), h,
-                            ("layers", "expert", "embed", "mlp"))
-    out[at + ("wo_mlp",)] = ((n, held, m, h), m,
-                             ("layers", "expert", "mlp", "embed"))
-    if cfg.shared_expert_hidden:
-        ms = cfg.shared_expert_hidden
-        out[at + ("shared_gate",)] = ((n, h, ms), h,
-                                      ("layers", "embed", "mlp"))
-        out[at + ("shared_up",)] = ((n, h, ms), h, ("layers", "embed", "mlp"))
-        out[at + ("shared_down",)] = ((n, ms, h), ms,
-                                      ("layers", "mlp", "embed"))
+    out.update(mlp_leaves(cfg))
     return out
 
 
@@ -173,16 +188,7 @@ def mla_leaves(cfg: TransformerConfig, lead: tuple, axes: tuple) -> dict:
     return out
 
 
-def num_params(cfg: TransformerConfig) -> int:
-    """What is HELD here: `experts_held` experts a layer, not `num_experts`."""
-    return sum(math.prod(shape) for shape, _, _ in leaves(cfg).values())
-
-
-def param_axes(cfg: TransformerConfig) -> dict:
-    return _tree({path: axes for path, (_, _, axes) in leaves(cfg).items()})
-
-
-def _special(key, shape, init: str, dtype):
+def special(cfg: TransformerConfig, key, shape, init: str):
     """The leaves a normal draw would leave degenerate, by the family's
     initialisers (the linear-attention library's, as remembered): the taps
     uniform in +-1/sqrt(taps); `a_log` the log of a rate uniform in [1, 16);
@@ -203,33 +209,7 @@ def _special(key, shape, init: str, dtype):
         out = 0.01 * jax.random.normal(key, shape, F32)
     else:
         raise ValueError(f"unknown initialiser {init!r}")
-    return out.astype(dtype)
-
-
-def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
-    out = {}
-    for i, (path, (shape, init, _)) in enumerate(leaves(cfg).items()):
-        k = jax.random.fold_in(key, i)
-        if init is None:
-            out[path] = jnp.ones(shape, cfg.param_dtype)
-        elif isinstance(init, str):
-            out[path] = _special(k, shape, init, cfg.param_dtype)
-        else:
-            out[path] = _draw(k, shape, init, cfg.param_dtype)
-    return _tree(out)
-
-
-def init_state(cfg: TransformerConfig, batch: int, dtype) -> dict:
-    """What `batch` new sequences keep in the "kda" layers: the matrix
-    states, float32 whatever the stream's dtype, and the convolutions'
-    windows, the `kda_conv - 1` last inputs of q, k and v flat in one row a
-    sequence (positions, then q | k | v, heads, head_dim): a slot is one row
-    of whole lanes, where [taps - 1, 3, heads, D] a slot made the chip's
-    compiler transpose the stack in and out of every step."""
-    n, nh, d = cfg.layers_of("kda"), cfg.heads, cfg.hd
-    return {"mat": jnp.zeros((n, batch, nh, d, d), F32),
-            "conv": jnp.zeros((n, batch, (cfg.kda_conv - 1) * 3 * nh * d),
-                              dtype)}
+    return out.astype(cfg.param_dtype)
 
 
 # -- the linear-attention layer -------------------------------------------------
@@ -592,11 +572,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     written in place at [layer of its kind]. `aux` as `laguna.
     forward_cached`'s: "expert_load", "expert_choice" [sparse layers, B*S,
     k], "experts_reached"."""
-    if access is not _write_stack:
-        raise ValueError(
-            f"a layer pattern {cfg.layer_kinds!r} keeps a matrix state a head "
-            "and one latent row a position: no other cache access (pages) "
-            "holds either")
+    only_the_stack(cfg, access)
     blocks = params["blocks"]
     sparse = {n: a for n, a in blocks["sparse"].items()
               if n not in EXPERT_LEAVES}
@@ -656,9 +632,6 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
         choice = jnp.concatenate([choice, c])
     aux = {"expert_load": load, "expert_choice": choice,
            "experts_reached": reached}
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = jnp.einsum("bsh,hv->bsv", x,
-                            params["unembed"].astype(x.dtype))
     mat, conv, latent = held
-    return logits, cache._replace(mat=mat, conv=conv, latent=latent), aux
+    return (lm_head(cfg, params, x),
+            cache._replace(mat=mat, conv=conv, latent=latent), aux)

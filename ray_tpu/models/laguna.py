@@ -1,8 +1,9 @@
 """A layer pattern: window layers beside full ones (poolside/Laguna-S-2.1,
 `model_type` laguna). Imported only where a configuration has one
-(`TransformerConfig.layer_kinds`); the cache's slots, the grouped attention,
-the expert matmuls, sampling and the scheduler are the other models'
-(`decoding._attend_cached` / `_write_stack`, `transformer.moe_dropless`).
+(`families.PATTERNS`); the cache's slots, the grouped attention, the expert
+matmuls, sampling and the scheduler are the other models'
+(`decoding._attend_cached` / `_write_stack`, `transformer.moe_dropless`),
+and what the patterns share is `pattern.py`'s.
 
 **Layers.** One leading layer of full attention with a dense SwiGLU MLP,
 then periods of `layer_kinds` (window, window, window, full), every one with
@@ -56,17 +57,46 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models.decoding import (
-    KVCache, StackLayer, _attend_cached, _write_stack, attend_held,
+    KVCache, StackLayer, _attend_cached, _write_stack, attend_held, lm_head,
 )
-from ray_tpu.models.transformer import (
-    TransformerConfig, _rms_norm, _rope, moe_dropless, moe_router,
+from ray_tpu.models.families import Kept
+from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
+    EXPERT_LEAVES, _swiglu, _take, init_params, mlp_leaves, num_params,
+    only_the_stack, param_axes, sparse_mlp,
 )
+from ray_tpu.models.transformer import TransformerConfig, _rms_norm, _rope
 from ray_tpu.ops.attention import NEG_INF
 
-EXPERT_LEAVES = ("wi_gate", "wi_up", "wo_mlp")
-# A leaf larger than this many elements is drawn a piece at a time
-# (`_draw`): its float32 draw would not fit beside the leaves before it.
-WHOLE_DRAW_MAX = 1 << 28
+# -- the family (`families.py`) ---------------------------------------------------
+FIELDS = frozenset({
+    "layer_kinds", "window", "window_heads", "rope_yarn", "partial_rotary",
+    "head_gate", "dense_mlp_hidden", "shared_expert_hidden", "experts_held"})
+
+
+def check(cfg: TransformerConfig) -> None:
+    if (cfg.layers - 1) % len(cfg.layer_kinds) or cfg.layers < 2:
+        raise ValueError(
+            f"layers {cfg.layers} is not one leading layer and whole "
+            f"periods of {cfg.layer_kinds!r}")
+    if not (cfg.window > 0 and cfg.window_heads and cfg.num_experts
+            and cfg.dense_mlp_hidden):
+        raise ValueError("a pattern of window and full layers needs window, "
+                         "window_heads, num_experts and dense_mlp_hidden")
+    if cfg.window_heads % cfg.kv_heads or cfg.heads % cfg.kv_heads:
+        raise ValueError("both kinds' query heads are whole groups of "
+                         "kv_heads")
+    if cfg.rope_yarn is not None and len(cfg.rope_yarn) != 5:
+        raise ValueError("rope_yarn is (factor, original positions, "
+                         "beta_fast, beta_slow, attention_factor)")
+
+
+def kept(cfg: TransformerConfig, max_len: int):
+    """A full layer's K/V rows in slots of `max_len`, a window layer's in a
+    ring of `window` beside them."""
+    row = (cfg.kv_heads, cfg.hd)
+    return (Kept(("k", "v"), cfg.layers_of("full"), max_len, row),
+            Kept(("ring_k", "ring_v"), cfg.layers_of("window"), cfg.window,
+                 row))
 
 
 # -- parameters --------------------------------------------------------------
@@ -91,81 +121,8 @@ def leaves(cfg: TransformerConfig) -> dict:
         if cfg.head_gate:
             out[at + ("wg",)] = ((n, h, nh), h, ("layers", "embed", "heads"))
         out[at + ("ln_attn",)] = ((n, h), None, ("layers", "norm"))
-    m = cfg.dense_mlp_hidden
-    out[("blocks", "dense", "ln_mlp")] = ((h,), None, ("norm",))
-    out[("blocks", "dense", "wi_gate")] = ((h, m), h, ("embed", "mlp"))
-    out[("blocks", "dense", "wi_up")] = ((h, m), h, ("embed", "mlp"))
-    out[("blocks", "dense", "wo_mlp")] = ((m, h), m, ("mlp", "embed"))
-    n, m, at = cfg.sparse_layers, cfg.mlp_hidden, ("blocks", "sparse")
-    held = cfg.experts_held[1] if cfg.experts_held else cfg.num_experts
-    out[at + ("ln_mlp",)] = ((n, h), None, ("layers", "norm"))
-    out[at + ("router",)] = ((n, h, cfg.num_experts), h,
-                             ("layers", "embed", None))
-    out[at + ("wi_gate",)] = ((n, held, h, m), h,
-                              ("layers", "expert", "embed", "mlp"))
-    out[at + ("wi_up",)] = ((n, held, h, m), h,
-                            ("layers", "expert", "embed", "mlp"))
-    out[at + ("wo_mlp",)] = ((n, held, m, h), m,
-                             ("layers", "expert", "mlp", "embed"))
-    if cfg.shared_expert_hidden:
-        ms = cfg.shared_expert_hidden
-        out[at + ("shared_gate",)] = ((n, h, ms), h,
-                                      ("layers", "embed", "mlp"))
-        out[at + ("shared_up",)] = ((n, h, ms), h, ("layers", "embed", "mlp"))
-        out[at + ("shared_down",)] = ((n, ms, h), ms,
-                                      ("layers", "mlp", "embed"))
+    out.update(mlp_leaves(cfg))
     return out
-
-
-def _tree(flat: dict) -> dict:
-    out: dict = {}
-    for path, value in flat.items():
-        node = out
-        for name in path[:-1]:
-            node = node.setdefault(name, {})
-        node[path[-1]] = value
-    return out
-
-
-def num_params(cfg: TransformerConfig) -> int:
-    """What is HELD here: `experts_held` experts a layer, not `num_experts`."""
-    return sum(math.prod(shape) for shape, _, _ in leaves(cfg).values())
-
-
-def param_axes(cfg: TransformerConfig) -> dict:
-    return _tree({path: axes for path, (_, _, axes) in leaves(cfg).items()})
-
-
-@functools.partial(jax.jit, static_argnames=("shape", "fan_in", "dtype"))
-def _draw(key, shape, fan_in, dtype):
-    """A leaf at its stacked shape, never held twice or whole in float32: a
-    large one is drawn over its leading axes a piece at a time and cast
-    inside (the one block's `stack()` holds a Python list of layers AND
-    their `jnp.stack`, each drawn in float32: 14.2 GB for OLMoE's 7.1)."""
-    lead = 0
-    while math.prod(shape[lead:]) > WHOLE_DRAW_MAX and lead < len(shape) - 1:
-        lead += 1
-
-    def piece(k):
-        return (jax.random.normal(k, shape[lead:], jnp.float32)
-                / math.sqrt(fan_in)).astype(dtype)
-
-    if not lead:
-        return piece(key)
-    keys = jax.random.split(key, math.prod(shape[:lead]))
-    return lax.map(piece, keys).reshape(shape)
-
-
-def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
-    flat = leaves(cfg)
-    out = {}
-    for i, (path, (shape, fan_in, _)) in enumerate(flat.items()):
-        if fan_in is None:
-            out[path] = jnp.ones(shape, cfg.param_dtype)
-        else:
-            out[path] = _draw(jax.random.fold_in(key, i), shape, fan_in,
-                              cfg.param_dtype)
-    return _tree(out)
 
 
 # -- rotary embeddings by kind -------------------------------------------------
@@ -325,40 +282,7 @@ def attention(cfg: TransformerConfig, kind: str, x, p, positions, k_cache,
     return x + out, k_cache, v_cache
 
 
-# -- the MLP halves ---------------------------------------------------------------
-
-def _swiglu(y, gate, up, down):
-    act = jax.nn.silu(jnp.einsum("bsh,hm->bsm", y, gate.astype(y.dtype))) \
-        * jnp.einsum("bsh,hm->bsm", y, up.astype(y.dtype))
-    return jnp.einsum("bsm,mh->bsh", act, down.astype(act.dtype))
-
-
-def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer,
-               router=moe_router):
-    """The expert half of sparse layer `layer`: `p` is that layer's small
-    parameters and the WHOLE expert stacks (`_grouped_matmul` reads its
-    layer in place); `router(cfg, rows, p)` gives `moe_router`'s pair.
-    Returns (x, load [num_experts] from the real rows, the experts every row
-    chose [B*S, k], how many of the experts held here the real rows
-    reached)."""
-    y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
-    routing = router(cfg, y.reshape(-1, y.shape[-1]), p)
-    routed, load = moe_dropless(cfg, y, p, row_mask, layer, routing)
-    x = x + routed
-    if cfg.shared_expert_hidden:
-        with jax.named_scope("moe.shared"):
-            x = x + _swiglu(y, p["shared_gate"], p["shared_up"],
-                            p["shared_down"])
-    first, count = cfg.experts_held or (0, cfg.num_experts)
-    reached = (load[first:first + count] > 0).sum().astype(jnp.int32)
-    return x, load.astype(jnp.int32), routing[1], reached
-
-
-def _take(tree, i):
-    """Layer `i` of a kind's stacked parameters."""
-    return jax.tree.map(
-        lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
-
+# -- the layer loop -------------------------------------------------------------
 
 def forward_cached(cfg: TransformerConfig, params, tokens, positions,
                    cache: KVCache, kv_len_mask, row_mask, access=_write_stack,
@@ -374,11 +298,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     the sets that were taken, as with ZAYA1's one expert);
     "experts_reached": how many experts held here the real rows reached,
     summed over the layers: what a step's grouped matmuls read}."""
-    if access is not _write_stack:
-        raise ValueError(
-            f"a layer pattern {cfg.layer_kinds!r} keeps its full layers' rows "
-            "in slots and its window layers' in a ring: no other cache "
-            "access (pages) holds a ring")
+    only_the_stack(cfg, access)
     blocks, kinds = params["blocks"], cfg.layer_kinds
     sparse = {n: a for n, a in blocks["sparse"].items()
               if n not in EXPERT_LEAVES}
@@ -421,8 +341,5 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     aux = {"expert_load": load.sum(0),
            "expert_choice": choice.reshape(-1, *choice.shape[2:]),
            "experts_reached": reached.sum()}
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = jnp.einsum("bsh,hv->bsv", x,
-                            params["unembed"].astype(x.dtype))
-    return logits, KVCache(k, v, cache.lengths, None, ring_k, ring_v), aux
+    return (lm_head(cfg, params, x),
+            KVCache(k, v, cache.lengths, None, ring_k, ring_v), aux)

@@ -1,10 +1,10 @@
 """A pattern of DOUBLE layers with the expert layer on a shortcut
 (meituan-longcat/LongCat-Flash-Chat, `model_type` longcat_flash). Imported
-only where a configuration has one (`TransformerConfig.pattern_module`, kind
-"scmoe"); the latent attention, the expert matmuls, the router, sampling, the
-scheduler and the drawing of weights are the other models'
+only where a configuration has one (`families.PATTERNS`, kind "scmoe"); the
+latent attention, the expert matmuls, the router, sampling, the scheduler
+and the drawing of weights are the other models'
 (`kimi_linear.mla_attention` / `router`, `transformer.moe_dropless`,
-`laguna._draw`).
+`pattern._draw`).
 
 **A double layer** holds two latent-attention sublayers `A_0`, `A_1`, two
 dense SwiGLU MLPs `D_0`, `D_1` of `dense_mlp_hidden`, four RMSNorms `N_0..N_3`
@@ -42,16 +42,17 @@ work of 0 to k real experts.
 
 from __future__ import annotations
 
-import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.decoding import KVCache, _write_stack
+from ray_tpu.models.decoding import KVCache, _write_stack, lm_head
+from ray_tpu.models.families import Kept
 from ray_tpu.models.kimi_linear import mla_attention, mla_leaves, router
-from ray_tpu.models.laguna import (
-    EXPERT_LEAVES, _draw, _swiglu, _take, _tree,
+from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
+    EXPERT_LEAVES, _swiglu, _take, expert_leaves, init_params, num_params,
+    only_the_stack, param_axes,
 )
 from ray_tpu.models.transformer import (
     TransformerConfig, _rms_norm, moe_dropless, rows_gathered,
@@ -62,6 +63,34 @@ from ray_tpu.models.transformer import (
 # be eight times a score here and choose the same k outputs for every token.
 ROUTER_BIAS_OVER_MEAN = 0.1
 EXPERT_ROWS = 1024  # rows of a prompt a call of the expert layer
+
+# -- the family (`families.py`) ---------------------------------------------------
+FIELDS = frozenset({
+    "layer_kinds", "lead_kind", "mla_latent", "mla_rope_dim", "mla_q_rank",
+    "mla_rotate", "mla_scales", "zero_experts", "dense_mlp_hidden",
+    "experts_held"})
+
+
+def check(cfg: TransformerConfig) -> None:
+    if cfg.layer_kinds != ("scmoe",) or cfg.lead_kind or cfg.layers < 1:
+        raise ValueError(
+            "a pattern of double layers is layer_kinds ('scmoe',) with "
+            "lead_kind '': `layers` of them and nothing else")
+    if not (cfg.mla_latent and cfg.mla_rope_dim and cfg.num_experts
+            and cfg.dense_mlp_hidden) or cfg.mla_rope_dim % 2:
+        raise ValueError("a double layer needs mla_latent, an even "
+                         "mla_rope_dim, num_experts and dense_mlp_hidden")
+    if cfg.zero_experts < 0:
+        raise ValueError("a double layer's router is a softmax over "
+                         "num_experts + zero_experts outputs")
+    if cfg.kv_heads != cfg.heads:
+        raise ValueError("a double layer's heads are all alike: kv_heads "
+                         f"{cfg.kv_heads} is not heads {cfg.heads}")
+
+
+def kept(cfg: TransformerConfig, max_len: int):
+    """One latent row a position and SUBLAYER: two layers a double layer."""
+    return (Kept(("latent",), 2 * cfg.layers, max_len, (cfg.latent_row,)),)
 
 
 # -- parameters --------------------------------------------------------------
@@ -82,43 +111,20 @@ def leaves(cfg: TransformerConfig) -> dict:
     out[at + ("wi_up",)] = ((n, 2, h, m), h, ("layers", None, "embed", "mlp"))
     out[at + ("wo_mlp",)] = ((n, 2, m, h), m,
                              ("layers", None, "mlp", "embed"))
-    m, at = cfg.mlp_hidden, ("blocks", "sparse")
-    held = cfg.experts_held[1] if cfg.experts_held else cfg.num_experts
+    at = ("blocks", "sparse")
     out[at + ("router",)] = ((n, h, cfg.router_outputs), h,
                              ("layers", "embed", None))
     out[at + ("router_bias",)] = ((n, cfg.router_outputs), "router_bias",
                                   ("layers", None))
-    out[at + ("wi_gate",)] = ((n, held, h, m), h,
-                              ("layers", "expert", "embed", "mlp"))
-    out[at + ("wi_up",)] = ((n, held, h, m), h,
-                            ("layers", "expert", "embed", "mlp"))
-    out[at + ("wo_mlp",)] = ((n, held, m, h), m,
-                             ("layers", "expert", "mlp", "embed"))
+    out.update(expert_leaves(cfg, n))
     return out
 
 
-def num_params(cfg: TransformerConfig) -> int:
-    """What is HELD here: `experts_held` experts a layer, not `num_experts`."""
-    return sum(math.prod(shape) for shape, _, _ in leaves(cfg).values())
-
-
-def param_axes(cfg: TransformerConfig) -> dict:
-    return _tree({path: axes for path, (_, _, axes) in leaves(cfg).items()})
-
-
-def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
-    out = {}
-    for i, (path, (shape, init, _)) in enumerate(leaves(cfg).items()):
-        k = jax.random.fold_in(key, i)
-        if init is None:
-            out[path] = jnp.ones(shape, cfg.param_dtype)
-        elif init == "router_bias":
-            out[path] = (ROUTER_BIAS_OVER_MEAN / cfg.router_outputs
-                         * jax.random.normal(k, shape, jnp.float32)
-                         ).astype(cfg.param_dtype)
-        else:
-            out[path] = _draw(k, shape, init, cfg.param_dtype)
-    return _tree(out)
+def special(cfg: TransformerConfig, key, shape, init: str):
+    """The stored selection bias (`ROUTER_BIAS_OVER_MEAN`)."""
+    return (ROUTER_BIAS_OVER_MEAN / cfg.router_outputs
+            * jax.random.normal(key, shape, jnp.float32)
+            ).astype(cfg.param_dtype)
 
 
 # -- the double layer ------------------------------------------------------------
@@ -205,10 +211,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     "experts_reached"), then "routed_most": the most routed experts one real
     row chose in one layer, a row's largest share of real expert work, and
     "rows_gathered": the rows the expert layers gathered, over the layers."""
-    if access is not _write_stack:
-        raise ValueError(
-            "a pattern of double layers keeps one latent row a position and "
-            "sublayer: no other cache access (pages) holds it")
+    only_the_stack(cfg, access)
     blocks = params["blocks"]
     small = {n: a for n, a in blocks["sparse"].items()
              if n not in EXPERT_LEAVES}
@@ -227,8 +230,4 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     aux = {"expert_load": load.sum(0), "expert_choice": choice,
            "experts_reached": reached.sum(), "routed_most": most.max(),
            "rows_gathered": gathered.sum()}
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = jnp.einsum("bsh,hv->bsv", x,
-                            params["unembed"].astype(x.dtype))
-    return logits, cache._replace(latent=latent), aux
+    return lm_head(cfg, params, x), cache._replace(latent=latent), aux

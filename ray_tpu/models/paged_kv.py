@@ -60,6 +60,7 @@ from ray_tpu.models.decoding import (
     init_cache,
     init_state,
 )
+from ray_tpu.models.families import only_kv_rows
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import schema as spans
 from ray_tpu.observability.tracing import device_span
@@ -172,15 +173,11 @@ class PagedBatcher(ContinuousBatcher):
         recompute-preemption absorb the shortfall — vLLM's model);
         ``extra_pages`` adds headroom so freed prefix pages survive
         longer in the cache."""
-        if cfg.layer_kinds:
-            # and with it `submit_prefilled`: a premade row brings no ring
-            raise ValueError(
-                f"a layer pattern {cfg.layer_kinds!r} keeps "
-                f"{', '.join(n for n in cfg.keeps if n not in ("k", "v"))} a "
-                "sequence beside or in place of the slots' rows; pages hold "
-                "no ring, matrix state, convolution window or latent row "
-                "(prefix reuse and preemption would have to rebuild them): "
-                "serve it from ContinuousBatcher")
+        # one `state` a slot rides beside the pages, prompts prefilled whole
+        only_kv_rows(cfg, "pages hold no ring, matrix state, convolution "
+                     "window or latent row (prefix reuse and preemption "
+                     "would have to rebuild them): serve it from "
+                     "ContinuousBatcher", also=("state",))
         if max_len % page_size != 0:
             raise ValueError("max_len must be a multiple of page_size")
         self.page_size = page_size
@@ -200,10 +197,7 @@ class PagedBatcher(ContinuousBatcher):
         replica (disaggregated prefill — reference:
         llm/_internal/serve/engines/vllm/kv_transfer/). ``row_k/row_v``
         are [L, S, kvH, D] with S >= len(tokens)."""
-        if self.cfg.stateful:
-            raise ValueError(
-                f"attention {self.cfg.attention!r} keeps a state beside its "
-                "K/V rows and a premade row brings none: submit the prompt")
+        only_kv_rows(self.cfg, "a premade row brings none: submit the prompt")
         return self._enqueue(_Request(
             list(tokens) or [0], sampling or SamplingParams(), Future(),
             None, kv=_Held(premade_row=(

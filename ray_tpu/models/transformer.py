@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.models import families
+from ray_tpu.models.families import Kept
 from ray_tpu.ops.attention import FLASH_KEPT, flash_attention, gqa_expand
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.parallel.sharding import constrain
@@ -61,8 +63,10 @@ HELD_SHARE_CAPPED = 32  # `held_rows_cap`: shares of the outputs under 1 / this
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
-    """Hyperparameters for the Llama family (reference parity target:
-    the Llama-2-7B LoRA fine-tune from BASELINE.md)."""
+    """Every model's hyperparameters, one flat record. The fields in
+    `COMMON` are every family's; each of the others belongs to the families
+    that declare it (`families.py`: a module's `FIELDS`, `check`, `kept`),
+    and is refused by name where another family's configuration sets it."""
 
     vocab_size: int = 32000
     hidden: int = 4096
@@ -80,10 +84,11 @@ class TransformerConfig:
     remat: bool = True  # jax.checkpoint each block
     lora_rank: int = 0  # 0 = dense training; >0 = LoRA adapters on attn+mlp
     lora_alpha: float = 16.0
-    # Mixture-of-experts (0 = dense MLP). Experts shard over the "expert"
-    # mesh axis (EP); routing is top-k token-choice with capacity drop —
-    # the Mixtral/Switch recipe expressed as dense einsums so GSPMD can
-    # partition on the expert dim (no gather/scatter on the hot path).
+    # Mixture-of-experts (0 = dense MLP): top-k token-choice routing. The
+    # TRAINING forward alone (`_moe_mlp`) drops past `capacity_factor` and
+    # dispatches with dense einsums, so that GSPMD partitions the expert
+    # dim over the "expert" mesh axis; every SERVED sparse model runs
+    # `moe_dropless` (the cached forward): no capacity, nothing dropped.
     num_experts: int = 0
     experts_per_token: int = 2
     capacity_factor: float = 1.25
@@ -181,14 +186,8 @@ class TransformerConfig:
             raise ValueError(f"unknown attention {self.attention!r}")
         if self.router not in ("linear", "zaya_mlp"):
             raise ValueError(f"unknown router {self.router!r}")
-        if self.router == "zaya_mlp" and not (self.num_experts
-                                              and self.router_hidden):
-            raise ValueError("router 'zaya_mlp' needs num_experts and "
-                             "router_hidden")
-        if self.attention == "gqa" and self.partial_rotary != 1.0 \
-                and not self.layer_kinds:
-            raise ValueError("partial_rotary is read by attention 'cca' and "
-                             "by a layer pattern's full layers alone")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score {self.router_score!r}")
         if self.experts_held is not None:
             first, count = self.experts_held
             if not (self.num_experts and 0 <= first and count >= 1
@@ -196,148 +195,21 @@ class TransformerConfig:
                 raise ValueError(
                     f"experts_held {self.experts_held} is no share of "
                     f"num_experts {self.num_experts}")
-        pattern = (self.window, self.window_heads, self.dense_mlp_hidden,
-                   self.head_gate, self.rope_yarn, self.shared_expert_hidden,
-                   self.experts_held, self.tail_kinds, self.kda_conv,
-                   self.mla_latent, self.mla_rope_dim,
-                   self.lead_kind != "full", self.router_score != "softmax")
-        latent = (self.mla_q_rank, self.mla_rotate,
-                  tuple(self.mla_scales) != (1.0, 1.0), self.zero_experts)
-        if not self.layer_kinds:
-            if any(pattern) or any(latent):
-                raise ValueError(
-                    "window, window_heads, dense_mlp_hidden, head_gate, "
-                    "rope_yarn, shared_expert_hidden, experts_held, "
-                    "lead_kind, tail_kinds, kda_conv, mla_latent, "
-                    "mla_rope_dim, router_score, mla_q_rank, mla_rotate, "
-                    "mla_scales and zero_experts belong to a layer pattern "
-                    "(layer_kinds): the one block has none")
-            return
-        kinds = {self.lead_kind, *self.layer_kinds, *self.tail_kinds} - {""}
-        if kinds == {"scmoe"}:
-            self._check_double_pattern()
-            return
-        if not (kinds <= {"kda", "mla"} or kinds <= {"window", "full"}):
+        family = families.of(self)
+        refused = [f.name for f in dataclasses.fields(self)
+                   if f.name not in COMMON and f.name not in family.FIELDS
+                   and getattr(self, f.name) != f.default]
+        if refused:
             raise ValueError(
-                f"unknown layer kinds {sorted(kinds)!r}: a pattern is of "
-                "'window' and 'full' layers (models/laguna.py) or of 'kda' "
-                "and 'mla' layers (models/kimi_linear.py), not of both, or "
-                "of 'scmoe' double layers alone (models/longcat.py)")
-        if self.zero_experts:
-            raise ValueError("zero_experts belong to a pattern of 'scmoe' "
-                             "double layers")
-        if kinds <= {"kda", "mla"}:
-            self._check_linear_pattern()
-            return
-        if any(latent):
-            raise ValueError("mla_q_rank, mla_rotate and mla_scales are a "
-                             "latent-attention layer's ('mla', 'scmoe')")
-        if self.lead_kind != "full" or self.tail_kinds or self.kda_conv \
-                or self.mla_latent or self.mla_rope_dim \
-                or self.router_score != "softmax":
-            raise ValueError(
-                "a pattern of window and full layers leads with a full "
-                "layer, ends on a whole period, and has no kda_conv, "
-                "mla_latent, mla_rope_dim or sigmoid router")
-        if (self.layers - 1) % len(self.layer_kinds) or self.layers < 2:
-            raise ValueError(
-                f"layers {self.layers} is not one leading layer and whole "
-                f"periods of {self.layer_kinds!r}")
-        if not (self.window > 0 and self.window_heads and self.num_experts
-                and self.dense_mlp_hidden):
-            raise ValueError("a layer pattern needs window, window_heads, "
-                             "num_experts and dense_mlp_hidden")
-        if self.window_heads % self.kv_heads or self.heads % self.kv_heads:
-            raise ValueError("both kinds' query heads are whole groups of "
-                             "kv_heads")
-        if self.attention != "gqa" or self.router != "linear" \
-                or self.qk_norm or self.lora_rank or self.tie_embeddings:
-            raise ValueError("a layer pattern's layers are grouped-query "
-                             "attention and a linear router, without QK-norm, "
-                             "adapters or a tied head")
-        if self.rope_yarn is not None and len(self.rope_yarn) != 5:
-            raise ValueError("rope_yarn is (factor, original positions, "
-                             "beta_fast, beta_slow, attention_factor)")
-
-    def _check_linear_pattern(self):
-        """A pattern of "kda" and "mla" layers: what it needs, and what of
-        the other blocks' options it refuses, each by name."""
-        body = self.layers - 1 - len(self.tail_kinds)
-        if not self.lead_kind or body < 0 or body % len(self.layer_kinds):
-            raise ValueError(
-                f"layers {self.layers} is not one leading {self.lead_kind!r} "
-                f"layer, whole periods of {self.layer_kinds!r} and the "
-                f"trailing layers {self.tail_kinds!r}")
-        if not (self.kda_conv >= 2 and self.mla_latent and self.mla_rope_dim
-                and self.num_experts and self.dense_mlp_hidden):
-            raise ValueError("a pattern of kda and mla layers needs kda_conv "
-                             "(taps, >= 2), mla_latent, mla_rope_dim, "
-                             "num_experts and dense_mlp_hidden")
-        if self.router_score not in ("softmax", "sigmoid"):
-            raise ValueError(f"unknown router_score {self.router_score!r}")
-        refused = dict(
-            window=self.window, window_heads=self.window_heads,
-            head_gate=self.head_gate, rope_yarn=self.rope_yarn,
-            qk_norm=self.qk_norm, lora_rank=self.lora_rank,
-            tie_embeddings=self.tie_embeddings,
-            attention=self.attention != "gqa", router=self.router != "linear",
-            partial_rotary=self.partial_rotary != 1.0,
-            kv_heads=self.kv_heads != self.heads)
-        if any(refused.values()):
-            raise ValueError(
-                "a pattern of kda and mla layers has no "
-                f"{', '.join(n for n, v in refused.items() if v)}: its heads "
-                "are all alike, nothing rotates, and its router is linear")
-
-    def _check_double_pattern(self):
-        """A pattern of "scmoe" double layers: what it needs, and what of
-        the other blocks' options it refuses, each by name."""
-        if self.layer_kinds != ("scmoe",) or self.lead_kind \
-                or self.tail_kinds or self.layers < 1:
-            raise ValueError(
-                "a pattern of double layers is layer_kinds ('scmoe',) with "
-                "lead_kind '' and no tail_kinds: `layers` of them and "
-                "nothing else")
-        if not (self.mla_latent and self.mla_rope_dim and self.num_experts
-                and self.dense_mlp_hidden) or self.mla_rope_dim % 2:
-            raise ValueError("a double layer needs mla_latent, an even "
-                             "mla_rope_dim, num_experts and dense_mlp_hidden")
-        if self.router_score != "softmax" or self.zero_experts < 0:
-            raise ValueError("a double layer's router is a softmax over "
-                             "num_experts + zero_experts outputs")
-        refused = dict(
-            window=self.window, window_heads=self.window_heads,
-            head_gate=self.head_gate, rope_yarn=self.rope_yarn,
-            qk_norm=self.qk_norm, lora_rank=self.lora_rank,
-            tie_embeddings=self.tie_embeddings, kda_conv=self.kda_conv,
-            shared_expert_hidden=self.shared_expert_hidden,
-            attention=self.attention != "gqa", router=self.router != "linear",
-            partial_rotary=self.partial_rotary != 1.0,
-            kv_heads=self.kv_heads != self.heads)
-        if any(refused.values()):
-            raise ValueError(
-                "a pattern of double layers has no "
-                f"{', '.join(n for n, v in refused.items() if v)}")
+                f"{', '.join(refused)}: no field of {family.__name__}, the "
+                "family of layers this configuration is (families.py); it "
+                f"reads {', '.join(sorted(family.FIELDS))} beside the common "
+                "fields")
+        family.check(self)
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden // self.heads
-
-    def pattern_module(self):
-        """The module that runs this configuration's layer pattern
-        (parameters stacked by kind, `forward_cached`), imported only where
-        a configuration has one."""
-        if "scmoe" in self.kinds:
-            from ray_tpu.models import longcat
-
-            return longcat
-        if set(self.kinds) & {"kda", "mla"}:
-            from ray_tpu.models import kimi_linear
-
-            return kimi_linear
-        from ray_tpu.models import laguna
-
-        return laguna
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -350,12 +222,6 @@ class TransformerConfig:
     def layers_of(self, kind: str) -> int:
         """How many of a pattern's layers are of `kind`."""
         return self.kinds.count(kind)
-
-    @property
-    def latent_layers(self) -> int:
-        """Layers of `KVCache.latent`: one an "mla" layer, two a "scmoe"
-        double layer (its two attention sublayers), in the layers' order."""
-        return self.layers_of("mla") + 2 * self.layers_of("scmoe")
 
     @property
     def router_outputs(self) -> int:
@@ -371,24 +237,35 @@ class TransformerConfig:
         its logical width; stated so, a copy takes whole rows."""
         return -(-(self.mla_latent + self.mla_rope_dim) // 128) * 128
 
+    def kept(self, max_len: Optional[int] = None) -> Tuple[Kept, ...]:
+        """What this configuration's layers keep a sequence in a cache of
+        `max_len` rows a slot (`max_seq` if None): its family's statement
+        (`families.Kept`), the entries that have a layer. Remembered on the
+        instance (its fields are frozen, and no field): the engine's pump
+        asks every step (`_kv_rows`)."""
+        max_len = max_len or self.max_seq
+        memo = self.__dict__.setdefault("_kept", {})
+        if max_len not in memo:
+            memo[max_len] = tuple(k for k in families.of(self).kept(
+                self, max_len) if k.layers)
+        return memo[max_len]
+
     @property
     def keeps(self) -> Tuple[str, ...]:
         """The per-slot fields of `decoding.KVCache` that this
         configuration's layers keep for a sequence: rows appended a position
         at a time ("k", "v", "ring_k", "ring_v", "latent") and states read
         and rewritten every step ("state", "mat", "conv")."""
-        kinds = set(self.kinds)
-        if kinds & {"kda", "mla", "scmoe"}:
-            return (("mat", "conv") if "kda" in kinds else ()) + (
-                ("latent",) if kinds & {"mla", "scmoe"} else ())
-        return ("k", "v") + (("state",) if self.attention == "cca" else ()) \
-            + (("ring_k", "ring_v") if "window" in kinds else ())
+        return tuple(name for kept in self.kept() for name in kept.fields)
 
     @property
     def stateful(self) -> bool:
         """Whether a sequence keeps more than rows between steps: something
         its layers read and rewrite every step (`KVCache.STATES`)."""
-        return bool(set(self.keeps) & {"state", "mat", "conv"})
+        return any(kept.rows is None for kept in self.kept())
+
+    def _layers_kept(self, field: str) -> int:
+        return sum(k.layers for k in self.kept() if field in k.fields)
 
     @property
     def periods(self) -> int:
@@ -401,14 +278,18 @@ class TransformerConfig:
     def full_layers(self) -> int:
         """Layers whose K/V rows are slots of `max_len` (`KVCache.k`): all
         of them without a pattern; with one, its "full" ones."""
-        if not self.layer_kinds:
-            return self.layers
-        return self.layers_of("full")
+        return self._layers_kept("k")
 
     @property
     def window_layers(self) -> int:
         """Layers whose K/V rows are a ring of `window` (`KVCache.ring_k`)."""
-        return self.layers_of("window")
+        return self._layers_kept("ring_k")
+
+    @property
+    def latent_layers(self) -> int:
+        """Layers of `KVCache.latent`: one an "mla" layer, two a "scmoe"
+        double layer (its two attention sublayers), in the layers' order."""
+        return self._layers_kept("latent")
 
     @property
     def sparse_layers(self) -> int:
@@ -430,7 +311,7 @@ class TransformerConfig:
         h, m, l, v = self.hidden, self.mlp_hidden, self.layers, self.vocab_size
         hd, nh, nkv = self.hd, self.heads, self.kv_heads
         if self.layer_kinds:
-            return self.pattern_module().num_params(self)
+            return families.of(self).num_params(self)
         mlp = 3 * h * m
         if self.num_experts:
             mlp = self.num_experts * 3 * h * m + h * self.num_experts  # + router
@@ -438,50 +319,72 @@ class TransformerConfig:
         if self.qk_norm:
             per_layer += nh * hd + nkv * hd
         if self.attention == "cca" or self.router == "zaya_mlp":
-            from ray_tpu.models import zaya
-
-            per_layer += zaya.extra_params(self)
+            per_layer += families.of(self).extra_params(self)
         emb = v * h * (1 if self.tie_embeddings else 2)
         return l * per_layer + emb + h
 
 
-# Presets. llama2_7b mirrors the reference north-star target
+# The fields every family reads, or that no check has ever policed (the last
+# five: set where nothing reads them, they change nothing). Every other field
+# is the families' that declare it in their `FIELDS`.
+COMMON = frozenset({
+    "vocab_size", "hidden", "mlp_hidden", "layers", "heads", "kv_heads",
+    "head_dim", "max_seq", "rope_theta", "norm_eps", "dtype", "param_dtype",
+    "remat", "num_experts", "experts_per_token", "norm_topk_prob",
+    "lora_alpha", "capacity_factor", "router_hidden", "window_rope_theta",
+    "routed_scale"})
+
+# -- the one block as a family (`families.py`): dense, Mixtral, OLMoE ---------
+FIELDS = frozenset({"tie_embeddings", "lora_rank", "qk_norm"})
+
+
+def check(cfg: TransformerConfig) -> None:
+    """The one block needs nothing that its fields' types do not say."""
+
+
+def kept(cfg: TransformerConfig, max_len: int) -> Tuple[Kept, ...]:
+    """Every layer's K/V rows, `max_len` a slot."""
+    return (Kept(("k", "v"), cfg.layers, max_len, (cfg.kv_heads, cfg.hd)),)
+
+
+# Presets: name -> field values; `config` constructs (the family's module
+# checks, imported then and not with this one). llama2_7b mirrors the target
 # (BASELINE.md "Train Llama-2-7B LoRA ... v5e-64").
 PRESETS: Dict[str, TransformerConfig] = {
-    "debug": TransformerConfig(
+    "debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=352, layers=2, heads=4,
         kv_heads=2, max_seq=128, remat=False,
     ),
-    "tiny": TransformerConfig(
+    "tiny": dict(
         vocab_size=2048, hidden=256, mlp_hidden=704, layers=4, heads=8,
         kv_heads=4, max_seq=512,
     ),
-    "llama2_7b": TransformerConfig(),
-    "llama2_7b_lora": TransformerConfig(lora_rank=16),
-    "llama3_8b": TransformerConfig(
+    "llama2_7b": dict(),
+    "llama2_7b_lora": dict(lora_rank=16),
+    "llama3_8b": dict(
         vocab_size=128256, hidden=4096, mlp_hidden=14336, layers=32,
         heads=32, kv_heads=8, max_seq=8192, rope_theta=500000.0,
     ),
     # Mixtral-8x7B-shaped MoE (EP flagship)
-    "mixtral_8x7b": TransformerConfig(
+    "mixtral_8x7b": dict(
         vocab_size=32000, hidden=4096, mlp_hidden=14336, layers=32,
         heads=32, kv_heads=8, max_seq=8192, rope_theta=1e6,
         num_experts=8, experts_per_token=2,
     ),
-    "moe_debug": TransformerConfig(
+    "moe_debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=256, layers=2, heads=4,
         kv_heads=2, max_seq=128, remat=False, num_experts=4,
         experts_per_token=2,
     ),
     # allenai/OLMoE-1B-7B-0125-Instruct: MHA with QK-norm, 64 dropless
     # SwiGLU experts of width 1024, top-8 without renormalisation
-    "olmoe_1b_7b": TransformerConfig(
+    "olmoe_1b_7b": dict(
         vocab_size=50304, hidden=2048, mlp_hidden=1024, layers=16, heads=16,
         kv_heads=16, max_seq=4096, rope_theta=1e4, norm_eps=1e-5,
         num_experts=64, experts_per_token=8, norm_topk_prob=False,
         qk_norm=True,
     ),
-    "olmoe_debug": TransformerConfig(
+    "olmoe_debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=64, layers=2, heads=4,
         kv_heads=4, max_seq=128, remat=False, num_experts=16,
         experts_per_token=4, norm_topk_prob=False, qk_norm=True,
@@ -490,14 +393,14 @@ PRESETS: Dict[str, TransformerConfig] = {
     # Zyphra/ZAYA1-8B (models/zaya.py): attention in a compressed latent
     # with a convolution state, a router that carries its representation
     # from layer to layer, top-1 of 16 wide experts, a tied 262k vocabulary
-    "zaya1_8b": TransformerConfig(
+    "zaya1_8b": dict(
         vocab_size=262272, hidden=2048, mlp_hidden=2048, layers=40, heads=8,
         kv_heads=2, head_dim=128, max_seq=131072, rope_theta=5e6,
         norm_eps=1e-5, tie_embeddings=True, num_experts=16,
         experts_per_token=1, norm_topk_prob=False, attention="cca",
         router="zaya_mlp", router_hidden=256, partial_rotary=0.5,
     ),
-    "zaya_debug": TransformerConfig(
+    "zaya_debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=64, layers=3, heads=4,
         kv_heads=2, head_dim=16, max_seq=128, remat=False,
         tie_embeddings=True, num_experts=8, experts_per_token=1,
@@ -510,7 +413,7 @@ PRESETS: Dict[str, TransformerConfig] = {
     # top-4 of 16 experts times 2.5 of which 8 are held, a shared expert.
     # The published widths are the benchmark's to build from `config.json`
     # (benchmarks/runners/serve_laguna.py)
-    "laguna_debug": TransformerConfig(
+    "laguna_debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=64, layers=9, heads=4,
         kv_heads=2, head_dim=16, max_seq=128, remat=False, rope_theta=5e5,
         norm_eps=1e-6, num_experts=16, experts_per_token=4,
@@ -527,7 +430,7 @@ PRESETS: Dict[str, TransformerConfig] = {
     # 16, a latent of 32 + 8, sigmoid top-4 of 16 experts times 2.446 of
     # which 8 are held, a shared expert. The published widths are the
     # benchmark's to build (benchmarks/runners/serve_kimi_linear.py)
-    "kimi_linear_debug": TransformerConfig(
+    "kimi_linear_debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=64, layers=11, heads=4,
         kv_heads=4, head_dim=16, max_seq=128, remat=False, norm_eps=1e-5,
         num_experts=16, experts_per_token=4, norm_topk_prob=True,
@@ -543,7 +446,7 @@ PRESETS: Dict[str, TransformerConfig] = {
     # held) + 8 zero-compute outputs, not renormalised, times 6. The
     # published widths are the benchmark's to build
     # (benchmarks/runners/serve_longcat.py)
-    "longcat_debug": TransformerConfig(
+    "longcat_debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=64, layers=3, heads=4,
         kv_heads=4, head_dim=16, max_seq=128, remat=False, rope_theta=1e7,
         norm_eps=1e-5, num_experts=16, experts_per_token=4,
@@ -557,8 +460,10 @@ PRESETS: Dict[str, TransformerConfig] = {
 
 
 def config(name_or_cfg, **overrides) -> TransformerConfig:
-    cfg = PRESETS[name_or_cfg] if isinstance(name_or_cfg, str) else name_or_cfg
-    return dataclasses.replace(cfg, **overrides) if overrides else cfg
+    if isinstance(name_or_cfg, str):
+        return TransformerConfig(**{**PRESETS[name_or_cfg], **overrides})
+    return dataclasses.replace(name_or_cfg, **overrides) if overrides \
+        else name_or_cfg
 
 
 # ---------------------------------------------------------------------------
@@ -575,7 +480,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     while-loop body compiled once, not ``layers`` inlined copies (compile
     time and HBM win on TPU)."""
     if cfg.layer_kinds:  # stacked by kind, never held twice
-        return cfg.pattern_module().init_params(cfg, key)
+        return families.of(cfg).init_params(cfg, key)
     h, m, v, l = cfg.hidden, cfg.mlp_hidden, cfg.vocab_size, cfg.layers
     hd, nh, nkv = cfg.hd, cfg.heads, cfg.kv_heads
     pd = cfg.param_dtype
@@ -597,9 +502,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         blocks["ln_q"] = jnp.ones((l, nh * hd), pd)
         blocks["ln_k"] = jnp.ones((l, nkv * hd), pd)
     if cfg.attention == "cca" or cfg.router == "zaya_mlp":
-        from ray_tpu.models import zaya
-
-        zaya.init_block_params(cfg, blocks, stack, jax.random.fold_in(key, 13))
+        families.of(cfg).init_block_params(cfg, blocks, stack,
+                                           jax.random.fold_in(key, 13))
     if cfg.num_experts:
         e = cfg.num_experts
         if cfg.router == "linear":
@@ -636,7 +540,7 @@ def param_axes(cfg: TransformerConfig) -> Params:
     """Pytree of logical-axis tuples mirroring init_params output.
     Feed to parallel.sharding.tree_shardings(mesh, ...) for NamedShardings."""
     if cfg.layer_kinds:
-        return cfg.pattern_module().param_axes(cfg)
+        return families.of(cfg).param_axes(cfg)
     block_axes: Params = {
         "wq": ("layers", "embed", "heads", "head_dim"),
         "wk": ("layers", "embed", "kv_heads", "head_dim"),
@@ -649,9 +553,7 @@ def param_axes(cfg: TransformerConfig) -> Params:
         block_axes.update({"ln_q": ("layers", "heads"),
                            "ln_k": ("layers", "kv_heads")})
     if cfg.attention == "cca" or cfg.router == "zaya_mlp":
-        from ray_tpu.models import zaya
-
-        zaya.update_block_axes(cfg, block_axes)
+        families.of(cfg).update_block_axes(cfg, block_axes)
     if cfg.num_experts:
         if cfg.router == "linear":  # the router stays replicated
             block_axes["router"] = ("layers", "embed", None)
@@ -1025,17 +927,13 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
     ``remat_kept``: with ``cfg.remat``, the names each block's checkpoint
     keeps for the backward, a rung of ``REMAT_LADDER``.
     """
-    if cfg.layer_kinds:
+    if families.of(cfg).__name__ != __name__:
         raise ValueError(
             f"a layer pattern {cfg.layer_kinds!r} (layers of several kinds, "
-            "parameters stacked by kind) runs in the cached forward "
-            "alone (decoding.forward_cached): the training forward scans one "
-            "kind of block")
-    if cfg.stateful or cfg.router != "linear":
-        raise ValueError(
-            f"attention {cfg.attention!r} / router {cfg.router!r} run in the "
-            "cached forward alone (decoding.forward_cached): the training "
-            "forward has no such sublayer")
+            f"parameters stacked by kind), attention {cfg.attention!r} and "
+            f"router {cfg.router!r} run in the cached forward alone "
+            "(decoding.forward_cached): the training forward scans the one "
+            "block and has no such sublayer")
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
     attn_fn = attn_fn or _default_attn(cfg)

@@ -1,7 +1,7 @@
 """ZAYA1's two sublayers (Zyphra/ZAYA1-8B, `model_type` zaya): compressed
 convolutional attention and the router that carries its representation from
 layer to layer. Imported only where a configuration asks for them
-(`TransformerConfig.attention == "cca"`, `.router == "zaya_mlp"`); the block
+(`families.SUBLAYERS`: `attention == "cca"`, `router == "zaya_mlp"`); the block
 around them, the cache, the expert matmuls and the layer loop are the other
 models' (`decoding.forward_cached`, `transformer.moe_dropless`).
 
@@ -46,8 +46,35 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import transformer
 from ray_tpu.models.decoding import attend_held
+from ray_tpu.models.families import Kept
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm, _rope
+
+# -- the family (`families.py`): the one block's fields, and the sublayers' ---------
+FIELDS = transformer.FIELDS | {"attention", "router", "partial_rotary"}
+
+
+def check(cfg: TransformerConfig) -> None:
+    if cfg.router == "zaya_mlp" and not (cfg.num_experts
+                                         and cfg.router_hidden):
+        raise ValueError("router 'zaya_mlp' needs num_experts and "
+                         "router_hidden")
+    if cfg.attention != "cca" and cfg.partial_rotary != 1.0:
+        raise ValueError("partial_rotary is read by attention 'cca' alone")
+
+
+def kept(cfg: TransformerConfig, max_len: int):
+    """The one block's K/V rows, and beside them what "cca" reads of the
+    position before (`state_heads`)."""
+    rows = transformer.kept(cfg, max_len)
+    if cfg.attention != "cca":
+        return rows
+    if cfg.kv_heads % 2:
+        raise ValueError("attention 'cca' shifts half of the KV heads: "
+                         f"kv_heads {cfg.kv_heads} is odd")
+    return rows + (Kept(("state",), cfg.layers, None,
+                        (state_heads(cfg), cfg.hd)),)
 
 
 def conv_heads(cfg: TransformerConfig) -> int:
@@ -58,14 +85,6 @@ def conv_heads(cfg: TransformerConfig) -> int:
 def state_heads(cfg: TransformerConfig) -> int:
     """Heads of head_dim a sequence keeps per layer: c, u, the shifted v."""
     return 2 * conv_heads(cfg) + cfg.kv_heads // 2
-
-
-def init_state(cfg: TransformerConfig, batch: int, dtype):
-    if cfg.kv_heads % 2:
-        raise ValueError("attention 'cca' shifts half of the KV heads: "
-                         f"kv_heads {cfg.kv_heads} is odd")
-    return {"state": jnp.zeros(
-        (cfg.layers, batch, state_heads(cfg), cfg.hd), dtype)}
 
 
 def extra_params(cfg: TransformerConfig) -> int:

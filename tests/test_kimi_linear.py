@@ -393,18 +393,18 @@ def test_the_reference_without_a_part_is_another_model(params, change):
 
 
 @pytest.mark.parametrize("change, says", [
-    (dict(layer_kinds=("kda", "full")), "not of both"),
+    (dict(layer_kinds=("kda", "full")), "unknown layer kinds"),
     (dict(layer_kinds=("kda", "ssm")), "unknown layer kinds"),
     (dict(layers=10), "whole periods"),
     (dict(kda_conv=0), "needs kda_conv"),
     (dict(mla_latent=0), "needs kda_conv"),
-    (dict(window=8), "has no window"),
-    (dict(kv_heads=2), "has no kv_heads"),
-    (dict(tie_embeddings=True), "has no tie_embeddings"),
+    (dict(window=8), "window: no field of .*kimi_linear"),
+    (dict(kv_heads=2), "all alike: kv_heads 2"),
+    (dict(tie_embeddings=True), "tie_embeddings: no field of"),
     (dict(router_score="tanh"), "unknown router_score"),
     (dict(experts_held=(12, 8)), "no share of num_experts"),
     (dict(layer_kinds=(), lead_kind="full", tail_kinds=()),
-     "belong to a layer pattern"),
+     "kda_conv, mla_latent.*no field of .*transformer"),
 ])
 def test_the_configuration_is_validated(change, says):
     with pytest.raises(ValueError, match=says):
@@ -418,7 +418,7 @@ def test_each_refusal_names_what_it_refuses(params):
     assert CFG.stateful and CFG.keeps == ("mat", "conv", "latent")
     with pytest.raises(ValueError, match="mat, conv, latent.*pages hold no"):
         PagedBatcher(CFG, params, max_len=64, slots=2, page_size=16)
-    with pytest.raises(ValueError, match="layer pattern.*KV channel"):
+    with pytest.raises(ValueError, match="mat, conv, latent.*KV channel"):
         DisaggPrefillEngine(CFG, params, max_len=64)
     with pytest.raises(ValueError, match="layer pattern.*cached forward"):
         T.forward(CFG, params, jnp.zeros((1, 8), jnp.int32))
@@ -428,5 +428,5 @@ def test_each_refusal_names_what_it_refuses(params):
             CFG, params, jnp.zeros((1, 1), jnp.int32),
             jnp.zeros((1, 1), jnp.int32), cache, jnp.ones((1, 16), bool),
             jnp.ones((1, 1), bool), access=lambda layer: None)
-    with pytest.raises(ValueError, match="a pattern of window and full"):
+    with pytest.raises(ValueError, match="router_score: no field of .*laguna"):
         dataclasses.replace(T.config("laguna_debug"), router_score="sigmoid")

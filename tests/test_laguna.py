@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from benchmarks import reference_laguna as R
-from ray_tpu.models import decoding, laguna
+from ray_tpu.models import decoding, laguna, pattern
 from ray_tpu.models import transformer as T
 from ray_tpu.models.continuous_batching import ContinuousBatcher
 from ray_tpu.models.decoding import SamplingParams
@@ -86,17 +86,17 @@ def test_a_large_leaf_is_drawn_in_pieces_and_never_in_float32(monkeypatch):
     axes, cast inside: the program holds no float32 array of the leaf's
     size."""
     shape = (4, 8, 32, 16)
-    monkeypatch.setattr(laguna, "WHOLE_DRAW_MAX", 32 * 16)
-    laguna._draw.clear_cache()
-    text = laguna._draw.lower(jax.random.key(0), shape=shape, fan_in=32,
+    monkeypatch.setattr(pattern, "WHOLE_DRAW_MAX", 32 * 16)
+    pattern._draw.clear_cache()
+    text = pattern._draw.lower(jax.random.key(0), shape=shape, fan_in=32,
                               dtype=jnp.bfloat16).as_text()
     assert "while" in text and "f32[4,8,32,16]" not in text.replace(" ", "")
-    leaf = laguna._draw(jax.random.key(0), shape=shape, fan_in=32,
+    leaf = pattern._draw(jax.random.key(0), shape=shape, fan_in=32,
                         dtype=jnp.bfloat16)
     assert leaf.shape == shape and leaf.dtype == jnp.bfloat16
     spread = float(jnp.std(leaf.astype(jnp.float32)))
     assert abs(spread - 32 ** -0.5) < 0.01
-    laguna._draw.clear_cache()
+    pattern._draw.clear_cache()
 
 
 @pytest.mark.parametrize("s", [5, 8, 16, 21, 40])
@@ -363,9 +363,9 @@ def test_each_refusal_names_the_pattern(params):
     from ray_tpu.models.disagg_prefill import DisaggPrefillEngine
     from ray_tpu.models.paged_kv import PagedBatcher
 
-    with pytest.raises(ValueError, match="layer pattern.*pages hold no ring"):
+    with pytest.raises(ValueError, match="ring_k, ring_v.*pages hold no ring"):
         PagedBatcher(CFG, params, max_len=64, slots=2, page_size=16)
-    with pytest.raises(ValueError, match="layer pattern.*KV channel"):
+    with pytest.raises(ValueError, match="ring_k, ring_v.*KV channel"):
         DisaggPrefillEngine(CFG, params, max_len=64)
     with pytest.raises(ValueError, match="layer pattern.*cached forward"):
         T.forward(CFG, params, jnp.zeros((1, 8), jnp.int32))
@@ -383,9 +383,10 @@ def test_each_refusal_names_the_pattern(params):
     (dict(window=0), "needs window"),
     (dict(window_heads=5), "whole groups of kv_heads"),
     (dict(experts_held=(12, 8)), "no share of num_experts"),
-    (dict(qk_norm=True), "without QK-norm"),
+    (dict(qk_norm=True), "qk_norm: no field of .*laguna"),
     (dict(rope_yarn=(4.0, 16.0)), "rope_yarn is"),
-    (dict(layer_kinds=(), partial_rotary=1.0), "belong to a layer pattern"),
+    (dict(layer_kinds=(), partial_rotary=1.0),
+     "window, window_heads.*no field of .*transformer"),
 ])
 def test_the_configuration_is_validated(change, says):
     with pytest.raises(ValueError, match=says):
