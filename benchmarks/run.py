@@ -20,6 +20,12 @@ in a child process tree of its own session, waits until that tree has ended
 TPU chips the child fails, and this command exits non-zero and prints no
 result. ``--toy`` runs the same code at debug widths on whatever device JAX
 finds; it prints that device (``cpu``) and is never a result.
+
+The benchmark never touches a run that can still end well: a run is lost
+only to the program or to ``CHILD_LIMIT_S``. A hang shows where it hangs
+once, ``DUMP_BEFORE_S`` under that limit, in a file of the cell's
+``.bench_out`` directory, and the parent copies its end to stderr once the
+run is lost.
 """
 
 from __future__ import annotations
@@ -41,14 +47,28 @@ sys.path.insert(0, ROOT)
 from benchmarks import harness  # noqa: E402
 
 CHILD_LIMIT_S = 1150  # the contract allows a compiling run 1200 s
+DUMP_BEFORE_S = 10  # a run this near its limit is beyond saving
+DUMP_FILE = "hang_dump.txt"  # in the cell's out_dir
 
 
 def child(args) -> int:
     """Run the cell in this process (tree); write the record to a file."""
     import faulthandler
 
-    # a hang shows where it hangs before the parent's limit ends the tree
-    faulthandler.dump_traceback_later(300, repeat=True)
+    # a hang shows where it hangs: once, when the parent's limit is about to
+    # end the tree anyway, and in a file. A dump stops every thread of this
+    # process (the load generator and the proxy) while it is written, so
+    # none may fire in a run that still ends (PR 51)
+    with open(os.path.join(args.out_dir, DUMP_FILE), "w") as dump:
+        faulthandler.dump_traceback_later(args.limit_s - DUMP_BEFORE_S,
+                                          file=dump)
+        try:
+            return _run_cell(args)
+        finally:
+            faulthandler.cancel_dump_traceback_later()
+
+
+def _run_cell(args) -> int:
     cell = harness.load_cell(args.workload, toy=args.toy)
     runner = harness.load_module("runners", cell["config"]["runner"])
     run_args = {"seed": args.seed, "seconds": args.seconds,
@@ -125,6 +145,27 @@ def _end_tree(sid: int, grace_s: float) -> list:
     return left
 
 
+def _how_it_failed(returncode: int, timed_out: bool, limit_s: float,
+                   dump_path: str) -> str:
+    """The last words of a lost run: a child that ended on a signal is told
+    from one that exited, and a run ended at the limit says where it hung."""
+    if returncode < 0:
+        ended = (f"the child was killed by signal {-returncode} "
+                 f"({signal.strsignal(-returncode)})")
+    else:
+        ended = f"the child exited with code {returncode}"
+    if not timed_out:
+        return f"run failed: {ended}; no result"
+    if os.path.exists(dump_path):
+        with open(dump_path, errors="replace") as f:
+            where = (f"every thread's frames {DUMP_BEFORE_S} s before are in "
+                     f"{dump_path}, which ends:\n{f.read()[-1500:]}")
+    else:
+        where = f"it left no {dump_path}"
+    return (f"{where}\nrun failed: timed out at the limit of {limit_s:g} s "
+            f"and was ended ({ended}); no result")
+
+
 def parent(args) -> int:
     if not os.path.isdir(os.path.join(ROOT, "ray_tpu")):
         print("the program (ray_tpu/) is not beside benchmarks/: nothing to "
@@ -139,6 +180,7 @@ def parent(args) -> int:
            "--workload", args.workload, "--seed", str(args.seed),
            "--seconds", str(args.seconds), "--trace", str(args.trace),
            "--t0-wall", repr(T0_WALL), "--out-dir", out_dir,
+           "--limit-s", repr(args.limit_s),
            "--sample-to", args.sample_to] + (["--toy"] if args.toy else [])
     env = dict(os.environ, PYTHONPATH=os.pathsep.join(
         p for p in (ROOT, os.environ.get("PYTHONPATH")) if p))
@@ -162,7 +204,7 @@ def parent(args) -> int:
     signal.signal(signal.SIGINT, on_signal)
     timed_out = False
     try:
-        proc.wait(timeout=CHILD_LIMIT_S)
+        proc.wait(timeout=args.limit_s)
     except subprocess.TimeoutExpired:
         timed_out = True
         os.killpg(proc.pid, signal.SIGTERM)
@@ -172,9 +214,12 @@ def parent(args) -> int:
             pass
     leftover = _end_tree(proc.pid, grace_s=0 if timed_out else 30)
     proc.wait()
+    dump_path = os.path.join(out_dir, DUMP_FILE)
+    if os.path.exists(dump_path) and not os.path.getsize(dump_path):
+        os.remove(dump_path)  # armed and never fired
     if timed_out or proc.returncode != 0 or not os.path.exists(result_file):
-        print(f"run failed: exit code {proc.returncode}, timed out "
-              f"{timed_out}; no result", file=sys.stderr)
+        print(_how_it_failed(proc.returncode, timed_out, args.limit_s,
+                             dump_path), file=sys.stderr)
         return 1
     result = harness.load_json(result_file)
     if leftover:
@@ -199,6 +244,9 @@ def main() -> int:
     ap.add_argument("--child", help=argparse.SUPPRESS)
     ap.add_argument("--t0-wall", type=float, help=argparse.SUPPRESS)
     ap.add_argument("--out-dir", help=argparse.SUPPRESS)
+    # for the tests of the limit alone: the driver never passes it
+    ap.add_argument("--limit-s", type=float, default=float(CHILD_LIMIT_S),
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.seconds is None:
         args.seconds = float(harness.load_benchmark()["run_seconds"])

@@ -27,6 +27,16 @@ from benchmarks import (harness, loadgen, program_spans, replica,
                         stream_spans, trace_reduce)
 
 WARMUP_TIMEOUT_S = 540  # the first request of a bucket compiles
+# What the cluster's processes read at their start, set before ray_tpu.init.
+# A caller waits this long for a PENDING actor to answer (the program ships
+# 180 s), and the Serve controller calls a replica's health_check while the
+# replica is still in __init__: a cold replica of the largest configuration
+# builds its weights past 180 s. 600 s is the program's own limit on an
+# actor's creation (actor_creation_timeout_s), so the controller's wait
+# (300 s in ServeController.deploy) is what now ends a replica that never
+# starts. The environment and not ``_system_config``: the latter reaches the
+# driver alone (the raylet hands its workers its own values; PR 51)
+CLUSTER_ENV = {"RAY_TPU_ACTOR_WAIT_ALIVE_TIMEOUT_S": "600"}
 # an end-to-end metric so named is that percentile of the first-token times
 # (a failed request counting as the worst) or of the pooled inter-token gaps
 PERCENTILE_METRIC = re.compile(r"^(ttft|itl)_p(\d+)_ms$")
@@ -72,6 +82,7 @@ class Deployed:
         from ray_tpu.llm import LLMConfig, SamplingParams
 
         cell, args, traffic, tok = self.cell, self.args, self.traffic, self.tok
+        os.environ.update(CLUSTER_ENV)
         ray_tpu.init(num_tpus=1 if self.toy else None, log_to_driver=False)
         deadline = time.monotonic() + 20
         while not (resources := ray_tpu.cluster_resources()) and \

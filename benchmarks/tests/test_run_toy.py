@@ -11,6 +11,7 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -93,10 +94,10 @@ def run(cell, args):
 '''
 
 
-def test_a_cell_is_added_with_new_files_and_entries_alone(tmp_path):
-    """A later PR adds a configuration, a traffic mix, a runner and a
-    per-layer metric: four new files, entries in BENCHMARK.json, and not one
-    edit to a file that is there."""
+def _checkout_with_a_dummy_cell(tmp_path, runner_source=DUMMY_RUNNER):
+    """A copy of the benchmark beside the program, plus ``dummy-cell``: a
+    configuration, a traffic mix, a runner and two per-layer metrics as new
+    files and entries. Returns the copied files as they were before."""
     shutil.copytree(harness.HERE, tmp_path / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(os.path.join(harness.ROOT, "ray_tpu"), tmp_path / "ray_tpu")
@@ -107,7 +108,7 @@ def test_a_cell_is_added_with_new_files_and_entries_alone(tmp_path):
     (b / "configs" / "dummy-config.json").write_text(json.dumps(
         {"name": "dummy-config", "runner": "dummy", "knob": 7}))
     (b / "traffic" / "dummy-mix.json").write_text(json.dumps({"n": 11}))
-    (b / "runners" / "dummy.py").write_text(DUMMY_RUNNER)
+    (b / "runners" / "dummy.py").write_text(runner_source)
     (b / "layer_metrics" / "dummy_knob.py").write_text(
         "def read(ctx):\n    return ctx['counters']['knob'] * 1.5\n")
     (b / "layer_metrics" / "dummy_absent.py").write_text(
@@ -129,6 +130,14 @@ def test_a_cell_is_added_with_new_files_and_entries_alone(tmp_path):
             "source": "program_counter", "layer": "dummy",
             "moves": "dummy_rate", "workloads": ["dummy-cell"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return before
+
+
+def test_a_cell_is_added_with_new_files_and_entries_alone(tmp_path):
+    """A later PR adds a configuration, a traffic mix, a runner and a
+    per-layer metric: four new files, entries in BENCHMARK.json, and not one
+    edit to a file that is there."""
+    before = _checkout_with_a_dummy_cell(tmp_path)
 
     def run(trace):
         proc = subprocess.run(
@@ -148,3 +157,73 @@ def test_a_cell_is_added_with_new_files_and_entries_alone(tmp_path):
              for root, _d, files in os.walk(tmp_path / "benchmarks")
              for p in files if "__pycache__" not in root}
     assert all(after[p] == data for p, data in before.items())
+
+
+# The limit of a run, at a scale a test can wait for: run.py's hidden
+# --limit-s stands for CHILD_LIMIT_S (1150 s), and the dump of every
+# thread's frames is armed DUMP_BEFORE_S (10 s) under it. The watchdog this
+# replaced fired every 300 s, at 300 / 1150 of the limit.
+LIMIT_S = 20.0
+OLD_WATCHDOG_AT_S = LIMIT_S * 300 / 1150
+HEALTHY_BUT_LONG = DUMMY_RUNNER + f"""
+import time
+_quick = run
+
+def run(cell, args):
+    time.sleep({OLD_WATCHDOG_AT_S + 0.5})
+    return _quick(cell, args)
+"""
+HANGS = """
+import time
+
+def hangs_in_this_frame():
+    time.sleep(3600)
+
+def run(cell, args):
+    hangs_in_this_frame()
+"""
+KILLED = """
+import os, signal
+
+def run(cell, args):
+    os.kill(os.getpid(), signal.SIGSEGV)
+"""
+
+
+@pytest.mark.parametrize("runner,rc,stderr_has,dump_has", [
+    (HEALTHY_BUT_LONG, 0, None, None),
+    (HANGS, 1, "timed out at the limit of 20 s", "hangs_in_this_frame"),
+    (KILLED, 1, "killed by signal 11 (Segmentation fault)", None),
+], ids=["healthy-past-the-old-watchdog", "hangs", "killed-by-a-signal"])
+def test_a_run_is_lost_only_to_the_program_or_the_limit(
+        tmp_path, runner, rc, stderr_has, dump_has):
+    """No dump of threads fires in a run that still ends; a hang is ended at
+    the limit and leaves where it hung; a signal is named."""
+    from benchmarks import run as run_py
+
+    _checkout_with_a_dummy_cell(tmp_path, runner)
+    t0 = time.monotonic()
+    proc = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", "dummy-cell",
+         "--seed", "4", "--seconds", "1", "--trace", "0",
+         "--limit-s", str(LIMIT_S)],
+        cwd=tmp_path, capture_output=True, text=True, timeout=120)
+    took = time.monotonic() - t0
+    assert proc.returncode == rc, proc.stderr[-2000:]
+    dump = tmp_path / ".bench_out" / "dummy-cell" / run_py.DUMP_FILE
+    if rc == 0:
+        assert took > OLD_WATCHDOG_AT_S
+        line = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert line["correct"] is True and line["attempted"] == 11
+        assert "Timeout (" not in proc.stderr
+        assert "most recent call first" not in proc.stderr
+    else:
+        assert proc.stdout.strip() == ""
+        assert stderr_has in proc.stderr
+        assert proc.stderr.rstrip().splitlines()[-1].startswith("run failed")
+    if dump_has is None:
+        assert not dump.exists()
+    else:
+        assert LIMIT_S <= took < LIMIT_S + 15
+        assert dump_has in dump.read_text()
+        assert str(dump) in proc.stderr and dump_has in proc.stderr
