@@ -77,26 +77,93 @@ def _on(sharding, tree):
         s.shape, s.dtype, sharding=sh), tree, sharding)
 
 
-def _qkv(topo):
+def _qkv(topo, shape=(BATCH, SEQ, 16, 128)):
     one = SingleDeviceSharding(topo.devices[0])
-    return (jax.ShapeDtypeStruct((BATCH, SEQ, 16, 128), jnp.bfloat16,
-                                 sharding=one),) * 3
+    return (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one),) * 3
+
+
+def _pallas_calls(text):
+    """[(HLO name, `trace_reduce`'s tag)] of every Pallas call of a compiled
+    program: what the benchmark's readers find the kernels by."""
+    from benchmarks import trace_reduce
+
+    return [(trace_reduce.short_name(line.strip()), trace_reduce.op_tag(line))
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line and " = " in line]
 
 
 def test_flash_forward_compiles(topo):
     compiled = jax.jit(lambda q, k, v: A._flash_fwd_pallas(
-        q, k, v, True, None, 256, 512)).lower(*_qkv(topo)).compile()
+        q, k, v, True, None, A.FLASH_BLOCK_Q, A.FLASH_BLOCK_K)).lower(
+            *_qkv(topo)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
-def test_flash_backward_compiles(topo):
-    def loss(q, k, v):
-        return A.flash_attention(q, k, v).astype(jnp.float32).sum()
+def _flash_loss(q, k, v):
+    return A.flash_attention(q, k, v).astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+
+def test_flash_backward_compiles(topo):
+    compiled = jax.jit(jax.grad(_flash_loss, argnums=(0, 1, 2))).lower(
         *_qkv(topo)).compile()
     # forward (for its residuals), dQ pass, dK/dV pass
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("shape", [(4, 2048, 32, 128), (2, 2048, 16, 128)],
+                         ids=["one-chip-cell", "four-chip-cell"])
+def test_flash_kernels_compile_at_the_train_cells_shapes(topo, shape):
+    """The three kernels at the shapes the two train cells call them with
+    (the four-chip cell's is a device's share under `make_attn_fn`'s
+    `shard_map`) and the default blocks: one forward call of 3 inputs and
+    two backward calls of 6 inputs under the names the benchmark's readers
+    (`benchmarks/readers.py::FLASH_*`) and the step's test find them by."""
+    assert (A.FLASH_BLOCK_Q, A.FLASH_BLOCK_K) == (512, 512)
+    compiled = jax.jit(jax.grad(_flash_loss, argnums=(0, 1, 2))).lower(
+        *_qkv(topo, shape)).compile()
+    calls = _pallas_calls(compiled.as_text())
+    # the HLO wraps a kernel's name in what differentiated it
+    # (`jvp_flash_attention_fwd_.3`, `transpose_jvp_flash_attention_bwd_dq__.5`)
+    found = sorted((re.search(r"flash_attention_(fwd|bwd_dq|bwd_dkv)", name)[0],
+                    tag.rsplit("/", 1)[0]) for name, tag in calls)
+    assert found == [("flash_attention_bwd_dkv", "tpu_custom_call/6in"),
+                     ("flash_attention_bwd_dq", "tpu_custom_call/6in"),
+                     ("flash_attention_fwd", "tpu_custom_call/3in")], calls
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_flash_kernel_bodies_multiply_what_arrives_and_transpose_nothing():
+    """The block step of the three kernels by their jaxprs at a bf16 shape
+    with bare, masked and skipped blocks: no `transpose`, and every
+    `dot_general` takes bf16 operands (q, k, v, dO as they arrive, `p` and
+    `ds` cast to them) and sums in float32."""
+    shape = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(_flash_loss, argnums=(0, 1, 2)))(
+        shape, shape, shape)
+    kernels = {e.params["name"]: e
+               for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"}
+    assert sorted(kernels) == ["flash_attention_bwd_dkv",
+                               "flash_attention_bwd_dq", "flash_attention_fwd"]
+    dots = {"flash_attention_fwd": 2, "flash_attention_bwd_dq": 3,
+            "flash_attention_bwd_dkv": 4}
+    for name, call in kernels.items():
+        body = list(_eqns(call.params["jaxpr"]))
+        assert "transpose" not in {e.primitive.name for e in body}, name
+        products = [e for e in body if e.primitive.name == "dot_general"]
+        # each product once in the body without a mask and once in the
+        # body with it (no padding here: dK/dV's masked walk behind the
+        # bare one is empty at trace time and not there)
+        assert len(products) == 2 * dots[name], (name, len(products))
+        for e in products:
+            assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2, name
+            assert e.outvars[0].aval.dtype == jnp.float32, name
 
 
 # the train cells' widths (`benchmarks/configs/mistral7b-v03-lora-*.json`:
@@ -952,31 +1019,62 @@ def test_attend_cached_reads_the_cache_once(topo):
                 if math.prod(map(int, m.split(","))) >= cache_elems]
 
 
-@pytest.mark.parametrize("causal,sq,sk", [
-    (True, 128, 128),    # block-aligned
-    (False, 128, 128),
-    (True, 100, 100),    # padded to the block
-    (False, 72, 136),    # cross-attention shape, both padded
+# bf16 keeps 8 significant bits, so a result is rounded by up to half its
+# last place: 2^-6 for an output under 8, 2^-4 for a gradient under 32 (sums
+# of a few hundred products of standard normal values); `p` and `ds` enter
+# their products rounded to bf16 too, which these cover. The float32 cases
+# keep 2e-5 / 5e-5.
+BF16_ATOL = (2 ** -6, 2 ** -4)
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.mark.parametrize("causal,sq,sk,dtype,blocks,head_dim", [
+    (True, 128, 128, F32, (64, 64), 32),    # block-aligned: a diagonal block a row
+    (False, 128, 128, F32, (64, 64), 32),   # the body without a mask alone
+    (True, 100, 100, F32, (64, 64), 32),    # padded to the block
+    (False, 72, 136, F32, (64, 64), 32),    # cross-attention shape, both padded
+    (True, 256, 256, F32, (64, 64), 32),    # 4 x 4 blocks: 6 bare, 4 masked, 6 skipped
+    (True, 200, 200, F32, (64, 64), 32),    # the same with a padded last block
+    (True, 320, 192, F32, (64, 64), 32),    # sq > sk: rows below every key
+    (True, 192, 320, F32, (64, 64), 32),    # sq < sk: keys that no row sees
+    (True, 136, 72, F32, (64, 64), 32),     # both ways again, both padded
+    (True, 72, 136, F32, (64, 64), 32),
+    (False, 136, 72, F32, (64, 64), 32),
+    (True, 256, 256, BF16, (64, 64), 32),   # operands as the train cells send them
+    (True, 200, 136, BF16, (64, 64), 32),
+    (False, 192, 128, BF16, (64, 64), 32),
+    # whole lanes, as on the chip: the row statistics' [rows, 128] layout
+    # and its way to and from the rows in HBM, one and two tiles a block
+    (True, 384, 384, F32, (128, 128), 128),
+    (True, 512, 512, F32, (256, 256), 128),
+    (False, 256, 512, F32, (128, 256), 128),
+    (True, 512, 256, BF16, (256, 128), 128),
 ])
 def test_pallas_kernels_match_reference_in_interpret_mode(
-        monkeypatch, causal, sq, sk):
-    """Forward, dQ and dK/dV kernels against mha_reference and its
-    jax.grad, through the Pallas interpreter on the CPU."""
+        monkeypatch, causal, sq, sk, dtype, blocks, head_dim):
+    """Forward, dQ and dK/dV kernels against float32 mha_reference and its
+    jax.grad on the same (rounded) inputs, through the Pallas interpreter
+    on the CPU: every path of the block step at toy size."""
     import jax.experimental.pallas as pl
 
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
     ks = jax.random.split(jax.random.key(0), 4)
-    q = jax.random.normal(ks[0], (1, sq, 2, 32), jnp.float32)
-    k = jax.random.normal(ks[1], (1, sk, 2, 32), jnp.float32)
-    v = jax.random.normal(ks[2], (1, sk, 2, 32), jnp.float32)
-    g = jax.random.normal(ks[3], (1, sq, 2, 32), jnp.float32)
+    q, k, v, g = (
+        jax.random.normal(key, (1, s, 2, head_dim), jnp.float32).astype(dtype)
+        for key, s in zip(ks, (sq, sk, sk, sq)))
 
     flash = functools.partial(A.flash_attention, causal=causal,
-                              block_q=64, block_k=64)
+                              block_q=blocks[0], block_k=blocks[1])
     ref = functools.partial(A.mha_reference, causal=causal)
     out, vjp = jax.vjp(flash, q, k, v)
-    want_out, want_vjp = jax.vjp(ref, q, k, v)
-    np.testing.assert_allclose(out, want_out, atol=2e-5)
-    for a, w in zip(vjp(g), want_vjp(g)):
-        np.testing.assert_allclose(a, w, atol=5e-5)
+    q32, k32, v32, g32 = (x.astype(jnp.float32) for x in (q, k, v, g))
+    want_out, want_vjp = jax.vjp(ref, q32, k32, v32)
+    atol_out, atol_grad = (2e-5, 5e-5) if dtype == F32 else BF16_ATOL
+    assert out.dtype == dtype
+    np.testing.assert_allclose(out.astype(jnp.float32), want_out, atol=atol_out)
+    for a, w in zip(vjp(g), want_vjp(g32)):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(a.astype(jnp.float32), w, atol=atol_grad)
