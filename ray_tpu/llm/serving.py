@@ -77,10 +77,12 @@ def build_llm_deployment(config: LLMConfig):
             batcher = getattr(self.engine, "batcher", None)
             st = getattr(batcher, "stats", None)
             out = dict(st) if st is not None else {}
-            if getattr(batcher, "moe_grouped_path", None):
-                # per jitted program, what a sparse model's grouped expert
-                # matmuls were traced with: "kernel" or "ragged_dot"
-                out["moe_grouped_path"] = batcher.moe_grouped_path
+            # per jitted program, what a sparse model's grouped expert
+            # matmuls were traced with, "kernel" or "ragged_dot", and what a
+            # prefill's fresh rows were attended with, "flash" or "dense"
+            for booked in ("moe_grouped_path", "prefill_attention_path"):
+                if getattr(batcher, booked, None):
+                    out[booked] = getattr(batcher, booked)
             devices = jax.devices()
             mem = devices[0].memory_stats() or {}
             out.update(self._compiles)

@@ -77,6 +77,7 @@ from ray_tpu.models.decoding import (
     SamplingParams,
     _write_stack,
     forward_cached,
+    fresh_rows_attended,
     init_cache,
 )
 from ray_tpu.models.transformer import TransformerConfig
@@ -204,15 +205,20 @@ class PrefillPrograms:
         # with, "kernel" or "ragged_dot" (`_traced_with`); a dense model's
         # programs have none and book nothing. `engine_stats()` carries it
         self.moe_grouped_path: Dict[str, str] = {}
+        # prefill program -> what its fresh rows were attended with,
+        # "flash" or "dense" (`decoding.attend_held`); nothing from a model
+        # whose prefill has an attention of its own. `engine_stats()` too
+        self.prefill_attention_path: Dict[str, str] = {}
 
-    def _traced_with(self, program: str, paths: set) -> None:
-        """Book, while `program` is being traced, the implementation(s)
-        `ops.grouped_matmul` chose for its grouped matmuls: a program that
-        fell back to `lax.ragged_dot` says so in one look. A new dict, not
-        an update in place: a reader may be copying the old one."""
-        if paths:
-            self.moe_grouped_path = {**self.moe_grouped_path,
-                                     program: "+".join(sorted(paths))}
+    @staticmethod
+    def _traced_with(booked: dict, program: str, paths: set) -> dict:
+        """`booked` with, for `program`, the implementation(s) an op chose
+        by what it saw of its call while the program was traced
+        (`ops.grouped_matmul` for its grouped matmuls, `attend_held` for a
+        prefill's fresh rows): a program that fell back says so in one look.
+        A new dict, not an update in place: a reader may be copying the old
+        one."""
+        return {**booked, program: "+".join(sorted(paths))} if paths else booked
 
     def _prefill_impl(self, params, tokens, length):
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
@@ -230,11 +236,15 @@ class PrefillPrograms:
         row_cache = init_cache(self.cfg, 1, s)
         positions = jnp.arange(s)[None, :]
         kv_mask = jnp.arange(s)[None, :] < length
-        with grouped_matmul.paths_traced() as paths:
+        with grouped_matmul.paths_traced() as paths, \
+                fresh_rows_attended() as attended:
             logits, row_cache, aux = forward_cached(
                 self.cfg, params, tokens, positions, row_cache, kv_mask,
                 kv_mask)
-        self._traced_with(f"prefill_{s}", paths)
+        self.moe_grouped_path = self._traced_with(
+            self.moe_grouped_path, f"prefill_{s}", paths)
+        self.prefill_attention_path = self._traced_with(
+            self.prefill_attention_path, f"prefill_{s}", attended)
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
@@ -497,7 +507,8 @@ class ContinuousBatcher(PrefillPrograms):
             logits, cache, aux = forward_cached(
                 self.cfg, params, toks[:, None], positions, cache, kv_mask,
                 active_mask[:, None], access, rows)
-        self._traced_with("decode", paths)
+        self.moe_grouped_path = self._traced_with(
+            self.moe_grouped_path, "decode", paths)
         with jax.named_scope("sample"):
             nxt = _sample_per_slot(
                 logits[:, 0], rng, temps, topks, active_mask)

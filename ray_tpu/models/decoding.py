@@ -50,6 +50,7 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.ops import attention as attention_ops
 from ray_tpu.ops.attention import NEG_INF
+from ray_tpu.ops.traced import TracedPaths
 
 
 class KVCache(NamedTuple):
@@ -136,9 +137,13 @@ def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
 
     Its sibling rule is the caller's (`_write_stack`): never copy a layer
     of the cache out of its stack to get here. Who gets here: a prefill
-    (S > 1, over its fresh rows or the indexed layer), `PagedBatcher`'s
-    gathered view, and a decode step off the chip; on the chip a decode
-    step over a stack reads only the rows held (`attend_held`).
+    behind a prefix or into a longer cache (S > 1, over the indexed layer),
+    `PagedBatcher`'s gathered view, and off the chip, or at a shape a kernel
+    does not take, a decode step and a prefill from position 0. On the chip
+    a decode step over a stack reads only the rows held and a prefill from
+    position 0 never writes its [S, S] logits (`attend_held`): here they
+    cross HBM three times in float32, 30% of a 2,048-token prefill of 32
+    heads (PERF.md, PR 53).
     """
     b, s, h, d = q.shape
     t, kvh = k_cache.shape[1], k_cache.shape[2]
@@ -179,16 +184,52 @@ class StackLayer(NamedTuple):
                 lax.dynamic_index_in_dim(self.v, self.layer, keepdims=False))
 
 
+class FreshRows(NamedTuple):
+    """A prefill from position 0, as its cache access says it: the fresh
+    K/V [B, S, kvH, D] of positions 0..S-1 are every row there is, and the
+    queries they are attended by are the same S positions."""
+    k: jax.Array
+    v: jax.Array
+
+
+# What the fresh rows of a prefill were attended with, "flash" or "dense"
+# (`attend_held`): `with fresh_rows_attended() as seen:` around a prefill
+# program's trace.
+_fresh_rows = TracedPaths("fresh_rows_attention")
+fresh_rows_attended = _fresh_rows.traced
+
+
 def attend_held(q, held, q_pos, kv_len_mask, rows=None):
     """q [B, S, H, D] against what a cache access returned as `held`: a
-    dense (k, v) [B, T, kvH, D] pair, or a `StackLayer`. One token a
-    sequence (S == 1) over a stack whose caller states `rows` [B], how many
-    rows each slot holds (a prefix; 0: the slot takes no part), goes to the
-    kernel that reads those rows in the stack and nothing else
+    dense (k, v) [B, T, kvH, D] pair, a `StackLayer` or `FreshRows`. One
+    token a sequence (S == 1) over a stack whose caller states `rows` [B],
+    how many rows each slot holds (a prefix; 0: the slot takes no part),
+    goes to the kernel that reads those rows in the stack and nothing else
     (`ops.attention.decode_attention`; on a TPU, as `flash_attention`).
+
+    A prefill from position 0 (`FreshRows`) goes to `flash_attention` where
+    its forward kernel takes the shape (`flash_attention_takes`): the causal
+    rule is the whole mask there. A real query at position i sees keys 0..i,
+    all real, so `kv_len_mask` has nothing left to hide; a pad row sees other
+    keys than under the mask, and is as unused as before: its K/V lie past
+    the sequence's length, `row_mask` keeps it out of the experts' counts and
+    out of a state. The kernel multiplies the operands as they arrive and
+    casts the probabilities to their dtype before the weighted sum, which at
+    default precision is what the MXU makes of `_attend_cached`'s float32
+    ones. Which of the two a program was traced with is booked
+    (`fresh_rows_attended`).
+
     Everything else is `_attend_cached` over the dense rows under
     `kv_len_mask` and the causal rule. What decides is in the arguments:
     no option, no model's name."""
+    if isinstance(held, FreshRows):
+        flash = attention_ops.flash_attention_takes(q, held.k)
+        _fresh_rows.book("flash" if flash else "dense")
+        if flash:
+            with jax.named_scope("attend_cached"):
+                return attention_ops.flash_attention(
+                    q, *attention_ops.gqa_expand(*held, q.shape[2]),
+                    causal=True)
     if isinstance(held, StackLayer):
         if (rows is not None and q.shape[1] == 1
                 and attention_ops.decode_attention_takes(held.k)):
@@ -212,9 +253,10 @@ def _write_stack(layer):
 
     `positions` [B, S] are each sequence's S CONSECUTIVE positions, as
     every engine writes a cache. The one thing read off the shapes rests
-    on that: S rows into a cache of S rows are the whole layer, written as
-    one slice (2,048 row scatters into the carried stack cost a 2,048-token
-    prefill 2 ms more than the parent's, PERF.md, PR 26)."""
+    on that: S rows into a cache of S rows are the whole layer, positions
+    0..S-1, written as one slice (2,048 row scatters into the carried stack
+    cost a 2,048-token prefill 2 ms more than the parent's, PERF.md, PR 26)
+    and handed to attention as what they are, `FreshRows`."""
 
     def access(k_cache, v_cache, k, v, positions):
         k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
@@ -223,7 +265,7 @@ def _write_stack(layer):
             # the fresh K/V ARE the layer, no row is scattered or read back
             return (lax.dynamic_update_index_in_dim(k_cache, k, layer, 0),
                     lax.dynamic_update_index_in_dim(v_cache, v, layer, 0),
-                    (k, v))
+                    FreshRows(k, v))
         bidx = jnp.arange(k.shape[0])[:, None]
         k_cache = k_cache.at[layer, bidx, positions].set(k)
         v_cache = v_cache.at[layer, bidx, positions].set(v)
@@ -243,9 +285,10 @@ def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
     positions) -> (k_cache, v_cache, held)`: it writes the fresh, rotated
     K/V wherever its cache keeps them and returns the caches with what
     attention reads (`attend_held`): a dense (k, v) [B, max_len, kvH, D]
-    pair, or the stack and the layer. One layer's cache (the default), the
-    carried stack (`_write_stack`) and `PagedBatcher`'s page pool each
-    bring their own; the block around it has this one spelling. `rows` [B]
+    pair, the stack and the layer, or the fresh rows of a prefill from
+    position 0. One layer's cache (the default), the carried stack
+    (`_write_stack`) and `PagedBatcher`'s page pool each bring their own;
+    the block around it has this one spelling. `rows` [B]
     is a decode step's statement of the rows each slot holds."""
     scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
     b, s, _ = x.shape

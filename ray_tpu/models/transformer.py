@@ -14,7 +14,9 @@ Design notes (TPU):
 - matmuls in bfloat16 with fp32 accumulation (``preferred_element_type``),
   params kept fp32 by default (master weights), cast per-step.
 - attention = ops.flash_attention (pallas on TPU) or ops.ring_attention
-  when the sequence axis is sharded.
+  when the sequence axis is sharded. The same forward kernel attends a serve
+  prefill's fresh rows from position 0 (``decoding.attend_held``, where
+  ``flash_attention_takes`` the shape).
 - ``jax.checkpoint`` per block with a policy: beside the block's input the
   backward keeps the named results of the block's matmuls and of the flash
   forward kernel (``REMAT_LADDER``) and recomputes only elementwise work

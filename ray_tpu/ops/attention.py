@@ -610,6 +610,41 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+# K and V of one head, two buffers each, as the forward kernel's BlockSpecs
+# map them whole a program: 8,192 keys of 128 bf16 values, the longest that
+# has run on a v5e (PERF.md section 6, PRs 52 and 53). The chip's compiler
+# refuses four times that, and the backward kernels twice (ROADMAP
+# `flash-f32` e).
+FLASH_KV_VMEM_BYTES = 8 << 20
+# Float32 scores [B, H, S, S] up to which the XLA spelling of a prefill's
+# attention (`models/decoding.py::_attend_cached`) is as fast as the kernel
+# or faster: the compiler keeps them in the v5e's 128 MiB of fast memory
+# between its fusions. One layer, batch 1, my chip runs, PR 53: at 32-96 MiB
+# of scores 0.038-0.112 ms against the kernel's and its copies' 0.042-0.184;
+# at 128 MiB and past it (8 heads x 2,048, 32 x 1,024, 32 x 2,048, 48 x
+# 2,048) 0.56-3.42 against 0.12-0.67.
+DENSE_SCORES_BYTES = 112 << 20
+
+
+def flash_attention_takes(q, k) -> bool:
+    """Whether causal self-attention of q [B, S, H, D] over k / v [B, S, kvH,
+    D] goes to the forward KERNEL, for a caller that has another spelling
+    to fall back on (a prefill's fresh rows, `models/decoding.py::
+    attend_held`), by what can be seen of the call: on a TPU (as
+    `flash_attention`), one 2- or 4-byte dtype, heads of whole lanes, a
+    length of whole 128s (the row statistics leave the kernel 128 positions
+    at a time, `_store_row`: the chip's compiler refuses 16 to 64
+    positions), a head's whole K and V inside `FLASH_KV_VMEM_BYTES`, and
+    scores past `DENSE_SCORES_BYTES`: below that the other spelling never
+    sends them to HBM and the kernel has nothing to save."""
+    b, s, h, d = q.shape
+    itemsize = q.dtype.itemsize
+    return (_on_tpu() and q.dtype == k.dtype and itemsize in (2, 4)
+            and k.shape[1] == s and d % 128 == 0 and s % 128 == 0
+            and 4 * s * d * itemsize <= FLASH_KV_VMEM_BYTES
+            and 4 * b * h * s * s > DENSE_SCORES_BYTES)
+
+
 # ---------------------------------------------------------------------------
 # Decode attention over a cache STACK (TPU): one new token a sequence against
 # the rows the sequence holds, read where they lie.

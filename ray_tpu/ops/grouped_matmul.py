@@ -18,8 +18,6 @@ GB/s at all four shapes and 1-3.5 us for a call that reaches no expert.
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import functools
 
 import jax
@@ -27,6 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops import attention as attention_ops
+from ray_tpu.ops.traced import TracedPaths
 
 # Bytes of one block of an expert's matrix at most: a block is [bk, n], whole
 # rows of the matrix, so one contiguous piece of the stack. Two buffers a
@@ -38,20 +37,10 @@ BLOCK_BYTES = 5 << 18  # 1.25 MiB
 # need more keeps `lax.ragged_dot`.
 VMEM_BYTES = 96 << 20
 
-_traced: contextvars.ContextVar = contextvars.ContextVar(
-    "grouped_matmul_paths", default=None)
-
-
-@contextlib.contextmanager
-def paths_traced():
-    """The set of implementations ("kernel", "ragged_dot") that
-    `grouped_matmul` chose while the body ran: a jitted program's trace."""
-    seen: set = set()
-    token = _traced.set(seen)
-    try:
-        yield seen
-    finally:
-        _traced.reset(token)
+# The implementations ("kernel", "ragged_dot") that `grouped_matmul` chose:
+# `with paths_traced() as seen:` around a jitted program's trace.
+_paths = TracedPaths("grouped_matmul_paths")
+paths_traced = _paths.traced
 
 
 def row_tile(dtype) -> int:
@@ -308,9 +297,7 @@ def grouped_matmul(rows, weights, group_sizes, layer=None):
     if layer is None:
         mats, layer = tuple(w[None] for w in mats), 0
     kernel = takes(rows, mats[0], len(mats))
-    seen = _traced.get()
-    if seen is not None:
-        seen.add("kernel" if kernel else "ragged_dot")
+    _paths.book("kernel" if kernel else "ragged_dot")
     if kernel:
         return _kernel_call(rows, mats, group_sizes, layer, gated)
     return _with_ragged_dot(rows, mats, group_sizes, layer, gated)

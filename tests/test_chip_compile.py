@@ -437,7 +437,7 @@ def serve_programs(topo):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     @functools.cache
-    def compiled(name):
+    def engine(name):
         widths = dict({**SERVE_CONFIGS, **PATTERN_CONFIGS}[name])
         slots = widths.pop("slots", SERVE_SLOTS)
         bucket = widths.pop("bucket", SEQ)
@@ -448,9 +448,22 @@ def serve_programs(topo):
         batcher = ContinuousBatcher.__new__(ContinuousBatcher)  # programs only
         batcher.cfg, batcher.max_len, batcher.slots = cfg, max_len, slots
         batcher._jit_programs()
-        prefill = jax.jit(batcher._prefill_impl).lower(  # as `_prefill_into`
+        return batcher, params, bucket
+
+    @functools.cache
+    def prefill_at(name, bucket):
+        """The configuration's prefill program of another bucket than its
+        cell's; what it attended with is in `attention_paths(name)`."""
+        batcher, params, _ = engine(name)
+        return jax.jit(batcher._prefill_impl).lower(  # as `_prefill_into`
             params, arr((1, bucket), jnp.int32), arr((1,), jnp.int32)
         ).compile()
+
+    @functools.cache
+    def compiled(name):
+        batcher, params, bucket = engine(name)
+        cfg, slots, max_len = batcher.cfg, batcher.slots, batcher.max_len
+        prefill = prefill_at(name, bucket)
         cache = _on(one, jax.eval_shape(
             lambda: init_cache(cfg, slots, max_len)))
         decode = batcher._decode_jit.lower(
@@ -463,6 +476,9 @@ def serve_programs(topo):
         return cfg, prefill, decode, cache
 
     compiled.grouped_paths = grouped_paths = {}
+    compiled.prefill_at = prefill_at
+    # what the engine's `prefill_attention_path` says of the prefills so far
+    compiled.attention_paths = lambda name: engine(name)[0].prefill_attention_path
     return compiled
 
 
@@ -939,6 +955,102 @@ def test_decode_reads_the_cache_in_its_stack_with_the_kernel(
     assert called <= {op for s in wanted for op in by_scope[s]}
     for scope in wanted:
         assert called & set(by_scope[scope]), scope
+
+
+@pytest.mark.parametrize("name", sorted(SERVE_CONFIGS))
+def test_prefill_attends_its_fresh_rows_with_the_flash_kernel(
+        serve_programs, name):
+    """Every prefill program whose layers attend through `attend_held` (the
+    one block's two, ZAYA1's, Laguna's full layers), at a 2,048 bucket: the
+    fresh rows of a prefill from position 0 go to the flash forward kernel,
+    one Mosaic call of three inputs a scanned body under the name and the
+    scope the benchmark's readers select by, the engine's counter says
+    "flash" for the bucket (its float32 scores [heads, S, S] are past what
+    the XLA spelling keeps in fast memory, `A.DENSE_SCORES_BYTES`, over 8
+    heads too), and NO instruction, fused or not, has a float32
+    result of `[heads, S, S]` elements with two dimensions of the bucket's
+    length: before PR 53 `_attend_cached` wrote and read such logits three
+    times a layer (20 instructions of `f32[8,2048,2048,4]` in the dense
+    program, 40 of `f32[1,8,6,2048,2048]` in Laguna's). A window layer's
+    band stays. ZAYA1's cell prefills in the 1,024 bucket: 32 MiB of scores
+    over 8 heads, which XLA keeps in fast memory, so that program is the
+    parent's (below) and the counter says "dense" for it."""
+    from benchmarks import harness, scope_ops
+
+    cfg, bucket = serve_programs(name)[0], SEQ
+    text = serve_programs.prefill_at(name, bucket).as_text()
+    paths = serve_programs.attention_paths(name)
+    assert paths[f"prefill_{bucket}"] == "flash"
+    if name == "zaya1-8b-serve-d16":
+        assert paths == {"prefill_1024": "dense", "prefill_2048": "flash"}
+    calls = [(op, tag) for op, tag in _pallas_calls(text) if "flash" in op]
+    # one scanned body; a pattern's leading layer and its period's full one
+    assert len(calls) == (1 + cfg.layer_kinds.count("full")
+                          if cfg.layer_kinds else 1), calls
+    for op, tag in calls:
+        assert op.startswith("flash_attention_fwd")
+        assert tag.startswith("tpu_custom_call/3in"), calls
+    logits = [(op_name, dims, op) for op_name, dtype, dims, op in _results(text)
+              if dtype == "f32" and dims.count(bucket) >= 2
+              and math.prod(dims) >= cfg.heads * bucket * bucket]
+    assert not logits, logits[:4]
+    wanted, scopes = "attend_cached", scope_ops.SCOPES + ("attend_cached",)
+    if cfg.attention == "cca":
+        wanted = "cca.attend"
+    if cfg.layer_kinds:
+        wanted = "attn.full"
+        scopes = harness.load_module("runners", "serve_laguna").SCOPES
+    assert {op for op, _ in calls} <= set(
+        scope_ops.op_scopes(text, scopes)[wanted])
+
+
+def _program_text(program) -> str:
+    """A compiled program's text without what names the checkout and the
+    call stack: the tables of file names and stack frames, every
+    instruction's frame id, and a Mosaic call's payload (its module with the
+    kernel's own source locations)."""
+    text = "\n".join(
+        re.sub(r"backend_config=.*", "backend_config=<payload>", line)
+        if "tpu_custom_call" in line else line
+        for line in program.as_text().split("\n"))
+    head, _, rest = text.partition("\nFileNames\n")
+    if rest:
+        body = rest.partition("\nStackFrames\n")[2].split("\n\n", 1)[1]
+        text = head + "\n" + body
+    return re.sub(r'source_file="[^"]*"|source_line=\d+| ?stack_frame_id=\d+',
+                  "", text)
+
+
+# sha256 (16 digits) of `_program_text` of the programs that PR 53, which
+# changed what a prefill from position 0 attends with, left as its parent
+# compiled them line for line: every decode step, both programs of the two
+# families whose prefill has an attention of its own, and ZAYA1's 1,024
+# bucket. A PR that means to change one of these programs pins what the
+# failure prints.
+UNCHANGED_PROGRAMS = {
+    ("zaya1-8b-serve-d16", "prefill"): "b6e375849037b5ca",
+    ("mistral7b-v03-serve-d16", "decode"): "f367d611b8b354af",
+    ("olmoe-1b-7b-serve-d8", "decode"): "db5f0cb4da39e451",
+    ("zaya1-8b-serve-d16", "decode"): "32de0ec26bb5d39f",
+    ("laguna-s-2.1-serve-ep2-d5", "decode"): "5ab54d08f545bb4f",
+    (KIMI_LINEAR, "prefill"): "153b5d99acbf6027",
+    (KIMI_LINEAR, "decode"): "abaec82cb79aa990",
+    (LONGCAT, "prefill"): "8c6f7b4c6d88f202",
+    (LONGCAT, "decode"): "9f079d1be9ec6bb4",
+}
+
+
+@pytest.mark.parametrize("name,kind", sorted(UNCHANGED_PROGRAMS))
+def test_programs_a_prefills_attention_does_not_reach_are_the_parents(
+        serve_programs, name, kind):
+    import hashlib
+
+    _, prefill, decode, _ = serve_programs(name)
+    text = _program_text(prefill if kind == "prefill" else decode)
+    assert not re.search(r"flash_attention_(fwd|bwd)", text)
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == UNCHANGED_PROGRAMS[name, kind], (
+        f"{name} {kind}: {len(text.splitlines())} lines now hash to {got}")
 
 
 # the four sparse serve programs: what one layer's expert stack holds (the

@@ -77,7 +77,9 @@ def test_attend_cached_matches_plain_reference(rep, s, dtype):
 def kernels_through_the_interpreter(monkeypatch):
     """The chip's path on the CPU: `_on_tpu` says yes (steered here, not by
     an option of the program) and every Pallas call runs interpreted. Blocks
-    of 16 rows, so that a toy slot has several."""
+    of 16 rows, so that a toy slot has several, and no scores small enough to
+    stay with `_attend_cached`, so that a toy bucket goes a long prompt's
+    way."""
     import functools
 
     import jax.experimental.pallas as pl
@@ -86,6 +88,7 @@ def kernels_through_the_interpreter(monkeypatch):
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(A, "DECODE_BLOCK_ROWS", 16)
+    monkeypatch.setattr(A, "DENSE_SCORES_BYTES", 0)
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
     return A
@@ -220,6 +223,154 @@ def test_the_decode_step_with_the_kernel_gives_the_xla_steps_tokens(
     assert (stats["kv_rows_held"], stats["kv_rows_read"]) == tuple(total)
     assert stats["kv_rows_read"] < stats["steps"] * slots * (
         cfg.full_layers * max_len + cfg.window_layers * cfg.window)
+
+
+def _prefill_qkv(s, heads, kv_heads, dtype=jnp.bfloat16, d=128, seed=0):
+    ks = jax.random.split(jax.random.key(seed + s + heads), 3)
+    return [jax.random.normal(key, (1, s, n, d), jnp.float32).astype(dtype)
+            for key, n in zip(ks, (heads, kv_heads, kv_heads))]
+
+
+@pytest.mark.parametrize("bucket,length", [(128, 77), (256, 150)])
+@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (16, 16), (8, 2)])
+def test_a_prefill_from_position_0_attends_with_the_flash_kernel(
+        kernels_through_the_interpreter, heads, kv_heads, bucket, length):
+    """The fresh rows of a prefill from position 0 (`FreshRows`, what
+    `_write_stack` hands over for S rows into a cache of S rows) through
+    `attend_held` on the chip's path: the flash forward kernel over the
+    expanded KV heads under the causal rule alone, against `_attend_cached`
+    under the causal rule AND the length's mask, on the REAL rows, for the
+    head layouts of the one block's cells and ZAYA1 (groups of 4, 1 and 4)
+    and a length inside the bucket. A pad row sees other keys there than
+    here and is nobody's to read."""
+    from ray_tpu.models import decoding as D
+
+    q, k, v = _prefill_qkv(bucket, heads, kv_heads)
+    pos = jnp.arange(bucket)[None]
+    mask = pos < length
+    with D.fresh_rows_attended() as seen:
+        got = jax.jit(D.attend_held)(q, D.FreshRows(k, v), pos, mask)
+    assert seen == {"flash"}
+    want = _attend_cached(q, k, v, pos, mask)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    _close(got[:, :length], want[:, :length], jnp.bfloat16)
+
+
+@pytest.mark.parametrize("held", [
+    "a-stack-layer", "gathered-rows", "one-token", "a-narrow-head",
+    "a-short-bucket", "another-dtype"])
+def test_attend_held_keeps_attend_cached(
+        kernels_through_the_interpreter, monkeypatch, held):
+    """On the chip's path too everything but a prefill from position 0 at a
+    shape the kernel takes is `_attend_cached`, bit for bit: a prefill into a
+    longer cache (a `StackLayer` with S > 1), `PagedBatcher`'s gathered
+    dense rows, one token, and fresh rows the predicate refuses (a head of
+    64, a bucket of 64 whose row statistics the chip's compiler refuses, q
+    and k of two dtypes), which book "dense"."""
+    from ray_tpu.models import decoding as D
+
+    A = kernels_through_the_interpreter
+
+    def no_kernel(*a, **kw):
+        raise AssertionError("the flash kernel was called")
+
+    monkeypatch.setattr(A, "flash_attention", no_kernel)
+    s, t, d = {"one-token": (1, 1, 128), "a-narrow-head": (128, 128, 64),
+               "a-short-bucket": (64, 64, 128),
+               "a-stack-layer": (128, 256, 128)}.get(held, (128, 128, 128))
+    q, _, _ = _prefill_qkv(s, 8, 2, d=d)
+    _, k, v = _prefill_qkv(t, 8, 2, d=d)
+    pos = jnp.arange(s)[None]
+    mask = jnp.arange(t)[None] < max(s - 11, 1)
+    fresh = held not in ("a-stack-layer", "gathered-rows")
+    if held == "another-dtype":
+        q = q.astype(jnp.float32)
+    arg = D.FreshRows(k, v) if fresh else (k, v)
+    if held == "a-stack-layer":
+        arg = D.StackLayer(jnp.stack([k, k + 1]), jnp.stack([v, v + 1]),
+                           jnp.int32(0))
+    with D.fresh_rows_attended() as seen:
+        got = jax.jit(D.attend_held)(q, arg, pos, mask)
+    assert seen == ({"dense"} if fresh else set())
+    want = jax.jit(_attend_cached)(q, k, v, pos, mask)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_flash_attention_takes_what_fits_and_what_it_can_save(monkeypatch):
+    """The predicate beside the kernel, by what it sees of a call. What the
+    kernel can run: whole lanes, whole 128s of positions (not 16 to 64),
+    8,192 keys of bf16 at most (K and V of a head, two buffers each, inside
+    `FLASH_KV_VMEM_BYTES`) or 4,096 of float32, one length, one dtype, a
+    TPU. And what it can save: float32 scores [B, H, S, S] past the 112 MiB
+    up to which XLA keeps them in fast memory: the cells' 2,048 buckets over
+    32, 16 and 48 heads and 8 heads from 2,048 on, not ZAYA1's 1,024 bucket
+    (32 MiB) nor the chat cell's 128 to 512 over 32 heads (2 to 32 MiB)."""
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+
+    def takes(s, h=32, d=128, dtype=jnp.bfloat16, sk=None, kdtype=None, b=1):
+        q = jax.ShapeDtypeStruct((b, s, h, d), dtype)
+        k = jax.ShapeDtypeStruct((b, sk or s, 2, d), kdtype or dtype)
+        return A.flash_attention_takes(q, k)
+
+    assert all(takes(s) for s in (1024, 1536, 2048, 4096, 8192))
+    assert takes(2048, h=16) and takes(2048, h=48) and takes(2048, h=8)
+    assert not any(takes(s) for s in (128, 256, 512, 768))
+    assert not takes(1024, h=8) and not takes(1024, h=24)
+    assert takes(512, b=4) and takes(1024, h=8, b=4)
+    assert not takes(16384)
+    assert takes(4096, dtype=jnp.float32) and takes(2048, d=256)
+    assert not takes(8192, dtype=jnp.float32) and not takes(8192, d=256)
+    monkeypatch.setattr(A, "DENSE_SCORES_BYTES", 0)
+    assert all(takes(s, h=8) for s in (128, 256, 384, 512, 1024))
+    assert not any(takes(s) for s in (1, 16, 32, 64, 200, 16384))
+    assert not takes(2048, d=64) and not takes(2048, d=192)
+    assert not takes(2048, sk=4096)
+    assert not takes(2048, kdtype=jnp.float32)
+    assert not takes(2048, dtype=jnp.float16, kdtype=jnp.bfloat16)
+    monkeypatch.setattr(A, "_on_tpu", lambda: False)
+    assert not takes(2048)
+
+
+@pytest.mark.parametrize("name", ["debug", "zaya_debug", "laguna_debug"])
+def test_the_engine_says_what_each_prefill_attended_with(
+        kernels_through_the_interpreter, monkeypatch, name):
+    """`attend_held`'s three callers (the one block, ZAYA1's `cca.attend`,
+    Laguna's full layers) through `ContinuousBatcher`, prompts in three
+    buckets: on the chip's path the 128 and 256 buckets' fresh rows take the
+    flash kernel and the 16 bucket's keep `_attend_cached` (what decides is
+    the shape, no family's name), `prefill_attention_path` names a path for
+    every compiled bucket, and the greedy tokens are the ones the same
+    engine gives off a TPU, where every bucket reads "dense"."""
+    from ray_tpu.models.continuous_batching import ContinuousBatcher
+
+    A = kernels_through_the_interpreter
+    cfg = T.config(name, dtype=jnp.float32, param_dtype=jnp.float32,
+                   head_dim=128)
+    params = T.init_params(cfg, jax.random.key(4))
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab_size, n).tolist()
+               for n in (9, 100, 190)]
+
+    def served():
+        cb = ContinuousBatcher(cfg, params, max_len=256, slots=2)
+        try:
+            return [cb.submit(p, SamplingParams(max_tokens=5)
+                              ).result(timeout=600) for p in prompts], \
+                cb.prefill_attention_path
+        finally:
+            cb.shutdown()
+
+    got, paths = served()
+    assert paths == {"prefill_16": "dense", "prefill_128": "flash",
+                     "prefill_256": "flash"}
+    monkeypatch.setattr(A, "_on_tpu", lambda: False)
+    want, paths = served()
+    assert paths == dict.fromkeys(
+        ("prefill_16", "prefill_128", "prefill_256"), "dense")
+    assert got == want and [len(g) for g in got] == [5, 5, 5]
 
 
 def _forward_cached_plainly(cfg, params, tokens, positions, cache,

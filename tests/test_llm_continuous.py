@@ -596,6 +596,11 @@ class TestServeContinuous:
                 handle.engine_stats.remote().result()["process_cpu_s"]
             # the process as a producer of streams (nothing streamed here)
             assert stats["stream_items_sent"] >= stats["stream_calls"] >= 0
+            # every compiled prefill bucket says what its fresh rows were
+            # attended with: off a TPU, `_attend_cached`
+            buckets = stats["prefill_attention_path"]
+            assert buckets and set(buckets.values()) == {"dense"}
+            assert all(b.startswith("prefill_") for b in buckets)
             assert stats["admitted"] == 5
             assert stats["max_active"] <= 2  # bounded by cache_slots
             assert stats["finished"] == 5
