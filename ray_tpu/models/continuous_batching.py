@@ -347,6 +347,14 @@ class ContinuousBatcher(PrefillPrograms):
             # prefills whose state went into a slot with their rows, and
             # slots whose state was cleared when their request left
             self.stats.update(state_installs=0, state_resets=0)
+            # bytes of state the decode steps read and wrote back: every
+            # state of a step's active sequences once each way (`cfg.kept`);
+            # a free slot's state is kept as it is
+            self.stats["state_bytes_rewritten"] = 0
+            self._state_bytes = 2 * sum(
+                kept.layers * math.prod(kept.shape)
+                * jnp.dtype(kept.dtype or cfg.dtype).itemsize
+                for kept in cfg.kept(max_len) if kept.rows is None)
         # A list while someone wants to know which expert each row was given
         # (a program's `expert_choice`, from a router that gives one, or the
         # k of a layer pattern's): every admit and every step read appends
@@ -933,6 +941,9 @@ class ContinuousBatcher(PrefillPrograms):
         self.stats["steps_sorted"] += sorts
         self.stats["kv_rows_held"] += held
         self.stats["kv_rows_read"] += read
+        if self.cfg.stateful:
+            self.stats["state_bytes_rewritten"] += \
+                len(slots) * self._state_bytes
         # nothing is in flight while the step before is read: if that
         # fails, the failure path must not emit `newer` behind the hole
         self._drain()

@@ -182,6 +182,28 @@ class TransformerConfig:
     # multiply nothing: a choice among them adds its weight times the expert
     # layer's input (`moe_dropless`), here, whatever `experts_held` says.
     zero_experts: int = 0
+    # A pattern of layers that are ONE sublayer each, `x + f(norm(x))`
+    # (models/nemotron_h.py; the cached forward alone runs it): `layer_kinds`
+    # is every layer's kind in order, no lead, period or tail read into it
+    # ("ssm", "gqa", "lmoe"). "ssm": a Mamba-2 mixer of `ssm_heads` heads of
+    # `ssm_head_dim` whose B and C are shared by the heads of one of
+    # `ssm_groups` groups; it keeps a float32 [ssm_state, ssm_heads *
+    # ssm_head_dim] state and the last `ssm_conv - 1` inputs of its
+    # convolution a sequence (`KVCache.mat` / `.conv`); a prefill runs the
+    # recurrence `ssm_chunk` positions at a time. "gqa": grouped attention
+    # over K/V rows, no rotation. "lmoe": an expert layer whose experts work
+    # on a `moe_latent`-wide projection of the stream (0: on the stream).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 128
+    moe_latent: int = 0
+    # What one expert of `moe_dropless` is (and a pattern's shared expert):
+    # "swiglu", three matrices, `(silu(x Wg) * (x Wu)) Wd`; or "relu2", two,
+    # `relu(x Wu)^2 Wd`
+    expert_act: str = "swiglu"
 
     def __post_init__(self):
         if self.attention not in ("gqa", "cca"):
@@ -190,6 +212,8 @@ class TransformerConfig:
             raise ValueError(f"unknown router {self.router!r}")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router_score {self.router_score!r}")
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert_act {self.expert_act!r}")
         if self.experts_held is not None:
             first, count = self.experts_held
             if not (self.num_experts and 0 <= first and count >= 1
@@ -296,9 +320,13 @@ class TransformerConfig:
     @property
     def sparse_layers(self) -> int:
         """Layers that route: all of a sparse model's, or all but a
-        pattern's leading dense one (a double layer routes once)."""
+        pattern's leading dense one (a double layer routes once); a family
+        whose layers do not all route states its own (`sparse_layers`)."""
         if not self.num_experts:
             return 0
+        stated = getattr(families.of(self), "sparse_layers", None)
+        if stated is not None:
+            return stated(self)
         return self.layers - bool(self.lead_kind and self.layer_kinds)
 
     def flops_per_token(self) -> float:
@@ -457,6 +485,25 @@ PRESETS: Dict[str, TransformerConfig] = {
         mla_scales=((128 / 24) ** 0.5, 2.0), zero_experts=8,
         dense_mlp_hidden=192, routed_scale=6.0, experts_held=(0, 4),
         dtype=jnp.float32,
+    ),
+    # nvidia/NVIDIA-Nemotron-3-Super-120B-A12B's layers at debug widths
+    # (models/nemotron_h.py): 11 layers of ONE sublayer each, the published
+    # string's own order of kinds (M E M E M * E M E M *): 5 Mamba-2 mixers
+    # (8 heads of 8 in 2 groups, a state of 16, 4 taps), 2 attentions of 4
+    # heads on 2 KV heads without rotation, 4 expert layers of sigmoid top-4
+    # of 16 ReLU^2 experts in a latent of 32 (8 held) times 5 with a shared
+    # expert on the full width. The published widths are the benchmark's to
+    # build (benchmarks/runners/serve_nemotron_h.py)
+    "nemotron_h_debug": dict(
+        vocab_size=512, hidden=128, mlp_hidden=48, layers=11, heads=4,
+        kv_heads=2, head_dim=16, max_seq=128, remat=False, norm_eps=1e-5,
+        num_experts=16, experts_per_token=4, norm_topk_prob=True,
+        layer_kinds=("ssm", "lmoe", "ssm", "lmoe", "ssm", "gqa", "lmoe",
+                     "ssm", "lmoe", "ssm", "gqa"), lead_kind="",
+        ssm_heads=8, ssm_head_dim=8, ssm_groups=2, ssm_state=16, ssm_conv=4,
+        ssm_chunk=8, moe_latent=32, expert_act="relu2",
+        router_score="sigmoid", routed_scale=5.0, shared_expert_hidden=96,
+        experts_held=(0, 8), dtype=jnp.float32,
     ),
 }
 
@@ -751,7 +798,9 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
     gate, up and down projections are grouped matmuls over the E groups of
     that sorted order, gate and up in one call and down in a second
     (`_grouped_matmul`: a kernel cut to a decode step's few rows a group on a
-    TPU, `lax.ragged_dot` for a long prompt and off a TPU); then the rows go
+    TPU, `lax.ragged_dot` for a long prompt and off a TPU; with
+    `cfg.expert_act` "relu2" an expert is two matrices, up and down, and
+    ReLU squared between them); then the rows go
     back to token order and are summed with the router's weights. Shapes are
     static: T*k rows always, only `group_sizes` is data. No [T*k, E, C]
     dispatch tensor exists and nothing is dropped. Differentiable.
@@ -761,7 +810,10 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
     token's result; `row_mask` [B,S] (True = a real row) keeps them out of
     `load`, the assignments each expert received from real rows.
 
-    `p` is one layer's parameters; with `layer` its three expert weights are
+    y's width is the experts' input and output width, whatever the stream's
+    (a family whose experts work in a latent hands the latent in and routes
+    on the stream: `routing`). `p` is one layer's parameters; with `layer` its
+    expert weights are
     the whole stacks [L,E,...] and `layer` the index to use
     (`_grouped_matmul` says why a layer scan wants that). `routing` is
     `moe_router`'s pair from a router of another kind (`zaya.router`).
@@ -812,9 +864,14 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
             groups' gate, up and down matrices: [len(order), h] float32,
             zeros where an assignment is in no group."""
             rows = x[order // k]  # one expert's rows adjoin
-            act = _grouped_matmul(
-                rows, (p["wi_gate"].astype(x.dtype),
-                       p["wi_up"].astype(x.dtype)), group_sizes, layer)
+            if cfg.expert_act == "relu2":  # two matrices, no gate
+                up = _grouped_matmul(rows, p["wi_up"].astype(x.dtype),
+                                     group_sizes, layer)
+                act = jnp.square(jax.nn.relu(up)).astype(x.dtype)
+            else:
+                act = _grouped_matmul(
+                    rows, (p["wi_gate"].astype(x.dtype),
+                           p["wi_up"].astype(x.dtype)), group_sizes, layer)
             down = _grouped_matmul(act, p["wo_mlp"].astype(x.dtype),
                                    group_sizes, layer)
             if held is not None or zero:  # no group: whatever was left

@@ -140,8 +140,28 @@ PROGRAM_SCOPES = {
                   "the held latent rows (absorbed), a prefill's over keys "
                   "and values expanded from its own fresh rows",
     "mla.out": "models/kimi_linear.py: a decode step's value expansion; wo",
+    "ssm.project": "models/nemotron_h.py: a state-space mixer's input "
+                   "projection (gate, convolution channels, steps), the "
+                   "steps' softplus and the decay's log",
+    "ssm.conv": "models/nemotron_h.py: the depthwise convolution with its "
+                "bias over x, B and C, its window's read and write, SiLU",
+    "ssm.state": "models/nemotron_h.py: a decode step's state update: every "
+                 "sequence's states decayed, one rank-one term a head added "
+                 "and read out, D x (read once, written once; the bytes are "
+                 "a counter, `state_bytes_rewritten`)",
+    "ssm.prefill_scan": "models/nemotron_h.py: a prefill's recurrence, a "
+                        "chunk of positions at a time",
+    "ssm.norm": "models/nemotron_h.py: the gate, then the norm a group",
+    "ssm.out": "models/nemotron_h.py: the output projection",
+    "attn.gqa": "models/nemotron_h.py: an attention layer's projections, "
+                "row write, attention over its slots (attend_cached inside "
+                "it), wo; no rotation",
+    "lmoe.down": "models/pattern.py: the projection of the normed stream "
+                 "into the latent the experts work in",
+    "lmoe.up": "models/pattern.py: the experts' weighted sum back out of "
+               "the latent",
     "moe.shared": "models/laguna.py, models/kimi_linear.py: the shared "
-                  "expert's SwiGLU",
+                  "expert's SwiGLU; models/nemotron_h.py: its ReLU^2 unit",
     "moe_router": "models/transformer.py: the linear router and its top-k "
                   "(softmax); models/kimi_linear.py: the sigmoid router with "
                   "its selection bias",

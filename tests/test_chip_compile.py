@@ -399,6 +399,7 @@ SERVE_CONFIGS = {
 # SERVE_CONFIGS: its cache has no K/V rows for the tests that walk those
 KIMI_LINEAR = "kimi-linear-48b-a3b-serve-ep16"
 LONGCAT = "longcat-flash-serve-ep32-d4"
+NEMOTRON_H = "nemotron-3-super-serve-ep8-d22"
 PATTERN_CONFIGS = {
     KIMI_LINEAR: dict(
         name="kimi_linear_debug", vocab_size=20480, hidden=2304,
@@ -419,6 +420,20 @@ PATTERN_CONFIGS = {
         mla_q_rank=1536, mla_scales=(2.0, 12 ** 0.5), dense_mlp_hidden=12288,
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, slots=32, max_len=5120,
         bucket=4096),
+    # NVIDIA-Nemotron-3-Super-120B-A12B's published widths, the first 22 of
+    # its 88 one-sublayer layers, one chip's share of a layer that 8 hold: 64
+    # of 512 experts, an eighth of the vocabulary; 32 slots of 2048
+    # positions, the 512 bucket
+    NEMOTRON_H: dict(
+        name="nemotron_h_debug", vocab_size=16384, hidden=4096,
+        mlp_hidden=2688, layers=22, heads=32, kv_heads=2, head_dim=128,
+        max_seq=262144, num_experts=512, experts_per_token=22,
+        experts_held=(0, 64), shared_expert_hidden=5376, moe_latent=1024,
+        layer_kinds=tuple({"M": "ssm", "*": "gqa", "E": "lmoe"}[c]
+                          for c in "MEMEMEM*EMEMEMEM*EMEME"),
+        ssm_heads=128, ssm_head_dim=64, ssm_groups=8, ssm_state=128,
+        ssm_chunk=128, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        slots=32, max_len=2048, bucket=512),
 }
 
 
@@ -833,6 +848,91 @@ def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     leaves = len(jax.tree.leaves(jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.key(0)))))
     assert _entry_parameters(decode) == leaves + 5 + 6  # k, v, lengths + 3
+    from ray_tpu.observability import schema
+
+    assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+def test_state_space_serve_programs_compile_and_fit(serve_programs):
+    """The `serve-ssm-lmoe-reason-long-out` deployment (Nemotron-H at its
+    published widths, the first 22 layers, 64 of 512 experts, 32 slots x
+    2048): the decode step is given the states, the convolution windows and
+    the K/V rows to keep (all aliased in to out), holds no temporary of one
+    layer's states' size, rewrites the states with the Mosaic call that is
+    given the stack, under `ssm.state`, one a layer body (three scans over
+    the `M E` runs between the attentions and four single layers: 4 bodies
+    with a mixer, 4 with experts); its experts are TWO ungated grouped
+    matmuls a body, the kernel's; the prefill of the 512 bucket runs the
+    recurrence in chunks of 128; both fit the chip under 14 GB, and the
+    scopes reach the compiled text."""
+    from benchmarks import harness, moe_cost, scope_ops
+    from ray_tpu.models import nemotron_h
+
+    cfg, prefill, decode, cache = serve_programs(NEMOTRON_H)
+    slots = cache.lengths.shape[0]
+    assert [cfg.kinds.count(k) for k in ("ssm", "gqa", "lmoe")] == [10, 2, 10]
+    assert [(len(u), r) for u, r in nemotron_h.runs(cfg.kinds)] == [
+        (2, 3), (1, 1), (1, 1), (2, 4), (1, 1), (2, 2), (1, 1)]
+    assert cfg.sparse_layers == 10 and cfg.num_params() == 5370454784
+    assert cache.k.shape == (2, slots, 2048, 2, 128) and cache.state is None
+    assert cache.mat.shape == (10, slots, 128, 128 * 64)
+    assert cache.mat.dtype == jnp.float32
+    assert cache.conv.shape == (10, slots, 3 * 10240)
+    kept = _arg_bytes((cache.k, cache.v, cache.mat, cache.conv))
+    assert round(_arg_bytes(cache.mat) / 1e9, 2) == 1.34
+    for name, program in (("prefill[512]", prefill),
+                          (f"decode[{slots}x2048]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        assert "s32[512]" in program.as_text()  # the load over all experts
+        # no layer's held experts are copied out of their stack
+        assert not re.search(r"bf16\[64,(1024,2688|2688,1024)\]",
+                             program.as_text())
+        assert _total_bytes(program) < 14e9
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    # no copy of the state stack (1.34 GB) or of one layer of it (134 MB)
+    assert m.temp_size_in_bytes < _arg_bytes(cache.mat) / 10
+    assert _total_bytes(prefill) + kept < 14e9  # beside the engine's cache
+    text = decode.as_text()
+    layer_states = math.prod(cache.mat.shape[1:])
+    for op_name, dtype, dims, op in _results(text):
+        assert not (dtype == "f32" and math.prod(dims) == layer_states), (
+            op_name, dims, op)  # (the K stack has as many bfloat16 values)
+        assert math.prod(dims) != 64 * 1024 * 2688, (op_name, dims, op)
+    runner = harness.load_module("runners", "serve_nemotron_h")
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) >= set(runner.SCOPES) - {"ssm.prefill_scan"}
+
+    def mosaic(name):
+        return {scope_ops._INSTRUCTION.match(line)[1]
+                for line in text.splitlines()
+                if "tpu_custom_call" in line and "%" + name in line}
+
+    assert len(mosaic("ssm_state_update")) == 4  # a body with a mixer
+    assert mosaic("ssm_state_update") <= set(scopes["ssm.state"])
+    experts = mosaic("ragged_dot")
+    assert len(experts) == 2 * 4  # up and down, a body with experts
+    assert {op.split(".")[0] for op in experts} == {"ragged_dot_rows"}
+    assert all(moe_cost.EXPERT_OP.search(op) for op in experts)
+    assert experts <= set(scopes["moe_experts"]) and "ragged-dot" not in text
+    assert len(mosaic("decode_attention")) == 2  # the two attention layers
+    assert serve_programs.grouped_paths[NEMOTRON_H] == {
+        "decode": "kernel", "prefill_512": "ragged_dot"}
+    assert serve_programs.attention_paths(NEMOTRON_H) == {
+        "prefill_512": "dense"}
+    pscopes = scope_ops.op_scopes(prefill.as_text(), runner.SCOPES)
+    assert "ssm.prefill_scan" in pscopes and "ssm.state" not in pscopes
+    # a chunk's masked decay matrix [128, 128] a head inside the scan
+    assert re.search(r"f32\[128,128,8,16\]", prefill.as_text())
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    assert _entry_parameters(decode) == leaves + 5 + 5  # k, v, lengths + 2
     from ray_tpu.observability import schema
 
     assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)

@@ -22,6 +22,7 @@ PRESETS = {
     "laguna": ("laguna_debug",),
     "kimi_linear": ("kimi_linear_debug",),
     "longcat": ("longcat_debug",),
+    "nemotron_h": ("nemotron_h_debug",),
 }
 MODULES = (families.ONE_BLOCK, *families.SUBLAYERS, *families.PATTERNS)
 # the fields `families.of` chooses by: set on another family's configuration
@@ -34,7 +35,9 @@ AWAY = dict(
     head_gate=True, dense_mlp_hidden=64, shared_expert_hidden=32,
     experts_held=(0, 2), kda_conv=4, mla_latent=32, mla_rope_dim=8,
     mla_q_rank=8, mla_rotate=True, mla_scales=(2.0, 2.0),
-    router_score="sigmoid", zero_experts=4)
+    router_score="sigmoid", zero_experts=4, ssm_heads=4, ssm_head_dim=16,
+    ssm_groups=4, ssm_state=8, ssm_conv=3, ssm_chunk=64, moe_latent=16,
+    expert_act="relu2")
 BATCH, MAX_LEN = 3, 32
 
 
@@ -69,6 +72,9 @@ def hand_rows(name: str, cfg, lens):
         return (full * int(lens.sum()) + window * int(ring.sum()),
                 full * blocks(lens, MAX_LEN, kv)
                 + window * blocks(ring, cfg.window, kv))
+    if name == "nemotron_h":  # the attention layers' rows; a mixer keeps none
+        gqa = cfg.kinds.count("gqa")
+        return gqa * int(lens.sum()), gqa * blocks(lens, MAX_LEN, kv)
     latent = {"kimi_linear": cfg.kinds.count("mla"),
               "longcat": 2 * cfg.layers}[name]
     width = -(-(cfg.mla_latent + cfg.mla_rope_dim) // 128) * 128
