@@ -37,11 +37,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import families
 from ray_tpu.models.families import Kept
 from ray_tpu.ops.attention import FLASH_KEPT, flash_attention, gqa_expand
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.parallel import ring
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -656,10 +658,21 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
-@jax.named_scope("lora")
+def _lora_xa(x, a):
+    """An adapter's first product, the rows times `a`: what its second
+    product and `a`'s gradient read, so the checkpoint keeps it."""
+    with jax.named_scope("lora"):
+        return checkpoint_name(
+            jnp.einsum("bsh,hr->bsr", x, a.astype(x.dtype)), "lora_xa")
+
+
+def _lora_out(xa, b, scale):
+    with jax.named_scope("lora"):
+        return xa @ b.astype(xa.dtype) * scale
+
+
 def _lora_delta(x, a, b, scale):
-    xa = checkpoint_name(jnp.einsum("bsh,hr->bsr", x, a.astype(x.dtype)), "lora_xa")
-    return xa @ b.astype(x.dtype) * scale
+    return _lora_out(_lora_xa(x, a), b, scale)
 
 
 def _moe_mlp(cfg: TransformerConfig, y, p):
@@ -697,7 +710,7 @@ def _moe_mlp(cfg: TransformerConfig, y, p):
 
     xk = jnp.tile(x, (k, 1)).astype(jnp.float32)                 # [kT,h]
     expert_in = jnp.einsum("pec,ph->ech", dispatch, xk).astype(y.dtype)
-    expert_in = constrain(expert_in, ("expert", None, "embed"))
+    expert_in = constrain(expert_in, ("expert", None, "act_embed"))
     gate = jnp.einsum("ech,ehm->ecm", expert_in, p["wi_gate"].astype(y.dtype))
     up = jnp.einsum("ech,ehm->ecm", expert_in, p["wi_up"].astype(y.dtype))
     act = jax.nn.silu(gate) * up
@@ -921,21 +934,146 @@ def _qk_norm(cfg: TransformerConfig, q, k, p):
     return q, k
 
 
-def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
-           attn_fn):
-    """One decoder block. x [B,S,H_emb] in compute dtype."""
-    p = layer_params
-    nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.hd
-    b, s, h = x.shape
-    scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
+# The residual stream between a block's sublayers, by the rule table's names.
+STREAM = ("batch", "act_rows", "act_embed")
 
-    y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
+
+def _ring_axes():
+    """The mesh axes a block's rings are manual over, or None where the
+    context's mesh has no `tensor` axis larger than one that is still the
+    partitioner's: ("tensor",), or ("sequence", "tensor") where ring
+    attention's axis cuts the rows too and no outer region has bound it."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
+        return None
+    free = lambda a: mesh.shape.get(a, 1) > 1 and a not in mesh.manual_axes
+    if not free("tensor"):
+        return None
+    return tuple(a for a in ("sequence", "tensor") if free(a))
+
+
+def tensor_ring(cfg: TransformerConfig, seq_len: int) -> Optional[Dict[str, int]]:
+    """What a block of a forward over `seq_len` positions traces under the
+    context's mesh: how many of its groups of projections are rings
+    (`_block`: two that gather rows, two that sum and scatter them; a
+    sparse MLP is the partitioner's), of how many turns, over how many rows
+    a turn; None where `_block` traces whole products. Raises where the
+    rows cannot be cut."""
+    if _ring_axes() is None:
+        return None
+    mesh = jax.sharding.get_abstract_mesh()
+    turns = mesh.shape["tensor"]
+    cut = turns * mesh.shape.get("sequence", 1)
+    if seq_len % cut:
+        raise ValueError(
+            f"a sequence of {seq_len} positions cannot be cut over this "
+            f"mesh: between a block's sublayers its rows lie over `sequence` "
+            f"x `tensor` = {cut} chips (the rule table's \"act_rows\"), "
+            f"which does not divide it")
+    return {"rings": 2 if cfg.num_experts else 4, "turns": turns,
+            "rows": seq_len // cut}
+
+
+def _qkv(y, p, lo, scale, xa):
+    """Rows y [B,R,h] through `wq`, `wk`, `wv` and the adapters of `wq` and
+    `wv`: q, k, v [B,R,heads here,D]. ``xa(name)`` is the rows' product with
+    the adapter matrix `name`."""
     q = jnp.einsum("bsh,hnd->bsnd", y, p["wq"].astype(y.dtype))
     k = jnp.einsum("bsh,hnd->bsnd", y, p["wk"].astype(y.dtype))
     v = jnp.einsum("bsh,hnd->bsnd", y, p["wv"].astype(y.dtype))
-    if lora_params is not None:
-        q = q + _lora_delta(y, lora_params["wq_a"], lora_params["wq_b"], scale).reshape(b, s, nh, hd)
-        v = v + _lora_delta(y, lora_params["wv_a"], lora_params["wv_b"], scale).reshape(b, s, nkv, hd)
+    if lo is not None:
+        q = q + _lora_out(xa("wq_a"), lo["wq_b"], scale).reshape(q.shape)
+        v = v + _lora_out(xa("wv_a"), lo["wv_b"], scale).reshape(v.shape)
+    return q, k, v
+
+
+def _gated(y, p, lo, scale, xa):
+    """Rows y [B,R,h] through the gate (with its adapter) and up projections
+    and the activation: [B,R,mlp columns here]."""
+    gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
+    up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
+    if lo is not None:
+        gate = gate + _lora_out(xa("wi_a"), lo["wi_b"], scale)
+    gate = checkpoint_name(gate, "mlp_gate")
+    up = checkpoint_name(up, "mlp_up")
+    act = jax.nn.silu(gate) * up
+    return constrain(act, ("batch", "seq", "mlp"))
+
+
+# The dimension of a block's weight that `tensor` cuts, as a ring's region
+# takes it: the columns of a projection into a sublayer and of an adapter's
+# `b`, the rows of a projection out of it.
+_COLUMNS, _ROWS = P(None, "tensor"), P("tensor")
+_RING_CUT = {"wq": _COLUMNS, "wk": _COLUMNS, "wv": _COLUMNS, "wo": _ROWS,
+             "wi_gate": _COLUMNS, "wi_up": _COLUMNS, "wo_mlp": _ROWS,
+             "wq_b": _COLUMNS, "wv_b": _COLUMNS, "wi_b": _COLUMNS}
+
+
+def _ringed(axes, fn, arrays, arrays_spec, out_specs, p, lo, names):
+    """``fn(index, arrays, p, lo)`` in a `shard_map` region manual over
+    `axes` ALONE, where the rings of `parallel/ring.py` run: the other axes
+    stay the partitioner's, so the frozen weights' gathers over `fsdp` stay
+    its asynchronous ones, and no Pallas call is inside. `arrays` enter cut
+    by `arrays_spec`, `p` and `lo` cut down to `names` and over `tensor` as
+    `_RING_CUT` says, and `index` is the chip's place on `tensor`."""
+    def some(tree):
+        return None if tree is None else {n: tree[n] for n in names if n in tree}
+
+    weights = (some(p), some(lo))
+    turns = jax.sharding.get_abstract_mesh().shape["tensor"]
+    return jax.shard_map(
+        lambda index, arrays, weights: fn(index[0], arrays, *weights),
+        in_specs=(P("tensor"), jax.tree.map(lambda _: arrays_spec, arrays),
+                  tuple(None if w is None else {n: _RING_CUT[n] for n in w}
+                        for w in weights)),
+        out_specs=out_specs, axis_names=set(axes), check_vma=False,
+    )(ring.chip_indices(turns), arrays, weights)
+
+
+def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
+           attn_fn):
+    """One decoder block. x [B,S,H_emb] in compute dtype, its rows cut as
+    `STREAM` says.
+
+    Under a mesh whose `tensor` axis is larger than one (`_ring_axes`) the
+    three groups of projections that meet the stream run as rings of that
+    many turns (`parallel/ring.py`), each group in a `shard_map` region
+    manual over the rows' axes alone: `wq`/`wk`/`wv` and gate/up multiply a
+    chip's rows while they travel on to the next chip, `wo` and the down
+    projection send a partial sum on while they multiply the next chip's
+    rows. q, k, v leave split by heads for the rotation and the kernels
+    (`attn_fn` keeps its own region); what the stream adds arrives with this
+    chip's rows whole. Everywhere else each is one product over all rows."""
+    p, lo = layer_params, lora_params
+    scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
+    axes = _ring_axes()
+    if axes is not None:
+        rows = P(None, axes)  # [B, S, ...]: a chip's rows of the sequence
+        # [B, S, heads, D]: heads over `tensor`, S as far as `sequence` cuts it
+        by_heads = P(None, "sequence" if "sequence" in axes else None, "tensor")
+
+    def xa_of(y):
+        return lambda name: _lora_xa(y, lo[name])
+
+    def carried(y, *names):
+        # what a gathering ring carries round: a chip's rows and their
+        # products with the adapters' `a`
+        return {"y": y, "xa": {} if lo is None else {
+            n: _lora_xa(y, lo[n]) for n in names}}
+
+    y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
+    if axes is None:
+        q, k, v = _qkv(y, p, lo, scale, xa_of(y))
+    else:
+        def qkv(index, mine, p, lo):
+            by_turn = [_qkv(held["y"], p, lo, scale, held["xa"].__getitem__)
+                       for held in ring.gather_turns(mine, "tensor")]
+            return tuple(ring.in_sequence_order(t, "tensor", index)
+                         for t in zip(*by_turn))
+
+        q, k, v = _ringed(axes, qkv, carried(y, "wq_a", "wv_a"), rows,
+                          (by_heads,) * 3, p, lo,
+                          ("wq", "wk", "wv", "wq_b", "wv_b"))
     if cfg.qk_norm:
         q, k = _qk_norm(cfg, q, k, p)
     q = _rope(q, positions, cfg.rope_theta)
@@ -944,25 +1082,43 @@ def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
     q, k, v = (checkpoint_name(t, name)
                for t, name in zip((q, k, v), REMAT_ATTENTION))
     attn = attn_fn(q, k, v)
-    attn = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
-    x = checkpoint_name(x + constrain(attn, ("batch", "seq", "embed")),
-                        "resid_attn")
+
+    def through_wo(attn, p):
+        return jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
+
+    if axes is None:
+        attn = through_wo(attn, p)
+    else:
+        attn = _ringed(
+            axes, lambda index, attn, p, _: ring.scatter_sum(
+                lambda turn: through_wo(
+                    ring.rows_of_turn(attn, turn, "tensor", index), p),
+                "tensor"),
+            attn, by_heads, rows, p, None, ("wo",))
+    x = checkpoint_name(x + constrain(attn, STREAM), "resid_attn")
 
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
+
+    def down(act, p):
+        return jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
+
     if cfg.num_experts:
         out = _moe_mlp(cfg, y, p)
     else:
         with jax.named_scope("mlp"):
-            gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
-            up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
-            if lora_params is not None:
-                gate = gate + _lora_delta(y, lora_params["wi_a"], lora_params["wi_b"], scale)
-            gate = checkpoint_name(gate, "mlp_gate")
-            up = checkpoint_name(up, "mlp_up")
-            act = jax.nn.silu(gate) * up
-            act = constrain(act, ("batch", "seq", "mlp"))
-            out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
-    return x + constrain(out, ("batch", "seq", "embed"))
+            if axes is None:
+                out = down(_gated(y, p, lo, scale, xa_of(y)), p)
+            else:
+                def mlp(index, mine, p, lo):
+                    acts = [_gated(held["y"], p, lo, scale,
+                                   held["xa"].__getitem__)
+                            for held in ring.gather_turns(mine, "tensor")]
+                    return ring.scatter_sum(
+                        lambda turn: down(acts[turn], p), "tensor")
+
+                out = _ringed(axes, mlp, carried(y, "wi_a"), rows, rows, p, lo,
+                              ("wi_gate", "wi_up", "wo_mlp", "wi_b"))
+    return x + constrain(out, STREAM)
 
 
 def _default_attn(cfg: TransformerConfig):
@@ -996,8 +1152,9 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
     attn_fn = attn_fn or _default_attn(cfg)
+    tensor_ring(cfg, tokens.shape[1])  # rows the mesh cannot cut: raises
     x = params["embed"].astype(cfg.dtype)[tokens]
-    x = constrain(x, ("batch", "seq", "embed"))
+    x = constrain(x, STREAM)
 
     blocks, lora = params["blocks"], params.get("lora")
 

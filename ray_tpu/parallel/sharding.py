@@ -6,6 +6,15 @@ arrays with *logical* axis names ("batch", "embed", "mlp", "heads",
 `PartitionSpec`s / `NamedSharding`s mechanically and let XLA's GSPMD
 insert the collectives.
 
+Parameters and activations have axes of their own. A parameter's "embed"
+is cut over `fsdp`; the residual stream of a training block is
+``("batch", "act_rows", "act_embed")``: the batch over the data axes, the
+ROWS of the sequence over ``("sequence", "tensor")`` and the hidden
+dimension whole (Megatron's sequence parallelism: norms, residual adds and
+what a checkpoint keeps of the stream work on a chip's share of the rows).
+Where a mesh's `tensor` axis is larger than one, the projections that meet
+the stream gather or scatter those rows in rings (`parallel/ring.py`).
+
 The reference has no equivalent (its parallelism lives in torch DDP /
 FSDP wrappers, SURVEY.md §2.3) — this module is what replaces all of it.
 """
@@ -22,7 +31,10 @@ Rules = Dict[str, Union[str, Tuple[str, ...], None]]
 
 # Default rule table for transformer LMs. Batch is split over every
 # data-like axis; parameters shard over (fsdp, tensor); sequence over the
-# sequence axis (ring attention); experts over expert.
+# sequence axis (ring attention); experts over expert. "act_rows" and
+# "act_embed" are the residual stream's: its rows go over `sequence`
+# (outermost, ring attention's split) and `tensor`, its hidden dimension is
+# whole on every chip, whatever cuts a parameter's "embed".
 DEFAULT_RULES: Rules = {
     "batch": ("replica", "data", "fsdp"),
     "seq": "sequence",
@@ -36,6 +48,8 @@ DEFAULT_RULES: Rules = {
     "stage": "stage",
     "norm": None,
     "lora_rank": None,
+    "act_rows": ("sequence", "tensor"),
+    "act_embed": None,
 }
 
 
@@ -95,32 +109,26 @@ def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]],
               rules: Optional[Rules] = None) -> jax.Array:
     """`with_sharding_constraint` by logical names — inside jit, under a
     Mesh context this pins intermediate activations so GSPMD doesn't
-    make bad layout choices on the hot path."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:  # not under a mesh context
-            return x
-        spec = spec_for(logical_axes, rules)
-        # Inside a (partial-)manual shard_map region, constraints may only
-        # reference auto axes — drop mesh axes the context binds as manual.
-        manual = {
-            name for name, ty in zip(mesh.axis_names, mesh.axis_types)
-            if "manual" in str(ty).lower()
-        }
-        if manual:
-            def _keep(entry):
-                if entry is None:
-                    return None
-                if isinstance(entry, tuple):
-                    kept = tuple(a for a in entry if a not in manual)
-                    return kept if len(kept) > 1 else (kept[0] if kept else None)
-                return None if entry in manual else entry
-            spec = P(*[_keep(e) for e in spec])
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, spec)
-        )
-    except Exception:
+    make bad layout choices on the hot path. Outside a mesh context it
+    returns `x`; a spec no mesh can take (one mesh axis on two dimensions,
+    whatever its size here) raises, as `NamedSharding` does."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:  # not under a mesh context
         return x
+    spec = spec_for(logical_axes, rules)
+    # Inside a (partial-)manual shard_map region, constraints may only
+    # reference auto axes — drop mesh axes the context binds as manual.
+    manual = set(mesh.manual_axes)
+    if manual:
+        def _keep(entry):
+            if entry is None:
+                return None
+            if isinstance(entry, tuple):
+                kept = tuple(a for a in entry if a not in manual)
+                return kept if len(kept) > 1 else (kept[0] if kept else None)
+            return None if entry in manual else entry
+        spec = P(*[_keep(e) for e in spec])
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def shard_batch(mesh: Mesh, batch: Any, rules: Optional[Rules] = None) -> Any:

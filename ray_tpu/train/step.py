@@ -17,7 +17,10 @@ NamedSharding choices over ONE jitted program (SURVEY.md §2.3):
   of its matmuls and of the flash forward kernel), as far down
   ``REMAT_LADDER`` as the device's memory asks (``run.remat_kept``),
 - sequence axis > 1 switches attention to ring_attention under
-  shard_map (exact, comms overlap compute on ICI).
+  shard_map (exact, comms overlap compute on ICI),
+- tensor axis > 1 keeps the residual stream's rows cut over it between the
+  sublayers, and the sums and gathers the projections then need run as
+  rings under the products (``run.tensor_ring``).
 
 Everything compiles to a single XLA program per step; donated input
 state keeps HBM flat."""
@@ -35,7 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.transformer import (
     REMAT_LADDER, TransformerConfig, forward, init_params, loss_fn, param_axes,
-    trainable_mask,
+    tensor_ring, trainable_mask,
 )
 from ray_tpu.ops.attention import flash_attention, gqa_expand
 from ray_tpu.ops.ring_attention import ring_attention
@@ -258,7 +261,20 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     fits is compiled once (the call reuses the executable); a step that
     falls back pays one more compile a rung. Where no limit can be read the
     first rung stands. ``run.remat_kept`` is the names taken: ``()`` for
-    the bare checkpoint, None without ``cfg.remat``."""
+    the bare checkpoint, None without ``cfg.remat``.
+
+    The residual stream between a block's sublayers is the rule table's
+    ``("batch", "act_rows", "act_embed")``: rows over ``("sequence",
+    "tensor")``, the hidden dimension whole. On every mesh whose `tensor`
+    axis is larger than one (alone, beside `fsdp`/`data`, with `sequence`,
+    inside the pipeline's `stage` region) the projections that meet it run
+    as rings (``transformer._block``, ``parallel/ring.py``), and
+    ``run.tensor_ring``, fixed when the step is traced, says what a block
+    traced: ``{"rings": 4, "turns": 2, "rows": 1024}`` for the groups of
+    projections (2 where the MLP is sparse), the `tensor` axis' size and a
+    turn's rows of the sequence; None on any other mesh, where a block
+    traces whole products. A sequence that `sequence` x `tensor` does not
+    divide fails at trace time."""
     rules = _effective_rules(mesh, rules)
     attn = make_attn_fn(cfg, mesh, rules)
     n_stage = mesh_axis_size(mesh, "stage")
@@ -277,6 +293,7 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     }
 
     def step(kept, state: TrainState, batch: Dict[str, jax.Array]):
+        run.tensor_ring = tensor_ring(cfg, batch["tokens"].shape[1])
         params = state["params"]
         trainable = jax.tree.map(lambda m, p: p if m else None, mask, params)
 
@@ -365,6 +382,7 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     run._shardings = shardings
     run._batch_sharding = b_shard
     run.differentiated = differentiated
+    run.tensor_ring = None  # until a trace has seen the batch's rows
     return run
 
 

@@ -248,7 +248,9 @@ def test_lora_step_compiles_for_four_chips(train_steps):
     compiled, state, _, _ = train_steps("four")
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert "all-gather" in text and "all-reduce" in text
+    # the frozen weights gathered over `fsdp`, a block's rows carried round
+    # `tensor` (PR 55: the whole sums of the stream are rings' transfers)
+    assert "all-gather" in text and "collective-permute-start" in text
     # every large leaf is sharded over fsdp x tensor: a device holds about
     # a quarter of the state (norms and the step counter are replicated)
     per_device = compiled.memory_analysis().argument_size_in_bytes
@@ -298,6 +300,75 @@ def test_lora_step_runs_no_matmul_and_no_flash_forward_twice(train_steps, chips)
           f"{mem.temp_size_in_bytes / 1e9:.3f} = {total / 1e9:.3f} GB")
     assert compiled.cost_analysis()["flops"] < flops
     assert total < fits
+
+
+def _computations(text):
+    """{name: lines} of a compiled program's computations."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head[1]
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return {name: "\n".join(lines) for name, lines in out.items()}
+
+
+def _scanned_bodies(text):
+    """The text of every `while` body of a compiled program and of the
+    computations a body calls: what runs once a layer."""
+    comps = _computations(text)
+    todo = [body for comp in comps.values()
+            for body in re.findall(r" while\(.*?body=%([\w.\-]+)", comp)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                           comps[name])
+    return "\n".join(comps[name] for name in sorted(seen))
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute", "collective-broadcast")
+
+
+@pytest.mark.parametrize("chips", ["one", "four"])
+def test_lora_step_sums_the_stream_over_tensor_in_rings(train_steps, chips):
+    """The train cells' own step (PR 55). On `fsdp=2 x tensor=2` a block's
+    rows lie over `tensor` between its sublayers and its projections are
+    rings of 2 turns over 1,024 rows: the scanned bodies hold no `all-reduce`
+    (nor `reduce-scatter`) of the whole residual `bf16[2,2048,4096]` (four a
+    layer before), and half the rows travel in `collective-permute`s that are
+    `-start` / `-done` pairs, so a product can run between them; the
+    checkpoint still keeps its richest rung. On one chip nothing is cut and
+    the step holds no collective at all."""
+    compiled, _, _, step = train_steps(chips, "bench")
+    text = compiled.as_text()
+    found = set(re.findall(
+        r" (%s)(?:-start|-done)?\(" % "|".join(COLLECTIVES), text))
+    assert step.remat_kept == T.REMAT_LADDER[0]
+    if chips == "one":
+        assert step.tensor_ring is None
+        assert not found
+        return
+    assert step.tensor_ring == {"rings": 4, "turns": 2, "rows": 1024}
+    bodies = _scanned_bodies(text)
+    assert "tpu_custom_call" in bodies  # the layers' bodies, not a helper's
+    whole = [(name, shape) for name, shape in _summed(bodies)
+             if shape == "bf16[2,2048,4096]"]
+    assert not whole, whole
+    half = r"bf16\[2,1024,4096\]\S*"
+    starts = re.findall(rf"= \({half}, [^=]*\) collective-permute-start\(",
+                        bodies)
+    dones = re.findall(rf"= {half} collective-permute-done\(", bodies)
+    alone = re.findall(rf"= {half} collective-permute\(", bodies)
+    print(f"four: {len(starts)} transfers of half the rows in the bodies")
+    # a layer's forward and backward: four rings each, one transfer a ring
+    assert len(starts) == len(dones) >= 8 and not alone
 
 
 def _summed(text):

@@ -373,6 +373,97 @@ class TestRematKeepsWhatTheBackwardReads:
         assert dots() > first
 
 
+RING_MESHES = {
+    "fsdp2xtp2": (MeshSpec(fsdp=2, tensor=2), {}),
+    "tp2xsp2": (MeshSpec(tensor=2, sequence=2), {}),
+    "pp2xtp2": (MeshSpec(stage=2, tensor=2), {"num_microbatches": 4}),
+    "dp2xtp4": (MeshSpec(data=2, tensor=4), {}),
+}
+
+
+class TestTensorRings:
+    """A block's projections as rings over `tensor` (`parallel/ring.py`,
+    `T._block`): the numbers are the one-device block's."""
+
+    @staticmethod
+    def _cfg(**kw):
+        # 4 KV heads: `tensor=4` cuts them; adapters on, checkpoint on
+        return T.config("debug", lora_rank=4, kv_heads=4, remat=True,
+                        dtype=jnp.float32, **kw)
+
+    @staticmethod
+    def _params(cfg):
+        """Fresh parameters with every adapter's `b` off zero, so that `a`
+        has a gradient too."""
+        params = T.init_params(cfg, jax.random.key(0))
+        keys = jax.random.split(jax.random.key(1), len(params["lora"]))
+        params["lora"] = {
+            name: (0.05 * jax.random.normal(k, leaf.shape, leaf.dtype)
+                   if name.endswith("_b") else leaf)
+            for k, (name, leaf) in zip(keys, sorted(params["lora"].items()))}
+        return params
+
+    @staticmethod
+    def _loss_and_grads(cfg, mesh, params, batch, **kw):
+        """The loss and its gradient by the adapters as the train step takes
+        them, and what `T.tensor_ring` says the blocks traced."""
+        attn = S.make_attn_fn(cfg, mesh)
+        pp_mesh = mesh if mesh.shape["stage"] > 1 else None
+        seen = {}
+
+        @jax.jit
+        def probe(params, batch):
+            seen["ring"] = T.tensor_ring(cfg, batch["tokens"].shape[1])
+            return jax.value_and_grad(lambda lora: T.loss_fn(
+                cfg, dict(params, lora=lora), batch, attn_fn=attn,
+                mesh=pp_mesh, **kw)[0])(params["lora"])
+
+        params = jax.device_put(
+            params, S.state_shardings(cfg, S.default_optimizer(cfg), mesh)["params"])
+        with jax.set_mesh(mesh):
+            loss, grads = probe(params, batch)
+        return float(loss), jax.device_get(grads), seen["ring"]
+
+    @pytest.mark.parametrize("name", sorted(RING_MESHES))
+    def test_loss_and_adapter_gradients_are_the_one_device_steps(self, name):
+        spec, kw = RING_MESHES[name]
+        cfg = self._cfg()
+        params, batch = self._params(cfg), _batch(cfg)
+        one = build_mesh(MeshSpec(), [jax.devices()[0]])
+        want_loss, want, ring = self._loss_and_grads(cfg, one, params, batch)
+        assert ring is None
+        mesh = build_mesh(spec)
+        loss, got, ring = self._loss_and_grads(cfg, mesh, params, batch, **kw)
+        turns = mesh.shape["tensor"]
+        assert ring == {"rings": 4, "turns": turns,
+                        "rows": 64 // turns // mesh.shape["sequence"]}
+        np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+        for leaf in sorted(want):
+            assert np.abs(want[leaf]).max() > 0, leaf
+            np.testing.assert_allclose(got[leaf], want[leaf], rtol=1e-4,
+                                       atol=1e-6, err_msg=leaf)
+
+    def test_the_step_says_what_its_blocks_traced(self):
+        cfg = self._cfg()
+        for spec, want in [(MeshSpec(fsdp=2, tensor=2),
+                            {"rings": 4, "turns": 2, "rows": 32}),
+                           (MeshSpec(fsdp=4), None)]:
+            mesh = build_mesh(spec)
+            opt = S.default_optimizer(cfg)
+            ts = S.make_train_step(cfg, opt, mesh)
+            assert ts.tensor_ring is None  # nothing traced yet
+            ts(S.init_state(cfg, opt, mesh), _batch(cfg))
+            assert ts.tensor_ring == want
+
+    def test_rows_the_mesh_cannot_cut_fail_at_trace_time(self):
+        cfg = self._cfg()
+        mesh = build_mesh(MeshSpec(data=2, tensor=4))
+        opt = S.default_optimizer(cfg)
+        ts = S.make_train_step(cfg, opt, mesh)
+        with pytest.raises(ValueError, match="cannot be cut over this mesh"):
+            ts(S.init_state(cfg, opt, mesh), _batch(cfg, s=62))
+
+
 class TestCheckpoint:
     def test_save_restore_roundtrip(self, tmp_path):
         from ray_tpu.train import restore_state, save_state
