@@ -22,6 +22,7 @@ from ray_tpu._private.core_worker import CoreWorker
 from ray_tpu._private.ids import JobID
 from ray_tpu._private.node import Node
 from ray_tpu._private.rpc import RpcClient, clear_client_cache
+from ray_tpu.observability import timeline as obs_timeline
 
 logger = logging.getLogger(__name__)
 
@@ -81,12 +82,17 @@ class ClusterRuntime(CoreWorker):
             gcs.close()
 
         # register the driver's job
-        runtime = cls(node, gcs_addr, raylet_addr, store_socket, node_id, JobID.from_int(0))
-        reply = runtime.gcs.call_retrying("RegisterJob", driver_addr=runtime.address, metadata={})
-        runtime.job_id = JobID.from_int(reply["job_id_int"])
+        with obs_timeline.setup_phase("ray_tpu.setup.init.connect"):
+            runtime = cls(node, gcs_addr, raylet_addr, store_socket, node_id, JobID.from_int(0))
+            reply = runtime.gcs.call_retrying("RegisterJob", driver_addr=runtime.address, metadata={})
+            runtime.job_id = JobID.from_int(reply["job_id_int"])
         return runtime
 
     def shutdown(self) -> None:
+        if self._node is not None:
+            # the cluster ends with this driver: its set-up record stays
+            # readable here (observability.setup_record)
+            obs_timeline.keep_setup_record(self.gcs)
         try:
             self.gcs.call("MarkJobFinished", job_id=self.job_id.hex(), timeout=5)
         except Exception:  # GCS may already be gone — finish local teardown

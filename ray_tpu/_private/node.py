@@ -24,6 +24,7 @@ import psutil
 from ray_tpu._private.config import config
 from ray_tpu._private.ids import NodeID
 from ray_tpu._private.rpc import RpcClient
+from ray_tpu.observability import timeline as obs_timeline
 
 logger = logging.getLogger(__name__)
 
@@ -220,16 +221,22 @@ class Node:
         return ("127.0.0.1", self.raylet_port)
 
     def start(self) -> None:
-        self.gcs_proc = spawn_gcs(self.gcs_port, self.session_dir)
-        self.raylet_proc, self.raylet_port = spawn_raylet(
-            gcs_addr=self.gcs_addr,
-            node_id=self.node_id,
-            resources=self.resources,
-            store_socket=self.store_socket,
-            store_capacity=self.store_capacity,
-            session_dir=self.session_dir,
-            is_head=True,
-        )
+        from ray_tpu._private.object_store.client import store_binary_path
+
+        with obs_timeline.setup_phase("ray_tpu.setup.init.gcs"):
+            self.gcs_proc = spawn_gcs(self.gcs_port, self.session_dir)
+        with obs_timeline.setup_phase("ray_tpu.setup.init.raylet") as attrs:
+            # the raylet builds the store's daemon where it is missing
+            attrs["native_built"] = not os.path.exists(store_binary_path())
+            self.raylet_proc, self.raylet_port = spawn_raylet(
+                gcs_addr=self.gcs_addr,
+                node_id=self.node_id,
+                resources=self.resources,
+                store_socket=self.store_socket,
+                store_capacity=self.store_capacity,
+                session_dir=self.session_dir,
+                is_head=True,
+            )
         atexit.register(self.stop)
 
     def _wait_rpc_ready(self, addr: Tuple[str, int], name: str, timeout: float = 30.0) -> None:

@@ -136,17 +136,21 @@ def init(
             w.core = ClientRuntime(str(address)[len("ray://"):])
             w.core.job_runtime_env = runtime_env or {}
         else:
-            from ray_tpu._private.cluster_runtime import ClusterRuntime
+            from ray_tpu.observability.timeline import setup_phase
 
-            w.core = ClusterRuntime.create(
-                address=address,
-                num_cpus=num_cpus,
-                num_tpus=num_tpus,
-                resources=resources,
-                object_store_memory=object_store_memory,
-                namespace=namespace,
-                dashboard=dashboard,
-            )
+            with setup_phase("ray_tpu.setup.init"):
+                # the runtime's import is part of what a start costs
+                from ray_tpu._private.cluster_runtime import ClusterRuntime
+
+                w.core = ClusterRuntime.create(
+                    address=address,
+                    num_cpus=num_cpus,
+                    num_tpus=num_tpus,
+                    resources=resources,
+                    object_store_memory=object_store_memory,
+                    namespace=namespace,
+                    dashboard=dashboard,
+                )
             w.job_id = w.core.job_id
             # job-level runtime env: merged under every task/actor env
             w.core.job_runtime_env = runtime_env or {}

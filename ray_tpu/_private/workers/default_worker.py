@@ -578,6 +578,10 @@ class WorkerServer:
         self._jax_setting: Any = "cpu"
         self._jax_bound: Any = None
         self.actors: Dict[str, _ActorRunner] = {}
+        # when the raylet took this worker's registration (`main`), and
+        # whether its boot has been booked (`_book_boot`)
+        self.ready_mono: Optional[float] = None
+        self._boot_booked = False
         self._task_pool = ThreadPoolExecutor(max_workers=8, thread_name_prefix="exec")
         from collections import OrderedDict
 
@@ -860,6 +864,22 @@ class WorkerServer:
         return {"ok": n == 1}
 
     # -- actors ---------------------------------------------------------
+    def _book_boot(self) -> None:
+        """`setup.worker.boot`, once a process, when its first actor
+        arrives: the spawn (the raylet's stamp on this host's monotonic
+        clock) -> now. A worker that then idled in the pool for longer than
+        its boot took says `pooled`: the lease did not pay for the boot."""
+        spawned = os.environ.get("RAY_TPU_WORKER_SPAWNED_MONO")
+        if self._boot_booked or not spawned:
+            return
+        self._boot_booked = True
+        spawned, now = float(spawned), time.monotonic()
+        ready_s = (self.ready_mono or now) - spawned
+        obs_timeline.record_setup_phase(
+            "ray_tpu.setup.worker.boot", time.time() - (now - spawned),
+            spawned, now - spawned, ready_s=ready_s,
+            pooled=now - spawned > 2 * ready_s)
+
     def CreateActor(self, actor_id: str, serialized_spec: bytes) -> dict:
         import pickle
 
@@ -874,13 +894,17 @@ class WorkerServer:
                 actor_id, "worker_started",
                 spawn_age_s=round(time.monotonic() - float(spawned), 3)
                 if spawned else None)
+        self._book_boot()
         spec = pickle.loads(serialized_spec)
         self._apply_py_paths(spec.get("py_paths"))
         try:
-            self._apply_runtime_env(spec.get("runtime_env"))
-            cls = loads_function(spec["serialized_class"])
-            args, kwargs = _resolve_args(spec["args"], spec["kwargs"])
-            instance = cls(*args, **kwargs)
+            with obs_timeline.setup_phase("ray_tpu.setup.actor.init",
+                                          actor_id=actor_id) as attrs:
+                self._apply_runtime_env(spec.get("runtime_env"))
+                cls = loads_function(spec["serialized_class"])
+                attrs["cls"] = getattr(cls, "__name__", "")
+                args, kwargs = _resolve_args(spec["args"], spec["kwargs"])
+                instance = cls(*args, **kwargs)
         except BaseException as e:  # noqa: BLE001
             return {"ok": False, "error": f"{type(e).__name__}: {e}\n{traceback.format_exc()}"}
         obs_timeline.mark_actor(actor_id, "init_done")
@@ -990,8 +1014,8 @@ def main() -> None:
     )
     w.core = core
     w.reference_counter.set_on_zero_callback(core.free_object)
-    WorkerServer(core, (raylet_host, int(raylet_port)), worker_id,
-                 node_jax_platforms)
+    server = WorkerServer(core, (raylet_host, int(raylet_port)), worker_id,
+                          node_jax_platforms)
 
     # process-lifetime client: the raylet owns this process and the
     # block-forever wait below never falls through —
@@ -1002,6 +1026,7 @@ def main() -> None:
         logger.error("raylet rejected registration")
         raylet.close()
         return
+    server.ready_mono = time.monotonic()
     logger.info("worker %s serving at %s", worker_id[:8], core.address)
 
     # block forever; raylet owns our lifetime

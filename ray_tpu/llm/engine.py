@@ -10,6 +10,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 
 from ray_tpu.llm.config import LLMConfig
 from ray_tpu.models.decoding import Generator, SamplingParams
+from ray_tpu.observability.timeline import setup_phase
 
 
 class LLMEngine:
@@ -17,9 +18,14 @@ class LLMEngine:
         import jax
 
         from ray_tpu.models import transformer as T
-        from ray_tpu.parallel.bootstrap import configure_compilation_cache
+        from ray_tpu.parallel.bootstrap import (
+            configure_compilation_cache,
+            process_compiles,
+        )
 
         configure_compilation_cache()
+        with setup_phase("ray_tpu.setup.engine.backend"):
+            jax.devices()
         self.config = config
         self.tokenizer = config.get_tokenizer()
         cfg = T.config(config.model)
@@ -28,14 +34,22 @@ class LLMEngine:
             # model must cover the tokenizer's id space
             cfg = T.config(cfg, vocab_size=int(vocab))
         self.model_config = cfg
-        if config.params_path:
-            from ray_tpu.train.checkpoint import restore_state
+        compiles = process_compiles()
+        programs = compiles["programs"]
+        with setup_phase("ray_tpu.setup.engine.params") as attrs:
+            if config.params_path:
+                from ray_tpu.train.checkpoint import restore_state
 
-            params_shape = jax.eval_shape(
-                lambda: T.init_params(cfg, jax.random.key(0)))
-            params = restore_state(config.params_path, target=params_shape)
-        else:
-            params = T.init_params(cfg, jax.random.key(config.seed))
+                params_shape = jax.eval_shape(
+                    lambda: T.init_params(cfg, jax.random.key(0)))
+                params = restore_state(config.params_path,
+                                       target=params_shape)
+            else:
+                params = T.init_params(cfg, jax.random.key(config.seed))
+            params = jax.block_until_ready(params)
+            attrs.update(
+                bytes=sum(p.nbytes for p in jax.tree.leaves(params)),
+                programs=compiles["programs"] - programs)
         self.generator = Generator(cfg, params, max_len=config.max_len)
         self._call_count = 0
 

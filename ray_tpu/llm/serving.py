@@ -21,6 +21,7 @@ from ray_tpu._private import streaming
 from ray_tpu.llm.config import LLMConfig
 from ray_tpu.models.decoding import SamplingParams
 from ray_tpu.observability import schema
+from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.observability.tracing import device_span
 
 
@@ -41,14 +42,15 @@ def build_llm_deployment(config: LLMConfig):
             from ray_tpu.parallel.bootstrap import watch_compiles
 
             self._compiles = watch_compiles()
-            if config.continuous_batching:
-                from ray_tpu.llm.engine import ContinuousLLMEngine
+            with setup_phase("ray_tpu.setup.engine.build"):
+                if config.continuous_batching:
+                    from ray_tpu.llm.engine import ContinuousLLMEngine
 
-                self.engine = ContinuousLLMEngine(config)
-            else:
-                from ray_tpu.llm.engine import LLMEngine
+                    self.engine = ContinuousLLMEngine(config)
+                else:
+                    from ray_tpu.llm.engine import LLMEngine
 
-                self.engine = LLMEngine(config)
+                    self.engine = LLMEngine(config)
             self.tokenizer = self.engine.tokenizer
 
         @serve.batch(max_batch_size=config.batch_max_size,

@@ -82,9 +82,11 @@ from ray_tpu.models.decoding import (
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import schema as spans
+from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.observability.tracing import device_span
 from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.attention import NEG_INF, decode_block
+from ray_tpu.parallel.bootstrap import FirstCall
 
 
 # passes of the pump between two bookings of its clocks (`_book`)
@@ -132,8 +134,11 @@ def _sample_per_slot(logits, rng, temps, topks, active):
 
 
 # an admit's first token: the same function as a program of its own (called
-# eagerly, a `lax.cond` is traced and compiled again at every call)
-_sample_first = jax.jit(_sample_per_slot)
+# eagerly, a `lax.cond` is traced and compiled again at every call). Its
+# first call in the process is booked (`FirstCall`), then the name holds the
+# bare jitted callable
+_sample_first = FirstCall(jax.jit(_sample_per_slot), "sample_first",
+                          globals(), "_sample_first")
 
 
 @dataclasses.dataclass
@@ -272,7 +277,9 @@ class PrefillPrograms:
         """The bucket's prefill program, compiled at its first prompt."""
         pf = self._prefill_jits.get(bucket)
         if pf is None:
-            pf = self._prefill_jits[bucket] = jax.jit(self._prefill_impl)
+            pf = self._prefill_jits[bucket] = FirstCall(
+                jax.jit(self._prefill_impl), f"prefill_{bucket}",
+                self._prefill_jits, bucket)
         return pf
 
     def _prefill(self, tokens: Sequence[int]):
@@ -302,7 +309,10 @@ class ContinuousBatcher(PrefillPrograms):
         self._wake = threading.Event()
         self._shutdown = False
         self._rng = jax.random.key(seed)
-        self.cache = self._empty_cache()
+        with setup_phase("ray_tpu.setup.engine.cache") as attrs:
+            self.cache = jax.block_until_ready(self._empty_cache())
+            attrs["bytes"] = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.cache))
         # per-slot host-side state (no device sync on the emit path)
         self._temps = np.zeros(slots, np.float32)
         self._topks = np.zeros(slots, np.int32)
@@ -447,11 +457,15 @@ class ContinuousBatcher(PrefillPrograms):
         it in place, `self.cache` is replaced by what they return, and no
         one may hold the cache that went in."""
         super()._jit_programs()
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(2,))
-        self._install_jit = jax.jit(self._install_impl,
-                                    donate_argnums=(0,))
-        self._reset_state_jit = jax.jit(self._reset_state_impl,
-                                        donate_argnums=(0,))
+        self._decode_jit = FirstCall(
+            jax.jit(self._decode_impl, donate_argnums=(2,)), "decode",
+            self.__dict__, "_decode_jit")
+        self._install_jit = FirstCall(
+            jax.jit(self._install_impl, donate_argnums=(0,)), "install",
+            self.__dict__, "_install_jit")
+        self._reset_state_jit = FirstCall(
+            jax.jit(self._reset_state_impl, donate_argnums=(0,)),
+            "reset_state", self.__dict__, "_reset_state_jit")
 
     def _install_impl(self, cache: KVCache, row_k, row_v, slot, length,
                       *kept):

@@ -46,10 +46,13 @@ from ray_tpu.observability.export import (
     export_trace,
     to_chrome_trace,
 )
-from ray_tpu.observability.schema import EVENT_TYPES
+from ray_tpu.observability.schema import EVENT_TYPES, SETUP_PHASES
 from ray_tpu.observability.timeline import (
     mark_actor,
     mark_task,
+    record_setup_phase,
+    setup_phase,
+    setup_record,
 )
 from ray_tpu.observability.tracing import (
     TraceContext,
@@ -72,6 +75,10 @@ __all__ = [
     "EVENT_TYPES",
     "mark_actor",
     "mark_task",
+    "SETUP_PHASES",
+    "setup_phase",
+    "record_setup_phase",
+    "setup_record",
     "counter_sample",
     "dump_now",
     "trigger_cluster_dump",

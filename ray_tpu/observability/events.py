@@ -78,6 +78,9 @@ class EventBuffer:
         self._pending: List[dict] = []
         self._dropped = 0
         self._flusher_started = False
+        # set while there may be something to ship: the flusher waits on
+        # it, so a process with nothing to ship runs no Python for it
+        self._work = threading.Event()
 
     @classmethod
     def get(cls) -> "EventBuffer":
@@ -94,6 +97,7 @@ class EventBuffer:
                 drop = _PENDING_MAX // 2
                 del self._pending[:drop]
                 self._dropped += drop
+        self._work.set()
         self._ensure_flusher()
 
     def recent(self, etype: Optional[str] = None) -> List[dict]:
@@ -158,10 +162,15 @@ class EventBuffer:
                 overflow = len(self._pending) - _PENDING_MAX
                 del self._pending[:overflow]
                 self._dropped += overflow
+        self._work.set()  # the flusher tries again a period on
 
     def _flush_loop(self) -> None:
         while True:
-            time.sleep(_FLUSH_PERIOD_S)
+            self._work.wait()
+            time.sleep(_FLUSH_PERIOD_S)  # gather a batch
+            # cleared BEFORE the drain: an event recorded in between costs
+            # one empty pass, never a batch left behind
+            self._work.clear()
             try:
                 self.flush_once()
             except Exception:  # noqa: BLE001 — the bus must never die

@@ -37,10 +37,83 @@ EVENT_TYPES = {
     # control-plane lifecycle timelines (observability/timeline.py)
     "actor_lifecycle": "actor_id, phase, mono, job_id, node_id?",
     "task_lifecycle": "task_id, phase, mono, job_id",
+    # bring-up intervals (observability/timeline.py ``setup_phase``, the
+    # one producer): ``mono`` / ``ts`` are the interval's START on
+    # time.monotonic() / time.time(), ``name`` is a key of SETUP_PHASES
+    "setup_phase": "name, mono, dur, attrs",
     # flight-recorder dumps (observability/dump.py)
     "debug_dump": "reason, path, source",
     # podracer stage accounting (rllib/podracer/obs.py snapshots)
     "podracer_stage": "stages {name: {s, n}}, role",
+}
+
+# Set-up phases (observability/timeline.py ``setup_phase``): what a process
+# does ONCE before its first useful step, booked as one ``setup_phase`` event
+# an interval, always on (two clock reads and one ``record_event`` a phase,
+# nothing a step, a token or a request). A closed vocabulary, a dict literal
+# with literal keys like EVENT_TYPES (RC009 reads it via AST); a name that is
+# not here raises. Children lie inside their parent's interval. Value: the
+# process that books it, what the interval covers, [its attrs].
+SETUP_PHASES = {
+    # driver: _private/worker.py::init, _private/node.py::Node.start
+    "ray_tpu.setup.init": "driver: ray_tpu.init(), whole",
+    "ray_tpu.setup.init.gcs": "driver: the GCS spawned -> it answers Ping",
+    "ray_tpu.setup.init.raylet":
+        "driver: the raylet spawned -> its port bound: the object store's "
+        "daemon started; it registers with the GCS after [native_built: "
+        "the daemon was built first, a fresh checkout's first run]",
+    "ray_tpu.setup.init.connect":
+        "driver: the core worker's connections, the job's registration",
+    # driver: serve/controller.py::run, serve/http_proxy.py
+    "ray_tpu.setup.serve.run": "driver: serve.run(), whole",
+    "ray_tpu.setup.serve.controller":
+        "driver: the Serve controller looked up or created",
+    "ray_tpu.setup.serve.deploy":
+        "driver: ctl.deploy sent -> its snapshot back: scheduling, the "
+        "lease, the worker's start, the replica's __init__, the health "
+        "check",
+    "ray_tpu.setup.serve.proxy": "driver: the HTTP proxy bound and serving",
+    # every worker: _private/workers/default_worker.py::CreateActor
+    "ray_tpu.setup.worker.boot":
+        "worker, once a process: its spawn (RAY_TPU_WORKER_SPAWNED_MONO) -> "
+        "its first actor's arrival [ready_s: spawn -> registered with the "
+        "raylet; pooled: it then idled in the pool for longer than that, so "
+        "the interval is no part of the actor's bring-up]",
+    "ray_tpu.setup.actor.init":
+        "worker: an actor's runtime env applied, its class and arguments "
+        "unpickled, the class's __init__ [actor_id, cls]",
+    # the replica: llm/engine.py, llm/serving.py, models/continuous_batching
+    "ray_tpu.setup.engine.build":
+        "replica: the engine's constructor, whole (LLMServer.__init__)",
+    "ray_tpu.setup.engine.backend":
+        "replica: the first jax.devices(): the backend's start, unless "
+        "the caller's code touched it before",
+    "ray_tpu.setup.engine.params":
+        "replica: init_params (a pattern's _draw) or the checkpoint's "
+        "restore, waited for [bytes, programs: executables obtained]",
+    "ray_tpu.setup.engine.cache":
+        "replica: the slots' cache allocated, waited for [bytes]",
+    # parallel/bootstrap.py::FirstCall, round every jitted program of the
+    # engine and round the train step
+    "ray_tpu.setup.program":
+        "chip process: a jitted program's FIRST call, waited for [program: "
+        "prefill_<bucket> | decode | install | reset_state | sample_first | "
+        "train_step; trace_s, lower_s, compile_s: JAX's own events over the "
+        "call (compile_s holds a cache read); cache: hit | miss | none; "
+        "first_run_s: the call's wall less those three: the executable's "
+        "load, the first transfers, the first execution]",
+    # train/step.py::make_train_step
+    "ray_tpu.setup.step.build":
+        "chip process: make_train_step's own Python (shapes, shardings, the "
+        "trainable mask)",
+    "ray_tpu.setup.step.settle":
+        "chip process: the first call's way down REMAT_LADDER, whole "
+        "[rungs_tried: rungs the step stood on, 1 where no limit can be "
+        "read; kept: the names taken, as run.remat_kept]",
+    "ray_tpu.setup.step.rung":
+        "chip process: one rung compiled ahead of time [kept; lower_s: its "
+        "tracing and lowering; compile_s; bytes: what it needs, None where "
+        "the compiler refused it; fits]",
 }
 
 # Device spans (observability/tracing.py ``device_span``): host spans on

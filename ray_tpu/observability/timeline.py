@@ -23,6 +23,13 @@ deterministic hash of the task id (``RAY_TPU_TIMELINE_TASK_SAMPLE``)
 so a 100k-task flood doesn't swamp the aggregator while any given
 task's timeline stays all-or-nothing.
 
+Set-up has a record of its own, ALWAYS on: ``setup_phase`` books an
+interval of a process's bring-up (a closed vocabulary,
+``schema.SETUP_PHASES``) as one ``setup_phase`` bus event, run once a
+process or once a jitted program and never on a step's, a token's or a
+request's path. ``setup_record()`` returns every process's intervals;
+a driver keeps them past the ``shutdown()`` that stops its cluster.
+
 The analysis half is pure functions over event dicts — shared by the
 GCS aggregator (state API), ``tools/obsdump`` (offline shards) and
 ``scale_bench`` (the per-phase bring-up row).
@@ -30,12 +37,18 @@ GCS aggregator (state API), ``tools/obsdump`` (offline shards) and
 
 from __future__ import annotations
 
+import contextlib
+import logging
 import os
 import time
 import zlib
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 from ray_tpu.observability import events as _events
+from ray_tpu.observability.schema import SETUP_PHASES
+from ray_tpu.observability.tracing import device_span
+
+logger = logging.getLogger(__name__)
 
 # canonical phase orders (documentation + plot ordering; analysis uses
 # observed timestamps, so a missing or out-of-order mark degrades to
@@ -104,6 +117,109 @@ def mark_task(task_id: str, phase: str, **fields: Any) -> None:
         return
     _events.record_event("task_lifecycle", task_id=task_id,
                          phase=phase, mono=time.monotonic(), **fields)
+
+
+# =====================================================================
+# set-up phases — always on
+# =====================================================================
+
+SETUP_RECORD_MAX = 2000  # the newest events a record asks the GCS for
+_KEEP_LIMIT_S = 2.0  # what a shutdown may wait for them
+# what the cluster's aggregator held when this process stopped the cluster
+# it had started (`keep_setup_record`): the set-up of a job outlives it
+_kept: List[dict] = []
+
+
+def _declared(name: str) -> None:
+    if name not in SETUP_PHASES:
+        raise ValueError(f"{name!r} is no set-up phase: "
+                         f"observability/schema.py SETUP_PHASES names them")
+
+
+def record_setup_phase(name: str, ts: float, mono: float, dur: float,
+                       **attrs: Any) -> None:
+    """Book one interval of this process's bring-up, known at its end:
+    ``ts`` / ``mono`` are its START on ``time.time()`` /
+    ``time.monotonic()``, ``dur`` its seconds. ``name`` is a key of
+    ``schema.SETUP_PHASES``; any other raises."""
+    _declared(name)
+    _events.record_event("setup_phase", name=name, ts=ts, mono=mono,
+                         dur=dur, attrs=attrs)
+
+
+@contextlib.contextmanager
+def setup_phase(name: str, **attrs: Any) -> Iterator[Dict[str, Any]]:
+    """Book the enclosed interval of this process's bring-up. Yields the
+    event's ``attrs``, to be filled inside. Also a ``device_span`` of the
+    same name: in a process that has loaded JAX, a profiler capture that
+    runs during set-up shows the phase on the device trace's clock. A
+    phase that raises is booked with the exception's type as ``error``."""
+    _declared(name)
+    ts, mono = time.time(), time.monotonic()
+    try:
+        with device_span(name):
+            yield attrs
+    except BaseException as e:
+        attrs.setdefault("error", type(e).__name__)
+        raise
+    finally:
+        record_setup_phase(name, ts, mono, time.monotonic() - mono, **attrs)
+
+
+def merge_setup_phases(*event_lists: List[dict]) -> List[dict]:
+    """``setup_phase`` events from several sources (the aggregator's, a
+    process's own ring) as one record: ``[{name, worker, ts, mono, gts,
+    dur, attrs}]`` sorted by start, an interval that several sources hold
+    once, with the ``gts`` of the one that has it."""
+    seen: Dict[tuple, dict] = {}
+    for events in event_lists:
+        for ev in events:
+            if ev.get("type", "setup_phase") != "setup_phase":
+                continue
+            key = (ev.get("worker", ""), ev.get("name"), ev.get("mono"))
+            if key in seen and ev.get("gts") is None:
+                continue
+            seen[key] = {
+                "name": ev.get("name"), "worker": ev.get("worker", ""),
+                "ts": ev.get("ts"), "mono": ev.get("mono"),
+                "gts": ev.get("gts"), "dur": ev.get("dur", 0.0),
+                "attrs": dict(ev.get("attrs") or {})}
+    return sorted(seen.values(), key=_ev_time)
+
+
+def keep_setup_record(gcs: Any) -> None:
+    """Called by ``shutdown()`` before it stops a cluster this process
+    started: ship what is pending, ask the aggregator ONCE for the
+    cluster's ``setup_phase`` events (``_KEEP_LIMIT_S``, the newest
+    ``SETUP_RECORD_MAX``) and keep the answer in the process. Never
+    raises: a GCS that is gone leaves this process's own ring."""
+    global _kept
+    try:
+        _events.flush()
+        _kept = gcs.call("ListClusterEvents", etype="setup_phase",
+                         limit=SETUP_RECORD_MAX, timeout=_KEEP_LIMIT_S)
+    except Exception:  # noqa: BLE001 — a postmortem aid must not fail one
+        logger.debug("the cluster's set-up record was not fetched",
+                     exc_info=True)
+
+
+def setup_record() -> List[dict]:
+    """Every process's set-up intervals, ``[{name, worker, ts, mono, gts,
+    dur, attrs}]`` sorted by start. While a cluster is up:
+    ``util.state.setup_timeline()``. After the ``shutdown()`` that stopped
+    it: what was kept then, with this process's own ring (which holds
+    everything in local mode, and in a train job whose loop ran here)."""
+    from ray_tpu._private import worker as worker_mod
+
+    w = worker_mod.global_worker
+    if w is not None and getattr(w.core, "gcs", None) is not None:
+        from ray_tpu.util import state
+
+        try:
+            return state.setup_timeline()
+        except Exception:  # noqa: BLE001 — the GCS is gone: what we hold
+            logger.debug("setup_timeline failed", exc_info=True)
+    return merge_setup_phases(_kept, _events.local_events("setup_phase"))
 
 
 # =====================================================================

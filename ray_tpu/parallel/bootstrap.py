@@ -15,7 +15,10 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Dict, List, Optional
+import time
+from typing import Any, Dict, List, Optional
+
+from ray_tpu.observability.timeline import setup_phase
 
 _JAX_DIST_INITIALIZED = False
 
@@ -75,19 +78,42 @@ def configure_compilation_cache() -> str:
     return path
 
 
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+_TRACES_KEPT = 4096  # disjoint trace intervals remembered, newest last
+
+
 def watch_compiles() -> Dict[str, float]:
     """Count this process's XLA compiles from JAX's own monitoring
-    events. Returns a dict that keeps updating: ``compile_s`` (seconds
-    spent obtaining executables, cache reads included) and the persistent
-    cache's ``cache_hits`` / ``cache_misses``. Costs nothing per step."""
+    events. Returns a dict that keeps updating: ``trace_s`` (Python traced
+    to jaxprs), ``lower_s`` (jaxprs to MLIR modules), ``compile_s``
+    (seconds spent obtaining executables, cache reads included, and
+    ``programs``, how many were obtained) and the persistent cache's
+    ``cache_hits`` / ``cache_misses``. Costs nothing per step."""
     import jax.monitoring
 
-    seen: Dict[str, float] = {"compile_s": 0.0, "cache_hits": 0,
-                              "cache_misses": 0}
+    seen: Dict[str, float] = {"trace_s": 0.0, "lower_s": 0.0,
+                              "compile_s": 0.0, "programs": 0,
+                              "cache_hits": 0, "cache_misses": 0}
+    traces: List[tuple] = []  # (start, seconds), disjoint
 
     def on_duration(event: str, duration: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            seen["compile_s"] += duration
+        key = _COMPILE_EVENTS.get(event)
+        if key == "trace_s":
+            # a jit traced inside another's trace (most of jax.numpy is
+            # one) is reported alone AND inside its parent's duration, and
+            # ends first: count what each interval adds to their union
+            start = time.monotonic() - duration
+            while traces and traces[-1][0] >= start:
+                seen[key] -= traces.pop()[1]
+            traces.append((start, duration))
+            del traces[:-_TRACES_KEPT]
+        if key:
+            seen[key] += duration
+            seen["programs"] += key == "compile_s"
 
     def on_event(event: str, **_kw) -> None:
         if event == "/jax/compilation_cache/cache_hits":
@@ -98,6 +124,65 @@ def watch_compiles() -> Dict[str, float]:
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     jax.monitoring.register_event_listener(on_event)
     return seen
+
+
+_process_compiles: Optional[Dict[str, float]] = None
+
+
+def process_compiles() -> Dict[str, float]:
+    """``watch_compiles()`` once a process, from the first call on: what a
+    set-up phase reads before and after itself."""
+    global _process_compiles
+    if _process_compiles is None:
+        _process_compiles = watch_compiles()
+    return _process_compiles
+
+
+class FirstCall:
+    """Round a jitted callable until its first call has returned: that call
+    is booked as ONE ``ray_tpu.setup.program`` phase [program; trace_s,
+    lower_s, compile_s: what JAX's events counted in this process over the
+    call; cache: the persistent cache's answer, ``hit`` / ``miss`` /
+    ``none``; first_run_s: the call's wall, its results waited for, less
+    those three: the executable's load, the first transfers, the first
+    execution], and then ``holder[key]`` (a dict: an object's ``__dict__``,
+    a module's ``globals()``, a table of programs), if it still holds this
+    wrapper, holds the bare callable: a steady-state step runs no line of
+    this. Everything else asked of the wrapper (``.lower``, ...) is the
+    callable's own."""
+
+    def __init__(self, fn, program: str, holder: dict, key: Any):
+        self._fn, self._program = fn, program
+        self._holder, self._key = holder, key
+        self._booked = False
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self._fn, name)
+
+    def __call__(self, *args: Any, **kwargs: Any) -> Any:
+        if self._booked:  # a second caller while the first compiles
+            return self._fn(*args, **kwargs)
+        self._booked = True
+        import jax
+
+        seen = process_compiles()
+        before = dict(seen)
+        with setup_phase("ray_tpu.setup.program",
+                         program=self._program) as attrs:
+            t0 = time.monotonic()
+            try:
+                return jax.block_until_ready(self._fn(*args, **kwargs))
+            finally:
+                wall = time.monotonic() - t0
+                parts = {k: seen[k] - before[k]
+                         for k in ("trace_s", "lower_s", "compile_s")}
+                attrs.update(
+                    parts, first_run_s=max(0.0, wall - sum(parts.values())),
+                    cache="miss" if seen["cache_misses"] > before["cache_misses"]
+                    else "hit" if seen["cache_hits"] > before["cache_hits"]
+                    else "none")
+                if self._holder.get(self._key) is self:
+                    self._holder[self._key] = self._fn
 
 
 def initialize_host(spec: HostGroupSpec, platform: str = "tpu") -> None:

@@ -20,6 +20,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.serve import slo
 from ray_tpu.serve.deployment import (
     Application,
@@ -633,12 +634,17 @@ def run(app: Application, *, name: Optional[str] = None,
     (reference: serve.run api.py:930). ``local_testing_mode`` runs the
     deployment in-process with no cluster (reference:
     serve/_private/local_testing_mode.py)."""
-    import inspect
-
     if local_testing_mode:
         from ray_tpu.serve.local_mode import run_local
 
         return run_local(app)
+
+    with setup_phase("ray_tpu.setup.serve.run"):
+        return _run(app)
+
+
+def _run(app: Application) -> DeploymentHandle:
+    import inspect
 
     from ray_tpu._private.serialization import dumps_function
 
@@ -665,8 +671,10 @@ def run(app: Application, *, name: Optional[str] = None,
         "autoscaling_config": cfg.autoscaling_config,
         "streaming_methods": streaming_methods,
     }
-    ctl = _controller()
-    snapshot = ray_tpu.get(ctl.deploy.remote(cfg.name, spec), timeout=600)
+    with setup_phase("ray_tpu.setup.serve.controller"):
+        ctl = _controller()
+    with setup_phase("ray_tpu.setup.serve.deploy"):
+        snapshot = ray_tpu.get(ctl.deploy.remote(cfg.name, spec), timeout=600)
     return DeploymentHandle(cfg.name, ctl, snapshot)
 
 

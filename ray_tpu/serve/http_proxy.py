@@ -40,6 +40,7 @@ from typing import Dict, Optional
 
 from ray_tpu._private.streaming import ObjectRefGenerator, StreamEnd
 from ray_tpu.exceptions import GetTimeoutError
+from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.serve import slo
 from ray_tpu.serve.deployment import (
     REPLICA_FAILURES,
@@ -516,8 +517,9 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
     ``max_queue_depth`` bound the admission gate (see slo.py)."""
     global _proxy
     if _proxy is None:
-        _proxy = _AsyncProxy(host, port, max_inflight=max_inflight,
-                             max_queue_depth=max_queue_depth)
+        with setup_phase("ray_tpu.setup.serve.proxy"):
+            _proxy = _AsyncProxy(host, port, max_inflight=max_inflight,
+                                 max_queue_depth=max_queue_depth)
         if _proxy.port is None:
             _proxy = None
             raise RuntimeError("HTTP proxy failed to start")

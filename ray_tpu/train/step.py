@@ -28,6 +28,7 @@ state keeps HBM flat."""
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -40,8 +41,10 @@ from ray_tpu.models.transformer import (
     REMAT_LADDER, TransformerConfig, forward, init_params, loss_fn, param_axes,
     tensor_ring, trainable_mask,
 )
+from ray_tpu.observability.timeline import record_setup_phase, setup_phase
 from ray_tpu.ops.attention import flash_attention, gqa_expand
 from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.parallel.bootstrap import FirstCall
 from ray_tpu.parallel.mesh import mesh_axis_size
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES, Rules, named_sharding, spec_for, tree_shardings,
@@ -275,6 +278,7 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     turn's rows of the sequence; None on any other mesh, where a block
     traces whole products. A sequence that `sequence` x `tensor` does not
     divide fails at trace time."""
+    build_ts, build_mono = time.time(), time.monotonic()
     rules = _effective_rules(mesh, rules)
     attn = make_attn_fn(cfg, mesh, rules)
     n_stage = mesh_axis_size(mesh, "stage")
@@ -334,29 +338,46 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     settled = len(ladder) == 1
 
     def rung(kept):
-        return jax.jit(functools.partial(step, kept), **jit_kwargs)
+        # the first call of whichever rung the step comes to stand on is
+        # booked, then `run._jitted` is the bare jitted callable
+        return FirstCall(jax.jit(functools.partial(step, kept), **jit_kwargs),
+                         "train_step", run.__dict__, "_jitted")
 
     def fits(jitted, state, batch, limit):
-        try:
-            need = _step_bytes(jitted.lower(state, batch).compile())
-        except jax.errors.JaxRuntimeError as e:
-            if "RESOURCE_EXHAUSTED" not in str(e):
-                raise
-            return False  # the compiler itself refused it
-        return need <= (1 - REMAT_HEADROOM) * limit
+        """Compile the rung ahead of time (the call reuses the executable)
+        and say whether it fits; booked as one `setup.step.rung`."""
+        with setup_phase("ray_tpu.setup.step.rung",
+                         kept=list(run.remat_kept)) as attrs:
+            t0 = time.monotonic()
+            lowered = jitted.lower(state, batch)
+            t1 = time.monotonic()
+            try:
+                need = _step_bytes(lowered.compile())
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                need = None  # the compiler itself refused it
+            attrs.update(
+                lower_s=t1 - t0, compile_s=time.monotonic() - t1, bytes=need,
+                fits=need is not None and need <= (1 - REMAT_HEADROOM) * limit)
+            return attrs["fits"]
 
     def settle(state, batch):
-        """Down the ladder to the first rung that fits the device."""
+        """Down the ladder to the first rung that fits the device; booked
+        as `setup.step.settle` with one `setup.step.rung` a rung compiled."""
         nonlocal settled
         settled = True
-        limit = _bytes_limit(mesh)
-        if limit is None:
-            return
-        for kept in ladder:
-            if kept != run.remat_kept:
-                run.remat_kept, run._jitted = kept, rung(kept)
-            if kept == ladder[-1] or fits(run._jitted, state, batch, limit):
-                return
+        with setup_phase("ray_tpu.setup.step.settle") as attrs:
+            limit = _bytes_limit(mesh)
+            if limit is not None:
+                for kept in ladder:
+                    if kept != run.remat_kept:
+                        run.remat_kept, run._jitted = kept, rung(kept)
+                    if kept == ladder[-1] or fits(run._jitted, state, batch,
+                                                  limit):
+                        break
+            attrs.update(rungs_tried=ladder.index(run.remat_kept) + 1,
+                         kept=list(run.remat_kept))
 
     def place(batch):
         return {k: jax.device_put(v, b_shard if v.ndim >= 2 else repl)
@@ -383,6 +404,8 @@ def make_train_step(cfg: TransformerConfig, optimizer: optax.GradientTransformat
     run._batch_sharding = b_shard
     run.differentiated = differentiated
     run.tensor_ring = None  # until a trace has seen the batch's rows
+    record_setup_phase("ray_tpu.setup.step.build", build_ts, build_mono,
+                       time.monotonic() - build_mono)
     return run
 
 

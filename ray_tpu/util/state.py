@@ -86,6 +86,24 @@ def actor_timeline(actor_id: str) -> Dict[str, Any]:
     return _gcs().call_retrying("ActorTimeline", actor_id=actor_id)
 
 
+def setup_timeline() -> List[Dict[str, Any]]:
+    """Where set-up went, in every process of the cluster: the
+    ``setup_phase`` intervals (``observability/schema.SETUP_PHASES``: the
+    cluster's start, a worker's boot, an actor's ``__init__``, the
+    backend, parameters, engine, each jitted program's first call, the
+    train step's ladder) as ``[{name, worker, ts, mono, gts, dur,
+    attrs}]`` sorted by start on the GCS's timebase. One call to the
+    aggregator, merged with the caller's own ring (what it has not shipped
+    yet). Always on; ``observability.setup_record()`` still answers after
+    ``shutdown()``."""
+    from ray_tpu.observability import events, timeline
+
+    return timeline.merge_setup_phases(
+        _gcs().call_retrying("ListClusterEvents", etype="setup_phase",
+                             limit=timeline.SETUP_RECORD_MAX),
+        events.local_events("setup_phase"))
+
+
 def lifecycle_summary(job_id: Optional[str] = None,
                       wall_s: Optional[float] = None,
                       etype: str = "actor_lifecycle") -> Dict[str, Any]:

@@ -1279,6 +1279,28 @@ class TestRC009:
         """, rules=["RC009"])
         assert fs == []
 
+    @pytest.mark.parametrize("call", ["setup_phase", "record_setup_phase"])
+    def test_flags_an_undeclared_setup_phase_literal(self, tmp_path, call):
+        """A set-up phase's name is held to SETUP_PHASES as an event's
+        type is to EVENT_TYPES; a declared literal and a variable pass."""
+        p = tmp_path / "ray_tpu" / "observability" / "schema.py"
+        p.parent.mkdir(parents=True, exist_ok=True)
+        p.write_text(self.SCHEMA
+                     + 'SETUP_PHASES = {"ray_tpu.setup.init": "driver"}\n')
+        fs = _scan(tmp_path, "mod.py", f"""
+            from ray_tpu.observability import timeline as obs_timeline
+            from ray_tpu.observability.timeline import {call}
+
+            def f(name, op):
+                obs_timeline.{call}("ray_tpu.setup.init")
+                {call}(name)
+                {call}("ray_tpu.setup.innit")
+                obs_timeline.{call}(f"ray_tpu.setup.{{op}}")
+        """, rules=["RC009"])
+        assert _details(fs) == [
+            ("RC009", "undeclared-phase:ray_tpu.setup.innit"),
+            ("RC009", f"dynamic-name:{call}")]
+
     def test_missing_schema_skips_membership_only(self, tmp_path):
         """No schema in the analyzed tree: membership checks are
         skipped (partial trees must stay lintable), dynamic-name checks

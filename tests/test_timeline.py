@@ -21,6 +21,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -150,6 +151,38 @@ class TestOverheadGuard:
         assert [e["i"] for e in buf._pending[:3]] == [0, 1, 2]
         buf._requeue([{"type": "span", "i": -1}] * obs_events._PENDING_MAX)
         assert len(buf._pending) <= obs_events._PENDING_MAX
+
+    def test_the_flusher_waits_for_work_not_for_a_period(self, monkeypatch):
+        """Every process records set-up phases, so every process has a
+        flusher: with an empty backlog it must run no Python. After a
+        `flush()` that shipped everything the thread makes no further call
+        in 2 s; the next `record()` wakes it within a period or two."""
+        monkeypatch.setattr(obs_events, "_FLUSH_PERIOD_S", 0.05)
+        shipped, calls = [], []
+        monkeypatch.setattr(obs_events, "_local_sink",
+                            lambda batch, clock: shipped.extend(batch))
+        buf = obs_events.EventBuffer()
+        flush_once = buf.flush_once
+        monkeypatch.setattr(
+            buf, "flush_once",
+            lambda: calls.append(threading.current_thread().name)
+            or flush_once())
+
+        def mine():  # the sink is the module's: other buffers ship there too
+            return [e["i"] for e in shipped if "i" in e]
+
+        buf.record({"type": "span", "i": 0})
+        assert buf.flush_once() and mine() == [0]
+        time.sleep(0.3)  # the wake-up the record armed finds nothing
+        calls.clear()
+        time.sleep(2.0)
+        assert calls == [], "the flusher polls an empty backlog"
+        buf.record({"type": "span", "i": 1})
+        deadline = time.monotonic() + 5
+        while len(mine()) < 2 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert mine() == [0, 1]
+        assert calls == ["obs-events-flush"]
 
 
 # =====================================================================

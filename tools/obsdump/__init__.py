@@ -9,6 +9,10 @@ https://ui.perfetto.dev file:
 - **span** events → complete slices ("ph": "X"), grouped by process;
 - **actor/task lifecycle** marks → per-entity phase slices on a
   ``lifecycle`` track (submit→registered→…→first_ping laid end to end);
+- **setup_phase** intervals (always on: the cluster's start, a worker's
+  boot, an actor's ``__init__``, the engine, each jitted program's first
+  call) → slices on a ``setup`` track beside ``lifecycle``, one tid a
+  process;
 - **collective_op** events → stacked op + per-phase slices;
 - **counter series** (GCS queue depth, serve shed rate) and **event-loop
   lag** samples → counter tracks ("ph": "C");
@@ -96,6 +100,22 @@ def _lifecycle_slices(marks: List[dict], entity: str) -> List[dict]:
                      "job_id": a.get("job_id", "")},
         })
     return out
+
+
+def _setup_slice(ev: dict) -> dict:
+    """A ``setup_phase`` interval (``ts`` is its start) → one slice on the
+    shared ``setup`` pid, one tid a process: children nest inside their
+    parent's slice, processes lie one under another."""
+    return {
+        "name": ev.get("name", "?"),
+        "cat": "setup_phase",
+        "ph": "X",
+        "ts": float(ev.get("ts", 0.0)) * _US,
+        "dur": max(0.0, float(ev.get("dur", 0.0))) * _US,
+        "pid": "setup",
+        "tid": str(ev.get("worker", "?"))[:16],
+        "args": dict(ev.get("attrs") or {}),
+    }
 
 
 def _collective_slices(ev: dict, pid: str) -> List[dict]:
@@ -232,6 +252,8 @@ def merge(shards: List[dict]) -> Dict[str, Any]:
             elif etype in ("actor_lifecycle", "task_lifecycle"):
                 eid = ev.get("actor_id") or ev.get("task_id") or "?"
                 lifecycle.setdefault((etype, eid), []).append(ev)
+            elif etype == "setup_phase":
+                trace_events.append(_setup_slice(ev))
             elif etype == "collective_op":
                 trace_events.extend(_collective_slices(ev, pid))
             else:

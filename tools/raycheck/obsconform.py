@@ -11,6 +11,10 @@ Two failure shapes this rule catches:
    in rings and dumps, and silently matches no query, timeline builder
    or obsdump lane. (Variables as the type are allowed — tests drive
    the bus generically — only literals are checked against the schema.)
+   The same holds for a set-up phase: a literal name passed to
+   ``setup_phase`` / ``record_setup_phase`` must be a key of
+   ``SETUP_PHASES`` (at run time an undeclared name raises; this finds it
+   before the site ever runs).
 2. **Dynamic name** — an f-string / ``.format`` / ``%`` / string
    concatenation as the *name* of an event, span or metric:
    unbounded-cardinality names explode Prometheus label sets and the
@@ -32,6 +36,14 @@ from tools.raycheck.rules import Finding, SourceModule, const_str
 _EVENT_CALLS = {
     "ray_tpu.observability.events.record_event",
     "ray_tpu.observability.record_event",
+}
+# resolved call target -> the schema dict whose keys its literal name is held
+# to, beside the dynamic-name check every name call gets
+_SETUP_CALLS = {
+    "ray_tpu.observability.timeline.setup_phase",
+    "ray_tpu.observability.timeline.record_setup_phase",
+    "ray_tpu.observability.setup_phase",
+    "ray_tpu.observability.record_setup_phase",
 }
 _NAME_CALLS = {
     "ray_tpu.observability.tracing.span",
@@ -91,9 +103,10 @@ def _is_dynamic(node: ast.expr) -> bool:
     return False
 
 
-def _schema_event_types(modules: List[SourceModule],
-                        ) -> Optional[Set[str]]:
-    """The declared ``EVENT_TYPES`` keys, from the analyzed module set
+def _schema_keys(modules: List[SourceModule],
+                 table: str = "EVENT_TYPES") -> Optional[Set[str]]:
+    """The declared keys of ``table`` (``EVENT_TYPES``, ``SETUP_PHASES``),
+    from the analyzed module set
     when schema.py is in it, else from disk next to the analyzed tree.
     None (skip membership checks) when the schema can't be found —
     raycheck must stay runnable on partial trees."""
@@ -118,7 +131,7 @@ def _schema_event_types(modules: List[SourceModule],
         return None
     for node in ast.walk(tree):
         if isinstance(node, ast.Assign) and any(
-                isinstance(t, ast.Name) and t.id == "EVENT_TYPES"
+                isinstance(t, ast.Name) and t.id == table
                 for t in node.targets) and \
                 isinstance(node.value, ast.Dict):
             keys = {k.value for k in node.value.keys
@@ -138,7 +151,8 @@ def _name_arg(call: ast.Call) -> Optional[ast.expr]:
 
 
 def check_rc009(modules: List[SourceModule]) -> List[Finding]:
-    declared = _schema_event_types(modules)
+    declared = _schema_keys(modules)
+    phases = _schema_keys(modules, "SETUP_PHASES")
     out: List[Finding] = []
     for mod in modules:
         for node in mod.all_nodes:
@@ -148,7 +162,8 @@ def check_rc009(modules: List[SourceModule]) -> List[Finding]:
             if target is None:
                 continue
             is_event = target in _EVENT_CALLS
-            if not is_event and target not in _NAME_CALLS:
+            is_setup = target in _SETUP_CALLS
+            if not (is_event or is_setup or target in _NAME_CALLS):
                 continue
             arg = _name_arg(node)
             if arg is None:
@@ -174,4 +189,14 @@ def check_rc009(modules: List[SourceModule]) -> List[Finding]:
                         f" — undeclared events match no query, timeline "
                         f"or obsdump lane",
                         f"undeclared-event:{literal}"))
+            if is_setup and phases is not None:
+                literal = const_str(arg)
+                if literal is not None and literal not in phases:
+                    out.append(Finding(
+                        "RC009", mod.relpath, node.lineno,
+                        mod.scope_of(node),
+                        f"set-up phase {literal!r} is not declared in "
+                        f"ray_tpu/observability/schema.py SETUP_PHASES — "
+                        f"the site raises when it runs",
+                        f"undeclared-phase:{literal}"))
     return out

@@ -397,7 +397,8 @@ RULE_DOCS: Dict[str, str] = {
              "lock",
     "RC008": "protocol-conformance: actor/node-drain/lease/pg state "
              "assignments verified against checked-in transition tables",
-    "RC009": "obs-conformance: record_event types must be declared in "
+    "RC009": "obs-conformance: record_event types and set-up phase names "
+             "must be declared in "
              "observability/schema.py; event/span/metric names must not "
              "be built with f-strings/format/concat at the call site",
 }
