@@ -121,20 +121,44 @@ def build_llm_deployment(config: LLMConfig):
 
 
 def text_deltas(tokenizer, stream):
-    """Text deltas of a stream of token ids: every id decodes the WHOLE
-    answer so far again and yields what is new of it. Each decode is a
-    `ray_tpu.replica.detokenize` span [ids: the ids decoded; backlog: the ids
-    the engine has emitted for this stream and this thread has not taken
-    yet, 0 while the handler keeps up with the pump]."""
+    """Text deltas of a stream of token ids, decoded incrementally: beside
+    the ids two offsets into them, `prefix` <= `read`. Every id taken
+    decodes `ids[prefix:]` and `ids[prefix:read]`, a window of a handful of
+    ids whatever the answer's length, and yields what the first text has
+    beyond the second. The ids before `read` are decoded again only to be
+    subtracted: they keep a `decode` that looks at the left neighbour (a
+    leading-space rule, byte-fallback pieces of one character) right
+    without the whole list. Where the new text is longer and does not end
+    in an incomplete character (U+FFFD at its end) the window moves on,
+    `prefix <- read`, `read <- len(ids)`; else the ids are held and nothing
+    is yielded until the character completes (the window grows only
+    then), and the stream's end flushes what is still held. So the deltas'
+    concatenation is `decode(all ids)` and no delta is ever taken back. Each
+    id taken is a `ray_tpu.replica.detokenize` span [ids: the ids of the
+    answer so far; decoded: the ids this turn hands to `decode`; backlog:
+    the ids the engine has emitted for this stream and this thread has not
+    taken yet, 0 while the handler keeps up with the pump]."""
     backlog = getattr(stream, "backlog", int)  # a stream without a queue: 0
-    out_ids = []
-    prev_text = ""
+    decode = tokenizer.decode
+    ids = []
+    prefix = read = 0
+
+    def unread():
+        return decode(ids[prefix:])[len(decode(ids[prefix:read])):]
+
     for t in stream:
-        out_ids.append(t)
-        with device_span(schema.REPLICA_DETOKENIZE, ids=len(out_ids),
+        ids.append(t)
+        with device_span(schema.REPLICA_DETOKENIZE, ids=len(ids),
+                         decoded=(len(ids) - prefix) + (read - prefix),
                          backlog=backlog()):
-            text = tokenizer.decode(out_ids)
-            delta, prev_text = text[len(prev_text):], text
+            delta = unread()
+            held = not delta or delta.endswith("\ufffd")
+            if not held:
+                prefix, read = read, len(ids)
+        if not held:
+            yield delta
+    if read < len(ids):  # held to the end: an incomplete character, or none
+        delta = unread()
         if delta:
             yield delta
 

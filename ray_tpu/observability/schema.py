@@ -156,9 +156,12 @@ DEVICE_SPANS = {
     ENGINE_EMIT: "",
     # handler threads of a replica, one a stream. A streamed token goes
     # pump `engine.emit` -> the stream's queue -> llm/serving.py
-    # `text_deltas` (`replica.detokenize`: the WHOLE answer so far decoded
-    # again; `ids` = how many, `backlog` = ids emitted and not yet taken, 0
-    # while the handler keeps up) -> _private/workers/default_worker.py
+    # `text_deltas` (`replica.detokenize`: a short window of the answer's
+    # last ids decoded, whatever its length; `ids` = the ids of the answer
+    # so far, `decoded` = the ids this turn handed to `decode`, a handful
+    # unless an incomplete character is held, `backlog` = ids emitted and
+    # not yet taken, 0 while the handler keeps up) ->
+    # _private/workers/default_worker.py
     # `_execute_streaming` (`worker.stream_yield`: one generator item
     # serialised and handed to the caller's `streaming.StreamSender`; the
     # yield waits only when its stream is a whole buffer ahead of its
@@ -166,7 +169,7 @@ DEVICE_SPANS = {
     # StreamingYield CALL: the wire, the caller's handler, the ack back,
     # for the `items` (`bytes` in all) that every stream of this process
     # had handed over for that caller since the call before
-    REPLICA_DETOKENIZE: "ids, backlog",
+    REPLICA_DETOKENIZE: "ids, decoded, backlog",
     WORKER_STREAM_YIELD: "",
     WORKER_STREAM_RPC: "items, bytes",
 }

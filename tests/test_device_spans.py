@@ -306,6 +306,16 @@ def test_one_detokenize_span_a_token_with_ids_growing_by_one(traced):
     assert stream_spans.handoff_ms_p50(parsed) >= 0
 
 
+def test_a_detokenize_span_says_how_few_ids_its_turn_decoded(traced):
+    """`decoded`, the ids a turn hands to `decode`, is the counter that says
+    the window engaged: the first id alone, then the id before (twice: once
+    to be subtracted) and the new one, while `ids` counts the whole answer."""
+    stats = [s[4] for s in program_spans.named(
+        traced["parsed"], schema.REPLICA_DETOKENIZE)]
+    assert [s["decoded"] for s in stats] == [1, 3, 3, 3, 3, 3]
+    assert stats[-1]["ids"] == 6 > stats[-1]["decoded"]
+
+
 def test_a_trace_holds_the_pumps_counters_of_its_own_window(traced):
     """`engine.step` carries the pump's clocks as last booked: what two
     bookings in a trace say between them is what `ContinuousBatcher.stats`
