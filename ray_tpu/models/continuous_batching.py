@@ -542,14 +542,13 @@ class ContinuousBatcher(PrefillPrograms):
 
     def _pad_row(self, row_k, row_v):
         """A prefilled row [L, S, kvH, D] out to max_len, as install takes
-        it."""
+        it (keys and values each by their own row's shape)."""
         pad = self.max_len - row_k.shape[1]
         if pad <= 0:
             return row_k, row_v
-        zeros = jnp.zeros(
-            row_k.shape[:1] + (pad,) + row_k.shape[2:], row_k.dtype)
-        return (jnp.concatenate([row_k, zeros], axis=1),
-                jnp.concatenate([row_v, zeros], axis=1))
+        return tuple(jnp.concatenate([row, jnp.zeros(
+            row.shape[:1] + (pad,) + row.shape[2:], row.dtype)], axis=1)
+            for row in (row_k, row_v))
 
     # -- the cache: fixed slots ------------------------------------------
     # The six methods the scheduler reaches K/V rows through. It calls them

@@ -97,7 +97,7 @@ def init_state(cfg: TransformerConfig, batch: int, dtype=None) -> dict:
     """{field: zeros} of `KVCache`'s STATES for `batch` new sequences, by
     what the configuration's layers keep (`cfg.kept`); {} for a model whose
     sequences keep nothing but their rows."""
-    return {name: jnp.zeros((kept.layers, batch, *kept.shape),
+    return {name: jnp.zeros((kept.layers, batch, *kept.shape_of(name)),
                             kept.dtype or dtype or cfg.dtype)
             for kept in cfg.kept() if kept.rows is None
             for name in kept.fields}
@@ -106,8 +106,9 @@ def init_state(cfg: TransformerConfig, batch: int, dtype=None) -> dict:
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None) -> KVCache:
     dtype = dtype or cfg.dtype
-    rows = {name: jnp.zeros((kept.layers, batch, kept.rows, *kept.shape),
-                            kept.dtype or dtype)
+    rows = {name: jnp.zeros(
+        (kept.layers, batch, kept.rows, *kept.shape_of(name)),
+        kept.dtype or dtype)
             for kept in cfg.kept(max_len) if kept.rows is not None
             for name in kept.fields}
     for name in ("k", "v"):  # a family without K/V rows: no layer of them
@@ -118,8 +119,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
 
 
 @jax.named_scope("attend_cached")
-def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
-    """q [B,S,H,D] against the full cache [B,max_len,kvH,D].
+def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask, sink=None,
+                   sm_scale=None):
+    """q [B,S,H,D] against the full cache [B,max_len,kvH,D] (the values may
+    have a width of their own). `sink` [H]: a learned logit a query head
+    that joins the softmax's denominator and carries no value
+    (`ops.attention.softmax_with_sink`); `sm_scale`: the factor on the
+    logits where it is not 1 / sqrt(D) (keys cached wider than they are).
 
     kv_len_mask [B, max_len] marks valid cache slots; q_pos [B,S] are the
     global positions of the queries (causal: key position <= q position).
@@ -149,15 +155,18 @@ def _attend_cached(q, k_cache, v_cache, q_pos, kv_len_mask):
     t, kvh = k_cache.shape[1], k_cache.shape[2]
     q5 = q.reshape(b, s, kvh, h // kvh, d)  # heads of one KV group adjoin
     logits = jnp.einsum("bsgrd,btgd->bgrst", q5, k_cache,
-                        preferred_element_type=jnp.float32) / (d ** 0.5)
+                        preferred_element_type=jnp.float32)
+    logits = logits / (d ** 0.5) if sm_scale is None else logits * sm_scale
     key_pos = jnp.arange(t)
     causal = q_pos[:, None, None, :, None] >= key_pos
     mask = kv_len_mask[:, None, None, None, :] & causal
     logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = attention_ops.softmax_with_sink(
+        logits, None if sink is None else sink.astype(jnp.float32).reshape(
+            1, kvh, h // kvh, 1, 1))
     out = jnp.einsum("bgrst,btgd->bsgrd", probs, v_cache,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, s, h, d).astype(q.dtype)
+    return out.reshape(b, s, h, v_cache.shape[-1]).astype(q.dtype)
 
 
 def _write_layer(k_cache, v_cache, k, v, positions):
@@ -172,7 +181,9 @@ def _write_layer(k_cache, v_cache, k, v, positions):
 
 class StackLayer(NamedTuple):
     """Where a layer's rows lie in a cache that is a stack: the stacks
-    [N, B, T, kvH, D] and the layer's index, NOT a view cut out of them."""
+    [N, B, T, kvH, D] and the layer's index, NOT a view cut out of them.
+    Keys wider than the values lie in pieces of the values' width
+    (`ops.attention.key_pieces`)."""
     k: jax.Array
     v: jax.Array
     layer: Any  # int32 scalar
@@ -180,7 +191,8 @@ class StackLayer(NamedTuple):
     def view(self):
         """The layer as dense [B, T, kvH, D] arrays, read by index: what a
         prefill into a longer cache and a step off the chip attend over."""
-        return (lax.dynamic_index_in_dim(self.k, self.layer, keepdims=False),
+        return (attention_ops.whole_keys(lax.dynamic_index_in_dim(
+            self.k, self.layer, keepdims=False), self.v.shape[3]),
                 lax.dynamic_index_in_dim(self.v, self.layer, keepdims=False))
 
 
@@ -199,9 +211,12 @@ _fresh_rows = TracedPaths("fresh_rows_attention")
 fresh_rows_attended = _fresh_rows.traced
 
 
-def attend_held(q, held, q_pos, kv_len_mask, rows=None):
+def attend_held(q, held, q_pos, kv_len_mask, rows=None, sink=None,
+                sm_scale=None):
     """q [B, S, H, D] against what a cache access returned as `held`: a
-    dense (k, v) [B, T, kvH, D] pair, a `StackLayer` or `FreshRows`. One
+    dense (k, v) [B, T, kvH, D] pair, a `StackLayer` or `FreshRows`
+    (`sink`, `sm_scale`: `_attend_cached`'s; no caller has a sink over fresh
+    rows, and the flash kernel has none). One
     token a sequence (S == 1) over a stack whose caller states `rows` [B],
     how many rows each slot holds (a prefix; 0: the slot takes no part),
     goes to the kernel that reads those rows in the stack and nothing else
@@ -223,21 +238,26 @@ def attend_held(q, held, q_pos, kv_len_mask, rows=None):
     `kv_len_mask` and the causal rule. What decides is in the arguments:
     no option, no model's name."""
     if isinstance(held, FreshRows):
-        flash = attention_ops.flash_attention_takes(q, held.k)
+        flash = sink is None and attention_ops.flash_attention_takes(
+            q, *held)
         _fresh_rows.book("flash" if flash else "dense")
         if flash:
             with jax.named_scope("attend_cached"):
+                # values of a width of their own: the forward reads a KV
+                # head where it lies, by index; else the heads are repeated
+                kv = held if held.k.shape[-1] != held.v.shape[-1] else \
+                    attention_ops.gqa_expand(*held, q.shape[2])
                 return attention_ops.flash_attention(
-                    q, *attention_ops.gqa_expand(*held, q.shape[2]),
-                    causal=True)
+                    q, *kv, causal=True, sm_scale=sm_scale)
     if isinstance(held, StackLayer):
         if (rows is not None and q.shape[1] == 1
-                and attention_ops.decode_attention_takes(held.k)):
+                and attention_ops.decode_attention_takes(held.k, held.v)):
             with jax.named_scope("attend_cached"):
                 return attention_ops.decode_attention(
-                    q[:, 0], held.k, held.v, held.layer, rows)[:, None]
+                    q[:, 0], held.k, held.v, held.layer, rows, sink=sink,
+                    sm_scale=sm_scale)[:, None]
         held = held.view()
-    return _attend_cached(q, *held, q_pos, kv_len_mask)
+    return _attend_cached(q, *held, q_pos, kv_len_mask, sink, sm_scale)
 
 
 def _write_stack(layer):
@@ -260,12 +280,15 @@ def _write_stack(layer):
 
     def access(k_cache, v_cache, k, v, positions):
         k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        fresh = k
+        # keys wider than the values are kept in pieces of the values' width
+        k = attention_ops.key_pieces(k, k_cache.shape[-1])
         if k.shape[1] == k_cache.shape[2]:
             # a batcher's prefill into a row cache of its bucket's length:
             # the fresh K/V ARE the layer, no row is scattered or read back
             return (lax.dynamic_update_index_in_dim(k_cache, k, layer, 0),
                     lax.dynamic_update_index_in_dim(v_cache, v, layer, 0),
-                    FreshRows(k, v))
+                    FreshRows(fresh, v))
         bidx = jnp.arange(k.shape[0])[:, None]
         k_cache = k_cache.at[layer, bidx, positions].set(k)
         v_cache = v_cache.at[layer, bidx, positions].set(v)

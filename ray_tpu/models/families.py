@@ -43,6 +43,13 @@ class Kept(NamedTuple):
     rows: Optional[int]  # a slot's: the cache's `max_len`, or a ring's window
     shape: Tuple[int, ...]  # of one row, or of the state
     dtype: Any = None  # None: the cache's
+    # one a field where the fields' rows differ (keys wider than the values,
+    # `shape` then the widest); (): `shape` is every field's
+    shapes: Tuple[Tuple[int, ...], ...] = ()
+
+    def shape_of(self, field: str) -> Tuple[int, ...]:
+        return self.shapes[self.fields.index(field)] if self.shapes \
+            else self.shape
 
 
 def of(cfg):

@@ -60,14 +60,13 @@ from ray_tpu.models.decoding import KVCache, _write_stack, attend_held, lm_head
 from ray_tpu.models.families import Kept
 from ray_tpu.models.kimi_linear import router
 from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
-    _take, expert_names, init_params, mlp_leaves, num_params, only_the_stack,
-    param_axes, sparse_mlp,
+    RUN_MAX, _take, expert_names, init_params, mlp_leaves, num_params,
+    only_the_stack, param_axes, runs, sparse_mlp,
 )
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm
 from ray_tpu.ops import ssd
 
 F32 = jnp.float32
-RUN_MAX = 4  # layers of one repeating unit at most (`runs`)
 # the published steps (`time_step_min`, `time_step_max`, `time_step_floor`)
 DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
 
@@ -282,24 +281,6 @@ def attention(cfg: TransformerConfig, x, p, positions, k_cache, v_cache,
 
 
 # -- the layer loop -------------------------------------------------------------
-
-def runs(kinds: tuple) -> list:
-    """`kinds` cut into [(unit, repeats)]: at each layer the unit of at most
-    `RUN_MAX` kinds whose repeats from there cover the most layers, if it
-    repeats at all (one scan over its repeats), else the one layer."""
-    out, i = [], 0
-    while i < len(kinds):
-        unit, repeats = kinds[i:i + 1], 1
-        for size in range(1, RUN_MAX + 1):
-            r = 1
-            while kinds[i + r * size:i + (r + 1) * size] == kinds[i:i + size]:
-                r += 1
-            if r > 1 and r * size > repeats * len(unit):
-                unit, repeats = kinds[i:i + size], r
-        out.append((unit, repeats))
-        i += len(unit) * repeats
-    return out
-
 
 def forward_cached(cfg: TransformerConfig, params, tokens, positions,
                    cache: KVCache, kv_len_mask, row_mask, access=_write_stack,

@@ -22,6 +22,13 @@ from ray_tpu.models.transformer import (
 )
 
 EXPERT_LEAVES = ("wi_gate", "wi_up", "wo_mlp")  # a SwiGLU expert's stacks
+RUN_MAX = 4  # layers of one repeating unit at most (`runs`)
+# A call of more rows than `WHOLE_ROWS_MAX` runs its MLPs `MLP_ROWS` rows at a
+# time (`rows_at_a_time`): `moe_dropless` gathers k rows a row in float32 (at
+# 8,192 rows x 8 of 4,096 three arrays of 1 GB), and a dense SwiGLU of 16,384
+# holds [rows, 16,384] three times. Every cell's bucket but the 8,192 one is
+# below it and keeps the program it had.
+WHOLE_ROWS_MAX, MLP_ROWS = 4096, 1024
 # A leaf larger than this many elements is drawn a piece at a time
 # (`_draw`): its float32 draw would not fit beside the leaves before it.
 WHOLE_DRAW_MAX = 1 << 28
@@ -36,6 +43,25 @@ def only_the_stack(cfg: TransformerConfig, access) -> None:
             f"a layer pattern {cfg.layer_kinds!r} keeps "
             f"{', '.join(cfg.keeps)} a sequence, each written in place in "
             "its stack: no other cache access (pages) holds them")
+
+
+def runs(kinds: tuple, longest: int = RUN_MAX) -> list:
+    """`kinds` cut into [(unit, repeats)]: at each layer the unit of at most
+    `longest` kinds whose repeats from there cover the most layers, if it
+    repeats at all (one scan over its repeats), else the one layer. What a
+    family whose `layer_kinds` name every layer reads its loop off."""
+    out, i = [], 0
+    while i < len(kinds):
+        unit, repeats = kinds[i:i + 1], 1
+        for size in range(1, longest + 1):
+            r = 1
+            while kinds[i + r * size:i + (r + 1) * size] == kinds[i:i + size]:
+                r += 1
+            if r > 1 and r * size > repeats * len(unit):
+                unit, repeats = kinds[i:i + size], r
+        out.append((unit, repeats))
+        i += len(unit) * repeats
+    return out
 
 
 # -- parameters: a pattern states `leaves(cfg)`, {(group, ..., name): (shape,
@@ -154,6 +180,29 @@ def _draw(key, shape, fan_in, dtype):
 
 # -- the MLP halves ---------------------------------------------------------------
 
+def long_prompt(x) -> bool:
+    """Whether x [B, S, ...] is one sequence of more rows than
+    `WHOLE_ROWS_MAX`, in whole pieces of `MLP_ROWS`: `rows_at_a_time`'s."""
+    return x.shape[0] == 1 and x.shape[1] > WHOLE_ROWS_MAX \
+        and x.shape[1] % MLP_ROWS == 0
+
+
+def rows_at_a_time(fn, *arrays):
+    """`fn(*arrays)` -> (out [1, rows, h], counted) for a `long_prompt`'s
+    arrays [1, S, ...], `MLP_ROWS` rows a call: one `lax.scan` over the
+    pieces, the outputs put together again and `counted` (a tree of each
+    call's sums over its rows) summed."""
+    s = arrays[0].shape[1]
+
+    def piece(_, xs):
+        return None, fn(*(a[None] for a in xs))
+
+    _, (out, counted) = lax.scan(piece, None, tuple(
+        a.reshape(s // MLP_ROWS, MLP_ROWS, *a.shape[2:]) for a in arrays))
+    return out.reshape(1, s, *out.shape[3:]), \
+        jax.tree.map(lambda c: c.sum(0), counted)
+
+
 def _swiglu(y, gate, up, down):
     act = jax.nn.silu(jnp.einsum("bsh,hm->bsm", y, gate.astype(y.dtype))) \
         * jnp.einsum("bsh,hm->bsm", y, up.astype(y.dtype))
@@ -185,7 +234,14 @@ def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer,
         with jax.named_scope("lmoe.down"):
             into = jnp.einsum("bsh,hl->bsl", y,
                               p["latent_down"].astype(y.dtype))
-    routed, load = moe_dropless(cfg, into, p, row_mask, layer, routing)
+    if long_prompt(into):  # `moe_dropless` gathers k float32 rows a row
+        k = routing[0].shape[-1]
+        routed, load = rows_at_a_time(
+            lambda rows, real, w, e: moe_dropless(
+                cfg, rows, p, real, layer, (w[0], e[0])),
+            into, row_mask, *(r.reshape(1, -1, k) for r in routing))
+    else:
+        routed, load = moe_dropless(cfg, into, p, row_mask, layer, routing)
     if cfg.moe_latent:
         with jax.named_scope("lmoe.up"):
             routed = jnp.einsum("bsl,lh->bsh", routed,

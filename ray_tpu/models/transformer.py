@@ -136,6 +136,21 @@ class TransformerConfig:
     # one sigmoid gate a query head, from the layer's normed input, on the
     # head's output before `wo` (a layer pattern's attention)
     head_gate: bool = False
+    # What else a pattern of window and full layers may state (models/
+    # laguna.py; XiaomiMiMo/MiMo-V2-Flash does). `lead_kind` "": `layer_kinds`
+    # is EVERY layer's kind in order, the first a full layer with the dense
+    # MLP (no period is read into it). `window_kv_heads`: a window layer's KV
+    # heads (0: `kv_heads`, a full layer's). `value_dim`: the width of a
+    # head's values where it is not the keys' `hd` (0). `window_sink`: one
+    # learned logit a query head of a window layer that joins its softmax's
+    # denominator and carries no value. `value_scale`: a factor on the
+    # values. `window_partial_rotary`: the share of a window layer's head
+    # that its RoPE rotates (`partial_rotary` is a full layer's).
+    window_kv_heads: int = 0
+    value_dim: int = 0
+    window_sink: bool = False
+    value_scale: float = 1.0
+    window_partial_rotary: float = 1.0
     # Expert layers of `moe_dropless` (the cached forward). The k router
     # weights times `routed_scale`; a dense SwiGLU of `shared_expert_hidden`
     # that every token runs beside its k experts; and `experts_held` =
@@ -455,6 +470,24 @@ PRESETS: Dict[str, TransformerConfig] = {
         rope_yarn=(4.0, 16.0, 32.0, 1.0, 1.1386294361119891),
         dense_mlp_hidden=192, head_gate=True, routed_scale=2.5,
         shared_expert_hidden=64, experts_held=(0, 8), dtype=jnp.float32,
+    ),
+    # XiaomiMiMo/MiMo-V2-Flash's block at debug widths (models/laguna.py, the
+    # list form): full (dense MLP), 2 window, full, 3 window; 8 query heads
+    # on 2 KV heads (full) and 4 (window), keys of 24 (8 rotated) beside
+    # values of 16 times 0.707, a window of 8 with a learned sink, sigmoid
+    # top-2 of 8 experts of which 4 are held, no shared expert, no gate. The
+    # published widths are the benchmark's to build
+    # (benchmarks/runners/serve_mimo.py)
+    "mimo_v2_debug": dict(
+        vocab_size=512, hidden=128, mlp_hidden=64, layers=7, heads=8,
+        kv_heads=2, head_dim=24, max_seq=128, remat=False, rope_theta=5e6,
+        norm_eps=1e-5, num_experts=8, experts_per_token=2,
+        norm_topk_prob=True, partial_rotary=0.334, lead_kind="",
+        layer_kinds=("full", "window", "window", "full", "window", "window",
+                     "window"), window=8, window_heads=8, window_kv_heads=4,
+        window_rope_theta=1e4, window_partial_rotary=0.334, value_dim=16,
+        window_sink=True, value_scale=0.707, dense_mlp_hidden=192,
+        router_score="sigmoid", experts_held=(0, 4), dtype=jnp.float32,
     ),
     # moonshotai/Kimi-Linear-48B-A3B-Instruct's block at debug widths
     # (models/kimi_linear.py): a leading kda layer with a dense MLP, two

@@ -57,6 +57,18 @@ def mha_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
+def softmax_with_sink(logits, sink=None):
+    """Softmax over the last axis with one more term in the denominator:
+    `sink`, a logit that takes its share of the probability and carries no
+    value (broadcast against `logits[..., :1]`); None: the plain softmax.
+    A sink of -inf gives the plain softmax's very numbers."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    m = jnp.maximum(logits.max(axis=-1, keepdims=True), sink)
+    e = jnp.exp(logits - m)
+    return e / (e.sum(axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
 def _block_step(q, kc, vc, acc, m, l, mask=None):
     """One online-softmax accumulation step.
 
@@ -85,7 +97,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
     `jax.checkpoint` at the layer level for long sequences).
     """
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]  # the values may have a width of their own
     block_k = min(block_k, sk)
     nblocks = (sk + block_k - 1) // block_k
     pad = nblocks * block_k - sk
@@ -95,7 +107,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
 
     qs = _scale(q, sm_scale).astype(jnp.float32)
     kb = k.reshape(b, nblocks, block_k, h, d).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(b, nblocks, block_k, h, d).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(b, nblocks, block_k, h, dv).transpose(1, 0, 2, 3, 4)
 
     qi = jnp.arange(sq)[:, None] + q_offset  # global q positions
 
@@ -110,7 +122,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
         return (acc, m, l), None
 
     init = (
-        jnp.zeros((b, sq, h, d), jnp.float32),
+        jnp.zeros((b, sq, h, dv), jnp.float32),
         jnp.full((b, h, sq), NEG_INF, jnp.float32),
         jnp.zeros((b, h, sq), jnp.float32),
     )
@@ -300,7 +312,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     softmax's state (accumulator, running maximum and sum) in VMEM scratch."""
     import jax.experimental.pallas as pl
 
-    block_q, d = q_ref.shape
+    block_q, d = o_ref.shape  # the values' width: the keys' may be another
     j = pl.program_id(1)
     q = q_ref[:]
     bare, end = _k_blocks(j, seq_q, seq_k, block_q, block_k, causal, xp=jnp)
@@ -418,11 +430,15 @@ def _bhsd_to_flat(x, pad_s):
 
 
 def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k):
+    """q [B, S, H, D], k [B, Sk, kvH, D], v [B, Sk, kvH, Dv]: the values may
+    have a width of their own, and `H / kvH` adjoining query heads may share
+    a KV head, whose K and V a program then maps by index (no head is
+    repeated in HBM)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv, rep = k.shape[1], v.shape[-1], h // k.shape[2]
     scale = sm_scale if sm_scale is not None else d ** -0.5
     block_q, block_k = _flash_blocks(sq, sk, block_q, block_k)
     pad_q = (-sq) % block_q
@@ -438,28 +454,37 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k):
         _flash_fwd_kernel, sm_scale=scale, block_k=block_k, causal=causal,
         seq_k=sk, seq_q=sq,
     )
+    # program i is head i % h of sequence i // h; its KV head i // rep
+    kv_at = (lambda i, j: (i, 0, 0)) if rep == 1 else \
+        (lambda i, j: (i // rep, 0, 0))
+    kv_vmem = 2 * skp * (d + dv) * k.dtype.itemsize  # two buffers each
+    params = {}
+    if kv_vmem > FLASH_KV_VMEM_BYTES:  # past the compiler's own allowance
+        params = dict(compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=kv_vmem + FLASH_BLOCKS_VMEM_BYTES))
     out, lse = pl.pallas_call(
         kernel,
         name="flash_attention_fwd",
         out_shape=(
-            jax.ShapeDtypeStruct(qf.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * h, sqp, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sqp), jnp.float32),
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, skp, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, skp, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, skp, d), kv_at),
+            pl.BlockSpec((None, skp, dv), kv_at),
         ],
         out_specs=(
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, 1, sqp), lambda i, j: (i, 0, 0)),
         ),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, dv), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        **params,
     )(qf, kf, vf)
-    out = out.reshape(b, h, sqp, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, sqp, dv).transpose(0, 2, 1, 3)
     return out[:, :sq], lse
 
 
@@ -559,7 +584,11 @@ def flash_attention(q, k, v, causal: bool = True,
     fallback off-TPU.
 
     q [B, Sq, H, D], k / v [B, Sk, H, D] in any one float dtype; `causal`
-    aligns position 0 of q with position 0 of k. A kernel program holds one
+    aligns position 0 of q with position 0 of k. The FORWARD alone also takes
+    values of another width than the keys (v [B, Sk, kvH, Dv]) and `H / kvH`
+    adjoining query heads on one KV head, read where it lies (a prefill
+    whose keys are 192 wide in 256 lanes beside values of 128, 16 query
+    heads a KV head). A kernel program holds one
     head's whole K and V (dK/dV: Q and dO) in VMEM and walks them in blocks
     of `block_q` x `block_k` scores, (512, 512) by default and clipped to
     the lengths: on a v5e the best or within 2% of it at every shape probed
@@ -590,12 +619,18 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         lse = checkpoint_name(lse, FLASH_KEPT[1])
         return out, (q, k, v, out, lse)
     out = checkpoint_name(
-        blockwise_attention(q, k, v, causal, sm_scale, block_k), FLASH_KEPT[0])
+        blockwise_attention(q, *gqa_expand(k, v, q.shape[2]), causal,
+                            sm_scale, block_k), FLASH_KEPT[0])
     return out, (q, k, v, None, None)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
     q, k, v, o, lse = res
+    if q.shape[2:] != k.shape[2:] or k.shape != v.shape:
+        raise NotImplementedError(
+            "flash_attention's backward kernels take q, k and v of one "
+            "shape: a value width of its own and KV heads shared by query "
+            "heads are the forward's alone (a prefill's)")
     if lse is not None:
         return _flash_bwd_pallas(
             q, k, v, o, lse, g, causal, sm_scale, block_q, block_k
@@ -616,6 +651,14 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # refuses four times that, and the backward kernels twice (ROADMAP
 # `flash-f32` e).
 FLASH_KV_VMEM_BYTES = 8 << 20
+# Past it (8,192 keys of 256 beside values of 128 are 12 MiB) the forward
+# asks the compiler for what K and V take and this much for its blocks of q
+# and o, its scratch and the block step's scores.
+FLASH_BLOCKS_VMEM_BYTES = 12 << 20
+# The most of K and V that the forward has been given so (`flash_attention_
+# takes`): 8,192 keys of 192 in 256 lanes beside values of 128 (PERF.md
+# section 6, PR 58).
+FLASH_KV_VMEM_MAX_BYTES = 12 << 20
 # Float32 scores [B, H, S, S] up to which the XLA spelling of a prefill's
 # attention (`models/decoding.py::_attend_cached`) is as fast as the kernel
 # or faster: the compiler keeps them in the v5e's 128 MiB of fast memory
@@ -626,22 +669,28 @@ FLASH_KV_VMEM_BYTES = 8 << 20
 DENSE_SCORES_BYTES = 112 << 20
 
 
-def flash_attention_takes(q, k) -> bool:
-    """Whether causal self-attention of q [B, S, H, D] over k / v [B, S, kvH,
-    D] goes to the forward KERNEL, for a caller that has another spelling
-    to fall back on (a prefill's fresh rows, `models/decoding.py::
-    attend_held`), by what can be seen of the call: on a TPU (as
-    `flash_attention`), one 2- or 4-byte dtype, heads of whole lanes, a
+def flash_attention_takes(q, k, v=None) -> bool:
+    """Whether causal self-attention of q [B, S, H, D] over k [B, S, kvH, D]
+    and v [B, S, kvH, Dv] (None: as k) goes to the forward KERNEL, for a
+    caller that has another spelling to fall back on (a prefill's fresh
+    rows, `models/decoding.py::attend_held`), by what can be seen of the
+    call: on a TPU (as `flash_attention`), one 2- or 4-byte dtype, heads of
+    whole lanes (the values' width may be another than the keys'), a
     length of whole 128s (the row statistics leave the kernel 128 positions
     at a time, `_store_row`: the chip's compiler refuses 16 to 64
-    positions), a head's whole K and V inside `FLASH_KV_VMEM_BYTES`, and
-    scores past `DENSE_SCORES_BYTES`: below that the other spelling never
-    sends them to HBM and the kernel has nothing to save."""
+    positions), a head's whole K and V inside `FLASH_KV_VMEM_BYTES` (values
+    of a width of their own: `FLASH_KV_VMEM_MAX_BYTES`, which the call then
+    asks of the compiler), and scores past `DENSE_SCORES_BYTES`: below that
+    the other spelling never sends them to HBM and the kernel has nothing to
+    save."""
     b, s, h, d = q.shape
     itemsize = q.dtype.itemsize
+    dv = d if v is None else v.shape[-1]
+    fits = 4 * s * d * itemsize <= FLASH_KV_VMEM_BYTES if dv == d else \
+        2 * s * (d + dv) * itemsize <= FLASH_KV_VMEM_MAX_BYTES
     return (_on_tpu() and q.dtype == k.dtype and itemsize in (2, 4)
-            and k.shape[1] == s and d % 128 == 0 and s % 128 == 0
-            and 4 * s * d * itemsize <= FLASH_KV_VMEM_BYTES
+            and k.shape[1] == s and d % 128 == 0 and dv % 128 == 0
+            and s % 128 == 0 and fits
             and 4 * b * h * s * s > DENSE_SCORES_BYTES)
 
 
@@ -669,26 +718,60 @@ def decode_block(t: int, row_bytes: int) -> int:
     return block
 
 
-def decode_attention_takes(stack) -> bool:
-    """Whether `decode_attention` runs on a cache stack [N, B, T, kvH, D] of
-    this shape and dtype, here: on a TPU (as `flash_attention`), rows of
-    whole lanes, slots of whole sublanes, and 2- or 4-byte values whose KV
-    heads fill whole 32-bit words and whole tiles of 1, 2, 4 or 8 words
-    (XLA then keeps a position's heads unpadded, and so does the kernel)."""
-    _, _, t, kvh, d = stack.shape
-    itemsize = stack.dtype.itemsize
-    if itemsize not in (2, 4) or kvh % (4 // itemsize):
-        return False
-    words = kvh // (4 // itemsize)
-    return (_on_tpu() and (words in (1, 2, 4) or words % 8 == 0)
-            and t % 8 == 0 and d % 128 == 0)
+def decode_attention_takes(stack, v_stack=None) -> bool:
+    """Whether `decode_attention` runs on a cache stack [N, B, T, kvH, D]
+    (as `v_stack`, if given) of this shape and dtype, here: on a TPU (as
+    `flash_attention`), rows of ONE lane tile or, beside values as wide,
+    whole ones (the compiler takes a strided read of a head's rows only out
+    of a buffer whose last dimension is one tile: keys wider than the values
+    are cached in pieces of the values' width, `key_pieces`), slots of whole
+    sublanes, and 2- or 4-byte values whose KV heads fill whole 32-bit words
+    and whole tiles of 1, 2, 4 or 8 words (XLA then keeps a position's heads
+    unpadded, and so does the kernel)."""
+    for one in (stack,) if v_stack is None else (stack, v_stack):
+        _, _, t, kvh, d = one.shape
+        itemsize = one.dtype.itemsize
+        if itemsize not in (2, 4) or kvh % (4 // itemsize):
+            return False
+        words = kvh // (4 // itemsize)
+        if not ((words in (1, 2, 4) or words % 8 == 0) and t % 8 == 0
+                and d % 128 == 0):
+            return False
+    # keys in pieces: each as wide as the values, one lane tile
+    pieces = v_stack is not None and v_stack.shape != stack.shape
+    return _on_tpu() and (not pieces or (
+        stack.shape[-1] == v_stack.shape[-1] == 128
+        and stack.shape[3] % v_stack.shape[3] == 0
+        and stack.dtype == v_stack.dtype))
 
 
-def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
-                             kbuf, vbuf, sem, *, block):
-    """q_ref / o_ref [B, kvH, R, D] in VMEM (a KV head's `rep` query heads
-    padded to R rows); k_hbm / v_hbm the stacks [N, B, T, kvH, D] where XLA
-    keeps them; kbuf / vbuf [2, block, kvH, D]. ONE invocation walks the
+def key_pieces(k, width: int):
+    """Keys [..., kvH, D] as [..., kvH * D / width, width]: a head's key in
+    adjoining pieces of `width` (the values' width), as a cache keeps keys
+    that are wider than its values; `D == width`: as they are."""
+    d = k.shape[-1]
+    return k if d == width else k.reshape(
+        *k.shape[:-2], k.shape[-2] * (d // width), width)
+
+
+def whole_keys(k, kvh: int):
+    """`key_pieces`' way back: [..., kvH * pieces, width] as [..., kvH, D]."""
+    return k if k.shape[-2] == kvh else k.reshape(
+        *k.shape[:-2], kvh, k.shape[-1] * (k.shape[-2] // kvh))
+
+
+def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
+                             block, sink, sm_scale):
+    """q_ref [B, kvH, R, D] / o_ref [B, kvH, R, Dv] in VMEM (a KV head's
+    `rep` query heads padded to R rows); k_hbm [N, B, T, kvH * chunks, D /
+    chunks] / v_hbm [N, B, T, kvH, Dv] the stacks where XLA keeps them (keys
+    wider than the values lie in `chunks` pieces of the values' width a
+    head, pieces of one head adjoining: a strided read takes whole lane
+    tiles only); kbuf / vbuf two blocks of them each. With `sink`, `rest` leads with sink_ref [kvH,
+    R, _LANES] float32 (a query head's learned logit over its lanes): the
+    online softmax STARTS from it, a running maximum of the sink and a sum
+    of one, so that it is in every denominator and adds no value. ONE
+    invocation walks the
     slots in order and each slot's `ceil(rows / block)` blocks, the next
     block's copy (the same slot's, or block 0 of the next slot that holds a
     row) in flight while this one is attended to: a slot without rows starts
@@ -696,7 +779,11 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    sink_ref, (o_ref, kbuf, vbuf, sem) = (rest[0] if sink else None), \
+        rest[-4:]
     n_slots, kvh, r, d = q_ref.shape
+    dv = o_ref.shape[-1]
+    chunks = kbuf.shape[2] // kvh
     layer = layer_ref[0]
     # 2-byte rows lie in pairs of KV heads, one 32-bit word a pair and lane
     packing = 4 // kbuf.dtype.itemsize
@@ -724,12 +811,13 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
         buffer [block, kvH, D]: a strided read of the heads' sublanes, and
         for 2-byte rows the two halves of each word. Rows outside `keep`
         [block, D] come out as zeros whatever the buffer holds there."""
-        flat = ref.reshape(block * kvh, d)
+        heads = ref.shape[1]  # a position's rows: KV heads, or their pieces
+        flat = ref.reshape(block * heads, ref.shape[-1])
         if packing == 1:
-            rows = flat[pl.ds(g0, block, stride=kvh), :]
+            rows = flat[pl.ds(g0, block, stride=heads), :]
             return [rows if keep is None else jnp.where(keep, rows, 0)]
         words = flat.bitcast(jnp.uint32)[
-            pl.ds(g0 // 2, block, stride=kvh // 2), :]
+            pl.ds(g0 // 2, block, stride=heads // 2), :]
         if keep is not None:
             words = jnp.where(keep, words, jnp.uint32(0))
         return [pltpu.bitcast(half, jnp.float32).astype(ref.dtype)
@@ -766,16 +854,21 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
             held = (j * block + lax.broadcasted_iota(
                 jnp.int32, (r, block), 1)) < rows
             v_held = (j * block + lax.broadcasted_iota(
-                jnp.int32, (block, d), 0)) < rows
+                jnp.int32, (block, dv), 0)) < rows
             new_state = []
             for g0 in range(0, kvh, packing):
-                ks = heads_of(kbuf.at[buf], g0)
+                ks = [k for at in range(g0 * chunks, (g0 + packing) * chunks,
+                                        packing)
+                      for k in heads_of(kbuf.at[buf], at)]
                 vs = heads_of(vbuf.at[buf], g0, v_held)
-                for g, k, v in zip(range(g0, g0 + packing), ks, vs):
+                for i, (g, v) in enumerate(zip(range(g0, g0 + packing), vs)):
                     m, l, acc = state[g]
-                    s = lax.dot_general(
-                        q_ref[b, g], k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * (d ** -0.5)
+                    s = functools.reduce(jnp.add, [lax.dot_general(
+                        q_ref[b, g] if chunks == 1 else
+                        q_ref[b, g, :, c * _LANES:(c + 1) * _LANES],
+                        ks[i * chunks + c], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                        for c in range(chunks)]) * sm_scale
                     s = jnp.where(held, s, NEG_INF)
                     m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
                     p = jnp.exp(s - m_new)
@@ -789,8 +882,12 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         empty = (jnp.full((r, 1), NEG_INF, jnp.float32),
                  jnp.zeros((r, 1), jnp.float32),
-                 jnp.zeros((r, d), jnp.float32))
-        buf, state = lax.fori_loop(0, n_blocks, attend, (buf, (empty,) * kvh))
+                 jnp.zeros((r, dv), jnp.float32))
+        start_from = (empty,) * kvh
+        if sink:
+            start_from = tuple((sink_ref[g][:, :1], jnp.ones_like(empty[1]),
+                                empty[2]) for g in range(kvh))
+        buf, state = lax.fori_loop(0, n_blocks, attend, (buf, start_from))
         for g, (_, l, acc) in enumerate(state):  # no row held: zeros
             o_ref[b, g] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         return buf
@@ -799,13 +896,19 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def decode_attention(q, k_stack, v_stack, layer, rows,
-                     block: Optional[int] = None):
+                     block: Optional[int] = None, sink=None,
+                     sm_scale: Optional[float] = None):
     """One new token a sequence against its cached rows: q [B, H, D], the
-    cache STACKS k_stack / v_stack [N, B, T, kvH, D], `layer` (int32 scalar)
+    cache STACKS k_stack / v_stack [N, B, T, kvH, D], or, where the keys are
+    wider than the values, k_stack [N, B, T, kvH * D / Dv, Dv] (a head's key
+    in adjoining pieces of the values' width) beside v_stack [N, B, T, kvH,
+    Dv]; `layer` (int32 scalar)
     the layer to read and `rows` (int32 [B]) how many of its T rows each
-    slot holds, a prefix. Returns [B, H, D] in q's dtype: softmax(q k^T /
-    sqrt(D)) v over rows 0 .. rows[b] - 1 per KV-head group (the heads of
-    one group adjoin), zeros where rows[b] == 0.
+    slot holds, a prefix. Returns [B, H, Dv] in q's dtype: softmax(q k^T
+    sm_scale) v (`sm_scale` None: 1 / sqrt(D)) over rows 0 .. rows[b] - 1 per
+    KV-head group (the heads of one group adjoin), zeros where rows[b] == 0.
+    `sink` [H] (float): a learned logit a query head that joins the
+    softmax's denominator and carries no value.
 
     A Pallas kernel. `layer` and `rows` are scalar-prefetch operands and
     the stacks stay in HBM: slot b's `ceil(rows[b] / block)` blocks of K and
@@ -819,19 +922,26 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    n, _, t, kvh, _ = k_stack.shape
+    n, _, t, kvh, dv = v_stack.shape
     rep = h // kvh
     itemsize = k_stack.dtype.itemsize
-    block = block or decode_block(t, kvh * d * itemsize)
+    block = block or decode_block(t, kvh * max(d, dv) * itemsize)
     tile = 8 * (4 // itemsize)  # rows of a tile of q's dtype
     r = -(-rep // tile) * tile
     q4 = q.astype(k_stack.dtype).reshape(b, kvh, rep, d)
     if r != rep:
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r - rep), (0, 0)))
+    sinks = ()
+    if sink is not None:
+        sinks = (jnp.broadcast_to(jnp.pad(
+            sink.astype(jnp.float32).reshape(kvh, rep),
+            ((0, 0), (0, r - rep)))[:, :, None], (kvh, r, _LANES)),)
     out = pl.pallas_call(
-        functools.partial(_decode_attention_kernel, block=block),
+        functools.partial(
+            _decode_attention_kernel, block=block, sink=sink is not None,
+            sm_scale=d ** -0.5 if sm_scale is None else sm_scale),
         name="decode_attention",
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, r, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
@@ -839,18 +949,18 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
                 pl.BlockSpec(memory_space=pltpu.VMEM),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            ] + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(sinks),
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[
-                pltpu.VMEM((2, block, kvh, d), k_stack.dtype),
-                pltpu.VMEM((2, block, kvh, d), v_stack.dtype),
+                pltpu.VMEM((2, block, *k_stack.shape[3:]), k_stack.dtype),
+                pltpu.VMEM((2, block, kvh, dv), v_stack.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       # a copy is started for every block counted here and waited for
-      jnp.clip(rows.astype(jnp.int32), 0, t), q4, k_stack, v_stack)
-    return out[:, :, :rep].reshape(b, h, d)
+      jnp.clip(rows.astype(jnp.int32), 0, t), q4, k_stack, v_stack, *sinks)
+    return out[:, :, :rep].reshape(b, h, dv)
 
 
 def latent_decode_attention_takes(stack, value_dim: int) -> bool:

@@ -471,7 +471,20 @@ SERVE_CONFIGS = {
 KIMI_LINEAR = "kimi-linear-48b-a3b-serve-ep16"
 LONGCAT = "longcat-flash-serve-ep32-d4"
 NEMOTRON_H = "nemotron-3-super-serve-ep8-d22"
+MIMO = "mimo-v2-flash-serve-ep16-d11"
 PATTERN_CONFIGS = {
+    # MiMo-V2-Flash's published widths, layers 0-10 of 48 (full, 4 window,
+    # full, 5 window), one chip's share of a layer that sixteen hold: 16 of
+    # 256 experts, an eighth of the vocabulary; 32 slots of 10240 positions,
+    # the 8192 bucket
+    MIMO: dict(
+        name="mimo_v2_debug", vocab_size=19072, hidden=4096, mlp_hidden=2048,
+        layers=11, heads=64, kv_heads=4, head_dim=192, max_seq=262144,
+        num_experts=256, experts_per_token=8, experts_held=(0, 16),
+        layer_kinds=("full",) + ("window",) * 4 + ("full",)
+        + ("window",) * 5, window=128, window_heads=64, window_kv_heads=8,
+        value_dim=128, dense_mlp_hidden=16384, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, slots=32, max_len=10240, bucket=8192),
     KIMI_LINEAR: dict(
         name="kimi_linear_debug", vocab_size=20480, hidden=2304,
         mlp_hidden=1024, layers=27, heads=32, kv_heads=32, head_dim=128,
@@ -1004,6 +1017,84 @@ def test_state_space_serve_programs_compile_and_fit(serve_programs):
     leaves = len(jax.tree.leaves(jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.key(0)))))
     assert _entry_parameters(decode) == leaves + 5 + 5  # k, v, lengths + 2
+    from ray_tpu.observability import schema
+
+    assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+def test_sink_window_serve_programs_compile_and_fit(serve_programs):
+    """The `serve-sink-window-moe-agent-8k-in-2k-out` deployment
+    (MiMo-V2-Flash at its published widths, layers 0-10, 16 of 256 experts, 32
+    slots x 10240): the cache holds each kind's rows by its own KV heads, a
+    key of 192 as two pieces of 128 beside a value of 128; the decode step
+    is given all four stacks to keep (aliased in to out), keeps no window
+    layer's rows in slots, and reads both kinds' rows with the Mosaic call
+    that is given the stacks (a lead, a run of 4 window layers, a full layer,
+    a run of 5: four bodies, three scans); the prefill of the 8192 bucket
+    attends its full layers' fresh rows with the two-width flash forward and
+    holds no [*, 8192, 8192] array, and stays with the engine's cache under
+    15 GB."""
+    from benchmarks import harness, scope_ops
+    from ray_tpu.models import laguna, pattern
+
+    cfg, prefill, decode, cache = serve_programs(MIMO)
+    slots = cache.lengths.shape[0]
+    assert [cfg.kinds.count(k) for k in ("full", "window")] == [2, 9]
+    assert [(len(u), r) for u, r in pattern.runs(
+        cfg.layer_kinds[1:], laguna.RUN_MAX)] == [(1, 4), (1, 1), (1, 5)]
+    assert cfg.sparse_layers == 10 and cfg.num_params() == 5422283840
+    assert cache.k.shape == (2, slots, 10240, 8, 128)  # 4 KV heads x 2 pieces
+    assert cache.v.shape == (2, slots, 10240, 4, 128)
+    assert cache.ring_k.shape == (9, slots, 128, 16, 128)  # 8 KV heads x 2
+    assert cache.ring_v.shape == (9, slots, 128, 8, 128)
+    assert cache.state is None and cache.mat is None
+    kept = _arg_bytes((cache.k, cache.v, cache.ring_k, cache.ring_v))
+    assert round(kept / 1e9, 2) == 2.24  # 2.013 of slots, 0.226 of rings
+    for name, program in (("prefill[8192]", prefill),
+                          (f"decode[{slots}x10240]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        assert "s32[256]" in program.as_text()  # the load over all experts
+        # no layer's held experts are copied out of their stack
+        assert not re.search(r"bf16\[16,(4096,2048|2048,4096)\]",
+                             program.as_text())
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    assert m.temp_size_in_bytes < _arg_bytes(cache.k) / 4  # no layer of it
+    assert _total_bytes(decode) < 15e9
+    assert _total_bytes(prefill) + kept < 15e9  # beside the engine's cache
+    text, ptext = decode.as_text(), prefill.as_text()
+    for op_name, dtype, dims, op in _results(ptext):
+        assert dims.count(8192) < 2, (op_name, dims, op)  # no [S, S] logits
+    for op_name, dtype, dims, op in _results(text):
+        # no window layer keeps its rows in slots: nine layers of them
+        assert not (dims[:1] == [9] and 10240 in dims), (op_name, dims, op)
+
+    def mosaic(of, name):
+        return {scope_ops._INSTRUCTION.match(line)[1]
+                for line in of.splitlines()
+                if "tpu_custom_call" in line and "%" + name in line}
+
+    runner = harness.load_module("runners", "serve_mimo")
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) >= set(runner.SCOPES)
+    attends = mosaic(text, "decode_attention")
+    assert len(attends) == 4  # the lead, a window body, the full, a window
+    assert len(attends & set(scopes["attn.full"])) == 2
+    assert len(attends & set(scopes["attn.window"])) == 2
+    flash = mosaic(ptext, "flash_attention_fwd")
+    pscopes = scope_ops.op_scopes(ptext, runner.SCOPES)
+    assert len(flash) == 2 and flash <= set(pscopes["attn.full"])
+    assert serve_programs.attention_paths(MIMO) == {"prefill_8192": "flash"}
+    assert serve_programs.grouped_paths[MIMO] == {
+        "decode": "kernel", "prefill_8192": "ragged_dot"}
+    # a window layer's prefill in a band: [.., 128, 256] logits
+    assert re.search(r"f32\[1,64,8,8,128,256\]", ptext)
     from ray_tpu.observability import schema
 
     assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)

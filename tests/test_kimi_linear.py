@@ -428,5 +428,8 @@ def test_each_refusal_names_what_it_refuses(params):
             CFG, params, jnp.zeros((1, 1), jnp.int32),
             jnp.zeros((1, 1), jnp.int32), cache, jnp.ones((1, 16), bool),
             jnp.ones((1, 1), bool), access=lambda layer: None)
-    with pytest.raises(ValueError, match="router_score: no field of .*laguna"):
-        dataclasses.replace(T.config("laguna_debug"), router_score="sigmoid")
+    # the sigmoid router is the window-and-full family's too since its second
+    # model (PR 58); a latent the experts work in is not
+    dataclasses.replace(T.config("laguna_debug"), router_score="sigmoid")
+    with pytest.raises(ValueError, match="moe_latent: no field of .*laguna"):
+        dataclasses.replace(T.config("laguna_debug"), moe_latent=16)

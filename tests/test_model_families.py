@@ -19,7 +19,7 @@ from ray_tpu.ops.attention import decode_block
 PRESETS = {
     "transformer": ("debug", "moe_debug", "olmoe_debug"),
     "zaya": ("zaya_debug",),
-    "laguna": ("laguna_debug",),
+    "laguna": ("laguna_debug", "mimo_v2_debug"),
     "kimi_linear": ("kimi_linear_debug",),
     "longcat": ("longcat_debug",),
     "nemotron_h": ("nemotron_h_debug",),
@@ -37,7 +37,8 @@ AWAY = dict(
     mla_q_rank=8, mla_rotate=True, mla_scales=(2.0, 2.0),
     router_score="sigmoid", zero_experts=4, ssm_heads=4, ssm_head_dim=16,
     ssm_groups=4, ssm_state=8, ssm_conv=3, ssm_chunk=64, moe_latent=16,
-    expert_act="relu2")
+    expert_act="relu2", window_kv_heads=2, value_dim=8, window_sink=True,
+    value_scale=0.5, window_partial_rotary=0.5)
 BATCH, MAX_LEN = 3, 32
 
 
@@ -69,9 +70,12 @@ def hand_rows(name: str, cfg, lens):
     if name == "laguna":
         full, window = cfg.kinds.count("full"), cfg.kinds.count("window")
         ring = np.minimum(lens, cfg.window)
+        # a kind's rows by its own KV heads, a key as wide as it is carried
+        row = module(name).key_row(cfg)
         return (full * int(lens.sum()) + window * int(ring.sum()),
-                full * blocks(lens, MAX_LEN, kv)
-                + window * blocks(ring, cfg.window, kv))
+                full * blocks(lens, MAX_LEN, cfg.kv_heads * row)
+                + window * blocks(ring, cfg.window, (
+                    cfg.window_kv_heads or cfg.kv_heads) * row))
     if name == "nemotron_h":  # the attention layers' rows; a mixer keeps none
         gqa = cfg.kinds.count("gqa")
         return gqa * int(lens.sum()), gqa * blocks(lens, MAX_LEN, kv)
@@ -112,7 +116,8 @@ def test_a_family_states_what_it_is_and_the_rest_reads_it(name, preset):
         rows = () if kept.rows is None else (kept.rows,)
         for field in kept.fields:
             array = getattr(cache, field)
-            assert array.shape == (kept.layers, BATCH, *rows, *kept.shape)
+            assert array.shape == (kept.layers, BATCH, *rows,
+                                   *kept.shape_of(field))
             assert array.dtype == (kept.dtype or cfg.dtype)
     for field in set(KVCache._fields) - set(names) - {"lengths"}:
         array = getattr(cache, field)  # K/V without a layer, the rest absent
