@@ -383,20 +383,24 @@ class ContinuousBatcher(PrefillPrograms):
             # of those assignments, the ones to experts held here (the rest
             # are the other chips' of the layer), and the held experts that
             # had at least one real row, summed over layers and decode steps:
-            # what the steps' grouped matmuls had to read
-            self.stats.update(moe_assignments_held=0, moe_experts_reached=0)
+            # what the steps' grouped matmuls had to read; the rows the
+            # programs GATHERED for their grouped matmuls
+            # (`transformer.rows_gathered`: pad rows' and free slots'
+            # choices among them), against which the held assignments are
+            # the rows that met a weight, and how many of their calls held
+            # more than `held_rows_cap` and took the whole layout: a capped
+            # program counts both; without a cap the layout is static, every
+            # assignment of the rows the program ran, and never gives way
+            self.stats.update(moe_assignments_held=0, moe_experts_reached=0,
+                              moe_rows_gathered=0, moe_calls_whole_layout=0)
         if cfg.zero_experts:
             # of the real rows' choices, those on zero-compute outputs (their
             # weight times the input, no matmul) and on routed experts that
-            # are not held here; the rows the programs GATHERED for their
-            # grouped matmuls (`transformer.rows_gathered`: a static number
-            # a call, pad rows' and free slots' choices among them), against
-            # which the held assignments are the rows that met a weight;
-            # and, summed over
-            # the programs, the most routed experts one real row chose in
-            # one layer (`aux["routed_most"]`)
+            # are not held here; and, summed over the programs, the most
+            # routed experts one real row chose in one layer
+            # (`aux["routed_most"]`)
             self.stats.update(moe_assignments_zero=0, moe_assignments_absent=0,
-                              moe_rows_gathered=0, moe_routed_most=0)
+                              moe_routed_most=0)
         self._thread = threading.Thread(
             target=self._pump, daemon=True, name="cb-pump")
         self._thread.start()
@@ -735,8 +739,10 @@ class ContinuousBatcher(PrefillPrograms):
         program) to the counters. Called where the program's tokens have
         just been copied to the host, so the load is ready (`_fetch_ahead`)
         and this is no sync point of its own. `step`: a decode step's, whose
-        reached experts (`[load, choices, reached]` from a held share) are
-        what its grouped matmuls read; a prefill reaches them all."""
+        reached experts (`[load, choices, counted]` from a held share:
+        `pattern.sparse_mlp`'s reached and, from a capped layout, rows
+        gathered and whole-layout calls) are what its grouped matmuls read;
+        a prefill reaches them all."""
         if not load:
             return
         loads, load = load, np.asarray(load[0])
@@ -751,8 +757,18 @@ class ContinuousBatcher(PrefillPrograms):
             first, count = self.cfg.experts_held
             self.stats["moe_assignments_held"] += int(
                 load[first:first + count].sum())
+            counted = np.asarray(loads[2])
+            if counted.ndim:
+                reached, gathered, whole = counted.tolist()
+            else:  # no cap: k rows a row of the program, a sparse layer
+                ran = self.slots if step else min(self._bucket(rows),
+                                                  self.max_len)
+                reached, gathered, whole = int(counted), ran \
+                    * self.cfg.experts_per_token * self.cfg.sparse_layers, 0
             if step:
-                self.stats["moe_experts_reached"] += int(np.asarray(loads[2]))
+                self.stats["moe_experts_reached"] += reached
+            self.stats["moe_rows_gathered"] += gathered
+            self.stats["moe_calls_whole_layout"] += whole
         if self.cfg.zero_experts:
             first, count = self.cfg.experts_held or (0, self.cfg.num_experts)
             on_zero = int(load[self.cfg.num_experts:].sum())
@@ -760,7 +776,6 @@ class ContinuousBatcher(PrefillPrograms):
             self.stats["moe_assignments_absent"] += int(
                 load.sum() - on_zero - load[first:first + count].sum())
             self.stats["moe_routed_most"] += int(np.asarray(loads[3]))
-            self.stats["moe_rows_gathered"] += int(np.asarray(loads[4]))
 
     def _log_routes(self, counted: list, reqs: Dict[int, _Request]) -> None:
         """Keep a program's `expert_choice` (`counted[1]`, behind the load)

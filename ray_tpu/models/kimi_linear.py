@@ -571,7 +571,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     states and convolution windows and the "mla" layers' latent rows, all
     written in place at [layer of its kind]. `aux` as `laguna.
     forward_cached`'s: "expert_load", "expert_choice" [sparse layers, B*S,
-    k], "experts_reached"."""
+    k], "experts_counted"."""
     only_the_stack(cfg, access)
     blocks = params["blocks"]
     sparse = {n: a for n, a in blocks["sparse"].items()
@@ -621,7 +621,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
 
     (x, held), (load, choice, reached) = lax.scan(
         one_period, (x, held), jnp.arange(cfg.periods))
-    load, reached = load.sum(0), reached.sum()
+    load, reached = load.sum(0), reached.sum(0)
     choice = choice.reshape(-1, *choice.shape[2:])
     if cfg.tail_kinds:
         at = {k: lead[k] + cfg.periods * cfg.layer_kinds.count(k)
@@ -631,7 +631,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
         load, reached = load + l, reached + r
         choice = jnp.concatenate([choice, c])
     aux = {"expert_load": load, "expert_choice": choice,
-           "experts_reached": reached}
+           "experts_counted": reached}
     mat, conv, latent = held
     return (lm_head(cfg, params, x),
             cache._replace(mat=mat, conv=conv, latent=latent), aux)

@@ -416,8 +416,11 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     lie close, rounding flips them, and a flipped expert moves a logit by a
     third of the logits' spread: a comparison with a reference has to know
     the sets that were taken, as with ZAYA1's one expert);
-    "experts_reached": how many experts held here the real rows reached,
-    summed over the layers: what a step's grouped matmuls read}."""
+    "experts_counted": `pattern.sparse_mlp`'s summed over the layers: how
+    many experts held here the real rows reached (what a step's grouped
+    matmuls read) and, in int32 [3] where `held_rows_cap` caps the layers'
+    calls, the rows they gathered and the calls that took the whole
+    layout}."""
     only_the_stack(cfg, access)
     blocks = params["blocks"]
     sparse = {n: a for n, a in blocks["sparse"].items()
@@ -441,7 +444,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     def unit_of(kinds, carry, at, sparse_at):
         """The layers `kinds` in a row from the `at[kind]`-th of each kind,
         the j-th of them with sparse layer `sparse_at(j)`: (carry, (load
-        summed, choices stacked, reached summed))."""
+        summed, choices stacked, `sparse_mlp`'s counted summed))."""
         x, k, v, ring_k, ring_v = carry
         load, reached, choices = 0, 0, []
         for j, kind in enumerate(kinds):
@@ -485,7 +488,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
                 period, carry, jnp.arange(repeats))
             load = load.sum(0)
             choice = choice.reshape(-1, *choice.shape[2:])
-            reached = reached.sum()
+            reached = reached.sum(0)
         else:
             carry, (load, choice, reached) = unit_of(
                 kinds, carry, at, lambda j, layer=layer: layer + j)
@@ -498,6 +501,6 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     aux = {"expert_load": functools.reduce(operator.add, loads),
            "expert_choice": jnp.concatenate(choices) if len(choices) > 1
            else choices[0],
-           "experts_reached": functools.reduce(operator.add, reaches)}
+           "experts_counted": functools.reduce(operator.add, reaches)}
     return (lm_head(cfg, params, x),
             KVCache(k, v, cache.lengths, None, ring_k, ring_v), aux)

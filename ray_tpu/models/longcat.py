@@ -55,7 +55,8 @@ from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
     only_the_stack, param_axes,
 )
 from ray_tpu.models.transformer import (
-    TransformerConfig, _rms_norm, moe_dropless, rows_gathered,
+    TransformerConfig, _rms_norm, layout_counted, moe_dropless,
+    rows_gathered,
 )
 
 # The stored selection bias is seeded normal of this over the router's
@@ -133,10 +134,12 @@ def expert_branch(cfg: TransformerConfig, y, p, row_mask, layer):
     """The shortcut's expert layer on the normed stream y [B, S, h]: `p` is
     double layer `layer`'s router and bias and the WHOLE expert stacks.
     Returns (m [B, S, h], load [router outputs] from the real rows, the
-    outputs every row chose [B*S, k], how many of the experts held here the
-    real rows reached, the most routed (not zero-compute) experts any real
-    row chose, the rows that were gathered for the grouped matmuls:
-    `transformer.rows_gathered`). A long prompt's rows go through
+    outputs every row chose [B*S, k], `counted` as `pattern.sparse_mlp`'s:
+    how many of the experts held here the real rows reached and, where a
+    cap stands, the rows gathered and the calls that took the whole layout
+    (`transformer.layout_counted`), the most routed (not zero-compute)
+    experts any real row chose, the rows that were gathered for the grouped
+    matmuls: `transformer.rows_gathered`). A long prompt's rows go through
     `moe_dropless` `EXPERT_ROWS` at a time: the layout it falls back to is
     k gathered rows a row whatever is held (at 4,096 rows x 12 the three
     float32 [rows x k, h] arrays are 3.6 GB, where 4 GB are free)."""
@@ -148,22 +151,27 @@ def expert_branch(cfg: TransformerConfig, y, p, row_mask, layer):
             rows, real, w, e = xs
             out, load = moe_dropless(cfg, rows[None], p, real[None], layer,
                                      (w, e))
-            return None, (out[0], load, rows_gathered(cfg, e))
+            return None, (out[0], load, rows_gathered(cfg, e),
+                          layout_counted(cfg, e))
 
         n = t // EXPERT_ROWS
-        _, (m, load, gathered) = lax.scan(some, None, (
+        _, (m, load, gathered, layout) = lax.scan(some, None, (
             y.reshape(n, EXPERT_ROWS, h), row_mask.reshape(n, EXPERT_ROWS),
             weights.reshape(n, EXPERT_ROWS, k),
             experts.reshape(n, EXPERT_ROWS, k)))
         m, load, gathered = m.reshape(b, s, h), load.sum(0), gathered.sum()
+        layout = layout if layout is None else layout.sum(0)
     else:
         m, load = moe_dropless(cfg, y, p, row_mask, layer, (weights, experts))
         gathered = rows_gathered(cfg, experts)
+        layout = layout_counted(cfg, experts)
     first, count = cfg.experts_held or (0, cfg.num_experts)
     reached = (load[first:first + count] > 0).sum().astype(jnp.int32)
     routed = (experts < cfg.num_experts).sum(-1)  # [B*S]
     most = jnp.where(row_mask.reshape(-1), routed, 0).max().astype(jnp.int32)
-    return m, load.astype(jnp.int32), experts, reached, most, gathered
+    counted = reached if layout is None \
+        else jnp.concatenate([reached[None], layout])
+    return m, load.astype(jnp.int32), experts, counted, most, gathered
 
 
 def double_layer(cfg: TransformerConfig, x, mla, mlp, sparse, positions,
@@ -208,9 +216,11 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     in place at [2 x double layer + sublayer]. `aux` as `laguna.
     forward_cached`'s ("expert_load" over the router's outputs, the
     zero-compute ones behind the routed; "expert_choice" [layers, B*S, k];
-    "experts_reached"), then "routed_most": the most routed experts one real
+    "experts_counted"), then "routed_most": the most routed experts one real
     row chose in one layer, a row's largest share of real expert work, and
-    "rows_gathered": the rows the expert layers gathered, over the layers."""
+    "rows_gathered": the rows the expert layers gathered, over the layers
+    (the program's last output, where `benchmarks/runners/serve_longcat.py`
+    counts five from the end; the engine reads `experts_counted`)."""
     only_the_stack(cfg, access)
     blocks = params["blocks"]
     small = {n: a for n, a in blocks["sparse"].items()
@@ -228,6 +238,6 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     (x, latent), (load, choice, reached, most, gathered) = lax.scan(
         one, (x, cache.latent), jnp.arange(cfg.layers))
     aux = {"expert_load": load.sum(0), "expert_choice": choice,
-           "experts_reached": reached.sum(), "routed_most": most.max(),
+           "experts_counted": reached.sum(0), "routed_most": most.max(),
            "rows_gathered": gathered.sum()}
     return lm_head(cfg, params, x), cache._replace(latent=latent), aux

@@ -290,7 +290,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     stacks and the "ssm" layers' states and convolution windows, all written
     in place at [layer of its kind]. `aux` as `laguna.forward_cached`'s:
     "expert_load", "expert_choice" [sparse layers, B*S, k],
-    "experts_reached"."""
+    "experts_counted"."""
     only_the_stack(cfg, access)
     blocks = params["blocks"]
     names = expert_names(cfg)
@@ -341,12 +341,12 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
             load, choice, reach = (jnp.stack(c, axis=1) for c in zip(*counted))
             loads.append(load.sum((0, 1)))
             choices.append(choice.reshape(-1, *choice.shape[2:]))
-            reached.append(reach.sum())
+            reached.append(reach.sum((0, 1)))
     x, k, v, mat, conv = carry
     aux = {}
     if loads:
         aux = {"expert_load": sum(loads),
                "expert_choice": jnp.concatenate(choices),
-               "experts_reached": sum(reached)}
+               "experts_counted": sum(reached)}
     return (lm_head(cfg, params, x),
             cache._replace(k=k, v=v, mat=mat, conv=conv), aux)

@@ -18,16 +18,27 @@ from jax import lax
 from ray_tpu.models import families
 from ray_tpu.models.decoding import _write_stack
 from ray_tpu.models.transformer import (
-    TransformerConfig, _rms_norm, moe_dropless, moe_router,
+    TransformerConfig, _rms_norm, held_rows_cap, layout_counted,
+    moe_dropless, moe_router,
 )
 
 EXPERT_LEAVES = ("wi_gate", "wi_up", "wo_mlp")  # a SwiGLU expert's stacks
 RUN_MAX = 4  # layers of one repeating unit at most (`runs`)
-# A call of more rows than `WHOLE_ROWS_MAX` runs its MLPs `MLP_ROWS` rows at a
-# time (`rows_at_a_time`): `moe_dropless` gathers k rows a row in float32 (at
-# 8,192 rows x 8 of 4,096 three arrays of 1 GB), and a dense SwiGLU of 16,384
-# holds [rows, 16,384] three times. Every cell's bucket but the 8,192 one is
-# below it and keeps the program it had.
+# A call of more rows than `WHOLE_ROWS_MAX` runs its MLPs a piece at a time
+# (`rows_at_a_time`): `moe_dropless` gathers its rows in float32 (k a row where
+# every assignment's is gathered: at 8,192 rows x 8 of 4,096 three arrays of
+# 1 GB), and a dense SwiGLU of 16,384 holds [rows, 16,384] three times. A
+# dense MLP's piece is `MLP_ROWS` rows; an expert layer's is cut by the rows
+# it GATHERS, `MLP_ROWS` x k a call (`expert_rows`): `MLP_ROWS` rows without
+# a cap, up to `WHOLE_ROWS_MAX` where `held_rows_cap` answers (at a sixteenth
+# held 4,096 rows gather 8,192: an 8,192-row prompt reads its 805 MB of held
+# experts a layer twice, not eight times: the grouped matmuls 105 -> 52 ms
+# and the prefill 374 -> 330 ms against 1,024-row pieces under the same cap;
+# the whole prompt in one call gave 319 ms for 1.5 GB more of temporaries, in
+# the whole-layout branch that is compiled whether or not it runs: my chip
+# runs, PR 59).
+# Every cell's bucket but the 8,192 one is below it and keeps the program it
+# had.
 WHOLE_ROWS_MAX, MLP_ROWS = 4096, 1024
 # A leaf larger than this many elements is drawn a piece at a time
 # (`_draw`): its float32 draw would not fit beside the leaves before it.
@@ -187,20 +198,36 @@ def long_prompt(x) -> bool:
         and x.shape[1] % MLP_ROWS == 0
 
 
-def rows_at_a_time(fn, *arrays):
+def rows_at_a_time(fn, *arrays, rows=None):
     """`fn(*arrays)` -> (out [1, rows, h], counted) for a `long_prompt`'s
-    arrays [1, S, ...], `MLP_ROWS` rows a call: one `lax.scan` over the
-    pieces, the outputs put together again and `counted` (a tree of each
+    arrays [1, S, ...], `rows` (`MLP_ROWS`) rows a call: one `lax.scan` over
+    the pieces, the outputs put together again and `counted` (a tree of each
     call's sums over its rows) summed."""
-    s = arrays[0].shape[1]
+    s, rows = arrays[0].shape[1], rows or MLP_ROWS
 
     def piece(_, xs):
         return None, fn(*(a[None] for a in xs))
 
     _, (out, counted) = lax.scan(piece, None, tuple(
-        a.reshape(s // MLP_ROWS, MLP_ROWS, *a.shape[2:]) for a in arrays))
+        a.reshape(s // rows, rows, *a.shape[2:]) for a in arrays))
     return out.reshape(1, s, *out.shape[3:]), \
         jax.tree.map(lambda c: c.sum(0), counted)
+
+
+def expert_rows(cfg: TransformerConfig, s: int) -> int:
+    """How many of a `long_prompt`'s `s` rows one call of `moe_dropless`
+    takes: a piece is cut by the rows it GATHERS, `MLP_ROWS` x k a call.
+    Where `held_rows_cap` answers, a call gathers its cap and so takes more
+    of the prompt's rows (at a sixteenth held a quarter of its assignments:
+    4,096 rows, and the held experts' weights are read twice an 8,192-row
+    prompt, not eight times); never more than `WHOLE_ROWS_MAX`, because the
+    whole-layout branch of its `lax.cond` is compiled, and its temporaries
+    held, whether or not it ever runs. Without a cap: `MLP_ROWS`."""
+    k, rows = cfg.experts_per_token, MLP_ROWS
+    while 2 * rows <= WHOLE_ROWS_MAX and s % (2 * rows) == 0 and (
+            held_rows_cap(cfg, 2 * rows * k) or 2 * rows * k) <= MLP_ROWS * k:
+        rows *= 2
+    return rows
 
 
 def _swiglu(y, gate, up, down):
@@ -225,8 +252,11 @@ def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer,
     weighted sum up through `latent_up` behind them (`lmoe.up`); the router
     and the shared expert read the stream itself.
     Returns (x, load [num_experts] from the real rows, the experts every row
-    chose [B*S, k], how many of the experts held here the real rows
-    reached)."""
+    chose [B*S, k], `counted`: how many of the experts held here the real
+    rows reached, int32; where `held_rows_cap` gives the layer's calls a cap
+    int32 [3], behind it the rows `moe_dropless` gathered and how many of
+    its calls took the whole layout, `transformer.layout_counted`). A
+    `long_prompt` goes through `moe_dropless` `expert_rows` rows a call."""
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
     routing = router(cfg, y.reshape(-1, y.shape[-1]), p)
     into = y
@@ -234,14 +264,20 @@ def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer,
         with jax.named_scope("lmoe.down"):
             into = jnp.einsum("bsh,hl->bsl", y,
                               p["latent_down"].astype(y.dtype))
-    if long_prompt(into):  # `moe_dropless` gathers k float32 rows a row
+
+    def experts(rows, real, w, e):
+        """One call's (out, (load, `layout_counted`))."""
+        out, load = moe_dropless(cfg, rows, p, real, layer, (w, e))
+        return out, (load, layout_counted(cfg, e))
+
+    if long_prompt(into):  # `moe_dropless` gathers float32 rows: `expert_rows`
         k = routing[0].shape[-1]
-        routed, load = rows_at_a_time(
-            lambda rows, real, w, e: moe_dropless(
-                cfg, rows, p, real, layer, (w[0], e[0])),
-            into, row_mask, *(r.reshape(1, -1, k) for r in routing))
+        routed, (load, layout) = rows_at_a_time(
+            lambda rows, real, w, e: experts(rows, real, w[0], e[0]),
+            into, row_mask, *(r.reshape(1, -1, k) for r in routing),
+            rows=expert_rows(cfg, into.shape[1]))
     else:
-        routed, load = moe_dropless(cfg, into, p, row_mask, layer, routing)
+        routed, (load, layout) = experts(into, row_mask, *routing)
     if cfg.moe_latent:
         with jax.named_scope("lmoe.up"):
             routed = jnp.einsum("bsl,lh->bsh", routed,
@@ -255,7 +291,8 @@ def sparse_mlp(cfg: TransformerConfig, x, p, row_mask, layer,
                      _relu2(y, p["shared_up"], p["shared_down"]))
     first, count = cfg.experts_held or (0, cfg.num_experts)
     reached = (load[first:first + count] > 0).sum().astype(jnp.int32)
-    return x, load.astype(jnp.int32), routing[1], reached
+    return x, load.astype(jnp.int32), routing[1], \
+        reached if layout is None else jnp.concatenate([reached[None], layout])
 
 
 def _take(tree, i):

@@ -62,7 +62,7 @@ REMAT_LADDER = (
     REMAT_ATTENTION,
     (),  # the bare checkpoint: the block's input alone
 )
-HELD_SHARE_CAPPED = 32  # `held_rows_cap`: shares of the outputs under 1 / this
+HELD_ROWS_ROOM = 4  # `held_rows_cap`: a cap is this many even shares of rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -787,20 +787,35 @@ def held_rows_cap(cfg: TransformerConfig, assignments: int):
     ones among the rest) one assignment in 48 meets a weight, and the static
     T x k layout gathered, multiplied as no group's and unsorted 48 rows for
     it: 73 ms of a 4,096-token prefill's 402 and a decode step's 384 rows, too
-    many for the grouped kernel's one tile a group (my chip runs, PR 44). The
+    many for the grouped kernel's one tile a group (my chip runs, PR 44);
+    with 16 of 256 held, 16 rows for it: 236 ms of an 8,192-row prefill's
+    468 (its grouped matmuls 105 of them) and 98 of 332 with the cap (my
+    chip runs, PR 59). The
     sorted order has the held groups' rows FIRST, so the first `cap` rows
-    hold them all unless more than `cap` assignments are held: four times
-    the even share, 64 at least, in whole tiles; a call that holds more
-    (`lax.cond` on the count) takes the whole layout, so nothing is ever
-    dropped. Only under a share of 1 / `HELD_SHARE_CAPPED`, and where it
-    halves the rows at least: the thicker shares' programs are what they
-    were."""
+    hold them all unless more than `cap` assignments are held:
+    `HELD_ROWS_ROOM` (four) times the even share, 64 at least, in whole
+    tiles; a call that holds more (`lax.cond` on the count) takes the whole
+    layout, so nothing is ever dropped. ONE rule, read off the held share
+    and the call: the cap is taken where it leaves at most one row in
+    `HELD_ROWS_ROOM` of the layout (a share of a sixteenth or thinner: the
+    `lax.cond` and the rows' sum by token, 1.2 ms a call of 8,192 rows, have
+    to be paid for) and, where the 64 rows decide, halves the rows at least.
+    A decode step's 256 -> 64 rows at a sixteenth buy nothing and cost
+    nothing (12.971 against 12.963 ms a step: the kernel's time is the
+    reached experts' bytes either way; PR 44's step gained because 384 rows
+    were more than the kernel's tile a group). An eighth (704 -> 352 rows a
+    step, its experts at 92% of their bytes already), a half and a
+    configuration that holds every expert keep the whole layout and the
+    programs they had. Pad rows choose like any other and choose ALIKE: a
+    piece of mostly padding whose common choice holds three experts here
+    passes its cap and takes the whole layout (`layout_counted` counts
+    such calls)."""
     if cfg.experts_held is None:
         return None
     count, outputs = cfg.experts_held[1], cfg.num_experts + cfg.zero_experts
-    if count * HELD_SHARE_CAPPED > outputs:
+    if count * HELD_ROWS_ROOM ** 2 > outputs:
         return None
-    cap = max(64, 4 * -(-assignments * count // outputs))
+    cap = max(64, HELD_ROWS_ROOM * -(-assignments * count // outputs))
     cap = -(-cap // 16) * 16
     return cap if 2 * cap <= assignments else None
 
@@ -816,6 +831,19 @@ def rows_gathered(cfg: TransformerConfig, experts):
     first, count = cfg.experts_held
     held = ((experts >= first) & (experts < first + count)).sum()
     return jnp.where(held <= cap, cap, n).astype(jnp.int32)
+
+
+def layout_counted(cfg: TransformerConfig, experts):
+    """What a capped call whose rows chose `experts` [T, k] has to count,
+    int32 [2]: the rows `moe_dropless` gathers for it (`rows_gathered`), and
+    1 if it held more than its cap and took the whole layout (else 0). None
+    where `held_rows_cap` gives the call no cap: its layout is static, T x k
+    rows, and the program counts nothing for it (the engine knows the rows
+    its programs run: `ContinuousBatcher._count_experts`)."""
+    if held_rows_cap(cfg, experts.size) is None:
+        return None
+    gathered = rows_gathered(cfg, experts)
+    return jnp.stack([gathered, gathered == experts.size]).astype(jnp.int32)
 
 
 @jax.named_scope("moe_router")
@@ -884,10 +912,14 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
     `load` is then [num_experts + zero_experts], the zero-compute outputs
     counted apart behind the routed ones.
 
-    Where the held share is thin (`held_rows_cap`) the rows of the first
-    `cap` sorted assignments alone are gathered and multiplied, and added to
-    their tokens; a call with more held assignments than that takes the
-    whole T*k layout: the result is the same either way.
+    Where the held share is thin (`held_rows_cap`: a sixteenth of the
+    router's outputs or less, by one rule read off the share and the call's
+    assignments) the rows of the first `cap` sorted assignments alone are
+    gathered and multiplied, and added to their tokens; a call with more
+    held assignments than that takes the whole T*k layout: the result is the
+    same either way, the same bfloat16 products and float32 weighted sum,
+    absent and padded rows adding exactly nothing. `layout_counted` says per
+    call what was gathered and whether the cap gave way.
     """
     b, s, h = y.shape
     e, k, zero = cfg.num_experts, cfg.experts_per_token, cfg.zero_experts

@@ -241,13 +241,24 @@ PROGRAM_SCOPES = {
     "moe_router": "models/transformer.py: the linear router and its top-k "
                   "(softmax); models/kimi_linear.py: the sigmoid router with "
                   "its selection bias",
-    "moe_experts": "models/transformer.py: sort, grouped matmuls, unsort",
+    "moe_experts": "models/transformer.py: sort, grouped matmuls, unsort; "
+                   "where a thin share of the experts is held "
+                   "(`held_rows_cap`) the first sorted rows alone, added to "
+                   "their tokens. How thin the layout was is two counters of "
+                   "every configuration with `experts_held`: "
+                   "`moe_rows_gathered` (the rows the programs gathered for "
+                   "the grouped matmuls, over `moe_assignments_held`, the "
+                   "real rows' that met a weight) and "
+                   "`moe_calls_whole_layout` (the calls that held more than "
+                   "their cap and took the whole layout); a capped program "
+                   "counts both, a layout without a cap is static and the "
+                   "engine reckons it from the rows its programs ran",
     "moe.zero": "models/transformer.py: the zero-compute outputs' part of an "
                 "expert layer: the sum of a token's weights on them times "
                 "its input. What they were chosen how often is a counter "
                 "(`moe_assignments_zero`, beside `moe_assignments_held` / "
-                "`_absent`; `moe_rows_gathered`: the rows the static layout "
-                "gathered; `moe_routed_most`: the most routed experts a row)",
+                "`_absent`; `moe_routed_most`: the most routed experts a "
+                "row)",
     "scmoe.dense": "models/longcat.py: a double layer's two dense SwiGLU "
                    "MLPs",
     "attend_cached": "models/decoding.py: attention over the cached rows",

@@ -262,6 +262,12 @@ def test_the_scheduler_serves_it_beside_busy_slots(params):
     assert st["moe_assignments_held"] == sum(st["moe_expert_load"][:8])
     assert 0.3 < st["moe_assignments_held"] / st["moe_assignments"] < 0.7
     assert 0 < st["moe_experts_reached"] <= 8 * CFG.sparse_layers * st["steps"]
+    # a half held: no cap, so the programs gathered a row for every
+    # assignment of every row they ran (pad rows and free slots among them)
+    assert st["moe_rows_gathered"] >= st["moe_assignments"] \
+        > st["moe_assignments_held"]
+    assert st["moe_rows_gathered"] % (4 * CFG.sparse_layers) == 0
+    assert st["moe_calls_whole_layout"] == 0
     # a latent layer's rows are read as held, a linear layer keeps none
     assert st["kv_rows_held"] % CFG.layers_of("mla") == 0
     # every slot was given back: no matrix state, no window is anyone's

@@ -418,6 +418,7 @@ def test_the_scheduler_serves_it_beside_busy_slots(params):
     # the static layout: every program gathers k rows a row it computes, a
     # pad row's and a free slot's too
     assert st["moe_rows_gathered"] >= st["moe_assignments"]  # pad rows too
+    assert st["moe_calls_whole_layout"] == 0  # 4 of 24 held: no cap stands
     programs = st["steps"] + st["admitted"]
     assert programs <= st["moe_routed_most"] <= 4 * programs
     # two cache layers a double layer

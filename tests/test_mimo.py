@@ -424,6 +424,12 @@ def test_the_scheduler_serves_it_beside_busy_slots(params):
         == st["moe_assignments"]
     assert 0.2 < st["moe_assignments_held"] / st["moe_assignments"] < 0.8
     assert 0 < st["moe_experts_reached"] <= 4 * CFG.sparse_layers * st["steps"]
+    # a half held: no cap, so the programs gathered a row for every
+    # assignment of every row they ran (pad rows and free slots among them)
+    assert st["moe_rows_gathered"] >= st["moe_assignments"] \
+        > st["moe_assignments_held"]
+    assert st["moe_rows_gathered"] % (2 * CFG.sparse_layers) == 0
+    assert st["moe_calls_whole_layout"] == 0
 
 
 def test_a_reused_slot_shows_nothing_of_its_last_occupant(params):
