@@ -81,8 +81,11 @@ def build_llm_deployment(config: LLMConfig):
             out = dict(st) if st is not None else {}
             # per jitted program, what a sparse model's grouped expert
             # matmuls were traced with, "kernel" or "ragged_dot", and what a
-            # prefill's fresh rows were attended with, "flash" or "dense"
-            for booked in ("moe_grouped_path", "prefill_attention_path"):
+            # prefill's fresh rows were attended with, "flash" or "dense", a
+            # step's held rows, "kernel" or "dense", and a state-space
+            # mixer's recurrence, "scan:kernel" / "state:kernel" or ":plain"
+            for booked in ("moe_grouped_path", "prefill_attention_path",
+                           "decode_attention_path", "ssm_path"):
                 if getattr(batcher, booked, None):
                     out[booked] = getattr(batcher, booked)
             devices = jax.devices()

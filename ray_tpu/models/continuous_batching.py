@@ -78,13 +78,14 @@ from ray_tpu.models.decoding import (
     _write_stack,
     forward_cached,
     fresh_rows_attended,
+    held_rows_attended,
     init_cache,
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import schema as spans
 from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.observability.tracing import device_span
-from ray_tpu.ops import grouped_matmul
+from ray_tpu.ops import grouped_matmul, ssd
 from ray_tpu.ops.attention import NEG_INF, decode_block
 from ray_tpu.parallel.bootstrap import FirstCall
 
@@ -214,6 +215,12 @@ class PrefillPrograms:
         # "flash" or "dense" (`decoding.attend_held`); nothing from a model
         # whose prefill has an attention of its own. `engine_stats()` too
         self.prefill_attention_path: Dict[str, str] = {}
+        # decode program -> what its held rows in a stack were attended with,
+        # "kernel" or "dense" (`decoding.attend_held`); program -> what a
+        # state-space mixer's recurrence ran as, "scan:kernel" (a prefill's),
+        # "state:kernel" (a step's) or ":plain" (`ops.ssd`). `engine_stats()`
+        self.decode_attention_path: Dict[str, str] = {}
+        self.ssm_path: Dict[str, str] = {}
 
     @staticmethod
     def _traced_with(booked: dict, program: str, paths: set) -> dict:
@@ -242,7 +249,8 @@ class PrefillPrograms:
         positions = jnp.arange(s)[None, :]
         kv_mask = jnp.arange(s)[None, :] < length
         with grouped_matmul.paths_traced() as paths, \
-                fresh_rows_attended() as attended:
+                fresh_rows_attended() as attended, \
+                ssd.paths_traced() as scanned:
             logits, row_cache, aux = forward_cached(
                 self.cfg, params, tokens, positions, row_cache, kv_mask,
                 kv_mask)
@@ -250,6 +258,8 @@ class PrefillPrograms:
             self.moe_grouped_path, f"prefill_{s}", paths)
         self.prefill_attention_path = self._traced_with(
             self.prefill_attention_path, f"prefill_{s}", attended)
+        self.ssm_path = self._traced_with(
+            self.ssm_path, f"prefill_{s}", scanned)
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
@@ -529,12 +539,17 @@ class ContinuousBatcher(PrefillPrograms):
         # what each slot holds once its token is written: the same prefix as
         # `kv_mask`, stated as a count; a slot that takes no part holds none
         rows = jnp.where(active_mask, cache.lengths + 1, 0)
-        with grouped_matmul.paths_traced() as paths:
+        with grouped_matmul.paths_traced() as paths, \
+                held_rows_attended() as attended, \
+                ssd.paths_traced() as updated:
             logits, cache, aux = forward_cached(
                 self.cfg, params, toks[:, None], positions, cache, kv_mask,
                 active_mask[:, None], access, rows)
         self.moe_grouped_path = self._traced_with(
             self.moe_grouped_path, "decode", paths)
+        self.decode_attention_path = self._traced_with(
+            self.decode_attention_path, "decode", attended)
+        self.ssm_path = self._traced_with(self.ssm_path, "decode", updated)
         with jax.named_scope("sample"):
             nxt = _sample_per_slot(
                 logits[:, 0], rng, temps, topks, active_mask)

@@ -209,6 +209,11 @@ class FreshRows(NamedTuple):
 # program's trace.
 _fresh_rows = TracedPaths("fresh_rows_attention")
 fresh_rows_attended = _fresh_rows.traced
+# What a decode step's held rows in a stack were attended with, "kernel"
+# (`ops.attention.decode_attention`) or "dense" (`_attend_cached` over the
+# layer's view): `with held_rows_attended() as seen:` around the step's trace.
+_held_rows = TracedPaths("held_rows_attention")
+held_rows_attended = _held_rows.traced
 
 
 def attend_held(q, held, q_pos, kv_len_mask, rows=None, sink=None,
@@ -250,8 +255,11 @@ def attend_held(q, held, q_pos, kv_len_mask, rows=None, sink=None,
                 return attention_ops.flash_attention(
                     q, *kv, causal=True, sm_scale=sm_scale)
     if isinstance(held, StackLayer):
-        if (rows is not None and q.shape[1] == 1
-                and attention_ops.decode_attention_takes(held.k, held.v)):
+        step = rows is not None and q.shape[1] == 1
+        kernel = step and attention_ops.decode_attention_takes(held.k, held.v)
+        if step:
+            _held_rows.book("kernel" if kernel else "dense")
+        if kernel:
             with jax.named_scope("attend_cached"):
                 return attention_ops.decode_attention(
                     q[:, 0], held.k, held.v, held.layer, rows, sink=sink,

@@ -27,7 +27,7 @@ PATTERNS = {
     "longcat": {"scmoe"},
     "kimi_linear": {"kda", "mla"},
     "laguna": {"window", "full"},
-    "nemotron_h": {"ssm", "gqa", "lmoe"},
+    "nemotron_h": {"ssm", "ssm1", "gqa", "mlp", "lmoe"},
 }
 # the one block (`transformer.py` + `decoding.py`) by the sublayers it is given
 SUBLAYERS = {"zaya": {"attention": "cca", "router": "zaya_mlp"}}

@@ -1,7 +1,10 @@
 """A pattern of layers that are ONE sublayer each: Mamba-2 state-space
 mixers, attentions without rotation and expert layers whose experts work in a
-latent (nvidia/NVIDIA-Nemotron-3-Super-120B-A12B, `model_type` nemotron_h).
-Imported only where a configuration has one (`families.PATTERNS`); the
+latent (nvidia/NVIDIA-Nemotron-3-Super-120B-A12B, `model_type` nemotron_h);
+and Mamba-1 mixers and dense SwiGLU sublayers beside the same attention
+(ai21labs/AI21-Jamba2-3B, `model_type` jamba: a published layer is two of
+these, a mixer or an attention and then an MLP, each behind a norm of its
+own). Imported only where a configuration has one (`families.PATTERNS`); the
 cache's slots, the grouped attention, the expert matmuls, the sigmoid router,
 sampling, the scheduler and the drawing of weights are the other models'
 (`decoding._write_stack` / `attend_held`, `transformer.moe_dropless`,
@@ -10,8 +13,8 @@ sampling, the scheduler and the drawing of weights are the other models'
 **Layers.** `cfg.layer_kinds` is EVERY layer's kind, in the order of the
 published `hybrid_override_pattern` (M "ssm", * "gqa", E "lmoe"): it is not
 periodic, so no lead, period or tail is read into it. Every layer is `x <- x
-+ f(RMSNorm(x))`. Parameters are stacked BY KIND (`blocks["ssm" | "gqa" |
-"sparse"]`); `forward_cached` reads the loop off the string: a run of kinds
++ f(RMSNorm(x))`. Parameters are stacked BY KIND (`blocks["ssm" | "ssm1" |
+"gqa" | "mlp" | "sparse"]`); `forward_cached` reads the loop off the string: a run of kinds
 that repeats (`runs`: the `M E` between two attentions) is ONE `lax.scan`,
 what is left is unrolled. With y the normed stream:
 
@@ -33,10 +36,30 @@ written once, in place). A call with S > 1 is a prefill FROM POSITION 0
 (`ops.ssd.ssm_chunks`, exact); the state and the window it leaves are those
 at each sequence's TRUE last position (`row_mask`): a pad position has dt 0.
 
+**An "ssm1" layer** (Mamba-1: `channels` = `ssm_heads * ssm_head_dim`
+channels, each a head of its own; a state of `ssm_state`; `ssm_dt_rank`):
+`[u ; z] = y W_in`; the same convolution, over u ALONE (B and C are not
+convolved), then SiLU: x; `[dt~ ; B~ ; C~] = x W_x` (`ssm_dt_rank`, state,
+state columns), an RMSNorm with a weight on each of the three (the family's
+own addition); `dt = softplus(dt~ W_dt + dt_bias)` a CHANNEL; `A =
+-exp(A_log)` [state, channels]; channel c's state h [state] float32: `h_t[n]
+= exp(dt_t[c] A[n, c]) h_{t-1}[n] + dt_t[c] x_t[c] B_t[n]`, `o_t[c] = sum_n
+h_t[n] C_t[n] + D[c] x_t[c]`; `o <- o * SiLU(z)`, `W_out`: no norm behind the
+gate. The decay differs by channel AND by state index, so a chunk of
+positions has no matrix form: a prefill is a true scan (`ops.ssd.
+selective_scan`, on a TPU a kernel that keeps a block of channels' states in
+fast memory) and a decode step forms the decay inside its kernel
+(`selective_state_update`). What a sequence keeps has the "ssm" layer's
+layout: `KVCache.mat` [state, channels] float32 and the window of u in
+`KVCache.conv`; a configuration has mixers of ONE of the two kinds.
+
 **A "gqa" layer**: `q = y Wq` (`heads` of `hd`), `k, v = y Wk, y Wv`
 (`kv_heads`), causal softmax of `q k^T / sqrt(hd)`, `Wo`. NO rotation and no
 other position signal. Rows in `KVCache.k` / `.v` (`_write_stack`,
 `attend_held`).
+
+**An "mlp" layer**: the dense SwiGLU `W_down(SiLU(y W_gate) * (y W_up))`,
+`mlp_hidden` wide (`pattern._swiglu`).
 
 **An "lmoe" layer**: `pattern.sparse_mlp` with the family's statement: the
 router (`kimi_linear.router`: sigmoid scores, the top k of score + a stored
@@ -60,8 +83,8 @@ from ray_tpu.models.decoding import KVCache, _write_stack, attend_held, lm_head
 from ray_tpu.models.families import Kept
 from ray_tpu.models.kimi_linear import router
 from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
-    RUN_MAX, _take, expert_names, init_params, mlp_leaves, num_params,
-    only_the_stack, param_axes, runs, sparse_mlp,
+    RUN_MAX, _swiglu, _take, expert_names, init_params, mlp_leaves,
+    num_params, only_the_stack, param_axes, runs, sparse_mlp,
 )
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm
 from ray_tpu.ops import ssd
@@ -73,8 +96,10 @@ DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
 # -- the family (`families.py`) ---------------------------------------------------
 FIELDS = frozenset({
     "layer_kinds", "lead_kind", "ssm_heads", "ssm_head_dim", "ssm_groups",
-    "ssm_state", "ssm_conv", "ssm_chunk", "moe_latent", "expert_act",
-    "router_score", "shared_expert_hidden", "experts_held"})
+    "ssm_state", "ssm_conv", "ssm_chunk", "ssm_dt_rank", "moe_latent",
+    "expert_act", "router_score", "shared_expert_hidden", "experts_held",
+    "tie_embeddings"})
+KINDS = ("ssm", "ssm1", "gqa", "mlp", "lmoe")  # the loop counts layers by kind
 
 
 def check(cfg: TransformerConfig) -> None:
@@ -89,6 +114,14 @@ def check(cfg: TransformerConfig) -> None:
             and cfg.ssm_heads % cfg.ssm_groups == 0):
         raise ValueError("an ssm layer needs ssm_heads in whole ssm_groups, "
                          "ssm_head_dim, ssm_state and ssm_conv (taps, >= 2)")
+    if cfg.layers_of("ssm1") and (cfg.layers_of("ssm") or not (
+            channels(cfg) and cfg.ssm_state and cfg.ssm_conv >= 2
+            and cfg.ssm_dt_rank)):
+        raise ValueError(
+            "an ssm1 layer needs its channels (ssm_heads x ssm_head_dim), "
+            "ssm_state, ssm_conv (taps, >= 2) and ssm_dt_rank (the low rank "
+            "its steps are projected through), and no ssm layer beside it "
+            "(a sequence's KVCache.mat has one shape)")
     if cfg.layers_of("gqa") and cfg.heads % cfg.kv_heads:
         raise ValueError("the query heads are whole groups of kv_heads")
     if cfg.layers_of("lmoe") and not cfg.num_experts:
@@ -100,22 +133,27 @@ def sparse_layers(cfg: TransformerConfig) -> int:
     return cfg.layers_of("lmoe")
 
 
+def channels(cfg: TransformerConfig) -> int:
+    """A mixer's inner width: its heads' channels, side by side."""
+    return cfg.ssm_heads * cfg.ssm_head_dim
+
+
 def conv_channels(cfg: TransformerConfig) -> int:
-    """What the convolution runs over: x~ and a B~ and a C~ a group."""
-    return cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups \
-        * cfg.ssm_state
+    """What the convolution runs over: x~ and, in an "ssm" layer, a B~ and a
+    C~ a group (an "ssm1" layer projects B and C from the convolved x)."""
+    return channels(cfg) + (0 if cfg.layers_of("ssm1") else
+                            2 * cfg.ssm_groups * cfg.ssm_state)
 
 
 def kept(cfg: TransformerConfig, max_len: int):
-    """A "gqa" layer's K/V rows in slots of `max_len`; an "ssm" layer's
-    states, float32 whatever the stream's dtype, one matrix [state, heads *
-    head_dim] a sequence, and its convolution's window, the `ssm_conv - 1`
+    """A "gqa" layer's K/V rows in slots of `max_len`; an "ssm" or "ssm1"
+    layer's states, float32 whatever the stream's dtype, one matrix [state,
+    channels] a sequence, and its convolution's window, the `ssm_conv - 1`
     last inputs flat in one row a sequence (positions, then channels)."""
-    ssm = cfg.layers_of("ssm")
+    ssm = cfg.layers_of("ssm") + cfg.layers_of("ssm1")
     return (Kept(("k", "v"), cfg.layers_of("gqa"), max_len,
                  (cfg.kv_heads, cfg.hd)),
-            Kept(("mat",), ssm, None,
-                 (cfg.ssm_state, cfg.ssm_heads * cfg.ssm_head_dim), F32),
+            Kept(("mat",), ssm, None, (cfg.ssm_state, channels(cfg)), F32),
             Kept(("conv",), ssm, None,
                  ((cfg.ssm_conv - 1) * conv_channels(cfg),)))
 
@@ -133,6 +171,8 @@ def leaves(cfg: TransformerConfig) -> dict:
     out = {("embed",): ((cfg.vocab_size, h), h, ("vocab", "embed")),
            ("unembed",): ((h, cfg.vocab_size), h, ("embed", "vocab")),
            ("ln_f",): ((h,), None, ("norm",))}
+    if cfg.tie_embeddings:  # `lm_head` reads the embedding's transpose
+        del out["unembed",]
     n, at = cfg.layers_of("ssm"), ("blocks", "ssm")
     if n:
         inner, chans = cfg.ssm_heads * cfg.ssm_head_dim, conv_channels(cfg)
@@ -152,6 +192,38 @@ def leaves(cfg: TransformerConfig) -> dict:
         out[at + ("norm",)] = ((n, inner), None, ("layers", "norm"))
         out[at + ("w_out",)] = ((n, inner, h), inner,
                                 ("layers", "heads", "embed"))
+    n, at = cfg.layers_of("ssm1"), ("blocks", "ssm1")
+    if n:
+        inner, state, rank = channels(cfg), cfg.ssm_state, cfg.ssm_dt_rank
+        out[at + ("ln",)] = ((n, h), None, ("layers", "norm"))
+        # the columns: u, then the gate z
+        out[at + ("w_in",)] = ((n, h, 2 * inner), h,
+                               ("layers", "embed", "heads"))
+        out[at + ("conv_w",)] = ((n, cfg.ssm_conv, inner), "taps",
+                                 ("layers", None, "heads"))
+        out[at + ("conv_b",)] = ((n, inner), "conv_bias", ("layers", "heads"))
+        # the columns: dt~, then B~, then C~
+        out[at + ("w_x",)] = ((n, inner, rank + 2 * state), inner,
+                              ("layers", "heads", None))
+        for name, width in (("dt_norm", rank), ("b_norm", state),
+                            ("c_norm", state)):
+            out[at + (name,)] = ((n, width), None, ("layers", "norm"))
+        out[at + ("w_dt",)] = ((n, rank, inner), rank,
+                               ("layers", None, "heads"))
+        out[at + ("dt_bias",)] = ((n, inner), "dt_bias", ("layers", "heads"))
+        # [state, channels], as the states lie: row n, lane c (`ops/ssd.py`)
+        out[at + ("a_log",)] = ((n, state, inner), "a_log_by_state",
+                                ("layers", None, "heads"))
+        out[at + ("d",)] = ((n, inner), None, ("layers", "heads"))
+        out[at + ("w_out",)] = ((n, inner, h), inner,
+                                ("layers", "heads", "embed"))
+    n, at = cfg.layers_of("mlp"), ("blocks", "mlp")
+    if n:
+        m = cfg.mlp_hidden
+        out[at + ("ln",)] = ((n, h), None, ("layers", "norm"))
+        for name in ("wi_gate", "wi_up"):
+            out[at + (name,)] = ((n, h, m), h, ("layers", "embed", "mlp"))
+        out[at + ("wo_mlp",)] = ((n, m, h), m, ("layers", "mlp", "embed"))
     n, at = cfg.layers_of("gqa"), ("blocks", "gqa")
     if n:
         out[at + ("ln",)] = ((n, h), None, ("layers", "norm"))
@@ -169,14 +241,20 @@ def leaves(cfg: TransformerConfig) -> dict:
 def special(cfg: TransformerConfig, key, shape, init: str):
     """The leaves a normal draw would leave degenerate, by the family's
     initialisers (the state-space library's, as remembered): the taps uniform
-    in +-1/2 (one over the root of `ssm_conv` 4), their bias zero; `a_log`
-    the log of a rate uniform in [1, 16); `dt_bias` the inverse softplus of
+    in +-1/2 (one over the root of `ssm_conv` 4), their bias zero in an "ssm"
+    layer and drawn as the taps in an "ssm1" layer (a convolution's own
+    default; a zero bias could not be missed); `a_log` the log of a rate
+    uniform in [1, 16), or, where the decay is by state index too, of 1 ..
+    `ssm_state` down a channel's states; `dt_bias` the inverse softplus of
     a step log-uniform in [`DT_MIN`, `DT_MAX`), floored at `DT_FLOOR`; the
     selection bias normal of 0.01 about zero (a trained one is stored; zeros
     would leave it unexercised). Decays then lie strictly between 0 and 1."""
-    if init == "taps":
-        bound = 1 / math.sqrt(shape[1])
+    if init in ("taps", "conv_bias"):
+        bound = 1 / math.sqrt(cfg.ssm_conv)
         out = jax.random.uniform(key, shape, F32, -bound, bound)
+    elif init == "a_log_by_state":
+        out = jnp.broadcast_to(jnp.log(jnp.arange(
+            1, shape[1] + 1, dtype=F32))[None, :, None], shape)
     elif init == "zeros":
         out = jnp.zeros(shape, F32)
     elif init == "a_log":
@@ -192,14 +270,35 @@ def special(cfg: TransformerConfig, key, shape, init: str):
     return out.astype(cfg.param_dtype)
 
 
-# -- the state-space layer ---------------------------------------------------------
+# -- the state-space layers --------------------------------------------------------
+
+def convolve(u, p, conv, n_real, layer):
+    """A mixer's causal depthwise convolution with its bias over u [B, S,
+    channels], then SiLU, float32; `conv` is the stack of windows, read and
+    rewritten at `layer`; `n_real` [B] how many of the S positions are each
+    sequence's. Returns (the convolved channels, conv)."""
+    b, s, chans = u.shape
+    taps = p["conv_w"].shape[0]
+    before = lax.dynamic_index_in_dim(conv, layer, keepdims=False)
+    seen = jnp.concatenate(
+        [before.reshape(b, taps - 1, chans).astype(u.dtype), u], axis=1)
+    w = p["conv_w"].astype(F32)
+    out = jax.nn.silu(sum(w[j] * seen[:, j:j + s].astype(F32)
+                          for j in range(taps)) + p["conv_b"].astype(F32))
+    # the window after this call: the inputs of each sequence's last
+    # `taps - 1` real positions; what it was for a row that has none
+    at = n_real[:, None] + jnp.arange(taps - 1)  # into `seen`
+    window = jnp.take_along_axis(seen, at[:, :, None], axis=1)
+    return out, lax.dynamic_update_index_in_dim(
+        conv, window.reshape(b, -1).astype(conv.dtype), layer, 0)
+
 
 def ssm_mixer(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
     """"ssm" layer `layer` (its index within its kind): `mat` / `conv` are
     the stacks, read and rewritten at `layer` in place. Returns (x, mat,
     conv)."""
     b, s, _ = x.shape
-    nh, hd, taps = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_conv
+    nh, hd = cfg.ssm_heads, cfg.ssm_head_dim
     groups, n = cfg.ssm_groups, cfg.ssm_state
     inner, chans = nh * hd, conv_channels(cfg)
     y = _rms_norm(x, p["ln"], cfg.norm_eps)
@@ -213,18 +312,7 @@ def ssm_mixer(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
                              + p["dt_bias"].astype(F32)) * real[..., None]
         log_a = -jnp.exp(p["a_log"].astype(F32)) * dt  # [B, S, heads]
     with jax.named_scope("ssm.conv"):
-        before = lax.dynamic_index_in_dim(conv, layer, keepdims=False)
-        seen = jnp.concatenate(
-            [before.reshape(b, taps - 1, chans).astype(u.dtype), u], axis=1)
-        w = p["conv_w"].astype(F32)
-        xbc = jax.nn.silu(sum(w[j] * seen[:, j:j + s].astype(F32)
-                              for j in range(taps)) + p["conv_b"].astype(F32))
-        # the window after this call: the inputs of each sequence's last
-        # `taps - 1` real positions; what it was for a row that has none
-        at = n_real[:, None] + jnp.arange(taps - 1)  # into `seen`
-        window = jnp.take_along_axis(seen, at[:, :, None], axis=1)
-        conv = lax.dynamic_update_index_in_dim(
-            conv, window.reshape(b, -1).astype(conv.dtype), layer, 0)
+        xbc, conv = convolve(u, p, conv, n_real, layer)
         xs = xbc[..., :inner]  # [B, S, heads * head_dim], float32
         bs = xbc[..., inner:inner + groups * n].reshape(b, s, groups, n)
         cs = xbc[..., inner + groups * n:].reshape(b, s, groups, n)
@@ -233,7 +321,9 @@ def ssm_mixer(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
         if s == 1:
             a = jnp.repeat(jnp.exp(log_a[:, 0]), hd, axis=-1)
             dtx = jnp.repeat(dt[:, 0], hd, axis=-1) * xs[:, 0]
-            if ssd.ssm_state_update_takes(mat):  # read once, written once
+            kernel = ssd.ssm_state_update_takes(mat)
+            ssd.book("state", kernel)
+            if kernel:  # read once, written once
                 mat, o = ssd.ssm_state_update(mat, layer, a, dtx, bs[:, 0],
                                               cs[:, 0])
             else:
@@ -259,6 +349,76 @@ def ssm_mixer(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
         out = jnp.einsum("bsm,mh->bsh", o.astype(x.dtype),
                          p["w_out"].astype(x.dtype))
     return x + out, mat, conv
+
+
+def ssm1_mixer(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
+    """"ssm1" layer `layer` (its index within its kind), as `ssm_mixer`:
+    `mat` / `conv` are the stacks, read and rewritten at `layer` in place.
+    The stream and the four projections in its dtype; the three small norms,
+    dt, the decay, the state and the read-out float32. Returns (x, mat,
+    conv)."""
+    b, s, _ = x.shape
+    inner, n, rank = channels(cfg), cfg.ssm_state, cfg.ssm_dt_rank
+    y = _rms_norm(x, p["ln"], cfg.norm_eps)
+    real = row_mask.astype(F32)  # [B, S]
+    n_real = row_mask.sum(1).astype(jnp.int32)
+    with jax.named_scope("ssm1.project"):
+        uz = jnp.einsum("bsh,hm->bsm", y, p["w_in"].astype(y.dtype))
+        u, z = uz[..., :inner], uz[..., inner:]
+    with jax.named_scope("ssm1.conv"):
+        xs, conv = convolve(u, p, conv, n_real, layer)  # float32
+    with jax.named_scope("ssm1.project"):
+        low = jnp.einsum("bsm,mr->bsr", xs.astype(x.dtype),
+                         p["w_x"].astype(x.dtype),
+                         preferred_element_type=F32)
+        dt_low, bs, cs = (
+            _rms_norm(v, p[name].astype(F32), cfg.norm_eps)
+            for v, name in ((low[..., :rank], "dt_norm"),
+                            (low[..., rank:rank + n], "b_norm"),
+                            (low[..., rank + n:], "c_norm")))
+        # a position that is no sequence's has dt 0: decay 1, nothing added
+        dt = jax.nn.softplus(
+            jnp.einsum("bsr,rm->bsm", dt_low.astype(x.dtype),
+                       p["w_dt"].astype(x.dtype), preferred_element_type=F32)
+            + p["dt_bias"].astype(F32)) * real[..., None]
+    # the state's read and write are the sublayer's, under its scope
+    with jax.named_scope("ssm1.state" if s == 1 else "ssm1.prefill_scan"):
+        a = -jnp.exp(p["a_log"].astype(F32))  # [state, channels]
+        dtx = dt * xs
+        if s == 1:
+            kernel = ssd.ssm_state_update_takes(mat)
+            ssd.book("state", kernel)
+            if kernel:  # read once, written once, the decay formed inside
+                mat, o = ssd.selective_state_update(
+                    mat, layer, a, dt[:, 0], dtx[:, 0], bs[:, 0], cs[:, 0])
+            else:
+                after, o = ssd.selective_step(
+                    lax.dynamic_index_in_dim(mat, layer, keepdims=False), a,
+                    dt[:, 0], dtx[:, 0], bs[:, 0], cs[:, 0])
+                mat = lax.dynamic_update_index_in_dim(mat, after, layer, 0)
+            o = o[:, None]
+        else:
+            before = lax.dynamic_index_in_dim(mat, layer, keepdims=False)
+            kernel = ssd.selective_scan_takes(before, s)
+            ssd.book("scan", kernel)
+            after, o = ssd.selective_scan(before, a, dt, dtx, bs, cs) \
+                if kernel else ssd.selective_scan_plain(
+                    before, a, dt, dtx, bs, cs, cfg.ssm_chunk)
+            mat = lax.dynamic_update_index_in_dim(mat, after, layer, 0)
+        o = o + p["d"].astype(F32) * xs
+    with jax.named_scope("ssm1.gate"):
+        o = o * jax.nn.silu(z.astype(F32))
+    with jax.named_scope("ssm1.out"):
+        out = jnp.einsum("bsm,mh->bsh", o.astype(x.dtype),
+                         p["w_out"].astype(x.dtype))
+    return x + out, mat, conv
+
+
+def dense_mlp(cfg: TransformerConfig, x, p):
+    """"mlp" layer: the dense SwiGLU on the normed stream."""
+    with jax.named_scope("mlp"):
+        return x + _swiglu(_rms_norm(x, p["ln"], cfg.norm_eps), p["wi_gate"],
+                           p["wi_up"], p["wo_mlp"])
 
 
 # -- the attention layer -----------------------------------------------------------
@@ -307,9 +467,12 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
         for kind in unit:
             i = at[kind]
             at = dict(at, **{kind: i + 1})
-            if kind == "ssm":
-                x, mat, conv = ssm_mixer(cfg, x, _take(blocks["ssm"], i), mat,
-                                         conv, row_mask, i)
+            if kind in ("ssm", "ssm1"):
+                mixer = ssm_mixer if kind == "ssm" else ssm1_mixer
+                x, mat, conv = mixer(cfg, x, _take(blocks[kind], i), mat,
+                                     conv, row_mask, i)
+            elif kind == "mlp":
+                x = dense_mlp(cfg, x, _take(blocks["mlp"], i))
             elif kind == "gqa":
                 x, k, v = attention(cfg, x, _take(blocks["gqa"], i),
                                     positions, k, v, kv_len_mask, i, rows)
@@ -321,7 +484,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
         return (x, k, v, mat, conv), at, counted
 
     carry = (x, cache.k, cache.v, cache.mat, cache.conv)
-    at = dict.fromkeys(("ssm", "gqa", "lmoe"), 0)
+    at = dict.fromkeys(KINDS, 0)
     loads, choices, reached = [], [], []
     for unit, repeats in runs(cfg.layer_kinds):
         if repeats == 1:

@@ -210,12 +210,17 @@ class TransformerConfig:
     # recurrence `ssm_chunk` positions at a time. "gqa": grouped attention
     # over K/V rows, no rotation. "lmoe": an expert layer whose experts work
     # on a `moe_latent`-wide projection of the stream (0: on the stream).
+    # "ssm1": a Mamba-1 mixer of `ssm_heads * ssm_head_dim` channels whose
+    # decay differs by channel and by state index, its steps projected
+    # through `ssm_dt_rank`; it keeps what an "ssm" layer keeps. "mlp": a
+    # dense SwiGLU of `mlp_hidden`.
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_groups: int = 0
     ssm_state: int = 0
     ssm_conv: int = 0
     ssm_chunk: int = 128
+    ssm_dt_rank: int = 0
     moe_latent: int = 0
     # What one expert of `moe_dropless` is (and a pattern's shared expert):
     # "swiglu", three matrices, `(silu(x Wg) * (x Wu)) Wd`; or "relu2", two,
@@ -539,6 +544,21 @@ PRESETS: Dict[str, TransformerConfig] = {
         ssm_chunk=8, moe_latent=32, expert_act="relu2",
         router_score="sigmoid", routed_scale=5.0, shared_expert_hidden=96,
         experts_held=(0, 8), dtype=jnp.float32,
+    ),
+    # ai21labs/AI21-Jamba2-3B's layers at debug widths (models/nemotron_h.py):
+    # two periods of four published layers with the attention third, each a
+    # mixer or an attention and then a dense MLP = 16 sublayers: 6 Mamba-1
+    # mixers (256 channels, a state of 16, 4 taps, steps through a rank of 10:
+    # no multiple of 8), 2 attentions of 4 heads on ONE KV head without
+    # rotation, 8 SwiGLU MLPs; the head tied to the embedding. The published
+    # widths are the benchmark's to build (benchmarks/runners/serve_jamba.py)
+    "jamba_debug": dict(
+        vocab_size=512, hidden=128, mlp_hidden=192, layers=16, heads=4,
+        kv_heads=1, head_dim=32, max_seq=128, remat=False, norm_eps=1e-6,
+        layer_kinds=("ssm1", "mlp", "ssm1", "mlp", "gqa", "mlp", "ssm1",
+                     "mlp") * 2, lead_kind="", tie_embeddings=True,
+        ssm_heads=256, ssm_head_dim=1, ssm_state=16, ssm_conv=4, ssm_chunk=8,
+        ssm_dt_rank=10, dtype=jnp.float32,
     ),
 }
 

@@ -229,6 +229,24 @@ PROGRAM_SCOPES = {
                         "chunk of positions at a time",
     "ssm.norm": "models/nemotron_h.py: the gate, then the norm a group",
     "ssm.out": "models/nemotron_h.py: the output projection",
+    "ssm1.project": "models/nemotron_h.py: a Mamba-1 mixer's projections: "
+                    "W_in (u and the gate), W_x (the low-rank steps, B and "
+                    "C) with the three small norms, W_dt with its bias and "
+                    "the softplus",
+    "ssm1.conv": "models/nemotron_h.py: the depthwise convolution with its "
+                 "bias over u alone, its window's read and write, SiLU",
+    "ssm1.state": "models/nemotron_h.py: a decode step's state update for a "
+                  "decay by channel and state index: every sequence's states "
+                  "decayed (the decay formed inside the kernel), one "
+                  "rank-one term added and read out, D x (read once, "
+                  "written once; `state_bytes_rewritten` counts the bytes; "
+                  "`engine_stats()['ssm_path']` says kernel or plain)",
+    "ssm1.prefill_scan": "models/nemotron_h.py: a prefill's selective scan "
+                         "(no matrix form: a kernel that keeps a block of "
+                         "channels' states in fast memory over the "
+                         "positions), the rates, dt x, D x",
+    "ssm1.gate": "models/nemotron_h.py: the read-out times SiLU of the gate",
+    "ssm1.out": "models/nemotron_h.py: the output projection W_out",
     "attn.gqa": "models/nemotron_h.py: an attention layer's projections, "
                 "row write, attention over its slots (attend_cached inside "
                 "it), wo; no rotation",

@@ -727,7 +727,12 @@ def decode_attention_takes(stack, v_stack=None) -> bool:
     are cached in pieces of the values' width, `key_pieces`), slots of whole
     sublanes, and 2- or 4-byte values whose KV heads fill whole 32-bit words
     and whole tiles of 1, 2, 4 or 8 words (XLA then keeps a position's heads
-    unpadded, and so does the kernel)."""
+    unpadded, and so does the kernel), or ONE 2-byte KV head with values as
+    wide (`_one_head`: XLA keeps such a stack as [N, B, T, D], positions
+    down the sublanes, and the kernel reads its rows as they lie)."""
+    if v_stack is not None and _one_head(stack, v_stack):
+        return _on_tpu() and stack.shape[2] % 16 == 0 \
+            and stack.shape[-1] % 128 == 0
     for one in (stack,) if v_stack is None else (stack, v_stack):
         _, _, t, kvh, d = one.shape
         itemsize = one.dtype.itemsize
@@ -743,6 +748,18 @@ def decode_attention_takes(stack, v_stack=None) -> bool:
         stack.shape[-1] == v_stack.shape[-1] == 128
         and stack.shape[3] % v_stack.shape[3] == 0
         and stack.dtype == v_stack.dtype))
+
+
+def _one_head(stack, v_stack) -> bool:
+    """Whether the stacks [N, B, T, kvH, D] are ONE 2-byte KV head's, keys
+    and values alike: half a 32-bit word a position, which no strided read
+    of words takes apart. The chip keeps the unit dimension outside the
+    positions (a position is a D-wide row, two to a sublane: `{4,2,3,1,0:
+    T(8,128)(2,1)}` for bfloat16 [2, 16, 12288, 1, 128], the compiler's own
+    choice for a described v5e), so [N, B, T, D] is the same bytes and the
+    kernel copies blocks of whole rows."""
+    return (stack.shape == v_stack.shape and stack.dtype == v_stack.dtype
+            and stack.shape[3] == 1 and stack.dtype.itemsize == 2)
 
 
 def key_pieces(k, width: int):
@@ -783,10 +800,13 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
         rest[-4:]
     n_slots, kvh, r, d = q_ref.shape
     dv = o_ref.shape[-1]
-    chunks = kbuf.shape[2] // kvh
+    # ONE 2-byte KV head (`_one_head`): the stacks are [N, B, T, D], a block
+    # [block, D] is the head's rows as they lie
+    flat = kbuf.ndim == 3
+    chunks = 1 if flat else kbuf.shape[2] // kvh
     layer = layer_ref[0]
     # 2-byte rows lie in pairs of KV heads, one 32-bit word a pair and lane
-    packing = 4 // kbuf.dtype.itemsize
+    packing = 1 if flat else 4 // kbuf.dtype.itemsize
 
     def copies(b, j, buf):
         at = pl.ds(pl.multiple_of(j * block, block), block)
@@ -811,12 +831,15 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
         buffer [block, kvH, D]: a strided read of the heads' sublanes, and
         for 2-byte rows the two halves of each word. Rows outside `keep`
         [block, D] come out as zeros whatever the buffer holds there."""
-        heads = ref.shape[1]  # a position's rows: KV heads, or their pieces
-        flat = ref.reshape(block * heads, ref.shape[-1])
-        if packing == 1:
-            rows = flat[pl.ds(g0, block, stride=heads), :]
+        if flat:
+            rows = ref[...]
             return [rows if keep is None else jnp.where(keep, rows, 0)]
-        words = flat.bitcast(jnp.uint32)[
+        heads = ref.shape[1]  # a position's rows: KV heads, or their pieces
+        joined = ref.reshape(block * heads, ref.shape[-1])
+        if packing == 1:
+            rows = joined[pl.ds(g0, block, stride=heads), :]
+            return [rows if keep is None else jnp.where(keep, rows, 0)]
+        words = joined.bitcast(jnp.uint32)[
             pl.ds(g0 // 2, block, stride=heads // 2), :]
         if keep is not None:
             words = jnp.where(keep, words, jnp.uint32(0))
@@ -926,6 +949,8 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
     rep = h // kvh
     itemsize = k_stack.dtype.itemsize
     block = block or decode_block(t, kvh * max(d, dv) * itemsize)
+    if _one_head(k_stack, v_stack):  # its rows as they lie: the same bytes
+        k_stack, v_stack = (s.reshape(n, b, t, dv) for s in (k_stack, v_stack))
     tile = 8 * (4 // itemsize)  # rows of a tile of q's dtype
     r = -(-rep // tile) * tile
     q4 = q.astype(k_stack.dtype).reshape(b, kvh, rep, d)
@@ -953,7 +978,7 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[
                 pltpu.VMEM((2, block, *k_stack.shape[3:]), k_stack.dtype),
-                pltpu.VMEM((2, block, kvh, dv), v_stack.dtype),
+                pltpu.VMEM((2, block, *v_stack.shape[3:]), v_stack.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),

@@ -472,6 +472,7 @@ KIMI_LINEAR = "kimi-linear-48b-a3b-serve-ep16"
 LONGCAT = "longcat-flash-serve-ep32-d4"
 NEMOTRON_H = "nemotron-3-super-serve-ep8-d22"
 MIMO = "mimo-v2-flash-serve-ep16-d11"
+JAMBA = "jamba2-3b-serve-whole"
 PATTERN_CONFIGS = {
     # MiMo-V2-Flash's published widths, layers 0-10 of 48 (full, 4 window,
     # full, 5 window), one chip's share of a layer that sixteen hold: 16 of
@@ -518,6 +519,16 @@ PATTERN_CONFIGS = {
         ssm_heads=128, ssm_head_dim=64, ssm_groups=8, ssm_state=128,
         ssm_chunk=128, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
         slots=32, max_len=2048, bucket=512),
+    # AI21-Jamba2-3B whole: its published widths, all 28 layers (a mixer or
+    # an attention and then an MLP: 56 sublayers), the whole vocabulary, the
+    # head tied; 16 slots of 12288 positions, the 8192 bucket
+    JAMBA: dict(
+        name="jamba_debug", vocab_size=65536, hidden=2560, mlp_hidden=8192,
+        layers=56, heads=20, kv_heads=1, head_dim=128, max_seq=262144,
+        layer_kinds=(("ssm1", "mlp") * 7 + ("gqa", "mlp")
+                     + ("ssm1", "mlp") * 6) * 2,
+        ssm_heads=5120, ssm_dt_rank=160, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, slots=16, max_len=12288, bucket=8192),
 }
 
 
@@ -575,6 +586,7 @@ def serve_programs(topo):
         return cfg, prefill, decode, cache
 
     compiled.grouped_paths = grouped_paths = {}
+    compiled.engine = engine
     compiled.prefill_at = prefill_at
     # what the engine's `prefill_attention_path` says of the prefills so far
     compiled.attention_paths = lambda name: engine(name)[0].prefill_attention_path
@@ -1014,6 +1026,103 @@ def test_state_space_serve_programs_compile_and_fit(serve_programs):
     assert "ssm.prefill_scan" in pscopes and "ssm.state" not in pscopes
     # a chunk's masked decay matrix [128, 128] a head inside the scan
     assert re.search(r"f32\[128,128,8,16\]", prefill.as_text())
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    assert _entry_parameters(decode) == leaves + 5 + 5  # k, v, lengths + 2
+    from ray_tpu.observability import schema
+
+    assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+def test_mamba1_serve_programs_compile_and_take_their_kernels(serve_programs):
+    """The `serve-mamba1-mqa-docreason-8k-in-long-out` deployment (Jamba2-3B
+    whole: 28 layers, the whole vocabulary, 16 slots x 12288): all THREE
+    kernels take the benchmark configuration's shapes, so that a later change
+    cannot send this cell to a fallback unseen: the decode step rewrites the
+    26 mixers' states with the Mosaic call that is given the stack, under
+    `ssm1.state`, one a scanned body, and reads the ONE bfloat16 KV head's rows
+    with `decode_attention`; states, windows and rows are aliased in to out
+    and no temporary of a layer's states' size is held; the prefill of the
+    8192 bucket scans with `selective_scan` under `ssm1.prefill_scan`, holds
+    no [8192, 16, 5120] array and no [*, 8192, 8192] logits (the flash
+    forward, 20 heads on 1), and both fit the chip beside each other."""
+    from benchmarks import harness, scope_ops
+    from ray_tpu.models import nemotron_h
+    from ray_tpu.ops import attention as A, ssd
+
+    cfg, prefill, decode, cache = serve_programs(JAMBA)
+    slots = cache.lengths.shape[0]
+    assert [cfg.kinds.count(k) for k in ("ssm1", "gqa", "mlp")] == [26, 2, 28]
+    assert [(len(u), r) for u, r in nemotron_h.runs(cfg.kinds)] == [
+        (2, 7), (1, 1), (2, 13), (1, 1), (1, 1), (2, 6), (1, 1)]
+    assert cfg.num_params() == 3_029_337_472 and cfg.sparse_layers == 0
+    assert cache.k.shape == (2, slots, 12288, 1, 128)
+    assert cache.k.dtype == jnp.bfloat16 and cache.state is None
+    assert cache.mat.shape == (26, slots, 16, 5120)
+    assert cache.mat.dtype == jnp.float32
+    assert cache.conv.shape == (26, slots, 3 * 5120)
+    # each kernel's `takes`, on the configuration's own shapes
+    assert A.decode_attention_takes(cache.k, cache.v)
+    assert ssd.ssm_state_update_takes(cache.mat)
+    assert ssd.selective_scan_takes(
+        jax.ShapeDtypeStruct((1, 16, 5120), jnp.float32), 8192)
+    row = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16)
+    assert A.flash_attention_takes(
+        jax.ShapeDtypeStruct((1, 8192, 20, 128), jnp.bfloat16), row, row)
+    batcher_paths = serve_programs.engine(JAMBA)[0]
+    assert batcher_paths.ssm_path == {"prefill_8192": "scan:kernel",
+                                      "decode": "state:kernel"}
+    assert batcher_paths.decode_attention_path == {"decode": "kernel"}
+    assert serve_programs.attention_paths(JAMBA) == {"prefill_8192": "flash"}
+    kept = _arg_bytes((cache.k, cache.v, cache.mat, cache.conv))
+    assert round(kept / 1e9, 2) == 0.35
+    for name, program in (("prefill[8192]", prefill),
+                          (f"decode[{slots}x12288]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    assert m.temp_size_in_bytes < _arg_bytes(cache.mat) / 4
+    assert _total_bytes(decode) < 7e9
+    assert _total_bytes(prefill) + kept < 9e9  # beside the engine's cache
+    text, ptext = decode.as_text(), prefill.as_text()
+    for op_name, dtype, dims, op in _results(ptext):
+        # no [heads, S, S] logits (an MLP's [S, 8192] is no such array)
+        assert not (dims.count(8192) >= 2 and len(dims) > 2), (
+            op_name, dims, op)
+        assert not (8192 in dims and 16 in dims and 5120 in dims), (
+            op_name, dims, op)  # no [S, state, channels] array
+
+    def mosaic(of, name):
+        return {scope_ops._INSTRUCTION.match(line)[1]
+                for line in of.splitlines()
+                if "tpu_custom_call" in line and "%" + name in line}
+
+    runner = harness.load_module("runners", "serve_jamba")
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    # (a step's gate is one multiply, fused into the output projection's)
+    assert set(scopes) >= set(runner.SCOPES) - {"ssm1.prefill_scan",
+                                                "ssm1.gate"}
+    updates = mosaic(text, "selective_state_update")
+    assert len(updates) == 3  # the three scanned bodies: 7 + 13 + 6 mixers
+    assert updates <= set(scopes["ssm1.state"])
+    attends = mosaic(text, "decode_attention")
+    assert len(attends) == 2 and attends <= set(scopes["attn.gqa"])
+    pscopes = scope_ops.op_scopes(ptext, runner.SCOPES)
+    scans = mosaic(ptext, "selective_scan")
+    # (the compiler fuses the call's producers, B and C spread over the
+    # lanes and the state's read, INTO it: the program's operation is that
+    # fusion, under the kernel's name and scope, the call inside it)
+    assert len(scans) == 3 and sum(op.startswith("selective_scan") for op in
+                                   pscopes["ssm1.prefill_scan"]) == 3
+    assert "ssm1.state" not in pscopes
+    flash = mosaic(ptext, "flash_attention_fwd")
+    assert len(flash) == 2 and flash <= set(pscopes["attn.gqa"])
     leaves = len(jax.tree.leaves(jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.key(0)))))
     assert _entry_parameters(decode) == leaves + 5 + 5  # k, v, lengths + 2

@@ -22,7 +22,7 @@ PRESETS = {
     "laguna": ("laguna_debug", "mimo_v2_debug"),
     "kimi_linear": ("kimi_linear_debug",),
     "longcat": ("longcat_debug",),
-    "nemotron_h": ("nemotron_h_debug",),
+    "nemotron_h": ("nemotron_h_debug", "jamba_debug"),
 }
 MODULES = (families.ONE_BLOCK, *families.SUBLAYERS, *families.PATTERNS)
 # the fields `families.of` chooses by: set on another family's configuration
@@ -36,7 +36,8 @@ AWAY = dict(
     experts_held=(0, 2), kda_conv=4, mla_latent=32, mla_rope_dim=8,
     mla_q_rank=8, mla_rotate=True, mla_scales=(2.0, 2.0),
     router_score="sigmoid", zero_experts=4, ssm_heads=4, ssm_head_dim=16,
-    ssm_groups=4, ssm_state=8, ssm_conv=3, ssm_chunk=64, moe_latent=16,
+    ssm_groups=4, ssm_state=8, ssm_conv=3, ssm_chunk=64, ssm_dt_rank=4,
+    moe_latent=16,
     expert_act="relu2", window_kv_heads=2, value_dim=8, window_sink=True,
     value_scale=0.5, window_partial_rotary=0.5)
 BATCH, MAX_LEN = 3, 32

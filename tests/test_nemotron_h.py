@@ -431,7 +431,7 @@ def test_the_reference_follows_a_tie_and_refuses_the_rest(params):
     (dict(num_experts=0, experts_held=None), "needs num_experts"),
     (dict(window=8), "window: no field of .*nemotron_h"),
     (dict(kda_conv=4), "kda_conv: no field of .*nemotron_h"),
-    (dict(tie_embeddings=True), "tie_embeddings: no field of"),
+    (dict(qk_norm=True), "qk_norm: no field of"),  # (tied: the family's)
     (dict(expert_act="gelu"), "unknown expert_act"),
     (dict(experts_held=(12, 8)), "no share of num_experts"),
 ])
