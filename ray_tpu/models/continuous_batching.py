@@ -212,8 +212,10 @@ class PrefillPrograms:
         # programs have none and book nothing. `engine_stats()` carries it
         self.moe_grouped_path: Dict[str, str] = {}
         # prefill program -> what its fresh rows were attended with,
-        # "flash" or "dense" (`decoding.attend_held`); nothing from a model
-        # whose prefill has an attention of its own. `engine_stats()` too
+        # "flash" or "dense" (`decoding.attend_fresh`: `attend_held`'s
+        # prefills and a latent prefill's expanded rows, `kimi_linear.
+        # mla_attention`); nothing from a model without attention over
+        # fresh rows. `engine_stats()` too
         self.prefill_attention_path: Dict[str, str] = {}
         # decode program -> what its held rows in a stack were attended with,
         # "kernel" or "dense" (`decoding.attend_held`); program -> what a
@@ -226,7 +228,7 @@ class PrefillPrograms:
     def _traced_with(booked: dict, program: str, paths: set) -> dict:
         """`booked` with, for `program`, the implementation(s) an op chose
         by what it saw of its call while the program was traced
-        (`ops.grouped_matmul` for its grouped matmuls, `attend_held` for a
+        (`ops.grouped_matmul` for its grouped matmuls, `attend_fresh` for a
         prefill's fresh rows): a program that fell back says so in one look.
         A new dict, not an update in place: a reader may be copying the old
         one."""

@@ -205,7 +205,7 @@ class FreshRows(NamedTuple):
 
 
 # What the fresh rows of a prefill were attended with, "flash" or "dense"
-# (`attend_held`): `with fresh_rows_attended() as seen:` around a prefill
+# (`attend_fresh`): `with fresh_rows_attended() as seen:` around a prefill
 # program's trace.
 _fresh_rows = TracedPaths("fresh_rows_attention")
 fresh_rows_attended = _fresh_rows.traced
@@ -216,19 +216,38 @@ _held_rows = TracedPaths("held_rows_attention")
 held_rows_attended = _held_rows.traced
 
 
+def attend_fresh(q, fresh: FreshRows, sink=None, sm_scale=None):
+    """A prefill from position 0 through the flash forward kernel where it
+    takes the shape (`flash_attention_takes`), else None: the caller then
+    runs the spelling it has (`_attend_cached`; a latent prefill's
+    `kimi_linear._attend_expanded`). ONE rule and one booking for every
+    prefill's fresh rows: which of the two a program was traced with is
+    booked here, "flash" or "dense" (`fresh_rows_attended`). No caller has
+    a sink over fresh rows, and the kernel has none."""
+    flash = sink is None and attention_ops.flash_attention_takes(q, *fresh)
+    _fresh_rows.book("flash" if flash else "dense")
+    if not flash:
+        return None
+    # values of a width of their own: the forward reads a KV head where it
+    # lies, by index; else the heads are repeated
+    kv = fresh if fresh.k.shape[-1] != fresh.v.shape[-1] else \
+        attention_ops.gqa_expand(*fresh, q.shape[2])
+    return attention_ops.flash_attention(q, *kv, causal=True,
+                                         sm_scale=sm_scale)
+
+
 def attend_held(q, held, q_pos, kv_len_mask, rows=None, sink=None,
                 sm_scale=None):
     """q [B, S, H, D] against what a cache access returned as `held`: a
     dense (k, v) [B, T, kvH, D] pair, a `StackLayer` or `FreshRows`
-    (`sink`, `sm_scale`: `_attend_cached`'s; no caller has a sink over fresh
-    rows, and the flash kernel has none). One
+    (`sink`, `sm_scale`: `_attend_cached`'s). One
     token a sequence (S == 1) over a stack whose caller states `rows` [B],
     how many rows each slot holds (a prefix; 0: the slot takes no part),
     goes to the kernel that reads those rows in the stack and nothing else
     (`ops.attention.decode_attention`; on a TPU, as `flash_attention`).
 
     A prefill from position 0 (`FreshRows`) goes to `flash_attention` where
-    its forward kernel takes the shape (`flash_attention_takes`): the causal
+    its forward kernel takes the shape (`attend_fresh`): the causal
     rule is the whole mask there. A real query at position i sees keys 0..i,
     all real, so `kv_len_mask` has nothing left to hide; a pad row sees other
     keys than under the mask, and is as unused as before: its K/V lie past
@@ -236,24 +255,16 @@ def attend_held(q, held, q_pos, kv_len_mask, rows=None, sink=None,
     out of a state. The kernel multiplies the operands as they arrive and
     casts the probabilities to their dtype before the weighted sum, which at
     default precision is what the MXU makes of `_attend_cached`'s float32
-    ones. Which of the two a program was traced with is booked
-    (`fresh_rows_attended`).
+    ones.
 
     Everything else is `_attend_cached` over the dense rows under
     `kv_len_mask` and the causal rule. What decides is in the arguments:
     no option, no model's name."""
     if isinstance(held, FreshRows):
-        flash = sink is None and attention_ops.flash_attention_takes(
-            q, *held)
-        _fresh_rows.book("flash" if flash else "dense")
-        if flash:
-            with jax.named_scope("attend_cached"):
-                # values of a width of their own: the forward reads a KV
-                # head where it lies, by index; else the heads are repeated
-                kv = held if held.k.shape[-1] != held.v.shape[-1] else \
-                    attention_ops.gqa_expand(*held, q.shape[2])
-                return attention_ops.flash_attention(
-                    q, *kv, causal=True, sm_scale=sm_scale)
+        with jax.named_scope("attend_cached"):
+            out = attend_fresh(q, held, sink, sm_scale)
+        if out is not None:
+            return out
     if isinstance(held, StackLayer):
         step = rows is not None and q.shape[1] == 1
         kernel = step and attention_ops.decode_attention_takes(held.k, held.v)

@@ -43,8 +43,12 @@ Wkvb_i[:, hd:]`: each held row read once, where it lies
 the sublayer as DeepSeek-V3's family publishes it, by three fields that are
 off in this model (`models/longcat.py` sets them): a low-rank query
 (`mla_q_rank`), `q_r` and the shared `k_r` rotated by position
-(`mla_rotate`), two factors (`mla_scales`); and a prefill whose float32
-logits [heads, S, S] would pass `PREFILL_LOGITS_MAX` attends a block of
+(`mla_rotate`), two factors (`mla_scales`). A prefill's expanded rows go to
+the flash forward kernel where it takes their shape (`decoding.
+attend_fresh`: a head's `[q_n ; q_r]` against `[k_n ; k_r]` in whole lanes,
+values of `hd`; on a TPU, past `ops.attention.DENSE_SCORES_BYTES`), no
+logits through HBM; elsewhere `_attend_expanded`, which past
+`PREFILL_LOGITS_MAX` of float32 logits [heads, S, S] attends a block of
 queries at a time.
 
 **Experts.** `router`: sigmoid scores over all `num_experts` in float32, the
@@ -62,7 +66,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.decoding import KVCache, _write_stack, lm_head
+from ray_tpu.models.decoding import (
+    FreshRows, KVCache, _write_stack, attend_fresh, lm_head,
+)
 from ray_tpu.models.families import Kept
 from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
     EXPERT_LEAVES, _swiglu, _take, init_params, mlp_leaves, num_params,
@@ -74,9 +80,12 @@ from ray_tpu.ops import delta_rule
 from ray_tpu.ops.attention import NEG_INF
 
 KDA_CHUNK = 32  # positions a chunk of the prefill's scan (`kda_chunks`)
-# A latent prefill's float32 logits [heads, S, S] up to this many bytes are
-# one array; past it the queries go `PREFILL_QUERY_BLOCK` at a time
-# (`_attend_expanded`). 32 heads x 2,048^2 are 0.5 GiB, 64 x 4,096^2 are 4.
+# The rule of the shapes the flash forward does not take (off the chip,
+# heads or a length not in whole 128s, more keys than its VMEM holds:
+# `ops.attention.flash_attention_takes`; both cells' buckets go to it since
+# PR 61). There a latent prefill's float32 logits [heads, S, S] up to this
+# many bytes are one array; past it the queries go `PREFILL_QUERY_BLOCK` at a
+# time (`_attend_expanded`). 32 heads x 2,048^2 are 0.5 GiB, 64 x 4,096^2 4.
 PREFILL_LOGITS_MAX = 1 << 30
 PREFILL_QUERY_BLOCK = 512
 F32 = jnp.float32
@@ -425,7 +434,8 @@ def rotate_interleaved(x, positions, theta: float):
 
 def _attend_expanded(q_n, q_r, k_n, k_r, v, positions, row_mask, sm_scale,
                      block=None):
-    """A prefill's causal attention over its own fresh rows, expanded: q_n
+    """A prefill's causal attention over its own fresh rows, expanded, where
+    the flash forward does not take them (`mla_attention`): q_n
     [B, S, H, D] and q_r [B, S, H, R] against k_n [B, S, H, D] and the one
     shared k_r [B, S, R], values v [B, S, H, Dv]; float32 logits and softmax.
     `block`: queries go that many at a time, each block against the keys up
@@ -467,9 +477,20 @@ def mla_attention(cfg: TransformerConfig, x, p, positions, latent,
     step rotates its own query alone); `mla_scales` = (on the query: folded
     into the softmax's scale, which both of its parts share; on the normed
     latent: applied in float32 before the row is rounded, so on the keys'
-    unrotated part and on the values, not on `k_r`). A prefill whose float32
-    logits [heads, S, S] would pass `PREFILL_LOGITS_MAX` attends
-    `PREFILL_QUERY_BLOCK` queries at a time (`_attend_expanded`)."""
+    unrotated part and on the values, not on `k_r`).
+
+    A prefill from position 0 attends its own fresh rows, expanded, with the
+    flash forward kernel where `decoding.attend_fresh` takes them (by the
+    call's shape, no option and no model's name: LongCat's 4,096 bucket and
+    this model's 2,048 on the chip; the causal rule is the whole mask there,
+    for `attend_held`'s reasons: a real query sees real keys alone, a pad
+    row's result is nobody's), and with `_attend_expanded` elsewhere,
+    `PREFILL_QUERY_BLOCK` queries at a time where the float32 logits [heads,
+    S, S] would pass `PREFILL_LOGITS_MAX`. Both multiply bfloat16 operands
+    into float32 scores, take the softmax in float32 and cast the
+    probabilities to the values' dtype before the weighted sum; which one a
+    program was traced with is booked (`engine_stats()
+    ["prefill_attention_path"]`)."""
     b, s, _ = x.shape
     nh, d, lat, rope = cfg.heads, cfg.hd, cfg.mla_latent, cfg.mla_rope_dim
     scale_q, scale_kv = cfg.mla_scales
@@ -529,12 +550,22 @@ def mla_attention(cfg: TransformerConfig, x, p, positions, latent,
         with jax.named_scope("mla.attend"):
             expanded = jnp.einsum("bsc,cnd->bsnd", row[..., :lat].astype(
                 y.dtype), wkv_b)
-            o = _attend_expanded(
-                q_n, q_r, expanded[..., :d],
-                row[..., lat:lat + rope].astype(y.dtype), expanded[..., d:],
-                positions, row_mask, sm_scale,
-                PREFILL_QUERY_BLOCK if nh * s * s * 4 > PREFILL_LOGITS_MAX
-                else None)
+            k_n, v = expanded[..., :d], expanded[..., d:]
+            k_r = row[..., lat:lat + rope].astype(y.dtype)
+            # the expanded rows as the flash forward reads them: a head's
+            # query [q_n ; q_r] and key [k_n ; the shared k_r] in whole
+            # lanes, zeros behind both (they add nothing to a score)
+            zeros = jnp.zeros((b, s, nh, -(d + rope) % 128), y.dtype)
+            o = attend_fresh(
+                jnp.concatenate([q_n, q_r, zeros], -1),
+                FreshRows(jnp.concatenate([k_n, jnp.broadcast_to(
+                    k_r[:, :, None], (b, s, nh, rope)), zeros], -1), v),
+                sm_scale=sm_scale)
+            if o is None:  # a shape the kernel does not take
+                o = _attend_expanded(
+                    q_n, q_r, k_n, k_r, v, positions, row_mask, sm_scale,
+                    PREFILL_QUERY_BLOCK
+                    if nh * s * s * 4 > PREFILL_LOGITS_MAX else None)
     with jax.named_scope("mla.out"):
         out = _from_heads(o.astype(x.dtype), p["wo"])
     return x + out, latent

@@ -588,7 +588,8 @@ def flash_attention(q, k, v, causal: bool = True,
     values of another width than the keys (v [B, Sk, kvH, Dv]) and `H / kvH`
     adjoining query heads on one KV head, read where it lies (a prefill
     whose keys are 192 wide in 256 lanes beside values of 128, 16 query
-    heads a KV head). A kernel program holds one
+    heads a KV head; a latent prefill's expanded rows, `[k_n ; k_r]` of 128 +
+    64 in 256 lanes a head beside values of 128). A kernel program holds one
     head's whole K and V (dK/dV: Q and dO) in VMEM and walks them in blocks
     of `block_q` x `block_k` scores, (512, 512) by default and clipped to
     the lengths: on a v5e the best or within 2% of it at every shape probed
@@ -657,7 +658,8 @@ FLASH_KV_VMEM_BYTES = 8 << 20
 FLASH_BLOCKS_VMEM_BYTES = 12 << 20
 # The most of K and V that the forward has been given so (`flash_attention_
 # takes`): 8,192 keys of 192 in 256 lanes beside values of 128 (PERF.md
-# section 6, PR 58).
+# section 6, PR 58; a latent prefill's 4,096 expanded rows of the same
+# widths are 6 MiB, inside the compiler's own allowance: PR 61).
 FLASH_KV_VMEM_MAX_BYTES = 12 << 20
 # Float32 scores [B, H, S, S] up to which the XLA spelling of a prefill's
 # attention (`models/decoding.py::_attend_cached`) is as fast as the kernel
@@ -673,8 +675,9 @@ def flash_attention_takes(q, k, v=None) -> bool:
     """Whether causal self-attention of q [B, S, H, D] over k [B, S, kvH, D]
     and v [B, S, kvH, Dv] (None: as k) goes to the forward KERNEL, for a
     caller that has another spelling to fall back on (a prefill's fresh
-    rows, `models/decoding.py::attend_held`), by what can be seen of the
-    call: on a TPU (as `flash_attention`), one 2- or 4-byte dtype, heads of
+    rows, `models/decoding.py::attend_fresh`: `attend_held`'s, and a latent
+    prefill's expanded ones), by what can be seen of the call: on a TPU (as
+    `flash_attention`), one 2- or 4-byte dtype, heads of
     whole lanes (the values' width may be another than the keys'), a
     length of whole 128s (the row statistics leave the kernel 128 positions
     at a time, `_store_row`: the chip's compiler refuses 16 to 64

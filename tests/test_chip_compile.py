@@ -92,6 +92,16 @@ def _pallas_calls(text):
             if 'custom_call_target="tpu_custom_call"' in line and " = " in line]
 
 
+def _flash_forward_calls(text) -> list:
+    """The names of a compiled prefill's flash calls, each one the forward
+    kernel's Mosaic call of three inputs."""
+    calls = [(op, tag) for op, tag in _pallas_calls(text) if "flash" in op]
+    for op, tag in calls:
+        assert op.startswith("flash_attention_fwd"), calls
+        assert tag.startswith("tpu_custom_call/3in"), calls
+    return [op for op, _ in calls]
+
+
 def test_flash_forward_compiles(topo):
     compiled = jax.jit(lambda q, k, v: A._flash_fwd_pallas(
         q, k, v, True, None, A.FLASH_BLOCK_Q, A.FLASH_BLOCK_K)).lower(
@@ -886,7 +896,10 @@ def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     latent rows to keep (all aliased in to out), holds no temporary of the
     state stack's or of a latent layer's size, reads the latent rows with
     the Mosaic call that is given the stack, under `mla.attend`; the prefill
-    of the 2048 bucket runs the recurrence in chunks; both fit the chip
+    of the 2048 bucket runs the recurrence in chunks and attends its latent
+    layers' expanded fresh rows with the flash forward (a period's one
+    latent layer and the last layer: two Mosaic calls of three inputs under
+    `mla.attend`, no float32 [32, 2048, 2048] logits); both fit the chip
     beside each other's arguments, and the scopes reach the compiled text."""
     from benchmarks import harness, scope_ops
 
@@ -937,8 +950,16 @@ def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     assert set(scopes) >= set(runner.SCOPES) - {"kda.prefill_scan"}
     called = {scope_ops._INSTRUCTION.match(line)[1] for line in calls}
     assert called <= set(scopes["mla.attend"])
-    pscopes = scope_ops.op_scopes(prefill.as_text(), runner.SCOPES)
+    ptext = prefill.as_text()
+    pscopes = scope_ops.op_scopes(ptext, runner.SCOPES)
     assert "kda.prefill_scan" in pscopes and "kda.state" not in pscopes
+    # 512 MiB of float32 scores a latent layer went through HBM before PR 61
+    for op_name, dtype, dims, op in _results(ptext):
+        assert not (dtype == "f32" and dims.count(2048) >= 2
+                    and math.prod(dims) >= 32 * 2048 * 2048), (op_name, dims)
+    flash = _flash_forward_calls(ptext)
+    assert len(flash) == 2, flash  # a period's latent layer, and the last
+    assert set(flash) <= set(pscopes["mla.attend"])
     # a chunk's [heads, 32, 32] system inside the scan over 64 chunks
     assert re.search(r"f32\[1,32,32,32\]", prefill.as_text())
     leaves = len(jax.tree.leaves(jax.eval_shape(
@@ -1215,8 +1236,11 @@ def test_longcat_serve_programs_compile(serve_programs):
     (aliased in to out), holds no temporary of a sublayer's size and no
     float32 logits over `max_len`, reads the rows with the Mosaic call that
     is given the stack, twice a scanned double layer, under `mla.attend`;
-    the prefill of the 4096 bucket holds no [64, 4096, 4096] logits (its
-    largest block is [64, 512, 4096]) and no [4096 x 12, 6144] expert rows
+    the prefill of the 4096 bucket attends its expanded fresh rows with the
+    flash forward (keys of 192 in 256 lanes beside values of 128), one Mosaic
+    call of three inputs a scanned sublayer under `mla.attend`, and holds no
+    float32 logits at all, neither [64, 4096, 4096] nor the [64, 512, 4096]
+    blocks it went by before PR 61, and no [4096 x 12, 6144] expert rows
     (1024 rows a call); both fit the chip, the prefill beside the engine's
     cache, and the scopes reach the compiled text."""
     from benchmarks import harness, scope_ops
@@ -1267,9 +1291,16 @@ def test_longcat_serve_programs_compile(serve_programs):
     assert called <= set(scopes["mla.attend"])
     pscopes = scope_ops.op_scopes(ptext, runner.SCOPES)
     assert set(pscopes) >= set(runner.SCOPES) - {"sample"}
-    # a block of 512 queries against the keys up to its end, never S x S
-    assert re.search(r"f32\[64,512,4096\]", ptext)
+    # no float32 logits through HBM: neither S x S nor a block of 512
+    # queries against the keys up to its end (150 of a prefill's 331 ms on
+    # the chip, PERF.md PR 44)
     assert not re.search(r"f32\[(1,)?64,4096,4096\]", ptext)
+    for op_name, dtype, dims, op in _results(ptext):
+        assert not (dtype == "f32" and 4096 in dims
+                    and math.prod(dims) >= 64 * 512 * 4096), (op_name, dims)
+    flash = _flash_forward_calls(ptext)
+    assert len(flash) == 2, flash  # a double layer's two sublayers, scanned
+    assert set(flash) <= set(pscopes["mla.attend"])
     assert re.search(r"\[12288,6144\]", ptext)  # 1024 rows x 12 a call
     assert not re.search(r"\[49152,6144\]", ptext)
     leaves = len(jax.tree.leaves(jax.eval_shape(
@@ -1354,13 +1385,10 @@ def test_prefill_attends_its_fresh_rows_with_the_flash_kernel(
     assert paths[f"prefill_{bucket}"] == "flash"
     if name == "zaya1-8b-serve-d16":
         assert paths == {"prefill_1024": "dense", "prefill_2048": "flash"}
-    calls = [(op, tag) for op, tag in _pallas_calls(text) if "flash" in op]
+    calls = _flash_forward_calls(text)
     # one scanned body; a pattern's leading layer and its period's full one
     assert len(calls) == (1 + cfg.layer_kinds.count("full")
                           if cfg.layer_kinds else 1), calls
-    for op, tag in calls:
-        assert op.startswith("flash_attention_fwd")
-        assert tag.startswith("tpu_custom_call/3in"), calls
     logits = [(op_name, dims, op) for op_name, dtype, dims, op in _results(text)
               if dtype == "f32" and dims.count(bucket) >= 2
               and math.prod(dims) >= cfg.heads * bucket * bucket]
@@ -1371,8 +1399,7 @@ def test_prefill_attends_its_fresh_rows_with_the_flash_kernel(
     if cfg.layer_kinds:
         wanted = "attn.full"
         scopes = harness.load_module("runners", "serve_laguna").SCOPES
-    assert {op for op, _ in calls} <= set(
-        scope_ops.op_scopes(text, scopes)[wanted])
+    assert set(calls) <= set(scope_ops.op_scopes(text, scopes)[wanted])
 
 
 def _program_text(program) -> str:
@@ -1402,18 +1429,20 @@ def _program_text(program) -> str:
 # prompt's 16,384) and LongCat's two, whose capped expert layers count an
 # int32 [3] now (reached, rows gathered, whole-layout calls: a handful of
 # scalar adds and pads, no other instruction). A configuration without a
-# cap (Laguna's half) keeps the parent's program to the character.
+# cap (Laguna's half) keeps the parent's program to the character. PR 61
+# sent the two latent families' PREFILLS to the flash forward
+# (`LATENT_PREFILLS` below holds them to it); their decode steps stay.
 UNCHANGED_PROGRAMS = {
     ("zaya1-8b-serve-d16", "prefill"): "b6e375849037b5ca",
     ("mistral7b-v03-serve-d16", "decode"): "f367d611b8b354af",
     ("olmoe-1b-7b-serve-d8", "decode"): "db5f0cb4da39e451",
     ("zaya1-8b-serve-d16", "decode"): "32de0ec26bb5d39f",
     ("laguna-s-2.1-serve-ep2-d5", "decode"): "5ab54d08f545bb4f",
-    (KIMI_LINEAR, "prefill"): "5fbaca5ead8eca82",
     (KIMI_LINEAR, "decode"): "30e87476ec12a73c",
-    (LONGCAT, "prefill"): "513ca50bf464a8ad",
     (LONGCAT, "decode"): "d19b0ea7c5f6ee58",
 }
+# a latent family's prefill -> its cell's bucket
+LATENT_PREFILLS = {KIMI_LINEAR: 2048, LONGCAT: 4096}
 
 
 @pytest.mark.parametrize("name,kind", sorted(UNCHANGED_PROGRAMS))
@@ -1427,6 +1456,21 @@ def test_programs_a_prefills_attention_does_not_reach_are_the_parents(
     got = hashlib.sha256(text.encode()).hexdigest()[:16]
     assert got == UNCHANGED_PROGRAMS[name, kind], (
         f"{name} {kind}: {len(text.splitlines())} lines now hash to {got}")
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_PREFILLS))
+def test_a_latent_prefill_attends_with_the_flash_kernel(serve_programs, name):
+    """The two prefills whose attention is `kimi_linear.mla_attention`'s own
+    (until PR 61 pinned above as programs a prefill's attention does not
+    reach): the engine's counter says "flash" for the cell's bucket and
+    nothing else, and the flash forward is the only attention kernel of the
+    program (no backward, no decode kernel)."""
+    _, prefill, _, _ = serve_programs(name)
+    assert serve_programs.attention_paths(name) == {
+        f"prefill_{LATENT_PREFILLS[name]}": "flash"}
+    kernels = {op.rstrip(".0123456789") for op, _ in _pallas_calls(
+        prefill.as_text()) if "attention" in op}
+    assert kernels == {"flash_attention_fwd"}
 
 
 # the four sparse serve programs: what one layer's expert stack holds (the

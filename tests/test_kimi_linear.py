@@ -171,6 +171,99 @@ def test_the_absorbed_step_is_the_expanded_form(params):
     assert not np.asarray(stack[0]).any() and not np.asarray(stack[2]).any()
 
 
+@pytest.fixture
+def kernels_through_the_interpreter(monkeypatch):
+    """`tests/test_mimo.py`'s: the chip's path on the CPU."""
+    import jax.experimental.pallas as pl
+
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(A, "DENSE_SCORES_BYTES", 0)
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    return A
+
+
+# a latent sublayer as this model runs it, and as DeepSeek-V3's family does
+MLA_FIELDS = {
+    "plain": dict(),
+    "low_rank_rotated_scaled": dict(mla_q_rank=24, mla_rotate=True,
+                                    mla_scales=(1.5, 2.0)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("spelled", ["whole", "blocked"])
+@pytest.mark.parametrize("fields", sorted(MLA_FIELDS))
+def test_a_latent_prefill_through_the_flash_kernel_is_the_expanded_one(
+        kernels_through_the_interpreter, monkeypatch, fields, spelled, dtype):
+    """One latent sublayer over 256 fresh positions, heads of 128 + 64 and
+    values of 128 (the published widths: keys of 192 in 256 lanes), a
+    sequence of 256 rows and one of 200 with pad rows behind it: the flash
+    forward gives every REAL row what `_attend_expanded` gives, as one
+    [H, S, S] array and 96 queries at a time (a last block that is not
+    whole), and leaves the same latent rows; each spelling books itself."""
+    A = kernels_through_the_interpreter
+    cfg = T.config("kimi_linear_debug", head_dim=128, mla_rope_dim=64,
+                   dtype=dtype, **MLA_FIELDS[fields])
+    p = jax.tree.map(lambda a: a[1], T.init_params(
+        cfg, jax.random.key(7))["blocks"]["mla"])
+    b, s = 2, 256
+    x = jax.random.normal(jax.random.key(1), (b, s, cfg.hidden)).astype(dtype)
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    real = jnp.arange(s)[None] < jnp.array([256, 200])[:, None]
+    stack = jnp.zeros((3, b, s, cfg.latent_row), dtype)
+
+    def run():
+        with decoding.fresh_rows_attended() as seen:
+            out, rows = jax.jit(functools.partial(K.mla_attention, cfg))(
+                x, p, pos, stack, real, real, 1)
+        return out - x, rows, seen
+
+    got, rows, seen = run()
+    assert seen == {"flash"}
+    monkeypatch.setattr(A, "_on_tpu", lambda: False)
+    if spelled == "blocked":
+        monkeypatch.setattr(K, "PREFILL_LOGITS_MAX", 0)
+        monkeypatch.setattr(K, "PREFILL_QUERY_BLOCK", 96)
+    want, want_rows, seen = run()
+    assert seen == {"dense"}
+    np.testing.assert_array_equal(rows, want_rows)
+    got, want = (np.asarray(a, np.float32)[np.asarray(real)]
+                 for a in (got, want))
+    assert np.isfinite(got).all() and np.abs(want).max() > 0.1
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    else:  # one rounding to bf16, another order of summation
+        assert np.linalg.norm(got - want) <= 6e-3 * np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("where", ["off_the_chip", "a_toy_width_on_it"])
+def test_a_shape_the_flash_kernel_refuses_stays_expanded(
+        params, monkeypatch, where):
+    """What `flash_attention_takes` refuses runs the expanded form and the
+    engine says "dense" for the bucket: any shape off the chip, and on it
+    heads of 16 + 8 beside values of 16 (no whole lanes), with the plain
+    spelling's very numbers."""
+    from ray_tpu.ops import attention as A
+
+    def prefill():
+        cb = ContinuousBatcher(CFG, params, max_len=128, slots=1)
+        cb.shutdown()
+        out = cb._prefill(_prompt(4, 100))
+        # (what `engine_stats()["prefill_attention_path"]` carries)
+        assert cb.prefill_attention_path == {"prefill_128": "dense"}
+        return out
+
+    want = prefill()
+    if where == "a_toy_width_on_it":
+        monkeypatch.setattr(A, "_on_tpu", lambda: True)
+        monkeypatch.setattr(A, "DENSE_SCORES_BYTES", 0)
+    for a, b in zip(prefill(), want):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_the_latent_kernel_reads_the_held_rows(monkeypatch):
     """`latent_decode_attention` through the interpreter against the XLA
     spelling on the same stack: a layer that is not the first; slots that

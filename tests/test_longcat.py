@@ -209,6 +209,55 @@ def test_the_blocked_prefill_is_the_plain_one(block):
     assert CFG.heads * 128 * 128 * 4 <= K.PREFILL_LOGITS_MAX < 64 * 4096**2 * 4
 
 
+def test_the_engine_prefills_with_the_flash_kernel_where_it_takes_the_shape(
+        monkeypatch):
+    """The batcher's own prefill program at heads of 128 + 64 rotated beside
+    values of 128 (the published widths), a prompt of 200 tokens in the 256
+    bucket with pad rows behind it, the flash forward through the Pallas
+    interpreter (as `tests/test_mimo.py::kernels_through_the_interpreter`):
+    the engine books "flash" for the bucket, the last position's logits are
+    the reference's, and logits, latent rows, load and choices are those of
+    the expanded form, which the same engine runs off the chip and books as
+    "dense"."""
+    import functools
+
+    import jax.experimental.pallas as pl
+
+    from ray_tpu.ops import attention as A
+
+    cfg = T.config("longcat_debug", head_dim=128, mla_rope_dim=64, layers=2,
+                   max_seq=256)
+    wide = T.init_params(cfg, jax.random.key(8))
+    prompt = _prompt(9, 200)
+
+    def prefill():
+        cb = ContinuousBatcher(cfg, wide, max_len=256, slots=1)
+        cb.shutdown()
+        return cb._prefill(prompt), cb.prefill_attention_path
+
+    want, path = prefill()
+    assert path == {"prefill_256": "dense"}
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(A, "DENSE_SCORES_BYTES", 0)
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    got, path = prefill()
+    assert path == {"prefill_256": "flash"}
+    ref, _ = R.logits(wide, np.asarray(prompt)[None], published(cfg), last=1)
+    out = R.compare_logits(np.asarray(got[0])[None], np.asarray(ref[0]))
+    assert out["rms_err_over_std"] < LOGITS_RMS_MAX, out
+    assert out["argmax_agree"] == 1.0
+    for a, b in zip(got, want):
+        # a pad row sees other keys than under the mask, and is nobody's:
+        # the rows [sublayers, S, 640] and choices [layers, S, k] by position
+        a, b = (np.asarray(x)[:, :200] if x.ndim == 3 else np.asarray(x)
+                for x in (a, b))
+        if np.issubdtype(a.dtype, np.integer):
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, atol=2e-5)
+
+
 def test_a_long_prompts_expert_rows_go_a_part_at_a_time(params, monkeypatch):
     """A prompt of 70 tokens through the 128 bucket with the expert layer
     called 32 rows at a time gives the logits, the rows, the load and the
