@@ -23,6 +23,7 @@ from ray_tpu.models.decoding import SamplingParams
 from ray_tpu.observability import schema
 from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.observability.tracing import device_span
+from ray_tpu.ops import traced
 
 
 def build_llm_deployment(config: LLMConfig):
@@ -79,13 +80,9 @@ def build_llm_deployment(config: LLMConfig):
             batcher = getattr(self.engine, "batcher", None)
             st = getattr(batcher, "stats", None)
             out = dict(st) if st is not None else {}
-            # per jitted program, what a sparse model's grouped expert
-            # matmuls were traced with, "kernel" or "ragged_dot", and what a
-            # prefill's fresh rows were attended with, "flash" or "dense", a
-            # step's held rows, "kernel" or "dense", and a state-space
-            # mixer's recurrence, "scan:kernel" / "state:kernel" or ":plain"
-            for booked in ("moe_grouped_path", "prefill_attention_path",
-                           "decode_attention_path", "ssm_path"):
+            # per jitted program, the implementation each choice made at
+            # its trace fell on (`traced.TOLD` says which and their values)
+            for booked in traced.TOLD.values():
                 if getattr(batcher, booked, None):
                     out[booked] = getattr(batcher, booked)
             devices = jax.devices()

@@ -77,15 +77,13 @@ from ray_tpu.models.decoding import (
     SamplingParams,
     _write_stack,
     forward_cached,
-    fresh_rows_attended,
-    held_rows_attended,
     init_cache,
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import schema as spans
 from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.observability.tracing import device_span
-from ray_tpu.ops import grouped_matmul, ssd
+from ray_tpu.ops import traced
 from ray_tpu.ops.attention import NEG_INF, decode_block
 from ray_tpu.parallel.bootstrap import FirstCall
 
@@ -207,32 +205,21 @@ class PrefillPrograms:
 
     def _jit_programs(self) -> None:
         self._prefill_jits: Dict[int, Any] = {}
-        # jitted program -> what its grouped expert matmuls were traced
-        # with, "kernel" or "ragged_dot" (`_traced_with`); a dense model's
-        # programs have none and book nothing. `engine_stats()` carries it
-        self.moe_grouped_path: Dict[str, str] = {}
-        # prefill program -> what its fresh rows were attended with,
-        # "flash" or "dense" (`decoding.attend_fresh`: `attend_held`'s
-        # prefills and a latent prefill's expanded rows, `kimi_linear.
-        # mla_attention`); nothing from a model without attention over
-        # fresh rows. `engine_stats()` too
-        self.prefill_attention_path: Dict[str, str] = {}
-        # decode program -> what its held rows in a stack were attended with,
-        # "kernel" or "dense" (`decoding.attend_held`); program -> what a
-        # state-space mixer's recurrence ran as, "scan:kernel" (a prefill's),
-        # "state:kernel" (a step's) or ":plain" (`ops.ssd`). `engine_stats()`
-        self.decode_attention_path: Dict[str, str] = {}
-        self.ssm_path: Dict[str, str] = {}
+        # `moe_grouped_path`, `prefill_attention_path`, ...: jitted program
+        # -> the implementation(s) each choice of `traced.TOLD` fell on
+        # while it was traced (`_traced_with`). `engine_stats()` carries them
+        for told in traced.TOLD.values():
+            setattr(self, told, {})
 
-    @staticmethod
-    def _traced_with(booked: dict, program: str, paths: set) -> dict:
-        """`booked` with, for `program`, the implementation(s) an op chose
-        by what it saw of its call while the program was traced
-        (`ops.grouped_matmul` for its grouped matmuls, `attend_fresh` for a
-        prefill's fresh rows): a program that fell back says so in one look.
-        A new dict, not an update in place: a reader may be copying the old
-        one."""
-        return {**booked, program: "+".join(sorted(paths))} if paths else booked
+    def _traced_with(self, program: str, seen: dict) -> None:
+        """Books what `program`'s trace chose (`traced.booked`'s `seen`)
+        under each choice's attribute: a program that fell back says so in
+        one look. New dicts, not updates in place: a reader may be copying
+        the old one."""
+        for choice, paths in seen.items():
+            told = traced.TOLD[choice]
+            setattr(self, told, {**getattr(self, told),
+                                 program: "+".join(sorted(paths))})
 
     def _prefill_impl(self, params, tokens, length):
         """[1, S] prompt -> (last_logits [V], row_k, row_v [L, S, kvH, D])
@@ -250,18 +237,11 @@ class PrefillPrograms:
         row_cache = init_cache(self.cfg, 1, s)
         positions = jnp.arange(s)[None, :]
         kv_mask = jnp.arange(s)[None, :] < length
-        with grouped_matmul.paths_traced() as paths, \
-                fresh_rows_attended() as attended, \
-                ssd.paths_traced() as scanned:
+        with traced.booked() as seen:
             logits, row_cache, aux = forward_cached(
                 self.cfg, params, tokens, positions, row_cache, kv_mask,
                 kv_mask)
-        self.moe_grouped_path = self._traced_with(
-            self.moe_grouped_path, f"prefill_{s}", paths)
-        self.prefill_attention_path = self._traced_with(
-            self.prefill_attention_path, f"prefill_{s}", attended)
-        self.ssm_path = self._traced_with(
-            self.ssm_path, f"prefill_{s}", scanned)
+        self._traced_with(f"prefill_{s}", seen)
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
@@ -541,17 +521,11 @@ class ContinuousBatcher(PrefillPrograms):
         # what each slot holds once its token is written: the same prefix as
         # `kv_mask`, stated as a count; a slot that takes no part holds none
         rows = jnp.where(active_mask, cache.lengths + 1, 0)
-        with grouped_matmul.paths_traced() as paths, \
-                held_rows_attended() as attended, \
-                ssd.paths_traced() as updated:
+        with traced.booked() as seen:
             logits, cache, aux = forward_cached(
                 self.cfg, params, toks[:, None], positions, cache, kv_mask,
                 active_mask[:, None], access, rows)
-        self.moe_grouped_path = self._traced_with(
-            self.moe_grouped_path, "decode", paths)
-        self.decode_attention_path = self._traced_with(
-            self.decode_attention_path, "decode", attended)
-        self.ssm_path = self._traced_with(self.ssm_path, "decode", updated)
+        self._traced_with("decode", seen)
         with jax.named_scope("sample"):
             nxt = _sample_per_slot(
                 logits[:, 0], rng, temps, topks, active_mask)

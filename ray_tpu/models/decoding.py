@@ -46,11 +46,12 @@ from jax import lax
 
 from ray_tpu.models import families
 from ray_tpu.models.transformer import (
-    TransformerConfig, _lora_delta, _qk_norm, _rms_norm, _rope, moe_dropless,
+    TransformerConfig, _gated, _lora_xa, _qk_norm, _qkv, _rms_norm, _rope,
+    moe_dropless,
 )
 from ray_tpu.ops import attention as attention_ops
 from ray_tpu.ops.attention import NEG_INF
-from ray_tpu.ops.traced import TracedPaths
+from ray_tpu.ops import traced
 
 
 class KVCache(NamedTuple):
@@ -204,16 +205,10 @@ class FreshRows(NamedTuple):
     v: jax.Array
 
 
-# What the fresh rows of a prefill were attended with, "flash" or "dense"
-# (`attend_fresh`): `with fresh_rows_attended() as seen:` around a prefill
-# program's trace.
-_fresh_rows = TracedPaths("fresh_rows_attention")
-fresh_rows_attended = _fresh_rows.traced
-# What a decode step's held rows in a stack were attended with, "kernel"
-# (`ops.attention.decode_attention`) or "dense" (`_attend_cached` over the
-# layer's view): `with held_rows_attended() as seen:` around the step's trace.
-_held_rows = TracedPaths("held_rows_attention")
-held_rows_attended = _held_rows.traced
+# What the fresh rows of a prefill were attended with while the body ran,
+# "flash" or "dense" (`attend_fresh`): this choice's share of
+# `traced.booked()`.
+fresh_rows_attended = functools.partial(traced.booked, "fresh_rows")
 
 
 def attend_fresh(q, fresh: FreshRows, sink=None, sm_scale=None):
@@ -225,7 +220,7 @@ def attend_fresh(q, fresh: FreshRows, sink=None, sm_scale=None):
     booked here, "flash" or "dense" (`fresh_rows_attended`). No caller has
     a sink over fresh rows, and the kernel has none."""
     flash = sink is None and attention_ops.flash_attention_takes(q, *fresh)
-    _fresh_rows.book("flash" if flash else "dense")
+    traced.book("fresh_rows", "flash" if flash else "dense")
     if not flash:
         return None
     # values of a width of their own: the forward reads a KV head where it
@@ -269,7 +264,7 @@ def attend_held(q, held, q_pos, kv_len_mask, rows=None, sink=None,
         step = rows is not None and q.shape[1] == 1
         kernel = step and attention_ops.decode_attention_takes(held.k, held.v)
         if step:
-            _held_rows.book("kernel" if kernel else "dense")
+            traced.book("held_rows", "kernel" if kernel else "dense")
         if kernel:
             with jax.named_scope("attend_cached"):
                 return attention_ops.decode_attention(
@@ -333,18 +328,8 @@ def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
     the block around it has this one spelling. `rows` [B]
     is a decode step's statement of the rows each slot holds."""
     scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
-    b, s, _ = x.shape
-    nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.hd
-
     y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
-    q = jnp.einsum("bsh,hnd->bsnd", y, p["wq"].astype(y.dtype))
-    k = jnp.einsum("bsh,hnd->bsnd", y, p["wk"].astype(y.dtype))
-    v = jnp.einsum("bsh,hnd->bsnd", y, p["wv"].astype(y.dtype))
-    if lora is not None:
-        q = q + _lora_delta(y, lora["wq_a"], lora["wq_b"], scale).reshape(
-            b, s, nh, hd)
-        v = v + _lora_delta(y, lora["wv_a"], lora["wv_b"], scale).reshape(
-            b, s, nkv, hd)
+    q, k, v = _qkv(y, p, lora, scale, lambda name: _lora_xa(y, lora[name]))
     if cfg.qk_norm:
         q, k = _qk_norm(cfg, q, k, p)
     q = _rope(q, positions, cfg.rope_theta)
@@ -410,11 +395,7 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
         return (x + out, k_cache, v_cache, state, route), (load, *chosen)
     scale = cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 0.0
     with jax.named_scope("mlp"):
-        gate = jnp.einsum("bsh,hm->bsm", y, p["wi_gate"].astype(y.dtype))
-        up = jnp.einsum("bsh,hm->bsm", y, p["wi_up"].astype(y.dtype))
-        if lora is not None:
-            gate = gate + _lora_delta(y, lora["wi_a"], lora["wi_b"], scale)
-        act = jax.nn.silu(gate) * up
+        act = _gated(y, p, lora, scale, lambda name: _lora_xa(y, lora[name]))
         out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
     return (x + out, k_cache, v_cache, state, route), ()
 
@@ -463,15 +444,18 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     positions is each sequence's last: the state it leaves is that
     position's, and a sequence with no real row keeps the state it had.
 
-    A layer pattern (`cfg.layer_kinds`) has a loop of its own over what its
-    kinds of layers keep (its family's `forward_cached`, `families.PATTERNS`:
-    this carry plus a ring; matrix states, convolution windows and latent
-    rows): a scan over periods.
+    A layer pattern (`cfg.layer_kinds`, `families.PATTERNS`) has a loop of
+    its own over what its kinds of layers keep (this carry plus a ring;
+    matrix states, convolution windows and latent rows):
+    `pattern.forward_cached` over the one layer its family states, or the
+    family's own `forward_cached` (LongCat's scan of double layers).
     """
     if cfg.layer_kinds:
-        return families.of(cfg).forward_cached(
-            cfg, params, tokens, positions, cache, kv_len_mask, row_mask,
-            access, rows)
+        run = getattr(families.of(cfg), "forward_cached", None)
+        if run is None:  # `pattern` imports this module
+            from ray_tpu.models.pattern import forward_cached as run
+        return run(cfg, params, tokens, positions, cache, kv_len_mask,
+                   row_mask, access, rows)
     x = params["embed"].astype(cfg.dtype)[tokens]
     layer_tree, whole = layers_to_scan(cfg, params)
     route = None

@@ -9,8 +9,12 @@ no family lists another's); `check(cfg)`, what it needs of them;
 `kept(cfg, max_len)`, what its layers keep a sequence, `Kept`s in `KVCache`'s
 field order, stated ONCE (`cfg.keeps` / `.stateful` / `.full_layers` ...,
 `init_cache`, `_kv_rows` and `only_kv_rows` read it); a pattern its `leaves`
-(behind `pattern.py`'s `init_params` / `param_axes` / `num_params`) and
-`forward_cached`; the one block's sublayers `extra_params`,
+(behind `pattern.py`'s `init_params` / `param_axes` / `num_params`) and ONE
+layer of a kind, `layer(cfg, call, kind, i, n, carry)` over the `KVCache`
+fields `CARRIED` (behind `pattern.forward_cached`, the one loop over a
+pattern's layers: Laguna, Kimi-Linear and Nemotron-H state no
+`forward_cached`; LongCat, whose one scan of double layers counts five
+things, keeps its own); the one block's sublayers `extra_params`,
 `init_block_params`, `update_block_axes`, `attention_cached`, `router`.
 A new family is its module, one line of a table here, its fields, a
 `KVCache` field only for a new kind of thing, its scopes and its tests.

@@ -9,9 +9,9 @@ the drawing of weights are the other models' (`transformer.moe_dropless`,
 periods of `layer_kinds` (kda, kda, mla, kda) and the trailing layers
 `tail_kinds` (kda, mla), every layer behind the first with sparse experts.
 Parameters are stacked BY KIND (`blocks["kda" | "mla" | "sparse"]`,
-`blocks["dense"]` the leading MLP alone); `forward_cached` runs the leading
-layer, ONE `lax.scan` over the periods, then the trailing layers. With y the
-RMS-normed stream:
+`blocks["dense"]` the leading MLP alone); `pattern.forward_cached` runs
+`layer` here as the leading layer, ONE `lax.scan` over the periods, then the
+trailing layers. With y the RMS-normed stream:
 
 **A "kda" layer** (`heads` heads of `hd`, keys and values alike): `q~, k~,
 v~ = y Wq, y Wk, y Wv`; a causal depthwise convolution of `kda_conv` taps and
@@ -66,13 +66,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.decoding import (
-    FreshRows, KVCache, _write_stack, attend_fresh, lm_head,
-)
+from ray_tpu.models.decoding import FreshRows, attend_fresh
 from ray_tpu.models.families import Kept
 from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
-    EXPERT_LEAVES, _swiglu, _take, init_params, mlp_leaves, num_params,
-    only_the_stack, param_axes, sparse_mlp,
+    _swiglu, _take, init_params, mlp_leaves, num_params, param_axes,
+    sparse_mlp,
 )
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm
 from ray_tpu.ops import attention as attention_ops
@@ -594,75 +592,29 @@ def router(cfg: TransformerConfig, x, p):
     return weights * cfg.routed_scale, experts
 
 
-def forward_cached(cfg: TransformerConfig, params, tokens, positions,
-                   cache: KVCache, kv_len_mask, row_mask, access=_write_stack,
-                   rows=None):
-    """`decoding.forward_cached` for this pattern: the same arguments and
-    results, the carry being the residual stream, the "kda" layers' matrix
-    states and convolution windows and the "mla" layers' latent rows, all
-    written in place at [layer of its kind]. `aux` as `laguna.
-    forward_cached`'s: "expert_load", "expert_choice" [sparse layers, B*S,
-    k], "experts_counted"."""
-    only_the_stack(cfg, access)
-    blocks = params["blocks"]
-    sparse = {n: a for n, a in blocks["sparse"].items()
-              if n not in EXPERT_LEAVES}
-    experts = {n: blocks["sparse"][n] for n in EXPERT_LEAVES}
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    period = len(cfg.layer_kinds)
+CARRIED = ("mat", "conv", "latent")  # beside the stream, in `layer`'s carry
 
-    def attend(kind, x, held, at):
-        """One layer of `kind`, the `at[kind]`-th of it; counts it."""
-        mat, conv, latent = held
-        p = _take(blocks[kind], at[kind])
-        if kind == "kda":
-            x, mat, conv = kda_attention(cfg, x, p, mat, conv, row_mask,
-                                         at[kind])
-        else:
-            x, latent = mla_attention(cfg, x, p, positions, latent,
-                                      kv_len_mask, row_mask, at[kind], rows)
-        return x, (mat, conv, latent), dict(at, **{kind: at[kind] + 1})
 
-    def layers(x, held, at, kinds, first_sparse):
-        """`kinds` layers in a row, each with its sparse MLP."""
-        load, reached, choices = 0, 0, []
-        for j, kind in enumerate(kinds):
-            x, held, at = attend(kind, x, held, at)
-            layer = first_sparse + j
-            x, l, chosen, r = sparse_mlp(
-                cfg, x, dict(_take(sparse, layer), **experts), row_mask, layer,
-                router)
-            load, reached = load + l, reached + r
-            choices.append(chosen)
-        return x, held, (load, jnp.stack(choices), reached)
-
-    held = (cache.mat, cache.conv, cache.latent)
-    x, held, _ = attend(cfg.lead_kind, x, held, {"kda": 0, "mla": 0})
-    dense = blocks["dense"]
-    with jax.named_scope("mlp"):
-        x = x + _swiglu(_rms_norm(x, dense["ln_mlp"], cfg.norm_eps),
-                        dense["wi_gate"], dense["wi_up"], dense["wo_mlp"])
-    lead = {k: int(cfg.lead_kind == k) for k in ("kda", "mla")}
-
-    def one_period(carry, i):
-        x, held = carry
-        at = {k: lead[k] + i * cfg.layer_kinds.count(k) for k in lead}
-        x, held, counted = layers(x, held, at, cfg.layer_kinds, i * period)
-        return (x, held), counted
-
-    (x, held), (load, choice, reached) = lax.scan(
-        one_period, (x, held), jnp.arange(cfg.periods))
-    load, reached = load.sum(0), reached.sum(0)
-    choice = choice.reshape(-1, *choice.shape[2:])
-    if cfg.tail_kinds:
-        at = {k: lead[k] + cfg.periods * cfg.layer_kinds.count(k)
-              for k in lead}
-        x, held, (l, c, r) = layers(x, held, at, cfg.tail_kinds,
-                                    cfg.periods * period)
-        load, reached = load + l, reached + r
-        choice = jnp.concatenate([choice, c])
-    aux = {"expert_load": load, "expert_choice": choice,
-           "experts_counted": reached}
-    mat, conv, latent = held
-    return (lm_head(cfg, params, x),
-            cache._replace(mat=mat, conv=conv, latent=latent), aux)
+def layer(cfg: TransformerConfig, call, kind: str, i, n, carry):
+    """`pattern.forward_cached`'s one layer: the attention of `kind` at
+    layer `i` of its kind over the matrix states and convolution windows or
+    the latent rows, then the leading layer's dense MLP (`n` None) or sparse
+    layer `n`'s experts."""
+    x, mat, conv, latent = carry
+    p = _take(call.blocks[kind], i)
+    if kind == "kda":
+        x, mat, conv = kda_attention(cfg, x, p, mat, conv, call.row_mask, i)
+    else:
+        x, latent = mla_attention(cfg, x, p, call.positions, latent,
+                                  call.kv_len_mask, call.row_mask, i,
+                                  call.rows)
+    counted = None
+    if n is None:
+        dense = call.blocks["dense"]
+        with jax.named_scope("mlp"):
+            x = x + _swiglu(_rms_norm(x, dense["ln_mlp"], cfg.norm_eps),
+                            dense["wi_gate"], dense["wi_up"], dense["wo_mlp"])
+    else:
+        x, *counted = sparse_mlp(cfg, x, call.sparse(n), call.row_mask, n,
+                                 router)
+    return (x, mat, conv, latent), counted

@@ -15,8 +15,9 @@ cannot share a stack: parameters are stacked BY KIND, `blocks["full"]`
 [full layers, ...], `blocks["window"]` [window layers, ...], `blocks["sparse"]`
 [sparse layers, ...] (its expert stacks hold the `experts_held` alone and are
 read in place by `_grouped_matmul(layer=...)`), `blocks["dense"]` the leading
-MLP alone. `forward_cached` runs the leading layer, then ONE `lax.scan` over
-periods whose body unrolls a period's layers.
+MLP alone. The loop is `pattern.forward_cached`'s over `layer` here: the
+leading layer, then ONE `lax.scan` over periods whose body unrolls a period's
+layers.
 
 **Attention of kind K** on the normed stream y: `q = y Wq_K` [n_K, D], `k`,
 `v` [kv_heads, D]; RoPE by kind (full: `rope_theta` over the first
@@ -50,7 +51,7 @@ nothing stands in for the other chip or for the exchange.
 **What the second model states** (every field off or 0 is the first's).
 `lead_kind` "": `layer_kinds` is EVERY layer's kind in order (full, 4 window,
 full, then 5 window + full: no whole periods), the first a full layer with
-the dense MLP; the loop is read off the list (`pattern.runs`: a run of one
+the dense MLP; the loop is read off the list (`pattern.cut`: a run of one
 kind is ONE scan, a single layer is unrolled), where periods are one scan
 over periods. `window_kv_heads`: a KV-head count BY KIND. `value_dim`: values
 narrower than the keys (192 beside 128): the keys and queries are then
@@ -72,7 +73,6 @@ from __future__ import annotations
 
 import functools
 import math
-import operator
 
 import jax
 import jax.numpy as jnp
@@ -80,14 +80,13 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models.decoding import (
-    KVCache, StackLayer, _attend_cached, _write_stack, attend_held, lm_head,
+    StackLayer, _attend_cached, _write_stack, attend_held,
 )
 from ray_tpu.models.families import Kept
 from ray_tpu.models.kimi_linear import router as sigmoid_router
 from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
-    EXPERT_LEAVES, _swiglu, _take, init_params, mlp_leaves, num_params,
-    long_prompt, only_the_stack, param_axes, rows_at_a_time, runs,
-    sparse_mlp,
+    EXPERT_LEAVES, _swiglu, _take, init_params, long_prompt, mlp_leaves,
+    num_params, param_axes, rows_at_a_time, sparse_mlp,
 )
 from ray_tpu.models.transformer import (
     TransformerConfig, _rms_norm, _rope, moe_router,
@@ -402,105 +401,39 @@ def attention(cfg: TransformerConfig, kind: str, x, p, positions, k_cache,
     return x + out, k_cache, v_cache
 
 
-# -- the layer loop -------------------------------------------------------------
+# -- one layer (`pattern.forward_cached` walks them) ------------------------------
 
-def forward_cached(cfg: TransformerConfig, params, tokens, positions,
-                   cache: KVCache, kv_len_mask, row_mask, access=_write_stack,
-                   rows=None):
-    """`decoding.forward_cached` for a layer pattern: the same arguments and
-    results, the carry being the residual stream, the full layers' stacks
-    and the window layers' rings. `aux` is {"expert_load": int32
-    [num_experts], every routed assignment of the real rows summed over the
-    sparse layers; "expert_choice": int32 [sparse layers, B*S, k], every
-    row's experts in every layer (the k-th and (k+1)-th probability of 256
-    lie close, rounding flips them, and a flipped expert moves a logit by a
-    third of the logits' spread: a comparison with a reference has to know
-    the sets that were taken, as with ZAYA1's one expert);
-    "experts_counted": `pattern.sparse_mlp`'s summed over the layers: how
-    many experts held here the real rows reached (what a step's grouped
-    matmuls read) and, in int32 [3] where `held_rows_cap` caps the layers'
-    calls, the rows they gathered and the calls that took the whole
-    layout}."""
-    only_the_stack(cfg, access)
-    blocks = params["blocks"]
-    sparse = {n: a for n, a in blocks["sparse"].items()
-              if n not in EXPERT_LEAVES}
-    experts = {n: blocks["sparse"][n] for n in EXPERT_LEAVES}
-    router = sigmoid_router if cfg.router_score == "sigmoid" else moe_router
-    x = params["embed"].astype(cfg.dtype)[tokens]
+CARRIED = ("k", "v", "ring_k", "ring_v")  # beside the stream, in `layer`'s carry
 
-    x, k, v = attention(cfg, "full", x, _take(blocks["full"], 0), positions,
-                        cache.k, cache.v, kv_len_mask, row_mask, 0, rows)
-    dense = blocks["dense"]
-    def lead_mlp(rows):
-        return _swiglu(_rms_norm(rows, dense["ln_mlp"], cfg.norm_eps),
-                       dense["wi_gate"], dense["wi_up"], dense["wo_mlp"])
 
-    with jax.named_scope("mlp"):
-        x = x + (rows_at_a_time(lambda rows: (lead_mlp(rows), ()), x)[0]
-                 if long_prompt(x) else lead_mlp(x))
-    carry = (x, k, v, cache.ring_k, cache.ring_v)
-
-    def unit_of(kinds, carry, at, sparse_at):
-        """The layers `kinds` in a row from the `at[kind]`-th of each kind,
-        the j-th of them with sparse layer `sparse_at(j)`: (carry, (load
-        summed, choices stacked, `sparse_mlp`'s counted summed))."""
-        x, k, v, ring_k, ring_v = carry
-        load, reached, choices = 0, 0, []
-        for j, kind in enumerate(kinds):
-            i = at[kind]
-            if kind == "full":
-                x, k, v = attention(
-                    cfg, kind, x, _take(blocks["full"], i), positions, k, v,
-                    kv_len_mask, row_mask, i, rows)
-            else:
-                x, ring_k, ring_v = attention(
-                    cfg, kind, x, _take(blocks["window"], i), positions,
-                    ring_k, ring_v, kv_len_mask, row_mask, i, rows)
-            at = dict(at, **{kind: i + 1})
-            layer = sparse_at(j)
-            x, l, chosen, r = sparse_mlp(
-                cfg, x, dict(_take(sparse, layer), **experts), row_mask, layer,
-                router)
-            load, reached = load + l, reached + r
-            choices.append(chosen)
-        return (x, k, v, ring_k, ring_v), (load, jnp.stack(choices), reached)
-
-    if cfg.lead_kind:  # whole periods behind the leading layer: one scan
-        cut = [(cfg.layer_kinds, cfg.periods, True)]
-    else:  # every layer named: the loop read off the list
-        cut = [(unit, n, n > 1)
-               for unit, n in runs(cfg.layer_kinds[1:], RUN_MAX)]
-    at, layer = {"full": 1, "window": 0}, 0
-    loads, choices, reaches = [], [], []
-    for kinds, repeats, scanned in cut:
-        if scanned:
-            def period(carry, i, kinds=kinds, at=at, layer=layer):
-                def from_(n, step):  # `n + i * step`, and no `0 +`
-                    return n + i * step if n else i * step
-
-                here = {kind: from_(n, kinds.count(kind))
-                        for kind, n in at.items()}
-                return unit_of(kinds, carry, here,
-                               lambda j: from_(layer, len(kinds)) + j)
-
-            carry, (load, choice, reached) = lax.scan(
-                period, carry, jnp.arange(repeats))
-            load = load.sum(0)
-            choice = choice.reshape(-1, *choice.shape[2:])
-            reached = reached.sum(0)
-        else:
-            carry, (load, choice, reached) = unit_of(
-                kinds, carry, at, lambda j, layer=layer: layer + j)
-        at = {kind: n + repeats * kinds.count(kind) for kind, n in at.items()}
-        layer += repeats * len(kinds)
-        loads.append(load)
-        choices.append(choice)
-        reaches.append(reached)
+def layer(cfg: TransformerConfig, call, kind: str, i, n, carry):
+    """`pattern.forward_cached`'s one layer: attention of `kind` at layer
+    `i` of its kind over the full layers' stacks or the window layers'
+    rings, then the leading layer's dense MLP (`n` None; a long prompt's a
+    piece at a time) or sparse layer `n`'s experts."""
     x, k, v, ring_k, ring_v = carry
-    aux = {"expert_load": functools.reduce(operator.add, loads),
-           "expert_choice": jnp.concatenate(choices) if len(choices) > 1
-           else choices[0],
-           "experts_counted": functools.reduce(operator.add, reaches)}
-    return (lm_head(cfg, params, x),
-            KVCache(k, v, cache.lengths, None, ring_k, ring_v), aux)
+    p = _take(call.blocks[kind], i)
+    if kind == "full":
+        x, k, v = attention(
+            cfg, kind, x, p, call.positions, k, v, call.kv_len_mask,
+            call.row_mask, i, call.rows)
+    else:
+        x, ring_k, ring_v = attention(
+            cfg, kind, x, p, call.positions, ring_k, ring_v,
+            call.kv_len_mask, call.row_mask, i, call.rows)
+    counted = None
+    if n is None:
+        dense = call.blocks["dense"]
+
+        def lead_mlp(rows):
+            return _swiglu(_rms_norm(rows, dense["ln_mlp"], cfg.norm_eps),
+                           dense["wi_gate"], dense["wi_up"], dense["wo_mlp"])
+
+        with jax.named_scope("mlp"):
+            x = x + (rows_at_a_time(lambda rows: (lead_mlp(rows), ()), x)[0]
+                     if long_prompt(x) else lead_mlp(x))
+    else:
+        x, *counted = sparse_mlp(
+            cfg, x, call.sparse(n), call.row_mask, n,
+            sigmoid_router if cfg.router_score == "sigmoid" else moe_router)
+    return (x, k, v, ring_k, ring_v), counted

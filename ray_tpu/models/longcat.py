@@ -213,7 +213,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
                    rows=None):
     """`decoding.forward_cached` for this pattern: the same arguments and
     results, the carry being the residual stream and the latent rows, written
-    in place at [2 x double layer + sublayer]. `aux` as `laguna.
+    in place at [2 x double layer + sublayer]. `aux` as `pattern.
     forward_cached`'s ("expert_load" over the router's outputs, the
     zero-compute ones behind the routed; "expert_choice" [layers, B*S, k];
     "experts_counted"), then "routed_most": the most routed experts one real

@@ -14,9 +14,10 @@ sampling, the scheduler and the drawing of weights are the other models'
 published `hybrid_override_pattern` (M "ssm", * "gqa", E "lmoe"): it is not
 periodic, so no lead, period or tail is read into it. Every layer is `x <- x
 + f(RMSNorm(x))`. Parameters are stacked BY KIND (`blocks["ssm" | "ssm1" |
-"gqa" | "mlp" | "sparse"]`); `forward_cached` reads the loop off the string: a run of kinds
-that repeats (`runs`: the `M E` between two attentions) is ONE `lax.scan`,
-what is left is unrolled. With y the normed stream:
+"gqa" | "mlp" | "sparse"]`); `pattern.forward_cached` reads the loop over
+`layer` here off the string: a run of kinds that repeats (`pattern.cut`: the
+`M E` between two attentions) is ONE `lax.scan`, what is left is unrolled.
+With y the normed stream:
 
 **An "ssm" layer** (`ssm_heads` heads of `ssm_head_dim`, `ssm_groups` groups,
 a state of `ssm_state`): `[z ; u ; dt~] = y W_in`, u the convolution's
@@ -79,12 +80,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.decoding import KVCache, _write_stack, attend_held, lm_head
+from ray_tpu.models.decoding import _write_stack, attend_held
 from ray_tpu.models.families import Kept
 from ray_tpu.models.kimi_linear import router
-from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
-    RUN_MAX, _swiglu, _take, expert_names, init_params, mlp_leaves,
-    num_params, only_the_stack, param_axes, runs, sparse_mlp,
+from ray_tpu.models.pattern import (  # noqa: F401 (the family's three, `runs`)
+    _swiglu, _take, init_params, mlp_leaves, num_params, param_axes, runs,
+    sparse_mlp,
 )
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm
 from ray_tpu.ops import ssd
@@ -99,7 +100,6 @@ FIELDS = frozenset({
     "ssm_state", "ssm_conv", "ssm_chunk", "ssm_dt_rank", "moe_latent",
     "expert_act", "router_score", "shared_expert_hidden", "experts_held",
     "tie_embeddings"})
-KINDS = ("ssm", "ssm1", "gqa", "mlp", "lmoe")  # the loop counts layers by kind
 
 
 def check(cfg: TransformerConfig) -> None:
@@ -440,76 +440,28 @@ def attention(cfg: TransformerConfig, x, p, positions, k_cache, v_cache,
     return x + out, k_cache, v_cache
 
 
-# -- the layer loop -------------------------------------------------------------
+# -- one layer (`pattern.forward_cached` walks them) ------------------------------
 
-def forward_cached(cfg: TransformerConfig, params, tokens, positions,
-                   cache: KVCache, kv_len_mask, row_mask, access=_write_stack,
-                   rows=None):
-    """`decoding.forward_cached` for this pattern: the same arguments and
-    results, the carry being the residual stream, the "gqa" layers' K/V
-    stacks and the "ssm" layers' states and convolution windows, all written
-    in place at [layer of its kind]. `aux` as `laguna.forward_cached`'s:
-    "expert_load", "expert_choice" [sparse layers, B*S, k],
-    "experts_counted"."""
-    only_the_stack(cfg, access)
-    blocks = params["blocks"]
-    names = expert_names(cfg)
-    sparse = {n: a for n, a in blocks.get("sparse", {}).items()
-              if n not in names}
-    experts = {n: blocks["sparse"][n] for n in names} if sparse else {}
-    x = params["embed"].astype(cfg.dtype)[tokens]
+CARRIED = ("k", "v", "mat", "conv")  # beside the stream, in `layer`'s carry
 
-    def layers(carry, at, unit):
-        """The layers `unit` in a row, each the `at[kind]`-th of its kind
-        (counted on); what its expert layers counted, stacked."""
-        x, k, v, mat, conv = carry
-        counted = []
-        for kind in unit:
-            i = at[kind]
-            at = dict(at, **{kind: i + 1})
-            if kind in ("ssm", "ssm1"):
-                mixer = ssm_mixer if kind == "ssm" else ssm1_mixer
-                x, mat, conv = mixer(cfg, x, _take(blocks[kind], i), mat,
-                                     conv, row_mask, i)
-            elif kind == "mlp":
-                x = dense_mlp(cfg, x, _take(blocks["mlp"], i))
-            elif kind == "gqa":
-                x, k, v = attention(cfg, x, _take(blocks["gqa"], i),
-                                    positions, k, v, kv_len_mask, i, rows)
-            else:
-                x, load, chosen, reached = sparse_mlp(
-                    cfg, x, dict(_take(sparse, i), **experts), row_mask, i,
-                    router)
-                counted.append((load, chosen, reached))
-        return (x, k, v, mat, conv), at, counted
 
-    carry = (x, cache.k, cache.v, cache.mat, cache.conv)
-    at = dict.fromkeys(KINDS, 0)
-    loads, choices, reached = [], [], []
-    for unit, repeats in runs(cfg.layer_kinds):
-        if repeats == 1:
-            carry, at, counted = layers(carry, at, unit)
-            counted = [tuple(c[None] for c in one) for one in counted]
-        else:
-            def repeat(carry, r, at=at, unit=unit):
-                here = {kind: at[kind] + r * unit.count(kind) for kind in at}
-                carry, _, counted = layers(carry, here, unit)
-                return carry, counted
-
-            carry, counted = lax.scan(repeat, carry, jnp.arange(repeats))
-            at = {kind: at[kind] + repeats * unit.count(kind) for kind in at}
-        # a scan stacks each expert layer of its unit over the repeats: in
-        # the layers' order that is repeat-major
-        if counted:
-            load, choice, reach = (jnp.stack(c, axis=1) for c in zip(*counted))
-            loads.append(load.sum((0, 1)))
-            choices.append(choice.reshape(-1, *choice.shape[2:]))
-            reached.append(reach.sum((0, 1)))
+def layer(cfg: TransformerConfig, call, kind: str, i, n, carry):
+    """`pattern.forward_cached`'s one layer, the ONE sublayer of `kind` at
+    layer `i` of its kind: a mixer over the states and convolution windows,
+    the attention over the K/V stacks, a dense MLP, or sparse layer `i`'s
+    experts (every "lmoe" layer routes and no other: `n` is not read)."""
     x, k, v, mat, conv = carry
-    aux = {}
-    if loads:
-        aux = {"expert_load": sum(loads),
-               "expert_choice": jnp.concatenate(choices),
-               "experts_counted": sum(reached)}
-    return (lm_head(cfg, params, x),
-            cache._replace(k=k, v=v, mat=mat, conv=conv), aux)
+    if kind == "lmoe":
+        x, *counted = sparse_mlp(cfg, x, call.sparse(i), call.row_mask, i,
+                                 router)
+        return (x, k, v, mat, conv), counted
+    p = _take(call.blocks[kind], i)
+    if kind in ("ssm", "ssm1"):
+        mixer = ssm_mixer if kind == "ssm" else ssm1_mixer
+        x, mat, conv = mixer(cfg, x, p, mat, conv, call.row_mask, i)
+    elif kind == "mlp":
+        x = dense_mlp(cfg, x, p)
+    else:
+        x, k, v = attention(cfg, x, p, call.positions, k, v,
+                            call.kv_len_mask, i, call.rows)
+    return (x, k, v, mat, conv), None

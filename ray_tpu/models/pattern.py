@@ -1,44 +1,41 @@
 """What the layer patterns share (`families.PATTERNS`): their parameters
 from their `leaves`, a kind's layer out of its stack, the dense SwiGLU, a
 layer's sparse half (an expert's matrices and the width it works in are the
-family's to state: `cfg.expert_act`, `cfg.moe_latent`) and the one cache
-access their loops know. Each keeps
-its own `forward_cached`: a double layer is no attention + sparse MLP.
+family's to state: `cfg.expert_act`, `cfg.moe_latent`) and the ONE loop over
+a pattern's layers (`forward_cached`: the cut of a configuration's layers
+into scans, the counters by kind, what the expert layers counted). A family
+states one layer of a kind (`layer`, `CARRIED`). LongCat keeps a
+`forward_cached` of its own: one scan of one kind of double layer, no
+counter and no cut, and five things counted a layer where these count three.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+import operator
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import families
-from ray_tpu.models.decoding import _write_stack
+from ray_tpu.models.decoding import _write_stack, lm_head
 from ray_tpu.models.transformer import (
     TransformerConfig, _rms_norm, held_rows_cap, layout_counted,
     moe_dropless, moe_router,
 )
 
 EXPERT_LEAVES = ("wi_gate", "wi_up", "wo_mlp")  # a SwiGLU expert's stacks
-RUN_MAX = 4  # layers of one repeating unit at most (`runs`)
+# layers of one repeating unit at most (`runs`); a family whose units are
+# longer states its own (`laguna.RUN_MAX`)
+RUN_MAX = 4
 # A call of more rows than `WHOLE_ROWS_MAX` runs its MLPs a piece at a time
-# (`rows_at_a_time`): `moe_dropless` gathers its rows in float32 (k a row where
-# every assignment's is gathered: at 8,192 rows x 8 of 4,096 three arrays of
-# 1 GB), and a dense SwiGLU of 16,384 holds [rows, 16,384] three times. A
-# dense MLP's piece is `MLP_ROWS` rows; an expert layer's is cut by the rows
-# it GATHERS, `MLP_ROWS` x k a call (`expert_rows`): `MLP_ROWS` rows without
-# a cap, up to `WHOLE_ROWS_MAX` where `held_rows_cap` answers (at a sixteenth
-# held 4,096 rows gather 8,192: an 8,192-row prompt reads its 805 MB of held
-# experts a layer twice, not eight times: the grouped matmuls 105 -> 52 ms
-# and the prefill 374 -> 330 ms against 1,024-row pieces under the same cap;
-# the whole prompt in one call gave 319 ms for 1.5 GB more of temporaries, in
-# the whole-layout branch that is compiled whether or not it runs: my chip
-# runs, PR 59).
-# Every cell's bucket but the 8,192 one is below it and keeps the program it
-# had.
+# (`rows_at_a_time`), because `moe_dropless` gathers its rows in float32 and
+# a dense SwiGLU holds [rows, mlp] three times: a dense MLP `MLP_ROWS` rows a
+# call, an expert layer by the rows it GATHERS (`expert_rows`). Every cell's
+# bucket but the 8,192 one is below it (the readings: PERF.md section 6, PR 59).
 WHOLE_ROWS_MAX, MLP_ROWS = 4096, 1024
 # A leaf larger than this many elements is drawn a piece at a time
 # (`_draw`): its float32 draw would not fit beside the leaves before it.
@@ -299,3 +296,127 @@ def _take(tree, i):
     """Layer `i` of a kind's stacked parameters."""
     return jax.tree.map(
         lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+# -- the layer loop -------------------------------------------------------------
+
+def cut(cfg: TransformerConfig) -> list:
+    """`cfg`'s layers as the loop runs them, [(unit, repeats, scanned)]: the
+    unit's kinds `repeats` times over, as ONE `lax.scan` or in a row. The
+    period form (`lead_kind`) is its leading layer, one scan over the
+    `periods` (of one, too) and the trailing layers as one unit in a row;
+    the list form (`layer_kinds` names every layer) is read off the list by
+    `runs`, behind the leading layer where the family has one (the one with
+    the dense MLP, `cfg.dense_mlp_hidden`), and a unit that repeats is
+    scanned. The period form does not go through `runs`: it would scan a
+    tail and could cut the lead into the first unit, another program."""
+    kinds = cfg.layer_kinds
+    if cfg.lead_kind:
+        tail = [(cfg.tail_kinds, 1, False)] if cfg.tail_kinds else []
+        return [((cfg.lead_kind,), 1, False), (kinds, cfg.periods, True),
+                *tail]
+    lead = 1 if cfg.dense_mlp_hidden else 0
+    longest = getattr(families.of(cfg), "RUN_MAX", RUN_MAX)
+    return [(kinds[:1], 1, False)] * lead + [
+        (unit, n, n > 1) for unit, n in runs(kinds[lead:], longest)]
+
+
+class Call(NamedTuple):
+    """What the layers of one `forward_cached` share: `blocks`, the
+    parameters stacked by kind; the call's `positions`, `kv_len_mask`,
+    `row_mask` and `rows` (`decoding.forward_cached`'s); `sparse(n)`, sparse
+    layer `n`'s parameters as `sparse_mlp` takes them."""
+    blocks: dict
+    positions: Any
+    kv_len_mask: Any
+    row_mask: Any
+    rows: Any
+    sparse: Callable
+
+
+def forward_cached(cfg: TransformerConfig, params, tokens, positions, cache,
+                   kv_len_mask, row_mask, access=_write_stack, rows=None):
+    """`decoding.forward_cached` for a layer pattern whose family states one
+    layer: the same arguments and results. The carry is the residual stream
+    and the `KVCache` fields the family names (`CARRIED`), each written in
+    place at [layer of its kind]; `family.layer(cfg, call, kind, i, n,
+    carry)` is the layer of `kind`, the `i`-th of its kind and the `n`-th
+    behind the leading dense layer (None AT it, 0 on without one), and
+    returns (carry, what `sparse_mlp` counted or None).
+
+    `aux` is {} without an expert layer, else {"expert_load": int32
+    [num_experts], every routed assignment of the real rows summed over the
+    sparse layers; "expert_choice": int32 [sparse layers, B*S, k], every
+    row's experts in every layer (the k-th and (k+1)-th probability of 256
+    lie close, rounding flips them, and a flipped expert moves a logit by a
+    third of the logits' spread: a comparison with a reference has to know
+    the sets that were taken, as with ZAYA1's one expert);
+    "experts_counted": `sparse_mlp`'s summed over the layers: how many
+    experts held here the real rows reached (what a step's grouped matmuls
+    read) and, in int32 [3] where `held_rows_cap` caps the layers' calls,
+    the rows they gathered and the calls that took the whole layout}, in
+    that order (a prefill program's results are unpacked by position)."""
+    only_the_stack(cfg, access)
+    family, blocks = families.of(cfg), params["blocks"]
+    names = expert_names(cfg)
+    small = {n: a for n, a in blocks.get("sparse", {}).items()
+             if n not in names}
+    experts = {n: blocks["sparse"][n] for n in names} if small else {}
+    call = Call(blocks, positions, kv_len_mask, row_mask, rows,
+                lambda n: dict(_take(small, n), **experts))
+
+    def unit(kinds, carry, at, n):
+        """The layers `kinds` in a row, each the `at[kind]`-th of its kind
+        (counted on) and the first the `n`-th behind the leading dense
+        layer: (carry, None or what its expert layers counted: loads
+        summed, choices stacked, `counted` summed)."""
+        counted = []
+        for j, kind in enumerate(kinds):
+            i = at.get(kind, 0)
+            at = {**at, kind: i + 1}
+            carry, c = family.layer(cfg, call, kind, i,
+                                    None if n is None else n + j, carry)
+            if c is not None:
+                counted.append(c)
+        if not counted:
+            return carry, None
+        load, chosen, reached = zip(*counted)
+        return carry, (functools.reduce(operator.add, load),
+                       jnp.stack(chosen),
+                       functools.reduce(operator.add, reached))
+
+    carry = (params["embed"].astype(cfg.dtype)[tokens],
+             *(getattr(cache, field) for field in family.CARRIED))
+    at, n, runs_counted = {}, None if cfg.dense_mlp_hidden else 0, []
+    for kinds, repeats, scanned in cut(cfg):
+        if scanned:
+            def repeat(carry, r, kinds=kinds, at=at, n=n):
+                def from_(start, step):  # `start + r * step`, and no `0 +`
+                    return start + r * step if start else r * step
+
+                here = {kind: from_(at.get(kind, 0), kinds.count(kind))
+                        for kind in dict.fromkeys(kinds)}
+                return unit(kinds, carry, here, from_(n, len(kinds)))
+
+            carry, counted = lax.scan(repeat, carry, jnp.arange(repeats))
+            if counted is not None:
+                # a scan stacks what its unit counted over the repeats: in
+                # the layers' order the choices are repeat-major
+                load, choice, reached = counted
+                counted = (load.sum(0), choice.reshape(-1, *choice.shape[2:]),
+                           reached.sum(0))
+        else:
+            carry, counted = unit(kinds, carry, at, n)
+        for kind in dict.fromkeys(kinds):
+            at = {**at, kind: at.get(kind, 0) + repeats * kinds.count(kind)}
+        n = 0 if n is None else n + repeats * len(kinds)
+        if counted is not None:
+            runs_counted.append(counted)
+    aux = {}
+    if runs_counted:
+        loads, choices, reached = zip(*runs_counted)
+        aux = {"expert_load": functools.reduce(operator.add, loads),
+               "expert_choice": jnp.concatenate(choices),
+               "experts_counted": functools.reduce(operator.add, reached)}
+    return (lm_head(cfg, params, carry[0]),
+            cache._replace(**dict(zip(family.CARRIED, carry[1:]))), aux)

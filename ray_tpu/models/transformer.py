@@ -724,10 +724,6 @@ def _lora_out(xa, b, scale):
         return xa @ b.astype(xa.dtype) * scale
 
 
-def _lora_delta(x, a, b, scale):
-    return _lora_out(_lora_xa(x, a), b, scale)
-
-
 def _moe_mlp(cfg: TransformerConfig, y, p):
     """Top-k token-choice MoE with capacity drop (GShard/Mixtral recipe).
 
@@ -803,33 +799,23 @@ def _grouped_matmul(rows, weights, group_sizes, layer=None):
 def held_rows_cap(cfg: TransformerConfig, assignments: int):
     """How many of a call's `assignments` (T x k, static) `moe_dropless`
     gathers rows for where only a thin share of the router's outputs is held
-    here, or None: all of them. With 16 of 768 outputs held (zero-compute
-    ones among the rest) one assignment in 48 meets a weight, and the static
-    T x k layout gathered, multiplied as no group's and unsorted 48 rows for
-    it: 73 ms of a 4,096-token prefill's 402 and a decode step's 384 rows, too
-    many for the grouped kernel's one tile a group (my chip runs, PR 44);
-    with 16 of 256 held, 16 rows for it: 236 ms of an 8,192-row prefill's
-    468 (its grouped matmuls 105 of them) and 98 of 332 with the cap (my
-    chip runs, PR 59). The
-    sorted order has the held groups' rows FIRST, so the first `cap` rows
-    hold them all unless more than `cap` assignments are held:
+    here, or None: all of them. The static T x k layout gathers, multiplies
+    as no group's and unsorts a row for EVERY assignment, held or not: 48
+    rows for each that meets a weight at 16 of 768 held, 16 at 16 of 256.
+    The sorted order has the held groups' rows FIRST, so the first `cap`
+    rows hold them all unless more than `cap` assignments are held:
     `HELD_ROWS_ROOM` (four) times the even share, 64 at least, in whole
     tiles; a call that holds more (`lax.cond` on the count) takes the whole
     layout, so nothing is ever dropped. ONE rule, read off the held share
     and the call: the cap is taken where it leaves at most one row in
     `HELD_ROWS_ROOM` of the layout (a share of a sixteenth or thinner: the
-    `lax.cond` and the rows' sum by token, 1.2 ms a call of 8,192 rows, have
-    to be paid for) and, where the 64 rows decide, halves the rows at least.
-    A decode step's 256 -> 64 rows at a sixteenth buy nothing and cost
-    nothing (12.971 against 12.963 ms a step: the kernel's time is the
-    reached experts' bytes either way; PR 44's step gained because 384 rows
-    were more than the kernel's tile a group). An eighth (704 -> 352 rows a
-    step, its experts at 92% of their bytes already), a half and a
+    `lax.cond` and the rows' sum by token have to be paid for) and, where
+    the 64 rows decide, halves the rows at least. An eighth, a half and a
     configuration that holds every expert keep the whole layout and the
-    programs they had. Pad rows choose like any other and choose ALIKE: a
-    piece of mostly padding whose common choice holds three experts here
-    passes its cap and takes the whole layout (`layout_counted` counts
-    such calls)."""
+    programs they had (the readings: PERF.md section 6, PRs 44 and 59).
+    Pad rows choose like any other and choose ALIKE: a piece of mostly
+    padding whose common choice holds three experts here passes its cap and
+    takes the whole layout (`layout_counted` counts such calls)."""
     if cfg.experts_held is None:
         return None
     count, outputs = cfg.experts_held[1], cfg.num_experts + cfg.zero_experts

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops import attention as attention_ops
-from ray_tpu.ops.traced import TracedPaths
+from ray_tpu.ops import traced
 
 # Bytes of one block of an expert's matrix at most: a block is [bk, n], whole
 # rows of the matrix, so one contiguous piece of the stack. Two buffers a
@@ -37,10 +37,9 @@ BLOCK_BYTES = 5 << 18  # 1.25 MiB
 # need more keeps `lax.ragged_dot`.
 VMEM_BYTES = 96 << 20
 
-# The implementations ("kernel", "ragged_dot") that `grouped_matmul` chose:
-# `with paths_traced() as seen:` around a jitted program's trace.
-_paths = TracedPaths("grouped_matmul_paths")
-paths_traced = _paths.traced
+# The implementations ("kernel", "ragged_dot") that `grouped_matmul` chose
+# while the body ran: this choice's share of `traced.booked()`.
+paths_traced = functools.partial(traced.booked, "grouped_matmul")
 
 
 def row_tile(dtype) -> int:
@@ -297,7 +296,7 @@ def grouped_matmul(rows, weights, group_sizes, layer=None):
     if layer is None:
         mats, layer = tuple(w[None] for w in mats), 0
     kernel = takes(rows, mats[0], len(mats))
-    _paths.book("kernel" if kernel else "ragged_dot")
+    traced.book("grouped_matmul", "kernel" if kernel else "ragged_dot")
     if kernel:
         return _kernel_call(rows, mats, group_sizes, layer, gated)
     return _with_ragged_dot(rows, mats, group_sizes, layer, gated)

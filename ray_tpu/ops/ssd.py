@@ -29,21 +29,17 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops import attention as attention_ops
-from ray_tpu.ops.traced import TracedPaths
+from ray_tpu.ops import traced
 
 HI = lax.Precision.HIGHEST
 F32 = jnp.float32
-# Which spelling a program's state update ("state") and prefill recurrence
-# ("scan") were traced with, "kernel" or "plain": `with paths_traced() as
-# seen:` around the trace collects {"state:kernel", ...} (`engine_stats()`).
-_paths = TracedPaths("ssm_paths")
-paths_traced = _paths.traced
 
 
 def book(what: str, kernel: bool) -> None:
-    """The caller's pick for `what` ("state" or "scan"): the kernel or the
-    plain spelling."""
-    _paths.book(f"{what}:{'kernel' if kernel else 'plain'}")
+    """The caller's pick for a program's state update ("state") or prefill
+    recurrence ("scan"): the kernel or the plain spelling
+    (`traced.TOLD["ssm"]`)."""
+    traced.book("ssm", f"{what}:{'kernel' if kernel else 'plain'}")
 
 
 def ssm_state_update_takes(mat) -> bool:

@@ -1431,14 +1431,20 @@ def _program_text(program) -> str:
 # scalar adds and pads, no other instruction). A configuration without a
 # cap (Laguna's half) keeps the parent's program to the character. PR 61
 # sent the two latent families' PREFILLS to the flash forward
-# (`LATENT_PREFILLS` below holds them to it); their decode steps stay.
+# (`LATENT_PREFILLS` below holds them to it); their decode steps stay. PR 62
+# pinned Laguna's and Kimi-Linear's decode steps anew: the same instructions
+# in the same order on the same operands, and other NUMBERS in their names
+# (`%mul.1155` -> `%mul.1149`): the one layer loop (`pattern.forward_cached`)
+# traces fewer dead index computations than each family's own loop did
+# (`build/pr62/compare_texts.py` numbers the names anew and finds the texts
+# equal).
 UNCHANGED_PROGRAMS = {
     ("zaya1-8b-serve-d16", "prefill"): "b6e375849037b5ca",
     ("mistral7b-v03-serve-d16", "decode"): "f367d611b8b354af",
     ("olmoe-1b-7b-serve-d8", "decode"): "db5f0cb4da39e451",
     ("zaya1-8b-serve-d16", "decode"): "32de0ec26bb5d39f",
-    ("laguna-s-2.1-serve-ep2-d5", "decode"): "5ab54d08f545bb4f",
-    (KIMI_LINEAR, "decode"): "30e87476ec12a73c",
+    ("laguna-s-2.1-serve-ep2-d5", "decode"): "8c541fa29e4b51a8",
+    (KIMI_LINEAR, "decode"): "5257c4650a1f74e3",
     (LONGCAT, "decode"): "d19b0ea7c5f6ee58",
 }
 # a latent family's prefill -> its cell's bucket

@@ -130,6 +130,66 @@ def test_a_family_states_what_it_is_and_the_rest_reads_it(name, preset):
     assert batcher._kv_rows(lens) == hand_rows(name, cfg, lens)
 
 
+WINDOWS, PERIOD = ("window",) * 3, ("kda", "kda", "mla", "kda")
+# preset, overrides -> `pattern.cut`: [(unit, repeats, scanned)] as each
+# family's own loop cut its layers before `pattern.forward_cached` took the
+# loops over; None: the family keeps a `forward_cached` of its own
+CUTS = {
+    # the period form: the lead, ONE scan of the periods (of one period
+    # too), the tail as one unit in a row
+    ("laguna_debug", ()): [
+        (("full",), 1, False), ((*WINDOWS, "full"), 2, True)],
+    ("laguna_debug", (("layers", 5),)): [
+        (("full",), 1, False), ((*WINDOWS, "full"), 1, True)],
+    ("kimi_linear_debug", ()): [
+        (("kda",), 1, False), (PERIOD, 2, True), (("kda", "mla"), 1, False)],
+    ("kimi_linear_debug", (("layers", 9), ("tail_kinds", ()))): [
+        (("kda",), 1, False), (PERIOD, 2, True)],
+    # the list form: `runs` behind the first layer where it has the dense MLP
+    ("mimo_v2_debug", ()): [
+        (("full",), 1, False), (("window",), 2, True), (("full",), 1, False),
+        (("window",), 3, True)],
+    ("nemotron_h_debug", ()): [
+        (("ssm", "lmoe"), 2, True), (("ssm",), 1, False),
+        (("gqa",), 1, False), (("lmoe", "ssm"), 2, True),
+        (("gqa",), 1, False)],
+    ("jamba_debug", ()): [
+        (("ssm1", "mlp"), 2, True), (("gqa",), 1, False),
+        (("mlp", "ssm1"), 3, True), (("mlp",), 1, False),
+        (("gqa",), 1, False), (("mlp",), 1, False), (("ssm1",), 1, False),
+        (("mlp",), 1, False)],
+    ("longcat_debug", ()): None,
+}
+
+
+@pytest.mark.parametrize("preset, overrides", sorted(CUTS))
+def test_a_pattern_family_states_one_layer_and_the_loop_is_patterns(
+        preset, overrides):
+    from ray_tpu.models import pattern
+
+    cfg = T.config(preset, **dict(overrides))
+    family, want = families.of(cfg), CUTS[preset, overrides]
+    name = family.__name__.rsplit(".")[-1]
+    assert name in families.PATTERNS
+    if want is None:  # the one family named as keeping its own loop
+        assert name == "longcat" and callable(family.forward_cached)
+        assert not hasattr(family, "layer")
+        return
+    assert not hasattr(family, "forward_cached"), (
+        f"{name} has a layer loop of its own beside pattern.forward_cached")
+    assert callable(getattr(family, "layer", None)), (
+        f"{name} does not state its one layer (`layer`)")
+    # what it carries is what its layers keep, in the cache's own order
+    assert set(cfg.keeps) <= set(family.CARRIED) <= set(KVCache._fields)
+    assert family.CARRIED == tuple(
+        n for n in KVCache._fields if n in family.CARRIED)
+    got = pattern.cut(cfg)
+    assert got == want
+    assert sum((unit * n for unit, n, _ in got), ()) == cfg.kinds
+    # a lead is alone and never scanned; the list form scans what repeats
+    assert all(scanned == (n > 1) for _, n, scanned in got) or cfg.lead_kind
+
+
 @pytest.mark.parametrize("name, field", [
     (m, f) for m in MODULES
     for f in sorted(set(AWAY) - module(m).FIELDS)])
