@@ -782,17 +782,22 @@ def _grouped_matmul(rows, weights, group_sizes, layer=None):
     (226-805 MB a layer: 102-2,457 us a call beside the call's own 106-887
     at the four serve cells' decode shapes on the v5e, PERF.md PR 41).
 
-    What runs where (`ops/grouped_matmul.py`, which decides by the shapes it
-    is given): on a TPU a call of at most one tile of rows a group (a decode
-    step's 32-320 rows, a short prompt's) is a Pallas kernel that keeps the
-    rows in fast memory and streams each reached expert's matrix from
-    `weights[layer, expert]` in contiguous blocks of about 1 MB; `layer`
-    reaches it as a prefetched scalar, so its metadata is a layer's E groups.
-    Everywhere else, and for a long prompt's thousands of rows a group, it is
-    `lax.ragged_dot` over the stack VIEWED as L*E groups of which only that
-    layer's hold rows: the compiler's kernel spends 18-22 us a call on
-    metadata over 416-1,024 groups where 16-128 cost 1-6, which a prefill's
-    5 ms layer does not feel and a decode step's 0.1-0.9 ms call does."""
+    What runs where (`ops/grouped_matmul.py::takes`, which decides by the
+    shapes it is given): on a TPU a call of at most one tile of rows a group
+    (a decode step's 32-320 rows, a short prompt's) is a Pallas kernel that
+    keeps the rows in fast memory and streams each reached expert's matrix
+    from `weights[layer, expert]` in contiguous blocks of about 1 MB; a call
+    of more rows than that (a long prompt's 16,384 over 64 groups, a held
+    share's capped 1,024-8,192 over 16) is a second kernel that holds a
+    reached group's matrices whole and passes the group's rows by them a
+    tile of 256 at a time, a tile multiplied for ONE group (PR 63: the
+    sparse document cell's layer 1.8 ms where `lax.ragged_dot` took 3.1-4.6,
+    its values to the last bit). `layer` reaches both as a prefetched
+    scalar, so their metadata is a layer's E groups. Off a TPU, and for
+    widths that fill no lane, it is `lax.ragged_dot` over the stack VIEWED
+    as L*E groups of which only that layer's hold rows (the compiler's
+    kernel spends 18-22 us a call on metadata over 416-1,024 groups where
+    16-128 cost 1-6)."""
     return grouped_matmul(rows, weights, group_sizes, layer)
 
 
@@ -877,8 +882,9 @@ def moe_dropless(cfg: TransformerConfig, y, p, row_mask=None, layer=None,
     the T*k assignments are sorted by expert, their rows gathered, and the
     gate, up and down projections are grouped matmuls over the E groups of
     that sorted order, gate and up in one call and down in a second
-    (`_grouped_matmul`: a kernel cut to a decode step's few rows a group on a
-    TPU, `lax.ragged_dot` for a long prompt and off a TPU; with
+    (`_grouped_matmul`: on a TPU one of two kernels, cut to a decode step's
+    few rows a group or to a long prompt's many, `lax.ragged_dot` off a TPU;
+    with
     `cfg.expert_act` "relu2" an expert is two matrices, up and down, and
     ReLU squared between them); then the rows go
     back to token order and are summed with the router's weights. Shapes are

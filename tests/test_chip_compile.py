@@ -889,6 +889,37 @@ def _results(text):
         yield name, dtype, [int(d) for d in dims.split(",") if d], op
 
 
+def _prefill_passes_row_tiles(text, scopes, layer_stack=None) -> set:
+    """What a prefill program whose grouped matmuls take the kernel of row
+    tiles (`ops/grouped_matmul.py::_tiles_kernel`, PR 63) holds: they are
+    Mosaic calls that compiled for the described chip, `ragged_dot_gated_
+    tiles` and `ragged_dot_rows_tiles` (a family without a gate: the second
+    alone), each under a name the benchmark's readers select the expert
+    operations by (`moe_cost.EXPERT_OP`: `moe_expert_ms_per_prefill` and
+    `moe_experts_roofline` read the same work) and under `moe_experts`; no
+    `lax.ragged_dot` is left, and no instruction's result has one layer's
+    expert stack's size (`layer_stack` = held experts, hidden, expert width:
+    the stacks are read where they lie). -> the calls' names."""
+    from benchmarks import moe_cost, scope_ops
+
+    called = {scope_ops._INSTRUCTION.match(line)[1]
+              for line in text.splitlines()
+              if "tpu_custom_call" in line and "%ragged_dot" in line}
+    assert called and all(moe_cost.EXPERT_OP.search(op) for op in called)
+    assert {op.split(".")[0] for op in called} <= {
+        "ragged_dot_gated_tiles", "ragged_dot_rows_tiles"}, called
+    assert "ragged_dot_rows_tiles" in {op.split(".")[0] for op in called}
+    assert called <= set(scopes["moe_experts"])
+    assert "ragged-dot" not in text
+    if layer_stack:
+        held, k, n = layer_stack
+        for op_name, dtype, dims, op in _results(text):
+            assert not (math.prod(dims) == held * k * n
+                        and sorted(dims[-2:]) == sorted((k, n))), (
+                op_name, dims, op)
+    return called
+
+
 def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     """The `serve-kda-mla-rollout-long-out` deployment (Kimi-Linear at its
     published widths and depth, 16 of 256 experts, 32 slots x 4096): the
@@ -1039,11 +1070,16 @@ def test_state_space_serve_programs_compile_and_fit(serve_programs):
     assert all(moe_cost.EXPERT_OP.search(op) for op in experts)
     assert experts <= set(scopes["moe_experts"]) and "ragged-dot" not in text
     assert len(mosaic("decode_attention")) == 2  # the two attention layers
+    # the 512 bucket's 11,264 rows over 64 held groups (22 a group, most in
+    # none) pass the matrices a tile at a time: up and down, no gate
     assert serve_programs.grouped_paths[NEMOTRON_H] == {
-        "decode": "kernel", "prefill_512": "ragged_dot"}
+        "decode": "kernel", "prefill_512": "row_tiles"}
     assert serve_programs.attention_paths(NEMOTRON_H) == {
         "prefill_512": "dense"}
     pscopes = scope_ops.op_scopes(prefill.as_text(), runner.SCOPES)
+    tiles = _prefill_passes_row_tiles(prefill.as_text(), pscopes,
+                                      (64, 1024, 2688))
+    assert len(tiles) == 2 * 4  # up and down, a body with experts
     assert "ssm.prefill_scan" in pscopes and "ssm.state" not in pscopes
     # a chunk's masked decay matrix [128, 128] a head inside the scan
     assert re.search(r"f32\[128,128,8,16\]", prefill.as_text())
@@ -1221,8 +1257,11 @@ def test_sink_window_serve_programs_compile_and_fit(serve_programs):
     pscopes = scope_ops.op_scopes(ptext, runner.SCOPES)
     assert len(flash) == 2 and flash <= set(pscopes["attn.full"])
     assert serve_programs.attention_paths(MIMO) == {"prefill_8192": "flash"}
+    # the capped call's 8,192 rows and the whole layout's 32,768 over 16
+    # held groups both pass the matrices a tile at a time
     assert serve_programs.grouped_paths[MIMO] == {
-        "decode": "kernel", "prefill_8192": "ragged_dot"}
+        "decode": "kernel", "prefill_8192": "row_tiles"}
+    _prefill_passes_row_tiles(ptext, pscopes, (16, 4096, 2048))
     # a window layer's prefill in a band: [.., 128, 256] logits
     assert re.search(r"f32\[1,64,8,8,128,256\]", ptext)
     from ray_tpu.observability import schema
@@ -1301,6 +1340,14 @@ def test_longcat_serve_programs_compile(serve_programs):
     flash = _flash_forward_calls(ptext)
     assert len(flash) == 2, flash  # a double layer's two sublayers, scanned
     assert set(flash) <= set(pscopes["mla.attend"])
+    # the expert layer's 1,024-row pieces (12,288 assignments, capped to
+    # 1,024 rows; the whole layout beside it) pass the matrices a tile at a
+    # time, gate and up in two column blocks of 1,024
+    # (a step whose cap gives way, 384 rows over 16 groups, is neither
+    # kernel's shape: `lax.ragged_dot`, as before PR 63)
+    assert serve_programs.grouped_paths[LONGCAT] == {
+        "decode": "kernel+ragged_dot", "prefill_4096": "row_tiles"}
+    _prefill_passes_row_tiles(ptext, pscopes, (16, 6144, 2048))
     assert re.search(r"\[12288,6144\]", ptext)  # 1024 rows x 12 a call
     assert not re.search(r"\[49152,6144\]", ptext)
     leaves = len(jax.tree.leaves(jax.eval_shape(
@@ -1437,9 +1484,12 @@ def _program_text(program) -> str:
 # (`%mul.1155` -> `%mul.1149`): the one layer loop (`pattern.forward_cached`)
 # traces fewer dead index computations than each family's own loop did
 # (`build/pr62/compare_texts.py` numbers the names anew and finds the texts
-# equal).
+# equal). PR 63 pinned ZAYA1's 1,024 bucket anew: its 1,024 rows over 16
+# groups pass the experts' matrices with the kernel of row tiles, as every
+# sparse prefill does since (`_prefill_passes_row_tiles`); every DECODE
+# step here is the one PR 62 left.
 UNCHANGED_PROGRAMS = {
-    ("zaya1-8b-serve-d16", "prefill"): "b6e375849037b5ca",
+    ("zaya1-8b-serve-d16", "prefill"): "6fda4d4839491acf",
     ("mistral7b-v03-serve-d16", "decode"): "f367d611b8b354af",
     ("olmoe-1b-7b-serve-d8", "decode"): "db5f0cb4da39e451",
     ("zaya1-8b-serve-d16", "decode"): "32de0ec26bb5d39f",
@@ -1500,8 +1550,8 @@ def test_decode_multiplies_the_experts_with_the_kernel(serve_programs, name):
     `lax.ragged_dot` is left in the step; the cache stays aliased and the
     total under what the configuration's own test holds it to (the kernel's
     buffers are fast memory, not the program's). The 2,048-token prefill's
-    thousands of rows a group keep `lax.ragged_dot`, and the engine's
-    counter says both."""
+    thousands of rows a group take the kernel of row tiles
+    (`_prefill_passes_row_tiles`), and the engine's counter says both."""
     from benchmarks import moe_cost, scope_ops
 
     cfg, prefill, decode, cache = serve_programs(name)
@@ -1530,11 +1580,20 @@ def test_decode_multiplies_the_experts_with_the_kernel(serve_programs, name):
             if f != "lengths" and getattr(cache, f) is not None]
     assert decode.memory_analysis().alias_size_in_bytes >= _arg_bytes(kept)
     assert _total_bytes(decode) < limit
-    assert "ragged-dot" in prefill.as_text()
-    assert "%ragged_dot" not in prefill.as_text()
+    # the prefill's many rows a group (16,384 over 64; ZAYA1's 1,024 over
+    # 16; Laguna's 20,480 over 128 held; Kimi-Linear's capped 4,096 and whole
+    # 16,384 over 16 held) pass the matrices a tile at a time
     bucket = 1024 if name.startswith("zaya") else SEQ
     assert serve_programs.grouped_paths[name] == {
-        "decode": "kernel", f"prefill_{bucket}": "ragged_dot"}
+        "decode": "kernel", f"prefill_{bucket}": "row_tiles"}
+    ptext = prefill.as_text()
+    stack = ((cfg.experts_held or (0, cfg.num_experts))[1], cfg.hidden,
+             cfg.mlp_hidden)
+    assert math.prod(stack) == layer_stack
+    tiles = _prefill_passes_row_tiles(
+        ptext, scope_ops.op_scopes(ptext, ("moe_experts",)), stack)
+    assert {op.split(".")[0] for op in tiles} == {"ragged_dot_gated_tiles",
+                                                  "ragged_dot_rows_tiles"}
 
 
 def test_attend_cached_reads_the_cache_once(topo):
