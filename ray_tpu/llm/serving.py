@@ -85,6 +85,12 @@ def build_llm_deployment(config: LLMConfig):
             for booked in traced.TOLD.values():
                 if getattr(batcher, booked, None):
                     out[booked] = getattr(batcher, booked)
+            cfg = getattr(batcher, "cfg", None)
+            if cfg is not None:
+                # the passes a program runs its layers in, and the cache
+                # layers of K/V rows a sequence keeps for them
+                out.update(loop_steps=cfg.loop_steps,
+                           kv_layers_kept=cfg.full_layers)
             devices = jax.devices()
             mem = devices[0].memory_stats() or {}
             out.update(self._compiles)

@@ -192,6 +192,14 @@ class _TokenStream:
         return len(self._req.stream_q.queue)  # no lock: a reading, a token
 
 
+def _counted(aux: dict) -> list:
+    """What a program returns of `forward_cached`'s `aux`: what the expert
+    layers counted. A looped model's `exit_pdf` stays in the program: at the
+    threshold 1 no token's path depends on it, and the compiler drops the
+    gate with it."""
+    return [value for name, value in aux.items() if name != "exit_pdf"]
+
+
 class PrefillPrograms:
     """A prompt through the model, one compiled program per length bucket:
     all a prefill replica runs (`models/disagg_prefill.py`), and where the
@@ -245,7 +253,7 @@ class PrefillPrograms:
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
-        return last[0], *self._row_of(row_cache), *aux.values()
+        return last[0], *self._row_of(row_cache), *_counted(aux)
 
     @staticmethod
     def _row_of(row_cache: KVCache) -> tuple:
@@ -533,7 +541,7 @@ class ContinuousBatcher(PrefillPrograms):
         # given the mask); free rows stay put so a later install never
         # races a drifting length past max_len
         new_len = jnp.where(active_mask, cache.lengths + 1, cache.lengths)
-        return nxt, cache._replace(lengths=new_len), *aux.values()
+        return nxt, cache._replace(lengths=new_len), *_counted(aux)
 
     def _pad_row(self, row_k, row_v):
         """A prefilled row [L, S, kvH, D] out to max_len, as install takes

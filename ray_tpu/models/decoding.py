@@ -47,7 +47,7 @@ from jax import lax
 from ray_tpu.models import families
 from ray_tpu.models.transformer import (
     TransformerConfig, _gated, _lora_xa, _qk_norm, _qkv, _rms_norm, _rope,
-    moe_dropless,
+    exit_pdf, moe_dropless, pass_end,
 )
 from ray_tpu.ops import attention as attention_ops
 from ray_tpu.ops.attention import NEG_INF
@@ -338,6 +338,8 @@ def _attention_cached(cfg: TransformerConfig, x, p, lora, positions,
     k_cache, v_cache, held = access(k_cache, v_cache, k, v, positions)
     attn = attend_held(q, held, positions, kv_len_mask, rows)
     attn = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"].astype(attn.dtype))
+    if cfg.sandwich:
+        attn = _rms_norm(attn, p["ln_attn_post"], cfg.norm_eps)
     return x + attn, k_cache, v_cache
 
 
@@ -397,6 +399,8 @@ def _block_cached(cfg: TransformerConfig, x, p, lora, positions,
     with jax.named_scope("mlp"):
         act = _gated(y, p, lora, scale, lambda name: _lora_xa(y, lora[name]))
         out = jnp.einsum("bsm,mh->bsh", act, p["wo_mlp"].astype(act.dtype))
+        if cfg.sandwich:
+            out = _rms_norm(out, p["ln_mlp_post"], cfg.norm_eps)
     return (x + out, k_cache, v_cache, state, route), ()
 
 
@@ -431,7 +435,11 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     computed, not counted. A router that gives a token ONE expert adds
     {"expert_choice": int32 [L, B*S]}, every row's expert in every layer: a
     near-tie that rounding flips there is a whole other expert, so a
-    comparison with a reference has to know the route that was taken.
+    comparison with a reference has to know the route that was taken. A
+    looped model (`cfg.loop_steps` > 1) gives {"exit_pdf": float32 [passes,
+    B, S]}, the probability that a token leaves the loop at each pass
+    (`transformer.exit_pdf` of the exit gate's outputs); at the threshold 1
+    the logits are the last pass's whatever it says.
 
     `rows` [B] is a decode step's (S == 1) own statement of how many rows
     each sequence holds once its token is written, 0 for a slot that takes
@@ -462,15 +470,40 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
     if cfg.router == "zaya_mlp":  # the first layer's router adds nothing
         route = jnp.zeros((tokens.size, cfg.router_hidden), jnp.float32)
 
-    def body(carry, layer):
-        x, k_cache, v_cache, state, route = carry
-        return _block_cached(
-            cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
-            k_cache, v_cache, kv_len_mask, row_mask, layer["i"],
-            access(layer["i"]), state, route, rows)
+    def layers(carry, first=None):
+        """The ONE scan over the stacked layers. `first`: the cache layer of
+        this pass's layer 0 where the model runs its layers more than once
+        (None: a layer's rows are cache layer `i`, the program as it was)."""
+        def body(carry, layer):
+            x, k_cache, v_cache, state, route = carry
+            at = layer["i"] if first is None else first + layer["i"]
+            return _block_cached(
+                cfg, x, dict(layer["p"], **whole), layer.get("l"), positions,
+                k_cache, v_cache, kv_len_mask, row_mask, layer["i"],
+                access(at), state, route, rows)
 
-    (x, new_k, new_v, new_state, _), counted = lax.scan(
-        body, (x, cache.k, cache.v, cache.state, route), layer_tree)
+        return lax.scan(body, carry, layer_tree)
+
+    if cfg.loop_steps > 1:
+        # A looped model: the same stacked parameters every pass, read in
+        # place (the inner scan's body is compiled once and the outer loop is
+        # not unrolled); pass t's layer i writes and attends cache layer
+        # `t * layers + i` of the carried stacks, and the final norm's output
+        # is the next pass's input
+        def a_pass(carry, t):
+            (x, k_cache, v_cache, _, _), _ = layers(
+                (*carry, None, None), t * cfg.layers)
+            x, lam = pass_end(cfg, params, x)
+            return (x, k_cache, v_cache), lam
+
+        (x, new_k, new_v), lam = lax.scan(
+            a_pass, (x, cache.k, cache.v), jnp.arange(cfg.loop_steps))
+        # threshold 1 (`transformer.check`): every token leaves at the last
+        return (lm_head(cfg, params, x, normed=True),
+                KVCache(new_k, new_v, cache.lengths),
+                {"exit_pdf": exit_pdf(lam)})
+    (x, new_k, new_v, new_state, _), counted = layers(
+        (x, cache.k, cache.v, cache.state, route))
     aux = dict(zip(("expert_load", "expert_choice"), counted))
     if aux:
         aux["expert_load"] = aux["expert_load"].sum(0)
@@ -478,11 +511,13 @@ def forward_cached(cfg: TransformerConfig, params, tokens, positions,
             KVCache(new_k, new_v, cache.lengths, new_state), aux)
 
 
-def lm_head(cfg: TransformerConfig, params, x):
+def lm_head(cfg: TransformerConfig, params, x, normed: bool = False):
     """Every cached forward's end: the stream x [B, S, h] through the final
-    norm and the output matrix (the embedding's transpose where they are
-    tied) -> logits [B, S, V]."""
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    norm (`normed`: a looped model's last pass has applied it) and the
+    output matrix (the embedding's transpose where they are tied) -> logits
+    [B, S, V]."""
+    if not normed:
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     with jax.named_scope("lm_head"):
         unembed = params.get("unembed")
         if unembed is None:

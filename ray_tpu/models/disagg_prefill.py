@@ -40,8 +40,9 @@ _TRANSPORT_DTYPE = "float32"  # numpy has no bfloat16; rows are cast
 
 
 def _row_shape(cfg: TransformerConfig, max_len: int):
-    # [2 (k/v), L, max_len, kvH, D]
-    return (2, cfg.layers, max_len, cfg.kv_heads, cfg.hd)
+    # [2 (k/v), L, max_len, kvH, D]: the K/V layers a sequence keeps
+    # (`cfg.kept`: a looped model's every pass has its own)
+    return (2, cfg.full_layers, max_len, cfg.kv_heads, cfg.hd)
 
 
 @ray_tpu.remote(max_concurrency=1)

@@ -52,7 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ray_tpu.models.continuous_batching import ContinuousBatcher, _Request
+from ray_tpu.models.continuous_batching import (
+    ContinuousBatcher, _Request, _counted)
 from ray_tpu.models.decoding import (
     KVCache,
     SamplingParams,
@@ -233,7 +234,7 @@ class PagedBatcher(ContinuousBatcher):
         last = jnp.take_along_axis(
             logits, (length - prefix_len - 1)[:, None, None].repeat(
                 logits.shape[-1], -1), axis=1)[:, 0]
-        return last[0], *self._row_of(row), *aux.values()
+        return last[0], *self._row_of(row), *_counted(aux)
 
     def _install_impl(self, cache: KVCache, row_k, row_v, page_ids, slot,
                       length, *kept):
@@ -294,7 +295,7 @@ class PagedBatcher(ContinuousBatcher):
         # cached in them
         self.kv.clear()
         self._page_table[:] = 0
-        shape = (self.cfg.layers, self.kv.num_pages, self.page_size,
+        shape = (self.cfg.full_layers, self.kv.num_pages, self.page_size,
                  self.cfg.kv_heads, self.cfg.hd)
         return KVCache(jnp.zeros(shape, self.cfg.dtype),
                        jnp.zeros(shape, self.cfg.dtype),
@@ -371,7 +372,7 @@ class PagedBatcher(ContinuousBatcher):
         """Pages are gathered into a dense view of every slot before a
         step's attention reads them: all of it is read, whatever is held."""
         held, _ = super()._kv_rows(lens)
-        return held, self.cfg.layers * self.slots * self.max_len
+        return held, self.cfg.full_layers * self.slots * self.max_len
 
     def _pages_to_admit(self, n: int) -> int:
         """Pages an n-token prompt takes at admission: its own and the one

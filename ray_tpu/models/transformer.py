@@ -226,6 +226,20 @@ class TransformerConfig:
     # "swiglu", three matrices, `(silu(x Wg) * (x Wu)) Wd`; or "relu2", two,
     # `relu(x Wu)^2 Wd`
     expert_act: str = "swiglu"
+    # A LOOPED model of the one block (ByteDance/Ouro-2.6B, `total_ut_steps`):
+    # the SAME `layers` layers run `loop_steps` times a token, the final norm
+    # at the end of every pass and its output the next pass's input; a pass
+    # attends its OWN pass's rows, so a sequence keeps `loop_steps * layers`
+    # K/V layers (`kept`), pass t's layer i as cache layer `t * layers + i`.
+    # An exit gate (hidden -> 1 with a bias, `params["exit_w"]` / `["exit_b"]`)
+    # reads every pass's normed output: `forward_cached`'s `aux["exit_pdf"]`.
+    # `exit_threshold`: the cumulated exit probability at which a token
+    # leaves the loop; 1.0, every token runs every pass, is all that runs.
+    # `sandwich`: a second RMSNorm BEHIND each sublayer, on its output before
+    # the residual sum (`ln_attn_post`, `ln_mlp_post`).
+    loop_steps: int = 1
+    sandwich: bool = False
+    exit_threshold: float = 1.0
 
     def __post_init__(self):
         if self.attention not in ("gqa", "cca"):
@@ -370,10 +384,13 @@ class TransformerConfig:
         per_layer = h * (nh * hd) + 2 * h * (nkv * hd) + (nh * hd) * h + mlp + 2 * h
         if self.qk_norm:
             per_layer += nh * hd + nkv * hd
+        if self.sandwich:
+            per_layer += 2 * h
         if self.attention == "cca" or self.router == "zaya_mlp":
             per_layer += families.of(self).extra_params(self)
         emb = v * h * (1 if self.tie_embeddings else 2)
-        return l * per_layer + emb + h
+        gate = h + 1 if self.loop_steps > 1 else 0
+        return l * per_layer + emb + h + gate
 
 
 # The fields every family reads, or that no check has ever policed (the last
@@ -387,16 +404,38 @@ COMMON = frozenset({
     "routed_scale"})
 
 # -- the one block as a family (`families.py`): dense, Mixtral, OLMoE ---------
-FIELDS = frozenset({"tie_embeddings", "lora_rank", "qk_norm"})
+# `SHARED`: what the one block's other sublayers (`zaya.py`) read too; a
+# looped model's three fields are the one block's with its own sublayers alone
+SHARED = frozenset({"tie_embeddings", "lora_rank", "qk_norm"})
+FIELDS = SHARED | {"loop_steps", "sandwich", "exit_threshold"}
 
 
 def check(cfg: TransformerConfig) -> None:
-    """The one block needs nothing that its fields' types do not say."""
+    """The one block needs of its fields what their types do not say: of a
+    looped model, that every token runs every pass and no layer routes."""
+    if cfg.loop_steps < 1:
+        raise ValueError(f"loop_steps {cfg.loop_steps}: a model runs its "
+                         "layers once a token at least")
+    if cfg.exit_threshold < 1.0:
+        raise ValueError(
+            f"exit_threshold {cfg.exit_threshold} below 1: tokens of one "
+            "batch would leave the loop at different passes, and the rows of "
+            "the passes a token skipped, which later tokens' later passes "
+            "read, would never be written (decoding.forward_cached runs "
+            "every pass for every token: the threshold 1)")
+    if cfg.loop_steps > 1 and (cfg.num_experts or cfg.lora_rank):
+        raise ValueError(
+            f"loop_steps {cfg.loop_steps} with num_experts "
+            f"{cfg.num_experts} / lora_rank {cfg.lora_rank}: the looped block "
+            "is the dense one, served (what the expert layers count is a "
+            "pass's, and loss_fn has no loss over the exits for an adapter "
+            "to train under)")
 
 
 def kept(cfg: TransformerConfig, max_len: int) -> Tuple[Kept, ...]:
-    """Every layer's K/V rows, `max_len` a slot."""
-    return (Kept(("k", "v"), cfg.layers, max_len, (cfg.kv_heads, cfg.hd)),)
+    """Every layer's K/V rows of every pass, `max_len` a slot."""
+    return (Kept(("k", "v"), cfg.loop_steps * cfg.layers, max_len,
+                 (cfg.kv_heads, cfg.hd)),)
 
 
 # Presets: name -> field values; `config` constructs (the family's module
@@ -451,6 +490,15 @@ PRESETS: Dict[str, TransformerConfig] = {
         norm_eps=1e-5, tie_embeddings=True, num_experts=16,
         experts_per_token=1, norm_topk_prob=False, attention="cca",
         router="zaya_mlp", router_hidden=256, partial_rotary=0.5,
+    ),
+    # ByteDance/Ouro-2.6B's looped block at debug widths: 3 layers run twice a
+    # token (6 cache layers a sequence), 4 query heads = 4 KV heads, a norm
+    # behind each sublayer, the exit gate. The published widths are the
+    # benchmark's to build (benchmarks/runners/serve_ouro.py)
+    "ouro_debug": dict(
+        vocab_size=512, hidden=128, mlp_hidden=352, layers=3, heads=4,
+        kv_heads=4, max_seq=128, remat=False, rope_theta=1e6, norm_eps=1e-6,
+        loop_steps=2, sandwich=True, dtype=jnp.float32,
     ),
     "zaya_debug": dict(
         vocab_size=512, hidden=128, mlp_hidden=64, layers=3, heads=4,
@@ -605,6 +653,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     if cfg.qk_norm:
         blocks["ln_q"] = jnp.ones((l, nh * hd), pd)
         blocks["ln_k"] = jnp.ones((l, nkv * hd), pd)
+    if cfg.sandwich:
+        blocks["ln_attn_post"] = jnp.ones((l, h), pd)
+        blocks["ln_mlp_post"] = jnp.ones((l, h), pd)
     if cfg.attention == "cca" or cfg.router == "zaya_mlp":
         families.of(cfg).init_block_params(cfg, blocks, stack,
                                            jax.random.fold_in(key, 13))
@@ -628,6 +679,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         # keys[12]: own key — keys[8] seeds the MoE wo_mlp stack, and
         # sharing it would correlate the two inits (advisor finding, r1)
         params["unembed"] = _dense_init(keys[12], (h, v), pd, h)
+    if cfg.loop_steps > 1:  # the exit gate: hidden -> 1 with a bias
+        params["exit_w"] = _dense_init(jax.random.fold_in(key, 14), (h,), pd, h)
+        params["exit_b"] = jnp.zeros((1,), pd)
     if cfg.lora_rank:
         r = cfg.lora_rank
         def lz(shape):  # LoRA B starts at zero
@@ -656,6 +710,9 @@ def param_axes(cfg: TransformerConfig) -> Params:
     if cfg.qk_norm:
         block_axes.update({"ln_q": ("layers", "heads"),
                            "ln_k": ("layers", "kv_heads")})
+    if cfg.sandwich:
+        block_axes.update({"ln_attn_post": ("layers", "norm"),
+                           "ln_mlp_post": ("layers", "norm")})
     if cfg.attention == "cca" or cfg.router == "zaya_mlp":
         families.of(cfg).update_block_axes(cfg, block_axes)
     if cfg.num_experts:
@@ -679,6 +736,8 @@ def param_axes(cfg: TransformerConfig) -> Params:
     }
     if not cfg.tie_embeddings:
         axes["unembed"] = ("embed", "vocab")
+    if cfg.loop_steps > 1:
+        axes.update(exit_w=("norm",), exit_b=(None,))
     if cfg.lora_rank:
         axes["lora"] = {
             "wq_a": ("layers", "embed", "lora_rank"), "wq_b": ("layers", "lora_rank", "heads"),
@@ -1172,6 +1231,8 @@ def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
                     ring.rows_of_turn(attn, turn, "tensor", index), p),
                 "tensor"),
             attn, by_heads, rows, p, None, ("wo",))
+    if cfg.sandwich:
+        attn = _rms_norm(attn, p["ln_attn_post"], cfg.norm_eps)
     x = checkpoint_name(x + constrain(attn, STREAM), "resid_attn")
 
     y = _rms_norm(x, p["ln_mlp"], cfg.norm_eps)
@@ -1195,7 +1256,32 @@ def _block(cfg: TransformerConfig, x, layer_params, lora_params, positions,
 
                 out = _ringed(axes, mlp, carried(y, "wi_a"), rows, rows, p, lo,
                               ("wi_gate", "wi_up", "wo_mlp", "wi_b"))
+    if cfg.sandwich:
+        out = _rms_norm(out, p["ln_mlp_post"], cfg.norm_eps)
     return x + constrain(out, STREAM)
+
+
+def pass_end(cfg: TransformerConfig, params: Params, x):
+    """What a pass of a looped model adds behind its layers: the model's
+    final norm, whose output is the next pass's input (and the last pass's
+    the head's), and the exit gate on it. Returns (the normed stream, the
+    gate lam [B, S] in float32)."""
+    with jax.named_scope("loop.pass_end"):
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+        lam = jax.nn.sigmoid(
+            jnp.einsum("bsh,h->bs", x.astype(jnp.float32),
+                       params["exit_w"].astype(jnp.float32))
+            + params["exit_b"].astype(jnp.float32)[0])
+    return x, lam
+
+
+def exit_pdf(lam):
+    """The gates lam [passes, B, S] as the distribution over the pass a
+    token leaves at: p_t = lam_t prod_{j<t} (1 - lam_j), the last pass what
+    is left (its own gate is not read)."""
+    stay = jnp.cumprod(1.0 - lam[:-1], axis=0)  # still in the loop behind t
+    before = jnp.concatenate([jnp.ones_like(lam[:1]), stay[:-1]])
+    return jnp.concatenate([lam[:-1] * before, stay[-1:]])
 
 
 def _default_attn(cfg: TransformerConfig):
@@ -1270,15 +1356,23 @@ def forward(cfg: TransformerConfig, params: Params, tokens: jax.Array,
             h, _ = lax.scan(_remat(body_at(pos_local)), h, layers_local)
             return h
 
-        x = pipelined_layers(
-            mesh, apply_stage, layer_tree, x, positions,
-            num_microbatches or 2 * n_stage,
-            seq_axis=seq_axis,
-        )
+        def run_layers(x):
+            return pipelined_layers(
+                mesh, apply_stage, layer_tree, x, positions,
+                num_microbatches or 2 * n_stage,
+                seq_axis=seq_axis,
+            )
     else:
-        x, _ = lax.scan(_remat(body), x, layer_tree)
+        def run_layers(x):
+            return lax.scan(_remat(body), x, layer_tree)[0]
 
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    if cfg.loop_steps > 1:
+        # the same stacked layers every pass, the final norm between them
+        x, _ = lax.scan(
+            lambda x, _: (pass_end(cfg, params, run_layers(x))[0], None), x,
+            None, length=cfg.loop_steps)
+    else:
+        x = _rms_norm(run_layers(x), params["ln_f"], cfg.norm_eps)
     unembed = params.get("unembed")
     if unembed is None:
         unembed = params["embed"].T
@@ -1292,6 +1386,12 @@ def loss_fn(cfg: TransformerConfig, params: Params, batch: Dict[str, jax.Array],
             remat_kept: Tuple[str, ...] = REMAT_LADDER[0]) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Next-token cross-entropy. batch: tokens [B,S], optional loss_mask [B,S].
     Returns (loss, metrics)."""
+    if cfg.loop_steps > 1:
+        raise ValueError(
+            f"loop_steps {cfg.loop_steps}: a looped model is trained under a "
+            "loss over its exit distribution (every pass's cross-entropy "
+            "weighted by `exit_pdf`, and an entropy term), which loss_fn does "
+            "not have: it would train the last pass alone")
     tokens = batch["tokens"]
     # Forward over the FULL sequence (sequence-parallel shards must keep
     # S divisible by the mesh axis); shift at the logits instead.

@@ -52,7 +52,7 @@ from ray_tpu.models.families import Kept
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm, _rope
 
 # -- the family (`families.py`): the one block's fields, and the sublayers' ---------
-FIELDS = transformer.FIELDS | {"attention", "router", "partial_rotary"}
+FIELDS = transformer.SHARED | {"attention", "router", "partial_rotary"}
 
 
 def check(cfg: TransformerConfig) -> None:

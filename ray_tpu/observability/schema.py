@@ -279,6 +279,14 @@ PROGRAM_SCOPES = {
                 "row)",
     "scmoe.dense": "models/longcat.py: a double layer's two dense SwiGLU "
                    "MLPs",
+    "loop.pass_end": "models/transformer.py: what a pass of a looped model "
+                     "adds behind its layers: the model's final norm, whose "
+                     "output is the next pass's input, and the exit gate on "
+                     "it (a served program returns no `exit_pdf`, so the "
+                     "compiler keeps the norm alone). How many passes a "
+                     "program runs and how many cache layers a sequence "
+                     "keeps for them: `engine_stats()['loop_steps']` / "
+                     "`['kv_layers_kept']`",
     "attend_cached": "models/decoding.py: attention over the cached rows",
     "mlp": "the dense SwiGLU MLP",
     "lora": "models/transformer.py: an adapter's two matmuls",

@@ -483,7 +483,17 @@ LONGCAT = "longcat-flash-serve-ep32-d4"
 NEMOTRON_H = "nemotron-3-super-serve-ep8-d22"
 MIMO = "mimo-v2-flash-serve-ep16-d11"
 JAMBA = "jamba2-3b-serve-whole"
+OURO = "ouro-2.6b-serve-whole"
 PATTERN_CONFIGS = {
+    # Ouro-2.6B whole (the ONE block, looped; here and not among
+    # SERVE_CONFIGS because the tests that walk those hold 16 slots x 2048):
+    # its published widths, all 48 layers run four times, the whole
+    # vocabulary; 8 slots of 512 positions, the 256 bucket
+    OURO: dict(
+        name="ouro_debug", vocab_size=49152, hidden=2048, mlp_hidden=5632,
+        layers=48, heads=16, kv_heads=16, head_dim=128, max_seq=65536,
+        loop_steps=4, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, slots=8,
+        max_len=512, bucket=256),
     # MiMo-V2-Flash's published widths, layers 0-10 of 48 (full, 4 window,
     # full, 5 window), one chip's share of a layer that sixteen hold: 16 of
     # 256 experts, an eighth of the vocabulary; 32 slots of 10240 positions,
@@ -1089,6 +1099,72 @@ def test_state_space_serve_programs_compile_and_fit(serve_programs):
     from ray_tpu.observability import schema
 
     assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+def test_looped_serve_programs_compile_and_fit(serve_programs):
+    """The `serve-loop4-mha-problems-256-in-256-out` deployment (Ouro-2.6B
+    whole: 48 layers run four times a token, the whole vocabulary, 8 slots x
+    512): the decode step is given K/V stacks of 4 x 48 = 192 layers to keep
+    (6.44 GB aliased in to out) and reads the rows of cache layer t * 48 + i
+    with `decode_attention`; the stacked weights are read where they lie by
+    every pass: ONE layer body (one Mosaic call: neither loop is unrolled)
+    and no array of [4, 48, ...] or [192, ...] weights; its temporaries are
+    the three stacks `wq`, `wk`, `wv` re-tiled by head (the compiler moves a
+    layer's re-tiling, which a one-pass program does inside its body, out
+    of both loops: 1.2 GB rewritten every step, PERF.md section 7) and beside
+    them less than one cache layer (16.8 MB). The 256 bucket's prefill keeps
+    its scores dense (4 MiB a call: under `DENSE_SCORES_BYTES`, the one rule
+    every configuration's prefill reads), and both fit the chip."""
+    from benchmarks import harness, scope_ops
+    from ray_tpu.observability import schema
+    from ray_tpu.ops import attention as A
+
+    cfg, prefill, decode, cache = serve_programs(OURO)
+    assert cfg.num_params() == 2_667_974_657 and cfg.sparse_layers == 0
+    assert cfg.loop_steps == 4 and cfg.sandwich and cfg.full_layers == 192
+    assert cache.k.shape == cache.v.shape == (192, 8, 512, 16, 128)
+    assert cache.k.dtype == jnp.bfloat16 and cache.state is None
+    assert A.decode_attention_takes(cache.k, cache.v)
+    batcher = serve_programs.engine(OURO)[0]
+    assert batcher.decode_attention_path == {"decode": "kernel"}
+    assert serve_programs.attention_paths(OURO) == {"prefill_256": "dense"}
+    kept, a_layer = _arg_bytes((cache.k, cache.v)), _arg_bytes(cache.k) // 192
+    assert round(kept / 1e9, 2) == 6.44 and a_layer == 16_777_216
+    for name, program in (("prefill[256]", prefill), ("decode[8x512]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    retiled = 3 * 48 * 2048 * 16 * 128 * 2  # wq, wk, wv
+    assert m.temp_size_in_bytes < retiled + a_layer
+    assert _total_bytes(decode) < 13e9
+    assert _total_bytes(prefill) + kept < 14e9  # beside the engine's cache
+    text = decode.as_text()
+    for op_name, dtype, dims, op in _results(text):
+        assert dims[:2] != [4, 48] and not (
+            dims[:1] == [192] and dims[1:] != [8, 512, 16, 128]), (
+                op_name, dims, op)
+    attends = [op for op, _ in _pallas_calls(text)]
+    assert len(attends) == 1 and attends[0].startswith("decode_attention")
+    assert not _pallas_calls(prefill.as_text())
+    runner = harness.load_module("runners", "serve_ouro")
+    assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) == set(runner.SCOPES)
+    assert attends[0] in scopes["attend_cached"]
+    assert set(scope_ops.op_scopes(prefill.as_text(), runner.SCOPES)) \
+        >= set(runner.SCOPES) - {"sample"}
+    leaves = len(jax.tree.leaves(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0)))))
+    # a served program returns no `exit_pdf`: the gate's two leaves are no
+    # operand of either (at the threshold 1 no logit reads them)
+    assert _entry_parameters(decode) == leaves - 2 + 3 + 5
+    assert _entry_parameters(prefill) == leaves - 2 + 2
 
 
 def test_mamba1_serve_programs_compile_and_take_their_kernels(serve_programs):

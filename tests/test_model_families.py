@@ -17,7 +17,7 @@ from ray_tpu.ops.attention import decode_block
 
 # family module -> its debug preset(s)
 PRESETS = {
-    "transformer": ("debug", "moe_debug", "olmoe_debug"),
+    "transformer": ("debug", "moe_debug", "olmoe_debug", "ouro_debug"),
     "zaya": ("zaya_debug",),
     "laguna": ("laguna_debug", "mimo_v2_debug"),
     "kimi_linear": ("kimi_linear_debug",),
@@ -39,7 +39,8 @@ AWAY = dict(
     ssm_groups=4, ssm_state=8, ssm_conv=3, ssm_chunk=64, ssm_dt_rank=4,
     moe_latent=16,
     expert_act="relu2", window_kv_heads=2, value_dim=8, window_sink=True,
-    value_scale=0.5, window_partial_rotary=0.5)
+    value_scale=0.5, window_partial_rotary=0.5, loop_steps=2, sandwich=True,
+    exit_threshold=0.5)
 BATCH, MAX_LEN = 3, 32
 
 
@@ -65,9 +66,9 @@ def hand_rows(name: str, cfg, lens):
         return int((-(-rows // block) * block).sum())
 
     kv = cfg.kv_heads * cfg.hd
-    if name in ("transformer", "zaya"):
-        return (cfg.layers * int(lens.sum()),
-                cfg.layers * blocks(lens, MAX_LEN, kv))
+    if name in ("transformer", "zaya"):  # a looped model: a layer a pass
+        layers = cfg.loop_steps * cfg.layers
+        return layers * int(lens.sum()), layers * blocks(lens, MAX_LEN, kv)
     if name == "laguna":
         full, window = cfg.kinds.count("full"), cfg.kinds.count("window")
         ring = np.minimum(lens, cfg.window)
