@@ -84,7 +84,8 @@ from ray_tpu.observability import schema as spans
 from ray_tpu.observability.timeline import setup_phase
 from ray_tpu.observability.tracing import device_span
 from ray_tpu.ops import traced
-from ray_tpu.ops.attention import NEG_INF, decode_block
+from ray_tpu.ops.attention import (
+    NEG_INF, decode_block, decode_rows_copied)
 from ray_tpu.parallel.bootstrap import FirstCall
 
 
@@ -603,15 +604,13 @@ class ContinuousBatcher(PrefillPrograms):
         must read for sequences of `lens` rows (the step's own among them),
         summed over the layers that keep rows (`cfg.kept`: K and V one row;
         a ring holding its window at most, a latent layer one row a
-        position, a state none); and what the step's attention reads for
-        them: each slot's rows in whole blocks
-        (`ops.attention.decode_attention`; a free slot nothing). Before PR
+        position, a state none); and what the step's attention copies for
+        them: a slot's K and V rows in whole granules
+        (`ops.attention.decode_rows_copied`, which `decode_attention` copies
+        by; whole blocks before PR 65), a latent layer's in whole blocks
+        (`latent_decode_attention`); a free slot nothing. Before PR
         35 a step read `slots x max_len` a full layer and `slots x window`
         a window layer whatever was held."""
-        def in_blocks(rows, t, row_bytes):
-            block = decode_block(t, row_bytes)
-            return int((-(-rows // block) * block).sum())
-
         itemsize = jnp.dtype(self.cfg.dtype).itemsize
         held = read = 0
         for kept in self.cfg.kept(self.max_len):
@@ -619,8 +618,13 @@ class ContinuousBatcher(PrefillPrograms):
                 continue
             rows = np.minimum(lens, kept.rows)  # a ring holds its window
             held += kept.layers * int(rows.sum())
-            read += kept.layers * in_blocks(
-                rows, kept.rows, math.prod(kept.shape) * itemsize)
+            row_bytes = math.prod(kept.shape) * itemsize
+            if kept.fields == ("latent",):
+                block = decode_block(kept.rows, row_bytes)
+                copied = -(-rows // block) * block
+            else:
+                copied = decode_rows_copied(rows, kept.rows, row_bytes)
+            read += kept.layers * int(copied.sum())
         return held, read
 
     def _release(self, req: _Request) -> None:

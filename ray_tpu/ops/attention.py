@@ -24,6 +24,7 @@ this framework.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -704,21 +705,70 @@ def flash_attention_takes(q, k, v=None) -> bool:
 
 DECODE_BLOCK_ROWS = 512  # rows a block at most
 DECODE_BLOCK_BYTES = 2 << 20  # and bytes of K (and of V) a block at most
+# rows of at most this many bytes (2 KV heads of 128 bfloat16, ONE head) come
+# in blocks of up to this many rows: 512 of them are a copy of 256 KB or less
+DECODE_THIN_ROW_BYTES = 512
+DECODE_THIN_BLOCK_ROWS = 2048
+DECODE_GRANULE_ROWS = 16  # rows a copy of a last block starts and ends on
+DECODE_SUB_ROWS = 512  # rows a piece of a last block's products
 
 
 def decode_block(t: int, row_bytes: int) -> int:
     """Rows of one block of a slot of `t` rows of `row_bytes` (all KV heads):
-    at most 512 rows and 2 MiB (two buffers each of K and V: 8 MiB of VMEM),
-    a divisor of `t`. A slot reads whole blocks, so a smaller block wastes
-    fewer rows past a sequence's end and a larger one has fewer copies to
-    start and to wait for. On the v5e (my chip runs, PR 35: all layers of a
-    step, slots full / half full): 8 KV heads 725 / 550 GB/s of held rows
-    at 512 rows against 563 / 488 at 256; 16 KV heads 732 / 557 against 606 /
-    528; 2 KV heads 596 / 451 at 512, 707 / 439 at 1,024, 392 / 337 at 256."""
-    block = min(t, DECODE_BLOCK_ROWS, max(8, DECODE_BLOCK_BYTES // row_bytes))
+    at most 512 rows (2,048 of rows of 512 B or less) and 2 MiB (two buffers
+    each of K and V: 8 MiB of VMEM), a divisor of `t`. A block is what ONE
+    copy brings and what is attended to while the next is in flight; since
+    PR 65 it is not what a slot costs: the slot's LAST block is copied as
+    the rows it holds in whole granules (`decode_granule`) and multiplied as
+    the 512-row pieces that hold rows, so a larger block wastes nothing and
+    has fewer copies to start and to wait for.
+
+    On the v5e (my chip runs, PR 65, `build/pr65/prof.py`: one layer's call
+    alone under the profiler, the kernel's own microseconds a call; slots
+    full / half full / at the cell's fill; the parent copied whole blocks of
+    512 and multiplied a head at a time): 16 KV heads, 8 slots x 512 (Ouro's
+    136-504): parent 48.8 / 48.8 / 48.8, now 47.1 / 25.1 / 36.5 (the copies
+    alone 45.1 / 23.0 / 33.9 = 745 GB/s; a granule of 64: 38.7 at the
+    cell's fill); 16 slots x 2,048 (1,100-2,016): 359.0 / 181.7 / 331.3,
+    now 357.3 / 180.1 / 296.4. 8 KV heads, 16 x 2,048: 179.8 / 91.2 / 166.0,
+    now 178.9 / 90.3 / 148.6 (blocks of 1,024: 179.7 / 91.0 / 147.6, no
+    gain); 32 x 4,096 (1,040-3,024): 711.7 / 357.1 / 384.8, now 710.8 /
+    356.2 / 351.4. 4 KV heads, keys in two pieces, 32 x 10,240
+    (4,112-10,048): 1,333.3 / 668.5 / 936.5, now 1,332.8 / 668.0 / 912.5.
+    2 KV heads (rows of 512 B), 32 x 2,048 (530-2,030; 272-1,520): 108.3 /
+    55.7 / 81.0; 61.2, at 512 rows 100.8 / 51.3 / 73.2; 54.5, at 1,024 89.9
+    / 45.6 / 62.9; 50.0, at 2,048 90.3 / 45.7 / 57.5; 44.5 (with products
+    over the whole block 90.3 / 47.5 / 59.0; 48.7). ONE KV head (256 B), 16 x
+    12,288 (4,112-10,560): 214.8 / 107.8 / 143.5, at 512 rows 216.6 / 108.7
+    / 145.1, at 1,024 156.5 / 79.0 / 108.0, at 2,048 134.5 / 68.0 / 93.6, at
+    4,096 134.9 / 72.3 / 95.1. Hence 2,048 for rows of 512 B or less (over
+    10% at every fill) and 512 for the rest (rows of 1 KB: not measured)."""
+    rows = DECODE_THIN_BLOCK_ROWS if row_bytes <= DECODE_THIN_ROW_BYTES \
+        else DECODE_BLOCK_ROWS
+    block = min(t, rows, max(8, DECODE_BLOCK_BYTES // row_bytes))
     while t % block:
         block //= 2
     return block
+
+
+def decode_granule(block: int) -> int:
+    """Rows that a copy of `decode_attention` starts and ends on in blocks
+    of `block` rows: 16, where the block is whole 16s (every stack of ONE
+    2-byte KV head, whose positions lie two to a sublane, 16 to a tile:
+    `_one_head`; every slot of whole 16s), else 8 (`decode_attention_takes`
+    slots of whole 8s: a position's heads are whole tiles there and a copy
+    could start on any; 8 keeps a last block to 6 copies)."""
+    return math.gcd(block, DECODE_GRANULE_ROWS)
+
+
+def decode_rows_copied(rows, t: int, row_bytes: int):
+    """Rows of K (and of V) that `decode_attention` copies for slots that
+    hold `rows` (an int array) of `t` rows of `row_bytes`: a slot's rows in
+    whole granules, whatever block they end in. The kernel rounds by the
+    same `decode_granule`, and `engine_stats()["kv_rows_read"]` books
+    this."""
+    granule = decode_granule(decode_block(t, row_bytes))
+    return -(-rows // granule) * granule
 
 
 def decode_attention_takes(stack, v_stack=None) -> bool:
@@ -781,7 +831,7 @@ def whole_keys(k, kvh: int):
 
 
 def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
-                             block, sink, sm_scale):
+                             block, granule, sub, sink, sm_scale):
     """q_ref [B, kvH, R, D] / o_ref [B, kvH, R, Dv] in VMEM (a KV head's
     `rep` query heads padded to R rows); k_hbm [N, B, T, kvH * chunks, D /
     chunks] / v_hbm [N, B, T, kvH, Dv] the stacks where XLA keeps them (keys
@@ -793,9 +843,14 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
     of one, so that it is in every denominator and adds no value. ONE
     invocation walks the
     slots in order and each slot's `ceil(rows / block)` blocks, the next
-    block's copy (the same slot's, or block 0 of the next slot that holds a
-    row) in flight while this one is attended to: a slot without rows starts
-    no copy at all."""
+    block's copies (the same slot's, or block 0 of the next slot that holds
+    a row) in flight while this one is attended to: a slot without rows
+    starts no copy at all. A block that the slot's rows fill goes in one
+    copy; the LAST block goes as the rows it holds, rounded up to whole
+    `granule`s, in the binary pieces of that count (`each_copy`: on the
+    block's two semaphores, each piece waited for as it was started), and
+    its products run over the whole `sub`-row pieces that hold rows (a block
+    of at most `sub` rows: over all of it, the buffer's stale rows masked)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -810,17 +865,43 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
     layer = layer_ref[0]
     # 2-byte rows lie in pairs of KV heads, one 32-bit word a pair and lane
     packing = 1 if flat else 4 // kbuf.dtype.itemsize
+    # the sizes a last block's copies come in, largest first
+    pieces = [granule << i for i in range(block.bit_length())
+              if granule << i < block][::-1]
+    # the rows a block's products may run over: whole `sub`s, then all
+    extents = list(range(sub, block, sub)) + [block]
 
-    def copies(b, j, buf):
-        at = pl.ds(pl.multiple_of(j * block, block), block)
-        return (pltpu.make_async_copy(k_hbm.at[layer, b, at], kbuf.at[buf],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[layer, b, at], vbuf.at[buf],
-                                      sem.at[1, buf]))
+    def each_copy(b, j, buf, do):
+        """`do` (start, or wait for) every copy of block j of slot b into
+        buffer `buf`, the rows the slot holds there in whole granules: ONE
+        of the whole block where they fill it; else one a set bit of that
+        count, each at the sum of the larger ones, so that every copy's
+        size is static."""
+        n = jnp.minimum(
+            block, (rows_ref[b] - j * block + granule - 1) // granule * granule)
+
+        def copy(at, size):
+            aligned = math.gcd(block, size)
+            src = pl.ds(pl.multiple_of(j * block + at, aligned), size)
+            dst = pl.ds(pl.multiple_of(at, aligned), size)
+            do(pltpu.make_async_copy(k_hbm.at[layer, b, src],
+                                     kbuf.at[buf, dst], sem.at[0, buf]))
+            do(pltpu.make_async_copy(v_hbm.at[layer, b, src],
+                                     vbuf.at[buf, dst], sem.at[1, buf]))
+
+        @pl.when(n == block)
+        def _():
+            copy(0, block)
+
+        @pl.when(n < block)
+        def _():
+            for size in pieces:
+                @pl.when(n & size != 0)
+                def _():
+                    copy(n & -(2 * size), size)
 
     def start(b, j, buf):
-        for copy in copies(b, j, buf):
-            copy.start()
+        each_copy(b, j, buf, lambda copy: copy.start())
 
     def holds_rows_from(b):
         """The first slot >= b that holds a row; `n_slots` if none does."""
@@ -829,21 +910,22 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
             & (rows_ref[jnp.minimum(i, n_slots - 1)] == 0),
             lambda i: i + 1, b)
 
-    def heads_of(ref, g0, keep=None):
-        """The rows [block, D] of KV heads g0 .. g0 + packing - 1 of one
-        buffer [block, kvH, D]: a strided read of the heads' sublanes, and
-        for 2-byte rows the two halves of each word. Rows outside `keep`
-        [block, D] come out as zeros whatever the buffer holds there."""
+    def heads_of(ref, g0, size, keep=None):
+        """The first `size` rows [size, D] of KV heads g0 .. g0 + packing -
+        1 of one buffer [block, kvH, D]: a strided read of the heads'
+        sublanes, and for 2-byte rows the two halves of each word. Rows
+        outside `keep` [size, D] come out as zeros whatever the buffer holds
+        there."""
         if flat:
-            rows = ref[...]
+            rows = ref[:size, :]
             return [rows if keep is None else jnp.where(keep, rows, 0)]
         heads = ref.shape[1]  # a position's rows: KV heads, or their pieces
         joined = ref.reshape(block * heads, ref.shape[-1])
         if packing == 1:
-            rows = joined[pl.ds(g0, block, stride=heads), :]
+            rows = joined[pl.ds(g0, size, stride=heads), :]
             return [rows if keep is None else jnp.where(keep, rows, 0)]
         words = joined.bitcast(jnp.uint32)[
-            pl.ds(g0 // 2, block, stride=heads // 2), :]
+            pl.ds(g0 // 2, size, stride=heads // 2), :]
         if keep is not None:
             words = jnp.where(keep, words, jnp.uint32(0))
         return [pltpu.bitcast(half, jnp.float32).astype(ref.dtype)
@@ -860,6 +942,51 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
         n_blocks = pl.cdiv(rows, block)
         after = holds_rows_from(b + 1)
 
+        def attend_rows(j, buf, size, state):
+            """The first `size` rows of block j, in buffer `buf`, into the
+            running (maximum, sum, accumulator) [kvH * R, 1 | 1 | Dv] of
+            all KV heads, the heads down the rows: every head's logits
+            first, ONE maximum, exponential and sum over all of them, then
+            every head's weighted sum. A head at a time (until PR 65), each
+            head's chain of matrix unit, reduction and exponential waited
+            for the head before: 34.9 us of products a call at 8 slots x
+            512 rows x 16 heads against 17.1 (my chip runs, PR 65)."""
+            m, l, acc = state
+            # what a block holds past the slot's rows is someone else's or
+            # stale: its logits are masked, and its V rows are zeros (a
+            # probability of zero does not clear a NaN)
+            held = (j * block + lax.broadcasted_iota(
+                jnp.int32, (kvh * r, size), 1)) < rows
+            v_held = (j * block + lax.broadcasted_iota(
+                jnp.int32, (size, dv), 0)) < rows
+            logits = []
+            for g0 in range(0, kvh, packing):
+                ks = [k for idx in range(g0 * chunks, (g0 + packing) * chunks,
+                                         packing)
+                      for k in heads_of(kbuf.at[buf], idx, size)]
+                for i, g in enumerate(range(g0, g0 + packing)):
+                    logits.append(functools.reduce(jnp.add, [lax.dot_general(
+                        q_ref[b, g] if chunks == 1 else
+                        q_ref[b, g, :, c * _LANES:(c + 1) * _LANES],
+                        ks[i * chunks + c], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                        for c in range(chunks)]))
+            s = jnp.concatenate(logits, axis=0) * sm_scale
+            s = jnp.where(held, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + p.sum(axis=-1, keepdims=True)
+            p = p.astype(vbuf.dtype)
+            weighted = []
+            for g0 in range(0, kvh, packing):
+                vs = heads_of(vbuf.at[buf], g0, size, v_held)
+                for g, v in zip(range(g0, g0 + packing), vs):
+                    weighted.append(jnp.dot(
+                        p[g * r:(g + 1) * r], v,
+                        preferred_element_type=jnp.float32))
+            return m_new, l, acc * alpha + jnp.concatenate(weighted, axis=0)
+
         def attend(j, carry):
             buf, state = carry
             more = j + 1 < n_blocks
@@ -872,50 +999,30 @@ def _decode_attention_kernel(layer_ref, rows_ref, q_ref, k_hbm, v_hbm, *rest,
             def _():
                 start(after, 0, 1 - buf)
 
-            for copy in copies(b, j, buf):
-                copy.wait()
-            # what a block holds past the slot's rows is someone else's or
-            # stale: its logits are masked, and its V rows are zeros (a
-            # probability of zero does not clear a NaN)
-            held = (j * block + lax.broadcasted_iota(
-                jnp.int32, (r, block), 1)) < rows
-            v_held = (j * block + lax.broadcasted_iota(
-                jnp.int32, (block, dv), 0)) < rows
-            new_state = []
-            for g0 in range(0, kvh, packing):
-                ks = [k for at in range(g0 * chunks, (g0 + packing) * chunks,
-                                        packing)
-                      for k in heads_of(kbuf.at[buf], at)]
-                vs = heads_of(vbuf.at[buf], g0, v_held)
-                for i, (g, v) in enumerate(zip(range(g0, g0 + packing), vs)):
-                    m, l, acc = state[g]
-                    s = functools.reduce(jnp.add, [lax.dot_general(
-                        q_ref[b, g] if chunks == 1 else
-                        q_ref[b, g, :, c * _LANES:(c + 1) * _LANES],
-                        ks[i * chunks + c], (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                        for c in range(chunks)]) * sm_scale
-                    s = jnp.where(held, s, NEG_INF)
-                    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-                    p = jnp.exp(s - m_new)
-                    alpha = jnp.exp(m - m_new)
-                    l = l * alpha + p.sum(axis=-1, keepdims=True)
-                    acc = acc * alpha + jnp.dot(
-                        p.astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
-                    new_state.append((m_new, l, acc))
-            return 1 - buf, tuple(new_state)
+            each_copy(b, j, buf, lambda copy: copy.wait())
+            if len(extents) == 1:
+                return 1 - buf, attend_rows(j, buf, block, state)
+            # over the shortest of `extents` that holds the block's rows,
+            # each ONE chain of products: a loop of pieces made every
+            # piece's chain of matrix unit, maximum and exponential wait for
+            # the piece before (71 us a call for 54, full slots, PR 65)
+            return 1 - buf, lax.switch(
+                pl.cdiv(jnp.minimum(block, rows - j * block), sub) - 1,
+                [functools.partial(attend_rows, j, buf, size)
+                 for size in extents], state)
 
-        empty = (jnp.full((r, 1), NEG_INF, jnp.float32),
-                 jnp.zeros((r, 1), jnp.float32),
-                 jnp.zeros((r, dv), jnp.float32))
-        start_from = (empty,) * kvh
+        start_from = (jnp.full((kvh * r, 1), NEG_INF, jnp.float32),
+                      jnp.zeros((kvh * r, 1), jnp.float32),
+                      jnp.zeros((kvh * r, dv), jnp.float32))
         if sink:
-            start_from = tuple((sink_ref[g][:, :1], jnp.ones_like(empty[1]),
-                                empty[2]) for g in range(kvh))
-        buf, state = lax.fori_loop(0, n_blocks, attend, (buf, start_from))
-        for g, (_, l, acc) in enumerate(state):  # no row held: zeros
-            o_ref[b, g] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            start_from = (jnp.concatenate(
+                [sink_ref[g][:, :1] for g in range(kvh)], axis=0),
+                jnp.ones_like(start_from[1]), start_from[2])
+        buf, (_, l, acc) = lax.fori_loop(0, n_blocks, attend,
+                                         (buf, start_from))
+        out = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        for g in range(kvh):  # no row held: zeros
+            o_ref[b, g] = out[g * r:(g + 1) * r]
         return buf
 
     lax.fori_loop(0, n_slots, slot, 0)
@@ -937,9 +1044,12 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
     softmax's denominator and carries no value.
 
     A Pallas kernel. `layer` and `rows` are scalar-prefetch operands and
-    the stacks stay in HBM: slot b's `ceil(rows[b] / block)` blocks of K and
-    of V are copied from [layer, b, block] and nothing else is read, no
-    layer is cut out of its stack and no [B, T] logits exist. Operands in
+    the stacks stay in HBM: slot b's first `decode_rows_copied(rows[b])`
+    rows of K and of V (its rows in whole granules: 16 rows, 8 in a slot
+    that is not whole 16s, `decode_granule`) are copied from [layer, b], a
+    block (`decode_block`) at a time and the last block in a few pieces,
+    and nothing else is read, no layer is cut out of its stack and no [B, T]
+    logits exist; a free slot costs nothing. Operands in
     the cache's dtype, products summed in float32, the running maximum, sum
     and accumulator float32 across blocks; the probabilities enter the
     product with V in the cache's dtype, as the MXU takes them from
@@ -966,7 +1076,9 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
             ((0, 0), (0, r - rep)))[:, :, None], (kvh, r, _LANES)),)
     out = pl.pallas_call(
         functools.partial(
-            _decode_attention_kernel, block=block, sink=sink is not None,
+            _decode_attention_kernel, block=block,
+            granule=decode_granule(block), sub=DECODE_SUB_ROWS,
+            sink=sink is not None,
             sm_scale=d ** -0.5 if sm_scale is None else sm_scale),
         name="decode_attention",
         out_shape=jax.ShapeDtypeStruct((b, kvh, r, dv), q.dtype),
@@ -986,7 +1098,8 @@ def decode_attention(q, k_stack, v_stack, layer, rows,
             ],
         ),
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      # a copy is started for every block counted here and waited for
+      # copies are started, and waited for, by the rows counted here: none
+      # past a slot's end (rows of 0 .. t), none for a slot without rows
       jnp.clip(rows.astype(jnp.int32), 0, t), q4, k_stack, v_stack, *sinks)
     return out[:, :, :rep].reshape(b, h, dv)
 

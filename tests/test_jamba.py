@@ -52,6 +52,7 @@ def kernels_through_the_interpreter(monkeypatch):
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(A, "DECODE_BLOCK_ROWS", 16)
+    monkeypatch.setattr(A, "DECODE_THIN_BLOCK_ROWS", 16)
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
     return A

@@ -275,6 +275,7 @@ def test_the_latent_kernel_reads_the_held_rows(monkeypatch):
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(A, "DECODE_BLOCK_ROWS", 16)
+    monkeypatch.setattr(A, "DECODE_THIN_BLOCK_ROWS", 16)
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
     n, t, width, value, layer, heads = 3, 64, 256, 128, 1, 6

@@ -88,6 +88,7 @@ def kernels_through_the_interpreter(monkeypatch):
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(A, "DECODE_BLOCK_ROWS", 16)
+    monkeypatch.setattr(A, "DECODE_THIN_BLOCK_ROWS", 16)
     monkeypatch.setattr(A, "DENSE_SCORES_BYTES", 0)
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
@@ -134,6 +135,92 @@ def test_decode_attention_reads_the_rows_held_and_no_others(
     _close(got[live], want[live], dtype)
     assert not np.asarray(got[~live], np.float32).any()
     assert A.decode_block(t, kv_heads * d * q.dtype.itemsize) == block
+
+
+# heads, KV heads, pieces of a key (keys of 256 beside values of 128: 2), a sink
+TAIL_LAYOUTS = {
+    "group-of-4-on-8": (32, 8, 1, False), "group-of-1-on-16": (16, 16, 1, False),
+    "group-of-4-on-2": (8, 2, 1, False), "group-of-6-on-8": (48, 8, 1, False),
+    "group-of-9-on-8": (72, 8, 1, False), "one-kv-head": (20, 1, 1, False),
+    "keys-in-two-pieces": (8, 4, 2, False),
+    "keys-in-two-pieces-and-a-sink": (16, 8, 2, True)}
+
+
+@pytest.mark.parametrize("layout,dtype", [
+    (layout, dtype) for layout in sorted(TAIL_LAYOUTS)
+    for dtype in (jnp.float32, jnp.bfloat16)
+    # ONE KV head's layout is a 2-byte stack's
+    if (layout, dtype) != ("one-kv-head", jnp.float32)])
+def test_decode_attention_copies_and_multiplies_a_last_block_by_its_rows(
+        kernels_through_the_interpreter, monkeypatch, layout, dtype):
+    """Blocks of 64 rows, products in pieces of 32, copies in granules of
+    16: a slot's LAST block is copied as the granules it holds (the binary
+    pieces of that count) and multiplied as the whole pieces that hold
+    rows. Slots on every edge of that walk (no row, 1, a granule - 1, a
+    granule, + 1, a piece - 1, a piece, + 1, a block - 1, a block, + 1,
+    t - 1, t), every head layout of the serve configurations, ONE KV head,
+    keys in pieces, a sink: against `_attend_cached` on the same stack.
+    What lies beyond a slot's rows is NaN in the stack, and what a buffer
+    keeps from the block before is the other slots' rows: neither reaches
+    the output."""
+    A = kernels_through_the_interpreter
+    heads, kv_heads, pieces, sink = TAIL_LAYOUTS[layout]
+    block, piece = 64, 32
+    monkeypatch.setattr(A, "DECODE_BLOCK_ROWS", block)
+    monkeypatch.setattr(A, "DECODE_THIN_BLOCK_ROWS", block)
+    monkeypatch.setattr(A, "DECODE_SUB_ROWS", piece)
+    n, t, d, dv, layer = 2, 192, 128 * pieces, 128, 1
+    assert A.decode_block(
+        t, kv_heads * d * jnp.dtype(dtype).itemsize) == block
+    g = A.decode_granule(block)
+    assert g == 16
+    rows = jnp.asarray([0, 1, g - 1, g, g + 1, piece - 1, piece, piece + 1,
+                        block - 1, block, block + 1, 2 * block + g + 3,
+                        t - 1, t], jnp.int32)
+    b = rows.shape[0]
+    ks = jax.random.split(jax.random.key(heads), 4)
+    q = jax.random.normal(ks[0], (b, heads, d), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (n, b, t, kv_heads, d), jnp.float32
+                          ).astype(dtype)
+    v = jax.random.normal(ks[2], (n, b, t, kv_heads, dv), jnp.float32
+                          ).astype(dtype)
+    s = jax.random.normal(ks[3], (heads,), jnp.float32) if sink else None
+    held = jnp.arange(t)[None, :] < rows[:, None]
+    want = _attend_cached(q[:, None], k[layer], v[layer],
+                          jnp.full((b, 1), t), held, s)[:, 0]
+    stale = ~held[None, :, :, None, None]
+    stack = A.key_pieces(jnp.where(stale, jnp.nan, k), dv)
+    assert A.decode_attention_takes(stack, v)
+    got = jax.jit(A.decode_attention)(
+        q, stack, jnp.where(stale, jnp.nan, v), jnp.int32(layer), rows,
+        sink=s)
+    assert got.shape == (b, heads, dv) and got.dtype == q.dtype
+    live = np.asarray(rows) > 0
+    _close(got[live], want[live], dtype)
+    assert not np.asarray(got[~live], np.float32).any()
+
+
+@pytest.mark.parametrize("t,row_bytes,granule", [
+    (512, 4096, 16), (2048, 512, 16), (10240, 2048, 16), (12288, 256, 16),
+    (24, 1024, 8), (40, 4096, 8)])
+def test_decode_attention_copies_whole_granules_and_no_block(
+        t, row_bytes, granule):
+    """What `_kv_rows` books and the kernel's wrapper rounds by is ONE
+    function: a slot's rows rounded up to the granule of its blocks (16
+    rows, 8 where a slot is not whole 16s), so no slot costs more than its
+    rows and a granule less one, whatever block they end in."""
+    from ray_tpu.ops import attention as A
+
+    block = A.decode_block(t, row_bytes)
+    g = A.decode_granule(block)
+    assert g == granule and block % g == 0 and t % block == 0
+    rows = np.array([0, 1, g - 1, g, g + 1, block - 1, block, block + 1,
+                     t // 2 - 1, t - g - 1, t - 1, t])
+    rows = rows[(rows >= 0) & (rows <= t)]
+    copied = A.decode_rows_copied(rows, t, row_bytes)
+    assert copied.sum() == sum(-(-r // g) * g for r in rows.tolist())
+    assert (copied >= rows).all() and (copied <= rows + g - 1).all()
+    assert (copied <= t).all() and copied[0] == 0
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -212,14 +299,15 @@ def test_the_decode_step_with_the_kernel_gives_the_xla_steps_tokens(
     assert [len(g) for g in got] == [m for _, _, m in arrivals]
     assert stats["steps"] >= 12 and stats["max_active"] == slots
 
-    def rows_of(t, block):  # (held, read) over every step of every request
+    def rows_of(t, granule):  # (held, read) over every step of every request
         lens = np.concatenate([np.minimum(np.arange(n + 1, n + m), t)
                                for _, n, m in arrivals])
-        return np.array([lens.sum(), (-(-lens // block) * block).sum()])
+        return np.array([lens.sum(), (-(-lens // granule) * granule).sum()])
 
     total = cfg.full_layers * rows_of(max_len, 16)
-    if cfg.window_layers:
-        total += cfg.window_layers * rows_of(cfg.window, cfg.window)
+    if cfg.window_layers:  # a ring of 16 rows is one granule
+        total += cfg.window_layers * rows_of(
+            cfg.window, A.decode_granule(cfg.window))
     assert (stats["kv_rows_held"], stats["kv_rows_read"]) == tuple(total)
     assert stats["kv_rows_read"] < stats["steps"] * slots * (
         cfg.full_layers * max_len + cfg.window_layers * cfg.window)

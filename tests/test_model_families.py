@@ -61,26 +61,26 @@ def test_the_table_and_the_presets_cover_each_other():
 
 def hand_rows(name: str, cfg, lens):
     """(held, read) of `_kv_rows` by each family's own arithmetic."""
-    def blocks(rows, t, width):
+    def blocks(rows, t, width):  # a latent layer's rows: whole blocks
         block = decode_block(t, width * jnp.dtype(cfg.dtype).itemsize)
         return int((-(-rows // block) * block).sum())
 
-    kv = cfg.kv_heads * cfg.hd
+    def granules(rows, t):  # K and V rows: whole 16s (8s in a ring of 8)
+        granule = 16 if t % 16 == 0 else 8
+        return int((-(-rows // granule) * granule).sum())
+
     if name in ("transformer", "zaya"):  # a looped model: a layer a pass
         layers = cfg.loop_steps * cfg.layers
-        return layers * int(lens.sum()), layers * blocks(lens, MAX_LEN, kv)
+        return layers * int(lens.sum()), layers * granules(lens, MAX_LEN)
     if name == "laguna":
         full, window = cfg.kinds.count("full"), cfg.kinds.count("window")
         ring = np.minimum(lens, cfg.window)
-        # a kind's rows by its own KV heads, a key as wide as it is carried
-        row = module(name).key_row(cfg)
         return (full * int(lens.sum()) + window * int(ring.sum()),
-                full * blocks(lens, MAX_LEN, cfg.kv_heads * row)
-                + window * blocks(ring, cfg.window, (
-                    cfg.window_kv_heads or cfg.kv_heads) * row))
+                full * granules(lens, MAX_LEN)
+                + window * granules(ring, cfg.window))
     if name == "nemotron_h":  # the attention layers' rows; a mixer keeps none
         gqa = cfg.kinds.count("gqa")
-        return gqa * int(lens.sum()), gqa * blocks(lens, MAX_LEN, kv)
+        return gqa * int(lens.sum()), gqa * granules(lens, MAX_LEN)
     latent = {"kimi_linear": cfg.kinds.count("mla"),
               "longcat": 2 * cfg.layers}[name]
     width = -(-(cfg.mla_latent + cfg.mla_rope_dim) // 128) * 128

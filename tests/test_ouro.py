@@ -245,7 +245,7 @@ def test_the_scheduler_serves_it_and_slots_are_taken_again(params):
         prompts = [_prompt(10 + i, n) for i, n in enumerate((19, 9, 33, 12, 5))]
         futs = [cb.submit(p, SamplingParams(max_tokens=12)) for p in prompts]
         outs = [f.result(300) for f in futs]
-        assert cb._kv_rows(np.array([5, 9])) == (6 * 14, 6 * (64 + 64))
+        assert cb._kv_rows(np.array([5, 9])) == (6 * 14, 6 * (16 + 16))
     finally:
         cb.shutdown()
     for prompt, out in zip(prompts, outs):
@@ -397,6 +397,7 @@ def kernels_through_the_interpreter(monkeypatch):
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(A, "DECODE_BLOCK_ROWS", 16)
+    monkeypatch.setattr(A, "DECODE_THIN_BLOCK_ROWS", 16)
     monkeypatch.setattr(A, "DENSE_SCORES_BYTES", 0)
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
