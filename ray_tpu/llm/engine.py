@@ -104,6 +104,9 @@ class ContinuousLLMEngine(LLMEngine):
             self.model_config, self.generator.params,
             max_len=config.max_len, slots=config.cache_slots,
             seed=config.seed)
+        # ONE tree a process: the batcher put the leaves its decode step
+        # reads in another layout there and deleted the ones it was given
+        self.generator.params = self.batcher.params
 
     def submit(self, prompt: Union[str, Sequence[int]],
                sampling: Optional[SamplingParams] = None):

@@ -60,6 +60,7 @@ slot: ``PagedBatcher`` refuses a configuration that keeps anything else.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import math
 import queue
 import threading
@@ -70,6 +71,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from ray_tpu.models.decoding import (
     STATES,
@@ -86,7 +88,7 @@ from ray_tpu.observability.tracing import device_span
 from ray_tpu.ops import traced
 from ray_tpu.ops.attention import (
     NEG_INF, decode_block, decode_rows_copied)
-from ray_tpu.parallel.bootstrap import FirstCall
+from ray_tpu.parallel.bootstrap import FirstCall, program_phase
 
 
 # passes of the pump between two bookings of its clocks (`_book`)
@@ -201,12 +203,104 @@ def _counted(aux: dict) -> list:
     return [value for name, value in aux.items() if name != "exit_pdf"]
 
 
+def _weights_first(impl, formats, **jit_kwargs):
+    """`impl(params, ...)` jitted with `formats` said of its weights' leaves
+    (one `Format` for all of them, a tree of them like `params`, or None:
+    each leaf as it lies) and nothing said of its other operands."""
+    others = sum(p.kind is p.POSITIONAL_OR_KEYWORD for p in
+                 inspect.signature(impl).parameters.values()) - 1
+    return jax.jit(impl, in_shardings=(formats,) + (None,) * others,
+                   **jit_kwargs)
+
+
+def _as_it_is(leaf):
+    return leaf
+
+
+def _relaid(leaf, chosen: Format):
+    """`leaf`'s values in the format `chosen`, waited for. By a program of
+    its own that the persistent compile cache never keeps, and NOT by
+    `jax.device_put(leaf, chosen)`: read back from that cache, a program
+    whose OUTPUT has another layout than the default returns buffers that
+    say the default layout over data that lie in the one asked for (JAX
+    0.9.0 on a TPU v5e: `device_put`'s identity, written there by a cold
+    start that took over a second to compile it, gave every warm start after
+    it weights of the wrong values under the wrong label; PERF.md section 6,
+    PR 66). The compile is under a second and is paid at every start."""
+    kept_from = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", math.inf)
+    try:
+        program = jax.jit(_as_it_is, out_shardings=chosen).lower(leaf).compile()
+    finally:
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", kept_from)
+    there = jax.block_until_ready(program(leaf))
+    if there.format.layout.major_to_minor != chosen.layout.major_to_minor:
+        raise RuntimeError(
+            f"a leaf {leaf.shape} asked into {chosen.layout} came back in "
+            f"{there.format.layout}")
+    return there
+
+
+class _LaidOutProgram:
+    """A program `impl(params, ...)` that says in what layout ON THE DEVICE
+    it reads each leaf of its weights. `compile_for` compiles it once, ahead
+    of its first call, with `Layout.AUTO` asked for every leaf of `params`:
+    the compiler chooses the tiling the program's products read (a
+    projection kept `[in, heads, D]` tiled by head, where the default tiling
+    has it copied into that one every call), by the shapes it is given and
+    nothing else; the other operands keep what they have. `formats` is what
+    it chose: a tree of `Format`s like `params`, the layout None for a leaf
+    the program does not read. A CALL runs that executable, so the weights
+    must lie in `formats` (`_relaid(leaf, format)`: the caller's to see
+    to; logical shapes, dtypes and values are what they were).
+    Everything else asked of it (`.lower`, ...) is asked of a `jit` that
+    carries `formats` for `params`: lowered with plain shapes, it compiles
+    the program that runs, which is how the benchmark's readers and
+    `tests/test_chip_compile.py` map device operations to scopes. On a bare
+    instance (no engine, no weights) the first `.lower` compiles for the
+    shapes it is given."""
+
+    def __init__(self, impl, **jit_kwargs):
+        self._impl, self._jit_kwargs = impl, jit_kwargs
+        self.formats = self._run = self._jit = None
+
+    def compile_for(self, params, *others):
+        """Compile for weights like `params` (arrays or shapes; a leaf's
+        sharding is kept) and `others`; returns `formats`."""
+        like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=getattr(a, "sharding", None)), params)
+        self._run = _weights_first(
+            self._impl, Format(Layout.AUTO), **self._jit_kwargs).lower(
+                like, *others).compile()
+        self.formats = self._run.input_formats[0][0]
+        self._jit = _weights_first(
+            self._impl, self.formats, **self._jit_kwargs)
+        return self.formats
+
+    def lower(self, *args):
+        if self._jit is None:
+            self.compile_for(*args)
+        return self._jit.lower(*args)
+
+    def __call__(self, *args):
+        return self._run(*args)
+
+    def _cache_size(self) -> int:
+        """Executables compiled to RUN: the one, and whatever the `jit` was
+        called for (nothing, on the pump's path)."""
+        return (self._run is not None) + self._jit._cache_size()
+
+
 class PrefillPrograms:
     """A prompt through the model, one compiled program per length bucket:
     all a prefill replica runs (`models/disagg_prefill.py`), and where the
     scheduler below gets its prefill."""
 
     def __init__(self, cfg: TransformerConfig, params, max_len: int):
+        """Alone (a prefill replica) there is no decode program to say in
+        what layout the weights are read: `params` stay as they lie, in the
+        default layouts, and the programs are compiled for those."""
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
@@ -214,6 +308,10 @@ class PrefillPrograms:
 
     def _jit_programs(self) -> None:
         self._prefill_jits: Dict[int, Any] = {}
+        # what the programs are told of the weights' layouts on the device:
+        # nothing, until a decode program has chosen
+        # (`ContinuousBatcher._compile_decode`)
+        self._formats = None
         # `moe_grouped_path`, `prefill_attention_path`, ...: jitted program
         # -> the implementation(s) each choice of `traced.TOLD` fell on
         # while it was traced (`_traced_with`). `engine_stats()` carries them
@@ -275,12 +373,13 @@ class PrefillPrograms:
         return b
 
     def _prefill_program(self, bucket: int):
-        """The bucket's prefill program, compiled at its first prompt."""
+        """The bucket's prefill program, compiled at its first prompt, for
+        the weights as they lie (`_formats`)."""
         pf = self._prefill_jits.get(bucket)
         if pf is None:
             pf = self._prefill_jits[bucket] = FirstCall(
-                jax.jit(self._prefill_impl), f"prefill_{bucket}",
-                self._prefill_jits, bucket)
+                _weights_first(self._prefill_impl, self._formats),
+                f"prefill_{bucket}", self._prefill_jits, bucket)
         return pf
 
     def _prefill(self, tokens: Sequence[int]):
@@ -299,8 +398,17 @@ class ContinuousBatcher(PrefillPrograms):
 
     def __init__(self, cfg: TransformerConfig, params, max_len: int = 512,
                  slots: int = 8, seed: int = 0):
-        super().__init__(cfg, params, max_len)
         self.slots = slots
+        super().__init__(cfg, params, max_len)
+        # before the cache is there: a leaf that moves is twice on the
+        # device while it moves. The decode program's ONE
+        # `ray_tpu.setup.program` phase is this: trace_s, lower_s and
+        # compile_s its compile ahead of the first step, first_run_s the
+        # weights' re-lay
+        with program_phase("decode") as attrs:
+            relaid = self._lay_weights()
+            attrs.update(weights_relaid=sorted(relaid["weights_relaid"]),
+                         weights_relaid_bytes=relaid["weights_relaid_bytes"])
         self._waiting: "queue.Queue[_Request]" = queue.Queue()
         # scheduler state (_active/_free/_host_len/...) is confined to
         # the pump thread; only _waiting and stats cross threads
@@ -327,8 +435,12 @@ class ContinuousBatcher(PrefillPrograms):
         # what a step takes that changes at an admit or a retire only, as
         # it was last uploaded: name -> (host value, device array)
         self._uploaded: Dict[str, tuple] = {}
-        # stats (observable by tests/metrics). `steps_ahead`: steps
-        # dispatched while the step before them was unread;
+        # stats (observable by tests/metrics). `weights_relaid`: {leaf of
+        # `params`: the `major_to_minor` it was put in} of the leaves the
+        # decode program reads in another layout than they were given in
+        # (`_lay_weights`; none where the compiler chooses the default, as
+        # on the CPU), `weights_relaid_bytes`: their bytes; `steps_ahead`:
+        # steps dispatched while the step before them was unread;
         # `steps_sampled` / `steps_sorted`: steps in which an active row had
         # a temperature / a temperature and a top-k, so that sampling drew /
         # sorted the vocabulary; `tokens_discarded`: slot-steps thrown away
@@ -347,7 +459,7 @@ class ContinuousBatcher(PrefillPrograms):
                       "steps_sampled": 0, "steps_sorted": 0,
                       "tokens_discarded": 0, "kv_rows_held": 0,
                       "kv_rows_read": 0, "pump_step_s": 0.0,
-                      "pump_sync_s": 0.0, "pump_cpu_s": 0.0}
+                      "pump_sync_s": 0.0, "pump_cpu_s": 0.0, **relaid}
         # of the passes since the last booking (`_book`): their wall time
         # and their waits for the device, the thread's CPU clock when the
         # first of them began, and the passes ever made
@@ -460,17 +572,75 @@ class ContinuousBatcher(PrefillPrograms):
         """The programs as the pump runs them. Install and the decode step
         are given the cache to keep (donated): each changes a few rows of
         it in place, `self.cache` is replaced by what they return, and no
-        one may hold the cache that went in."""
+        one may hold the cache that went in.
+
+        The decode step says in what layout on the device it reads the
+        weights (`_LaidOutProgram`): `Layout.AUTO` is asked of every leaf of
+        `params` and of nothing else (the cache, the tokens and the rest
+        keep what they have), once, when the program is compiled: at the
+        engine's build, ahead of the first step and before the cache is
+        allocated (`_compile_decode`, and `_lay_weights` puts the leaves
+        that differ there), or on a bare instance at the first `.lower`.
+        From then on `_decode_jit` and every prefill program carry the
+        chosen formats for `params`, so `.lower(<plain shapes>)` of either
+        compiles the program that runs. Where a prefill would read a leaf
+        in another tiling, the decode program's choice holds: a sequence
+        is one prefill and hundreds of steps."""
         super()._jit_programs()
-        self._decode_jit = FirstCall(
-            jax.jit(self._decode_impl, donate_argnums=(2,)), "decode",
-            self.__dict__, "_decode_jit")
+        self._decode_jit = _LaidOutProgram(
+            self._decode_impl, donate_argnums=(2,))
         self._install_jit = FirstCall(
             jax.jit(self._install_impl, donate_argnums=(0,)), "install",
             self.__dict__, "_install_jit")
         self._reset_state_jit = FirstCall(
             jax.jit(self._reset_state_impl, donate_argnums=(0,)),
             "reset_state", self.__dict__, "_reset_state_jit")
+
+    def _step_shapes(self) -> tuple:
+        """What `_decode` gives a step behind the weights, as shapes."""
+        def per_slot(dtype):
+            return jax.ShapeDtypeStruct((self.slots,), dtype)
+
+        return (per_slot(jnp.int32), jax.eval_shape(self._empty_cache),
+                jax.eval_shape(lambda: jax.random.key(0)),
+                per_slot(jnp.float32), per_slot(jnp.int32),
+                per_slot(jnp.bool_))
+
+    def _compile_decode(self, params):
+        """Compile the decode step for weights like `params` (arrays or
+        shapes) in the layouts it chooses for them; the prefill programs
+        made from here on are compiled for the same."""
+        self._formats = self._decode_jit.compile_for(
+            params, *self._step_shapes())
+
+    def _lay_weights(self) -> dict:
+        """Compile the decode step and put every leaf of `params` that it
+        reads in another layout than the leaf lies in there (`_relaid`), one
+        leaf at a time, the buffer it was given in deleted before the next
+        moves: never two copies of a stack. `self.params` is the tree that
+        results: the structure, shapes, dtypes and values it was given, and
+        the ONE tree a process should hold (a holder of the tree that was
+        given holds deleted leaves where one moved). Returns what `stats`
+        says of it."""
+        self._compile_decode(self.params)
+        moved = {}
+
+        def lay(path, leaf, chosen):
+            if chosen.layout is None or not isinstance(leaf, jax.Array) \
+                    or leaf.format == chosen:
+                return leaf
+            there = moved[jax.tree_util.keystr(
+                path, simple=True, separator="/")] = _relaid(leaf, chosen)
+            leaf.delete()
+            return there
+
+        self.params = jax.tree_util.tree_map_with_path(
+            lay, self.params, self._formats)
+        return {"weights_relaid": {
+                    name: list(leaf.format.layout.major_to_minor)
+                    for name, leaf in moved.items()},
+                "weights_relaid_bytes": sum(
+                    leaf.nbytes for leaf in moved.values())}
 
     def _install_impl(self, cache: KVCache, row_k, row_v, slot, length,
                       *kept):

@@ -86,6 +86,8 @@ class DecodeReplica:
 
         from ray_tpu.models.paged_kv import PagedBatcher
 
+        # the batcher's tree is the replica's one: it re-lays the leaves
+        # its decode step reads in another layout and deletes those given
         self.batcher = PagedBatcher(cfg, params, max_len=max_len,
                                     slots=slots, page_size=page_size)
         self.reader = reader

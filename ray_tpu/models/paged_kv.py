@@ -48,6 +48,7 @@ from collections import OrderedDict
 from concurrent.futures import Future
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -259,6 +260,10 @@ class PagedBatcher(ContinuousBatcher):
         k, v = self.cache.k[:, page_ids], self.cache.v[:, page_ids]
         dense = (k.shape[0], self.max_len, *k.shape[3:])  # [L, P, ps, ..]
         return k.reshape(dense), v.reshape(dense)
+
+    def _step_shapes(self):
+        return super()._step_shapes() + (jax.ShapeDtypeStruct(
+            self._page_table.shape, jnp.int32),)
 
     def _decode_impl(self, params, toks, cache, rng, temps, topks,
                      active_mask, page_table):

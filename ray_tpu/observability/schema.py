@@ -93,15 +93,20 @@ SETUP_PHASES = {
         "restore, waited for [bytes, programs: executables obtained]",
     "ray_tpu.setup.engine.cache":
         "replica: the slots' cache allocated, waited for [bytes]",
-    # parallel/bootstrap.py::FirstCall, round every jitted program of the
-    # engine and round the train step
+    # parallel/bootstrap.py::program_phase: FirstCall, round every jitted
+    # program of the engine and round the train step, and the engine's
+    # compile of its decode step (ContinuousBatcher.__init__)
     "ray_tpu.setup.program":
         "chip process: a jitted program's FIRST call, waited for [program: "
         "prefill_<bucket> | decode | install | reset_state | sample_first | "
         "train_step; trace_s, lower_s, compile_s: JAX's own events over the "
         "call (compile_s holds a cache read); cache: hit | miss | none; "
         "first_run_s: the call's wall less those three: the executable's "
-        "load, the first transfers, the first execution]",
+        "load, the first transfers, the first execution]. `decode` is the "
+        "engine's BUILD and not a call: the step compiled ahead of the "
+        "cache's allocation with the weights' layouts its own to choose, "
+        "first_run_s the re-lay of the leaves that differ [weights_relaid: "
+        "their paths; weights_relaid_bytes]",
     # train/step.py::make_train_step
     "ray_tpu.setup.step.build":
         "chip process: make_train_step's own Python (shapes, shardings, the "

@@ -13,6 +13,7 @@ learner groups alike (SURVEY.md §2.3 "Multi-slice coordination").
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -138,18 +139,40 @@ def process_compiles() -> Dict[str, float]:
     return _process_compiles
 
 
+@contextlib.contextmanager
+def program_phase(program: str):
+    """ONE ``ray_tpu.setup.program`` phase round the block [program;
+    trace_s, lower_s, compile_s: what JAX's events counted in this process
+    over the block; cache: the persistent cache's answer, ``hit`` /
+    ``miss`` / ``none``; first_run_s: the block's wall less those three].
+    Yields the phase's attrs, for what else the block has to say."""
+    seen = process_compiles()
+    before = dict(seen)
+    with setup_phase("ray_tpu.setup.program", program=program) as attrs:
+        t0 = time.monotonic()
+        try:
+            yield attrs
+        finally:
+            wall = time.monotonic() - t0
+            parts = {k: seen[k] - before[k]
+                     for k in ("trace_s", "lower_s", "compile_s")}
+            attrs.update(
+                parts, first_run_s=max(0.0, wall - sum(parts.values())),
+                cache="miss" if seen["cache_misses"] > before["cache_misses"]
+                else "hit" if seen["cache_hits"] > before["cache_hits"]
+                else "none")
+
+
 class FirstCall:
     """Round a jitted callable until its first call has returned: that call
-    is booked as ONE ``ray_tpu.setup.program`` phase [program; trace_s,
-    lower_s, compile_s: what JAX's events counted in this process over the
-    call; cache: the persistent cache's answer, ``hit`` / ``miss`` /
-    ``none``; first_run_s: the call's wall, its results waited for, less
-    those three: the executable's load, the first transfers, the first
-    execution], and then ``holder[key]`` (a dict: an object's ``__dict__``,
-    a module's ``globals()``, a table of programs), if it still holds this
-    wrapper, holds the bare callable: a steady-state step runs no line of
-    this. Everything else asked of the wrapper (``.lower``, ...) is the
-    callable's own."""
+    is booked as ONE ``ray_tpu.setup.program`` phase (``program_phase``;
+    first_run_s: the call's wall, its results waited for, less JAX's three:
+    the executable's load, the first transfers, the first execution), and
+    then ``holder[key]`` (a dict: an object's ``__dict__``, a module's
+    ``globals()``, a table of programs), if it still holds this wrapper,
+    holds the bare callable: a steady-state step runs no line of this.
+    Everything else asked of the wrapper (``.lower``, ...) is the callable's
+    own."""
 
     def __init__(self, fn, program: str, holder: dict, key: Any):
         self._fn, self._program = fn, program
@@ -165,22 +188,10 @@ class FirstCall:
         self._booked = True
         import jax
 
-        seen = process_compiles()
-        before = dict(seen)
-        with setup_phase("ray_tpu.setup.program",
-                         program=self._program) as attrs:
-            t0 = time.monotonic()
+        with program_phase(self._program):
             try:
                 return jax.block_until_ready(self._fn(*args, **kwargs))
             finally:
-                wall = time.monotonic() - t0
-                parts = {k: seen[k] - before[k]
-                         for k in ("trace_s", "lower_s", "compile_s")}
-                attrs.update(
-                    parts, first_run_s=max(0.0, wall - sum(parts.values())),
-                    cache="miss" if seen["cache_misses"] > before["cache_misses"]
-                    else "hit" if seen["cache_hits"] > before["cache_hits"]
-                    else "none")
                 if self._holder.get(self._key) is self:
                     self._holder[self._key] = self._fn
 

@@ -557,7 +557,10 @@ def serve_programs(topo):
     """configuration name -> (cfg, prefill[bucket], decode[slots x SEQ],
     cache shapes) of a `ContinuousBatcher`, compiled once for one described
     chip: the programs as the engine jits them, the decode step's donation
-    of its cache included."""
+    of its cache included, and the weights in the layouts the decode step
+    chooses for them (`_compile_decode`, as the engine's build does before
+    any prefill program is made): what `.lower(<plain shapes>)` of either
+    compiles is the program an engine runs."""
     from ray_tpu.models.continuous_batching import ContinuousBatcher
     from ray_tpu.models.decoding import init_cache
 
@@ -578,6 +581,7 @@ def serve_programs(topo):
         batcher = ContinuousBatcher.__new__(ContinuousBatcher)  # programs only
         batcher.cfg, batcher.max_len, batcher.slots = cfg, max_len, slots
         batcher._jit_programs()
+        batcher._compile_decode(params)
         return batcher, params, bucket
 
     @functools.cache
@@ -585,9 +589,10 @@ def serve_programs(topo):
         """The configuration's prefill program of another bucket than its
         cell's; what it attended with is in `attention_paths(name)`."""
         batcher, params, _ = engine(name)
-        return jax.jit(batcher._prefill_impl).lower(  # as `_prefill_into`
-            params, arr((1, bucket), jnp.int32), arr((1,), jnp.int32)
-        ).compile()
+        lowered = batcher._prefill_program(bucket).lower(  # as `_prefill_into`
+            params, arr((1, bucket), jnp.int32), arr((1,), jnp.int32))
+        prefill_modules[name, bucket] = lowered.as_text()
+        return lowered.compile()
 
     @functools.cache
     def compiled(name):
@@ -606,6 +611,8 @@ def serve_programs(topo):
         return cfg, prefill, decode, cache
 
     compiled.grouped_paths = grouped_paths = {}
+    # (name, bucket) -> the module a prefill program handed the compiler
+    compiled.prefill_modules = prefill_modules = {}
     compiled.engine = engine
     compiled.prefill_at = prefill_at
     # what the engine's `prefill_attention_path` says of the prefills so far
@@ -668,6 +675,76 @@ def test_prefill_and_decode_compile(serve_programs, name):
     assert cache.state is None
     assert _entry_parameters(decode) == leaves + 3 + 5
     assert _entry_parameters(prefill) == leaves + 2
+
+
+def test_dense_decode_keeps_no_projection_in_a_buffer_of_its_own(
+        serve_programs):
+    """The chat and document cells' decode step (Mistral-7B, 16 layers): the
+    layer body slices no layer of `wq`, `wk` or `wv` out of its stack into a
+    buffer of its own. The stacks lie on the chip tiled by head (`wq`, `wk`,
+    `wv` (0, 2, 1, 3), the decode program's own choice: `Layout.AUTO`), so
+    the stack's `dynamic-slice` sits inside the product's fusion; in the
+    default tiling a `constant_dynamic-slice_fusion` wrote
+    bf16[1,4096,32,128] and two bf16[1,4096,8,128] every layer for the
+    product to re-tile and read: a tenth of the step on the chip (PR 65's
+    ledger lines)."""
+    name = "mistral7b-v03-serve-d16"
+    _, _, decode, _ = serve_programs(name)
+    comps = _computations(decode.as_text())
+    bodies = {body for comp in comps.values() for body in
+              re.findall(r" while\(.*?body=%([\w.\-]+)", comp)}
+    assert bodies
+    for body in bodies:
+        for op_name, dtype, dims, op in _results(comps[body]):
+            assert dims not in ([1, 4096, 32, 128], [1, 4096, 8, 128]), (
+                op_name, dims, op)
+    chosen = serve_programs.engine(name)[0]._formats["blocks"]
+    assert {w: chosen[w].layout.major_to_minor for w in ("wq", "wk", "wv")} \
+        == dict.fromkeys(("wq", "wk", "wv"), (0, 2, 1, 3))
+
+
+@pytest.mark.parametrize("name", [*SERVE_CONFIGS, *PATTERN_CONFIGS])
+def test_lowering_with_plain_shapes_compiles_the_program_that_runs(
+        serve_programs, name):
+    """The readers' contract, for every serve configuration here: the
+    benchmark's runners map device operations to scopes by
+    `batcher._decode_jit.lower(<shapes that keep a leaf's sharding and
+    nothing else>).compile()` and `batcher._prefill_jits[bucket].lower(...)`
+    (`benchmarks/runners/serve_kimi_linear.py::_like`), so what those compile
+    must be compiled for the formats the decode step chose and hold the
+    operations of the programs that run: the executable the decode step was
+    compiled to with `Layout.AUTO` for its weights (the same instructions;
+    the NUMBERS in a few names, `%copy.27` / `%copy.28`, shift by one where
+    the other operands' shardings are said, as the readers say them and a
+    step's own arguments never did), and the prefill a `jit` is handed to
+    compile when it is called with weights that lie in those formats (the
+    same module to the character)."""
+    def names(program):  # without their numbers
+        return sorted(re.sub(r"\.\d+", "", n)
+                      for n, *_ in _results(program.as_text()))
+
+    def chosen(program):
+        return jax.tree.map(
+            lambda was, now: was.layout is None or was == now,
+            batcher._formats, program.input_formats[0][0])
+
+    batcher, params, bucket = serve_programs.engine(name)
+    _, prefill, decode, _ = serve_programs(name)
+    assert all(jax.tree.leaves(chosen(decode)))
+    assert all(jax.tree.leaves(chosen(prefill)))
+    assert names(decode) == names(batcher._decode_jit._run)
+    one = jax.tree.leaves(params)[0].sharding
+    laid = jax.tree.map(
+        lambda a, told: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=told if told.layout is not None else a.sharding),
+        params, batcher._formats)
+    rest = (jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one))
+    # the module the compiler is handed, character for character (lowered
+    # and not compiled again: one module's compile is the fixture's)
+    assert serve_programs.prefill_modules[name, bucket] \
+        == jax.jit(batcher._prefill_impl).lower(laid, *rest).as_text()
 
 
 def test_stateful_serve_programs_compile_and_fit(serve_programs):
@@ -1108,13 +1185,17 @@ def test_looped_serve_programs_compile_and_fit(serve_programs):
     (6.44 GB aliased in to out) and reads the rows of cache layer t * 48 + i
     with `decode_attention`; the stacked weights are read where they lie by
     every pass: ONE layer body (one Mosaic call: neither loop is unrolled)
-    and no array of [4, 48, ...] or [192, ...] weights; its temporaries are
-    the three stacks `wq`, `wk`, `wv` re-tiled by head (the compiler moves a
-    layer's re-tiling, which a one-pass program does inside its body, out
-    of both loops: 1.2 GB rewritten every step, PERF.md section 7) and beside
-    them less than one cache layer (16.8 MB). The 256 bucket's prefill keeps
-    its scores dense (4 MiB a call: under `DENSE_SCORES_BYTES`, the one rule
-    every configuration's prefill reads), and both fit the chip."""
+    and no array of [4, 48, ...] or [192, ...] weights; the three stacks
+    `wq`, `wk`, `wv` lie on the chip tiled by head, as the step's products
+    read them (PR 66: the decode program is compiled with `Layout.AUTO` for
+    its weights; in the default tiling the compiler copied all three into
+    that one in ENTRY, 1.2 GB every step), so its temporaries are less than
+    one cache layer (16.8 MB) and 8 MB. The 256 bucket's prefill, compiled
+    for the decode step's choice, keeps its scores dense (4 MiB a call:
+    under `DENSE_SCORES_BYTES`, the one rule every configuration's prefill
+    reads) and copies two of the stacks where it copied three (it wants
+    another tiling of them; 1.0 GB of temporaries for 1.4), and both fit
+    the chip."""
     from benchmarks import harness, scope_ops
     from ray_tpu.observability import schema
     from ray_tpu.ops import attention as A
@@ -1139,11 +1220,20 @@ def test_looped_serve_programs_compile_and_fit(serve_programs):
               f"{_total_bytes(program) / 1e9:.2f} GB")
     m = decode.memory_analysis()
     assert m.alias_size_in_bytes >= kept
-    retiled = 3 * 48 * 2048 * 16 * 128 * 2  # wq, wk, wv
-    assert m.temp_size_in_bytes < retiled + a_layer
-    assert _total_bytes(decode) < 13e9
-    assert _total_bytes(prefill) + kept < 14e9  # beside the engine's cache
+    assert m.temp_size_in_bytes < a_layer + 8e6
+    assert _total_bytes(decode) < 12e9
+    assert _total_bytes(prefill) + kept < 13.5e9  # beside the engine's cache
     text = decode.as_text()
+    entry = text.split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    assert not [line for line in entry.splitlines()
+                if " copy(" in line and "bf16[48,2048,16,128]" in line]
+    relaid = {jax.tree_util.keystr(path, simple=True, separator="/"):
+              chosen.layout.major_to_minor
+              for path, chosen in jax.tree_util.tree_leaves_with_path(
+                  batcher._formats) if chosen.layout is not None
+              and chosen.layout.major_to_minor != tuple(range(len(
+                  chosen.layout.major_to_minor)))}
+    assert relaid == {f"blocks/{w}": (0, 2, 1, 3) for w in ("wq", "wk", "wv")}
     for op_name, dtype, dims, op in _results(text):
         assert dims[:2] != [4, 48] and not (
             dims[:1] == [192] and dims[1:] != [8, 512, 16, 128]), (
@@ -1563,15 +1653,21 @@ def _program_text(program) -> str:
 # equal). PR 63 pinned ZAYA1's 1,024 bucket anew: its 1,024 rows over 16
 # groups pass the experts' matrices with the kernel of row tiles, as every
 # sparse prefill does since (`_prefill_passes_row_tiles`); every DECODE
-# step here is the one PR 62 left.
+# step here was the one PR 62 left until PR 66 pinned ALL seven anew: the
+# decode step is compiled with `Layout.AUTO` for its weights and every
+# program here for what it chose (`wq` / `wk` / `wv` tiled by head, a
+# router, the latent projections: PERF.md section 6, PR 66, has the leaves
+# by configuration), so a projection's slice of its stack is read inside
+# the product's fusion and no longer written out first; ZAYA1's prefill
+# reads the decode step's choice of its four leaves.
 UNCHANGED_PROGRAMS = {
-    ("zaya1-8b-serve-d16", "prefill"): "6fda4d4839491acf",
-    ("mistral7b-v03-serve-d16", "decode"): "f367d611b8b354af",
-    ("olmoe-1b-7b-serve-d8", "decode"): "db5f0cb4da39e451",
-    ("zaya1-8b-serve-d16", "decode"): "32de0ec26bb5d39f",
-    ("laguna-s-2.1-serve-ep2-d5", "decode"): "8c541fa29e4b51a8",
-    (KIMI_LINEAR, "decode"): "5257c4650a1f74e3",
-    (LONGCAT, "decode"): "d19b0ea7c5f6ee58",
+    ("zaya1-8b-serve-d16", "prefill"): "d6570bfa0207db33",
+    ("mistral7b-v03-serve-d16", "decode"): "eee95144c06f4bbd",
+    ("olmoe-1b-7b-serve-d8", "decode"): "b0482fe40d6d6b49",
+    ("zaya1-8b-serve-d16", "decode"): "8a7e6aa8169d0518",
+    ("laguna-s-2.1-serve-ep2-d5", "decode"): "32174ca84a35523a",
+    (KIMI_LINEAR, "decode"): "381ffe89d2e79edc",
+    (LONGCAT, "decode"): "2df7d08a1ecd6aa2",
 }
 # a latent family's prefill -> its cell's bucket
 LATENT_PREFILLS = {KIMI_LINEAR: 2048, LONGCAT: 4096}

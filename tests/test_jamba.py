@@ -133,7 +133,9 @@ def test_prefill_then_decode_is_the_reference(params):
         assert mat.shape == (6, 16, 256) and mat.dtype == jnp.float32
         assert conv.shape == (6, 3 * 256)
         firsts.append(np.asarray(last))
-    assert cb.ssm_path == {"prefill_32": "scan:plain",
+    # the decode step was traced at the engine's build (PR 66)
+    assert cb.ssm_path == {"decode": "state:plain",
+                           "prefill_32": "scan:plain",
                            "prefill_128": "scan:plain"}
     seqs = [list(p) for p in prompts]
     system = [[f] for f in firsts]

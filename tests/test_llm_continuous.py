@@ -235,6 +235,116 @@ def _primitives(jaxpr, inside_cond=False):
                 sub, inside_cond or eqn.primitive.name == "cond")
 
 
+class TestWeightsLieAsTheDecodeStepReadsThem:
+    """The engine compiles its decode step with the weights' layouts on the
+    device the step's own to choose and re-lays the leaves that differ once,
+    at its build (`ContinuousBatcher._lay_weights`). On host devices the
+    compiler chooses the default, so nothing moves: the tree, the counters
+    and the tokens are the ones the tree gave before PR 66."""
+
+    @pytest.mark.parametrize("batcher,kwargs", [
+        (ContinuousBatcher, {}), (PagedBatcher, {"page_size": 16})],
+        ids=["slots", "pages"])
+    def test_the_tree_its_counters_and_its_greedy_tokens(
+            self, tiny_model, batcher, kwargs):
+        cfg, given = tiny_model
+        kept = jax.tree.map(np.asarray, given)
+        prompt = np.random.default_rng(61).integers(
+            0, cfg.vocab_size, 11).tolist()
+        cb = batcher(cfg, given, max_len=64, slots=2, **kwargs)
+        try:
+            out = cb.submit(prompt, SamplingParams(max_tokens=8)).result(
+                timeout=120)
+            stats = dict(cb.stats)
+        finally:
+            cb.shutdown()
+        assert jax.tree.structure(cb.params) == jax.tree.structure(given)
+        assert jax.tree.all(jax.tree.map(
+            lambda leaf, was: leaf.shape == was.shape
+            and leaf.dtype == was.dtype
+            and np.array_equal(np.asarray(leaf), was), cb.params, kept))
+        assert stats["weights_relaid"] == {}
+        assert stats["weights_relaid_bytes"] == 0
+        # as the parent commit's batchers answered the same prompt
+        assert out == [26, 258, 367, 479, 405, 338, 106, 69]
+        assert out == Generator(cfg, given, max_len=64).generate(
+            [prompt], SamplingParams(max_tokens=8))[0]
+        # every program is told the formats the decode step chose, and
+        # lowering one with plain shapes says so
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), cb.params)
+        assert jax.tree.structure(cb._formats) == jax.tree.structure(shapes)
+        lowered = cb._decode_jit.lower(shapes, *cb._step_shapes())
+        assert lowered.compile().input_formats[0][0] == cb._formats
+
+    @pytest.mark.parametrize("batcher,kwargs", [
+        (ContinuousBatcher, {}), (PagedBatcher, {"page_size": 16})],
+        ids=["slots", "pages"])
+    def test_a_leaf_the_step_reads_in_another_layout_is_moved_once(
+            self, monkeypatch, batcher, kwargs):
+        """The whole path on host devices, where the compiler itself never
+        chooses: the decode step is compiled as if `Layout.AUTO` had answered
+        "`wq` by head" (what the chip's compiler answers), so the engine
+        moves that one leaf, deletes the one it was given, tells every
+        prefill program, and the tokens are the ones of the default layout."""
+        from jax.experimental.layout import Format, Layout
+        from ray_tpu.models import continuous_batching as CB
+
+        cfg = _tiny_cfg()
+        given = T.init_params(cfg, jax.random.key(0))
+        kept = jax.tree.map(np.asarray, given)
+        by_head = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: Format(
+                Layout((0, 2, 1, 3)) if path[-1].key == "wq" else None,
+                leaf.sharding), given)
+        real = CB._weights_first
+        monkeypatch.setattr(
+            CB, "_weights_first", lambda impl, formats, **kw: real(
+                impl, by_head if isinstance(formats, Format) else formats,
+                **kw))
+        prompt = np.random.default_rng(61).integers(
+            0, cfg.vocab_size, 11).tolist()
+        cb = batcher(cfg, given, max_len=64, slots=2, **kwargs)
+        try:
+            out = cb.submit(prompt, SamplingParams(max_tokens=8)).result(
+                timeout=120)
+        finally:
+            cb.shutdown()
+        wq = cb.params["blocks"]["wq"]
+        assert cb.stats["weights_relaid"] == {"blocks/wq": [0, 2, 1, 3]}
+        assert cb.stats["weights_relaid_bytes"] == wq.nbytes
+        assert wq.format.layout.major_to_minor == (0, 2, 1, 3)
+        assert given["blocks"]["wq"].is_deleted()
+        assert cb.params["blocks"]["wk"] is given["blocks"]["wk"]
+        assert jax.tree.all(jax.tree.map(
+            lambda leaf, was: np.array_equal(np.asarray(leaf), was),
+            cb.params, kept))
+        assert out == [26, 258, 367, 479, 405, 338, 106, 69]
+        # lowered with plain shapes, the step is compiled for that choice
+        told = cb._decode_jit.lower(
+            jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                         cb.params), *cb._step_shapes()).compile()
+        assert told.input_formats[0][0]["blocks"]["wq"].layout \
+            .major_to_minor == (0, 2, 1, 3)
+        assert cb._formats["blocks"]["wq"].layout.major_to_minor \
+            == (0, 2, 1, 3)
+
+    def test_an_engines_generator_and_batcher_hold_the_same_leaves(self):
+        from ray_tpu.llm import LLMConfig
+        from ray_tpu.llm.engine import ContinuousLLMEngine
+
+        engine = ContinuousLLMEngine(LLMConfig(
+            model="debug", max_len=64, cache_slots=2))
+        try:
+            ours = jax.tree.leaves(engine.batcher.params)
+            theirs = jax.tree.leaves(engine.generator.params)
+            assert len(ours) == len(theirs) > 0
+            assert all(a is b for a, b in zip(ours, theirs))
+            assert not any(leaf.is_deleted() for leaf in ours)
+        finally:
+            engine.shutdown()
+
+
 class TestSamplingFollowsItsRows:
     """Sampling does what its ACTIVE rows ask for: the argmax alone for
     greedy rows, the draw only where a row has a temperature, the sort only

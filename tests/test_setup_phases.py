@@ -221,7 +221,10 @@ class TestEngineSites:
         params = T.init_params(cfg, jax.random.key(0))
         cb = CB.ContinuousBatcher(cfg, params, max_len=64, slots=2)
         try:
-            assert isinstance(cb._decode_jit, bootstrap.FirstCall)
+            # the decode step was compiled at the build (its phase is booked
+            # by then); the others wait for their first call
+            assert not isinstance(cb._decode_jit, bootstrap.FirstCall)
+            assert isinstance(cb._install_jit, bootstrap.FirstCall)
             out = cb.submit([5, 17, 3], SamplingParams(max_tokens=4)
                             ).result(timeout=120)
             again = cb.submit(list(range(20)), SamplingParams(max_tokens=3)
@@ -237,6 +240,14 @@ class TestEngineSites:
         # once each, however many requests and steps followed
         assert sorted(p for p in programs if p != "sample_first") == [
             "decode", "install", "prefill_16", "prefill_32"]
+        # the decode program's is the build's: compiled before the cache is
+        # there, with what it re-laid of the weights (nothing, on the CPU)
+        (decode,) = (e for e in _phases(since, "ray_tpu.setup.program")
+                     if e["attrs"]["program"] == "decode")
+        assert decode["mono"] + decode["dur"] <= cache["mono"] + 1e-6
+        assert decode["attrs"]["compile_s"] > 0
+        assert decode["attrs"]["weights_relaid"] == []
+        assert decode["attrs"]["weights_relaid_bytes"] == 0
         # and nothing of the wrapper is left on a window's path
         for fn in (cb._decode_jit, cb._install_jit, CB._sample_first,
                    *cb._prefill_jits.values()):
@@ -253,6 +264,7 @@ class TestEngineSites:
         names = [e["name"] for e in _phases(since)]
         assert names == ["ray_tpu.setup.engine.backend",
                          "ray_tpu.setup.engine.params",
+                         "ray_tpu.setup.program",  # the decode step's
                          "ray_tpu.setup.engine.cache"]
         (params,) = _phases(since, "ray_tpu.setup.engine.params")
         assert params["attrs"]["bytes"] == sum(
