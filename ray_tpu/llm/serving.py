@@ -91,6 +91,10 @@ def build_llm_deployment(config: LLMConfig):
                 # layers of K/V rows a sequence keeps for them
                 out.update(loop_steps=cfg.loop_steps,
                            kv_layers_kept=cfg.full_layers)
+                if cfg.kinds:  # a pattern's layers, counted by kind
+                    out["layers_by_kind"] = {
+                        kind: cfg.layers_of(kind)
+                        for kind in dict.fromkeys(cfg.kinds)}
             devices = jax.devices()
             mem = devices[0].memory_stats() or {}
             out.update(self._compiles)

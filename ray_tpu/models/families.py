@@ -26,10 +26,15 @@ import importlib
 from typing import Any, NamedTuple, Optional, Tuple
 
 # module under `ray_tpu.models` -> what selects it: a layer pattern
-# (`layer_kinds`) by the kinds its layers are of, lead and tail among them;
+# (`layer_kinds`) by the kinds its layers are of, lead and tail among them.
+# The FIRST family whose kinds hold the pattern's runs it, so a kind has one
+# name in one family: grouped attention over K/V rows is "gqa" as one of
+# `nemotron_h`'s one-sublayer layers and "gkv" as the attention half of a
+# `kimi_linear` layer (delta-rule layers beside latent rows, "mla", or beside
+# K/V rows, "gkv");
 PATTERNS = {
     "longcat": {"scmoe"},
-    "kimi_linear": {"kda", "mla"},
+    "kimi_linear": {"kda", "mla", "gkv"},
     "laguna": {"window", "full"},
     "nemotron_h": {"ssm", "ssm1", "gqa", "mlp", "lmoe"},
 }

@@ -1,23 +1,32 @@
-"""A pattern of delta-rule linear-attention layers beside latent-attention
-layers (moonshotai/Kimi-Linear-48B-A3B-Instruct, `model_type` kimi_linear;
-the recurrence is arXiv:2510.26692). Imported only where a configuration has
-one (`families.PATTERNS`); the expert matmuls, sampling, the scheduler and
-the drawing of weights are the other models' (`transformer.moe_dropless`,
-`pattern._draw`).
+"""A pattern of delta-rule linear-attention layers beside full-attention
+layers of one of two kinds, latent rows or grouped K/V rows
+(moonshotai/Kimi-Linear-48B-A3B-Instruct, `model_type` kimi_linear;
+upstage/Solar-Open2-250B, `model_type` solar_open2; the recurrence is
+arXiv:2510.26692). Imported only where a configuration has one
+(`families.PATTERNS`); the expert matmuls, the cache's slots and the grouped
+attention over them, sampling, the scheduler and the drawing of weights are
+the other models' (`transformer.moe_dropless`, `decoding._write_stack` /
+`attend_held`, `pattern._draw`).
 
-**Layers.** `cfg.kinds`: a leading "kda" layer with a dense SwiGLU MLP, whole
-periods of `layer_kinds` (kda, kda, mla, kda) and the trailing layers
-`tail_kinds` (kda, mla), every layer behind the first with sparse experts.
-Parameters are stacked BY KIND (`blocks["kda" | "mla" | "sparse"]`,
-`blocks["dense"]` the leading MLP alone); `pattern.forward_cached` runs
-`layer` here as the leading layer, ONE `lax.scan` over the periods, then the
-trailing layers. With y the RMS-normed stream:
+**Layers.** Three kinds, "kda", "mla" and "gkv", in two forms of pattern
+(`check`). With a LEAD (`lead_kind` "kda", Kimi-Linear): a leading "kda"
+layer with a dense SwiGLU MLP, whole periods of `layer_kinds` (kda, kda, mla,
+kda) and the trailing layers `tail_kinds` (kda, mla), every layer behind the
+first with sparse experts. WITHOUT one (`lead_kind` "", Solar-Open2): whole
+periods of `layer_kinds` (gkv, kda, kda, kda) and nothing before or behind
+them, EVERY layer with sparse experts. Parameters are stacked BY KIND
+(`blocks["kda" | "mla" | "gkv" | "sparse"]`, `blocks["dense"]` the leading
+MLP alone); `pattern.forward_cached` runs `layer` here as the leading layer,
+if any, ONE `lax.scan` over the periods, then the trailing layers. With y the
+RMS-normed stream:
 
 **A "kda" layer** (`heads` heads of `hd`, keys and values alike): `q~, k~,
 v~ = y Wq, y Wk, y Wv`; a causal depthwise convolution of `kda_conv` taps and
 SiLU on each; `q = l2norm(c_q) / sqrt(hd)`, `k = l2norm(c_k)`, `v = c_v`; a
 decay by CHANNEL `a = exp(-exp(A_log) softplus((y Wfa) Wfb + dt_bias))`, a
-step `beta = sigmoid(y Wb)` a head; the state S [keys, values] of a head,
+step `beta = sigmoid(y Wb)` a head (`kda_neg_eigval`: `2 sigmoid(y Wb)`, so
+that `I - beta k k^T` has the eigenvalue `1 - beta` in (-1, 1) along k and a
+state can change its sign); the state S [keys, values] of a head,
 float32, zero at a sequence's start:
 `S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T`,
 `o_t = S_t^T q_t`; `o <- RMSNorm_head(o) * sigmoid((y Wga) Wgb)`, then `Wo`.
@@ -51,6 +60,16 @@ logits through HBM; elsewhere `_attend_expanded`, which past
 `PREFILL_LOGITS_MAX` of float32 logits [heads, S, S] attends a block of
 queries at a time.
 
+**A "gkv" layer** (`heads` query heads in groups of `heads / kv_heads` that
+adjoin, group g reading K/V head g): `q = y Wq`, `k, v = y Wk, y Wv`
+(`kv_heads` of `hd`), NO rotation and no other position signal, causal
+softmax of `q k^T / sqrt(hd)`; `gqa_gate`: `o <- o * sigmoid(y Wg)`,
+elementwise, `Wg` a matrix of `wq`'s shape, from the sublayer's own normed
+input; then `Wo`. Rows in `KVCache.k` / `.v` beside the "kda" layers' states
+(`_write_stack`, `attend_held`: a decode step reads the rows a slot holds
+with `ops.attention.decode_attention` on a TPU, a prefill attends its fresh
+rows with the flash forward where `attend_fresh` takes them).
+
 **Experts.** `router`: sigmoid scores over all `num_experts` in float32, the
 top k of score + a stored bias, the weights the scores alone, renormalised,
 times `routed_scale`; then `pattern.sparse_mlp`: `moe_dropless` with
@@ -66,7 +85,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.decoding import FreshRows, attend_fresh
+from ray_tpu.models.decoding import (
+    FreshRows, _write_stack, attend_fresh, attend_held,
+)
 from ray_tpu.models.families import Kept
 from ray_tpu.models.pattern import (  # noqa: F401 (the family's three)
     _swiglu, _take, init_params, mlp_leaves, num_params, param_axes,
@@ -93,36 +114,80 @@ HI = lax.Precision.HIGHEST
 FIELDS = frozenset({
     "layer_kinds", "lead_kind", "tail_kinds", "kda_conv", "mla_latent",
     "mla_rope_dim", "mla_q_rank", "mla_rotate", "mla_scales", "router_score",
-    "dense_mlp_hidden", "shared_expert_hidden", "experts_held"})
+    "dense_mlp_hidden", "shared_expert_hidden", "experts_held", "gqa_gate",
+    "kda_neg_eigval"})
 
 
 def check(cfg: TransformerConfig) -> None:
-    body = cfg.layers - 1 - len(cfg.tail_kinds)
-    if not cfg.lead_kind or body < 0 or body % len(cfg.layer_kinds):
-        raise ValueError(
-            f"layers {cfg.layers} is not one leading {cfg.lead_kind!r} "
-            f"layer, whole periods of {cfg.layer_kinds!r} and the "
-            f"trailing layers {cfg.tail_kinds!r}")
-    if not (cfg.kda_conv >= 2 and cfg.mla_latent and cfg.mla_rope_dim
-            and cfg.num_experts and cfg.dense_mlp_hidden):
-        raise ValueError("a pattern of kda and mla layers needs kda_conv "
-                         "(taps, >= 2), mla_latent, mla_rope_dim, "
-                         "num_experts and dense_mlp_hidden")
-    if cfg.kv_heads != cfg.heads:
-        raise ValueError("a pattern of kda and mla layers has heads that "
-                         f"are all alike: kv_heads {cfg.kv_heads} is not "
-                         f"heads {cfg.heads}")
+    """The two forms. With a lead: one leading layer with the dense MLP
+    (`dense_mlp_hidden`), whole periods, the tail. Without (`lead_kind` ""):
+    whole periods alone, every layer sparse, so no `tail_kinds` and no
+    `dense_mlp_hidden`. Then what each kind that has a layer needs."""
+    body = cfg.layers - bool(cfg.lead_kind) - len(cfg.tail_kinds)
+    if cfg.lead_kind:
+        if body < 0 or body % len(cfg.layer_kinds):
+            raise ValueError(
+                f"layers {cfg.layers} is not one leading {cfg.lead_kind!r} "
+                f"layer, whole periods of {cfg.layer_kinds!r} and the "
+                f"trailing layers {cfg.tail_kinds!r}")
+        if not cfg.dense_mlp_hidden:
+            raise ValueError(
+                f"the leading {cfg.lead_kind!r} layer has the dense MLP: "
+                "dense_mlp_hidden is its width (lead_kind '' for a pattern "
+                "whose every layer routes)")
+    else:
+        if cfg.tail_kinds or body <= 0 or body % len(cfg.layer_kinds):
+            raise ValueError(
+                f"without a leading layer (lead_kind '') layers "
+                f"{cfg.layers} is whole periods of layer_kinds "
+                f"{cfg.layer_kinds!r} and nothing behind them (tail_kinds "
+                f"{cfg.tail_kinds!r})")
+        if cfg.dense_mlp_hidden:
+            raise ValueError(
+                f"dense_mlp_hidden {cfg.dense_mlp_hidden} is the leading "
+                "layer's dense MLP, and lead_kind '' states no leading "
+                "layer: every layer routes")
+    if not cfg.num_experts:
+        raise ValueError("a pattern of kda, mla and gkv layers routes behind "
+                         "every layer but a leading one: num_experts")
+    if cfg.layers_of("kda") and cfg.kda_conv < 2:
+        raise ValueError(f"a kda layer convolves its inputs: kda_conv "
+                         f"{cfg.kda_conv} is its taps, >= 2")
+    if cfg.layers_of("mla") and not (cfg.mla_latent and cfg.mla_rope_dim):
+        raise ValueError("an mla layer keeps mla_latent + mla_rope_dim "
+                         f"values a position: they are {cfg.mla_latent} and "
+                         f"{cfg.mla_rope_dim}")
+    if cfg.layers_of("gkv"):
+        if cfg.heads % cfg.kv_heads:
+            raise ValueError(
+                f"a gkv layer's {cfg.heads} query heads are whole groups of "
+                f"kv_heads {cfg.kv_heads}")
+    else:
+        if cfg.kv_heads != cfg.heads:
+            raise ValueError(
+                "a pattern of kda and mla layers has heads that are all "
+                f"alike: kv_heads {cfg.kv_heads} is not heads {cfg.heads} "
+                "(a gkv layer alone reads kv_heads)")
+        if cfg.gqa_gate:
+            raise ValueError("gqa_gate is a gkv layer's output gate, and no "
+                             "layer of this pattern is one")
+    if cfg.kda_neg_eigval and not cfg.layers_of("kda"):
+        raise ValueError("kda_neg_eigval doubles a kda layer's step, and no "
+                         "layer of this pattern is one")
 
 
 def kept(cfg: TransformerConfig, max_len: int):
-    """A "kda" layer's matrix states, float32 whatever the stream's dtype,
+    """A "gkv" layer's K/V rows of `kv_heads` heads in slots of `max_len`.
+    A "kda" layer's matrix states, float32 whatever the stream's dtype,
     and its convolutions' windows, the `kda_conv - 1` last inputs of q, k
     and v flat in one row a sequence (positions, then q | k | v, heads,
     head_dim): a slot is one row of whole lanes, where [taps - 1, 3, heads,
     D] a slot made the chip's compiler transpose the stack in and out of
     every step. An "mla" layer's one latent row a position."""
     kda, nh, d = cfg.layers_of("kda"), cfg.heads, cfg.hd
-    return (Kept(("mat",), kda, None, (nh, d, d), F32),
+    return (Kept(("k", "v"), cfg.layers_of("gkv"), max_len,
+                 (cfg.kv_heads, d)),
+            Kept(("mat",), kda, None, (nh, d, d), F32),
             Kept(("conv",), kda, None, ((cfg.kda_conv - 1) * 3 * nh * d,)),
             Kept(("latent",), cfg.layers_of("mla"), max_len,
                  (cfg.latent_row,)))
@@ -156,7 +221,20 @@ def leaves(cfg: TransformerConfig) -> dict:
     out[at + ("o_norm",)] = ((n, d), None, ("layers", "norm"))
     out[at + ("wo",)] = ((n, nh, d, h), nh * d,
                          ("layers", "heads", "head_dim", "embed"))
-    out.update(mla_leaves(cfg, (cfg.layers_of("mla"),), ("layers",)))
+    if cfg.layers_of("mla"):
+        out.update(mla_leaves(cfg, (cfg.layers_of("mla"),), ("layers",)))
+    n, at = cfg.layers_of("gkv"), ("blocks", "gkv")
+    if n:  # plain matrices [in, heads * D], as the projections above
+        wide = ((n, h, nh * d), h, heads)
+        narrow = ((n, h, cfg.kv_heads * d), h,
+                  ("layers", "embed", "kv_heads"))
+        out[at + ("ln_attn",)] = ((n, h), None, ("layers", "norm"))
+        out.update({at + ("wq",): wide, at + ("wk",): narrow,
+                    at + ("wv",): narrow})
+        if cfg.gqa_gate:
+            out[at + ("wg",)] = wide
+        out[at + ("wo",)] = ((n, nh * d, h), nh * d,
+                             ("layers", "heads", "embed"))
     out.update(mlp_leaves(cfg))
     return out
 
@@ -361,6 +439,8 @@ def kda_attention(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
         beta = jax.nn.sigmoid(jnp.einsum(
             "bsh,hn->bsn", y, p["w_b"].astype(y.dtype),
             preferred_element_type=F32))
+        if cfg.kda_neg_eigval:  # steps in (0, 2): eigenvalues in (-1, 1)
+            beta = 2.0 * beta
         # a position that is no sequence's leaves the state as it is
         log_a = log_a * real[:, :, None, None]
         beta = beta * real[:, :, None]
@@ -569,6 +649,38 @@ def mla_attention(cfg: TransformerConfig, x, p, positions, latent,
     return x + out, latent
 
 
+# -- the grouped-attention layer ---------------------------------------------------
+
+def gkv_attention(cfg: TransformerConfig, x, p, positions, k_cache, v_cache,
+                  kv_len_mask, layer, rows=None):
+    """The attention half of "gkv" layer `layer` (its index in `KVCache.k`):
+    grouped attention over the slots' rows, no rotation, the output gated
+    where `gqa_gate`. Returns (x, k_cache, v_cache). The scopes are cut
+    where the bytes change: the four matrices read (`gqa.project`: the
+    gate's among them), the rows written and read (`gqa.attend`), the gate's
+    elementwise pass, `wo`."""
+    b, s, _ = x.shape
+    y = _rms_norm(x, p["ln_attn"], cfg.norm_eps)
+    with jax.named_scope("gqa.project"):
+        q, k, v = (_to_heads(y, p[w], n) for w, n in (
+            ("wq", cfg.heads), ("wk", cfg.kv_heads), ("wv", cfg.kv_heads)))
+        if cfg.gqa_gate:
+            gate = jnp.einsum("bsh,hm->bsm", y, p["wg"].astype(y.dtype),
+                              preferred_element_type=F32)
+    with jax.named_scope("gqa.attend"):
+        k_cache, v_cache, held = _write_stack(layer)(
+            k_cache, v_cache, k, v, positions)
+        o = attend_held(q, held, positions, kv_len_mask, rows).reshape(
+            b, s, -1)
+    if cfg.gqa_gate:
+        with jax.named_scope("gqa.gate"):
+            o = o.astype(F32) * jax.nn.sigmoid(gate)
+    with jax.named_scope("gqa.out"):
+        out = jnp.einsum("bsm,mh->bsh", o.astype(x.dtype),
+                         p["wo"].astype(x.dtype))
+    return x + out, k_cache, v_cache
+
+
 # -- the MLP halves ---------------------------------------------------------------
 
 @jax.named_scope("moe_router")
@@ -592,22 +704,26 @@ def router(cfg: TransformerConfig, x, p):
     return weights * cfg.routed_scale, experts
 
 
-CARRIED = ("mat", "conv", "latent")  # beside the stream, in `layer`'s carry
+# beside the stream, in `layer`'s carry
+CARRIED = ("k", "v", "mat", "conv", "latent")
 
 
 def layer(cfg: TransformerConfig, call, kind: str, i, n, carry):
     """`pattern.forward_cached`'s one layer: the attention of `kind` at
-    layer `i` of its kind over the matrix states and convolution windows or
-    the latent rows, then the leading layer's dense MLP (`n` None) or sparse
-    layer `n`'s experts."""
-    x, mat, conv, latent = carry
+    layer `i` of its kind over the matrix states and convolution windows,
+    the latent rows or the K/V rows, then the leading layer's dense MLP (`n`
+    None) or sparse layer `n`'s experts."""
+    x, k, v, mat, conv, latent = carry
     p = _take(call.blocks[kind], i)
     if kind == "kda":
         x, mat, conv = kda_attention(cfg, x, p, mat, conv, call.row_mask, i)
-    else:
+    elif kind == "mla":
         x, latent = mla_attention(cfg, x, p, call.positions, latent,
                                   call.kv_len_mask, call.row_mask, i,
                                   call.rows)
+    else:
+        x, k, v = gkv_attention(cfg, x, p, call.positions, k, v,
+                                call.kv_len_mask, i, call.rows)
     counted = None
     if n is None:
         dense = call.blocks["dense"]
@@ -617,4 +733,4 @@ def layer(cfg: TransformerConfig, call, kind: str, i, n, carry):
     else:
         x, *counted = sparse_mlp(cfg, x, call.sparse(n), call.row_mask, n,
                                  router)
-    return (x, mat, conv, latent), counted
+    return (x, k, v, mat, conv, latent), counted

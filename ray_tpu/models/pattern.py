@@ -305,16 +305,17 @@ def cut(cfg: TransformerConfig) -> list:
     unit's kinds `repeats` times over, as ONE `lax.scan` or in a row. The
     period form (`lead_kind`) is its leading layer, one scan over the
     `periods` (of one, too) and the trailing layers as one unit in a row;
-    the list form (`layer_kinds` names every layer) is read off the list by
-    `runs`, behind the leading layer where the family has one (the one with
-    the dense MLP, `cfg.dense_mlp_hidden`), and a unit that repeats is
-    scanned. The period form does not go through `runs`: it would scan a
-    tail and could cut the lead into the first unit, another program."""
-    kinds = cfg.layer_kinds
+    the list form (`lead_kind` "": `layer_kinds` names every layer, or a
+    period that `cfg.kinds` repeats) is read off the list by `runs`, behind
+    the leading layer where the family has one (the one with the dense MLP,
+    `cfg.dense_mlp_hidden`), and a unit that repeats is scanned. The period
+    form does not go through `runs`: it would scan a tail and could cut the
+    lead into the first unit, another program."""
     if cfg.lead_kind:
         tail = [(cfg.tail_kinds, 1, False)] if cfg.tail_kinds else []
-        return [((cfg.lead_kind,), 1, False), (kinds, cfg.periods, True),
-                *tail]
+        return [((cfg.lead_kind,), 1, False),
+                (cfg.layer_kinds, cfg.periods, True), *tail]
+    kinds = cfg.kinds  # `layer_kinds` itself, or its whole periods
     lead = 1 if cfg.dense_mlp_hidden else 0
     longest = getattr(families.of(cfg), "RUN_MAX", RUN_MAX)
     return [(kinds[:1], 1, False)] * lead + [

@@ -170,9 +170,20 @@ class TransformerConfig:
     # attention of `heads` heads that keeps `mla_latent + mla_rope_dim`
     # values a position (`KVCache.latent`), no rotation applied. A pattern of
     # "window" and "full" leads with "full" and has no tail.
+    # The same family's second form (upstage/Solar-Open2-250B): `lead_kind`
+    # "", whole periods of `layer_kinds` ("gkv", "kda", "kda", "kda") and
+    # nothing before or behind them, EVERY layer with sparse experts.
+    # "gkv": grouped attention of `heads` query heads on `kv_heads` K/V heads
+    # over K/V rows (`KVCache.k` / `.v`), no rotation; `gqa_gate`: its
+    # output times the sigmoid of a projection of the sublayer's normed
+    # input, elementwise, before `wo`. `kda_neg_eigval`: a "kda" layer's step
+    # is `2 * sigmoid(.)`, so that `I - beta k k^T` has an eigenvalue in
+    # (-1, 1) along k.
     lead_kind: str = "full"
     tail_kinds: Tuple[str, ...] = ()
     kda_conv: int = 0
+    gqa_gate: bool = False
+    kda_neg_eigval: bool = False
     mla_latent: int = 0
     mla_rope_dim: int = 0
     # How a linear router scores: "softmax" (`moe_router`), or "sigmoid" with
@@ -556,6 +567,20 @@ PRESETS: Dict[str, TransformerConfig] = {
         tail_kinds=("kda", "mla"), kda_conv=4, mla_latent=32, mla_rope_dim=8,
         router_score="sigmoid", dense_mlp_hidden=192, routed_scale=2.446,
         shared_expert_hidden=64, experts_held=(0, 8), dtype=jnp.float32,
+    ),
+    # upstage/Solar-Open2-250B's pattern at debug widths, the family's form
+    # without a lead (models/kimi_linear.py): two periods of one gated
+    # grouped-attention layer (8 query heads on 2 K/V heads: groups of 4)
+    # and three delta-rule layers whose steps reach 2, every layer sparse:
+    # sigmoid top-4 of 16 experts, 4 held, one shared. The published widths
+    # are the benchmark's to build (benchmarks/runners/serve_solar_open2.py)
+    "solar_open2_debug": dict(
+        vocab_size=512, hidden=128, mlp_hidden=64, layers=8, heads=8,
+        kv_heads=2, head_dim=16, max_seq=128, remat=False, norm_eps=1e-5,
+        num_experts=16, experts_per_token=4, norm_topk_prob=True,
+        layer_kinds=("gkv", "kda", "kda", "kda"), lead_kind="", kda_conv=4,
+        gqa_gate=True, kda_neg_eigval=True, router_score="sigmoid",
+        shared_expert_hidden=64, experts_held=(0, 4), dtype=jnp.float32,
     ),
     # meituan-longcat/LongCat-Flash-Chat's double layer at debug widths
     # (models/longcat.py): 3 double layers of two latent attentions (4 heads

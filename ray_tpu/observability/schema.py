@@ -221,6 +221,19 @@ PROGRAM_SCOPES = {
                   "the held latent rows (absorbed), a prefill's over keys "
                   "and values expanded from its own fresh rows",
     "mla.out": "models/kimi_linear.py: a decode step's value expansion; wo",
+    "gqa.project": "models/kimi_linear.py: a grouped-attention (\"gkv\") "
+                   "layer's q, k and v projections AND its output gate's "
+                   "(`gqa_gate`: the matrix is read here)",
+    "gqa.attend": "models/kimi_linear.py: the row write and attention over "
+                  "the slots' K/V rows, no rotation (attend_cached inside "
+                  "it: a decode step's kernel over the held rows, a "
+                  "prefill's flash forward over its fresh ones). Which "
+                  "layers are of which kind and how many cache layers of "
+                  "K/V rows a sequence keeps: "
+                  "`engine_stats()['layers_by_kind']` / `['kv_layers_kept']`",
+    "gqa.gate": "models/kimi_linear.py: the gate's sigmoid times the "
+                "attention's output, elementwise",
+    "gqa.out": "models/kimi_linear.py: wo",
     "ssm.project": "models/nemotron_h.py: a state-space mixer's input "
                    "projection (gate, convolution channels, steps), the "
                    "steps' softplus and the decay's log",

@@ -484,7 +484,19 @@ NEMOTRON_H = "nemotron-3-super-serve-ep8-d22"
 MIMO = "mimo-v2-flash-serve-ep16-d11"
 JAMBA = "jamba2-3b-serve-whole"
 OURO = "ouro-2.6b-serve-whole"
+SOLAR = "solar-open2-250b-serve-ep16-d8"
 PATTERN_CONFIGS = {
+    # Solar-Open2-250B's published widths, layers 0-7 of 48 (two periods of
+    # one gated GQA layer and three delta-rule layers of 64 heads), one
+    # chip's share of a layer that sixteen hold: 20 of 320 experts, an eighth
+    # of the vocabulary; 16 slots of 8704 positions, the 8192 bucket
+    SOLAR: dict(
+        name="solar_open2_debug", vocab_size=24576, hidden=4096,
+        mlp_hidden=1280, layers=8, heads=64, kv_heads=8, head_dim=128,
+        max_seq=1048576, num_experts=320, experts_per_token=8,
+        experts_held=(0, 20), shared_expert_hidden=1280,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, slots=16, max_len=8704,
+        bucket=8192),
     # Ouro-2.6B whole (the ONE block, looped; here and not among
     # SERVE_CONFIGS because the tests that walk those hold 16 slots x 2048):
     # its published widths, all 48 layers run four times, the whole
@@ -1086,6 +1098,83 @@ def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     from ray_tpu.observability import schema
 
     assert set(runner.SCOPES) <= set(schema.PROGRAM_SCOPES)
+
+
+def test_delta_rule_beside_kv_rows_serve_programs_compile_and_fit(
+        serve_programs):
+    """The `serve-kda-gqa-docqa-8k-in-512-out` deployment (Solar-Open2 at its
+    published widths, layers 0-7, 20 of 320 experts, 16 slots x 8704): the
+    decode step is given the K/V rows, the matrix states of 64 heads and the
+    convolution windows to keep (all aliased in to out) and holds no
+    temporary of one layer's states' size; ONE scanned body of four layers:
+    three state updates with the Mosaic call that is given the stack (64
+    heads in one grid step: the chip's compiler takes its 32 MiB of fast
+    memory), under `kda.state`, one `decode_attention` under `gqa.attend`
+    and four layers' grouped matmuls; the prefill of the 8192 bucket attends
+    with the flash forward and passes its rows by the experts in tiles; both
+    fit the chip beside the cache, and the new scopes reach the compiled
+    text."""
+    from benchmarks import harness, moe_cost, scope_ops
+    from ray_tpu.models import pattern
+
+    cfg, prefill, decode, cache = serve_programs(SOLAR)
+    slots = cache.lengths.shape[0]
+    assert pattern.cut(cfg) == [(("gkv", "kda", "kda", "kda"), 2, True)]
+    assert cfg.sparse_layers == 8 and cfg.num_params() == 3898793600
+    assert cache.k.shape == (2, slots, 8704, 8, 128) and cache.latent is None
+    assert cache.mat.shape == (6, slots, 64, 128, 128)
+    assert cache.mat.dtype == jnp.float32
+    assert cache.conv.shape == (6, slots, 3 * 3 * 64 * 128)
+    kept = _arg_bytes((cache.k, cache.v, cache.mat, cache.conv))
+    assert round(kept / 1e9, 2) == 1.56
+    for name, program in (("prefill[8192]", prefill),
+                          (f"decode[{slots}x8704]", decode)):
+        m = program.memory_analysis()
+        print(f"{name}: arguments {m.argument_size_in_bytes / 1e9:.2f} + "
+              f"outputs {m.output_size_in_bytes / 1e9:.2f} + temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} - aliased "
+              f"{m.alias_size_in_bytes / 1e9:.2f} = "
+              f"{_total_bytes(program) / 1e9:.2f} GB")
+        assert "s32[320]" in program.as_text()  # the load over all experts
+        # no layer's held experts are copied out of their stack
+        assert not re.search(r"bf16\[20,(4096,1280|1280,4096)\]",
+                             program.as_text())
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= kept
+    assert _total_bytes(decode) < 10e9
+    # no copy of the state stack (0.40 GB) or of one layer of it (67 MB)
+    assert m.temp_size_in_bytes < _arg_bytes(cache.mat) / 6
+    assert _total_bytes(prefill) + kept < 15.5e9  # beside the engine's cache
+    text = decode.as_text()
+    layer_states = math.prod(cache.mat.shape[1:])
+    for op_name, dtype, dims, op in _results(text):
+        assert not (dtype == "f32" and math.prod(dims) == layer_states), (
+            op_name, dims, op)
+    runner = harness.load_module("runners", "serve_solar_open2")
+    scopes = scope_ops.op_scopes(text, runner.SCOPES)
+    print({k: len(v) for k, v in scopes.items()})
+    assert set(scopes) >= set(runner.SCOPES) - {"kda.prefill_scan"}
+
+    def mosaic(name, text=text):
+        return {scope_ops._INSTRUCTION.match(line)[1]
+                for line in text.splitlines()
+                if "tpu_custom_call" in line and "%" + name in line}
+
+    assert len(mosaic("kda_state_update")) == 3  # the body's three kda layers
+    assert mosaic("kda_state_update") <= set(scopes["kda.state"])
+    assert len(mosaic("decode_attention")) == 1  # its one gkv layer
+    assert mosaic("decode_attention") <= set(scopes["gqa.attend"])
+    experts = mosaic("ragged_dot")
+    assert all(moe_cost.EXPERT_OP.search(op) for op in experts)
+    assert experts <= set(scopes["moe_experts"]) and "ragged-dot" not in text
+    assert serve_programs.grouped_paths[SOLAR] == {
+        "decode": "kernel", "prefill_8192": "row_tiles"}
+    assert serve_programs.attention_paths(SOLAR) == {"prefill_8192": "flash"}
+    ptext = prefill.as_text()
+    pscopes = scope_ops.op_scopes(ptext, runner.SCOPES)
+    assert set(pscopes) >= set(runner.SCOPES) - {"kda.state", "sample"}
+    assert len(mosaic("flash_attention_fwd", ptext)) == 1
+    assert not mosaic("kda_state_update", ptext)
 
 
 def test_state_space_serve_programs_compile_and_fit(serve_programs):

@@ -496,8 +496,10 @@ def test_the_reference_without_a_part_is_another_model(params, change):
     (dict(layer_kinds=("kda", "full")), "unknown layer kinds"),
     (dict(layer_kinds=("kda", "ssm")), "unknown layer kinds"),
     (dict(layers=10), "whole periods"),
-    (dict(kda_conv=0), "needs kda_conv"),
-    (dict(mla_latent=0), "needs kda_conv"),
+    (dict(kda_conv=0), "kda_conv 0 is its taps"),
+    (dict(mla_latent=0), r"mla_latent \+ mla_rope_dim values a position"),
+    (dict(dense_mlp_hidden=0), "dense_mlp_hidden is its width"),
+    (dict(gqa_gate=True), "gqa_gate is a gkv layer's output gate"),
     (dict(window=8), "window: no field of .*kimi_linear"),
     (dict(kv_heads=2), "all alike: kv_heads 2"),
     (dict(tie_embeddings=True), "tie_embeddings: no field of"),

@@ -20,7 +20,7 @@ PRESETS = {
     "transformer": ("debug", "moe_debug", "olmoe_debug", "ouro_debug"),
     "zaya": ("zaya_debug",),
     "laguna": ("laguna_debug", "mimo_v2_debug"),
-    "kimi_linear": ("kimi_linear_debug",),
+    "kimi_linear": ("kimi_linear_debug", "solar_open2_debug"),
     "longcat": ("longcat_debug",),
     "nemotron_h": ("nemotron_h_debug", "jamba_debug"),
 }
@@ -37,7 +37,7 @@ AWAY = dict(
     mla_q_rank=8, mla_rotate=True, mla_scales=(2.0, 2.0),
     router_score="sigmoid", zero_experts=4, ssm_heads=4, ssm_head_dim=16,
     ssm_groups=4, ssm_state=8, ssm_conv=3, ssm_chunk=64, ssm_dt_rank=4,
-    moe_latent=16,
+    moe_latent=16, gqa_gate=True, kda_neg_eigval=True,
     expert_act="relu2", window_kv_heads=2, value_dim=8, window_sink=True,
     value_scale=0.5, window_partial_rotary=0.5, loop_steps=2, sandwich=True,
     exit_threshold=0.5)
@@ -81,6 +81,10 @@ def hand_rows(name: str, cfg, lens):
     if name == "nemotron_h":  # the attention layers' rows; a mixer keeps none
         gqa = cfg.kinds.count("gqa")
         return gqa * int(lens.sum()), gqa * granules(lens, MAX_LEN)
+    if name == "kimi_linear" and cfg.kinds.count("gkv"):
+        # delta-rule layers beside K/V rows: the "gkv" layers' rows alone
+        gkv = cfg.kinds.count("gkv")
+        return gkv * int(lens.sum()), gkv * granules(lens, MAX_LEN)
     latent = {"kimi_linear": cfg.kinds.count("mla"),
               "longcat": 2 * cfg.layers}[name]
     width = -(-(cfg.mla_latent + cfg.mla_rope_dim) // 128) * 128
@@ -146,6 +150,11 @@ CUTS = {
         (("kda",), 1, False), (PERIOD, 2, True), (("kda", "mla"), 1, False)],
     ("kimi_linear_debug", (("layers", 9), ("tail_kinds", ()))): [
         (("kda",), 1, False), (PERIOD, 2, True)],
+    # the family's form without a lead: whole periods, read off `cfg.kinds`
+    # by `runs` as a list is; ONE period has nothing to repeat but its kda
+    ("solar_open2_debug", ()): [(("gkv", "kda", "kda", "kda"), 2, True)],
+    ("solar_open2_debug", (("layers", 4),)): [
+        (("gkv",), 1, False), (("kda",), 3, True)],
     # the list form: `runs` behind the first layer where it has the dense MLP
     ("mimo_v2_debug", ()): [
         (("full",), 1, False), (("window",), 2, True), (("full",), 1, False),
@@ -224,3 +233,31 @@ def test_a_common_field_is_refused_by_no_family(field):
             value = not getattr(cfg, field)
         assert getattr(dataclasses.replace(cfg, **{field: value}),
                        field) == value
+
+
+@pytest.mark.parametrize("preset, kinds, module_name", [
+    ("solar_open2_debug", {"gkv", "kda"}, "kimi_linear"),
+    ("kimi_linear_debug", {"kda", "mla"}, "kimi_linear"),
+    ("nemotron_h_debug", {"ssm", "gqa", "lmoe"}, "nemotron_h"),
+    ("jamba_debug", {"ssm1", "gqa", "mlp"}, "nemotron_h"),
+])
+def test_grouped_attention_has_one_name_a_family(preset, kinds, module_name):
+    """K/V rows of grouped heads are "gqa" as one of `nemotron_h`'s
+    one-sublayer layers and "gkv" as the attention half of a `kimi_linear`
+    layer: `PATTERNS` takes the first family whose kinds hold a pattern's, so
+    a kind in two families would send one of them astray."""
+    cfg = T.config(preset)
+    assert set(cfg.kinds) == kinds
+    assert families.of(cfg) is module(module_name)
+    owners = [n for n, runs in families.PATTERNS.items()
+              if {"gqa", "gkv"} & runs]
+    assert owners == ["kimi_linear", "nemotron_h"]
+    assert not families.PATTERNS["kimi_linear"] & families.PATTERNS[
+        "nemotron_h"]
+
+
+def test_a_pattern_of_two_families_kinds_is_refused_by_name():
+    with pytest.raises(ValueError, match="unknown layer kinds"):
+        T.config("solar_open2_debug", layer_kinds=("gqa", "kda", "kda", "kda"))
+    with pytest.raises(ValueError, match="unknown layer kinds"):
+        T.config("nemotron_h_debug", layer_kinds=("ssm", "gkv"), layers=2)
