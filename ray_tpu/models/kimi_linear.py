@@ -35,9 +35,10 @@ three convolutions (`KVCache.conv`). A decode step (S == 1) is one update of
 S, every product into it exact in float32 (on a TPU `ops.delta_rule.
 state_update`: the stack read once and written once, in place). A call with S > 1 is a prefill
 FROM POSITION 0 (every engine's) and runs the recurrence a CHUNK at a time
-(`kda_chunks`); the state and the window it leaves are those at each
-sequence's TRUE last position (`row_mask`): a pad position has beta 0 and
-decay 1.
+(`kda_chunks`: on a TPU the kernel `ops.delta_rule.chunk_scan`, a block of
+heads' states in fast memory across the chunks); the state and the window it
+leaves are those at each sequence's TRUE last position (`row_mask`): a pad
+position has beta 0 and decay 1.
 
 **An "mla" layer** (`heads` heads; here no rotation is applied: the
 `mla_rope_dim` "rope" dimensions are one key part shared by all heads): `[q_n
@@ -333,10 +334,21 @@ def kda_step(state, q, k, v, log_a, beta):
     return state, (q[..., None] * state).sum(-2)
 
 
-def kda_chunks(state, q, k, v, log_a, beta, chunk: int = KDA_CHUNK):
+def kda_chunks(state, q, k, v, log_a, beta, chunk: int = None):
     """The recurrence over S positions a chunk at a time: state [B, H, K, V]
     float32 entering; q, k, v, log_a [B, S, H, D] float32; beta [B, S, H].
     Returns (state after position S - 1, o [B, S, H, V]).
+
+    Two spellings of the one algebra below, chosen by what the call shows
+    (`delta_rule.chunk_scan_takes`: on a TPU, float32, keys and values in
+    whole lanes, at least one chunk of positions): the kernel `ops.
+    delta_rule.chunk_scan`, the heads an extent of its grid (32 and 64 are
+    the same code); else the scan of XLA operations here, which is the CPU's
+    path, the toy configurations' and the tests' second reference. The pick
+    is booked (`traced.TOLD["delta_rule"]`: `engine_stats()["kda_path"]`
+    says "scan:kernel" or "scan:plain" by program). `chunk`: positions a
+    chunk, by default the spelling's own (`KDA_CHUNK`, `delta_rule.
+    SCAN_CHUNK`).
 
     Exact, derived from the recurrence. With g_t the running sum of log_a
     inside a chunk (G_t = exp(g_t)) and S_0 the state entering it, `S_t =
@@ -349,15 +361,26 @@ def kda_chunks(state, q, k, v, log_a, beta, chunk: int = KDA_CHUNK):
     channel decays fast (the seeded rates reach e^-13 a position). The
     products with the state run at the highest precision.
 
-    Chunks of 32: on the v5e a layer's 2,048 positions take 5.0 ms at 32,
-    7.6 at 64 and 12.3 at 128 (the [C, C, D] ratios are elementwise work,
-    the scan's steps about 40 us each). Tried and slower or no faster (my
-    chip runs, PR 38): the ratios in sub-chunks of 16 with matmuls between
-    them (5.0 at 32), the system inverted by halves in place of the
-    triangular solve (5.9), and everything that does not depend on the state
-    computed for all chunks at once ahead of a scan of four matmuls (7.1)."""
+    The plain spelling's chunks of 32: on the v5e a layer's 2,048 positions
+    at 32 heads take 5.0 ms at 32, 7.6 at 64 and 12.3 at 128 (the [C, C, D]
+    ratios are elementwise work, the scan's steps about 40 us each; at 64
+    heads about 120 us, 31.6 ms a layer of 8,192 positions: PERF.md, PR 67).
+    XLA spellings tried and slower or no faster (my chip runs, PR 38): the
+    ratios in sub-chunks of 16 with matmuls between them (5.0 at 32), the
+    system inverted by halves in place of the triangular solve (5.9), and
+    everything that does not depend on the state computed for all chunks at
+    once ahead of a scan of four matmuls (7.1): each kept a step a list of
+    XLA operations with the state and the [C, C] matrices through HBM
+    between them. The kernel (PR 68: chunks of 64 in sub-chunks of 16, four
+    heads a grid step; my chip runs, PR 68): the call alone 1.1 ms a layer
+    of 2,048 positions at 32 heads and 7.8 of 8,192 at 64; under this scope
+    in the cells' prefill programs 1.2 (for 3.9) and 10.3 (for 31.6)."""
+    kernel = delta_rule.chunk_scan_takes(state, q)
+    delta_rule.book("scan", kernel)
+    if kernel:
+        return delta_rule.chunk_scan(state, q, k, v, log_a, beta, chunk)
     b, s, h, d = q.shape
-    chunk = min(chunk, s)
+    chunk = min(chunk or KDA_CHUNK, s)
     pad = -s % chunk
     if pad:  # beta 0 and decay 1: the state passes a pad position unchanged
         q, k, v, log_a = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -448,7 +471,10 @@ def kda_attention(cfg: TransformerConfig, x, p, mat, conv, row_mask, layer):
         gate = jax.nn.sigmoid(_to_heads(gate, p["w_gb"], nh, F32))
     # the state's read and write are the sublayer's, under its scope
     with jax.named_scope("kda.state" if s == 1 else "kda.prefill_scan"):
-        if s == 1 and delta_rule.state_update_takes(mat):
+        kernel = s == 1 and delta_rule.state_update_takes(mat)
+        if s == 1:
+            delta_rule.book("state", kernel)
+        if kernel:
             mat, o = delta_rule.state_update(  # read once, written once
                 mat, layer, q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
                 beta[:, 0])

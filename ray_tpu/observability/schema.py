@@ -204,9 +204,12 @@ PROGRAM_SCOPES = {
                 "beta, the low-rank output gate",
     "kda.state": "models/kimi_linear.py: a decode step's state update: "
                  "every sequence's matrix state decayed, corrected by one "
-                 "rank-one term and read out (read once, written once)",
+                 "rank-one term and read out (read once, written once; "
+                 "`engine_stats()['kda_path']` says kernel or plain)",
     "kda.prefill_scan": "models/kimi_linear.py: a prefill's recurrence, a "
-                        "chunk of positions at a time",
+                        "chunk of positions at a time (on a TPU one kernel "
+                        "that keeps a block of heads' states in fast memory "
+                        "over the chunks: `kda_path` says which)",
     "kda.out": "models/kimi_linear.py: the head norm, the output gate, wo",
     "mla.project": "models/kimi_linear.py: a latent-attention sublayer's "
                    "query (through its low-rank factors and their norm, "

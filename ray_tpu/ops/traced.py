@@ -30,6 +30,10 @@ TOLD = {
     # a state-space mixer's recurrence (`ops.ssd.book`): "scan:kernel" (a
     # prefill's), "state:kernel" (a step's) or ":plain"
     "ssm": "ssm_path",
+    # a delta-rule layer's recurrence (`ops.delta_rule.book`): "scan:kernel"
+    # (a prefill's chunked scan, `kimi_linear.kda_chunks`), "state:kernel"
+    # (a decode step's update, `kimi_linear.kda_attention`) or ":plain"
+    "delta_rule": "kda_path",
 }
 _seen: contextvars.ContextVar = contextvars.ContextVar(
     "traced_choices", default=None)

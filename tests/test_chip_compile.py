@@ -629,6 +629,8 @@ def serve_programs(topo):
     compiled.prefill_at = prefill_at
     # what the engine's `prefill_attention_path` says of the prefills so far
     compiled.attention_paths = lambda name: engine(name)[0].prefill_attention_path
+    # and its `kda_path` of the delta-rule layers' two choices
+    compiled.kda_paths = lambda name: engine(name)[0].kda_path
     return compiled
 
 
@@ -1019,6 +1021,45 @@ def _prefill_passes_row_tiles(text, scopes, layer_stack=None) -> set:
     return called
 
 
+def _chunk_scan_calls(text) -> set:
+    """The prefill's delta-rule kernels in a compiled text; no `while` is
+    left under the recurrence's scope (PR 68: `kda_chunks` was a scan of XLA
+    operations, 1,536 steps a prefill of 8,192 positions)."""
+    from benchmarks import scope_ops
+
+    lines = text.splitlines()
+    assert not [line for line in lines if "kda.prefill_scan" in line
+                and re.search(r"\bwhile\(", line)]
+    return {scope_ops._INSTRUCTION.match(line)[1] for line in lines
+            if "tpu_custom_call" in line and "%kda_chunk_scan" in line}
+
+
+@pytest.mark.parametrize("positions, heads", [(2048, 32), (8192, 64)])
+def test_chunk_scan_compiles(topo, positions, heads):
+    """The delta-rule prefill kernel at both cells' shapes (Kimi-Linear's
+    2,048 bucket at 32 heads, Solar-Open2's 8,192 at 64; keys and values of
+    128): ONE Mosaic call within the fast memory a call is given unasked (16
+    MiB: a grid step holds four heads' states and a chunk of each operand
+    twice over, 1.5 MiB), and around it no more than the operands laid [B,
+    H, S, D] and the output laid back."""
+    from ray_tpu.ops import delta_rule
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    rows = arr(1, positions, heads, 128)
+    compiled = jax.jit(delta_rule.chunk_scan).lower(
+        arr(1, heads, 128, 128), rows, rows, rows, rows,
+        arr(1, positions, heads)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kda_chunk_scan" in text
+    assert not re.search(r"\bwhile\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 6 * positions * heads * 128 * 4
+
+
 def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     """The `serve-kda-mla-rollout-long-out` deployment (Kimi-Linear at its
     published widths and depth, 16 of 256 experts, 32 slots x 4096): the
@@ -1090,8 +1131,12 @@ def test_linear_and_latent_serve_programs_compile_and_fit(serve_programs):
     flash = _flash_forward_calls(ptext)
     assert len(flash) == 2, flash  # a period's latent layer, and the last
     assert set(flash) <= set(pscopes["mla.attend"])
-    # a chunk's [heads, 32, 32] system inside the scan over 64 chunks
-    assert re.search(r"f32\[1,32,32,32\]", prefill.as_text())
+    # the recurrence is the kernel, a call a kda layer of the text (the
+    # lead, a period's three, the tail's one), and no loop of XLA operations
+    scans = _chunk_scan_calls(ptext)
+    assert len(scans) == 5 and scans <= set(pscopes["kda.prefill_scan"])
+    assert serve_programs.kda_paths(KIMI_LINEAR) == {
+        "decode": "state:kernel", "prefill_2048": "scan:kernel"}
     leaves = len(jax.tree.leaves(jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.key(0)))))
     assert _entry_parameters(decode) == leaves + 5 + 6  # k, v, lengths + 3
@@ -1175,6 +1220,10 @@ def test_delta_rule_beside_kv_rows_serve_programs_compile_and_fit(
     assert set(pscopes) >= set(runner.SCOPES) - {"kda.state", "sample"}
     assert len(mosaic("flash_attention_fwd", ptext)) == 1
     assert not mosaic("kda_state_update", ptext)
+    scans = _chunk_scan_calls(ptext)  # the body's three kda layers
+    assert len(scans) == 3 and scans <= set(pscopes["kda.prefill_scan"])
+    assert serve_programs.kda_paths(SOLAR) == {
+        "decode": "state:kernel", "prefill_8192": "scan:kernel"}
 
 
 def test_state_space_serve_programs_compile_and_fit(serve_programs):

@@ -113,39 +113,82 @@ def test_prefill_then_decode_is_the_reference(params):
         assert out["argmax_agree"] == 1.0
 
 
-@pytest.mark.parametrize("chunk", [4, 32, 128])
-def test_the_chunked_scan_is_the_token_scan(chunk):
+@pytest.fixture
+def scan_through_the_interpreter(monkeypatch):
+    """`ops.delta_rule.chunk_scan` where `kda_chunks` chooses it: the chip's
+    path on the CPU."""
+    import jax.experimental.pallas as pl
+
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+
+
+@pytest.mark.parametrize("steps", ["to_one", "to_two"])
+@pytest.mark.parametrize("path, chunk", [
+    ("plain", 4), ("plain", 32), ("plain", 128),
+    ("kernel", 16), ("kernel", 32), ("kernel", 64)])
+def test_the_chunked_scan_is_the_token_scan(path, chunk, steps, request):
     """State and outputs of `kda_chunks` against `kda_step` a position at a
-    time, from a state that is not zero, over 70 positions (a last chunk
-    that is not whole; one chunk longer than the sequence), one channel
-    decaying by e^-30 a position (its `1 / G` would overflow within a
-    chunk), one not at all, one key repeated thirty times (the system's
-    entries are then as large as they get), and a run of pad positions (beta
-    0, decay 1) that must leave the state alone."""
-    b, s, h, d = 2, 70, 3, 8
+    time, by both of its spellings (the scan of XLA operations at heads of
+    8; the kernel `ops.delta_rule.chunk_scan` through the interpreter at
+    heads of 128, one, two and four sub-chunks a chunk), from a state that
+    is not zero, over 70 positions (a last chunk that is not whole; one
+    chunk longer than the sequence), one channel decaying by e^-30 a
+    position (its `1 / G` would overflow within a chunk), one not at all,
+    one key repeated thirty times (the system's entries are then as large
+    as they get), a run of pad positions (beta 0, decay 1) that must leave
+    the state alone, bit for bit where a call is pads alone, six heads
+    (two of the kernel's blocks of three), and steps beta in (0, 1) or, as
+    `kda_neg_eigval` makes them, in (0, 2) (the system's entries twice as
+    large)."""
+    from ray_tpu.ops import delta_rule, traced
+
+    kernel = path == "kernel"
+    if kernel:
+        request.getfixturevalue("scan_through_the_interpreter")
+    b, s, h, d = 2, 70, 6, 128 if kernel else 8
     ks = jax.random.split(jax.random.key(chunk), 6)
     q, k, v = (jax.random.normal(key, (b, s, h, d)) for key in ks[:3])
+    q = q * (8 / d) ** 0.5  # of the length it has at heads of 8
     k = k.at[0, 10:40].set(k[0, 10])  # one key thirty times over: products of 1
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     log_a = -jnp.exp(jax.random.normal(ks[3], (b, s, h, d)))
     log_a = log_a.at[..., 0].set(-30.0).at[..., 1].set(0.0)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    if steps == "to_two":
+        beta = 2.0 * beta
+        assert float(beta.max()) > 1.7
     log_a = log_a.at[1, 60:].set(0.0)
     beta = beta.at[1, 60:].set(0.0)
     state = jax.random.normal(ks[5], (b, h, d, d))
+    assert delta_rule.chunk_scan_takes(state, q) == kernel
+    assert h // delta_rule.SCAN_HEADS and h % delta_rule.SCAN_HEADS
     want, outs = state, []
+    step = jax.jit(K.kda_step)
     for t in range(s):
-        want, o = K.kda_step(want, q[:, t], k[:, t], v[:, t], log_a[:, t],
-                             beta[:, t])
+        want, o = step(want, q[:, t], k[:, t], v[:, t], log_a[:, t],
+                       beta[:, t])
         outs.append(o)
         if t == 59:
             at_60 = want
-    got, o = jax.jit(functools.partial(K.kda_chunks, chunk=chunk))(
-        state, q, k, v, log_a, beta)
-    np.testing.assert_allclose(o, jnp.stack(outs, 1), atol=2e-5)
-    np.testing.assert_allclose(got, want, atol=2e-5)
+    scan = jax.jit(functools.partial(K.kda_chunks, chunk=chunk))
+    with traced.booked("delta_rule") as seen:
+        got, o = scan(state, q, k, v, log_a, beta)
+    assert seen == {f"scan:{path}"}
+    # steps up to two: `tests/test_solar_open2.py`'s tolerance for them
+    atol = 2e-5 if steps == "to_one" else 5e-5
+    np.testing.assert_allclose(o, jnp.stack(outs, 1), atol=atol)
+    np.testing.assert_allclose(got, want, atol=atol)
     np.testing.assert_array_equal(want[1], at_60[1])  # pads changed nothing
     assert np.isfinite(np.asarray(got)).all()
+    still, o = scan(state, q, k, v, jnp.zeros_like(log_a),
+                    jnp.zeros_like(beta))
+    np.testing.assert_array_equal(still, state)  # pads alone: bit for bit
+    np.testing.assert_allclose(
+        o, jnp.einsum("bshk,bhkv->bshv", q, state), atol=2e-5)
 
 
 def test_the_absorbed_step_is_the_expanded_form(params):

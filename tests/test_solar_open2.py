@@ -124,6 +124,11 @@ def test_the_scheduler_serves_it_with_slots_taken_again(params):
         got = R.compare_tokens(out, np.asarray(ref[0]))
         assert got["max_shortfall_over_std"] < 1e-3, got
     st = cb.stats
+    # off the chip both delta-rule choices say so, by program (what
+    # `engine_stats()["kda_path"]` carries)
+    assert cb.kda_path["decode"] == "state:plain"
+    assert {cb.kda_path[f"prefill_{b}"] for b in (16, 32, 64)} == {
+        "scan:plain"}
     assert st["state_installs"] == st["state_resets"] == 5
     assert st["moe_assignments"] == 4 * st["moe_rows"] * 8
     assert st["moe_assignments_held"] == sum(st["moe_expert_load"][:4])
