@@ -1,4 +1,4 @@
-"""Self time of the decode program's operations under `mla.attend` (the absorbed attention over the held latent rows), all latent-attention layers, per traced decode step."""
+"""Self time of the decode program's operations under `mla.attend` (the absorbed attention over the held latent rows), all latent-attention layers (a cell of double layers counts its 2 x `num_layers` attention sublayers), per traced decode step."""
 
 from benchmarks import readers, scope_ops
 

@@ -1,4 +1,4 @@
-"""Mean `ray_tpu.replica.detokenize` span: the WHOLE answer so far decoded again for one streamed token; the median `ids` and the `backlog`'s median, largest and share of zeros go to stderr."""
+"""Mean `ray_tpu.replica.detokenize` span: what one streamed token's text costs its handler thread; since PR 57 a window of the answer's last few ids decoded (the span's `decoded`), not the whole answer so far (its `ids`) again. The median `ids` and the `backlog`'s median, largest and share of zeros go to stderr."""
 
 import statistics
 

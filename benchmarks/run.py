@@ -33,6 +33,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import pickle
 import shutil
 import signal
 import subprocess
@@ -75,6 +76,9 @@ def _run_cell(args) -> int:
                 "trace": bool(args.trace), "t0_wall": args.t0_wall,
                 "out_dir": args.out_dir, "sample_to": args.sample_to}
     rec = runner.run(cell, run_args)
+    if args.keep_record:  # what the readers are given, less the cell
+        with open(args.keep_record, "wb") as f:
+            pickle.dump({k: rec[k] for k in ("counters", "trace", "device")}, f)
     result = assemble(cell, rec, bool(args.trace))
     with open(args.child, "w") as f:
         json.dump(result, f)
@@ -181,7 +185,8 @@ def parent(args) -> int:
            "--seconds", str(args.seconds), "--trace", str(args.trace),
            "--t0-wall", repr(T0_WALL), "--out-dir", out_dir,
            "--limit-s", repr(args.limit_s),
-           "--sample-to", args.sample_to] + (["--toy"] if args.toy else [])
+           "--sample-to", args.sample_to, "--keep-record", args.keep_record
+           ] + (["--toy"] if args.toy else [])
     env = dict(os.environ, PYTHONPATH=os.pathsep.join(
         p for p in (ROOT, os.environ.get("PYTHONPATH")) if p))
     if args.toy and cell["chips"] > 1 and \
@@ -241,6 +246,9 @@ def main() -> int:
                          "a result")
     ap.add_argument("--sample-to", default="",
                     help="with --trace 1: keep a small cut of the trace here")
+    ap.add_argument("--keep-record", default="",
+                    help="keep what the per-layer readers are given here "
+                         "(pickled): `same_readings.py` reads it")
     ap.add_argument("--child", help=argparse.SUPPRESS)
     ap.add_argument("--t0-wall", type=float, help=argparse.SUPPRESS)
     ap.add_argument("--out-dir", help=argparse.SUPPRESS)

@@ -1,11 +1,12 @@
 """What the grouped-attention layers of a configuration with delta-rule
 layers beside K/V rows require of the chip in one decode step. The yardstick
 of ``gqa_attention_roofline``; the held experts keep the yardstick they have
-(``laguna_cost.held_experts_cost``). The delta-rule layers' state update has
-none in this cell: ``kimi_linear_cost.state_update_cost`` counts
-``linear_attn_config.kda_layers``, a key this configuration does not publish
-and its file, which holds the published group whole, does not add; the time
-under ``kda.state`` is read all the same (``kda_state_ms_per_decode_step``).
+(``laguna_cost.held_experts_cost``), and so do the delta-rule layers' states
+(``kimi_linear_cost.state_update_cost``, which counts
+``linear_attn_config.kda_layers``: a key this configuration does not publish
+and its file, which holds the published group whole, does not add, so
+``with_kda_layers`` hands that arithmetic the layers that are no
+``gqa_layers``; since PR 69, ``state_update_roofline`` in this cell).
 A decode step is memory bound at these shapes.
 
 Required work counts the published mathematics only, and only bytes that are
@@ -21,7 +22,8 @@ required work, so a roofline share from these numbers cannot pass 100%.
 
 from __future__ import annotations
 
-from benchmarks import laguna_cost, program_spans, readers, scope_ops
+from benchmarks import (kimi_linear_cost, laguna_cost, program_spans,
+                        readers, scope_ops)
 
 ROW_BYTES = 2  # the K/V rows are bfloat16
 
@@ -63,3 +65,24 @@ def attention_roofline(ctx):
     return laguna_cost._share(
         ctx, gqa_attention_cost(ctx["cell"]["config"], rows),
         attention_ms(ctx))
+
+
+def with_kda_layers(config: dict) -> dict:
+    """The configuration as ``kimi_linear_cost`` reads one: the layers under
+    the file's depth that ``gqa_layers`` does not name are the delta-rule
+    layers (6 of the 8 kept), listed where a ``kimi_linear`` file lists
+    them. Nothing else is touched, and the file is not."""
+    kda = [layer for layer in range(config["num_hidden_layers"])
+           if layer not in config["gqa_layers"]]
+    return dict(config, linear_attn_config=dict(
+        config["linear_attn_config"], kda_layers=kda))
+
+
+def state_roofline(ctx):
+    """``kimi_linear_cost.state_roofline`` over this configuration's
+    delta-rule layers: the same count an element and layer, the same scope
+    (``kda.state``), the same spans."""
+    if "gqa_layers" not in ctx["cell"]["config"]:
+        return None
+    cell = dict(ctx["cell"], config=with_kda_layers(ctx["cell"]["config"]))
+    return kimi_linear_cost.state_roofline(dict(ctx, cell=cell))

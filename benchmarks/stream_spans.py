@@ -4,8 +4,9 @@ over the stream path's counters.
 
 The way (``ray_tpu/observability/schema.py``): the pump's ``engine.emit``
 puts a step's ids into their streams' queues; each stream's handler thread
-takes its id and decodes the answer so far (``replica.detokenize`` [ids,
-backlog]); the worker's stream loop sends what is new as one generator item
+takes its id and decodes a window of the answer's last ids (since PR 57:
+``llm/serving.text_deltas``, not the whole answer again a token;
+``replica.detokenize`` [ids, decoded, backlog]); the worker's stream loop sends what is new as one generator item
 (``worker.stream_yield``), whose ``worker.stream_rpc`` child is the blocking
 call to the caller: the part of a yield in which the handler thread holds no
 GIL. The pump's own clocks (``ContinuousBatcher.stats``: ``pump_step_s``,

@@ -14,9 +14,9 @@ CONF = harness.load_json(os.path.join(
 TRAFFIC = harness.load_json(harness.find_data_file(
     "traffic", "docreason-8k-in-long-out"))
 CELL = "serve-mamba1-mqa-docreason-8k-in-long-out"
-NEW = ("mamba1_state_ms_per_decode_step", "mamba1_state_roofline",
-       "mamba1_project_ms_per_decode_step", "mamba1_prefill_scan_ms_per_req",
-       "mamba1_prefill_scan_roofline", "mamba1_prefill_ms_per_req")
+NEW = ("state_update_ms_per_decode_step", "state_update_roofline",
+       "state_project_ms_per_decode_step", "mamba1_prefill_scan_ms_per_req",
+       "mamba1_prefill_scan_roofline", "whole_prefill_ms_per_req")
 JOINED = ("tput_decode_steps_per_s", "tput_slot_occupancy",
           "tput_device_idle_share", "tput_engine_host_ms_per_step",
           "tput_stream_yield_ms_per_token", "tput_decode_step_device_ms",
@@ -139,10 +139,10 @@ def _ctx(toy=False, spans=True, scopes=SCOPES, prefill=True):
 
 
 @pytest.mark.parametrize("metric, want", [
-    ("mamba1_state_ms_per_decode_step", 0.6),
-    ("mamba1_project_ms_per_decode_step", 5.0),
+    ("state_update_ms_per_decode_step", 0.6),
+    ("state_project_ms_per_decode_step", 5.0),
     ("mamba1_prefill_scan_ms_per_req", 130.0),
-    ("mamba1_prefill_ms_per_req", 520.0),
+    ("whole_prefill_ms_per_req", 520.0),
     ("gqa_attention_ms_per_decode_step", 0.6),
     ("head_sample_ms_per_decode_step", 0.25),
     ("tput_decode_step_device_ms", 10.0),
@@ -162,8 +162,8 @@ def test_roofline_shares_of_the_hbm_bound():
     state = jamba_cost.state_update_cost(CONF, 16)
     scan = jamba_cost.scan_cost(CONF, 6000)
     got = {m: harness.load_reader(m).read(ctx) for m in (
-        "mamba1_state_roofline", "mamba1_prefill_scan_roofline")}
-    assert got["mamba1_state_roofline"] == pytest.approx(
+        "state_update_roofline", "mamba1_prefill_scan_roofline")}
+    assert got["state_update_roofline"] == pytest.approx(
         100 * state["bytes"] / 819e9 / 0.6e-3)
     assert got["mamba1_prefill_scan_roofline"] == pytest.approx(
         100 * scan["bytes"] / 819e9 / 130e-3)
@@ -174,7 +174,7 @@ def test_roofline_shares_of_the_hbm_bound():
         other = dict(_ctx(), cell={"toy": False, "config": {},
                                    "traffic": TRAFFIC, "name": CELL})
         assert read(other) is None  # another family's keys: nothing to read
-    read = harness.load_reader("mamba1_state_roofline").read
+    read = harness.load_reader("state_update_roofline").read
     assert read(_ctx(spans=False)) is None and read(_ctx(scopes={})) is None
     read = harness.load_reader("mamba1_prefill_scan_roofline").read
     assert read(_ctx(prefill=False)) is None
@@ -186,10 +186,13 @@ def test_the_new_readers_have_files_of_their_own():
             os.path.join("layer_metrics", metric + ".py"))
     bench = harness.load_benchmark()
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    own = {m["name"] for m in mine if m["workloads"] == [CELL]}
-    assert own == set(NEW)
-    assert {m["name"] for m in mine} - own == set(JOINED)
-    assert len(bench["per_layer"]) <= 128 and len(bench["workloads"]) <= 24
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert all(CELL in by_name[metric]["workloads"] for metric in NEW)
+    assert {m["name"] for m in mine} >= set(JOINED)
+    # (which entries are this cell's ALONE, and how many entries and cells
+    # there are, is `test_per_layer_entries.py`'s and `test_contract.py`'s to
+    # say: an entry is a question since PR 69, and the next cell's PR edits
+    # no file the benchmark has, this one among them)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONF["name"], "docreason-8k-in-long-out", 1)

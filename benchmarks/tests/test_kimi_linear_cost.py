@@ -11,9 +11,9 @@ from benchmarks import harness, kimi_linear_cost
 CONF = harness.load_json(os.path.join(
     harness.HERE, "configs", "kimi-linear-48b-a3b-serve-ep16.json"))
 CELL = "serve-kda-mla-rollout-long-out"
-NEW = ("kda_state_ms_per_decode_step", "kda_project_ms_per_decode_step",
+NEW = ("state_update_ms_per_decode_step", "state_project_ms_per_decode_step",
        "mla_attention_ms_per_decode_step",
-       "kda_prefill_ms_per_req", "kda_state_roofline",
+       "state_prefill_ms_per_req", "state_update_roofline",
        "mla_attention_roofline")
 
 
@@ -148,10 +148,10 @@ def _ctx(toy=False, spans=True, scopes=SCOPES, prefill=True):
 
 
 @pytest.mark.parametrize("metric, want", [
-    ("kda_state_ms_per_decode_step", 6.0),
-    ("kda_project_ms_per_decode_step", 3.0),
+    ("state_update_ms_per_decode_step", 6.0),
+    ("state_project_ms_per_decode_step", 3.0),
     ("mla_attention_ms_per_decode_step", 1.2),
-    ("kda_prefill_ms_per_req", 61.5),
+    ("state_prefill_ms_per_req", 61.5),
     ("shared_expert_ms_per_decode_step", 0.8),
     ("moe_router_ms_per_decode_step", 0.4),
     ("moe_expert_ms_per_decode_step", 5.0),
@@ -170,18 +170,19 @@ def test_each_reader_on_a_recorded_run(metric, want):
     assert read(bare) is None
 
 
-def test_the_new_readers_have_files_of_their_own():
+def test_the_cell_is_on_every_entry_read_here():
+    # which entries are this cell's ALONE, and how many there are, is
+    # `test_per_layer_entries.py`'s to say (PR 69: an entry is a question,
+    # and other configurations' cells answer these too)
+    bench = harness.load_benchmark()
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for metric in NEW:
         assert harness.load_reader(metric).__file__.endswith(
             os.path.join("layer_metrics", metric + ".py"))
-    bench = harness.load_benchmark()
+        assert CELL in by_name[metric]["workloads"]
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    own = {m["name"] for m in mine if m["workloads"] == [CELL]}
-    # since PR 42 the cell's own readers have an entry each and the shared
-    # ones' lists are joined: no twin is entered for it
-    assert own == set(NEW) and len(mine) > len(own)
-    assert len(bench["per_layer"]) <= 128  # the contract's cap
-    assert all(m["moves"] == "out_tokens_per_s" for m in mine)
+    assert len(mine) > len(NEW)  # the shared readers' lists, joined
+    assert all(m["moves"] in ("out_tokens_per_s", "setup_s") for m in mine)
     for m in mine:  # every entry finds its reader, a prefixed one its words'
         harness.load_reader(m["name"])
 
@@ -192,9 +193,9 @@ def test_roofline_shares_from_what_the_steps_hold_and_reach():
     rows = kimi_linear_cost.latent_attention_cost(CONF, 32 * 2000 + 1)
     held = kimi_linear_cost.held_experts_cost(CONF, 262.0)
     got = {m: harness.load_reader(m).read(ctx) for m in (
-        "kda_state_roofline", "mla_attention_roofline",
+        "state_update_roofline", "mla_attention_roofline",
         "held_experts_roofline")}
-    assert got["kda_state_roofline"] == pytest.approx(
+    assert got["state_update_roofline"] == pytest.approx(
         100 * state["bytes"] / 819e9 / 6.0e-3)
     assert got["mla_attention_roofline"] == pytest.approx(
         100 * rows["bytes"] / 819e9 / 1.2e-3)

@@ -12,11 +12,11 @@ CONF = harness.load_json(os.path.join(
     harness.HERE, "configs", "longcat-flash-serve-ep32-d4.json"))
 CELL = "serve-scmoe-mla-agent-long-ctx"
 ROLLOUT = "serve-kda-mla-rollout-long-out"
-OWN = ("longcat_mla_attention_ms_per_decode_step",
-       "longcat_mla_attention_roofline", "scmoe_dense_ms_per_decode_step",
+OWN = ("mla_attention_ms_per_decode_step",
+       "mla_attention_roofline", "scmoe_dense_ms_per_decode_step",
        "scmoe_dense_roofline", "moe_zero_share",
        "moe_real_experts_per_token_max_over_mean",
-       "moe_rows_gathered_per_computed", "longcat_prefill_ms_per_req")
+       "moe_rows_gathered_per_computed", "whole_prefill_ms_per_req")
 NEW = OWN + ("mla_project_ms_per_decode_step",)
 
 
@@ -164,10 +164,10 @@ def _ctx(toy=False, config=CONF):
 
 
 @pytest.mark.parametrize("metric, want", [
-    ("longcat_mla_attention_ms_per_decode_step", 2.4),
+    ("mla_attention_ms_per_decode_step", 2.4),
     ("mla_project_ms_per_decode_step", 1.2),
     ("scmoe_dense_ms_per_decode_step", 6.0),
-    ("longcat_prefill_ms_per_req", 240.0),
+    ("whole_prefill_ms_per_req", 240.0),
     ("moe_zero_share", 100 * 51200 / 153600),
     # the most a row chose, 12 a program; the mean 8 a row and layer
     ("moe_real_experts_per_token_max_over_mean", 12 / 8),
@@ -194,9 +194,9 @@ def test_roofline_shares_from_what_the_steps_hold():
     rows = longcat_cost.latent_attention_cost(CONF, 32 * 3500 + 1)
     dense = longcat_cost.dense_cost(CONF, 32)
     got = {m: harness.load_reader(m).read(ctx) for m in (
-        "longcat_mla_attention_roofline", "scmoe_dense_roofline",
+        "mla_attention_roofline", "scmoe_dense_roofline",
         "held_experts_roofline")}
-    assert got["longcat_mla_attention_roofline"] == pytest.approx(
+    assert got["mla_attention_roofline"] == pytest.approx(
         100 * rows["bytes"] / 819e9 / 2.4e-3)
     assert got["scmoe_dense_roofline"] == pytest.approx(
         100 * dense["bytes"] / 819e9 / 6.0e-3)
@@ -209,9 +209,13 @@ def test_roofline_shares_from_what_the_steps_hold():
         _ctx(toy=True)) is None
     other = harness.load_json(os.path.join(
         harness.HERE, "configs", "kimi-linear-48b-a3b-serve-ep16.json"))
-    for metric in ("longcat_mla_attention_roofline", "scmoe_dense_roofline",
-                   "longcat_mla_attention_ms_per_decode_step"):
-        assert harness.load_reader(metric).read(_ctx(config=other)) is None
+    assert harness.load_reader("scmoe_dense_roofline").read(
+        _ctx(config=other)) is None
+    # the shared entries answer for whichever configuration the cell names
+    # (`costs.py`): that one's 7 latent layers' rows, not 8 sublayers'
+    assert harness.load_reader("mla_attention_roofline").read(
+        _ctx(config=other)) == pytest.approx(
+        got["mla_attention_roofline"] * 7 / 8)
 
 
 def test_every_new_entry_has_a_reader_a_unit_and_a_cell():
@@ -225,20 +229,17 @@ def test_every_new_entry_has_a_reader_a_unit_and_a_cell():
         assert entry["unit"] and entry["layer"] == "model"
         assert entry["moves"] == "out_tokens_per_s"
         assert CELL in entry["workloads"]
-    assert by_name["mla_project_ms_per_decode_step"]["workloads"] == [
-        ROLLOUT, CELL]
+    assert {ROLLOUT, CELL} <= set(
+        by_name["mla_project_ms_per_decode_step"]["workloads"])
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    assert {m["name"] for m in mine if m["workloads"] == [CELL]} == set(OWN)
-    assert len(mine) == 17 + len(NEW)  # the shared readers' lists, joined
-    # one entry a pair (reader file, moves), and under the contract's cap
-    pairs = [(harness.load_reader(m["name"]).__file__, m["moves"])
-             for m in bench["per_layer"]]
-    assert len(set(pairs)) == len(pairs) == 92 <= 128
+    assert len(mine) > len(NEW)  # the shared readers' lists, joined
+    # (which entries are this cell's ALONE, and how many entries and cells
+    # there are, is `test_per_layer_entries.py`'s and `test_contract.py`'s to
+    # say: an entry is a question since PR 69, and the next cell's PR edits
+    # no file the benchmark has, this one among them)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "longcat-flash-serve-ep32-d4", "agent-long-ctx", 1)
-    assert len(bench["workloads"]) == 9
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     # the contract's one line of at most 200 printable characters: the
     # driver refuses the whole file for one `why` past it
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
@@ -246,4 +247,4 @@ def test_every_new_entry_has_a_reader_a_unit_and_a_cell():
         assert 1 <= len(line) <= 200 and line.isprintable() and line.isascii()
     tput = next(m for m in bench["end_to_end"]
                 if m["name"] == "out_tokens_per_s")
-    assert tput["workloads"][-1] == CELL and tput["bound"] == 0.055
+    assert CELL in tput["workloads"] and tput["bound"] == 0.055

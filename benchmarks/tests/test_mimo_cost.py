@@ -12,8 +12,8 @@ from benchmarks import harness, laguna_cost, mimo_cost
 CONF = harness.load_json(os.path.join(
     harness.HERE, "configs", "mimo-v2-flash-serve-ep16-d11.json"))
 CELL = "serve-sink-window-moe-agent-8k-in-2k-out"
-NEW = ("mimo_window_attention_roofline", "mimo_full_attention_roofline",
-       "mimo_prefill_attention_roofline", "mimo_prefill_ms_per_req")
+NEW = ("window_attention_roofline", "full_attention_roofline",
+       "mimo_prefill_attention_roofline", "whole_prefill_ms_per_req")
 JOINED = ("tput_decode_steps_per_s", "tput_slot_occupancy",
           "tput_device_idle_share", "tput_engine_host_ms_per_step",
           "tput_stream_yield_ms_per_token", "tput_decode_step_device_ms",
@@ -204,7 +204,7 @@ def _ctx(toy=False, spans=True, scopes=SCOPES, prefill=True):
 
 
 @pytest.mark.parametrize("metric, want", [
-    ("mimo_prefill_ms_per_req", 230.0),
+    ("whole_prefill_ms_per_req", 230.0),
     ("window_attention_ms_per_decode_step", 2.6),
     ("full_attention_ms_per_decode_step", 2.4),
     ("moe_router_ms_per_decode_step", 0.5),
@@ -231,9 +231,9 @@ def test_roofline_shares_from_what_the_steps_hold():
     full = mimo_cost.decode_attention_cost(CONF, "full", 32 * 7000 + 1)
     ring = mimo_cost.decode_attention_cost(CONF, "window", 32 * 128)
     pre = mimo_cost.prefill_attention_cost(CONF, 8192)
-    assert got["mimo_full_attention_roofline"] == pytest.approx(
+    assert got["full_attention_roofline"] == pytest.approx(
         100 * full["bytes"] / 819e9 / 2.4e-3)
-    assert got["mimo_window_attention_roofline"] == pytest.approx(
+    assert got["window_attention_roofline"] == pytest.approx(
         100 * ring["bytes"] / 819e9 / 2.6e-3)
     assert got["mimo_prefill_attention_roofline"] == pytest.approx(
         100 * pre["flops"] / 197e12 / 24e-3)
@@ -253,15 +253,15 @@ def test_the_new_readers_have_files_of_their_own():
             os.path.join("layer_metrics", metric + ".py"))
     bench = harness.load_benchmark()
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    own = {m["name"] for m in mine if m["workloads"] == [CELL]}
-    assert own == set(NEW) and len(NEW) <= 6
-    assert {m["name"] for m in mine} - own == set(JOINED)
-    # another family's configuration keys: not this cell's
-    assert all(CELL not in m["workloads"] for m in bench["per_layer"]
-               if m["name"] in ("window_attention_roofline",
-                                "full_attention_roofline",
-                                "shared_expert_ms_per_decode_step"))
-    assert len(bench["per_layer"]) <= 128 and len(bench["workloads"]) <= 24
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert all(CELL in by_name[metric]["workloads"] for metric in NEW)
+    assert {m["name"] for m in mine} >= set(JOINED)
+    # (which entries are this cell's ALONE, and how many entries and cells
+    # there are, is `test_per_layer_entries.py`'s and `test_contract.py`'s to
+    # say: an entry is a question since PR 69, and the next cell's PR edits
+    # no file the benchmark has, this one among them)
+    # no shared expert in this configuration: not this cell's
+    assert CELL not in by_name["shared_expert_ms_per_decode_step"]["workloads"]
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONF["name"], "agent-8k-in-2k-out", 1)

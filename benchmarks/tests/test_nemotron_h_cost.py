@@ -11,9 +11,9 @@ from benchmarks import harness, laguna_cost, nemotron_h_cost
 CONF = harness.load_json(os.path.join(
     harness.HERE, "configs", "nemotron-3-super-serve-ep8-d22.json"))
 CELL = "serve-ssm-lmoe-reason-long-out"
-NEW = ("ssm_state_ms_per_decode_step", "ssm_state_roofline",
-       "ssm_project_ms_per_decode_step", "ssm_prefill_ms_per_req",
-       "lmoe_latent_ms_per_decode_step", "lmoe_held_experts_roofline",
+NEW = ("state_update_ms_per_decode_step", "state_update_roofline",
+       "state_project_ms_per_decode_step", "state_prefill_ms_per_req",
+       "lmoe_latent_ms_per_decode_step", "held_experts_roofline",
        "gqa_attention_ms_per_decode_step")
 JOINED = ("tput_decode_steps_per_s", "tput_slot_occupancy",
           "tput_device_idle_share", "tput_engine_host_ms_per_step",
@@ -191,9 +191,9 @@ def _ctx(toy=False, spans=True, scopes=SCOPES, prefill=True):
 
 
 @pytest.mark.parametrize("metric, want", [
-    ("ssm_state_ms_per_decode_step", 4.2),
-    ("ssm_project_ms_per_decode_step", 5.0),
-    ("ssm_prefill_ms_per_req", 21.5),
+    ("state_update_ms_per_decode_step", 4.2),
+    ("state_project_ms_per_decode_step", 5.0),
+    ("state_prefill_ms_per_req", 21.5),
     ("lmoe_latent_ms_per_decode_step", 0.5),
     ("gqa_attention_ms_per_decode_step", 0.6),
     ("shared_expert_ms_per_decode_step", 1.1),
@@ -220,16 +220,17 @@ def test_the_new_readers_have_files_of_their_own():
             os.path.join("layer_metrics", metric + ".py"))
     bench = harness.load_benchmark()
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    own = {m["name"] for m in mine if m["workloads"] == [CELL]}
-    assert own == set(NEW)
-    assert {m["name"] for m in mine} - own == set(JOINED)
-    # the three-matrix count on the full width is not this cell's
-    assert all(CELL not in m["workloads"] for m in bench["per_layer"]
-               if m["name"] in ("held_experts_roofline",
-                                "moe_experts_roofline"))
-    # (no count of entries or cells is pinned here: the next cell's PR may
-    # edit no file the benchmark has, this one among them)
-    assert len(bench["per_layer"]) <= 128 and len(bench["workloads"]) <= 24
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert all(CELL in by_name[metric]["workloads"] for metric in NEW)
+    assert {m["name"] for m in mine} >= set(JOINED)
+    # (which entries are this cell's ALONE, and how many entries and cells
+    # there are, is `test_per_layer_entries.py`'s and `test_contract.py`'s to
+    # say: an entry is a question since PR 69, and the next cell's PR edits
+    # no file the benchmark has, this one among them)
+    # the prefill's count of three matrices on the full width is not this
+    # cell's (the decode step's is `nemotron_h_cost`'s two in a latent:
+    # `answers/serve_nemotron_h.py` hands `held_experts_roofline` to it here)
+    assert CELL not in by_name["moe_experts_roofline"]["workloads"]
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONF["name"], "reason-short-in-long-out", 1)
@@ -241,7 +242,7 @@ def test_the_new_readers_have_files_of_their_own():
     tput = next(m for m in bench["end_to_end"]
                 if m["name"] == "out_tokens_per_s")
     assert CELL in tput["workloads"] and tput["bound"] == 0.055
-    assert all(m["moves"] == "out_tokens_per_s" for m in mine)
+    assert all(m["moves"] in ("out_tokens_per_s", "setup_s") for m in mine)
     for m in mine:  # every entry finds its reader, a prefixed one its words'
         harness.load_reader(m["name"])
 
@@ -251,10 +252,10 @@ def test_roofline_shares_from_what_the_steps_hold_and_reach():
     state = nemotron_h_cost.state_update_cost(CONF, 32)
     held = nemotron_h_cost.held_experts_cost(CONF, 480.0)
     got = {m: harness.load_reader(m).read(ctx) for m in (
-        "ssm_state_roofline", "lmoe_held_experts_roofline")}
-    assert got["ssm_state_roofline"] == pytest.approx(
+        "state_update_roofline", "held_experts_roofline")}
+    assert got["state_update_roofline"] == pytest.approx(
         100 * state["bytes"] / 819e9 / 4.2e-3)
-    assert got["lmoe_held_experts_roofline"] == pytest.approx(
+    assert got["held_experts_roofline"] == pytest.approx(
         100 * held["bytes"] / 819e9 / 7.0e-3)
     assert all(0 < v < 100 for v in got.values()), got
     for m in got:  # a CPU has no published peak; the parent has no span,
@@ -262,5 +263,5 @@ def test_roofline_shares_from_what_the_steps_hold_and_reach():
         assert read(_ctx(toy=True)) is None
         other = dict(_ctx(), cell={"toy": False, "config": {}, "name": CELL})
         assert read(other) is None  # another family's keys: nothing to read
-    read = harness.load_reader("ssm_state_roofline").read
+    read = harness.load_reader("state_update_roofline").read
     assert read(_ctx(spans=False)) is None and read(_ctx(scopes={})) is None

@@ -16,7 +16,7 @@ TRAFFIC = harness.load_json(harness.find_data_file(
 CELL = "serve-loop4-mha-problems-256-in-256-out"
 NEW = ("loop_layers_ms_per_decode_step", "loop_layers_roofline",
        "loop_attention_ms_per_decode_step", "loop_attention_roofline",
-       "loop_prefill_ms_per_req", "loop_prefill_roofline")
+       "whole_prefill_ms_per_req", "loop_prefill_roofline")
 JOINED = ("tput_decode_steps_per_s", "tput_slot_occupancy",
           "tput_device_idle_share", "tput_engine_host_ms_per_step",
           "tput_stream_yield_ms_per_token", "tput_decode_step_device_ms",
@@ -139,7 +139,7 @@ def _ctx(toy=False, spans=True, scopes=SCOPES, prefill=True, conf=CONF):
 @pytest.mark.parametrize("metric, want", [
     ("loop_layers_ms_per_decode_step", 32.0 - 0.35),
     ("loop_attention_ms_per_decode_step", 6.0),
-    ("loop_prefill_ms_per_req", 40.0),
+    ("whole_prefill_ms_per_req", 40.0),
     ("head_sample_ms_per_decode_step", 0.35),
     ("tput_decode_step_device_ms", 32.0),
 ])
@@ -185,15 +185,17 @@ def test_the_new_entries_have_readers_units_and_the_cell():
             os.path.join("layer_metrics", metric + ".py"))
     bench = harness.load_benchmark()
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    own = {m["name"]: m for m in mine if m["workloads"] == [CELL]}
+    own = {m["name"]: m for m in mine if m["name"] in NEW}
     assert set(own) == set(NEW)
     assert {m["unit"] for n, m in own.items() if "roofline" in n} == {"%"}
     assert {m["unit"] for n, m in own.items() if "roofline" not in n} == {"ms"}
     assert all((m["source"], m["layer"], m["moves"]) == (
         "device_trace", "model", "out_tokens_per_s") for m in own.values())
     assert {m["name"] for m in mine} - set(own) >= set(JOINED)
-    # (no count is pinned: a later PR appends cells and entries)
-    assert len(bench["per_layer"]) <= 128 and len(bench["workloads"]) <= 24
+    # (which entries are this cell's ALONE, and how many entries and cells
+    # there are, is `test_per_layer_entries.py`'s and `test_contract.py`'s to
+    # say: an entry is a question since PR 69, and the next cell's PR edits
+    # no file the benchmark has, this one among them)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONF["name"], "problems-256-in-256-out", 1)

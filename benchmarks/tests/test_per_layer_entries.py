@@ -5,13 +5,19 @@ reader sees its metric's name, so one file is one value: comparing the two
 names' values could not fail. The span readers that find something in the
 recorded chat trace are held to the values recorded from it instead.) The
 table is also the way back from a new name to the names the ledger's lines
-before PR 42 carry."""
+before PR 42 carry.
+
+Since PR 69 an entry is a QUESTION and not a configuration's answer to it:
+``benchmarks/renamed.json`` is the way back from an entry to the names the
+ledger's lines up to PR 68 carry, each with what its reader (a file of 5-7
+lines that PR 69 deleted) handed ``ctx`` to. The ``test_*_cost.py`` files
+hold their configurations' COUNTS to hand counts and pin no list."""
 
 import os
 
 import pytest
 
-from benchmarks import harness
+from benchmarks import costs, harness
 from benchmarks import program_spans as P
 
 # entry -> the prefixes under which its reader was entered before PR 42
@@ -108,3 +114,58 @@ def test_the_table_is_every_entry_that_several_cells_share():
                           if m["name"] in shared)
     with pytest.raises(FileNotFoundError):  # retired in PR 42, reader and all
         harness.load_reader("stream_hop_gap_ms")
+
+
+# PR 69: ``renamed.json`` holds, frozen from the parent's ``layer_metrics/``,
+# every name a merged entry took the place of, with the runners whose cells
+# it was read in and what its reader handed ``ctx`` to.
+RENAMED = harness.load_json(os.path.join(harness.HERE, "renamed.json"))["rows"]
+
+
+def _named(what):
+    """A frozen row's answer: ``module.function`` by name, a tuple of
+    scopes, or a capture's key as it is."""
+    if isinstance(what, list):
+        return tuple(what)
+    if "." in what:
+        module, function = what.split(".")
+        return getattr(__import__(f"benchmarks.{module}", fromlist=[function]),
+                       function)
+    return what
+
+
+@pytest.mark.parametrize("row", RENAMED, ids=lambda row: row["old"])
+def test_a_replaced_name_is_still_answered_as_it_was(row):
+    """A SUBSET is held, so that a later cell joins a list, brings its
+    ``answers/<runner>.py`` and adds entries without turning this red: every
+    cell the old name was read in is on the new entry's list, and its
+    configuration's runner gets the answer it had."""
+    bench = harness.load_benchmark()
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    me = per_layer[row["new"]]
+    # what the parent's entries stated, the merged ones still state
+    assert (me["unit"], me["better"]) == (
+        ("%", "higher") if row["new"].endswith("_roofline") else ("ms", "lower"))
+    assert (me["source"], me["layer"], me["moves"]) == (
+        "device_trace", "model", "out_tokens_per_s")
+    reader = harness.load_reader(row["new"])
+    if row["old"] != row["new"]:  # gone, entry and file: a name that still
+        # finds a reader finds, as a prefixed name does, the one that took
+        # its place
+        assert row["old"] not in per_layer
+        try:
+            assert harness.load_reader(row["old"]).__file__ == reader.__file__
+        except FileNotFoundError:
+            pass
+    assert set(row["cells"]) <= set(me["workloads"])
+    for cell in row["cells"]:
+        config = harness.load_cell(cell)["config"]
+        assert config["runner"] in row["runners"]
+        if row["question"]:
+            assert costs.of({"cell": {"config": config}},
+                            row["question"]) == _named(row["answer"])
+    # a runner with no file under ``answers/`` has nothing to read
+    nobody = {"cell": {"config": {"runner": "serve_nobody"}, "toy": False},
+              "counters": {}, "device": {}, "trace": {}}
+    if row["question"]:
+        assert reader.read(nobody) is None

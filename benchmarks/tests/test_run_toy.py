@@ -8,6 +8,7 @@ Toy runs print the device they ran on (cpu) and are never a result.
 
 import json
 import os
+import pickle
 import shutil
 import subprocess
 import sys
@@ -89,15 +90,18 @@ def run(cell, args):
             "device": {"platform": "none", "kind": "none", "count": 1,
                        "memory_peak_bytes": 0},
             "counters": {"setup_s": 0.5, "dummy_rate": 2.0 * args["seed"],
-                         "knob": cell["config"]["knob"]},
+                         "knob": cell["config"]["knob"],
+                         "dummy_capture": {"ms_per_req": 3.0}},
             "trace": {}}
 '''
 
 
 def _checkout_with_a_dummy_cell(tmp_path, runner_source=DUMMY_RUNNER):
     """A copy of the benchmark beside the program, plus ``dummy-cell``: a
-    configuration, a traffic mix, a runner and two per-layer metrics as new
-    files and entries. Returns the copied files as they were before."""
+    configuration, a traffic mix, a runner, its answers to the questions
+    several configurations share and two per-layer metrics as new files and
+    entries, and its name on a shared entry's list. Returns the copied files
+    as they were before."""
     shutil.copytree(harness.HERE, tmp_path / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(os.path.join(harness.ROOT, "ray_tpu"), tmp_path / "ray_tpu")
@@ -109,6 +113,8 @@ def _checkout_with_a_dummy_cell(tmp_path, runner_source=DUMMY_RUNNER):
         {"name": "dummy-config", "runner": "dummy", "knob": 7}))
     (b / "traffic" / "dummy-mix.json").write_text(json.dumps({"n": 11}))
     (b / "runners" / "dummy.py").write_text(runner_source)
+    (b / "answers" / "dummy.py").write_text(
+        "ANSWERS = {'whole_prefill': 'dummy_capture'}\n")
     (b / "layer_metrics" / "dummy_knob.py").write_text(
         "def read(ctx):\n    return ctx['counters']['knob'] * 1.5\n")
     (b / "layer_metrics" / "dummy_absent.py").write_text(
@@ -129,20 +135,23 @@ def _checkout_with_a_dummy_cell(tmp_path, runner_source=DUMMY_RUNNER):
             "name": name, "unit": "x", "better": "higher",
             "source": "program_counter", "layer": "dummy",
             "moves": "dummy_rate", "workloads": ["dummy-cell"]})
+    next(m for m in bench["per_layer"] if m["name"] ==
+         "whole_prefill_ms_per_req")["workloads"].append("dummy-cell")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     return before
 
 
 def test_a_cell_is_added_with_new_files_and_entries_alone(tmp_path):
-    """A later PR adds a configuration, a traffic mix, a runner and a
-    per-layer metric: four new files, entries in BENCHMARK.json, and not one
-    edit to a file that is there."""
+    """A later PR adds a configuration, a traffic mix, a runner, its answers
+    and a per-layer metric, and joins a shared entry's list: new files,
+    entries in BENCHMARK.json, and not one edit to a file that is there."""
     before = _checkout_with_a_dummy_cell(tmp_path)
 
     def run(trace):
         proc = subprocess.run(
             [sys.executable, "benchmarks/run.py", "--workload", "dummy-cell",
-             "--seed", "4", "--seconds", "1", "--trace", str(trace)],
+             "--seed", "4", "--seconds", "1", "--trace", str(trace),
+             "--keep-record", str(tmp_path / "record.pkl")],
             cwd=tmp_path, capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stderr[-2000:]
         return json.loads(proc.stdout.strip().splitlines()[-1])
@@ -152,7 +161,12 @@ def test_a_cell_is_added_with_new_files_and_entries_alone(tmp_path):
     assert line["metrics"] == {"dummy_rate": {"value": 8.0, "unit": "x/s"},
                                "setup_s": {"value": 0.5, "unit": "s"}}
     # a reader that finds nothing returns nothing: the metric is left out
-    assert run(1)["metrics"] == {"dummy_knob": {"value": 10.5, "unit": "x"}}
+    assert run(1)["metrics"] == {
+        "dummy_knob": {"value": 10.5, "unit": "x"},
+        "whole_prefill_ms_per_req": {"value": 3.0, "unit": "ms"}}
+    # what the readers were given, kept for `same_readings.py`
+    with open(tmp_path / "record.pkl", "rb") as f:
+        assert pickle.load(f)["counters"]["knob"] == 7
     after = {p: open(os.path.join(root, p), "rb").read()
              for root, _d, files in os.walk(tmp_path / "benchmarks")
              for p in files if "__pycache__" not in root}
